@@ -26,10 +26,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 names it TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_INF = -1e30
 
 
@@ -389,7 +385,7 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((KVH, Tp * g, Dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(meta, qr, kr, vr)
@@ -438,7 +434,7 @@ def flash_prefill_partial(q: jax.Array, k: jax.Array, v: jax.Array, *,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((KVH, Tp * g, Dh), jnp.float32),
                    jax.ShapeDtypeStruct((KVH, Tp * g, 2), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(meta, qr, kr, vr)
@@ -1544,7 +1540,7 @@ def ragged_paged_attention_pallas(q: jax.Array, k_cache: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((TT + Lmax, Hp, Cv), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(block_tables, jnp.asarray(seq_starts, jnp.int32),
@@ -1598,9 +1594,15 @@ def ragged_prefetch_counts(seq_counts, seq_lens, win_base=None, *,
             "hit_ratio": prefetched / max(first_waves, 1)}
 
 
-# VMEM budget for the ragged kernel's per-sequence windows (q + o + acc
-# + m/l scratch); conservative — the real bound also carries the KV
-# wave buffers, which ragged_supported charges separately
+# VMEM budget for the ragged kernel: the per-sequence windows (q + o +
+# acc + m/l scratch) plus what scales with the KV wave (the
+# double-buffered wave tiles and the score tile), both as
+# ragged_supported counts them. Mosaic's scoped-VMEM limit on v5e is
+# 16 MiB; the wave charge is calibrated, not derived — deviceless v5e
+# compiles at Llama 1B and 8B widths (blocks of 16/32/64 tokens, bf16
+# and int8 pools) build everywhere the sum fits and the sum stops
+# within two rows of where the compiler does
+# (tests/test_tpu_compile.py pins the 1B boundaries).
 _RAGGED_VMEM_BUDGET = 8 << 20
 
 
@@ -1609,23 +1611,25 @@ def ragged_supported(num_heads: int, num_kv_heads: int, head_dim: int,
                      kv_dtype=None) -> bool:
     """True if the ragged Pallas kernel handles this geometry at this
     per-sequence row budget: the decode kernel's lane/sublane
-    constraints (pallas_supported) plus the q/acc VMEM window fitting
-    the budget — [Lmax*Hp, C] f32 scores duplicate query rows across
+    constraints (pallas_supported) plus its VMEM working set fitting the
+    budget — [Lmax*Hp, C] f32 scores duplicate query rows across
     sublanes, so large GQA geometries bound Lmax (MQA/MLA pools,
-    KVH == 1, carry no duplication and take the deepest windows)."""
+    KVH == 1, carry no duplication and take the deepest windows), and a
+    larger KV block widens every wave, which buys a smaller Lmax."""
     if not pallas_supported(num_heads, num_kv_heads, head_dim,
                             block_size, kv_dtype=kv_dtype):
         return False
     Hp = max(8, num_heads)
     C = num_kv_heads * head_dim
     Lmax = max(8, max_rows)
+    wave = int(os.environ.get("DYN_ATTN_CHUNK_BLOCKS", "16")) * block_size
     window_bytes = Lmax * Hp * C * (2 + 2 + 4 + 4)   # q + o + acc(+m/l)
-    return window_bytes <= _RAGGED_VMEM_BUDGET
+    wave_bytes = 3 * wave * wave
+    return window_bytes + wave_bytes <= _RAGGED_VMEM_BUDGET
 
 
 @functools.cache
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
+    # a backend that fails to initialise raises here: it must never read
+    # as "not a TPU" and turn every attn_impl="auto" site into XLA
+    return jax.devices()[0].platform == "tpu"

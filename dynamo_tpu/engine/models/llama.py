@@ -20,11 +20,12 @@ Weight layout matches HF llama checkpoints after transpose (see weights.py).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from ..attention import causal_attention  # noqa: F401  (used by sp path)
 from ..attention import (KV_SCALE_LANES, RAGGED_WIN_SENTINEL, _on_tpu,
@@ -396,10 +397,19 @@ class ModelStatics:
     # run-coalesced decode DMA (attention.py wave_contig_table):
     # EngineConfig.kv_contig_alloc=False forces the per-block path
     kv_coalesce: bool = True
+    # the engine's mesh when it shards heads over "tp" (else None): the
+    # compiler refuses to partition a Pallas kernel ("Mosaic kernels
+    # cannot be automatically partitioned"), so the attention kernels
+    # run per tp shard under shard_map (_per_tp_shard)
+    mesh: Optional[Any] = None
 
     def __hash__(self):
         return hash((id(self.cfg), self.block_size, self.attn_impl,
-                     self.kv_coalesce))
+                     self.kv_coalesce, id(self.mesh)))
+
+    @property
+    def tp(self) -> int:
+        return self.mesh.shape["tp"] if self.mesh is not None else 1
 
 
 def _run_layers(params: Params, kv: KVCache, x: jax.Array,
@@ -614,6 +624,60 @@ def _attn_scale(cfg: ModelConfig) -> float:
     return (cfg.query_pre_attn_scalar or cfg.head_dim) ** -0.5
 
 
+def _wants_kernel(statics: ModelStatics) -> bool:
+    """Whether attn_impl asks for a Pallas kernel where one applies."""
+    impl = statics.attn_impl
+    return impl in ("pallas", "pallas_interpret") or (
+        impl == "auto" and _on_tpu())
+
+
+def _per_tp_shard(statics: ModelStatics, fn, in_specs, out_specs):
+    """fn as the engine must call it when a Pallas kernel may be inside:
+    unchanged on one device; under a tp mesh, per shard via shard_map —
+    attention heads are independent, q/out shard on the head axis and
+    the KV pool on its lane axis (whole KV heads per shard,
+    parallel/sharding.kv_pspecs), so no collective is needed."""
+    if statics.tp == 1:
+        return fn
+    return jax.shard_map(fn, mesh=statics.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+def _paged_attention(statics: ModelStatics, q, k_flat, v_flat, tables,
+                     seq_lens, win_lo, scale: float):
+    """paged_attention at the model's geometry (decode rows and the
+    ragged row path share it). Under tp each shard resolves its own
+    impl from its LOCAL geometry — a shard of an int8 pool holds exactly
+    one (values, scales) section, so it reads as a single-group pool."""
+    cfg = statics.cfg
+    impl = statics.attn_impl
+    tp = statics.tp if _wants_kernel(statics) else 1
+    if cfg.num_kv_heads % tp != 0:
+        # a KV head straddles two shards: no per-shard kernel exists
+        if impl != "auto":
+            raise ValueError(
+                f"attn_impl {impl!r} forced but tp={tp} does not divide "
+                f"the {cfg.num_kv_heads} KV heads")
+        impl, tp = "xla", 1
+
+    def attend(q, k_flat, v_flat, tables, seq_lens, win_lo):
+        return paged_attention(q, k_flat, v_flat, tables, seq_lens,
+                               block_size=statics.block_size, scale=scale,
+                               impl=impl,
+                               softcap=cfg.attn_logit_softcap,
+                               win_lo=win_lo,
+                               kv_heads=cfg.num_kv_heads // tp,
+                               coalesce=statics.kv_coalesce)
+
+    if tp > 1:
+        heads, lanes, rep = P(None, "tp", None), P(None, "tp"), P()
+        attend = _per_tp_shard(
+            statics, attend,
+            (heads, lanes, lanes, rep, rep, None if win_lo is None else rep),
+            heads)
+    return attend(q, k_flat, v_flat, tables, seq_lens, win_lo)
+
+
 def _prefill_flash_impl(statics: ModelStatics):
     """Prefill attention dispatch: the Pallas flash kernel on TPU (or
     interpret mode when forced), the dense-score einsum elsewhere. Mirrors
@@ -621,8 +685,9 @@ def _prefill_flash_impl(statics: ModelStatics):
     forced impl the geometry can't run, so a parity test can never silently
     compare the einsum path against itself."""
     cfg = statics.cfg
-    supported = flash_prefill_supported(cfg.num_heads, cfg.num_kv_heads,
-                                        cfg.head_dim)
+    supported = (flash_prefill_supported(cfg.num_heads, cfg.num_kv_heads,
+                                         cfg.head_dim)
+                 and cfg.num_kv_heads % statics.tp == 0)
     impl = statics.attn_impl
     if impl == "auto":
         return _on_tpu() and supported
@@ -699,12 +764,18 @@ def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
         if use_flash:
             # Pallas online-softmax kernel: O(TQ·SC) live memory instead
             # of a [KVH, g, T, S] score materialization
-            return flash_prefill(
-                q, ks, vs, scale=scale, start_pos=start_pos,
-                seq_len=seq_len, sliding=sliding,
-                window=cfg.sliding_window,
-                softcap=cfg.attn_logit_softcap or None,
-                interpret=(use_flash == "interpret"))
+            def flash(q, ks, vs, start_pos, seq_len, sliding):
+                return flash_prefill(
+                    q, ks, vs, scale=scale, start_pos=start_pos,
+                    seq_len=seq_len, sliding=sliding,
+                    window=cfg.sliding_window,
+                    softcap=cfg.attn_logit_softcap or None,
+                    interpret=(use_flash == "interpret"))
+            heads = P(None, "tp", None)
+            return _per_tp_shard(
+                statics, flash, (heads, heads, heads, P(), P(), P()),
+                heads)(q, ks, vs, start_pos, seq_len,
+                       jnp.asarray(sliding))
         g = cfg.num_heads // cfg.num_kv_heads
         qg = q.reshape(T, cfg.num_kv_heads, g, cfg.head_dim)
         scores = jnp.einsum("tkgd,skd->kgts", qg, ks).astype(jnp.float32) * scale
@@ -774,7 +845,9 @@ def ragged_attn_impl(statics: ModelStatics, max_rows: int, kv_dtype,
     always take the row path, exactly as paged_attention refuses them
     for the decode kernel."""
     cfg = statics.cfg
-    ok = (kv_groups == 1
+    # tp meshes take the row path: the sequence-grouped kernel has no
+    # per-shard form yet (its q window spans all of a sequence's heads)
+    ok = (kv_groups == 1 and statics.tp == 1
           and ragged_supported(cfg.num_heads, cfg.num_kv_heads,
                                cfg.head_dim, statics.block_size,
                                max_rows, kv_dtype=kv_dtype))
@@ -872,14 +945,9 @@ def ragged_forward(params: Params, kv: KVCache, tokens: jax.Array,
                                jnp.full_like(positions, -1))
         # the decode program's attention verbatim, over row-expanded
         # tables — the bit-exactness anchor of the ragged contract
-        return paged_attention(q, k_flat, v_flat,
-                               row_tables + li * num_blocks, seq_lens,
-                               block_size=bsz, scale=scale,
-                               impl=statics.attn_impl,
-                               softcap=cfg.attn_logit_softcap,
-                               win_lo=win_lo,
-                               kv_heads=cfg.num_kv_heads,
-                               coalesce=statics.kv_coalesce)
+        return _paged_attention(statics, q, k_flat, v_flat,
+                                row_tables + li * num_blocks, seq_lens,
+                                win_lo, scale)
 
     x = _embed(params, tokens, cfg)  # [TT, D]
     x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn)
@@ -916,14 +984,9 @@ def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
         # DMA addressing, and int8 pools via in-row scales) works
         # unchanged on offset tables
         num_blocks = k_flat.shape[0] // (cfg.num_layers * bsz)
-        return paged_attention(q, k_flat, v_flat,
-                               block_tables + li * num_blocks, seq_lens,
-                               block_size=bsz, scale=scale,
-                               impl=statics.attn_impl,
-                               softcap=cfg.attn_logit_softcap,
-                               win_lo=win_lo,
-                               kv_heads=cfg.num_kv_heads,
-                               coalesce=statics.kv_coalesce)
+        return _paged_attention(statics, q, k_flat, v_flat,
+                                block_tables + li * num_blocks, seq_lens,
+                                win_lo, scale)
 
     x = _embed(params, tokens, cfg)  # [B, D]
     x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn)

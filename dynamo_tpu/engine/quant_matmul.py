@@ -33,10 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 names it TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 GROUP = 128          # contraction rows per scale group (quant.GROUP_SIZE)
 _HG = GROUP // 2     # packed bytes (and even/odd x columns) per group
 
@@ -136,7 +132,7 @@ def grouped_int4_matmul(x: jax.Array, packed: jax.Array, scale: jax.Array,
         out_specs=pl.BlockSpec((TN, TF), lambda n, f, d: (n, f)),
         out_shape=jax.ShapeDtypeStruct((Np, F), x.dtype),
         scratch_shapes=[pltpu.VMEM((TN, TF), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xe, xo, packed, scale.astype(jnp.float32))

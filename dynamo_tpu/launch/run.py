@@ -347,6 +347,10 @@ def build_jax_core(args):
         ecfg = engine_config(args)   # validates pp/K/batch combos early
     except (ValueError, NotImplementedError) as e:
         raise SystemExit(str(e))
+    import jax
+    devices = jax.devices()   # a backend that cannot start raises here
+    logger.info("jax backend: platform=%s device_kind=%s count=%d",
+                devices[0].platform, devices[0].device_kind, len(devices))
     mesh = None
     if args.pp > 1:
         # pp(×tp) mesh: the stage ring crosses "pp" (the DCN-viable
@@ -887,6 +891,9 @@ async def amain(argv=None) -> None:
 
 
 def main() -> None:
+    if "out=jax" in sys.argv[1:]:
+        from ..utils.compile_cache import enable_compile_cache
+        enable_compile_cache()
     try:
         asyncio.run(amain())
     except KeyboardInterrupt:

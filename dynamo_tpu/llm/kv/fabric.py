@@ -73,14 +73,15 @@ DATAPLANE_ENV = "DYN_KV_FABRIC_DATAPLANE"
 
 
 def dataplane_serving_available() -> bool:
-    """Whether THIS process can serve native-dataplane fetches: the env
-    gate is on and the C++ data plane (csrc/data_plane.cpp) loads. A
-    peer where either fails declines ``fetch_native`` and the fetching
-    side falls back to the JSON path — never an error."""
+    """Whether THIS process serves native-dataplane fetches: the env
+    gate is on (the C++ data plane, csrc/data_plane.cpp, then loads or
+    raises). A peer with the gate off declines ``fetch_native`` and the
+    fetching side takes the JSON path."""
     if os.environ.get(DATAPLANE_ENV, "1") == "0":
         return False
     from ...runtime.native_tcp import load_data_plane_lib
-    return load_data_plane_lib() is not None
+    load_data_plane_lib()
+    return True
 
 
 # ---------------------------------------------------------------------------

@@ -23,9 +23,9 @@ _U64 = ctypes.c_uint64
 _P = ctypes.c_void_p
 
 
-def load_native_pool_lib() -> Optional[ctypes.CDLL]:
+def load_native_pool_lib() -> ctypes.CDLL:
     lib = native.load("kv_reuse_pool", ["kv_reuse_pool.cpp"])
-    if lib is None or getattr(lib, "_kvpool_ready", False):
+    if getattr(lib, "_kvpool_ready", False):
         return lib
     lib.kvpool_create.restype = _P
     lib.kvpool_create.argtypes = [_I64]
@@ -75,8 +75,6 @@ class NativeKvBlockPool:
                  on_removed: Optional[Callable] = None,
                  lib: Optional[ctypes.CDLL] = None):
         self._lib = lib or load_native_pool_lib()
-        if self._lib is None:
-            raise RuntimeError("native kv pool unavailable")
         self.num_blocks = num_blocks
         self._h = self._lib.kvpool_create(num_blocks)
         self.on_stored = on_stored

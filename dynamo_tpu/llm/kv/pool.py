@@ -501,16 +501,14 @@ class KvBlockPool:
 
 def make_kv_block_pool(num_blocks: int, on_stored=None, on_removed=None,
                        prefer_native: bool = True):
-    """Pool factory: the C++ pool (csrc/kv_reuse_pool.cpp) when the
-    toolchain is available and DYN_NATIVE_KVPOOL != 0, else the Python
-    implementation above. Both expose the identical interface."""
+    """Pool factory: the C++ pool (csrc/kv_reuse_pool.cpp) unless the
+    caller (``prefer_native=False``) or DYN_NATIVE_KVPOOL=0 asks for the
+    Python implementation above by name. Both expose the identical
+    interface; a failed native build raises (utils/native.py)."""
     if prefer_native and os.environ.get("DYN_NATIVE_KVPOOL", "1") != "0":
-        try:
-            from .native_pool import NativeKvBlockPool
-            return NativeKvBlockPool(num_blocks, on_stored=on_stored,
-                                     on_removed=on_removed)
-        except Exception as e:  # noqa: BLE001 — no toolchain / build failure
-            logger.info("native kv pool unavailable (%s); using Python", e)
+        from .native_pool import NativeKvBlockPool
+        return NativeKvBlockPool(num_blocks, on_stored=on_stored,
+                                 on_removed=on_removed)
     return KvBlockPool(num_blocks, on_stored=on_stored,
                        on_removed=on_removed)
 

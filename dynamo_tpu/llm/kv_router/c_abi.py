@@ -24,10 +24,8 @@ from .protocols import KvRemovedEvent, KvStoredEvent, RouterEvent
 DYN_OK = 0
 
 
-def load_abi() -> Optional[ctypes.CDLL]:
+def load_abi() -> ctypes.CDLL:
     lib = native.load("dynkvabi", ["kv_event_abi.cpp"])
-    if lib is None:
-        return None
     lib.dynamo_llm_init.restype = ctypes.c_int64
     lib.dynamo_llm_init.argtypes = [ctypes.c_char_p, ctypes.c_char_p,
                                     ctypes.c_int64, ctypes.c_uint32]
@@ -68,9 +66,6 @@ class CtypesKvEventPublisher:
     def __init__(self, namespace: str, component: str, worker_id: int,
                  kv_block_size: int):
         self.lib = load_abi()
-        if self.lib is None:
-            raise RuntimeError("native kv_event_abi unavailable "
-                               "(no C++ toolchain?)")
         rc = self.lib.dynamo_llm_init(namespace.encode(), component.encode(),
                                       worker_id, kv_block_size)
         if rc != DYN_OK:

@@ -4,7 +4,8 @@ which KV blocks.
 Reference: lib/llm/src/kv_router/indexer.rs:139-790 (`RadixTree`,
 `KvIndexer::new` single-writer event task, `compute_block_hash_for_seq`,
 `KvIndexerSharded`). The tree itself is native C++ (csrc/kv_radix_index.cpp)
-behind ctypes, with a pure-Python fallback; both sit behind the same
+behind ctypes, with a pure-Python twin chosen by name
+(``prefer_native=False``); both sit behind the same
 single-writer asyncio task so event application is serialized exactly like
 the reference's mpsc actor.
 """
@@ -81,8 +82,6 @@ class RadixIndexNative:
 
     def __init__(self, expiration_s: Optional[float] = None):
         lib = native.load("dynkv", ["kv_radix_index.cpp"])
-        if lib is None:
-            raise RuntimeError("native radix index unavailable")
         self._lib = lib
         # normalize: <=0 means off, matching the C++ gate (expiration > 0)
         if expiration_s is not None and expiration_s <= 0:
@@ -303,10 +302,7 @@ class RadixIndexPython:
 def make_radix_index(prefer_native: bool = True,
                      expiration_s: Optional[float] = None):
     if prefer_native:
-        try:
-            return RadixIndexNative(expiration_s)
-        except RuntimeError:
-            pass
+        return RadixIndexNative(expiration_s)
     return RadixIndexPython(expiration_s)
 
 

@@ -18,11 +18,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import os
-
-try:
-    import tomllib
-except ModuleNotFoundError:          # Python < 3.11
-    import tomli as tomllib
+import tomllib
 from typing import Any, Optional
 
 logger = logging.getLogger("dynamo_tpu.runtime.config")

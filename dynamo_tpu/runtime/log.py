@@ -46,10 +46,7 @@ class JsonlFormatter(logging.Formatter):
         return json.dumps(out, ensure_ascii=False)
 
 
-# logging.getLevelNamesMapping is 3.11+; build the same name→level map
-_LEVEL_NAMES = {name: lvl for lvl, name in logging._levelToName.items()}
-_LEVEL_NAMES["WARN"] = logging.WARNING
-_LEVEL_NAMES["FATAL"] = logging.CRITICAL
+_LEVEL_NAMES = logging.getLevelNamesMapping()
 
 
 def _parse_dyn_log(spec: str) -> tuple:

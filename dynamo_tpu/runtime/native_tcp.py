@@ -30,9 +30,9 @@ _HIGH_WATER = 8 * 1024 * 1024     # backpressure threshold (queued bytes)
 _POLL_S = 0.02                    # control-flag poll cadence
 
 
-def load_data_plane_lib() -> Optional[ctypes.CDLL]:
+def load_data_plane_lib() -> ctypes.CDLL:
     lib = native.load("data_plane", ["data_plane.cpp"], ["-pthread"])
-    if lib is None or getattr(lib, "_dp_ready", False):
+    if getattr(lib, "_dp_ready", False):
         return lib
     u8p = ctypes.POINTER(ctypes.c_uint8)
     lib.dp_connect.restype = ctypes.c_int
@@ -79,8 +79,6 @@ class NativeStreamSender:
         # first call may g++-compile the data plane — off the loop
         # (memoized afterwards; tcp.open_stream_sender does the same)
         lib = await asyncio.to_thread(load_data_plane_lib)
-        if lib is None:
-            raise RuntimeError("native data plane unavailable")
         host, port = info.address.rsplit(":", 1)
         loop = asyncio.get_running_loop()
         fd = await loop.run_in_executor(

@@ -32,17 +32,14 @@ __all__ = ["TcpStreamServer", "StreamReceiver", "StreamSender",
 async def open_stream_sender(info: "ConnectionInfo",
                              error: Optional[str] = None,
                              timeout: float = 10.0):
-    """Sender factory: the C++ data-plane sender (csrc/data_plane.cpp) when
-    the toolchain is available and DYN_NATIVE_DATAPLANE != 0, else the
-    asyncio StreamSender below. Only lib-unavailability falls back — real
-    connection failures propagate identically for both paths."""
+    """Sender factory: the C++ data-plane sender (csrc/data_plane.cpp)
+    unless DYN_NATIVE_DATAPLANE=0 asks for the asyncio StreamSender below
+    by name. A failed native build raises (utils/native.py); connection
+    failures propagate identically for both paths."""
     if os.environ.get("DYN_NATIVE_DATAPLANE", "1") != "0":
-        from .native_tcp import NativeStreamSender, load_data_plane_lib
-        # first use may g++-compile csrc/data_plane.cpp — off the loop
-        # (memoized, so the hop is a dict hit afterwards)
-        if await asyncio.to_thread(load_data_plane_lib) is not None:
-            return await NativeStreamSender.connect(info, error=error,
-                                                    timeout=timeout)
+        from .native_tcp import NativeStreamSender
+        return await NativeStreamSender.connect(info, error=error,
+                                                timeout=timeout)
     return await StreamSender.connect(info, error=error, timeout=timeout)
 
 

@@ -10,7 +10,9 @@ deployments get the same accounting with no env effect."""
 from __future__ import annotations
 
 import dataclasses
+import glob
 import logging
+import os
 from typing import Dict, List, Optional
 
 logger = logging.getLogger("dynamo_tpu.sdk.allocator")
@@ -18,17 +20,18 @@ logger = logging.getLogger("dynamo_tpu.sdk.allocator")
 __all__ = ["TpuAllocator"]
 
 
-def _detect_chip_count(default: int = 4) -> int:
-    """Chips on this host. v5e/v6e TPU-VM hosts expose 1/4/8 chips; fall
-    back to the JAX device count when available, else `default`."""
-    try:
-        import jax
-        devs = [d for d in jax.devices() if d.platform == "tpu"]
-        if devs:
-            return len(devs)
-    except Exception:  # noqa: BLE001 — no jax / no TPU: accounting only
-        pass
-    return default
+def _detect_chip_count() -> int:
+    """Chips on this host, counted from the kernel's device nodes and
+    nothing else: the supervisor must never initialise a JAX backend —
+    a process that has loaded libtpu holds the chips, and the workers it
+    spawns next could not open them. TPU-VM hosts expose one node per
+    chip, ``/dev/accel<N>`` or ``/dev/vfio/<N>`` by generation. A host
+    with neither has no chips (0): a service that asks for one then
+    fails in ``allocate`` unless ``--total-chips`` says otherwise."""
+    nodes = glob.glob("/dev/accel[0-9]*")
+    nodes += [p for p in glob.glob("/dev/vfio/[0-9]*")
+              if os.path.basename(p).isdigit()]
+    return len(nodes)
 
 
 @dataclasses.dataclass

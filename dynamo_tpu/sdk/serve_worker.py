@@ -137,6 +137,10 @@ async def amain(argv=None) -> None:
 
 
 def main() -> None:
+    # workers build their EngineCore under this entry (examples/llm
+    # components/worker.py, prefill_worker.py)
+    from ..utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     try:
         asyncio.run(amain())
     except KeyboardInterrupt:

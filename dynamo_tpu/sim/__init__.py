@@ -5,8 +5,8 @@ plane — the SLA planner (components/planner.py), the KV router
 (kv_router/{indexer,scheduler,scoring}.py), the disagg-threshold retune,
 and the fabric admission gate (llm/kv/fabric.py) — against hundreds of
 simulated replicas whose prefill/decode/KV-transfer timing comes from
-the measured device models already in-repo (parallel/ici_model.py,
-BENCH_LOCAL.jsonl step-time fits, the fabric PeerLinkTable cost model).
+the device models already in-repo (parallel/ici_model.py, the
+sim/models.py step-time line, the fabric PeerLinkTable cost model).
 
 The whole fleet runs on a VIRTUAL clock (sim/clock.py): a simulated hour
 of bursty trace-driven traffic over 200+ replicas completes in seconds

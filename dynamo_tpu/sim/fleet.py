@@ -193,7 +193,7 @@ class SimFleet:
         self.cfg = cfg
         self.seed = seed
         self.rng = random.Random(seed ^ 0x51AFEED)
-        self.perf = cfg.perf or WorkerPerfModel.from_bench()
+        self.perf = cfg.perf or WorkerPerfModel()
         self.clock = None              # bound at start() from the loop
         self.log: Optional[EventLog] = None
         self.runtime: Optional[DistributedRuntime] = None

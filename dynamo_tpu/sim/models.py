@@ -1,9 +1,7 @@
-"""Measured device models driving simulated worker timing.
+"""Device models driving simulated worker timing.
 
-Nothing here invents a cost model: decode step times come from the
-BENCH_LOCAL.jsonl device-truth fits (the bench's measured
-``device_step_ms`` per batch size, least-squares over the batch sweep),
-TP collective overhead from :mod:`dynamo_tpu.parallel.ici_model`
+Decode step times are a fixed base + per-sequence line (the constants
+below), TP collective overhead from :mod:`dynamo_tpu.parallel.ici_model`
 (``tp_decode_step_s``), pp boundary cost from ``pp_boundary_s``, and KV
 transfer time from the SAME ``LinkStats``/``AdmissionGate`` classes the
 live fabric uses (llm/kv/fabric.py) — the simulator prices a fetch with
@@ -13,73 +11,14 @@ the exact arithmetic the production gate runs.
 from __future__ import annotations
 
 import dataclasses
-import json
-import os
-import re
-from typing import Dict, List, Optional, Tuple
 
 from ..parallel import ici_model
 
-__all__ = ["WorkerPerfModel", "fit_step_times", "load_bench_step_points"]
+__all__ = ["WorkerPerfModel"]
 
-_BENCH_LOCAL = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__)))), "BENCH_LOCAL.jsonl")
-
-_METRIC_RE = re.compile(r"decode_tok_per_s_chip_(\w+?)_b(\d+)_")
-
-
-def load_bench_step_points(path: Optional[str] = None,
-                           family: str = "llama8b"
-                           ) -> List[Tuple[int, float]]:
-    """(batch, device_step_s) points for one model family out of the
-    bench ledger. Silent empty list when the ledger is absent/foreign —
-    callers fall back to the default constants."""
-    path = path or _BENCH_LOCAL
-    points: Dict[int, float] = {}
-    try:
-        with open(path) as f:
-            for line in f:
-                try:
-                    rec = json.loads(line)
-                except ValueError:
-                    continue
-                res = rec.get("result", {})
-                m = _METRIC_RE.match(res.get("metric", ""))
-                if m is None or m.group(1) != family:
-                    continue
-                step_ms = res.get("extra", {}).get("device_step_ms")
-                if step_ms:
-                    # newest entry wins per batch size (ledger is
-                    # append-only)
-                    points[int(m.group(2))] = float(step_ms) / 1e3
-    except OSError:
-        return []
-    return sorted(points.items())
-
-
-def fit_step_times(points: List[Tuple[int, float]]
-                   ) -> Optional[Tuple[float, float]]:
-    """Least-squares (base_s, per_seq_s) fit of step time vs batch size —
-    the continuous-batching cost curve. None when under-determined."""
-    if len(points) < 2:
-        return None
-    n = len(points)
-    sx = sum(b for b, _ in points)
-    sy = sum(s for _, s in points)
-    sxx = sum(b * b for b, _ in points)
-    sxy = sum(b * s for b, s in points)
-    denom = n * sxx - sx * sx
-    if denom == 0:
-        return None
-    slope = (n * sxy - sx * sy) / denom
-    base = (sy - slope * sx) / n
-    if base <= 0 or slope <= 0:
-        return None
-    return base, slope
-
-
-# Defaults measured on the v5e chip (BENCH_LOCAL.jsonl llama8b sweep:
-# b32 17.7ms → b128 32.8ms device step) — used when the ledger is absent.
+# Decode step-time line for a Llama-8B-class replica (17.6 ms at batch 32,
+# 32.7 ms at batch 128). Constants of the simulator, read from no record
+# file; not measured on this round's chip (PERF.md).
 _DEFAULT_BASE_S = 0.0126
 _DEFAULT_SLOPE_S = 0.000157
 
@@ -102,15 +41,6 @@ class WorkerPerfModel:
     hidden: int = 4096
     num_layers: int = 32
     kv_bytes_per_block: int = 1 << 20
-
-    @classmethod
-    def from_bench(cls, family: str = "llama8b",
-                   **overrides) -> "WorkerPerfModel":
-        fit = fit_step_times(load_bench_step_points(family=family))
-        if fit is not None:
-            overrides.setdefault("step_base_s", fit[0])
-            overrides.setdefault("step_per_seq_s", fit[1])
-        return cls(**overrides)
 
     def step_time_s(self, batch: int) -> float:
         b = max(int(batch), 1)

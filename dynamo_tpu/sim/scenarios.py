@@ -28,7 +28,7 @@ __all__ = ["SCENARIOS", "Scenario", "run_scenario", "check_report"]
 # sim/models.py pulls the llama8b device-step fit when the bench ledger
 # is present):
 def _perf_small() -> WorkerPerfModel:
-    return WorkerPerfModel.from_bench(prefill_tok_per_s=3000.0,
+    return WorkerPerfModel(prefill_tok_per_s=3000.0,
                                       step_base_s=0.03,
                                       step_per_seq_s=0.005)
 

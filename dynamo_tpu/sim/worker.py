@@ -2,10 +2,10 @@
 
 The engine model is deliberately coarse — the control plane under test
 (planner, router, disagg retune) consumes QUEUE/SLOT/KV/LATENCY signals,
-not kernel microstructure — but every timing input is measured:
+not kernel microstructure:
 
-- prefill runs serially at the perf model's measured token rate
-  (sim/models.py, BENCH_LOCAL.jsonl fits), scaled by the behavior
+- prefill runs serially at the perf model's token rate
+  (sim/models.py), scaled by the behavior
   profile's slow-start/latency factors;
 - decode is continuous batching as processor sharing: all active
   sequences advance one token per step, and the step time grows with

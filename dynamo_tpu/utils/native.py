@@ -1,10 +1,12 @@
 """Native (C++) component loader: builds csrc/ into shared libs on first use
-and memoizes. Keeps the framework importable on machines without a toolchain
-(callers fall back to pure-Python implementations when load fails)."""
+and memoizes. A failed build raises: the pure-Python counterparts are
+selected by name (``prefer_native=False``, ``DYN_NATIVE_*=0``), never as a
+silent fallback."""
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -21,15 +23,19 @@ _LOCK = threading.Lock()
 _CACHE: dict = {}
 
 
+class NativeLibError(RuntimeError):
+    """The C++ toolchain is missing or a csrc/ build failed."""
+
+
 # sanitizer build mode (csrc differential-fuzz hardening): the env knob
 # DYN_NATIVE_SANITIZE selects instrumented builds — "asan", "ubsan", or
 # "asan,ubsan". Sanitized objects land next to the normal ones under a
-# distinct name (lib<name>.asan.so) so the two build flavors never
-# clobber each other's mtime caching. NOTE: dlopen'ing an ASan build
+# distinct name (lib<name>.asan.<digest>.so) so the two build flavors
+# never clobber each other. NOTE: dlopen'ing an ASan build
 # into a non-ASan python requires LD_PRELOAD of libasan — the sanitized
 # smoke test (tests/test_native_sanitize.py) runs its fuzz round in a
 # subprocess with the preload set; in-process load() of an asan build
-# without the preload fails and falls back to Python cleanly.
+# without the preload raises NativeLibError.
 _SAN_FLAGS = {
     "asan": ["-fsanitize=address", "-fno-omit-frame-pointer", "-g", "-O1"],
     "ubsan": ["-fsanitize=undefined", "-fno-sanitize-recover=undefined",
@@ -53,21 +59,48 @@ def sanitize_mode() -> Optional[str]:
     return ",".join(modes)
 
 
+def _digest(srcs: list, flags: list) -> str:
+    """Content digest of everything that decides the binary: the source
+    files' bytes and the full flag list."""
+    h = hashlib.sha256()
+    for path in srcs:
+        h.update(os.path.basename(path).encode() + b"\0")
+        with open(path, "rb") as f:
+            h.update(f.read())
+        h.update(b"\0")
+    h.update("\0".join(flags).encode())
+    return h.hexdigest()[:16]
+
+
 def _build(name: str, sources: list, extra_flags: Optional[list] = None,
            sanitize: Optional[str] = None) -> str:
+    """Path of lib<name>[.<sanitize>].<digest>.so under csrc/build/,
+    compiled first if no file of that name exists. The digest covers the
+    sources (csrc/ files git tracks) and the flags, so a library built
+    from another tree's sources — csrc/build/ is git-ignored and travels
+    with a copied directory — never matches and is never loaded."""
     os.makedirs(_BUILD_DIR, exist_ok=True)
     tag = "" if not sanitize else "." + sanitize.replace(",", "-")
-    out = os.path.join(_BUILD_DIR, f"lib{name}{tag}.so")
     srcs = [os.path.join(_CSRC, s) for s in sources]
-    newest_src = max(os.path.getmtime(s) for s in srcs)
-    if os.path.exists(out) and os.path.getmtime(out) >= newest_src:
-        return out
     san_flags = [f for m in (sanitize.split(",") if sanitize else [])
                  for f in _SAN_FLAGS[m]]
-    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", out,
-           *srcs, *san_flags, *(extra_flags or [])]
+    flags = ["-O3", "-std=c++17", "-shared", "-fPIC",
+             *san_flags, *(extra_flags or [])]
+    out = os.path.join(_BUILD_DIR,
+                       f"lib{name}{tag}.{_digest(srcs, flags)}.so")
+    if os.path.exists(out):
+        return out
+    # build beside the target and rename: a concurrent builder (xdist
+    # workers, sibling serve workers) never dlopens a half-written file
+    tmp = f"{out}.{os.getpid()}.tmp"
+    cmd = ["g++", *flags, "-o", tmp, *srcs]
     logger.info("building native lib: %s", " ".join(cmd))
-    subprocess.run(cmd, check=True, capture_output=True, text=True)
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, text=True)
+        os.replace(tmp, out)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
     return out
 
 
@@ -76,7 +109,8 @@ def build(name: str, sources: list,
           sanitize: Optional[str] = None) -> Optional[str]:
     """Build without dlopen'ing (the sanitized-fuzz harness builds in the
     parent and loads in an LD_PRELOADed subprocess). Returns the .so path
-    or None when the toolchain is missing/fails."""
+    or None when the toolchain is missing/fails — the harness skips on
+    None; nothing on the serving path calls this."""
     try:
         return _build(name, sources, extra_flags, sanitize=sanitize)
     except (subprocess.CalledProcessError, OSError) as e:
@@ -87,10 +121,11 @@ def build(name: str, sources: list,
 
 
 def load(name: str, sources: list,
-         extra_flags: Optional[list] = None) -> Optional[ctypes.CDLL]:
-    """Build (if stale) and dlopen csrc/<sources> as lib<name>.so —
-    instrumented per DYN_NATIVE_SANITIZE when set. Returns None when the
-    toolchain or build fails."""
+         extra_flags: Optional[list] = None) -> ctypes.CDLL:
+    """Build (unless a library with this source+flag digest exists) and
+    dlopen csrc/<sources> as lib<name>.<digest>.so — instrumented per
+    DYN_NATIVE_SANITIZE when set. Raises NativeLibError when the
+    toolchain or the build fails."""
     sanitize = sanitize_mode()
     key = (name, sanitize)
     with _LOCK:
@@ -101,8 +136,8 @@ def load(name: str, sources: list,
             lib = ctypes.CDLL(path)
         except (subprocess.CalledProcessError, OSError) as e:
             detail = getattr(e, "stderr", "") or str(e)
-            logger.warning("native lib %s unavailable (%s); using Python "
-                           "fallback", name, detail.strip()[:500])
-            lib = None
+            raise NativeLibError(
+                f"native lib {name} unavailable: "
+                f"{detail.strip()[:500]}") from e
         _CACHE[key] = lib
         return lib

@@ -1,0 +1,222 @@
+"""Deviceless compiles of the main path's Pallas kernels for a described
+TPU v5e (``v5e:2x2``, one device) at published widths.
+
+Interpret mode cannot see what the chip's compiler refuses — a slice off
+the tiling, a kernel over its VMEM budget — so each kernel is lowered and
+compiled here for the real target with no chip attached. Nothing runs:
+these say a kernel BUILDS, never what it computes or how fast.
+
+Everything that touches the TPU compiler lives in this ONE file, behind a
+module-scoped fixture: only the xdist worker that is handed this file
+loads libtpu, and every worker collects the same tests.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from dynamo_tpu.engine import attention as A
+from dynamo_tpu.engine.config import bench_model_config
+
+CFG_1B = bench_model_config("1b")     # Llama-3.2-1B published widths
+CFG_8B = bench_model_config("8b")     # Llama-3-8B published widths
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler: skip, loudly
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """Sharding on the first described device, with the persistent
+    compile cache off around every compile of this module: an entry
+    written for a described device cannot be read back without a chip,
+    and the next compile would warn."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    """Compile fn for the described chip; the kernel must be in the
+    program (a Pallas call lowers to a ``tpu_custom_call``)."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _pool(cfg, block_size, num_blocks, kv_int8):
+    C = cfg.num_kv_heads * cfg.head_dim
+    lanes = C + A.KV_SCALE_LANES if kv_int8 else C
+    return ((num_blocks * block_size, lanes),
+            jnp.int8 if kv_int8 else jnp.bfloat16)
+
+
+def _decode_case(one_chip, cfg, block_size, kv_int8, B=8, max_len=4096,
+                 num_blocks=2048):
+    M = max_len // block_size
+    pool = _pool(cfg, block_size, num_blocks, kv_int8)
+    assert A.pallas_supported(cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+                              block_size, kv_dtype=pool[1])
+
+    def fn(q, k, v, bt, sl):
+        return A.paged_attention_pallas(
+            q, k, v, bt, sl, block_size=block_size,
+            scale=cfg.head_dim ** -0.5)
+
+    _compile(fn, one_chip,
+             ((B, cfg.num_heads, cfg.head_dim), jnp.bfloat16), pool, pool,
+             ((B, M), jnp.int32), ((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("cfg,block_size,kv_int8", [
+    (CFG_1B, 16, False), (CFG_1B, 32, True), (CFG_8B, 16, False),
+], ids=["1b-bf16", "1b-int8kv", "8b-bf16"])
+def test_paged_decode_kernel_compiles(one_chip, cfg, block_size, kv_int8):
+    _decode_case(one_chip, cfg, block_size, kv_int8)
+
+
+@pytest.mark.parametrize("cfg", [CFG_1B, CFG_8B],
+                         ids=["Dh64-1b", "Dh128-8b"])
+def test_flash_prefill_kernel_compiles(one_chip, cfg):
+    T = 1024
+    assert A.flash_prefill_supported(cfg.num_heads, cfg.num_kv_heads,
+                                     cfg.head_dim)
+
+    def fn(q, k, v, start, n):
+        return A.flash_prefill(q, k, v, scale=cfg.head_dim ** -0.5,
+                               start_pos=start, seq_len=n)
+
+    _compile(fn, one_chip,
+             ((T, cfg.num_heads, cfg.head_dim), jnp.bfloat16),
+             ((T, cfg.num_kv_heads, cfg.head_dim), jnp.bfloat16),
+             ((T, cfg.num_kv_heads, cfg.head_dim), jnp.bfloat16),
+             ((), jnp.int32), ((), jnp.int32))
+
+
+def _ragged_case(one_chip, cfg, block_size, kv_int8, max_rows, S=8,
+                 max_len=4096, num_blocks=2048):
+    M = max_len // block_size
+    TT = S + 2 * max_rows               # EngineConfig's auto capacity
+    pool = _pool(cfg, block_size, num_blocks, kv_int8)
+
+    def fn(q, k, v, bt, starts, counts, lens):
+        return A.ragged_paged_attention_pallas(
+            q, k, v, bt, starts, counts, lens, block_size=block_size,
+            scale=cfg.head_dim ** -0.5, max_rows=max_rows)
+
+    _compile(fn, one_chip,
+             ((TT, cfg.num_heads, cfg.head_dim), jnp.bfloat16), pool, pool,
+             ((S, M), jnp.int32), ((S,), jnp.int32), ((S,), jnp.int32),
+             ((S,), jnp.int32))
+
+
+def _ragged_boundary(cfg, block_size, kv_dtype) -> int:
+    """Largest per-sequence row budget ragged_supported accepts."""
+    rows = 0
+    while A.ragged_supported(cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+                             block_size, rows + 1, kv_dtype=kv_dtype):
+        rows += 1
+        assert rows < 4096, "ragged_supported never refuses"
+    return rows
+
+
+def test_ragged_kernel_compiles_at_a_supported_budget(one_chip):
+    assert A.ragged_supported(CFG_1B.num_heads, CFG_1B.num_kv_heads,
+                              CFG_1B.head_dim, 16, 32,
+                              kv_dtype=jnp.bfloat16)
+    _ragged_case(one_chip, CFG_1B, 16, False, max_rows=32)
+
+
+def test_ragged_supported_geometries_all_compile_at_1b_width(one_chip):
+    """ragged_supported is the gate ``attn_impl="auto"`` trusts: every
+    row budget it accepts at 1B width, up to its boundary, must build —
+    and the default budget (64) sits beyond the boundary, which is why
+    --ragged resolves to the XLA path at this width (ROADMAP open
+    item)."""
+    for block_size, kv_int8 in ((16, False), (32, True)):
+        dt = jnp.int8 if kv_int8 else jnp.bfloat16
+        top = _ragged_boundary(CFG_1B, block_size, dt)
+        assert 8 <= top < 64, top
+        for rows in sorted({8, 16, 24, 32, top}):
+            if rows <= top:
+                _ragged_case(one_chip, CFG_1B, block_size, kv_int8, rows)
+
+
+def test_int8_lm_head_kernel_compiles_at_llama_vocab(one_chip):
+    from dynamo_tpu.engine.lm_head import lm_head_int8
+    D, V = CFG_1B.hidden_size, CFG_1B.vocab_size
+    assert V == 128256
+    _compile(lm_head_int8, one_chip, ((8, D), jnp.bfloat16),
+             ((D, V), jnp.int8), ((1, V), jnp.float32))
+
+
+def test_grouped_int4_matmul_compiles_at_8b_ffn(one_chip):
+    from dynamo_tpu.engine.quant_matmul import (GROUP, grouped_int4_matmul,
+                                                grouped_kernel_eligible)
+    D, F = CFG_8B.hidden_size, CFG_8B.intermediate_size
+    assert (D, F) == (4096, 14336)
+    assert grouped_kernel_eligible(8, D, F, GROUP)
+    _compile(grouped_int4_matmul, one_chip, ((8, D), jnp.bfloat16),
+             ((D // 2, F), jnp.int8), ((D // GROUP, F), jnp.float32))
+
+
+def test_decode_kernel_compiles_per_tp_shard_on_four_chips(topo, one_chip):
+    """The compiler refuses to partition a Mosaic kernel, so under a tp
+    mesh the engine runs it per shard (llama._per_tp_shard): at 1B
+    widths over the four described chips that program must build, with
+    the kernel in it."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from dynamo_tpu.engine.models import llama
+    from dynamo_tpu.parallel.sharding import AXES
+    cfg = CFG_1B
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4, 1, 1), AXES)
+    statics = llama.ModelStatics(cfg=cfg, block_size=16,
+                                 attn_impl="pallas", mesh=mesh)
+    assert statics.tp == 4
+    B, M = 8, 256
+    pool, dt = _pool(cfg, 16, 2048, False)
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def fn(q, k, v, bt, sl):
+        return llama._paged_attention(statics, q, k, v, bt, sl, None,
+                                      cfg.head_dim ** -0.5)
+
+    compiled = jax.jit(fn).lower(
+        sds((B, cfg.num_heads, cfg.head_dim), jnp.bfloat16,
+            P(None, "tp", None)),
+        sds(pool, dt, P(None, "tp")), sds(pool, dt, P(None, "tp")),
+        sds((B, M), jnp.int32, P()), sds((B,), jnp.int32, P())).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    with pytest.raises(NotImplementedError, match="partition"):
+        # what the engine did before: the kernel handed to GSPMD whole
+        whole = llama.ModelStatics(cfg=cfg, block_size=16,
+                                   attn_impl="pallas")
+        jax.jit(lambda q, k, v, bt, sl: llama._paged_attention(
+            whole, q, k, v, bt, sl, None, cfg.head_dim ** -0.5)).lower(
+                sds((B, cfg.num_heads, cfg.head_dim), jnp.bfloat16,
+                    P(None, "tp", None)),
+                sds(pool, dt, P(None, "tp")), sds(pool, dt, P(None, "tp")),
+                sds((B, M), jnp.int32, P()),
+                sds((B,), jnp.int32, P())).compile()

@@ -166,6 +166,8 @@ async def run(isls, model, plane):
 
 
 def main():
+    from dynamo_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     isls = [int(a) for a in sys.argv[1:]] or [512, 1024, 2048, 3072]
     model = os.environ.get("DISAGG_MODEL", "1b")
     plane = os.environ.get("DISAGG_PLANE", "device")

@@ -121,6 +121,8 @@ async def run_config(users, turns, turn_tokens, gen, mcfg, host_blocks):
 
 
 def main():
+    from dynamo_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     users = int(sys.argv[1]) if len(sys.argv) > 1 else 6
     turns = int(sys.argv[2]) if len(sys.argv) > 2 else 4
     turn_tokens = int(os.environ.get("MT_TURN_TOKENS", "128"))
