@@ -28,6 +28,7 @@ older harvest-then-dispatch measurement mode.
 
 import json
 import os
+import statistics
 import sys
 import time
 
@@ -792,25 +793,32 @@ def run_kv_remote_bench(mcfg) -> dict:
             # REPEAT_FETCHES batches several fetches per sample so the
             # systematic JSON overhead (base64 both ways + JSON parse of
             # the bulk payload + 33% more wire bytes) dominates loopback
-            # jitter; min-of-samples is the standard noise floor.
-            REPEAT_FETCHES, SAMPLES = 5, 3
+            # jitter. The legs' samples alternate, so a burst of host
+            # load lands on both, and each leg reports its median: with
+            # 8 benches at once on 8 cores the medians kept their order
+            # in 24 of 24 runs, where min-of-16 flipped in 2 and
+            # back-to-back min-of-3 legs in about 1 of 3 even unloaded.
+            REPEAT_FETCHES, SAMPLES = 5, 16
 
-            async def time_leg(fetch):
-                walls, nbytes = [], 0
-                for _ in range(SAMPLES):
-                    t0 = time.monotonic()
-                    for _ in range(REPEAT_FETCHES):
-                        blobs = await fetch(wid_a, hashes)
-                        if blobs is None:
-                            raise RuntimeError(
-                                "native dataplane unavailable for the "
-                                "kv-remote A/B leg (toolchain missing?)")
-                    walls.append(time.monotonic() - t0)
-                    nbytes = sum(len(b) for b in blobs)
-                return min(walls) * 1e3, nbytes
+            async def time_sample(fetch):
+                t0 = time.monotonic()
+                for _ in range(REPEAT_FETCHES):
+                    blobs = await fetch(wid_a, hashes)
+                    if blobs is None:
+                        raise RuntimeError(
+                            "native dataplane unavailable for the "
+                            "kv-remote A/B leg (toolchain missing?)")
+                return time.monotonic() - t0, sum(len(b) for b in blobs)
 
-            dp_ms, dp_bytes = await time_leg(fab_c._fetch_blobs_native)
-            js_ms, js_bytes = await time_leg(fab_c._fetch_blobs_json)
+            dp_walls, js_walls = [], []
+            for _ in range(SAMPLES):
+                wall, dp_bytes = await time_sample(
+                    fab_c._fetch_blobs_native)
+                dp_walls.append(wall)
+                wall, js_bytes = await time_sample(fab_c._fetch_blobs_json)
+                js_walls.append(wall)
+            dp_ms = statistics.median(dp_walls) * 1e3
+            js_ms = statistics.median(js_walls) * 1e3
             predicted_fetch_s = gate.modeled_fetch_s(max(n_fetched, 1),
                                                      link)
             predicted_rec_s = gate.modeled_recompute_s(max(n_fetched, 1))

@@ -72,8 +72,12 @@ PALLAS_IMPL = "pallas"    # the CPU rehearsal says "pallas_interpret"
 KERNEL_ATOL = 3e-2
 # tp=4 changes every matmul's reduction order (psum of 4 partials): the
 # first-step logits of the 16-layer bf16 model differed by 0.085 of their
-# own spread on the chip (max abs 0.0107 over a std of 0.126, PR 25); a
-# missing or doubled reduction moves them by about one spread
+# own spread on the chip (max abs 0.0107 over a std of 0.126, PR 25). A
+# wrong reduction lands far outside: with the attention-output reduction
+# doubled or left out, the same logits (same seed, same widths, computed
+# on the CPU) move by 2.8 and 6.4 spreads, and by 0.87 when only the
+# last of the 16 layers is broken; tests/test_bring_up.py trips this
+# check both ways on four virtual devices
 TP_LOGITS_RTOL = 2e-1
 
 
@@ -424,6 +428,17 @@ def kernels_in(text: str) -> list:
         text)
 
 
+def impls_in(kernels: list) -> str:
+    """The impl a program resolved to, read from the kernels its compiled
+    text holds and from nothing the smoke works out for itself: the fused
+    LM head lowers under its own jit name; any other Pallas call in the
+    engine's step programs is the attention kernel."""
+    head = any("lm_head_int8" in k for k in kernels)
+    attn = any("lm_head_int8" not in k for k in kernels)
+    return (f"attn={'pallas' if attn else 'xla'} "
+            f"lm_head={'pallas-int8' if head else 'xla'}")
+
+
 def all_reduces_in(text: str) -> int:
     return text.count("all-reduce(") + text.count("all-reduce-start(")
 
@@ -437,6 +452,7 @@ def check_programs(srv: Server, expect: dict) -> dict:
         for text in texts.get(name, ()):
             kernels = kernels_in(text)
             note(f"program[{name}]", f"calls={rec.calls} "
+                 f"impl: {impls_in(kernels)} "
                  f"tpu_custom_call={len(kernels)} all-reduce="
                  f"{all_reduces_in(text)} kernels={sorted(set(kernels))}")
             check(bool(kernels), f"{name} contains tpu_custom_call")
@@ -449,20 +465,11 @@ def check_programs(srv: Server, expect: dict) -> dict:
     return texts
 
 
-def resolved_impls(core) -> str:
-    from dynamo_tpu.engine import attention as A
-    from dynamo_tpu.engine.models import llama
-    cfg = core.model_cfg
-    decode = ("pallas" if A._on_tpu() and A.pallas_supported(
-        cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-        core.cfg.kv_block_size, kv_dtype=core.kv["k"].dtype) else "xla")
-    prefill = "pallas-flash" if llama._prefill_flash_impl(
-        core.statics) else "xla"
-    head = core.params.get("lm_head")
-    lm_head = ("pallas-int8" if head is not None and hasattr(head, "q")
-               and llama._lm_head_kernel_ok(head, cfg) else "xla")
-    return (f"decode_attn={decode} prefill_attn={prefill} "
-            f"lm_head={lm_head} kv_block={core.cfg.kv_block_size} "
+def engine_settings(core) -> str:
+    """Settings the engine was built with (what each program resolved
+    to is read from its compiled text, in check_programs)."""
+    return (f"attn_impl={core.statics.attn_impl} "
+            f"kv_block={core.cfg.kv_block_size} "
             f"K={core.cfg.decode_steps_per_dispatch}")
 
 
@@ -471,7 +478,7 @@ async def serve_phase(tag: str, model_dir: str, flags: list, full: bool,
     t0 = time.monotonic()
     async with Server(model_dir, flags) as srv:
         note(f"phase[{tag}]", f"flags={flags or '(defaults)'} "
-             f"{resolved_impls(srv.core)}")
+             f"{engine_settings(srv.core)}")
         generated = await drive_requests(srv, full)
         check_programs(srv, expect)
     note(f"phase[{tag}]", f"tokens_generated={generated} "
@@ -533,7 +540,7 @@ async def four_chips(model_dir: str, cache: CacheWatch, devices) -> None:
         async with Server(model_dir, flags) as srv:
             core = srv.core
             note(f"phase[{tag}]", f"flags={flags or '(defaults)'} "
-                 f"{resolved_impls(core)} mesh="
+                 f"{engine_settings(core)} mesh="
                  f"{dict(core.mesh.shape) if core.mesh else None}")
             if tag == "tp4":
                 note("fused_lm_head_under_tp",

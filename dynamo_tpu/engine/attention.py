@@ -1149,7 +1149,7 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
     if impl == "auto":
         KVH = (kv_heads if kv_heads is not None
                else kv_value_lanes(k_cache) // Dh)
-        impl = ("pallas" if _on_tpu() and groups == 1
+        impl = ("pallas" if kernel_wanted(impl) and groups == 1
                 and pallas_supported(H, KVH, Dh, block_size,
                                      kv_dtype=k_cache.dtype) else "xla")
     if groups > 1 and impl in ("pallas", "pallas_interpret"):
@@ -1594,16 +1594,34 @@ def ragged_prefetch_counts(seq_counts, seq_lens, win_base=None, *,
             "hit_ratio": prefetched / max(first_waves, 1)}
 
 
-# VMEM budget for the ragged kernel: the per-sequence windows (q + o +
-# acc + m/l scratch) plus what scales with the KV wave (the
-# double-buffered wave tiles and the score tile), both as
-# ragged_supported counts them. Mosaic's scoped-VMEM limit on v5e is
-# 16 MiB; the wave charge is calibrated, not derived — deviceless v5e
-# compiles at Llama 1B and 8B widths (blocks of 16/32/64 tokens, bf16
-# and int8 pools) build everywhere the sum fits and the sum stops
-# within two rows of where the compiler does
-# (tests/test_tpu_compile.py pins the 1B boundaries).
-_RAGGED_VMEM_BUDGET = 8 << 20
+# What the ragged kernel asks of Mosaic's scoped VMEM, 16 MiB on v5e, in
+# bytes of the buffers it really holds (ragged_supported adds them up):
+#
+#   window   [Lmax*Hp, C]     15 B/element: the q and o windows in bf16
+#                             (2 + 2), the f32 accumulator (4) and the f32
+#                             scaled copy of q the wave loop keeps live (4),
+#                             plus 3 B of compiler staging
+#   rows     [Lmax*Hp, 1]     2048 B/row: m and l are f32 columns, padded to
+#                             a 128-lane tile (512 B each), and the loop
+#                             holds a second copy of each (m_new, alpha)
+#   scores   [Lmax*Hp, wave]  6 B/element: the f32 score/probability tile
+#                             and its mask
+#   KV waves [2, wave, lanes] the K and the V tile, double-buffered, at the
+#                             pool's own lane width and itemsize — exact
+#
+# The explicit scratch is exact; the 3 B of staging and the 6 B per score
+# element are what the compiler's own temporaries came to, calibrated on
+# deviceless v5e compiles of 17 geometries (C 128..1024, Hp 8..64, waves
+# of 256..2048 tokens, bf16 and int8 pools, MLA's v-aliases-k form) and
+# then held against three they had not seen (gemma-2b, gemma2-9b, MLA at
+# 128 heads): at all 20 the sum stops at 0.73..0.98 of the row budget
+# where the compiler stops and never beyond it; the low end is where V
+# aliases K and its wave tile is charged although the kernel has none
+# (tests/test_tpu_compile.py pins both sides at 1B, 8B and MQA widths).
+_RAGGED_VMEM_LIMIT = 16 << 20
+_RAGGED_WINDOW_BYTES = 2 + 2 + 4 + 4 + 3
+_RAGGED_ROW_BYTES = 4 * 512
+_RAGGED_SCORE_BYTES = 6
 
 
 def ragged_supported(num_heads: int, num_kv_heads: int, head_dim: int,
@@ -1612,20 +1630,29 @@ def ragged_supported(num_heads: int, num_kv_heads: int, head_dim: int,
     """True if the ragged Pallas kernel handles this geometry at this
     per-sequence row budget: the decode kernel's lane/sublane
     constraints (pallas_supported) plus its VMEM working set fitting the
-    budget — [Lmax*Hp, C] f32 scores duplicate query rows across
+    scoped limit — [Lmax*Hp, C] f32 scores duplicate query rows across
     sublanes, so large GQA geometries bound Lmax (MQA/MLA pools,
     KVH == 1, carry no duplication and take the deepest windows), and a
-    larger KV block widens every wave, which buys a smaller Lmax."""
+    larger KV block widens every wave, which buys a smaller Lmax. The
+    q window is DMA-sliced per sequence with the head axis second-minor,
+    so the head count must sit on the 8-sublane tiling: Mosaic refuses
+    the slice at 12, 14 or 28 heads (the decode and flash kernels take
+    them)."""
     if not pallas_supported(num_heads, num_kv_heads, head_dim,
                             block_size, kv_dtype=kv_dtype):
         return False
     Hp = max(8, num_heads)
+    if Hp % 8 != 0:
+        return False
+    rows = max(8, max_rows) * Hp                          # Lmax * Hp
     C = num_kv_heads * head_dim
-    Lmax = max(8, max_rows)
     wave = int(os.environ.get("DYN_ATTN_CHUNK_BLOCKS", "16")) * block_size
-    window_bytes = Lmax * Hp * C * (2 + 2 + 4 + 4)   # q + o + acc(+m/l)
-    wave_bytes = 3 * wave * wave
-    return window_bytes + wave_bytes <= _RAGGED_VMEM_BUDGET
+    lanes = C + KV_SCALE_LANES if kv_dtype == jnp.int8 else C
+    itemsize = jnp.dtype(kv_dtype or jnp.bfloat16).itemsize
+    kv_waves = 2 * 2 * wave * lanes * itemsize
+    windows = rows * (_RAGGED_WINDOW_BYTES * C + _RAGGED_ROW_BYTES
+                      + _RAGGED_SCORE_BYTES * wave)
+    return windows + kv_waves <= _RAGGED_VMEM_LIMIT
 
 
 @functools.cache
@@ -1633,3 +1660,12 @@ def _on_tpu() -> bool:
     # a backend that fails to initialise raises here: it must never read
     # as "not a TPU" and turn every attn_impl="auto" site into XLA
     return jax.devices()[0].platform == "tpu"
+
+
+def kernel_wanted(impl: str) -> bool:
+    """Whether ``impl`` asks for a Pallas kernel where the geometry has
+    one: forced by name, or "auto" on a TPU. The one statement of what
+    "auto" means for the paged kernels; the model code asks it too (to
+    know whether a kernel may sit inside what it hands to shard_map)."""
+    return impl in ("pallas", "pallas_interpret") or (
+        impl == "auto" and _on_tpu())

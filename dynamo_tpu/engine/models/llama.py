@@ -31,7 +31,7 @@ from ..attention import causal_attention  # noqa: F401  (used by sp path)
 from ..attention import (KV_SCALE_LANES, RAGGED_WIN_SENTINEL, _on_tpu,
                          dequant_kv_rows, flash_prefill,
                          flash_prefill_supported, flat_token_indices,
-                         kv_row_groups, paged_attention,
+                         kernel_wanted, kv_row_groups, paged_attention,
                          quantize_kv_rows, ragged_paged_attention_pallas,
                          ragged_supported,
                          softcap_scores as _softcap)
@@ -624,13 +624,6 @@ def _attn_scale(cfg: ModelConfig) -> float:
     return (cfg.query_pre_attn_scalar or cfg.head_dim) ** -0.5
 
 
-def _wants_kernel(statics: ModelStatics) -> bool:
-    """Whether attn_impl asks for a Pallas kernel where one applies."""
-    impl = statics.attn_impl
-    return impl in ("pallas", "pallas_interpret") or (
-        impl == "auto" and _on_tpu())
-
-
 def _per_tp_shard(statics: ModelStatics, fn, in_specs, out_specs):
     """fn as the engine must call it when a Pallas kernel may be inside:
     unchanged on one device; under a tp mesh, per shard via shard_map —
@@ -651,7 +644,7 @@ def _paged_attention(statics: ModelStatics, q, k_flat, v_flat, tables,
     one (values, scales) section, so it reads as a single-group pool."""
     cfg = statics.cfg
     impl = statics.attn_impl
-    tp = statics.tp if _wants_kernel(statics) else 1
+    tp = statics.tp if kernel_wanted(impl) else 1
     if cfg.num_kv_heads % tp != 0:
         # a KV head straddles two shards: no per-shard kernel exists
         if impl != "auto":

@@ -77,7 +77,7 @@ class NativeStreamSender:
                       error: Optional[str] = None,
                       timeout: float = 10.0) -> "NativeStreamSender":
         # first call may g++-compile the data plane — off the loop
-        # (memoized afterwards; tcp.open_stream_sender does the same)
+        # (memoized afterwards)
         lib = await asyncio.to_thread(load_data_plane_lib)
         host, port = info.address.rsplit(":", 1)
         loop = asyncio.get_running_loop()

@@ -25,13 +25,19 @@ def _detect_chip_count() -> int:
     nothing else: the supervisor must never initialise a JAX backend —
     a process that has loaded libtpu holds the chips, and the workers it
     spawns next could not open them. TPU-VM hosts expose one node per
-    chip, ``/dev/accel<N>`` or ``/dev/vfio/<N>`` by generation. A host
-    with neither has no chips (0): a service that asks for one then
-    fails in ``allocate`` unless ``--total-chips`` says otherwise."""
-    nodes = glob.glob("/dev/accel[0-9]*")
-    nodes += [p for p in glob.glob("/dev/vfio/[0-9]*")
-              if os.path.basename(p).isdigit()]
-    return len(nodes)
+    chip, ``/dev/accel<N>`` or ``/dev/vfio/<N>`` by generation: the accel
+    nodes are counted where there are any, the vfio groups only where
+    there are none, so a host that shows both kinds for the same chips
+    counts each chip once. Any other device bound to vfio on such a host
+    (a passed-through NIC) would count as a chip; ``--total-chips``
+    overrides. A host with neither has no chips (0): a service that asks
+    for one then fails in ``allocate`` unless ``--total-chips`` says
+    otherwise."""
+    accel = glob.glob("/dev/accel[0-9]*")
+    if accel:
+        return len(accel)
+    return len([p for p in glob.glob("/dev/vfio/[0-9]*")
+                if os.path.basename(p).isdigit()])
 
 
 @dataclasses.dataclass

@@ -108,7 +108,11 @@ def test_bench_kv_remote_mode():
         {"BENCH_FORCE_CPU": "1", "BENCH_MODEL": "tiny", "BENCH_BATCH": "2",
          "BENCH_STEPS": "4", "BENCH_PROMPT": "8", "BENCH_HARVEST": "2",
          "BENCH_QUANT": "none", "BENCH_DEVICE": "0",
-         "BENCH_KV_REMOTE_PROMPT": "32"})
+         # ~1 MB per fetch: JSON's base64 framing costs by the byte, so
+         # the wall gate below has a third of headroom where a 32-token
+         # prompt (267 KB, fixed per-fetch cost) left a tenth, which six
+         # busy xdist workers could overturn
+         "BENCH_KV_REMOTE_PROMPT": "128"})
     assert r.returncode == 0, f"bench.py crashed:\n{r.stderr[-4000:]}"
     out = json.loads([l for l in r.stdout.strip().splitlines()
                       if l.startswith("{")][-1])
@@ -124,13 +128,14 @@ def test_bench_kv_remote_mode():
     assert kr["predicted_fetch_ms"] > 0
     # ISSUE 12 satellite: the dataplane-vs-JSON A/B leg — the native
     # transport moves byte-identical payloads (same count both legs,
-    # JSON's base64 framing inflates its wire bytes). Which leg's wall
-    # is lower is a loopback timing on a shared CPU, not asserted here
-    # (it flipped under six xdist workers: 164.9 vs 58.8 ms, PR 25)
+    # JSON's base64 framing inflates its wire bytes) at a wall no worse
+    # than the base64-over-JSON path it replaced
     assert kr["dataplane_bytes"] == kr["json_bytes"] > 0
     assert kr["dataplane_fetches_total"] >= 1
     assert kr["dataplane_fallbacks_total"] == 0
-    assert kr["dataplane_fetch_ms"] > 0 and kr["json_fetch_ms"] > 0
+    assert kr["dataplane_fetch_ms"] <= kr["json_fetch_ms"], (
+        f"native dataplane fetch slower than the JSON fallback: "
+        f"{kr['dataplane_fetch_ms']}ms vs {kr['json_fetch_ms']}ms")
 
 
 @pytest.mark.kvfabric
@@ -391,22 +396,10 @@ def test_bench_unforced_run_on_cpu_host_exits_nonzero():
 
 
 def _tree_state() -> str:
-    """What git would call the checkout's state; for a checkout without
-    .git, every file outside the git-ignored build/cache directories."""
-    if os.path.isdir(os.path.join(REPO, ".git")):
-        return subprocess.run(
-            ["git", "status", "--porcelain", "--untracked-files=all"],
-            cwd=REPO, capture_output=True, text=True, check=True).stdout
-    ignored = {"__pycache__", ".pytest_cache", "build", ".jax_cache",
-               "chiprun_out"}
-    out = []
-    for root, dirs, files in os.walk(REPO):
-        dirs[:] = [d for d in dirs if d not in ignored]
-        for f in files:
-            path = os.path.join(root, f)
-            out.append(f"{os.path.relpath(path, REPO)} "
-                       f"{os.path.getsize(path)}")
-    return "\n".join(sorted(out))
+    """What git calls the checkout's state, untracked files included."""
+    return subprocess.run(
+        ["git", "status", "--porcelain", "--untracked-files=all"],
+        cwd=REPO, capture_output=True, text=True, check=True).stdout
 
 
 def test_bench_run_leaves_the_checkout_clean():

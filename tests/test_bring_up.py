@@ -55,7 +55,11 @@ def test_allocator_counts_device_nodes_and_never_guesses(monkeypatch):
              "/dev/vfio/[0-9]*": ["/dev/vfio/0", "/dev/vfio/1",
                                   "/dev/vfio/2", "/dev/vfio/3"]}
     monkeypatch.setattr(allocator.glob, "glob", lambda pat: nodes[pat])
-    assert allocator.TpuAllocator().total == 6
+    # both kinds shown: the accel nodes are the chips, counted once
+    assert allocator.TpuAllocator().total == 2
+    nodes["/dev/accel[0-9]*"] = []
+    nodes["/dev/vfio/[0-9]*"].append("/dev/vfio/vfio")   # control node
+    assert allocator.TpuAllocator().total == 4
     monkeypatch.setattr(allocator.glob, "glob", lambda pat: [])
     alloc = allocator.TpuAllocator()
     assert alloc.total == 0          # a host without chips has none, not 4
@@ -305,6 +309,48 @@ def test_chip_smoke_four_chip_rehearsal_on_virtual_devices(tmp_path):
     assert "# phase[tp4]" in r.stdout and "# phase[tp1]" in r.stdout
     assert "# phase[bf16]" not in r.stdout      # no one-chip phase ran
     assert "# tp4_vs_tp1_first_step_logits" in r.stdout
+
+
+# what a broken reduction of the row-parallel attention output leaves in
+# the residual stream, stated on the weights (the matmul is linear in wo):
+# the all-reduce applied to a sum that already holds every shard's partial
+# twice, or not applied at all (only the first shard's rows contribute)
+_BROKEN_REDUCTION = {
+    "doubled": "wo * 2",
+    "missing": "wo.at[:, wo.shape[1] // 4:, :].set(0)",
+}
+
+
+@pytest.mark.parametrize("fault", sorted(_BROKEN_REDUCTION))
+def test_chip_smoke_four_chip_check_trips_on_a_broken_reduction(
+        tmp_path, fault):
+    """TP_LOGITS_RTOL is wide enough for a changed reduction order (0.085
+    of the logits' spread on the chip) only if a wrong reduction still
+    lands far outside it: with the tp=4 engine's wo reduction doubled or
+    missing, the first-step comparison fails and no result is printed."""
+    code = _REHEARSAL.replace(
+        "sys.exit(cs.main(sys.argv[1:]))", f"""
+    first_step_logits = cs._first_step_logits
+
+    def broken(core, prompt_ids):
+        if core.mesh is not None:                  # the tp=4 engine only
+            wo = core.params["layers.wo"]
+            core.params = dict(core.params,
+                               **{{"layers.wo": {_BROKEN_REDUCTION[fault]}}})
+        return first_step_logits(core, prompt_ids)
+    cs._first_step_logits = broken
+    sys.exit(cs.main(['--chips', '4']))""")
+    r = _py(code, env_extra={
+        "JAX_PLATFORMS": "cpu",
+        "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
+        "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")})
+    assert r.returncode != 0, r.stdout[-2000:]
+    assert ("chip_smoke check failed: tp=4 first-step logits agree"
+            in r.stderr), r.stderr[-2000:]
+    assert not [l for l in r.stdout.splitlines() if l.startswith("{")]
+    rel = float(r.stdout.split("# tp4_vs_tp1_first_step_logits:")[1]
+                .split("rel=")[1].split()[0])
+    assert rel > 0.5, rel       # nowhere near the tolerance's edge
 
 
 def test_chip_smoke_any_failed_phase_exits_nonzero(tmp_path):

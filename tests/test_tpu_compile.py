@@ -11,6 +11,7 @@ module-scoped fixture: only the xdist worker that is handed this file
 loads libtpu, and every worker collects the same tests.
 """
 
+import dataclasses
 import os
 
 import jax
@@ -23,6 +24,10 @@ from dynamo_tpu.engine.config import bench_model_config
 
 CFG_1B = bench_model_config("1b")     # Llama-3.2-1B published widths
 CFG_8B = bench_model_config("8b")     # Llama-3-8B published widths
+# google/gemma-2b attention widths (MQA: 8 heads over one 256-wide KV
+# head), the narrow end of what the ragged gate prices
+CFG_GEMMA_2B = dataclasses.replace(CFG_1B, num_heads=8, num_kv_heads=1,
+                                   head_dim=256)
 
 
 @pytest.fixture(scope="module")
@@ -144,19 +149,42 @@ def test_ragged_kernel_compiles_at_a_supported_budget(one_chip):
     _ragged_case(one_chip, CFG_1B, 16, False, max_rows=32)
 
 
-def test_ragged_supported_geometries_all_compile_at_1b_width(one_chip):
-    """ragged_supported is the gate ``attn_impl="auto"`` trusts: every
-    row budget it accepts at 1B width, up to its boundary, must build —
-    and the default budget (64) sits beyond the boundary, which is why
-    --ragged resolves to the XLA path at this width (ROADMAP open
-    item)."""
-    for block_size, kv_int8 in ((16, False), (32, True)):
-        dt = jnp.int8 if kv_int8 else jnp.bfloat16
-        top = _ragged_boundary(CFG_1B, block_size, dt)
-        assert 8 <= top < 64, top
-        for rows in sorted({8, 16, 24, 32, top}):
-            if rows <= top:
-                _ragged_case(one_chip, CFG_1B, block_size, kv_int8, rows)
+@pytest.mark.parametrize("cfg,block_size,kv_int8,below,default_fits", [
+    (CFG_1B, 16, False, (8, 16, 24, 32), False),
+    (CFG_1B, 32, True, (8, 16, 24, 32), False),
+    (CFG_8B, 16, False, (8, 16), False),
+    (CFG_GEMMA_2B, 16, False, (64,), True),
+], ids=["1b-bf16", "1b-int8kv", "8b-bf16", "gemma2b-mqa"])
+def test_ragged_supported_geometries_all_compile(
+        one_chip, cfg, block_size, kv_int8, below, default_fits):
+    """ragged_supported is the gate ``attn_impl="auto"`` trusts, from
+    both sides: every row budget it accepts, up to its boundary, must
+    build, and a third beyond its boundary the compiler must really be
+    out of VMEM — the gate may stop a little early, never late, and
+    never far from where Mosaic stops. At 1B and 8B widths the default
+    budget (64) sits beyond the boundary, which is why --ragged
+    resolves to the XLA path there (ROADMAP open item)."""
+    dt = jnp.int8 if kv_int8 else jnp.bfloat16
+    top = _ragged_boundary(cfg, block_size, dt)
+    assert (top >= 64) == default_fits, top
+    for rows in (*below, top):
+        assert rows <= top
+        _ragged_case(one_chip, cfg, block_size, kv_int8, rows)
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _ragged_case(one_chip, cfg, block_size, kv_int8, -(-top * 4 // 3))
+
+
+def test_ragged_gate_refuses_head_counts_off_the_sublane_tiling(one_chip):
+    """Qwen2.5-1.5B attention widths (12 heads over 2 KV heads of 128):
+    the decode kernel builds, the ragged kernel's per-sequence q-window
+    slice does not — so ragged_supported must say no, whatever the row
+    budget."""
+    cfg = dataclasses.replace(CFG_1B, num_heads=12, num_kv_heads=2,
+                              head_dim=128)
+    _decode_case(one_chip, cfg, 16, False)
+    assert not A.ragged_supported(12, 2, 128, 16, 8, kv_dtype=jnp.bfloat16)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _ragged_case(one_chip, cfg, 16, False, max_rows=8)
 
 
 def test_int8_lm_head_kernel_compiles_at_llama_vocab(one_chip):
