@@ -1,0 +1,309 @@
+#!/usr/bin/env python3
+"""The CPU rehearsal of the benchmark: everything but the chip.
+
+    JAX_PLATFORMS=cpu python3 benchmark/selftest.py
+
+It runs the harness end to end at tiny widths of both families (dense GQA,
+and ``qwen2_moe`` with the gated shared expert and unnormalised top-k)
+through the same ``run_cell`` the chip runs, and checks the yardstick's own
+arithmetic on known inputs. Numbers it prints are CPU numbers: they show
+that the line parses, never how fast anything is.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import json
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+sys.path[:0] = [HERE, ROOT]
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+import loadgen  # noqa: E402
+import run as bench_run  # noqa: E402
+import stats  # noqa: E402
+import trace_reduce  # noqa: E402
+import traffic  # noqa: E402
+
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+TMP_METRIC = "selftest.tmp_metric"
+
+
+def check(cond: bool, what: str) -> None:
+    if not cond:
+        raise AssertionError(f"selftest failed: {what}")
+    print(f"ok  {what}", flush=True)
+
+
+def arithmetic() -> None:
+    xs = list(range(1, 101))
+    check(stats.percentile(xs, 95) == 95 and stats.percentile(xs, 50) == 50
+          and stats.percentile([7], 95) == 7,
+          "nearest-rank percentile on 1..100")
+    check(stats.supported_tail(400) == 95.0
+          and stats.supported_tail(100) == 90.0
+          and stats.supported_tail(12) == 50.0,
+          "the tail a sample supports: ten samples beyond it")
+    check(abs(stats.iqr_spread([10, 11, 12, 13, 14, 15]) - 3.5 / 12.5) < 1e-12,
+          "spread = (Q3-Q1)/median with statistics.quantiles")
+
+
+def generator() -> None:
+    mix = dict(traffic.load_mix("chat-open"), shuffle_block=8)
+    a = traffic.schedule(mix, 7, 10, 1000)
+    b = traffic.schedule(mix, 7, 10, 1000)
+    c = traffic.schedule(mix, 2 ** 31 + 11, 10, 1000)
+    check(a == b, "the same --seed gives the same arrivals, lengths and ids")
+    check(a != c and len(a) == len(c)
+          and sorted(len(r["prompt"]) for r in a)
+          == sorted(len(r["prompt"]) for r in c)
+          and sorted(r["max_tokens"] for r in a)
+          == sorted(r["max_tokens"] for r in c),
+          "another seed deals the same set of sizes in another order")
+    at = lambda reqs: sum(1 for r in reqs if 8 <= r["due"] < 16)  # noqa: E731
+    check(abs(at(a) - at(c)) <= 8 and a[3]["due"] != c[3]["due"]
+          and abs(a[16]["due"] - c[16]["due"]) < 1e-9,
+          "the order changes only inside blocks of 8: every seed offers "
+          "the same work at the same pace")
+    fixed = dict(mix, shuffle_block=1)
+    d, e = (traffic.schedule(fixed, s, 10, 1000) for s in (7, 2 ** 31 + 11))
+    check([(r["due"], len(r["prompt"]), r["max_tokens"]) for r in d]
+          == [(r["due"], len(r["prompt"]), r["max_tokens"]) for r in e]
+          and d[0]["prompt"] != e[0]["prompt"],
+          "shuffle_block 1: the same arrivals and sizes for every seed, "
+          "other token ids")
+    n = round(mix["rate_rps"] * traffic.span_s(mix, 10))
+    check(len(a) == n and a[-1]["due"] <= traffic.span_s(mix, 10),
+          "an open loop offers rate x span requests inside the span")
+    lens = [len(r["prompt"]) for r in a]
+    spec = mix["prompt_tokens"]
+    check(min(lens) >= spec["min"] and max(lens) <= spec["max"],
+          "prompt lengths stay inside the mix's clip")
+    closed = traffic.schedule(traffic.load_mix("prefill-closed"), 3, 10, 1000)
+    check(all(r["due"] is None and r["max_tokens"] == 32 for r in closed),
+          "a closed loop's requests carry no due time")
+    check(traffic.buckets_used(mix, [128, 256, 512, 1024, 2048, 4096])
+          == [128, 256, 512, 1024],
+          "the buckets a mix can use")
+    shared = dict(mix, shared_prefix={"groups": 2, "tokens": 40, "zipf": 1.0})
+    reqs = traffic.schedule(shared, 5, 10, 1000)
+    heads = {tuple(r["prompt"][:31]) for r in reqs}
+    check(len(heads) == 2, "shared prefixes: requests fall into the groups")
+
+
+def window_arithmetic() -> None:
+    """The load generator's reduction on records with known answers."""
+    run = loadgen.Run({"mix": {"loop": "open", "rate_rps": 1.0, "ramp_s": 1,
+                               "prompt_tokens": {"dist": "const", "value": 4},
+                               "output_tokens": {"dist": "const", "value": 3}},
+                       "seconds": 10, "seed": 0, "vocab": 50})
+
+    def rec(start, arrivals, asked=3, done=True, status=200, usage=3):
+        return {"start": start, "first": arrivals[0] if arrivals else None,
+                "arrivals": arrivals, "status": status, "asked": asked,
+                "completion_tokens": usage, "done": done, "error": None}
+    run.records = [
+        rec(0.5, [0.9, 1.1, 1.2]),              # started in the ramp
+        rec(2.0, [2.1, 2.2, 2.4]),              # wholly inside
+        rec(10.5, [10.9, 11.2], done=False),    # first token inside
+        rec(5.0, [], status=503),               # refused
+        rec(6.0, [6.5, 6.6], usage=2),          # short answer
+    ]
+    run.lateness = [0.001, 0.004]
+    out = run.reduce(11.5)
+    check(out["attempted"] == 4 and out["failed"] == 2,
+          "attempted = started in the window; refused and short answers fail")
+    check([round(x) for x in out["ttft_ms"]] == [100, 400],
+          "TTFT from when a request was due, no sample for a failed one")
+    check(out["tokens_in_window"] == 8 and len(out["itl_ms"]) == 5,
+          "tokens and gaps counted where they arrive inside the window")
+    check(abs(out["lateness_max_ms"] - 4.0) < 1e-9, "worst lateness")
+
+
+def trace_reduction() -> None:
+    events = {"/device:TPU:0": [
+        ("while", 0, 100), ("fusion.1", 0, 40), ("custom-call", 50, 40),
+        ("copy", 95, 20), ("fusion.1", 200, 100), ("tail", 500, 10)]}
+    out = trace_reduce.reduce(events, window=(0, 520),
+                              host_spans=[("decode", 90, 210),
+                                          ("prefill", 290, 400)])
+    check(abs(out["busy_s"] - 225e-9) < 1e-15
+          and abs(out["window_s"] - 520e-9) < 1e-15,
+          "busy time is the union of op intervals; nested ops count once")
+    ops = dict((n, round(s * 1e9)) for n, s in out["device_ops"])
+    check(ops == {"fusion.1": 140, "custom-call": 40, "copy": 20,
+                  "tail": 10},
+          "the op table lists what ran inside an enclosing op, not the op")
+    gaps = dict((n, round(s * 1e9)) for n, s in out["idle_gaps"])
+    check(gaps == {"decode": 85, "prefill": 200, "none": 10},
+          "idle gaps take the label of the host span that covers them")
+    with open(os.path.join(HERE, "fixtures", "trace_events.json")) as f:
+        fixture = json.load(f)
+    got = trace_reduce.reduce(
+        {k: [tuple(e) for e in v] for k, v in fixture["events"].items()})
+    for key, want in fixture["expect"].items():
+        check(abs(got[key] - want) <= 1e-9 * max(1.0, abs(want)),
+              f"recorded chip trace reduces to the recorded {key}")
+
+
+def fixture_events() -> dict:
+    with open(os.path.join(HERE, "fixtures", "trace_events.json")) as f:
+        return {k: [tuple(e) for e in v]
+                for k, v in json.load(f)["events"].items()}
+
+
+async def rehearse(bench: dict, cell: dict, trace: bool, devices, cache):
+    return await bench_run.run_cell(bench, cell, 2 ** 31 + 5, 6.0, trace,
+                                    devices, cache)
+
+
+def end_to_end() -> None:
+    import jax
+    devices = jax.devices()
+    cache = bench_run.enable_cache()
+    # the rehearsal leaves no CPU programs in the checkout's cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    real = bench_run.load_benchmark()
+    # every reader runs in both rehearsal cells, whatever cells it lists
+    for group in ("end_to_end", "per_layer"):
+        real[group] = [{k: v for k, v in m.items() if k != "workloads"}
+                       for m in real[group]]
+    bench = dict(real, configs=[
+        {"name": n, "file": f"benchmark/fixtures/{n}.json"}
+        for n in ("tiny-dense", "tiny-qwen2moe")],
+        per_layer=real["per_layer"] + [
+            {"name": TMP_METRIC, "unit": "1", "better": "higher",
+             "source": "program_counter", "layer": "Engine step",
+             "moves": "out_tokens_per_s"}])
+    # a traced run on the CPU has no device plane: the reduction is given
+    # the recorded chip events instead (its own check is trace_reduction)
+    bench_run.reduce_trace = lambda traced, flight: trace_reduce.reduce(
+        fixture_events())
+    tmp = os.path.join(HERE, "layer_metrics", f"{TMP_METRIC}.py")
+    with open(tmp, "w") as f:
+        f.write("def read(ctx):\n    return float(len(ctx['flight']))\n")
+    try:
+        for config, mix in (("tiny-dense", "selftest-open"),
+                            ("tiny-qwen2moe", "selftest-closed")):
+            cell = {"name": f"{config}.{mix}", "config": config,
+                    "traffic": mix, "chips": 1}
+            for trace in (False, True):
+                out = asyncio.run(rehearse(bench, cell, trace, devices,
+                                           cache))
+                line = json.dumps(out)
+                back = json.loads(line)
+                check(RESULT_KEYS <= set(back) and back["correct"] is True
+                      and back["failed"] == 0 and back["attempted"] > 0,
+                      f"{cell['name']} trace={int(trace)}: the last line "
+                      f"parses to the contract's keys and is correct")
+                names = set(back["metrics"])
+                if trace:
+                    check(TMP_METRIC in names and "breakdown" in back
+                          and {"busy_s", "window_s"} <= set(back["device"]),
+                          "a new per-layer metric is found by its name "
+                          "alone; the traced line has busy_s and window_s")
+                    check("device.hbm_peak_pct" not in names,
+                          "a reader with nothing to read (no memory stats "
+                          "on the CPU) leaves its metric out")
+                else:
+                    check(names == {m["name"] for m in real["end_to_end"]},
+                          "trace=0 reports the end-to-end metrics")
+    finally:
+        os.remove(tmp)
+
+
+def reference_check() -> None:
+    """The reference against the engine at tiny widths, and the three
+    breakages that the tolerance has to catch."""
+    import dataclasses
+    import jax
+    import numpy as np
+    import reference
+    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+    from dynamo_tpu.engine.core import EngineCore
+
+    for name in ("tiny-dense", "tiny-qwen2moe"):
+        with open(os.path.join(HERE, "fixtures", f"{name}.json")) as f:
+            hf = bench_run.hf_config(json.load(f))
+        # deeper and wider than the served rehearsal, so that one layer
+        # is a small part of the whole, as on the chip
+        hf = dict(hf, num_hidden_layers=6)
+        cfg = ModelConfig.from_hf_config(hf)
+        core = EngineCore(cfg, EngineConfig(
+            max_model_len=128, num_kv_blocks=32, max_num_seqs=2,
+            quantization="int8", seed=11))
+        rng = np.random.default_rng(3)
+        prompt = rng.integers(0, cfg.vocab_size, size=48).tolist()
+        # the engine's own greedy continuation, through its prefill and
+        # decode programs (the HTTP leg is end_to_end's)
+        ids, lps = greedy(core, prompt, 8)
+        rep = reference.compare(core.params, hf, prompt, ids, lps)
+        check(rep["ok"], f"{name}: engine within {reference.TOL_STD} std of "
+              f"the reference (logprob err {rep['worst_logprob_err_std']:.3f}"
+              f", argmax gap {rep['worst_argmax_gap_std']:.3f})")
+        breakages = ["drop_layer"] + (["no_shared_expert",
+                                       "unit_routing_weights"]
+                                      if cfg.num_experts else [])
+        for broken in breakages:
+            rep = reference.compare(core.params, hf, prompt, ids, lps,
+                                    broken=broken)
+            check(not rep["ok"], f"{name}: {broken} trips the tolerance "
+                  f"(logprob err {rep['worst_logprob_err_std']:.2f} std, "
+                  f"argmax gap {rep['worst_argmax_gap_std']:.2f} std)")
+        del core
+    jax.clear_caches()
+
+
+def greedy(core, prompt: list, n: int) -> tuple:
+    """n greedy tokens and their logprobs from the engine's prefill and
+    decode programs, driven directly (one sequence, slot 0)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from dynamo_tpu.engine.sampling import make_slot_keys
+    cfg = core.cfg
+    bucket = cfg.bucket_for(len(prompt))
+    padded = np.zeros((bucket,), np.int32)
+    padded[:len(prompt)] = prompt
+    table = np.arange(1, core.M + 1, dtype=np.int32)
+    f32, i32 = jnp.float32, jnp.int32
+    tok, lp, core.kv = core._prefill_jit(
+        core.params, core.kv, jnp.asarray(padded), jnp.asarray(table),
+        jnp.asarray(0, i32), jnp.asarray(len(prompt), i32),
+        jax.random.PRNGKey(0), jnp.asarray(0.0, f32), jnp.asarray(0, i32),
+        jnp.asarray(1.0, f32))
+    ids, lps = [int(tok)], [float(lp)]
+    B = core.B
+    tables = np.zeros((B, core.M), np.int32)
+    tables[0] = table
+    for step in range(n - 1):
+        tokens = np.zeros((B,), np.int32)
+        pos = np.zeros((B,), np.int32)
+        tokens[0], pos[0] = ids[-1], len(prompt) + step
+        keys = make_slot_keys(0, jnp.zeros((B,), jnp.int64),
+                              jnp.zeros((B,), jnp.int64))
+        toks, lpb, core.kv = core._decode_jit(
+            core.params, core.kv, jnp.asarray(tokens), jnp.asarray(pos),
+            jnp.asarray(tables), keys, jnp.zeros((B,), f32),
+            jnp.zeros((B,), i32), jnp.ones((B,), f32))
+        ids.append(int(toks[0]))
+        lps.append(float(lpb[0]))
+    return ids, lps
+
+
+def main() -> int:
+    arithmetic()
+    generator()
+    window_arithmetic()
+    trace_reduction()
+    reference_check()
+    end_to_end()
+    print("selftest: all passed")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
