@@ -388,6 +388,7 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_prefill",
     )(meta, qr, kr, vr)
     return _flash_unpack(out, KVH, Tp, g, Dh, T)
 
@@ -437,6 +438,7 @@ def flash_prefill_partial(q: jax.Array, k: jax.Array, v: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_prefill_partial",
     )(meta, qr, kr, vr)
 
     acc = _flash_unpack(acc, KVH, Tp, g, Dh, T)
@@ -1088,6 +1090,7 @@ def paged_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Bp, Hp, Cv), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(block_tables, seq_lens, jnp.asarray(win_lo, jnp.int32), runs, qm,
       k_cache, v_cache)
     if v_lanes is not None:
@@ -1543,6 +1546,7 @@ def ragged_paged_attention_pallas(q: jax.Array, k_cache: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(block_tables, jnp.asarray(seq_starts, jnp.int32),
       jnp.asarray(seq_counts, jnp.int32),
       jnp.asarray(seq_lens, jnp.int32),

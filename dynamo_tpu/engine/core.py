@@ -117,6 +117,10 @@ class EngineRequest:
     session: str = ""
     enqueue_time: float = dataclasses.field(default_factory=time.monotonic)
     first_token_time: Optional[float] = None
+    # when the admission's prefill dispatch (or precomputed scatter, or
+    # the lane admission itself) returned: the start of the trace's
+    # engine.first_token span
+    dispatched_time: Optional[float] = None
     # the request's runtime Trace (runtime/tracing.py) — attached by
     # submit() from the ambient contextvar so the engine can feed
     # per-phase spans (queue wait, KV onboard incl. fabric fetch,
@@ -522,23 +526,16 @@ class EngineCore:
         self.spec_drafted_tokens = 0   # draft tokens scored
         self.spec_accepted_tokens = 0  # drafts that matched their sample
         self.spec_emitted_tokens = 0   # tokens emitted by verify steps
-        # synchronous device→host fetches the engine loop has paid
-        # (harvests + admission token fetches): count + MEASURED stall
-        # seconds. Sampling host_stall_s around a latency window lets
-        # tools/serve_bench.py report host-scheduler-only latency net of
-        # the measured (not modeled) fetch stalls: an async copy that
-        # already landed, or a fetch of an already-host value, measures
-        # ~0 by construction
-        self.host_roundtrips = 0
-        self.host_stall_s = 0.0
         # flight recorder (engine/flight_recorder.py): bounded ring of
         # per-dispatch records + loop-lag probe, dumpable via /debug and
-        # llmctl trace dump; per-phase spans feed each request's trace
+        # llmctl trace dump; per-phase spans feed each request's trace.
+        # Its PhaseClock is the loop's one clock: every line of the loop
+        # runs in exactly one phase, and each decode/ragged/verify record
+        # carries the split of the cycle it closes
         from .flight_recorder import FlightRecorder, register_recorder
         self.flight = FlightRecorder()
+        self.clock = self.flight.clock
         register_recorder(self.flight)
-        self._flight_prev_stall_s = 0.0
-        self._flight_cycle_end = time.monotonic()
 
     # ------------------------------------------------------------------ jit
     def _compile_jits_pp(self) -> None:
@@ -615,7 +612,10 @@ class EngineCore:
                 top_p[None])
             return tok[0], logprob[0], kv
 
-        self._prefill_jit = jax.jit(prefill, donate_argnums=(1,))
+        # named scopes: stable names in the compiled programs and the
+        # profiler's trace, whatever the compiler calls its fusions
+        self._prefill_jit = jax.jit(jax.named_scope("prefill")(prefill),
+                                    donate_argnums=(1,))
 
         def decode(params, kv, tokens, positions, block_tables,
                    keys, temperature, top_k, top_p):
@@ -626,7 +626,8 @@ class EngineCore:
                                            top_k, top_p)
             return toks, logprobs, kv
 
-        self._decode_jit = jax.jit(decode, donate_argnums=(1,))
+        self._decode_jit = jax.jit(jax.named_scope("decode")(decode),
+                                   donate_argnums=(1,))
 
         # K decode steps fused into one dispatch (EngineConfig
         # decode_steps_per_dispatch): the sampled token feeds the next step
@@ -897,6 +898,14 @@ class EngineCore:
             # disk evictions whose promotion jobs are still queued
             await self.remote_spill_engine.stop()
             self.remote_store.close()
+
+    @property
+    def host_stall_s(self) -> float:
+        """Seconds the loop has blocked on device→host fetches (harvests
+        and admission token fetches): the clock's ``wait`` total, measured
+        not modelled — a copy that already landed reads ~0. Sampled around
+        a latency window by tools/serve_bench.py."""
+        return self.clock.seconds["wait"]
 
     @property
     def wire_kv_heads(self) -> int:
@@ -1351,8 +1360,10 @@ class EngineCore:
         detach_trace()
         logger.info("engine loop starting: %d slots, %d KV blocks, block=%d",
                     self.B, self.cfg.num_kv_blocks, self.cfg.kv_block_size)
+        clock = self.clock
         while not self._stopping:
             progressed = False
+            clock.enter("sweep")
             # 0) opportunistic KV compaction: only when no admission is
             # queued and no dispatch is un-harvested (the pass inserts
             # one small device copy ahead of the next decode dispatch)
@@ -1365,6 +1376,7 @@ class EngineCore:
             if self._sweep_cancelled():
                 progressed = True
             # 1) admit waiting work into free slots
+            clock.enter("admit")
             while not self.waiting.empty():
                 slot = self._free_slot_index()
                 if slot < 0:
@@ -1379,7 +1391,10 @@ class EngineCore:
                     break
                 progressed = True
             # 2) run one decode step for whatever is active and ready
+            # (the step functions and harvests mark build / dispatch /
+            # wait / post themselves)
             if any(s is not None and s.ready for s in self.slots):
+                clock.enter("build")
                 self._decode_step()
                 progressed = True
             elif self._pending is not None:
@@ -1395,6 +1410,7 @@ class EngineCore:
                 self._harvest_ragged(prev)
                 progressed = True
             # 3) deferred admissions: their async fetch overlapped step 2
+            clock.enter("complete")
             if self._admissions:
                 self._complete_admissions()
                 progressed = True
@@ -1402,6 +1418,7 @@ class EngineCore:
             if self._onboards:
                 self._complete_onboards()
                 progressed = True
+            clock.enter("yield")
             if not progressed:
                 self._work_event.clear()
                 try:
@@ -1958,8 +1975,9 @@ class EngineCore:
                     else:
                         self._finish_request(req, FinishReason.ERROR)
                     continue
-                self._admit_with_plan(req, slot, plan, prepped,
-                                      remote_values=remote_values)
+                with self.clock.phase("admit"):
+                    self._admit_with_plan(req, slot, plan, prepped,
+                                          remote_values=remote_values)
             finally:
                 # _start_onboard pinned these; safe to evict only now
                 # that hit_transfer (if any) is on the stream. A failed
@@ -2057,6 +2075,7 @@ class EngineCore:
                               disk_targets=list(
                                   plan.new_blocks[n_host:n_hd]))
         t0 = time.monotonic()
+        wait0 = self.clock.seconds["wait"]
         suffix_len = n_prompt - req.prefix_hit_tokens
         if (self._ragged_jit is not None and req.handoff is None
                 and req.precomputed is None and suffix_len > 0):
@@ -2096,12 +2115,8 @@ class EngineCore:
             # admission
             defer = (self.cfg.overlap_admission_fetch
                      and hasattr(tok, "copy_to_host_async"))
-            if not defer:
-                if hasattr(tok, "copy_to_host_async"):  # device, not host
-                    self.host_roundtrips += 1
-                _t0 = time.monotonic()
-                tok, logprob = int(tok), float(logprob)
-                self.host_stall_s += time.monotonic() - _t0
+            fetch = not defer
+            t_dispatched = time.monotonic()
         else:
             # prefill only the un-matched suffix — the prefix KV is already
             # in the pool's blocks (this is the TTFT win of prefix reuse)
@@ -2163,11 +2178,13 @@ class EngineCore:
                     jnp.asarray(req.sampling.top_k, jnp.int32),
                     jnp.asarray(req.sampling.top_p, jnp.float32))
             self.total_prefill_tokens += len(chunk)
+            self.clock.admits += 1
             # measured prefill rate (fabric admission gate + the
             # router's NetKV recompute model): wall time from plan to
             # dispatched prefill — an upper bound on the true compute
             # cost, so the modeled recompute stays conservative
-            admit_wall_s = time.monotonic() - t0
+            t_dispatched = time.monotonic()
+            admit_wall_s = t_dispatched - t0
             self.prefill_wall_s += admit_wall_s
             self.prefill_rate_estimator.observe(len(chunk), admit_wall_s)
             # defer the device→host fetch of the first token: it overlaps
@@ -2177,11 +2194,10 @@ class EngineCore:
             # device scalar and the decode side defers its own fetch.
             defer = (self.cfg.overlap_admission_fetch
                      and req.handoff is None)
-            if not defer and not req.handoff_device:
-                self.host_roundtrips += 1
-                _t0 = time.monotonic()
+            fetch = not defer and not req.handoff_device
+        if fetch:
+            with self.clock.phase("wait"):
                 tok, logprob = int(tok), float(logprob)
-                self.host_stall_s += time.monotonic() - _t0
         if req.handoff is not None:
             defer = False
         req.pos = n_prompt
@@ -2199,9 +2215,12 @@ class EngineCore:
         if req.handoff is not None:
             self._handoff_and_finish(req, tok, logprob)
             return True
+        # engine.first_token (the request's trace) runs from here, the
+        # prefill dispatch's return, to the first emit
+        req.dispatched_time = t_dispatched
         if not defer:
             req.last_token = int(tok)
-            req.first_token_time = time.monotonic()
+            self._mark_first_token(req)
             if self.recorder is not None:
                 self.recorder.rec("first_token", rid=req.rid,
                                   pf_seq=getattr(req, "_pf_seq", None),
@@ -2237,6 +2256,11 @@ class EngineCore:
             hit_remote=plan.remote_hit_tokens,
             precomputed=remote_admit,
             host_ms=round(1e3 * (now - t0), 3),
+            # of host_ms: plan to the prefill program's return (argument
+            # build and transfers included), and the blocking fetch of
+            # its token (0 when deferred behind the next decode dispatch)
+            dispatch_ms=round(1e3 * (t_dispatched - t0), 3),
+            wait_ms=round(1e3 * (self.clock.seconds["wait"] - wait0), 3),
             queue_wait_ms=round(1e3 * (_t_admit - req.enqueue_time), 3))
         if req.trace is not None:
             req.trace.add_span(
@@ -2275,6 +2299,7 @@ class EngineCore:
         req.key_step -= n_prompt - hit - 1
         req.last_token = req.prompt[hit]       # step-0 planned input
         req.ready = True
+        req.dispatched_time = time.monotonic()   # no prefill to wait for
         # hash chain restarts from the hit prefix and grows per input token
         req.seq = TokenBlockSequence(self.cfg.kv_block_size,
                                      req.prompt[:hit])
@@ -2352,18 +2377,15 @@ class EngineCore:
         been in flight across a decode dispatch; fetch, emit the first
         token, and make the slot decodable."""
         pending, self._admissions = self._admissions, []
-        if pending:
-            # the async copies were issued at admission and usually land
-            # during the intervening dispatch harvest — host_stall_s
-            # records what the fetches below ACTUALLY cost (often ~0)
-            self.host_roundtrips += 1
         for req, tok_dev, logprob_dev in pending:
-            _t0 = time.monotonic()
-            tok = int(np.asarray(tok_dev))
-            logprob = float(np.asarray(logprob_dev))
-            self.host_stall_s += time.monotonic() - _t0
+            # the async copies were issued at admission and usually land
+            # during the intervening dispatch harvest — the wait phase
+            # records what the fetches ACTUALLY cost (often ~0)
+            with self.clock.phase("wait"):
+                tok = int(np.asarray(tok_dev))
+                logprob = float(np.asarray(logprob_dev))
             req.last_token = tok
-            req.first_token_time = time.monotonic()
+            self._mark_first_token(req)
             req.ready = True
             if self.recorder is not None:
                 self.recorder.rec("first_token", rid=req.rid,
@@ -2683,6 +2705,7 @@ class EngineCore:
                 self._harvest(prev)
                 if not any(s is not None and s.ready for s in self.slots):
                     return
+                self.clock.enter("build")
             if self._decode_step_spec():
                 return
             # drafter came up dry everywhere: plain decode this step
@@ -2708,15 +2731,18 @@ class EngineCore:
         self._step += 1
         keys = make_slot_keys(self.cfg.seed, jnp.asarray(self._seeds),
                               jnp.asarray(steps))
+        args = (jnp.asarray(self._tokens), jnp.asarray(self._positions),
+                jnp.asarray(tables), keys,
+                jnp.asarray(self._samp["temperature"]),
+                jnp.asarray(self._samp["top_k"]),
+                jnp.asarray(self._samp["top_p"]))
+        self.clock.enter("dispatch")
         toks, logprobs, self.kv = self._decode_jit(
-            self.params, self.kv,
-            jnp.asarray(self._tokens), jnp.asarray(self._positions),
-            jnp.asarray(tables), keys,
-            jnp.asarray(self._samp["temperature"]),
-            jnp.asarray(self._samp["top_k"]),
-            jnp.asarray(self._samp["top_p"]))
+            self.params, self.kv, *args)
+        self.clock.enter("wait")
         toks = np.asarray(toks)
         logprobs = np.asarray(logprobs)
+        self.clock.enter("post")
         bs = self.cfg.kv_block_size
         for i in active_idx:
             req = self.slots[i]
@@ -2764,15 +2790,10 @@ class EngineCore:
                 self._block_tables[i, len(req.blocks) - 1] = new[0]
             self._emit(req, tok, float(logprobs[i]))
             self._maybe_finish_after_emit(req)
-        _now = time.monotonic()
-        self.flight.record(
+        self.flight.record_cycle(
             "decode", K=1, batch_fill=len(active_idx),
             planned_tokens=len(active_idx),
-            emitted=len(active_idx),
-            device_ms=0.0,
-            host_gap_ms=round(
-                1e3 * (_now - self._flight_cycle_end), 3))
-        self._flight_cycle_end = _now
+            emitted=len(active_idx))
 
     def _decode_step_multi(self, K: int) -> None:
         """K fused decode steps, one dispatch, one host harvest: sampled
@@ -2796,6 +2817,7 @@ class EngineCore:
                 return
             # couldn't chain (slot churn / growth failure): fall through to
             # a fresh host-fed dispatch against the harvested state
+            self.clock.enter("build")
         if not self._prepare_multi(K):
             return
         pending = self._dispatch_multi(K)
@@ -2938,15 +2960,17 @@ class EngineCore:
             planned_dev, pmask_dev = self._planned_zero
         else:
             planned_dev, pmask_dev = jnp.array(planned), jnp.array(pmask)
+        args = (tokens_in, jnp.array(self._positions),
+                jnp.array(tables),
+                jnp.array(self._seeds), jnp.array(steps),
+                jnp.array(self._samp["temperature"]),
+                jnp.array(self._samp["top_k"]),
+                jnp.array(self._samp["top_p"]),
+                planned_dev, pmask_dev)
+        self.clock.enter("dispatch")
         toks_k, logprobs_k, self.kv = self._decode_k_jit(
-            self.params, self.kv,
-            tokens_in, jnp.array(self._positions),
-            jnp.array(tables),
-            jnp.array(self._seeds), jnp.array(steps),
-            jnp.array(self._samp["temperature"]),
-            jnp.array(self._samp["top_k"]),
-            jnp.array(self._samp["top_p"]),
-            planned_dev, pmask_dev)
+            self.params, self.kv, *args)
+        self.clock.enter("build")
         return {"toks": toks_k, "logprobs": logprobs_k, "K": K, "id": did,
                 "reqs": [s if (s is not None and s.ready) else None
                          for s in self.slots]}
@@ -2959,11 +2983,10 @@ class EngineCore:
         _fault("engine.harvest")    # chaos: loop-fatal boundary — an
         # injected error here kills the loop LOUDLY and _fail_pending
         # releases every slot/hold (asserted in tests/test_chaos.py)
-        self.host_roundtrips += 1
-        _t0 = time.monotonic()
+        self.clock.enter("wait")
         toks_k = np.asarray(pending["toks"])       # [K, B] — ONE host fetch
         logprobs_k = np.asarray(pending["logprobs"])
-        self.host_stall_s += time.monotonic() - _t0
+        self.clock.enter("post")
         K = pending["K"]
         applied = []
         for i, req in enumerate(pending["reqs"]):
@@ -3002,8 +3025,7 @@ class EngineCore:
                 req.generated += 1
                 req.last_token = tok
                 self.total_decode_tokens += 1
-                if req.first_token_time is None:
-                    req.first_token_time = time.monotonic()
+                self._mark_first_token(req)
                 self._emit(req, tok, float(logprobs_k[k, i]))
                 self._maybe_finish_after_emit(req)
                 if self.slots[i] is not req:
@@ -3014,23 +3036,15 @@ class EngineCore:
             self.recorder.rec("harvest", id=pending["id"],
                               toks=toks_k.copy(), applied=applied)
         # flight record: one line per dispatch-harvest cycle. device_ms is
-        # the measured host stall on the fetch (what the loop actually
-        # waited for the device); host_gap_ms is everything since the last
-        # cycle ended that was NOT that wait — scheduling, admissions,
-        # python glue. Together they answer "device-bound or host-bound?"
-        _now = time.monotonic()
-        _stall = self.host_stall_s - self._flight_prev_stall_s
-        self._flight_prev_stall_s = self.host_stall_s
-        self.flight.record(
+        # the cycle's wait phase (what the loop actually blocked on the
+        # device); host_gap_ms is everything since the last cycle ended
+        # that was NOT that wait, and the <phase>_ms fields say what:
+        # admission, input build, dispatch, bookkeeping, the event loop
+        self.flight.record_cycle(
             "decode", K=K,
             batch_fill=len(applied),
             planned_tokens=K * len(applied),
-            emitted=sum(n for _i, _r, n in applied),
-            device_ms=round(1e3 * _stall, 3),
-            host_gap_ms=round(
-                max(1e3 * (_now - self._flight_cycle_end - _stall), 0.0),
-                3))
-        self._flight_cycle_end = _now
+            emitted=sum(n for _i, _r, n in applied))
 
     # --------------------------------------------------------------- ragged
     def _ragged_step(self) -> None:
@@ -3069,6 +3083,7 @@ class EngineCore:
             # couldn't chain (churn / drafts due / growth failure):
             # fall through to a fresh host-fed dispatch against the
             # harvested state
+            self.clock.enter("build")
         pending = self._ragged_dispatch_fresh()
         if pending is None:
             return
@@ -3290,15 +3305,17 @@ class EngineCore:
                 jnp.array(mask))
         else:
             tokens_in = host_tokens
+        args = (tokens_in, jnp.array(batch.positions),
+                jnp.array(tables), jnp.array(batch.row_slot),
+                jnp.array(batch.seq_starts),
+                jnp.array(batch.seq_counts),
+                jnp.array(batch.sample_rows),
+                jnp.array(seeds), jnp.array(steps),
+                jnp.array(temp), jnp.array(top_k), jnp.array(top_p))
+        self.clock.enter("dispatch")
         toks, logprobs, self.kv = self._ragged_jit(
-            self.params, self.kv,
-            tokens_in, jnp.array(batch.positions),
-            jnp.array(tables), jnp.array(batch.row_slot),
-            jnp.array(batch.seq_starts),
-            jnp.array(batch.seq_counts),
-            jnp.array(batch.sample_rows),
-            jnp.array(seeds), jnp.array(steps),
-            jnp.array(temp), jnp.array(top_k), jnp.array(top_p))
+            self.params, self.kv, *args)
+        self.clock.enter("build")
         self.ragged_dispatches += 1
         self.ragged_rows_total += batch.rows_used
         self.ragged_prefill_rows_total += batch.prefill_rows
@@ -3341,13 +3358,12 @@ class EngineCore:
         ``applied`` entries are (slot, rid, rows_applied, emitted) —
         emitted is a COUNT (spec spans emit one token per applied
         row)."""
-        self.host_roundtrips += 1
-        _t0 = time.monotonic()
+        self.clock.enter("wait")
         # [B+1] slot samples, or [capacity] row samples in the
         # spec-enabled row-sampled variant — ONE fetch either way
         toks = np.asarray(pending["toks"])
         logprobs = np.asarray(pending["logprobs"])
-        self.host_stall_s += time.monotonic() - _t0
+        self.clock.enter("post")
         batch = pending["batch"]
         row_sampled = self._ragged_row_sampled
         applied = []
@@ -3382,8 +3398,7 @@ class EngineCore:
                     self.spec_emitted_tokens += 1
                     if t > 0:
                         self.spec_accepted_tokens += 1
-                    if req.first_token_time is None:
-                        req.first_token_time = time.monotonic()
+                    self._mark_first_token(req)
                     self._emit(req, tok, float(logprobs[sq.start + t]))
                     self._maybe_finish_after_emit(req)
                     if self.slots[i] is not req:
@@ -3420,22 +3435,18 @@ class EngineCore:
             tok = int(toks[sample])
             req.generated += 1
             req.last_token = tok
-            if req.first_token_time is None:
-                req.first_token_time = time.monotonic()
+            self._mark_first_token(req)
             self._emit(req, tok, float(logprobs[sample]))
             self._maybe_finish_after_emit(req)
             applied.append((i, req.rid, sq.length, 1))
         if self.recorder is not None and pending.get("id") is not None:
             self.recorder.rec("ragged_harvest", id=pending["id"],
                               toks=toks.copy(), applied=applied)
-        _now = time.monotonic()
-        _stall = self.host_stall_s - self._flight_prev_stall_s
-        self._flight_prev_stall_s = self.host_stall_s
         # per-dispatch mode mix rides the flight recorder ring — the
         # /debug + llmctl trace dump view of how full, how mixed, how
         # speculative, and how well-prefetched each ragged dispatch ran
         pf = pending.get("prefetch") or {}
-        self.flight.record(
+        self.flight.record_cycle(
             "ragged", rows=batch.rows_used,
             capacity=batch.capacity,
             fill=round(batch.fill_ratio, 4),
@@ -3447,12 +3458,7 @@ class EngineCore:
             prefetch_hits=pf.get("prefetched", 0),
             chained=bool(pending.get("chained")),
             mixed=batch.mixed,
-            emitted=sum(e for _i, _r, _n, e in applied),
-            device_ms=round(1e3 * _stall, 3),
-            host_gap_ms=round(
-                max(1e3 * (_now - self._flight_cycle_end - _stall),
-                    0.0), 3))
-        self._flight_cycle_end = _now
+            emitted=sum(e for _i, _r, _n, e in applied))
 
     # ---------------------------------------------------------- speculation
     def _req_spec_k(self, req: EngineRequest) -> int:
@@ -3542,13 +3548,15 @@ class EngineCore:
                 n_rows=n_rows.copy(),
                 reqs=[s.rid if (s is not None and s.ready) else None
                       for s in self.slots])
+        args = (jnp.asarray(tokens),
+                jnp.asarray(self._positions), jnp.asarray(tables),
+                jnp.asarray(self._seeds), jnp.asarray(steps),
+                jnp.asarray(self._samp["temperature"]),
+                jnp.asarray(self._samp["top_k"]),
+                jnp.asarray(self._samp["top_p"]))
+        self.clock.enter("dispatch")
         toks_T, lps_T, self.kv = self._verify_jit(
-            self.params, self.kv, jnp.asarray(tokens),
-            jnp.asarray(self._positions), jnp.asarray(tables),
-            jnp.asarray(self._seeds), jnp.asarray(steps),
-            jnp.asarray(self._samp["temperature"]),
-            jnp.asarray(self._samp["top_k"]),
-            jnp.asarray(self._samp["top_p"]))
+            self.params, self.kv, *args)
         self.spec_dispatches += 1
         self.spec_drafted_tokens += sum(len(d) for d in dmap.values())
         self._harvest_verify({
@@ -3565,11 +3573,10 @@ class EngineCore:
         ``pos`` never advances over them, and every later dispatch
         rewrites a stale row before any query attends it (the same
         write-then-read ordering plain decode relies on)."""
-        self.host_roundtrips += 1
-        _t0 = time.monotonic()
+        self.clock.enter("wait")
         toks_T = np.asarray(pending["toks"])       # [B, Tv] — ONE fetch
         lps_T = np.asarray(pending["logprobs"])
-        self.host_stall_s += time.monotonic() - _t0
+        self.clock.enter("post")
         applied = []
         for i, req in enumerate(pending["reqs"]):
             if req is None or self.slots[i] is not req:
@@ -3602,8 +3609,7 @@ class EngineCore:
                 if t > 0:          # reaching row t>0 accepted draft t
                     self.spec_accepted_tokens += 1
                     accepted += 1
-                if req.first_token_time is None:
-                    req.first_token_time = time.monotonic()
+                self._mark_first_token(req)
                 self._emit(req, tok, float(lps_T[i, t]))
                 self._maybe_finish_after_emit(req)
                 if self.slots[i] is not req:
@@ -3614,7 +3620,7 @@ class EngineCore:
         if self.recorder is not None and pending.get("id") is not None:
             self.recorder.rec("spec_harvest", id=pending["id"],
                               toks=toks_T.copy(), applied=applied)
-        self.flight.record(
+        self.flight.record_cycle(
             "verify", batch_fill=len(applied),
             spec_k=self.cfg.spec_k,
             emitted=sum(n for _i, _r, n, _a in applied),
@@ -3686,6 +3692,19 @@ class EngineCore:
         self._work_event.set()
 
     # ------------------------------------------------------------- finishes
+    def _mark_first_token(self, req: EngineRequest) -> None:
+        """The request's first token is about to be emitted (a no-op on
+        every later call, a preempted request's recompute included): stamp
+        it, and put the wait since its admission's dispatch returned on
+        the request's trace. A lane admission has no prefill dispatch: its
+        span covers the prompt's ride through the decode batches."""
+        if req.first_token_time is not None:
+            return
+        req.first_token_time = time.monotonic()
+        if req.trace is not None and req.dispatched_time is not None:
+            req.trace.add_span("engine.first_token", req.dispatched_time,
+                               req.first_token_time)
+
     def _emit(self, req: EngineRequest, token: int, logprob: float) -> None:
         req.emitted_total += 1
         req.out_queue.put_nowait((token, logprob))

@@ -15,6 +15,13 @@ Pieces:
 - :class:`FlightRecorder` — the ring. ``record(kind, **fields)`` is
   called synchronously from the engine loop (append-only, no locks
   needed under the GIL); ``dump()`` returns the ring newest-last.
+- :class:`PhaseClock` — the engine loop's one clock. The loop is always
+  in exactly one phase (``PHASES``); ``enter`` closes the running phase
+  and opens the next with ONE timestamp, so the phases tile the cycle
+  between two ``record_cycle`` calls. Each boundary is also a
+  ``jax.profiler.TraceAnnotation`` named ``loop.<phase>``: under a
+  profiler session the phases sit on the profiler's clock beside the
+  device ops; with none the annotation costs well under a microsecond.
 - Event-loop **lag probe**: a periodic task that measures how late
   asyncio wakes it up — the direct observable for "something is blocking
   the engine loop" (sync file I/O, long host work), feeding the
@@ -29,6 +36,7 @@ Pieces:
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import itertools
 import logging
 import time
@@ -38,7 +46,8 @@ from typing import Dict, List, Optional
 
 logger = logging.getLogger("dynamo_tpu.engine.flight")
 
-__all__ = ["FlightRecorder", "register_recorder", "all_recorders",
+__all__ = ["FlightRecorder", "PhaseClock", "PHASES",
+           "register_recorder", "all_recorders",
            "trace_control_key", "trace_dump_key", "watch_trace_dump_loop",
            "TRACE_PREFIX"]
 
@@ -47,11 +56,87 @@ _REGISTRY: "weakref.WeakValueDictionary[str, FlightRecorder]" = \
 _ids = itertools.count()
 
 
+# What the engine loop can be doing (docs/observability.md has the seams):
+#   sweep     cancellation/deadline sweep, idle defrag
+#   admit     the admission pass: KV plan, prefill dispatch
+#   build     a decode-type dispatch's inputs: slot walk, tables, keys,
+#             host→device transfers
+#   dispatch  the decode/K-step/ragged/verify jit call until it returns
+#   wait      every blocking device→host fetch
+#   post      per-slot bookkeeping, block registration and growth, emits,
+#             finishes, the flight record itself
+#   complete  deferred admissions and tier onboards (minus their wait)
+#   yield     what the loop gives the shared event loop (HTTP, detokeniser,
+#             SSE), and its idle wait
+PHASES = ("sweep", "admit", "build", "dispatch", "wait", "post", "complete",
+          "yield")
+
+
+class PhaseClock:
+    """Which phase the engine loop is in, and for how long it has been.
+
+    ``seconds[phase]`` only ever grows; a cycle's split is the difference
+    of two readings (``close_cycle``), an admission's fetch stall the
+    difference of ``seconds["wait"]`` around it. Single-threaded: only the
+    engine loop's thread calls in."""
+
+    def __init__(self):
+        from jax.profiler import TraceAnnotation
+        self._annotate = TraceAnnotation
+        self._names = {p: "loop." + p for p in PHASES}
+        self.seconds: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
+        self.running = "yield"           # an engine not stepping is idle
+        self._since = self._cycle_start = time.monotonic()
+        self._at_cycle_start = dict(self.seconds)
+        self._annotation = None
+        self.admits = 0      # prefill dispatches issued in the open cycle
+
+    def enter(self, phase: str) -> float:
+        """Close the running phase and open ``phase`` at one timestamp
+        (returned). Re-entering the running phase is allowed."""
+        now = time.monotonic()
+        self.seconds[self.running] += now - self._since
+        self.running, self._since = phase, now
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        self._annotation = self._annotate(self._names[phase])
+        self._annotation.__enter__()
+        return now
+
+    @contextlib.contextmanager
+    def phase(self, phase: str):
+        """Nested use: suspend the running phase for ``phase`` and resume
+        it afterwards, also when the body raises."""
+        outer = self.running
+        self.enter(phase)
+        try:
+            yield
+        finally:
+            self.enter(outer)
+
+    def close_cycle(self) -> Dict[str, float]:
+        """End the open cycle now: ``<phase>_ms`` for each phase since the
+        last close, ``cycle_ms`` between the two closes by the timestamps
+        alone (so a reader can check the tiling), and ``admits``. The
+        running phase carries on into the next cycle."""
+        now = self.enter(self.running)
+        out = {f"{p}_ms": round(
+            1e3 * (self.seconds[p] - self._at_cycle_start[p]), 3)
+            for p in PHASES}
+        out["cycle_ms"] = round(1e3 * (now - self._cycle_start), 3)
+        out["admits"] = self.admits
+        self._cycle_start = now
+        self._at_cycle_start = dict(self.seconds)
+        self.admits = 0
+        return out
+
+
 class FlightRecorder:
     """Bounded ring of per-dispatch records + loop-lag probe."""
 
     def __init__(self, capacity: int = 512,
                  lag_probe_interval: float = 0.5):
+        self.clock = PhaseClock()
         self._ring: deque = deque(maxlen=capacity)
         self.capacity = capacity
         self.records_total = 0
@@ -66,6 +151,17 @@ class FlightRecorder:
         allocation-light — scalar fields only, no arrays)."""
         self.records_total += 1
         self._ring.append({"kind": kind, "t": time.time(), **fields})
+
+    def record_cycle(self, kind: str, **fields) -> None:
+        """One ``decode`` / ``ragged`` / ``verify`` record, closing the
+        clock's cycle: the phase split, ``admits``, ``device_ms`` (the
+        cycle's ``wait``: what the loop blocked on the device) and
+        ``host_gap_ms`` (the rest of the harvest-to-harvest cycle)."""
+        split = self.clock.close_cycle()
+        cycle_ms = split.pop("cycle_ms")
+        self.record(kind, **fields, device_ms=split["wait_ms"],
+                    host_gap_ms=round(cycle_ms - split["wait_ms"], 3),
+                    **split)
 
     def dump(self, last: Optional[int] = None) -> List[dict]:
         out = list(self._ring)

@@ -74,6 +74,7 @@ def lm_head_int8(x: jax.Array, q: jax.Array, scale: jax.Array,
         out_specs=pl.BlockSpec((Bp, tile_v), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((Bp, V), jnp.float32),
         interpret=interpret,
+        name="lm_head_int8",
     )(x, q, scale2d)
     out = out[:B]
     return out[0] if squeeze else out

@@ -125,6 +125,7 @@ def apply_rope(x: jax.Array, positions: jax.Array,
     return out.astype(x.dtype)
 
 
+@jax.named_scope("swiglu")
 def swiglu(x: jax.Array, gate_w: jax.Array, up_w: jax.Array,
            down_w: jax.Array, act: str = "silu",
            gateup_w=None) -> jax.Array:
@@ -184,6 +185,7 @@ def fuse_stacked_matmuls(params: dict, cfg: ModelConfig) -> dict:
     return params
 
 
+@jax.named_scope("run_experts_dense")
 def run_experts_dense(x: jax.Array, gate_w: jax.Array, up_w: jax.Array,
                       down_w: jax.Array, top_idx: jax.Array,
                       top_w: jax.Array, gateup_w=None) -> jax.Array:
@@ -207,6 +209,7 @@ def run_experts_dense(x: jax.Array, gate_w: jax.Array, up_w: jax.Array,
     return jnp.einsum("ne,end->nd", combine.astype(y.dtype), y)
 
 
+@jax.named_scope("moe_mlp")
 def moe_mlp(x: jax.Array, router_w: jax.Array, gate_w: jax.Array,
             up_w: jax.Array, down_w: jax.Array, top_k: int,
             norm_topk: bool = True,
@@ -243,10 +246,12 @@ def moe_mlp(x: jax.Array, router_w: jax.Array, gate_w: jax.Array,
                             gateup_w=gateup_w)
     if shared is not None:
         sh_gate, sh_up, sh_down, sh_router = shared
-        s = swiglu(x, sh_gate, sh_up, sh_down, "silu",
-                   gateup_w=shared_gateup)
-        sg = jax.nn.sigmoid((x @ sh_router).astype(jnp.float32))  # [N, 1]
-        out = out + sg.astype(out.dtype) * s
+        with jax.named_scope("shared_expert"):
+            s = swiglu(x, sh_gate, sh_up, sh_down, "silu",
+                       gateup_w=shared_gateup)
+            sg = jax.nn.sigmoid(
+                (x @ sh_router).astype(jnp.float32))             # [N, 1]
+            out = out + sg.astype(out.dtype) * s
     return out
 
 
@@ -503,8 +508,9 @@ def _run_layers(params: Params, kv: KVCache, x: jax.Array,
                                          mode="drop")
         # flat [L*NTOK, Cx] views (metadata-only reshape of the carry
         # buffers); readers address layer li at row offset li*NTOK
-        attn = attn_fn(q, k, v, kp.reshape(L * NTOK, kp.shape[2]),
-                       vp.reshape(L * NTOK, vp.shape[2]), li, sliding)
+        with jax.named_scope("attention"):
+            attn = attn_fn(q, k, v, kp.reshape(L * NTOK, kp.shape[2]),
+                           vp.reshape(L * NTOK, vp.shape[2]), li, sliding)
         attn_out = mm(attn.reshape(N, -1), lp["wo"])
         if reduce_axis is not None:   # row-parallel wo under shard_map tp
             attn_out = jax.lax.psum(attn_out, reduce_axis)
@@ -566,6 +572,7 @@ def _lm_head_kernel_ok(head: QuantizedArray,
     return _on_tpu()
 
 
+@jax.named_scope("lm_head")
 def _logits(params: Params, x: jax.Array,
             cfg: ModelConfig = None) -> jax.Array:
     head = params.get("lm_head")

@@ -135,5 +135,6 @@ def grouped_int4_matmul(x: jax.Array, packed: jax.Array, scale: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="quant_matmul_int4",
     )(xe, xo, packed, scale.astype(jnp.float32))
     return out[:N]

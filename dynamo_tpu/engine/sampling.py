@@ -49,6 +49,7 @@ def pack_sampling(slots: list) -> dict:
     }
 
 
+@jax.named_scope("sampling")
 def sample_tokens(logits: jax.Array, keys: jax.Array,
                   temperature: jax.Array, top_k: jax.Array,
                   top_p: jax.Array) -> tuple[jax.Array, jax.Array]:

@@ -80,7 +80,6 @@ class JaxEngine(AsyncEngine):
         async def stream() -> AsyncIterator[Annotated[BackendOutput]]:
             import asyncio
 
-            first = True
             emitted = 0
             while True:
                 # bounded receive (DL007): the engine contract is that
@@ -117,10 +116,6 @@ class JaxEngine(AsyncEngine):
                     return
                 token, logprob = item, payload
                 emitted += 1
-                if first:
-                    first = False
-                    if trace is not None:   # TTFT marker on the trace
-                        trace.event("engine.first_token")
                 yield Annotated.from_data(BackendOutput(
                     token_ids=[token], log_probs=[logprob],
                     cum_log_probs=None))

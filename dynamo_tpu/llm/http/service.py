@@ -23,7 +23,7 @@ from typing import Dict, Optional
 from aiohttp import web
 
 from ...runtime.engine import AsyncEngine, Context, EngineContext
-from ...runtime.tracing import Trace, span, use_trace
+from ...runtime.tracing import Trace, current_trace, span, use_trace
 from ..protocols.annotated import Annotated
 from ..protocols.openai import (aggregate_chat_stream,
                                 aggregate_completion_stream)
@@ -462,6 +462,10 @@ class HttpService:
 
         monitor_task = asyncio.create_task(monitor())
         first_chunk = True
+        # stream.first_write (the request's trace): the first token chunk
+        # has been written — with the engine's spans it tiles TTFT from
+        # inside the server (docs/observability.md)
+        trace = current_trace()
         try:
             async for ann in stream:
                 if not isinstance(ann, Annotated):
@@ -495,6 +499,9 @@ class HttpService:
                     guard.mark_cancelled()
                     ectx.kill()
                     return resp
+                if n_tok and trace is not None:
+                    trace.event("stream.first_write")
+                    trace = None
             if not ectx.is_killed:
                 try:
                     await resp.write(encode_done().encode())

@@ -248,3 +248,52 @@ def test_decode_kernel_compiles_per_tp_shard_on_four_chips(topo, one_chip):
                 sds(pool, dt, P(None, "tp")), sds(pool, dt, P(None, "tp")),
                 sds((B, M), jnp.int32, P()),
                 sds((B,), jnp.int32, P())).compile()
+
+
+@pytest.mark.parametrize("moe", [False, True], ids=["dense", "qwen2moe"])
+def test_engine_step_programs_carry_stable_names(one_chip, monkeypatch, moe):
+    """The engine's own prefill and decode programs (``_prefill_jit`` /
+    ``_decode_jit``, the ones the benchmark's cells serve) name their
+    parts with ``jax.named_scope`` and their Pallas calls with ``name=``,
+    so a trace reduction finds them whatever the compiler calls its
+    fusions. Narrow widths, kernel-supported geometry, two layers."""
+    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+    from dynamo_tpu.engine.core import EngineCore
+    from dynamo_tpu.engine.models import llama
+    # code that asks jax.devices() sees the CPU here
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    monkeypatch.setattr(llama, "_on_tpu", lambda: True)
+    cfg = ModelConfig(
+        vocab_size=2048, hidden_size=256, intermediate_size=512,
+        num_layers=2, num_heads=4, num_kv_heads=2, head_dim=64,
+        max_position_embeddings=1024,
+        **(dict(num_experts=4, num_experts_per_tok=2, moe_norm_topk=False,
+                shared_expert_size=512) if moe else {}))
+    B, M, T = 8, 16, 128
+    core = EngineCore(cfg, EngineConfig(
+        max_model_len=256, kv_block_size=16, num_kv_blocks=64,
+        max_num_seqs=B, prefill_buckets=[T]), attn_impl="pallas")
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, kv = jax.tree.map(lambda x: s(x.shape, x.dtype),
+                              (core.params, core.kv))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    i32, f32 = jnp.int32, jnp.float32
+    decode = core._decode_jit.lower(
+        params, kv, s((B,), i32), s((B,), i32), s((B, M), i32),
+        s((B,) + key.shape, key.dtype), s((B,), f32), s((B,), i32),
+        s((B,), f32)).compile().as_text()
+    prefill = core._prefill_jit.lower(
+        params, kv, s((T,), i32), s((M,), i32), s((), i32), s((), i32),
+        s(key.shape, key.dtype), s((), f32), s((), i32),
+        s((), f32)).compile().as_text()
+    mlp = (["moe_mlp/run_experts_dense", "moe_mlp/shared_expert/swiglu"]
+           if moe else ["swiglu"])
+    for text, top, kernel in ((decode, "decode", "paged_attention"),
+                              (prefill, "prefill", "flash_prefill")):
+        assert "tpu_custom_call" in text
+        for scope in [f"jit({top})/{top}/", "/lm_head/", "/sampling/",
+                      f"/attention/{kernel}/pallas_call"] + mlp:
+            assert scope in text, (top, scope)

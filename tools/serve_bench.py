@@ -4,9 +4,10 @@ reporting wall-clock throughput and TTFT/ITL percentiles — RAW and NET of
 the measured device→host fetch stalls.
 
 Why the decomposition: the engine MEASURES the wall time its synchronous
-fetches actually stall the loop (EngineCore.host_stall_s — an async copy
-that already landed, or a host-value "fetch", measures ~0 by
-construction, so nothing is modeled); this tool samples that clock at
+fetches actually stall the loop (EngineCore.host_stall_s, the running
+total of the loop's ``wait`` phase — an async copy that already landed,
+or a host-value "fetch", measures ~0 by construction, so nothing is
+modeled); this tool samples that clock at
 each request's submit / first-token / finish and subtracts the in-window
 delta — what the scheduler's own decisions cost. Raw numbers are printed
 beside it; nothing is hidden.
@@ -110,7 +111,7 @@ def main():
     async def run():
         # warm the compiles with one request end-to-end
         _ = await one(0)
-        rt_base, stall_base = core.host_roundtrips, core.host_stall_s
+        stall_base = core.host_stall_s
         t0 = time.monotonic()
         arrivals = np.cumsum(gaps)
         outs = await asyncio.gather(
@@ -133,7 +134,7 @@ def main():
               f"p95 {pct(ttfts_host, .95) * 1e3:.0f}ms | "
               f"ITL p50 {pct(itls_host, .5) * 1e3:.0f}ms "
               f"(net of {core.host_stall_s - stall_base:.1f}s measured "
-              f"stall over {core.host_roundtrips - rt_base} fetches)\n"
+              f"fetch stall)\n"
               f"  lane_admissions={core.lane_admissions} "
               f"prefill_tok={core.total_prefill_tokens}")
         if platform != "cpu":
@@ -149,7 +150,6 @@ def main():
                     "ttft_p50_raw_s": round(pct(ttfts, .5), 3),
                     "itl_p50_host_ms": round(pct(itls_host, .5) * 1e3, 1),
                     "rtt_ms": round(rtt * 1e3, 1),
-                    "host_roundtrips": core.host_roundtrips - rt_base,
                     "host_stall_s": round(
                         core.host_stall_s - stall_base, 2),
                     "n_requests": n_req, "slots": slots, "lanes": lanes,
