@@ -546,7 +546,7 @@ def run_kv_frag_bench(core, batch, blocks_per_seq, pos0, *,
                     toks_k, _lps, core.kv = core._decode_k_jit(
                         core.params, core.kv, tokens_in,
                         jnp.array(core._positions), tb, seeds, steps0,
-                        temp, topk, topp, planned, pmask)
+                        temp, topk, topp, planned, pmask, core._base_key)
                     core._positions[:] += K
                 np.asarray(toks_k)
                 return time.monotonic() - t0
@@ -1231,7 +1231,7 @@ def device_timing(core, mcfg, batch, pos0, *,
                 core.params, core.kv,
                 tokens_in, jnp.array(core._positions),
                 jnp.array(core._block_tables), seeds, steps0,
-                temp, topk, topp, planned, pmask)
+                temp, topk, topp, planned, pmask, core._base_key)
             core._positions[:] += K
         np.asarray(toks_k)                 # the one barrier fetch
         return time.monotonic() - t0
@@ -1503,7 +1503,7 @@ def main() -> None:
                 core.params, core.kv,
                 tokens_in, jnp.array(core._positions),
                 jnp.array(core._block_tables), seeds, steps0,
-                temp, topk, topp, planned, pmask)
+                temp, topk, topp, planned, pmask, core._base_key)
             core._positions[:] += harvest
             if pipeline:
                 # chain the next dispatch off device tokens; harvest the

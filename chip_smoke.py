@@ -494,7 +494,8 @@ async def one_chip(model_dir: str, cache: CacheWatch) -> None:
     # (…/pallas_call): their presence is the tpu_custom_call count
     await serve_phase("bf16", model_dir, [], True, {}, cache)
     # int8 weights: the fused LM-head kernel, now without a self-test to
-    # hide behind; K=8 so the K-step decode program serves as well
+    # hide behind; K=8 so the decode program serves with its scan as well
+    # as without (one step per dispatch, the phase above)
     await serve_phase(
         "int8", model_dir,
         ["--quantization", "int8", "--decode-steps-per-dispatch", "8"],

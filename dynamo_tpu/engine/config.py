@@ -586,12 +586,23 @@ class EngineConfig:
     # decode steps fused into one XLA dispatch (lax.scan): tokens are
     # harvested to the host once per dispatch, so the device→host fetch
     # is amortized K×. K>1 trades step-granular EOS/cancel reaction (worst
-    # case K-1 wasted steps per sequence) for throughput.
+    # case K-1 wasted steps per sequence) for throughput. At K = 1 (the
+    # default) the engine keeps one step in flight: step n+1 is launched
+    # off step n's on-device tokens before the loop fetches them, so the
+    # fetch, the bookkeeping and the event loop's turn run under a step's
+    # device time. A finish by max_tokens, context capacity or a cancel
+    # already seen is known ahead and wastes nothing; a finish by EOS or
+    # stop, or a cancel that arrives mid-step, is noticed one step later
+    # than it is sampled (one slot-row of one step is discarded; the
+    # client never sees it).
     decode_steps_per_dispatch: int = 1
-    # defer each K-dispatch's harvest one dispatch: the next batch chains
-    # off on-device tokens while the previous results copy to the host —
-    # steady-state cost max(fetch, compute) instead of fetch+compute.
-    # Finish/cancel reaction widens to ≤2K-1 steps. Requires K > 1.
+    # K > 1 and ragged dispatch: defer each dispatch's harvest one
+    # dispatch, as K = 1 always does: the next batch chains off on-device
+    # tokens while the previous results copy to the host — steady-state
+    # cost max(fetch, compute) instead of fetch+compute. Finish/cancel
+    # reaction widens to ≤2K-1 steps. At K > 1 any change of the
+    # slot→request map drains the pipeline (K = 1 chains per slot).
+    # Redundant at K = 1 without ragged dispatch.
     # Note on exactness: under RECOMPUTE PREEMPTION (any dispatch mode,
     # pipelined or not) a stream is bit-exact vs an uncontended run only up
     # to its first preemption point — the re-admission prefill's f32
@@ -746,14 +757,6 @@ class EngineConfig:
                     "pp with weight/KV quantization is not implemented "
                     "(QuantizedArray leaves under the stage shard_map "
                     "are unvalidated)")
-        if (self.decode_dispatch_pipeline
-                and self.decode_steps_per_dispatch <= 1
-                and not self.ragged_dispatch):
-            raise ValueError(
-                "decode_dispatch_pipeline requires decode_steps_per_dispatch"
-                " > 1 (the pipeline defers multi-step harvests) — except "
-                "under ragged_dispatch, whose single-step dispatches "
-                "pipeline via the chained-sample merge")
         if self.spec_k < 0:
             raise ValueError("spec_k must be >= 0 (0 disables speculation)")
         if not 0.0 <= self.kv_defrag_threshold <= 1.0:

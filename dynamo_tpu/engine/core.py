@@ -41,6 +41,15 @@ from .sampling import SlotSampling, make_slot_keys, sample_tokens
 logger = logging.getLogger("dynamo_tpu.engine")
 
 
+def _owned(a: np.ndarray) -> jax.Array:
+    """A device array from a numpy copy that nothing else holds: a
+    transfer may read its host buffer after the call returns (jnp.array of
+    an ndarray does not copy it first), and the engine's host mirrors are
+    mutated by the next iteration while a dispatch built from them is
+    still queued behind the one in flight."""
+    return jnp.asarray(a.copy())
+
+
 @dataclasses.dataclass
 class EngineRequest:
     """One sequence's engine-side state."""
@@ -425,6 +434,8 @@ class EngineCore:
             "top_p": np.ones((self.B,), np.float32),
         }
         self._seeds = np.zeros((self.B,), np.int64)
+        # "no slot": the default of the per-slot flags a dispatch takes
+        self._no_slot = np.zeros((self.B,), dtype=bool)
         # speculative decoding (engine/spec/): host-side drafter + the
         # live draft budget (llmctl spec set-k moves it within
         # [0, cfg.spec_k]; the verify program's shape is compiled at
@@ -437,11 +448,16 @@ class EngineCore:
                 max_ngram=engine_cfg.spec_ngram_max,
                 min_ngram=engine_cfg.spec_ngram_min,
                 window=engine_cfg.spec_window)
+        # the engine seed's key, an argument of the decode program
+        self._base_key = jax.random.PRNGKey(engine_cfg.seed)
         self._compile_jits()
         # serving stats
         self.total_prefill_tokens = 0
         self.total_decode_tokens = 0
         self.preemptions = 0
+        # deferred-harvest decode: times the in-flight dispatch was
+        # harvested with no successor queued behind it, by cause (_drain)
+        self.pipeline_drains: Dict[str, int] = {}
         self.lane_admissions = 0
         self.host_onboards = 0
         # contiguity-aware layout (docs/kv_layout.md): defrag passes run
@@ -557,7 +573,6 @@ class EngineCore:
         statics = self.statics
         mesh = self.mesh
         K = self.cfg.decode_steps_per_dispatch
-        seed = self.cfg.seed
 
         def prefill(params, kv, tokens, block_table, start_pos, true_len,
                     key, temperature, top_k, top_p):
@@ -574,18 +589,18 @@ class EngineCore:
 
         def decode_k(params, kv, tokens, positions, block_tables,
                      seeds, steps0, temperature, top_k, top_p,
-                     planned, planned_mask):
+                     planned, planned_mask, base_key):
             return pp_decode_k_forward(
                 params, kv, tokens, positions, block_tables, seeds,
                 steps0, temperature, top_k, top_p, planned,
-                planned_mask, statics, mesh, K, seed)
+                planned_mask, statics, mesh, K, base_key)
 
         self._decode_k_jit = jax.jit(decode_k, donate_argnums=(1,))
         self._planned_zero = (jnp.zeros((K, self.cfg.max_num_seqs),
                                         jnp.int32),
                               jnp.zeros((K, self.cfg.max_num_seqs), bool))
         self._merge_jit = jax.jit(
-            lambda dev, host, mask: jnp.where(mask, dev, host))
+            lambda dev_k, host, mask: jnp.where(mask, dev_k[-1], host))
         self._verify_jit = None
         self._ragged_jit = None   # EngineConfig refuses ragged + pp
         self._ragged_row_sampled = False
@@ -629,15 +644,22 @@ class EngineCore:
         self._decode_jit = jax.jit(jax.named_scope("decode")(decode),
                                    donate_argnums=(1,))
 
-        # K decode steps fused into one dispatch (EngineConfig
-        # decode_steps_per_dispatch): the sampled token feeds the next step
-        # ON DEVICE, and the host harvests [K, B] tokens once per dispatch.
+        # The served decode program: K steps fused into one dispatch
+        # (EngineConfig decode_steps_per_dispatch), K = 1 included. The
+        # per-slot keys are derived inside it from (seeds, steps) and the
+        # engine seed's key — an argument (_base_key), so that one
+        # compiled program serves every seed — the sampled token feeds
+        # the next step ON DEVICE, and the host
+        # harvests [K, B] tokens once per dispatch. _decode_jit above is
+        # the same step with host-made keys, for callers that drive one
+        # step by hand (bench.py, benchmark/selftest.py); the loop never
+        # calls it, so it costs no compile.
         K = self.cfg.decode_steps_per_dispatch
         seed = self.cfg.seed
 
         def decode_k(params, kv, tokens, positions, block_tables,
                      seeds, steps0, temperature, top_k, top_p,
-                     planned, planned_mask):
+                     planned, planned_mask, base_key):
             params = unpack_params(params)
             # planned [K, B] / planned_mask [K, B]: lane-prefill slots feed
             # predetermined prompt tokens per step instead of chaining the
@@ -646,7 +668,7 @@ class EngineCore:
             # happens on device, mid-scan.
             def body(carry, xs):
                 kv, toks, pos = carry
-                keys = make_slot_keys(seed, seeds, steps0 + xs["k"])
+                keys = make_slot_keys(base_key, seeds, steps0 + xs["k"])
                 tok_in = jnp.where(xs["pm"], xs["pt"], toks)
                 logits, kv = self.model_mod.decode_forward(
                     params, kv, tok_in, pos, block_tables, statics)
@@ -654,22 +676,29 @@ class EngineCore:
                                                 top_k, top_p)
                 return (kv, toks2, pos + 1), (toks2, logprobs)
 
+            if K == 1:
+                # one step needs no loop around it
+                (kv, _, _), (toks, logprobs) = body(
+                    (kv, tokens, positions),
+                    {"k": 0, "pt": planned[0], "pm": planned_mask[0]})
+                return toks[None], logprobs[None], kv
             (kv, _, _), (toks_k, logprobs_k) = jax.lax.scan(
                 body, (kv, tokens, positions),
                 {"k": jnp.arange(K), "pt": planned, "pm": planned_mask})
             return toks_k, logprobs_k, kv
 
-        self._decode_k_jit = (jax.jit(decode_k, donate_argnums=(1,))
-                              if K > 1 else None)
+        self._decode_k_jit = jax.jit(jax.named_scope("decode")(decode_k),
+                                     donate_argnums=(1,))
         # device-resident zeros reused by every dispatch with no active
         # lane (the overwhelmingly common case)
         self._planned_zero = (jnp.zeros((K, self.cfg.max_num_seqs),
                                         jnp.int32),
                               jnp.zeros((K, self.cfg.max_num_seqs), bool))
-        # pipelined-dispatch input merge: continuing slots chain the
-        # previous dispatch's device tokens, fresh slots feed host values
+        # deferred-harvest input merge: slots that continue from the
+        # in-flight dispatch chain its last device tokens ([K, B] → row
+        # K-1), every other slot feeds its host value
         self._merge_jit = jax.jit(
-            lambda dev, host, mask: jnp.where(mask, dev, host))
+            lambda dev_k, host, mask: jnp.where(mask, dev_k[-1], host))
 
         # unified ragged dispatch (engine/ragged.py +
         # docs/ragged_attention.md): ONE program serves a flat
@@ -827,7 +856,23 @@ class EngineCore:
                 self._run_loop(), name="engine-core-loop")
             self.flight.start_lag_probe()
 
+    @property
+    def running(self) -> bool:
+        """The engine loop is a live task (of the event loop that made
+        the first request)."""
+        return self._loop_task is not None and not self._loop_task.done()
+
     async def stop(self) -> None:
+        if self._stopping and self._loop_task is None:
+            # stopped already, maybe on another event loop (launch/run.py
+            # run_http stops the engine on the loop that served it, and
+            # the launcher's own stop comes after): the tiers are flushed
+            # and closed, and nothing of this engine is on this loop.
+            # Still one turn of the caller's loop, as every stop takes:
+            # what the loop's running callback held of a cancelled
+            # run_http task (its traceback, hence the engine) goes with it
+            await asyncio.sleep(0)
+            return
         self._stopping = True
         self.flight.stop_lag_probe()
         self._work_event.set()
@@ -873,9 +918,8 @@ class EngineCore:
                     self.remote_store.unpin(plan.remote_hashes)
                 self._finish_request(req, FinishReason.CANCELLED)
             self._onboards = []
-        if self._pending is not None:     # drain the pipelined dispatch
-            self._harvest(self._pending)
-            self._pending = None
+        if self._pending is not None:     # drain the in-flight dispatch
+            self._drain("stop")
         if self._ragged_pending is not None:  # the ragged form of same
             prev, self._ragged_pending = self._ragged_pending, None
             self._harvest_ragged(prev)
@@ -1366,9 +1410,13 @@ class EngineCore:
             clock.enter("sweep")
             # 0) opportunistic KV compaction: only when no admission is
             # queued and no dispatch is un-harvested (the pass inserts
-            # one small device copy ahead of the next decode dispatch)
-            if (self.waiting.empty() and self._pending is None
-                    and self._ragged_pending is None):
+            # one small device copy ahead of the next decode dispatch).
+            # The one-step path always has a step in flight while it
+            # decodes: the pass looks anyway and, if it finds a move,
+            # harvests that step first
+            if (self.waiting.empty() and self._ragged_pending is None
+                    and (self._pending is None
+                         or self._pending["K"] == 1)):
                 self._maybe_defrag()
             # 0.5) cancellation/deadline sweep: vacate slots and purge
             # the waiting queue for requests whose client stopped caring
@@ -1401,8 +1449,7 @@ class EngineCore:
                 # all requests finished mid-harvest with a chained dispatch
                 # still in flight: drain it so the dead requests and device
                 # buffers don't sit retained across an idle period
-                self._harvest(self._pending)
-                self._pending = None
+                self._drain("idle")
                 progressed = True
             elif self._ragged_pending is not None:
                 # same drain for a pipelined ragged dispatch
@@ -1442,7 +1489,8 @@ class EngineCore:
         UNINIT free space only (never evicts cached prefixes), and the
         pass is skipped while a replay recorder is attached (the copy
         is a device program the follower/replay streams don't carry).
-        Rate-limited to one pass per 64 decode steps."""
+        Rate-limited to one pass per 64 decode steps. Called with at most
+        one step in flight, which is harvested before anything moves."""
         cfg = self.cfg
         if (not cfg.kv_contig_alloc or cfg.kv_defrag_threshold <= 0
                 or self.recorder is not None
@@ -1473,6 +1521,13 @@ class EngineCore:
         if best is None or pool.free_uninit_blocks < len(best[4]):
             return False
         runs, _seq_frag, slot, j, old = best
+        if self._pending is not None:
+            # nothing in flight while blocks move: harvest the one step
+            req = self.slots[slot]
+            self._drain("defrag")
+            self.clock.enter("sweep")
+            if self.slots[slot] is not req:
+                return False        # it finished with that token
         new = pool.alloc_uninit(len(old))
         if new is None:
             return False
@@ -2675,13 +2730,17 @@ class EngineCore:
         self._release_slot(req)
         self._finish_request(req, FinishReason.LENGTH)
 
-    def _tables_for_dispatch(self) -> np.ndarray:
-        """Block tables a dispatch should see: non-ready admissions keep
-        their mirror row (written at admission) but the DISPATCH aims them
-        at the trash block — copy-on-write so the mirror survives."""
+    def _tables_for_dispatch(self, sit_out=None) -> np.ndarray:
+        """Block tables a dispatch should see: slots that hold a request
+        but take no part in it — non-ready admissions, and ``sit_out``
+        slots whose in-flight token is their last — keep their mirror row
+        but the DISPATCH aims them at the trash block — copy-on-write so
+        the mirror survives."""
         tables = self._block_tables
+        if sit_out is None:
+            sit_out = self._no_slot
         for i, s in enumerate(self.slots):
-            if s is not None and not s.ready:
+            if s is not None and (not s.ready or sit_out[i]):
                 if tables is self._block_tables:
                     tables = self._block_tables.copy()
                 tables[i, :] = 0
@@ -2696,13 +2755,12 @@ class EngineCore:
             self._ragged_step()
             return
         if self._verify_jit is not None and self._spec_candidates():
-            # speculation drafts from HARVESTED state, so the pipelined
+            # speculation drafts from HARVESTED state, so the in-flight
             # dispatch (if any) must drain first; spec mode therefore
             # forfeits the harvest/compute overlap — the multi-token
             # emission per dispatch is the bigger lever when drafts land
             if self._pending is not None:
-                prev, self._pending = self._pending, None
-                self._harvest(prev)
+                self._drain("spec")
                 if not any(s is not None and s.ready for s in self.slots):
                     return
                 self.clock.enter("build")
@@ -2710,90 +2768,17 @@ class EngineCore:
                 return
             # drafter came up dry everywhere: plain decode this step
             # (the k=0 degeneracy — speculation costs nothing when idle)
-        if self._decode_k_jit is not None:
-            self._decode_step_multi(self.cfg.decode_steps_per_dispatch)
-            return
-        active_idx = [i for i, s in enumerate(self.slots)
-                      if s is not None and s.ready]
-        steps = np.zeros((self.B,), np.int64)
-        for i in range(self.B):
-            s = self.slots[i]
-            if s is None or not s.ready:
-                self._tokens[i] = 0
-                self._positions[i] = 0
-                if s is None:
-                    self._block_tables[i, :] = 0  # trash block
-            else:
-                self._tokens[i] = s.last_token
-                self._positions[i] = s.pos
-                steps[i] = s.key_step
-        tables = self._tables_for_dispatch()
-        self._step += 1
-        keys = make_slot_keys(self.cfg.seed, jnp.asarray(self._seeds),
-                              jnp.asarray(steps))
-        args = (jnp.asarray(self._tokens), jnp.asarray(self._positions),
-                jnp.asarray(tables), keys,
-                jnp.asarray(self._samp["temperature"]),
-                jnp.asarray(self._samp["top_k"]),
-                jnp.asarray(self._samp["top_p"]))
-        self.clock.enter("dispatch")
-        toks, logprobs, self.kv = self._decode_jit(
-            self.params, self.kv, *args)
-        self.clock.enter("wait")
-        toks = np.asarray(toks)
-        logprobs = np.asarray(logprobs)
-        self.clock.enter("post")
-        bs = self.cfg.kv_block_size
-        for i in active_idx:
-            req = self.slots[i]
-            if req is None:
-                continue
-            if req.cancelled:
-                self._release_slot(req)
-                self._finish_request(req, FinishReason.CANCELLED)
-                continue
-            tok = int(toks[i])
-            # the step wrote the *input* token's KV into the cache — its
-            # block may now be full and registrable for prefix reuse
-            if req.seq is not None:
-                req.seq.append(int(self._tokens[i]))
-                req.registered_blocks = self.kv_manager.register_full_blocks(
-                    req.blocks, req.seq, req.registered_blocks,
-                    tenant=req.tenant or None)
-            req.pos += 1
-            req.generated += 1
-            req.key_step += 1
-            req.last_token = tok
-            self.total_decode_tokens += 1
-            # grow block table if the *next* token would start a new block
-            if (req.pos + 1) > len(req.blocks) * bs:
-                if len(req.blocks) >= self.M:       # context capacity
-                    self._emit(req, tok, float(logprobs[i]))
-                    self._release_slot(req)
-                    self._finish_request(req, FinishReason.LENGTH)
-                    continue
-                new = self.kv_manager.pool.alloc_uninit(1)
-                if new is None:
-                    # out of KV memory: the sampled token is still valid
-                    # (its input's KV was written) — emit it, then finish
-                    # if it was terminal anyway (EOS / budget / cancel),
-                    # else preempt
-                    self._emit(req, tok, float(logprobs[i]))
-                    if (req.last_token in req.eos_ids
-                            or req.generated >= req.max_new_tokens
-                            or req.cancelled):
-                        self._maybe_finish_after_emit(req)
-                    else:
-                        self._preempt_or_finish(req)
-                    continue
-                req.blocks.extend(new)
-                self._block_tables[i, len(req.blocks) - 1] = new[0]
-            self._emit(req, tok, float(logprobs[i]))
-            self._maybe_finish_after_emit(req)
-        self.flight.record_cycle(
-            "decode", K=1, batch_fill=len(active_idx),
-            planned_tokens=len(active_idx),
-            emitted=len(active_idx))
+        self._decode_step_multi(self.cfg.decode_steps_per_dispatch)
+
+    def _drain(self, cause: str) -> None:
+        """Harvest the in-flight dispatch with no successor launched
+        behind it: the device idles until the next fresh dispatch. Counted
+        by cause (``pipeline_drains``) and marked on the harvest's flight
+        record."""
+        self.pipeline_drains[cause] = self.pipeline_drains.get(cause, 0) + 1
+        prev, self._pending = self._pending, None
+        prev["drain"] = cause
+        self._harvest(prev)
 
     def _decode_step_multi(self, K: int) -> None:
         """K fused decode steps, one dispatch, one host harvest: sampled
@@ -2803,44 +2788,65 @@ class EngineCore:
         applied at harvest: device steps past a finish are discarded (the
         documented K-1-steps-of-waste trade, EngineConfig).
 
-        With ``decode_dispatch_pipeline`` the harvest is deferred one
-        dispatch: the next K-batch launches chained off the previous
-        dispatch's ON-DEVICE tokens, so the device→host copy overlaps the
-        next dispatch's compute — steady state max(fetch, compute)
-        instead of their sum. Finish reaction widens to ≤2K-1 steps."""
+        The harvest is deferred one dispatch — always at K = 1, with
+        ``decode_dispatch_pipeline`` at K > 1: the next dispatch launches
+        chained off the in-flight one's ON-DEVICE tokens before the loop
+        fetches them, so the fetch, the bookkeeping, the admissions'
+        completion and the event loop's turn all run under a step's
+        device time — steady state max(device, host) instead of their
+        sum. Finish reaction widens by one dispatch (≤2K-1 steps). A
+        replay recorder at K = 1 sees every step harvested before the
+        next is built (the followers' stream was validated for K > 1
+        only)."""
         if self._pending is not None:
-            nxt = self._dispatch_pipelined(K)
-            prev, self._pending = self._pending, None
-            self._harvest(prev)
+            nxt, cause = self._dispatch_pipelined(K)
             if nxt is not None:
-                self._pending = nxt
+                prev, self._pending = self._pending, nxt
+                self._harvest(prev)
                 return
-            # couldn't chain (slot churn / growth failure): fall through to
-            # a fresh host-fed dispatch against the harvested state
+            # nothing could be launched ahead (K > 1: slot churn; growth
+            # that needs harvested state; every in-flight token a last
+            # one): harvest, then a fresh host-fed dispatch against the
+            # harvested state
+            self._drain(cause)
             self.clock.enter("build")
         if not self._prepare_multi(K):
             return
         pending = self._dispatch_multi(K)
-        if self.cfg.decode_dispatch_pipeline:
+        if (self.cfg.decode_dispatch_pipeline if K > 1
+                else self.recorder is None):
             self._pending = pending
         else:
             self._harvest(pending)
 
-    def _prepare_multi(self, K: int, ahead_mask=None) -> bool:
+    def _prepare_multi(self, K: int, ahead_mask=None,
+                       sit_out=None) -> bool:
         """Capacity check + block-table pre-grow for the next K steps.
         ``ahead_mask`` flags slots whose request has K un-harvested steps
-        already in flight (pipelined dispatch). Returns False when nothing
-        is left to decode — or, with a mask, when the pipeline must drain
-        before growth/finish decisions can be made safely (note: blocks
-        already grown for earlier slots in the pass stay attached; they
-        remain owned by their requests either way)."""
+        already in flight (deferred harvest); ``sit_out`` flags slots the
+        coming dispatch leaves out. Returns False when nothing is left to
+        decode — or, with a mask, when the pipeline must drain before
+        growth/finish decisions can be made safely (note: blocks already
+        grown for earlier slots in the pass stay attached; they remain
+        owned by their requests either way).
+
+        One step grows exactly the block its write lands in (what the
+        one-step path has always done, after each token instead of before
+        it; a full context finishes at its harvest). K > 1 keeps a
+        token of headroom beyond its K writes and finishes a sequence
+        that close to its capacity before the dispatch."""
         capacity = self.M * self.cfg.kv_block_size
+        reach = K + 1 if K > 1 else 1
+        if ahead_mask is None:
+            ahead_mask = self._no_slot
+        if sit_out is None:
+            sit_out = self._no_slot
         for i, s in enumerate(self.slots):
-            if s is None or not s.ready:
+            if s is None or not s.ready or sit_out[i]:
                 continue
-            in_flight = bool(ahead_mask is not None and ahead_mask[i])
+            in_flight = bool(ahead_mask[i])
             pos_eff = s.pos + (K if in_flight else 0)
-            if pos_eff + K + 1 > capacity:
+            if pos_eff + reach > capacity:
                 # within K tokens of the context capacity: finish now
                 # rather than let the scan write past the block table
                 # (bounded early stop, same K-granularity trade as EOS)
@@ -2849,7 +2855,7 @@ class EngineCore:
                 self._release_slot(s)
                 self._finish_request(s, FinishReason.LENGTH)
                 continue
-            need = self._blocks_needed(pos_eff + K + 1)
+            need = self._blocks_needed(pos_eff + reach)
             if need > len(s.blocks):
                 new = self.kv_manager.pool.alloc_uninit(need - len(s.blocks))
                 if new is None:
@@ -2864,61 +2870,85 @@ class EngineCore:
                 self._block_tables[i, :len(s.blocks)] = s.blocks
         return any(s is not None and s.ready for s in self.slots)
 
-    def _dispatch_pipelined(self, K: int):
-        """Steady-state pipelined dispatch: chain off the in-flight batch's
-        device tokens. Returns the new pending record, or None when the
-        pipeline must drain first.
+    def _dispatch_pipelined(self, K: int) -> tuple:
+        """Steady-state dispatch behind an un-harvested one: chain off the
+        in-flight batch's device tokens. Returns (the new pending record,
+        None), or (None, why) when the pipeline must drain first.
 
-        Chaining requires the slot→request mapping to be IDENTICAL to the
-        in-flight dispatch's: any churn (admission, finish, preemption,
-        re-admission) drains the pipeline and restarts it from harvested
-        host state. Stable decode phases — where the overlap matters — pay
-        nothing; churn costs one un-overlapped dispatch."""
+        At one step per dispatch chaining is per slot: a slot whose
+        request is the one in flight takes its input token from the
+        device and runs one position and key step ahead of harvested
+        host state; a newly ready admission feeds its host-known first
+        token; an emptied slot aims at the trash block. A slot whose
+        in-flight token is known to be its last (token budget, context
+        capacity, a cancel already seen) sits the dispatch out, so such
+        a finish wastes nothing; a finish by EOS or stop discards one
+        slot-row at its harvest.
+
+        K > 1 chains all or nothing: the slot→request mapping must be
+        IDENTICAL to the in-flight dispatch's, and any churn (admission,
+        finish, preemption, re-admission) drains the pipeline and
+        restarts it from harvested host state."""
         prev = self._pending
-        if prev["K"] != K:
-            return None
         now = [s if (s is not None and s.ready) else None
                for s in self.slots]
-        if any(now[i] is not prev["reqs"][i] for i in range(self.B)):
-            return None
-        mask = np.array([s is not None for s in now], dtype=bool)
-        if not mask.any():
-            return None
-        if not self._prepare_multi(K, ahead_mask=mask):
-            return None
-        return self._dispatch_multi(K, chain=prev["toks"][-1], mask=mask,
-                                    chained_from=prev.get("id"))
+        same = [s is r for s, r in zip(now, prev["reqs"])]
+        mask = np.array([s is not None and m for s, m in zip(now, same)],
+                        dtype=bool)
+        sit_out = self._no_slot
+        if K > 1:
+            if prev["K"] != K or not all(same):
+                return None, "slot_churn"
+        else:
+            full = self.M * self.cfg.kv_block_size - 1
+            sit_out = np.array(
+                [bool(m) and (s.generated + 1 >= s.max_new_tokens
+                              or s.pos >= full or s.cancelled)
+                 for m, s in zip(mask, now)], dtype=bool)
+            mask &= ~sit_out
+            if not any(s is not None and not o
+                       for s, o in zip(now, sit_out)):
+                return None, "last_token"
+        if not self._prepare_multi(K, ahead_mask=mask, sit_out=sit_out):
+            return None, "kv_growth"
+        return self._dispatch_multi(K, chain=prev["toks"], mask=mask,
+                                    sit_out=sit_out,
+                                    chained_from=prev.get("id")), None
 
     def _dispatch_multi(self, K: int, chain=None, mask=None,
-                        chained_from=None) -> dict:
-        """Launch one K-step scan. ``mask`` flags slots chained off the
-        in-flight dispatch: their input token comes from ``chain`` (device)
-        and their positions/keys run K steps ahead of harvested host
-        state; everything else feeds host-known last_tokens."""
+                        sit_out=None, chained_from=None) -> dict:
+        """Launch one K-step dispatch. ``mask`` flags slots chained off
+        the in-flight dispatch: their input token comes from ``chain``
+        (its [K, B] device tokens) and their positions/keys run K steps
+        ahead of harvested host state; everything else feeds host-known
+        last_tokens, except ``sit_out`` slots, which take no part."""
         if mask is None:
-            mask = np.zeros((self.B,), dtype=bool)
+            mask = self._no_slot
+        if sit_out is None:
+            sit_out = self._no_slot
+        riders = [s if (s is not None and s.ready and not sit_out[i])
+                  else None for i, s in enumerate(self.slots)]
         steps = np.zeros((self.B,), np.int64)
-        for i in range(self.B):
-            s = self.slots[i]
+        for i, s in enumerate(riders):
             ahead = K if mask[i] else 0
-            if s is None or not s.ready:
+            if s is None:
                 self._tokens[i] = 0
                 self._positions[i] = 0
-                if s is None:
+                if self.slots[i] is None:
                     self._block_tables[i, :] = 0  # trash block
             else:
                 self._tokens[i] = s.last_token
                 self._positions[i] = s.pos + ahead
                 steps[i] = s.key_step + ahead
-        tables = self._tables_for_dispatch()
+        tables = self._tables_for_dispatch(sit_out)
         # lane-prefill planned inputs: stateless from positions (which
         # already include the pipelined +K lookahead), so chained and
         # host-fed dispatches agree without extra bookkeeping. The common
         # no-lanes case reuses cached device-resident zeros (no per-dispatch
         # host allocation/transfer on the latency-sensitive path).
         planned = pmask = None
-        for i, s in enumerate(self.slots):
-            if s is None or not s.ready or s.lane_prompt is None:
+        for i, s in enumerate(riders):
+            if s is None or s.lane_prompt is None:
                 continue
             if planned is None:
                 planned = np.zeros((K, self.B), np.int32)
@@ -2931,13 +2961,8 @@ class EngineCore:
                     planned[k, i] = s.lane_prompt[p]
                     pmask[k, i] = True
         self._step += K
-        # jnp.array COPIES: jnp.asarray of a numpy buffer may alias it
-        # zero-copy on CPU, and these mirrors are mutated by the next
-        # iteration while a deferred-harvest dispatch may still be
-        # executing — the single-step path never sees this because its
-        # harvest blocks before any mutation
-        host_tokens = jnp.array(self._tokens)
-        tokens_in = (self._merge_jit(chain, host_tokens, jnp.array(mask))
+        host_tokens = _owned(self._tokens)
+        tokens_in = (self._merge_jit(chain, host_tokens, jnp.asarray(mask))
                      if chain is not None else host_tokens)
         did = None
         if self.recorder is not None:
@@ -2954,26 +2979,22 @@ class EngineCore:
                 **({"planned": planned.copy(),
                     "planned_mask": pmask.copy()}
                    if planned is not None else {}),
-                reqs=[s.rid if (s is not None and s.ready) else None
-                      for s in self.slots])
+                reqs=[s.rid if s is not None else None for s in riders])
         if planned is None:
             planned_dev, pmask_dev = self._planned_zero
         else:
-            planned_dev, pmask_dev = jnp.array(planned), jnp.array(pmask)
-        args = (tokens_in, jnp.array(self._positions),
-                jnp.array(tables),
-                jnp.array(self._seeds), jnp.array(steps),
-                jnp.array(self._samp["temperature"]),
-                jnp.array(self._samp["top_k"]),
-                jnp.array(self._samp["top_p"]),
-                planned_dev, pmask_dev)
+            planned_dev, pmask_dev = jnp.asarray(planned), jnp.asarray(pmask)
+        args = (tokens_in, _owned(self._positions), _owned(tables),
+                _owned(self._seeds), jnp.asarray(steps),
+                _owned(self._samp["temperature"]),
+                _owned(self._samp["top_k"]), _owned(self._samp["top_p"]),
+                planned_dev, pmask_dev, self._base_key)
         self.clock.enter("dispatch")
         toks_k, logprobs_k, self.kv = self._decode_k_jit(
             self.params, self.kv, *args)
         self.clock.enter("build")
         return {"toks": toks_k, "logprobs": logprobs_k, "K": K, "id": did,
-                "reqs": [s if (s is not None and s.ready) else None
-                         for s in self.slots]}
+                "reqs": riders, "mask": mask}
 
     def _harvest(self, pending: dict) -> None:
         """Apply one dispatch's results: emissions, seq bookkeeping,
@@ -2988,11 +3009,11 @@ class EngineCore:
         logprobs_k = np.asarray(pending["logprobs"])
         self.clock.enter("post")
         K = pending["K"]
+        capacity = self.M * self.cfg.kv_block_size
         applied = []
         for i, req in enumerate(pending["reqs"]):
             if req is None or self.slots[i] is not req:
                 continue
-            n0 = req.generated
             n_applied = 0
             input_tok = req.last_token
             for k in range(K):
@@ -3027,7 +3048,14 @@ class EngineCore:
                 self.total_decode_tokens += 1
                 self._mark_first_token(req)
                 self._emit(req, tok, float(logprobs_k[k, i]))
-                self._maybe_finish_after_emit(req)
+                if req.pos >= capacity:
+                    # the context is full: no position left to write the
+                    # next input's KV (one step per dispatch only; K > 1
+                    # stops short of it in _prepare_multi)
+                    self._release_slot(req)
+                    self._finish_request(req, FinishReason.LENGTH)
+                else:
+                    self._maybe_finish_after_emit(req)
                 if self.slots[i] is not req:
                     break                      # finished: drop device overrun
                 input_tok = tok
@@ -3039,12 +3067,17 @@ class EngineCore:
         # the cycle's wait phase (what the loop actually blocked on the
         # device); host_gap_ms is everything since the last cycle ended
         # that was NOT that wait, and the <phase>_ms fields say what:
-        # admission, input build, dispatch, bookkeeping, the event loop
+        # admission, input build, dispatch, bookkeeping, the event loop.
+        # chained: of batch_fill, the slots this dispatch fed from the
+        # device behind an un-harvested one; drain: why no successor was
+        # launched behind it, where none was
         self.flight.record_cycle(
             "decode", K=K,
             batch_fill=len(applied),
+            chained=sum(1 for i, _r, _n in applied if pending["mask"][i]),
             planned_tokens=K * len(applied),
-            emitted=sum(n for _i, _r, n in applied))
+            emitted=sum(n for _i, _r, n in applied),
+            **({"drain": pending["drain"]} if "drain" in pending else {}))
 
     # --------------------------------------------------------------- ragged
     def _ragged_step(self) -> None:

@@ -169,13 +169,15 @@ class DispatchStreamLeader(Recorder):
         """Validate the engine is in a configuration whose EVERY device
         program flows through the recorder stream, then become its
         recorder. A program the follower never hears about deadlocks the
-        first cross-host collective (the single-step `_decode_jit` path
-        taught us this the hard way — it is unrecorded by design)."""
-        if core._decode_k_jit is None:
+        first cross-host collective (the unrecorded single-step path of
+        old taught us this the hard way). One step per dispatch is in the
+        stream now, harvested before the next is built while a recorder
+        is attached, but stays refused until a follower has replayed it."""
+        if core.cfg.decode_steps_per_dispatch <= 1:
             raise ValueError(
                 "multihost serving requires decode_steps_per_dispatch > 1 "
-                "(the single-step decode path is not in the dispatch "
-                "stream)")
+                "(no follower has replayed the one-step path's per-slot "
+                "chained dispatches)")
         pool = core.kv_manager.host_pool
         if pool is not None and len(pool) > 0:
             # followers mirror only post-attach stores; a pre-attach

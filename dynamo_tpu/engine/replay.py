@@ -224,7 +224,7 @@ def exec_dispatch_event(core, kv, ev: dict, chain):
     host_tokens = jnp.array(np.asarray(ev["tokens"]))
     if ev["chained_from"] is not None:
         tokens_in = core._merge_jit(
-            chain[-1], host_tokens, jnp.array(np.asarray(ev["mask"])))
+            chain, host_tokens, jnp.array(np.asarray(ev["mask"])))
     else:
         tokens_in = host_tokens
     K = int(ev["K"])
@@ -237,7 +237,7 @@ def exec_dispatch_event(core, kv, ev: dict, chain):
         jnp.array(ev["seeds"]), jnp.array(ev["steps"]),
         jnp.array(ev["temperature"]), jnp.array(ev["top_k"]),
         jnp.array(ev["top_p"]),
-        jnp.array(planned), jnp.array(pmask))
+        jnp.array(planned), jnp.array(pmask), core._base_key)
     return toks_k, kv
 
 

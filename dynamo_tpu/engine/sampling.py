@@ -97,13 +97,17 @@ def sample_tokens(logits: jax.Array, keys: jax.Array,
     return tok, chosen_logprob
 
 
-def make_slot_keys(base_seed: int, slot_seeds: jax.Array,
+def make_slot_keys(base_seed, slot_seeds: jax.Array,
                    steps: jax.Array) -> jax.Array:
     """Deterministic per-(request-seed, request-step) PRNG keys: a request
     with an explicit seed reproduces its stream regardless of which slot it
     lands in or what else is batched with it. `steps` is each slot's OWN
-    generated-token count (not a global counter)."""
-    base = jax.random.PRNGKey(base_seed)
+    generated-token count (not a global counter). ``base_seed`` is the
+    engine's seed or the key already made from it (``PRNGKey(seed)``): a
+    program that takes the key as an argument serves every seed, one that
+    closes over the number is compiled anew for each."""
+    base = (jax.random.PRNGKey(base_seed) if jnp.ndim(base_seed) == 0
+            else base_seed)
     steps = jnp.broadcast_to(jnp.asarray(steps), slot_seeds.shape)
 
     def mk(seed, step):

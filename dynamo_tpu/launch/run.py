@@ -89,9 +89,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "nvext.speculation, live retune via llmctl "
                         "spec set-k)")
     p.add_argument("--decode-dispatch-pipeline", action="store_true",
-                   help="overlap each dispatch's token harvest with the "
-                        "next dispatch (requires K>1; finish reaction "
-                        "widens to <=2K-1 steps)")
+                   help="K>1 / --ragged: overlap each dispatch's token "
+                        "harvest with the next dispatch (finish reaction "
+                        "widens to <=2K-1 steps); one step per dispatch "
+                        "always does")
     p.add_argument("--num-kv-blocks", type=int, default=2048)
     p.add_argument("--max-num-seqs", type=int, default=8)
     p.add_argument("--host-kv-blocks", type=int, default=0,
@@ -410,7 +411,7 @@ async def collect_chat_text(stream) -> str:
     return (choices[0].get("message") or {}).get("content") or ""
 
 
-async def run_http(args, pipeline, core) -> None:
+async def _serve_http(args, pipeline) -> None:
     from ..llm.http import HttpService
     svc = HttpService(port=args.http_port, host=args.http_host)
     name = _model_name(args)
@@ -420,6 +421,77 @@ async def run_http(args, pipeline, core) -> None:
     logger.info("serving %s on http://%s:%d/v1", name, args.http_host,
                 args.http_port)
     await svc.run_forever()
+
+
+async def run_http(args, pipeline, core) -> None:
+    """The HTTP front end over ``pipeline``, until cancelled.
+
+    With a local engine the front end, the pipeline and the engine loop
+    run on a thread and an event loop of their own: an ``EngineCore``
+    starts its loop on the first request, on whichever loop asks, so all
+    that a request awaits lives there, and a call that blocks the
+    caller's loop (a profiler writing its trace, a checkpoint; anything
+    that releases the interpreter lock) stops no token stream. The
+    caller's task only waits; cancelling it stops the engine on its own
+    loop (``core.stop()``), then the thread. Where something under the
+    pipeline already holds the caller's loop (a remote engine or a
+    disaggregated router's connections, a dispatch stream's followers, an
+    engine loop that is already running) the front end serves in place.
+    """
+    if (core is None or core.running or args.remote_prefill
+            or args.num_nodes > 1):
+        await _serve_http(args, pipeline)
+        return
+    import threading
+    caller = asyncio.get_running_loop()
+    outcome = caller.create_future()
+    started = threading.Event()
+    on_thread = {}
+
+    async def serve() -> None:
+        on_thread["loop"] = asyncio.get_running_loop()
+        on_thread["task"] = asyncio.current_task()
+        started.set()
+        try:
+            await _serve_http(args, pipeline)
+        finally:
+            await core.stop()      # the engine loop is a task of THIS loop
+
+    def tell(error) -> None:
+        if outcome.done():
+            return
+        if error is None:
+            outcome.set_result(None)
+        else:
+            outcome.set_exception(error)
+
+    def thread_main() -> None:
+        error = None
+        try:
+            asyncio.run(serve())
+        except asyncio.CancelledError:
+            pass
+        except BaseException as e:  # noqa: BLE001 — the caller's to raise
+            error = e
+        started.set()
+        try:
+            caller.call_soon_threadsafe(tell, error)
+        except RuntimeError:       # the caller's loop is gone already
+            pass
+
+    thread = threading.Thread(target=thread_main, name="http-serve",
+                              daemon=True)
+    thread.start()
+    try:
+        await outcome
+    except asyncio.CancelledError:
+        await asyncio.to_thread(started.wait)
+        try:
+            on_thread["loop"].call_soon_threadsafe(on_thread["task"].cancel)
+        except (KeyError, RuntimeError):   # never started, or over already
+            pass
+        await asyncio.to_thread(thread.join)
+        raise
 
 
 async def run_text(args, pipeline, interactive: bool) -> None:
@@ -822,8 +894,8 @@ async def amain(argv=None) -> None:
         if args.decode_steps_per_dispatch <= 1:
             raise SystemExit(
                 "multi-host serving requires --decode-steps-per-dispatch "
-                "> 1 (the single-step decode path is not in the dispatch "
-                "stream)")
+                "> 1 (no follower has replayed the one-step path's per-slot "
+                "chained dispatches)")
     initialize_multihost(MultiNodeConfig(
         num_nodes=args.num_nodes, node_rank=args.node_rank,
         leader_addr=args.leader_addr))

@@ -215,7 +215,7 @@ def pp_decode_forward(params: Dict[str, jax.Array], kv, tokens, positions,
 def pp_decode_k_forward(params, kv, tokens, positions, block_tables,
                         seeds, steps0, temperature, top_k, top_p,
                         planned, planned_mask, statics, mesh, K: int,
-                        seed: int) -> Tuple[jax.Array, jax.Array, dict]:
+                        seed) -> Tuple[jax.Array, jax.Array, dict]:
     """Token-interleaved K-step decode over a pp(×tp) mesh — the SAME
     contract as the engine's fused decode_k scan: returns
     (toks [K, B] int32, logprobs [K, B] f32, kv), with per-(seed,

@@ -290,9 +290,13 @@ def test_chip_smoke_cpu_rehearsal_drives_http_end_to_end(tmp_path):
     for needle in ("# phase[bf16]: tokens_generated=160",
                    "# phase[int8]: tokens_generated=96",
                    "# decode_kernel_vs_xla_max_abs_err",
-                   "# program[_prefill_jit]", "# program[_decode_jit]",
-                   "# program[_decode_k_jit]", "# native_libs_loaded"):
+                   "# program[_prefill_jit]", "# program[_decode_k_jit]",
+                   "# native_libs_loaded"):
         assert needle in r.stdout, needle
+    # one decode program per phase: the one-step path serves through the
+    # K-step program too, and the host-keyed _decode_jit is never built
+    assert r.stdout.count("# program[_decode_k_jit]") == 2
+    assert "# program[_decode_jit]" not in r.stdout
 
 
 def test_chip_smoke_four_chip_rehearsal_on_virtual_devices(tmp_path):

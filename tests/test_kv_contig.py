@@ -508,6 +508,9 @@ async def test_engine_defrag_restores_contiguity(tiny_model_dir):
             toks.append(item)
         assert toks == base_toks            # stream unaffected by moves
         assert core.defrag_passes >= 1
+        # each pass harvested the decode step in flight before it moved
+        # anything (one step per dispatch always has one in flight)
+        assert core.pipeline_drains.get("defrag", 0) >= core.defrag_passes
         assert pool.defrag_moves_total >= 2
     finally:
         await core.stop()

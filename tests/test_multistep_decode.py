@@ -1,6 +1,9 @@
 """Multi-step decode (decode_steps_per_dispatch > 1): K fused steps must
 produce exactly the single-step engine's token streams, including EOS and
-max_tokens finishes landing mid-dispatch (device overrun discarded)."""
+max_tokens finishes landing mid-dispatch (device overrun discarded). The
+single-step engine keeps one step in flight (tests/test_decode_overlap.py);
+the reference here is the same engine made to harvest every step before
+it builds the next, which an attached replay recorder does."""
 
 import asyncio
 
@@ -19,12 +22,21 @@ TINY = ModelConfig(vocab_size=256, hidden_size=64, intermediate_size=128,
                    max_position_embeddings=512)
 
 
-def make_core(k: int, pipeline: bool = False) -> EngineCore:
+def make_core(k: int, pipeline: bool = False,
+              drained: bool = False) -> EngineCore:
     ecfg = EngineConfig(max_model_len=256, kv_block_size=8, num_kv_blocks=64,
                         max_num_seqs=4, prefill_buckets=[16, 32, 64],
                         decode_steps_per_dispatch=k,
                         decode_dispatch_pipeline=pipeline)
-    return EngineCore(TINY, ecfg, attn_impl="xla", param_dtype=jnp.float32)
+    core = EngineCore(TINY, ecfg, attn_impl="xla", param_dtype=jnp.float32)
+    if drained:
+        from dynamo_tpu.engine.replay import Recorder
+        core.recorder = Recorder()
+    return core
+
+
+def decode_records(core) -> list:
+    return [r for r in core.flight.dump() if r["kind"] == "decode"]
 
 
 async def run_req_collect(core, prompt, **kw):
@@ -43,16 +55,19 @@ async def run_req_collect(core, prompt, **kw):
         toks.append(item)
 
 
-@pytest.mark.parametrize("k,pipeline", [(4, False), (5, False),
-                                        (4, True)])
+@pytest.mark.parametrize("k,pipeline", [(1, False), (1, True), (4, False),
+                                        (5, False), (4, True)])
 async def test_multistep_matches_single_step_greedy(k, pipeline):
     rng = np.random.default_rng(3)
     prompt = rng.integers(1, TINY.vocab_size, size=21).tolist()
-    core1 = make_core(1)
+    core1 = make_core(1, drained=True)
     try:
         ref, reason1 = await run_req_collect(core1, prompt, max_new=13)
     finally:
         await core1.stop()
+    # the reference never had a step in flight behind another
+    assert not core1.pipeline_drains
+    assert not any(r["chained"] for r in decode_records(core1))
     corek = make_core(k, pipeline=pipeline)
     try:
         got, reasonk = await run_req_collect(corek, prompt, max_new=13)
@@ -61,6 +76,11 @@ async def test_multistep_matches_single_step_greedy(k, pipeline):
     assert got == ref                      # identical greedy stream
     assert reason1 == reasonk
     assert len(got) == 13                  # max_tokens lands mid-dispatch
+    if k == 1:
+        # and this one did, whatever the flag says: every dispatch but
+        # the first fed from the device
+        chained = [r["chained"] for r in decode_records(corek)]
+        assert chained[0] == 0 and all(chained[1:]), chained
 
 
 async def test_multistep_eos_mid_dispatch_discards_overrun():

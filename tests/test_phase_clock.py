@@ -119,7 +119,7 @@ async def serve(core, prompts, max_new=10):
 
 @pytest.mark.asyncio
 @pytest.mark.parametrize("kind,kw", [
-    ("decode", {}),                                    # _decode_step, K=1
+    ("decode", {}),                  # K=1: deferred _harvest, always
     ("decode", {"decode_steps_per_dispatch": 4}),      # _harvest
     ("decode", {"decode_steps_per_dispatch": 4,
                 "decode_dispatch_pipeline": True}),    # deferred _harvest
@@ -146,6 +146,11 @@ async def test_flight_records_split_the_cycle_on_every_path(kind, kw):
         assert abs(r["device_ms"] + r["host_gap_ms"] - phases) < 0.01, r
         assert r["device_ms"] == r["wait_ms"]
         assert r["admits"] >= 0
+    if not kw:
+        # one step per dispatch is the overlapped path: steps were fed
+        # from the device behind an un-harvested one, and the records
+        # above still tile their cycles with device_ms == wait_ms
+        assert sum(r["chained"] for r in records) > len(records) // 2
     # the loop really was in these phases, and nothing waits for free
     assert sum(r["wait_ms"] for r in records) > 0
     assert sum(r["dispatch_ms"] for r in records) > 0

@@ -61,9 +61,11 @@ def assert_exact_to_recompute_boundary(got, ref, req, name):
         f"decode numerics; numeric_boundaries={req.numeric_boundaries}")
 
 
-@pytest.mark.parametrize("k,pipeline", [(1, False), (4, False),
-                                        (4, True)])
-async def test_preemption_exact_streams_under_contention(k, pipeline):
+@pytest.mark.parametrize("k,pipeline,record", [
+    (1, False, False), (1, False, True), (4, False, True), (4, True, True),
+], ids=["k1-overlapped", "k1-drained-replayed", "k4", "k4-pipelined"])
+async def test_preemption_exact_streams_under_contention(k, pipeline,
+                                                         record):
     rng = np.random.default_rng(23)
     p1 = rng.integers(1, TINY.vocab_size, size=30).tolist()
     p2 = rng.integers(1, TINY.vocab_size, size=30).tolist()
@@ -81,9 +83,10 @@ async def test_preemption_exact_streams_under_contention(k, pipeline):
     # pool big enough for either sequence alone (~9 blocks each + slack)
     # but not both at full length → forced preemption traffic
     small = make_core(num_kv_blocks=16, k=k, pipeline=pipeline)
-    if k > 1:
+    if record:
         # record the schedule so post-boundary tokens are verified too
-        # (dispatch recording exists only in the multi-step path)
+        # (at one step per dispatch an attached recorder makes the loop
+        # harvest every step before it builds the next)
         from dynamo_tpu.engine.replay import Recorder
         small.recorder = Recorder()
     try:
@@ -97,7 +100,18 @@ async def test_preemption_exact_streams_under_contention(k, pipeline):
         assert small.preemptions > 0, "contention never triggered preemption"
         assert_exact_to_recompute_boundary(g1, ref1, q1, "a")
         assert_exact_to_recompute_boundary(g2, ref2, q2, "b")
-        if k > 1:
+        decodes = [r for r in small.flight.dump() if r["kind"] == "decode"]
+        if k == 1 and not record:
+            # the overlapped path: steps were chained on the device, and
+            # the growth that failed with a token in flight drained the
+            # pipeline before anyone was preempted
+            assert any(r["chained"] for r in decodes)
+            assert small.pipeline_drains.get("kv_growth", 0) > 0
+            assert any(r.get("drain") == "kv_growth" for r in decodes)
+        elif k == 1:
+            assert not small.pipeline_drains
+            assert not any(r["chained"] for r in decodes)
+        if record:
             # tokens AFTER a recompute boundary aren't waived: a
             # synchronous replay of the recorded schedule (same prefill
             # programs, fresh KV) must reproduce every harvested token —

@@ -253,7 +253,8 @@ def test_decode_kernel_compiles_per_tp_shard_on_four_chips(topo, one_chip):
 @pytest.mark.parametrize("moe", [False, True], ids=["dense", "qwen2moe"])
 def test_engine_step_programs_carry_stable_names(one_chip, monkeypatch, moe):
     """The engine's own prefill and decode programs (``_prefill_jit`` /
-    ``_decode_jit``, the ones the benchmark's cells serve) name their
+    ``_decode_k_jit`` at one step per dispatch, the ones the benchmark's
+    cells serve) name their
     parts with ``jax.named_scope`` and their Pallas calls with ``name=``,
     so a trace reduction finds them whatever the compiler calls its
     fusions. Narrow widths, kernel-supported geometry, two layers."""
@@ -281,19 +282,21 @@ def test_engine_step_programs_carry_stable_names(one_chip, monkeypatch, moe):
                               (core.params, core.kv))
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     i32, f32 = jnp.int32, jnp.float32
-    decode = core._decode_jit.lower(
+    decode = core._decode_k_jit.lower(
         params, kv, s((B,), i32), s((B,), i32), s((B, M), i32),
-        s((B,) + key.shape, key.dtype), s((B,), f32), s((B,), i32),
-        s((B,), f32)).compile().as_text()
+        s((B,), i32), s((B,), i32), s((B,), f32), s((B,), i32),
+        s((B,), f32), s((1, B), i32), s((1, B), jnp.bool_),
+        s(key.shape, key.dtype)).compile().as_text()
     prefill = core._prefill_jit.lower(
         params, kv, s((T,), i32), s((M,), i32), s((), i32), s((), i32),
         s(key.shape, key.dtype), s((), f32), s((), i32),
         s((), f32)).compile().as_text()
     mlp = (["moe_mlp/run_experts_dense", "moe_mlp/shared_expert/swiglu"]
            if moe else ["swiglu"])
-    for text, top, kernel in ((decode, "decode", "paged_attention"),
-                              (prefill, "prefill", "flash_prefill")):
+    for text, fn, top, kernel in (
+            (decode, "decode_k", "decode", "paged_attention"),
+            (prefill, "prefill", "prefill", "flash_prefill")):
         assert "tpu_custom_call" in text
-        for scope in [f"jit({top})/{top}/", "/lm_head/", "/sampling/",
+        for scope in [f"jit({fn})/{top}/", "/lm_head/", "/sampling/",
                       f"/attention/{kernel}/pallas_call"] + mlp:
             assert scope in text, (top, scope)

@@ -85,7 +85,7 @@ def main():
             core.params, core.kv, toks_in,
             jnp.asarray(np.full((B,), 512, np.int32)),
             jnp.array(core._block_tables), seeds, steps0,
-            temp, topk, topp, planned, pmask)
+            temp, topk, topp, planned, pmask, core._base_key)
         return toks[-1]
 
     def prefill_dispatch(isl, prompt, table):
