@@ -2,21 +2,29 @@
 """Builder's rehearsal, no chip: compile a configuration's prefill and
 decode programs at their real sizes for a described TPU v5e and print what
 each needs (``memory_analysis``), which is how a configuration's
-``--num-kv-blocks`` is fixed. A build, not a timing; nothing runs.
+``--num-kv-blocks`` and depth cut are fixed. A build, not a timing; nothing
+runs.
 
     JAX_PLATFORMS=cpu python3 benchmark/compile_check.py --config mistral-7b \
-        --buckets 1024 --num-kv-blocks 2800
+        --buckets 1024 [--layers N] [--init] [launcher flags ...]
 
-It mirrors ``EngineCore._compile_jits`` (prefill / decode over the model's
-forward + ``sample_tokens``) on shapes only, with the attention kernels
-forced (``attn_impl="pallas"``) and the program's TPU test answered "yes",
-because code that asks ``jax.devices()`` sees the CPU here.
+``--config`` is a name in ``BENCHMARK.json`` or the path of a configuration
+file that is not in it yet. The engine is the one the launcher would build
+from the file's ``deployment.flags`` (further launcher flags on this
+command line are appended, so ``--num-kv-blocks 3200`` tries another pool),
+and everything architecture-specific comes from ``EngineCore``'s own
+dispatch: the model module (``kv_lora_rank > 0`` → ``models/mla.py``), its
+parameter tree and pool, and the two step functions that
+``EngineCore._compile_jits`` makes, lowered on shapes only. The attention
+kernels are forced (``attn_impl="pallas"``) and the program's TPU test is
+answered "yes", because code that asks ``jax.devices()`` sees the CPU here.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import sys
 import time
@@ -27,17 +35,36 @@ ROOT = os.path.dirname(HERE)
 sys.path[:0] = [HERE, ROOT]
 
 
+def engine_shell(cfg, engine_cfg):
+    """An ``EngineCore`` with no weights and no pool: the attributes its
+    ``_compile_jits`` reads, set as ``EngineCore.__init__`` sets them on one
+    chip with no mesh, then its own step functions."""
+    import dataclasses
+    from dynamo_tpu.engine.core import EngineCore
+    from dynamo_tpu.engine.models import llama, mla
+    if engine_cfg.kv_block_size == 0:
+        engine_cfg = dataclasses.replace(
+            engine_cfg, kv_block_size=engine_cfg.auto_kv_block_size(
+                cfg, engine_cfg.kv_quantization))
+    core = object.__new__(EngineCore)
+    core.cfg, core.mesh, core.pp = engine_cfg, None, 1
+    core.model_mod = mla if cfg.kv_lora_rank > 0 else llama
+    core.statics = llama.ModelStatics(
+        cfg=cfg, block_size=engine_cfg.kv_block_size, attn_impl="pallas",
+        kv_coalesce=engine_cfg.kv_contig_alloc)
+    core._compile_jits()
+    return core
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", required=True)
     ap.add_argument("--buckets", default="1024")
-    ap.add_argument("--num-kv-blocks", type=int, required=True)
-    ap.add_argument("--max-num-seqs", type=int, default=64)
-    ap.add_argument("--max-model-len", type=int, default=4096)
-    ap.add_argument("--block-size", type=int, default=16)
+    ap.add_argument("--layers", type=int, default=None,
+                    help="try another depth than the file's")
     ap.add_argument("--init", action="store_true",
                     help="also compile the largest init+quantize program")
-    opts = ap.parse_args()
+    opts, launcher_flags = ap.parse_known_args()
 
     import jax
     import jax.numpy as jnp
@@ -48,18 +75,33 @@ def main() -> int:
     from dynamo_tpu.engine.config import ModelConfig
     from dynamo_tpu.engine.models import llama
     from dynamo_tpu.engine.quant import (_quantize_named,
-                                         init_params_quantized, unpack_params)
-    from dynamo_tpu.engine.sampling import sample_tokens
+                                         init_params_quantized)
+    from dynamo_tpu.launch import run as launcher
 
     jax.config.update("jax_enable_compilation_cache", False)
     attention._on_tpu = llama._on_tpu = lambda: True
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
-    config = bench_run.load_config(bench_run.load_benchmark(), opts.config)
-    cfg = ModelConfig.from_hf_config(bench_run.hf_config(config))
-    statics = llama.ModelStatics(cfg=cfg, block_size=opts.block_size,
-                                 attn_impl="pallas")
+    if os.path.exists(opts.config):
+        with open(opts.config) as f:
+            config = json.load(f)
+    else:
+        config = bench_run.load_config(bench_run.load_benchmark(),
+                                       opts.config)
+    hf = bench_run.hf_config(config)
+    if opts.layers:
+        hf["num_hidden_layers"] = opts.layers
+    cfg = ModelConfig.from_hf_config(hf)
+    flags = list(config.get("deployment", {}).get("flags", ()))
+    engine_cfg = launcher.engine_config(launcher.build_parser().parse_args(
+        ["in=http", "out=jax", *flags, *launcher_flags]))
+    if engine_cfg.quantization != "int8":
+        raise SystemExit("compile_check builds int8 weights; the "
+                         f"deployment asks for {engine_cfg.quantization!r}")
+    core = engine_shell(cfg, engine_cfg)
+    engine_cfg = core.cfg
+    blocks, bsz = engine_cfg.num_kv_blocks, engine_cfg.kv_block_size
 
     def placed(tree):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
@@ -67,59 +109,46 @@ def main() -> int:
 
     params = jax.eval_shape(lambda: llama.fuse_stacked_matmuls(
         dict(init_params_quantized(cfg, jax.random.PRNGKey(0))), cfg))
-    kv = jax.eval_shape(lambda: llama.init_kv_cache(
-        cfg, opts.num_kv_blocks, opts.block_size))
+    kv = jax.eval_shape(lambda: core.model_mod.init_kv_cache(
+        cfg, blocks, bsz))
     size = lambda tree: sum(x.size * x.dtype.itemsize  # noqa: E731
                             for x in jax.tree.leaves(tree))
-    report = {"config": opts.config, "weights_bytes": size(params),
-              "kv_pool_bytes": size(kv), "num_kv_blocks": opts.num_kv_blocks,
-              "kv_bytes_per_token": size(kv) // (opts.num_kv_blocks
-                                                 * opts.block_size),
+    report = {"config": opts.config, "layers": cfg.num_layers,
+              "model_module": core.model_mod.__name__,
+              "weights_bytes": size(params),
+              "kv_pool_bytes": size(kv), "num_kv_blocks": blocks,
+              "kv_bytes_per_token": size(kv) // (blocks * bsz),
               "programs": {}}
     params, kv = placed(params), placed(kv)
-    M = opts.max_model_len // opts.block_size
-    B = opts.max_num_seqs
+    M = engine_cfg.max_model_len // bsz
+    B = engine_cfg.max_num_seqs
     i32, f32 = jnp.int32, jnp.float32
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    def prefill(params, kv, tokens, table, start, n, key, t, k, p):
-        logits, kv = llama.prefill_forward(unpack_params(params), kv, tokens,
-                                           table, start, n, statics)
-        tok, lp = sample_tokens(logits[None, :], key[None], t[None],
-                                k[None], p[None])
-        return tok[0], lp[0], kv
-
-    def decode(params, kv, tokens, pos, tables, keys, t, k, p):
-        logits, kv = llama.decode_forward(unpack_params(params), kv, tokens,
-                                          pos, tables, statics)
-        toks, lps = sample_tokens(logits, keys, t, k, p)
-        return toks, lps, kv
-
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     key = s(key.shape, key.dtype)
-    jobs = {f"prefill-{b}": (prefill, (
+    jobs = {f"prefill-{b}": (core._prefill_jit, (
         params, kv, s((b,), i32), s((M,), i32), s((), i32), s((), i32), key,
         s((), f32), s((), i32), s((), f32)))
         for b in (int(x) for x in opts.buckets.split(",") if x)}
-    jobs[f"decode-B{B}"] = (decode, (
+    jobs[f"decode-B{B}"] = (core._decode_jit, (
         params, kv, s((B,), i32), s((B,), i32), s((B, M), i32),
         s((B,) + key.shape, key.dtype), s((B,), f32), s((B,), i32),
         s((B,), f32)))
     if opts.init:
-        shapes = llama.param_shapes(cfg)
-        name = max(shapes, key=lambda n: int(jnp.prod(jnp.array(shapes[n]))))
+        shapes = core.model_mod.param_shapes(cfg)
+        name = max(shapes, key=lambda n: math.prod(shapes[n]))
 
         def build(sub):
             w = llama.init_one_param(cfg, name, shapes[name], sub,
                                      jnp.bfloat16)
             return _quantize_named(name, w, True, "lm_head" not in shapes, 8)
-        jobs[f"init[{name}]"] = (build, (key,))
+        jobs[f"init[{name}]"] = (jax.jit(build), (key,))
     for tag, (fn, args) in jobs.items():
         t0 = time.monotonic()
-        donate = (1,) if tag.startswith(("prefill", "decode")) else ()
-        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+        compiled = fn.lower(*args).compile()
         m = compiled.memory_analysis()
         report["programs"][tag] = {
             "argument_bytes": m.argument_size_in_bytes,

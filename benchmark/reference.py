@@ -54,6 +54,14 @@ TOL_STD = 0.25
 BREAKAGES = ("drop_layer", "no_shared_expert", "unit_routing_weights")
 
 
+def breakages_for(hf: dict) -> tuple:
+    """Those of BREAKAGES that change this configuration's mathematics."""
+    fam = family(hf)
+    return tuple(b for b in BREAKAGES if b == "drop_layer"
+                 or (b == "no_shared_expert" and fam["shared"])
+                 or (b == "unit_routing_weights" and fam["experts"]))
+
+
 def family(hf: dict) -> dict:
     """The sizes the mathematics needs, from the published config keys."""
     mt = hf["model_type"]
@@ -217,6 +225,35 @@ def make_layer(fam: dict, broken=None):
     return jax.jit(layer)
 
 
+def embed_rows(params: dict, tokens) -> jax.Array:
+    """The embedding rows of ``tokens`` as float32 (int8: q·scale per row)."""
+    emb = params["embed"]
+    if hasattr(emb, "q"):
+        return (emb.q[tokens].astype(jnp.float32)
+                * emb.scale[tokens].astype(jnp.float32))
+    return emb[tokens].astype(jnp.float32)
+
+
+def head_logits(params: dict, hf: dict, h, eps: float) -> jax.Array:
+    """RMSNorm(h) · W_head → float32 [rows, V], the head dequantised a
+    slice at a time."""
+    x = _rms(h, _f32(params["final_norm"]), eps)
+    head = params.get("lm_head")
+    chunks = []
+    V = int(hf["vocab_size"])
+    step = 16384
+    for lo in range(0, V, step):
+        if head is None:          # tied: the embedding, transposed
+            w = _f32(params["embed"], slice(lo, lo + step)).T
+        elif hasattr(head, "q"):
+            w = (head.q[:, lo:lo + step].astype(jnp.float32)
+                 * head.scale[..., lo:lo + step].astype(jnp.float32))
+        else:
+            w = head[:, lo:lo + step].astype(jnp.float32)
+        chunks.append(x @ w)
+    return jnp.concatenate(chunks, -1)
+
+
 def logits_for(params: dict, hf: dict, tokens, last: int,
                broken=None) -> np.ndarray:
     """Float32 logits [last, V] of the last ``last`` positions of one
@@ -224,36 +261,21 @@ def logits_for(params: dict, hf: dict, tokens, last: int,
     fam = family(hf)
     tokens = jnp.asarray(tokens, jnp.int32)
     with jax.default_matmul_precision("highest"):
-        emb = params["embed"]
-        if hasattr(emb, "q"):
-            h = (emb.q[tokens].astype(jnp.float32)
-                 * emb.scale[tokens].astype(jnp.float32))
-        else:
-            h = emb[tokens].astype(jnp.float32)
+        h = embed_rows(params, tokens)
         layer = make_layer(fam, broken)
         n_layers = fam["layers"] - (1 if broken == "drop_layer" else 0)
         for li in range(n_layers):
             h = layer(h, _layer_weights(params, li, fam))
-        x = _rms(h[-last:], _f32(params["final_norm"]), fam["eps"])
-        head = params.get("lm_head")
-        chunks = []
-        V = int(hf["vocab_size"])
-        step = 16384          # the head dequantised a slice at a time
-        for lo in range(0, V, step):
-            if head is None:          # tied: the embedding, transposed
-                w = _f32(emb, slice(lo, lo + step)).T
-            elif hasattr(head, "q"):
-                w = (head.q[:, lo:lo + step].astype(jnp.float32)
-                     * head.scale[..., lo:lo + step].astype(jnp.float32))
-            else:
-                w = head[:, lo:lo + step].astype(jnp.float32)
-            chunks.append(x @ w)
-        return np.asarray(jnp.concatenate(chunks, -1), np.float32)
+        return np.asarray(head_logits(params, hf, h[-last:], fam["eps"]),
+                          np.float32)
 
 
 def compare(params: dict, hf: dict, prompt: list, served_ids: list,
-            served_logprobs: list, broken=None) -> dict:
-    """Holds one served greedy continuation to the reference: at every
+            served_logprobs: list, broken=None, forward=None) -> dict:
+    """Holds one served greedy continuation to the reference (this
+    module's ``logits_for``, or as ``forward`` that of the module the
+    configuration names, ``references/<name>.py``; the comparison and the
+    tolerance are the same for every family): at every
     served position the served token's logprob must be within the
     tolerance of the reference's logprob for that token, and that token's
     reference logit within the same tolerance of the reference maximum
@@ -262,7 +284,7 @@ def compare(params: dict, hf: dict, prompt: list, served_ids: list,
     with errors in units of the reference logits' standard deviation."""
     n = len(served_ids)
     seq = list(prompt) + list(served_ids[:-1])
-    logits = logits_for(params, hf, seq, n, broken)           # [n, V]
+    logits = (forward or logits_for)(params, hf, seq, n, broken)  # [n, V]
     worst_lp = worst_gap = 0.0
     for i, (tok, lp) in enumerate(zip(served_ids, served_logprobs)):
         row = logits[i].astype(np.float64)

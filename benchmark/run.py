@@ -14,8 +14,10 @@ holds the served path to the float32 reference, and then measures for
 Driven by data: the cell, its configuration and its traffic mix are found
 by name in ``BENCHMARK.json``, ``benchmark/configs/<config>.json`` and
 ``benchmark/traffic/<mix>.json``; each per-layer metric is read by
-``benchmark/layer_metrics/<metric>.py``. Adding any of them edits no file
-that is there.
+``benchmark/layer_metrics/<metric>.py``; the plain reference a
+configuration is held to is the module its file names
+(``"reference": "<name>"`` → ``benchmark/references/<name>.py``; none named:
+``benchmark/reference.py``). Adding any of them edits no file that is there.
 
 The last line of standard output is the result object; earlier lines are
 ``# key: value`` notes. A builder-only mode, never the driver's:
@@ -52,7 +54,7 @@ from server import (MODEL_NAME, CacheWatch, Server, note,  # noqa: E402
 # keys of a configuration file that are the benchmark's own, not the
 # published config.json's
 CONFIG_EXTRAS = ("source", "reduced", "assumed", "deployment",
-                 "memory_analysis", "notes")
+                 "memory_analysis", "notes", "reference")
 PROBE_OUTPUT_TOKENS = 8
 PROBE_PROMPTS = 2
 # A traced window ends with the profiler: it runs for TRACE_SECONDS and stops
@@ -84,6 +86,32 @@ def hf_config(config: dict) -> dict:
 READER_DIRS = {"end_to_end": "end_to_end", "per_layer": "layer_metrics"}
 
 
+def load_by_path(module_name: str, path: str):
+    """A module of the benchmark that is found by a name in its data."""
+    spec = importlib.util.spec_from_file_location(module_name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def reference_module(config: dict):
+    """The plain reference this configuration is held to: the forward
+    pass (``logits_for``) and the breakages it must catch. A configuration
+    file names it (``"reference": "deepseek_v2"`` →
+    ``references/deepseek_v2.py``); one that names none is of the llama
+    family and gets ``reference.py``. The comparison and its tolerance are
+    ``reference.compare``'s for every family."""
+    name = config.get("reference")
+    if name is None:
+        import reference
+        return reference
+    path = os.path.join(HERE, "references", f"{name}.py")
+    if not os.path.exists(path):
+        raise SystemExit(f"the configuration names the reference {name!r}; "
+                         f"there is no {os.path.relpath(path, ROOT)}")
+    return load_by_path("reference_" + name.replace("-", "_"), path)
+
+
 def metric_reader(group: str, name: str):
     """The reader of one metric, found by the metric's name: a file of its
     own under ``end_to_end/`` or ``layer_metrics/``. Where one quantity
@@ -94,11 +122,8 @@ def metric_reader(group: str, name: str):
     if not os.path.exists(path):
         path = os.path.join(HERE, READER_DIRS[group],
                             f"{name.rpartition('.')[0]}.py")
-    spec = importlib.util.spec_from_file_location(
-        group + "_" + name.replace(".", "_").replace("-", "_"), path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return load_by_path(
+        group + "_" + name.replace(".", "_").replace("-", "_"), path).read
 
 
 def cell_metrics(bench: dict, cell: str, group: str) -> list:
@@ -111,14 +136,15 @@ def cell_metrics(bench: dict, cell: str, group: str) -> list:
 # ------------------------------------------------------------------ set-up
 
 def prepare(bench: dict, config_name: str, mix_name: str) -> tuple:
-    """→ (launcher flags, published config, mix, model dir): the model
-    directory is written, nothing has touched the chip yet."""
+    """→ (launcher flags, published config, mix, model dir, reference
+    module): the model directory is written, nothing has touched the chip
+    yet."""
     config = load_config(bench, config_name)
     hf = hf_config(config)
     model_dir = os.path.join(WORK_DIR, "model", config_name)
     write_model_dir(model_dir, hf)
     return (list(config["deployment"]["flags"]), hf,
-            traffic.load_mix(mix_name), model_dir)
+            traffic.load_mix(mix_name), model_dir, reference_module(config))
 
 
 def enable_cache() -> CacheWatch:
@@ -214,10 +240,11 @@ def warm_defrag(core) -> None:
     note("warm_up[defrag copies]", f"{time.monotonic() - t0:.2f} s")
 
 
-async def probe(srv: Server, hf: dict, mix: dict, seed: int) -> tuple:
-    """Seeded prompts through the served path, held to the reference.
-    → (report, prompts, served ids per prompt). The prompt is as long as the
-    mix's shortest (48 at least), so the programs probed are the cell's."""
+async def probe(srv: Server, hf: dict, mix: dict, seed: int, ref) -> tuple:
+    """Seeded prompts through the served path, held to the configuration's
+    reference ``ref``. → (report, prompts, served ids per prompt). The
+    prompt is as long as the mix's shortest (48 at least), so the programs
+    probed are the cell's."""
     import numpy as np
     import reference
     rng = np.random.default_rng(seed ^ 0x9e0be)
@@ -230,7 +257,7 @@ async def probe(srv: Server, hf: dict, mix: dict, seed: int) -> tuple:
     for prompt in prompts:
         got = await srv.complete(prompt, PROBE_OUTPUT_TOKENS)
         rep = reference.compare(srv.core.params, hf, prompt, got["ids"],
-                                got["logprobs"])
+                                got["logprobs"], forward=ref.logits_for)
         served.append(got["ids"])
         worst["ok"] = worst["ok"] and rep["ok"]
         for k in ("worst_logprob_err_std", "worst_argmax_gap_std"):
@@ -348,7 +375,8 @@ def reduce_trace(traced: dict, flight: list) -> dict:
     gaps labelled by the flight record whose host interval covers them."""
     import trace_reduce
     path = trace_reduce.find_xplane(traced["dir"])
-    events = trace_reduce.device_events(path)
+    scopes = {}
+    events = trace_reduce.device_events(path, scopes=scopes)
     host_spans = []
     anchors = trace_reduce.host_events(path, "bench_anchor")
     if anchors:
@@ -364,11 +392,14 @@ def reduce_trace(traced: dict, flight: list) -> dict:
     note("trace", f"file={os.path.basename(path)} devices={len(events)} "
          f"events={sum(len(e) for e in events.values())} "
          f"anchor={'found' if anchors else 'missing'}")
-    out = trace_reduce.reduce(events, host_spans=host_spans, top=7)
-    # the programs first (prefill against decode), then the operations
-    programs = [[f"program {name}", s] for name, s in
-                trace_reduce.program_seconds(path)[:3]]
-    out["device_ops"] = programs + out["device_ops"]
+    out = trace_reduce.reduce(events, host_spans=host_spans, top=7,
+                              scopes=scopes)
+    out["programs"] = trace_reduce.program_table(trace_reduce.device_events(
+        path, lines=(trace_reduce.MODULE_LINE,)))
+    # printed: the programs first (prefill against decode), then the ops
+    out["device_ops"] = [[f"program {name}", s]
+                         for name, s, _ in out["programs"][:3]
+                         ] + out["device_ops"]
     return out
 
 
@@ -377,9 +408,10 @@ def reduce_trace(traced: dict, flight: list) -> dict:
 async def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
                    trace: bool, devices, cache: CacheWatch,
                    keep_trace: bool = False) -> dict:
-    flags, hf, mix, model_dir = prepare(bench, cell["config"],
-                                        cell["traffic"])
+    flags, hf, mix, model_dir, ref = prepare(bench, cell["config"],
+                                             cell["traffic"])
     vocab = int(hf["vocab_size"])
+    note("reference", os.path.splitext(os.path.basename(ref.__file__))[0])
     note("setup[model dir written]", f"{time.time() - T_PROCESS_START:.1f} s")
     gen = LoadGen()
     try:
@@ -392,13 +424,13 @@ async def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
                 "seed": seed, "seconds": seconds, "vocab": vocab})
             note("offered", f"requests={ready['requests']} loop={mix['loop']}")
             buckets = await warm_up(srv, mix, vocab, seed)
-            verdict, prompts, served = await probe(srv, hf, mix, seed)
-            note("reference", json.dumps(verdict))
+            verdict, prompts, served = await probe(srv, hf, mix, seed, ref)
+            note("reference_before_window", json.dumps(verdict))
             note("setup[probed]", f"{time.time() - T_PROCESS_START:.1f} s")
             cache.report("set-up")
             seen = await measure(srv, gen, mix, seconds, trace, cache)
             load = seen["load"]
-            after, _, again = await probe(srv, hf, mix, seed)
+            after, _, again = await probe(srv, hf, mix, seed, ref)
             note("reference_after_window", json.dumps(after))
             note("probe_repeated_after_window", "same tokens"
                  if again == served else
@@ -462,7 +494,7 @@ async def sweep(bench: dict, config_name: str, mix_name: str, rates: list,
                 seconds: float, seed: int, cache: CacheWatch) -> None:
     """Builder only: one engine, windows at rising rates; prints a row per
     rate. The knee is the highest rate whose backlog does not grow."""
-    flags, hf, mix, model_dir = prepare(bench, config_name, mix_name)
+    flags, hf, mix, model_dir, _ = prepare(bench, config_name, mix_name)
     vocab = int(hf["vocab_size"])
     async with Server(model_dir, flags, seed & 0x7fffffff) as srv:
         await warm_up(srv, mix, vocab, seed)

@@ -3,16 +3,23 @@
 
     JAX_PLATFORMS=cpu python3 benchmark/selftest.py
 
-It runs the harness end to end at tiny widths of both families (dense GQA,
-and ``qwen2_moe`` with the gated shared expert and unnormalised top-k)
-through the same ``run_cell`` the chip runs, and checks the yardstick's own
-arithmetic on known inputs. Numbers it prints are CPU numbers: they show
-that the line parses, never how fast anything is.
+It runs the harness end to end at tiny widths of every family that has a
+fixture (``fixtures/tiny-*.json``: dense GQA, ``qwen2_moe`` with the gated
+shared expert and unnormalised top-k, ``deepseek_v2`` with latent attention,
+yarn rope and additive shared experts) through the same ``run_cell`` the
+chip runs, and checks the yardstick's own arithmetic on known inputs. A new
+family adds a fixture that names its reference (``"reference": "<name>"`` →
+``references/<name>.py``) and edits nothing here. Numbers it prints are CPU
+numbers: they show that the line parses, never how fast anything is.
+
+``tests/`` may run the cheap parts one by one: every check but
+``end_to_end`` takes no argument or a fixture's name, and raises on failure.
 """
 
 from __future__ import annotations
 
 import asyncio
+import glob
 import json
 import os
 import sys
@@ -30,6 +37,20 @@ import traffic  # noqa: E402
 
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 TMP_METRIC = "selftest.tmp_metric"
+# the mix of a fixture's served rehearsal: an open loop once, closed loops
+# for the rest
+REHEARSAL_MIX = {"tiny-dense": "selftest-open"}
+
+
+def fixtures() -> list:
+    """The tiny configurations, by the names of their files."""
+    return sorted(os.path.splitext(os.path.basename(p))[0] for p in
+                  glob.glob(os.path.join(HERE, "fixtures", "tiny-*.json")))
+
+
+def load_fixture(name: str) -> dict:
+    with open(os.path.join(HERE, "fixtures", f"{name}.json")) as f:
+        return json.load(f)
 
 
 def check(cond: bool, what: str) -> None:
@@ -140,6 +161,29 @@ def trace_reduction() -> None:
     gaps = dict((n, round(s * 1e9)) for n, s in out["idle_gaps"])
     check(gaps == {"decode": 85, "prefill": 200, "none": 10},
           "idle gaps take the label of the host span that covers them")
+    table = dict((n, (round(s * 1e9), c)) for n, s, c in out["ops"])
+    check(table == {"fusion.1": (140, 2), "custom-call": (40, 1),
+                    "copy": (20, 1), "tail": (10, 1)}
+          and [row[:2] for row in out["ops"][:2]] == out["device_ops"][:2],
+          "ops: every leaf op with its seconds and its count, longest first")
+    check("scopes" not in out, "no scopes where no op carries an op_name")
+    named = trace_reduce.reduce(events, window=(0, 520), scopes={
+        "fusion.1": "decode/moe_mlp", "custom-call": "decode/attention",
+        "copy": "decode/attention"})
+    check(dict((n, round(s * 1e9)) for n, s in named["scopes"])
+          == {"decode/moe_mlp": 140, "decode/attention": 60},
+          "scopes: seconds per named-scope path of the ops that carry one")
+    check(trace_reduce.scope_of(
+        '%f.1 = bf16[8] fusion(%p), metadata={op_name="jit(decode_k)/decode'
+        '/attention/dot_general" source_file="a.py"}') == "decode/attention"
+        and trace_reduce.scope_of("%f.1 = bf16[8] fusion(%p)") is None,
+        "an op's scope is its op_name without the program and the primitive")
+    programs = trace_reduce.program_table({"/device:TPU:0": [
+        ("jit_decode_k(123)", 0, 100), ("jit_prefill(7)", 100, 300),
+        ("jit_decode_k(123)", 400, 100), ("jit_prefill(8)", 500, 50)]})
+    check([(n, round(s * 1e9), c) for n, s, c in programs]
+          == [("jit_prefill", 350, 2), ("jit_decode_k", 200, 2)],
+          "programs: seconds and dispatches per program, fingerprints dropped")
     with open(os.path.join(HERE, "fixtures", "trace_events.json")) as f:
         fixture = json.load(f)
     got = trace_reduce.reduce(
@@ -147,6 +191,55 @@ def trace_reduction() -> None:
     for key, want in fixture["expect"].items():
         check(abs(got[key] - want) <= 1e-9 * max(1.0, abs(want)),
               f"recorded chip trace reduces to the recorded {key}")
+    n_events = sum(len(v) for v in fixture["events"].values())
+    check(sum(c for _, _, c in got["ops"]) <= n_events
+          and sum(s for _, s, _ in got["ops"]) <= got["busy_s"] * (1 + 1e-9)
+          and got["ops"][0][:2] == got["device_ops"][0]
+          and len(got["ops"]) > len(got["device_ops"]),
+          "the recorded trace's op table: all its leaf ops, inside busy_s")
+
+
+def reader_check() -> None:
+    """``kernel.paged_attention_ms`` on a small recorded ``ctx``: seconds
+    and dispatches of one traced run of ``qwen15-moe-a2.7b.decode-closed``
+    (my chip run, PR 30, seed 3000000011; 12 layers a step), and a second
+    kernel row made up to show that the kernel's variants add up."""
+    read = bench_run.metric_reader("per_layer", "kernel.paged_attention_ms")
+    trace = {"ops": [["%fusion.251 bf16[60,64,2816] fusion", 0.97852624, 2124],
+                     ["%paged_attention.12 bf16[64,16,2048] custom-call "
+                      "tpu_custom_call", 0.82311276, 2124],
+                     ["%paged_attention.3 bf16[64,16,2048] custom-call "
+                      "tpu_custom_call", 0.00088724, 2]],
+             "programs": [["jit_decode_k", 2.765658133, 177],
+                          ["jit_prefill", 0.420569207, 21]]}
+    got = read({"trace": trace})
+    check(abs(got - 0.824 / 177 * 1e3) < 1e-9,
+          "kernel.paged_attention_ms: seconds of %paged_attention* over the "
+          f"dispatches of jit_decode_k, in ms ({got:.3f})")
+    check(read({"trace": {"ops": trace["ops"], "programs": []}}) is None
+          and read({"trace": {"busy_s": 1.0, "window_s": 2.0}}) is None
+          and read({"trace": dict(trace, ops=trace["ops"][:1])}) is None,
+          "no decode dispatch or no such op in the trace: nothing to read")
+
+
+def reference_lookup() -> None:
+    import reference
+    check(bench_run.reference_module({"model_type": "mistral"}) is reference,
+          "a configuration that names no reference gets reference.py")
+    for name in fixtures():
+        ref = bench_run.reference_module(load_fixture(name))
+        check(callable(ref.logits_for) and callable(ref.breakages_for)
+              and set(ref.breakages_for(bench_run.hf_config(
+                  load_fixture(name)))) <= set(ref.BREAKAGES),
+              f"{name}: its reference has logits_for, BREAKAGES and "
+              f"breakages_for ({os.path.basename(ref.__file__)})")
+    try:
+        bench_run.reference_module({"reference": "no-such-family"})
+    except SystemExit as e:
+        check("no-such-family" in str(e),
+              "an unknown reference name fails loudly, before anything runs")
+    else:
+        check(False, "an unknown reference name fails loudly")
 
 
 def fixture_events() -> dict:
@@ -173,7 +266,7 @@ def end_to_end() -> None:
                        for m in real[group]]
     bench = dict(real, configs=[
         {"name": n, "file": f"benchmark/fixtures/{n}.json"}
-        for n in ("tiny-dense", "tiny-qwen2moe")],
+        for n in fixtures()],
         per_layer=real["per_layer"] + [
             {"name": TMP_METRIC, "unit": "1", "better": "higher",
              "source": "program_counter", "layer": "Engine step",
@@ -186,8 +279,8 @@ def end_to_end() -> None:
     with open(tmp, "w") as f:
         f.write("def read(ctx):\n    return float(len(ctx['flight']))\n")
     try:
-        for config, mix in (("tiny-dense", "selftest-open"),
-                            ("tiny-qwen2moe", "selftest-closed")):
+        for config in fixtures():
+            mix = REHEARSAL_MIX.get(config, "selftest-closed")
             cell = {"name": f"{config}.{mix}", "config": config,
                     "traffic": mix, "chips": 1}
             for trace in (False, True):
@@ -215,45 +308,45 @@ def end_to_end() -> None:
         os.remove(tmp)
 
 
-def reference_check() -> None:
-    """The reference against the engine at tiny widths, and the three
-    breakages that the tolerance has to catch."""
-    import dataclasses
+def reference_check(name: str) -> None:
+    """The fixture's reference against the engine at tiny widths, and the
+    breakages that the tolerance has to catch: those its module lists for
+    this configuration."""
     import jax
     import numpy as np
     import reference
     from dynamo_tpu.engine.config import EngineConfig, ModelConfig
     from dynamo_tpu.engine.core import EngineCore
 
-    for name in ("tiny-dense", "tiny-qwen2moe"):
-        with open(os.path.join(HERE, "fixtures", f"{name}.json")) as f:
-            hf = bench_run.hf_config(json.load(f))
-        # deeper and wider than the served rehearsal, so that one layer
-        # is a small part of the whole, as on the chip
-        hf = dict(hf, num_hidden_layers=6)
-        cfg = ModelConfig.from_hf_config(hf)
-        core = EngineCore(cfg, EngineConfig(
-            max_model_len=128, num_kv_blocks=32, max_num_seqs=2,
-            quantization="int8", seed=11))
-        rng = np.random.default_rng(3)
-        prompt = rng.integers(0, cfg.vocab_size, size=48).tolist()
-        # the engine's own greedy continuation, through its prefill and
-        # decode programs (the HTTP leg is end_to_end's)
-        ids, lps = greedy(core, prompt, 8)
-        rep = reference.compare(core.params, hf, prompt, ids, lps)
-        check(rep["ok"], f"{name}: engine within {reference.TOL_STD} std of "
-              f"the reference (logprob err {rep['worst_logprob_err_std']:.3f}"
-              f", argmax gap {rep['worst_argmax_gap_std']:.3f})")
-        breakages = ["drop_layer"] + (["no_shared_expert",
-                                       "unit_routing_weights"]
-                                      if cfg.num_experts else [])
-        for broken in breakages:
-            rep = reference.compare(core.params, hf, prompt, ids, lps,
-                                    broken=broken)
-            check(not rep["ok"], f"{name}: {broken} trips the tolerance "
-                  f"(logprob err {rep['worst_logprob_err_std']:.2f} std, "
-                  f"argmax gap {rep['worst_argmax_gap_std']:.2f} std)")
-        del core
+    config = load_fixture(name)
+    ref = bench_run.reference_module(config)
+    # deeper than the served rehearsal, so that one layer is a small part
+    # of the whole, as on the chip
+    hf = dict(bench_run.hf_config(config), num_hidden_layers=6)
+    cfg = ModelConfig.from_hf_config(hf)
+    core = EngineCore(cfg, EngineConfig(
+        max_model_len=128, num_kv_blocks=32, max_num_seqs=2,
+        quantization="int8", seed=11))
+    rng = np.random.default_rng(3)
+    prompt = rng.integers(0, cfg.vocab_size, size=48).tolist()
+    # the engine's own greedy continuation, through its prefill and
+    # decode programs (the HTTP leg is end_to_end's)
+    ids, lps = greedy(core, prompt, 8)
+    rep = reference.compare(core.params, hf, prompt, ids, lps,
+                            forward=ref.logits_for)
+    check(rep["ok"], f"{name}: engine within {reference.TOL_STD} std of "
+          f"{os.path.basename(ref.__file__)} (logprob err "
+          f"{rep['worst_logprob_err_std']:.3f}"
+          f", argmax gap {rep['worst_argmax_gap_std']:.3f})")
+    breakages = ref.breakages_for(hf)
+    check(len(breakages) > 0, f"{name}: breakages to catch: {breakages}")
+    for broken in breakages:
+        rep = reference.compare(core.params, hf, prompt, ids, lps,
+                                broken=broken, forward=ref.logits_for)
+        check(not rep["ok"], f"{name}: {broken} trips the tolerance "
+              f"(logprob err {rep['worst_logprob_err_std']:.2f} std, "
+              f"argmax gap {rep['worst_argmax_gap_std']:.2f} std)")
+    del core
     jax.clear_caches()
 
 
@@ -299,7 +392,12 @@ def main() -> int:
     generator()
     window_arithmetic()
     trace_reduction()
-    reference_check()
+    reader_check()
+    reference_lookup()
+    names = fixtures()
+    check(len(names) >= 3, f"fixtures found by their file names: {names}")
+    for name in names:
+        reference_check(name)
     end_to_end()
     print("selftest: all passed")
     return 0

@@ -1,0 +1,47 @@
+"""The cheap parts of ``selftest.py`` as pytest cases, on the CPU:
+
+    JAX_PLATFORMS=cpu python3 -m pytest benchmark/test_selftest.py -q -p no:cacheprovider
+
+One case per check and, for the reference, one per fixture, so that each
+counts. The served rehearsal (``selftest.end_to_end``, minutes) stays in
+``selftest.py``. Each case has a limit of its own (no pytest-timeout here:
+an alarm). Tier 1 runs ``tests/`` only: until a PR that may touch ``tests/``
+imports these cases there, they run by the command above (PERF.md §7).
+"""
+
+import os
+import signal
+import sys
+
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import selftest  # noqa: E402
+
+LIMIT_S = 60
+CHECKS = (selftest.arithmetic, selftest.generator, selftest.window_arithmetic,
+          selftest.trace_reduction, selftest.reader_check,
+          selftest.reference_lookup)
+
+
+@pytest.fixture(autouse=True)
+def limit():
+    def over(*_):
+        raise TimeoutError(f"the case ran over its {LIMIT_S} s")
+    before = signal.signal(signal.SIGALRM, over)
+    signal.alarm(LIMIT_S)
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, before)
+
+
+@pytest.mark.parametrize("check", CHECKS, ids=lambda f: f.__name__)
+def test_yardstick(check):
+    check()
+
+
+@pytest.mark.parametrize("fixture", selftest.fixtures())
+def test_engine_within_tolerance_of_its_reference(fixture):
+    selftest.reference_check(fixture)

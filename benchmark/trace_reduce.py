@@ -7,7 +7,10 @@ events (``fixtures/trace_events.json``) with no profiler at hand:
 
 * ``device_events(path)`` → per device, the ``(name, start_ns, dur_ns)`` of
   every event on the device's operation line;
-* ``reduce(events_by_device, window)`` → the numbers.
+* ``reduce(events_by_device, window)`` → the numbers: what the result line
+  prints (busy time, the longest operations and idle gaps) and, for the
+  per-layer readers, ``ops``: every operation with its seconds and count.
+  ``program_table`` does the same for the line of whole programs.
 
 Busy time is the union of the intervals in which an operation ran on the
 device, so operations that overlap (a copy under a matmul) count once. A
@@ -67,8 +70,23 @@ def short_name(hlo: str) -> str:
     return " ".join(p for p in parts if p)[:120]
 
 
-def device_events(path: str, lines: tuple = OP_LINES) -> dict:
-    """{device plane name: [(short op name, start_ns, dur_ns), ...]}"""
+def scope_of(hlo: str):
+    """The ``jax.named_scope`` path of an op whose HLO line carries its
+    ``op_name`` (``jit(decode_k)/decode/attention/dot_general`` →
+    ``decode/attention``: without the program and the primitive), else
+    None. The TPU profiler of JAX 0.9.0 / libtpu 0.0.34 writes no
+    ``op_name`` into its events (PERF.md section 7)."""
+    m = re.search(r'op_name="([^"]+)"', hlo)
+    if not m:
+        return None
+    return "/".join(m.group(1).split("/")[1:-1]) or None
+
+
+def device_events(path: str, lines: tuple = OP_LINES,
+                  scopes: dict = None) -> dict:
+    """{device plane name: [(short op name, start_ns, dur_ns), ...]}.
+    ``scopes``, where given, is filled with {short op name: named-scope
+    path} for the ops whose events carry one (``scope_of``)."""
     from jax.profiler import ProfileData
     out = {}
     for plane in ProfileData.from_file(path).planes:
@@ -76,26 +94,35 @@ def device_events(path: str, lines: tuple = OP_LINES) -> dict:
             continue
         events = []
         for line in plane.lines:
-            if line.name in lines:
-                events.extend((short_name(e.name), int(e.start_ns),
-                               int(e.duration_ns)) for e in line.events)
+            if line.name not in lines:
+                continue
+            for e in line.events:
+                name = short_name(e.name)
+                events.append((name, int(e.start_ns), int(e.duration_ns)))
+                if scopes is not None and name not in scopes:
+                    scope = scope_of(e.name)
+                    if scope:
+                        scopes[name] = scope
         out[plane.name] = events
     return out
 
 
-def program_seconds(path: str) -> list:
-    """[(program name, seconds)] by device time, from the line that holds
-    one event per executed program; the compiler's fingerprint in
-    brackets is dropped, so every bucket of ``jit_prefill`` adds up."""
+def program_table(events_by_device: dict) -> list:
+    """[[program name, seconds, dispatches]] by device time, from the
+    events of the line that holds one per executed program
+    (``device_events(path, lines=(MODULE_LINE,))``), averaged over the
+    devices; the compiler's fingerprint in brackets is dropped, so every
+    bucket of ``jit_prefill`` adds up."""
     totals = {}
-    by_dev = device_events(path, lines=(MODULE_LINE,))
-    for events in by_dev.values():
+    for events in events_by_device.values():
         for name, _, dur in events:
-            key = re.sub(r"\(\d+\)$", "", name)
-            totals[key] = totals.get(key, 0) + dur
-    n = max(1, len(by_dev))
-    return sorted(((k, v / n / 1e9) for k, v in totals.items()),
-                  key=lambda kv: -kv[1])
+            row = totals.setdefault(re.sub(r"\(\d+\)$", "", name), [0, 0])
+            row[0] += dur
+            row[1] += 1
+    n = max(1, len(events_by_device))
+    return sorted(([k, ns / n / 1e9, count / n]
+                   for k, (ns, count) in totals.items()),
+                  key=lambda row: -row[1])
 
 
 def host_events(path: str, name: str) -> list:
@@ -142,8 +169,14 @@ def _leaves(events: list) -> list:
 
 
 def reduce(events_by_device: dict, window: tuple = None,
-           host_spans: list = None, top: int = 10) -> dict:
-    """→ {"busy_s", "window_s", "device_ops", "idle_gaps"}.
+           host_spans: list = None, top: int = 10,
+           scopes: dict = None) -> dict:
+    """→ {"busy_s", "window_s", "device_ops", "idle_gaps", "ops"} and,
+    where ``scopes`` ({op name: named-scope path}, from
+    ``device_events``) names any op, "scopes".
+
+    ``device_ops`` are the ``top`` operations by time, ``ops`` all of them
+    as [name, seconds, count]; both leave out an op that encloses others.
 
     ``window``: (start_ns, end_ns) on the trace's clock; by default from
     the first device event to the last. ``busy_s`` is averaged over the
@@ -157,7 +190,7 @@ def reduce(events_by_device: dict, window: tuple = None,
         ends = [s + d for evs in events_by_device.values() for _, s, d in evs]
         window = (min(starts), max(ends))
     w0, w1 = window
-    busy, per_op, gaps = [], {}, []
+    busy, per_op, gaps = [], {}, []      # per_op: name → [ns, count]
     for events in events_by_device.values():
         clipped = [(max(s, w0), min(s + d, w1)) for _, s, d in events
                    if s < w1 and s + d > w0]
@@ -166,7 +199,9 @@ def reduce(events_by_device: dict, window: tuple = None,
         for name, s, d in _leaves(events):
             lo, hi = max(s, w0), min(s + d, w1)
             if hi > lo:
-                per_op[name] = per_op.get(name, 0) + (hi - lo)
+                row = per_op.setdefault(name, [0, 0])
+                row[0] += hi - lo
+                row[1] += 1
         edges = [w0] + [x for s, e in merged for x in (s, e)] + [w1]
         gaps.extend((edges[i], edges[i + 1])
                     for i in range(0, len(edges), 2)
@@ -185,12 +220,23 @@ def reduce(events_by_device: dict, window: tuple = None,
     for gap in gaps:
         key = label(gap)
         by_label[key] = by_label.get(key, 0) + (gap[1] - gap[0])
-    ops = sorted(per_op.items(), key=lambda kv: -kv[1])[:top]
-    return {
+    ops = sorted(([name, ns / n_dev / 1e9, count / n_dev]
+                  for name, (ns, count) in per_op.items()),
+                 key=lambda row: -row[1])
+    out = {
         "busy_s": sum(busy) / n_dev / 1e9,
         "window_s": (w1 - w0) / 1e9,
-        "device_ops": [[name, ns / n_dev / 1e9] for name, ns in ops],
+        "device_ops": [row[:2] for row in ops[:top]],
+        "ops": ops,
         "idle_gaps": [[name, ns / n_dev / 1e9] for name, ns in
                       sorted(by_label.items(), key=lambda kv: -kv[1])[:top]],
         "longest_gap_s": max((g[1] - g[0] for g in gaps), default=0) / 1e9,
     }
+    by_scope = {}
+    for name, seconds, _ in ops:
+        if name in (scopes or ()):
+            by_scope[scopes[name]] = by_scope.get(scopes[name], 0) + seconds
+    if by_scope:
+        out["scopes"] = sorted(([k, v] for k, v in by_scope.items()),
+                               key=lambda row: -row[1])
+    return out
