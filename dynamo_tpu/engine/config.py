@@ -86,11 +86,10 @@ class ModelConfig:
     shared_expert_size: int = 0
     # qwen3-style per-head q/k norm
     qk_norm: bool = False
-    # MLA (deepseek_v2): latent-KV attention dims for models/mla.py.
-    # q_lora_rank 0 = plain q_proj (the -Lite layout). NOTE: only the
-    # model module consumes these so far — from_hf_config does not parse
-    # them and the engine dispatch is pending (from_hf_config still
-    # rejects deepseek_v2/v3); currently set by tests only.
+    # MLA (deepseek_v2 / deepseek_v3 / deepseek_v32): latent-KV attention
+    # dims for models/mla.py, parsed by from_hf_config; kv_lora_rank > 0
+    # is what EngineCore dispatches on (core.is_mla). q_lora_rank 0 =
+    # plain q_proj (the -Lite layout).
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -109,6 +108,22 @@ class ModelConfig:
     # generation never runs — the loader skips exactly that many and
     # still fails loudly on any further excess layer
     num_nextn_predict_layers: int = 0
+    # deepseek_v32 sparse attention (models/mla.py): a lightning indexer
+    # of index_n_heads × index_head_dim scores every cached token per
+    # query, and the main attention reads only the index_topk best.
+    # 0 = no indexer: v2/v3 programs are unchanged. The index keys live
+    # in a second per-token cache beside the latent pool (kv["idx"]).
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # one chip's share of the routed experts (guide "model-configs" §4):
+    # num_experts counts the experts HELD here (the stacked tensors'
+    # E axis); the router keeps the published width num_experts_total
+    # (0 = all are held) and this chip holds the experts
+    # [expert_share_index * num_experts, +num_experts). What the absent
+    # experts would add is left out; nothing stands in for them.
+    num_experts_total: int = 0
+    expert_share_index: int = 0
     first_k_dense: int = 0
     dense_intermediate_size: int = 0
     routed_scaling: float = 1.0
@@ -133,6 +148,18 @@ class ModelConfig:
     # partitioning rule (models/llama.py _lm_head_kernel_ok)
     lm_head_pallas: bool = True
 
+    @property
+    def router_width(self) -> int:
+        """Experts the router scores: the published count, of which
+        num_experts are held here."""
+        return self.num_experts_total or self.num_experts
+
+    @property
+    def is_deepseek_v3(self) -> bool:
+        """The v3 generation's attention-score and routing conventions
+        (deepseek_v32 is v3 plus the indexer)."""
+        return self.model_type in ("deepseek_v3", "deepseek_v32")
+
     @classmethod
     def from_hf_config(cls, cfg: Dict[str, Any]) -> "ModelConfig":
         mt = str(cfg.get("model_type", "llama"))
@@ -148,6 +175,32 @@ class ModelConfig:
             raise ValueError(
                 f"unsupported shared-expert MoE family {mt!r} "
                 f"(qwen2_moe is the implemented shared-expert family)")
+        v32 = mt == "deepseek_v32"
+        if v32:
+            # v3's block plus the lightning indexer: every v3 check below
+            # applies, and the three indexer sizes must be there
+            for key in ("index_n_heads", "index_head_dim", "index_topk"):
+                if not cfg.get(key):
+                    raise ValueError(
+                        f"deepseek_v32 needs {key} (the lightning "
+                        f"indexer's sizes); a config without them is "
+                        f"deepseek_v3")
+            if int(cfg["index_head_dim"]) < int(
+                    cfg.get("qk_rope_head_dim", 64)):
+                raise ValueError(
+                    "deepseek_v32 index_head_dim is narrower than "
+                    "qk_rope_head_dim: the indexer ropes its first "
+                    "qk_rope_head_dim lanes")
+            if not int(cfg.get("q_lora_rank", 1536) or 0):
+                raise ValueError(
+                    "deepseek_v32 without q_lora_rank is not implemented "
+                    "(the indexer's queries project from the q-LoRA "
+                    "latent)")
+            mt = "deepseek_v3"          # the v3 branch, from here on
+        elif any(cfg.get(k) for k in ("index_n_heads", "index_topk")):
+            raise ValueError(
+                f"{mt!r} with index_n_heads/index_topk is not implemented "
+                f"(the indexer is deepseek_v32's)")
         if mt == "deepseek_v3":
             # models/mla.py implements exactly HF DeepseekV3's semantics:
             # sigmoid-scored noaux_tc routing, interleaved rope, bf16
@@ -277,6 +330,25 @@ class ModelConfig:
                 attention_factor=float(
                     raw_rs.get("attention_factor", 0.0) or 0.0),
             )
+        # one chip's share of the experts: n_routed_experts counts those
+        # held here, n_routed_experts_published the router's width (their
+        # quotient is the number of shares) and expert_share_index says
+        # which share this is (both travel in the served config.json)
+        n_total = int(cfg.get("n_routed_experts_published") or 0)
+        share_index = int(cfg.get("expert_share_index") or 0)
+        if n_total or share_index:
+            if not is_ds or not n_experts:
+                raise ValueError(
+                    "an expert share (n_routed_experts_published / "
+                    "expert_share_index) is implemented for the deepseek "
+                    "MoE block only")
+            if (n_total % n_experts
+                    or not 0 <= share_index < n_total // n_experts):
+                raise ValueError(
+                    f"expert share {share_index} holding {n_experts} "
+                    f"experts does not divide the published {n_total}")
+            if n_total == n_experts:
+                n_total = 0             # one share of one: nothing cut
         return cls(
             model_type=cfg.get("model_type", "llama"),
             vocab_size=int(cfg.get("vocab_size", 32000)),
@@ -366,6 +438,11 @@ class ModelConfig:
             num_nextn_predict_layers=int(
                 cfg.get("num_nextn_predict_layers", 1) or 0)
             if mt == "deepseek_v3" else 0,
+            index_n_heads=int(cfg["index_n_heads"]) if v32 else 0,
+            index_head_dim=int(cfg["index_head_dim"]) if v32 else 0,
+            index_topk=int(cfg["index_topk"]) if v32 else 0,
+            num_experts_total=n_total,
+            expert_share_index=share_index,
             q_lora_rank=int(cfg.get("q_lora_rank",
                                     1536 if is_ds else 0) or 0),
             kv_lora_rank=int(cfg.get("kv_lora_rank", 512) or 0)

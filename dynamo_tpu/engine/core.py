@@ -211,6 +211,15 @@ class EngineCore:
                 raise NotImplementedError(
                     "MLA + int4 weight quantization is not integrated "
                     "yet (int8 is)")
+            refused = mla.dsa_refusals(model_cfg, engine_cfg, mesh)
+            if refused:
+                # deepseek_v32: every path that ships or quantises cache
+                # rows either carries the index keys or refuses HERE, at
+                # build (the matrix: docs/dsa.md)
+                raise NotImplementedError(
+                    "deepseek_v32 sparse attention (index_topk > 0) / an "
+                    "expert share is not implemented with: "
+                    + "; ".join(refused))
         else:
             self.model_mod = llama
         if (model_cfg.sliding_window is not None
@@ -1079,6 +1088,14 @@ class EngineCore:
 
     # ------------------------------------------------------------- frontend
     async def submit(self, req: EngineRequest) -> None:
+        if self.model_cfg.index_topk > 0 and (
+                req.precomputed is not None or req.handoff is not None
+                or req.handoff_device):
+            # neither disagg plane ships the index keys (docs/dsa.md);
+            # raised here, to the caller, not inside the engine loop
+            raise NotImplementedError(
+                "disaggregated prefill/decode hand-off is not implemented "
+                "with deepseek_v32's index-key cache")
         if req.precomputed is not None:
             # validate the payload layout HERE, synchronously: the caller
             # gets the error; a raise inside the engine loop's admission
@@ -1735,6 +1752,10 @@ class EngineCore:
         construction (kv_remote_dir) may already have built an
         object-backed store — the fabric wraps that same store, so this
         is idempotent on the manager side."""
+        if self.model_cfg.index_topk > 0:
+            raise NotImplementedError(
+                "the KV fabric ships latent rows only; it is not "
+                "implemented with deepseek_v32's index-key cache")
         self.kv_fabric = fabric
         self.remote_store = fabric.store
         self.kv_manager.remote_store = fabric.store
@@ -3011,10 +3032,18 @@ class EngineCore:
         K = pending["K"]
         capacity = self.M * self.cfg.kv_block_size
         applied = []
+        # what attention had to cover: the live context of every step
+        # applied (ctx_tokens) and, under deepseek_v32's selection, the
+        # rows it is configured to read of it (sel_tokens; the same number
+        # with no indexer). Host arithmetic on positions: a descriptor of
+        # the traffic for cost models, not a reading of the device
+        topk = self.model_cfg.index_topk
+        ctx_tokens = sel_tokens = 0
         for i, req in enumerate(pending["reqs"]):
             if req is None or self.slots[i] is not req:
                 continue
             n_applied = 0
+            pos0 = req.pos
             input_tok = req.last_token
             for k in range(K):
                 if req.cancelled:
@@ -3060,6 +3089,9 @@ class EngineCore:
                     break                      # finished: drop device overrun
                 input_tok = tok
             applied.append((i, req.rid, n_applied))
+            for ctx in range(pos0 + 1, pos0 + 1 + n_applied):
+                ctx_tokens += ctx
+                sel_tokens += min(ctx, topk) if topk else ctx
         if self.recorder is not None and pending.get("id") is not None:
             self.recorder.rec("harvest", id=pending["id"],
                               toks=toks_k.copy(), applied=applied)
@@ -3077,6 +3109,7 @@ class EngineCore:
             chained=sum(1 for i, _r, _n in applied if pending["mask"][i]),
             planned_tokens=K * len(applied),
             emitted=sum(n for _i, _r, n in applied),
+            ctx_tokens=ctx_tokens, sel_tokens=sel_tokens,
             **({"drain": pending["drain"]} if "drain" in pending else {}))
 
     # --------------------------------------------------------------- ragged

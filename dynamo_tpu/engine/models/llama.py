@@ -320,15 +320,43 @@ def init_one_param(cfg: ModelConfig, name: str, shape: tuple,
     tensor at a time without materializing the full bf16 tree."""
     if name.endswith(("ln1", "ln2", "ln1_post", "ln2_post",
                       "q_norm", "k_norm",
-                      "kv_norm", "q_a_norm")) or name == "final_norm":
+                      "kv_norm", "q_a_norm",
+                      "idx_k_norm_w")) or name == "final_norm":
         return (jnp.zeros(shape, dtype=dtype)
                 if cfg.norm_plus_one
                 else jnp.ones(shape, dtype=dtype))
-    if name.endswith(("bq", "bk", "bv", "router_bias")):
+    if name.endswith(("bq", "bk", "bv", "router_bias", "idx_k_norm_b")):
         return jnp.zeros(shape, dtype=dtype)
     fan_in = shape[-2] if len(shape) > 1 else shape[-1]
     return (jax.random.normal(sub, shape, dtype=jnp.float32)
-            * (fan_in ** -0.5)).astype(dtype)
+            * seeded_std(cfg, name, fan_in)).astype(dtype)
+
+
+# Seeded weights of a sparse-attention model (ModelConfig.index_topk > 0).
+# Its top-k is a step: a bf16 program and a float32 reference disagree on
+# the members nearest the threshold, and at fan_in^-0.5 throughout (an
+# embedding row of norm 0.7 under branches of norm 85) the stream is made
+# of branch outputs alone, so that disagreement is passed on whole, layer
+# after layer (docs/dsa.md "Random weights"). So the stream carries the
+# embedding at the scale of a normalised branch input and every branch is
+# a perturbation of it, as in a trained model: "embed" is a standard
+# deviation, the others factors on fan_in^-0.5 of the projections that
+# write into the stream (attention, dense and shared MLPs, routed experts);
+# what they were measured against: PERF.md section 6, PR 31.
+SPARSE_SEEDED = {"embed": 1.0, "wo": 0.5, "down": 0.25, "moe_down": 0.1}
+
+
+def seeded_std(cfg: ModelConfig, name: str, fan_in: int) -> float:
+    """Standard deviation of a --random-weights matrix: fan_in^-0.5, but
+    see SPARSE_SEEDED."""
+    std = fan_in ** -0.5
+    if cfg.index_topk > 0:
+        if name == "embed":
+            return SPARSE_SEEDED["embed"]
+        for suffix in ("moe_down", "down", "wo"):
+            if name.endswith(suffix):
+                return std * SPARSE_SEEDED[suffix]
+    return std
 
 
 def init_params(cfg: ModelConfig, key: jax.Array,

@@ -40,6 +40,39 @@ rows ship whole as one opaque wire head — llm/kv/offload.py), both
 disagg planes, and sequence-parallel ring prefill (prefill_forward_sp:
 the ring moves compressed latent rows and accumulates in rank-space).
 Still refusing loudly: int4 weights.
+
+deepseek_v32 (``index_topk > 0``; docs/dsa.md) adds DeepSeek Sparse
+Attention on top of the v3 block:
+
+- the **lightning indexer**: per layer ``qI = qr·WqI_b → [T, J, dI]`` from
+  the q-LoRA latent, one index key ``kI = LayerNorm(a·WkI)`` per token,
+  rope on the FIRST ``qk_rope_head_dim`` lanes of both in the HALF-SPLIT
+  convention (not the main path's interleaved one), head weights
+  ``w = a·Ww · J^-0.5 · dI^-0.5``, and the score
+  ``I[t, s] = Σ_j w[t, j] · relu(qI[t, j]·kI[s])``;
+- a **second per-token cache** for the index keys, ``kv["idx"]``
+  ``[L, NTOK, dI]``, beside the latent pool and under the same block ids
+  (not wider latent rows: the indexer reads dI lanes per token, not
+  rank+rope+dI). ``_run_layers`` writes both at ``slots``; the block
+  copies of engine/block_copy.py move every array of the pool dict, so
+  prefix reuse, defrag and preemption carry both;
+- **select, then attend**: an exact top-k of the masked scores,
+  ``k = min(index_topk, table capacity)`` (one stable sort that carries
+  positions and pool rows: ``_select``), then the absorbed attention
+  over the gathered rows only. ``ctx <= index_topk`` selects every valid
+  row and equals the dense path. Prefill blocks its queries
+  (``DSA_QUERY_BLOCK``) so that nothing of size heads × chunk × table
+  exists;
+- **one chip's share of the experts** (``num_experts_total > 0``): the
+  router, its bias and the groups keep the published width; the expert
+  stacks hold ``num_experts`` of them, and ``_moe_mlp`` adds what the
+  held experts give for the tokens routed to them and drops the rest.
+
+What carries ``idx`` and what refuses is decided once, at engine build
+(``dsa_refusals``): ragged dispatch, speculative verify, sequence-parallel
+prefill, every mesh (tp/sp/pp/ep/dp), int8 KV pools, the host/disk/remote
+tiers, the KV fabric and both disagg planes refuse while ``index_topk > 0``.
+The multi-token-prediction layer is not served (weights.py skips it).
 """
 
 from __future__ import annotations
@@ -138,8 +171,7 @@ def softmax_scale(cfg: ModelConfig) -> float:
     corrections never double-apply)."""
     s = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
     rs = cfg.rope_scaling
-    if (cfg.model_type == "deepseek_v3" and rs is not None
-            and rs.mscale_all_dim):
+    if cfg.is_deepseek_v3 and rs is not None and rs.mscale_all_dim:
         m = get_mscale(rs.factor, rs.mscale_all_dim)
         s *= m * m
     return s
@@ -191,7 +223,11 @@ def param_shapes(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
         # experts — two parameter stacks, two scans (_run_layers)
         k = cfg.first_k_dense
         Lm = L - k
+        # E: the experts HELD here; the router scores all the published
+        # ones (cfg.router_width — the same number unless this chip holds
+        # a share)
         E, F = cfg.num_experts, cfg.intermediate_size
+        R = cfg.router_width
         if k > 0:
             Fd = cfg.dense_intermediate_size or F
             shapes.update({
@@ -200,7 +236,7 @@ def param_shapes(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
                 "layers.dense_down": (k, Fd, D),
             })
         shapes.update({
-            "layers.router": (Lm, D, E),
+            "layers.router": (Lm, D, R),
             "layers.moe_gate": (Lm, E, D, F),
             "layers.moe_up": (Lm, E, D, F),
             "layers.moe_down": (Lm, E, F, D),
@@ -208,7 +244,7 @@ def param_shapes(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
         if cfg.moe_routing == "sigmoid_noaux":
             # deepseek_v3: the router's e_score_correction_bias buffer —
             # it biases expert CHOICE only, never the mixing weights
-            shapes["layers.router_bias"] = (Lm, E)
+            shapes["layers.router_bias"] = (Lm, R)
         if cfg.shared_expert_size > 0:
             Fs = cfg.shared_expert_size
             shapes.update({
@@ -230,6 +266,18 @@ def param_shapes(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
         })
     else:
         shapes["layers.wq"] = (L, D, H * qk)
+    if cfg.index_topk > 0:
+        # deepseek_v32 lightning indexer (int8 under --quantization int8:
+        # idx_wq_b, idx_wk and idx_w go through mm(); the key LayerNorm's
+        # weight and bias stay in the load dtype)
+        J, dI = cfg.index_n_heads, cfg.index_head_dim
+        shapes.update({
+            "layers.idx_wq_b": (L, cfg.q_lora_rank, J * dI),
+            "layers.idx_wk": (L, D, dI),
+            "layers.idx_k_norm_w": (L, dI),
+            "layers.idx_k_norm_b": (L, dI),
+            "layers.idx_w": (L, D, J),
+        })
     if not cfg.tie_word_embeddings:
         shapes["lm_head"] = (D, cfg.vocab_size)
     return shapes
@@ -276,9 +324,20 @@ def init_kv_cache(cfg: ModelConfig, num_blocks: int,
         raise ValueError(f"unknown kv quantization {quantization!r} "
                          f"(none|int8)")
     W = latent_row_lanes(cfg, quantization)
-    return {"kv": jnp.zeros(
+    kv = {"kv": jnp.zeros(
         (cfg.num_layers, num_blocks * block_size, W),
         dtype=jnp.int8 if quantization == "int8" else dtype)}
+    if cfg.index_topk > 0:
+        if quantization != "none":
+            raise NotImplementedError(
+                "kv_quantization with the deepseek_v32 index-key cache is "
+                "not implemented (the index keys have no int8 encoding)")
+        # the indexer's second per-token cache, under the same block ids
+        # ("kv" stays the first key: pool-agnostic code reads it)
+        kv["idx"] = jnp.zeros(
+            (cfg.num_layers, num_blocks * block_size, cfg.index_head_dim),
+            dtype=dtype)
+    return kv
 
 
 # ---------------------------------------------------------------------------
@@ -287,16 +346,192 @@ def init_kv_cache(cfg: ModelConfig, num_blocks: int,
 
 
 def _q_proj(lp, hn, cfg: ModelConfig):
-    """[N, D] -> (q_nope [N, H, dn], q_pe [N, H, dr])."""
+    """[N, D] -> (q_nope [N, H, dn], q_pe [N, H, dr], qr): qr is the
+    normalised q-LoRA latent [N, q_lora_rank] (the deepseek_v32 indexer
+    projects its queries from it), None with a plain q_proj."""
     H = cfg.num_heads
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    qa = None
     if cfg.q_lora_rank > 0:
         qa = rms_norm(mm(hn, lp["wq_a"]), lp["q_a_norm"], cfg.rms_norm_eps)
         q = mm(qa, lp["wq_b"])
     else:
         q = mm(hn, lp["wq"])
     q = q.reshape(hn.shape[0], H, dn + dr)
-    return q[..., :dn], q[..., dn:]
+    return q[..., :dn], q[..., dn:], qa
+
+
+# ---------------------------------------------------------------------------
+# deepseek_v32: the lightning indexer, the selection, the sparse attend
+# ---------------------------------------------------------------------------
+
+# the indexer's key LayerNorm (the model repository's LayerNorm default)
+INDEX_NORM_EPS = 1e-6
+# queries a prefill selects and attends for at a time: bounds the index
+# scores [J, block, table] and the gathered rows [block, topk, W]
+DSA_QUERY_BLOCK = 32
+
+
+def apply_rope_half_split(x: jax.Array, positions: jax.Array,
+                          inv_freq: jax.Array,
+                          scaling: float = 1.0) -> jax.Array:
+    """x [T, ..., d]: lane i pairs with lane i + d/2 (rotate_half, the
+    NON-interleaved convention the indexer uses), angle pos·inv_freq[i]."""
+    ang = positions[:, None].astype(jnp.float32) * inv_freq[None, :]
+    cos, sin = jnp.cos(ang) * scaling, jnp.sin(ang) * scaling
+    for _ in range(x.ndim - 2):
+        cos, sin = cos[:, None], sin[:, None]
+    half = x.shape[-1] // 2
+    a = x[..., :half].astype(jnp.float32)
+    b = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
+                           axis=-1).astype(x.dtype)
+
+
+def _indexer_proj(lp, hn, qr, positions, cfg: ModelConfig):
+    """→ (qI [N, J, dI], kI [N, dI], w [N, J] float32): the indexer's
+    queries from the q-LoRA latent, this token's index key (LayerNorm with
+    weight and bias, one head) and the per-head weights, already scaled by
+    J^-0.5 · dI^-0.5. Rope turns the first qk_rope_head_dim lanes of qI
+    and kI, half-split, with the main path's frequencies."""
+    J, dI, dr = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+    inv_np, att = rope_params(cfg)
+    inv = jnp.asarray(inv_np)
+    qI = mm(qr, lp["idx_wq_b"]).reshape(hn.shape[0], J, dI)
+    qI = jnp.concatenate(
+        [apply_rope_half_split(qI[..., :dr], positions, inv, att),
+         qI[..., dr:]], axis=-1)
+    k = mm(hn, lp["idx_wk"]).astype(jnp.float32)
+    mu = jnp.mean(k, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(k - mu), axis=-1, keepdims=True)
+    k = ((k - mu) * jax.lax.rsqrt(var + INDEX_NORM_EPS)
+         * lp["idx_k_norm_w"].astype(jnp.float32)
+         + lp["idx_k_norm_b"].astype(jnp.float32))
+    kI = jnp.concatenate(
+        [apply_rope_half_split(k[..., :dr], positions, inv, att),
+         k[..., dr:]], axis=-1).astype(hn.dtype)
+    w = mm(hn, lp["idx_w"]).astype(jnp.float32) * (J ** -0.5 * dI ** -0.5)
+    return qI, kI, w
+
+
+def _index_scores(qI, w, keys) -> jax.Array:
+    """I[n, s] = Σ_j w[n, j] · relu(qI[n, j]·keys[(n,) s]) in float32.
+    keys: [S, dI] shared by every query, or [N, S, dI] one table each."""
+    eq = "njd,sd->njs" if keys.ndim == 2 else "njd,nsd->njs"
+    dots = jnp.einsum(eq, qI.astype(keys.dtype), keys,
+                      preferred_element_type=jnp.float32)
+    return jnp.einsum("nj,njs->ns", w, jax.nn.relu(dots))
+
+
+def _select(scores, live, topk: int, slots):
+    """Exact top-k of the live positions, by one stable sort that carries
+    each position and its pool slot along (ties go to the lower position,
+    as lax.top_k's do): no gather of 4-byte ids follows it. scores, live
+    [N, S]; slots [N, S] or [S], the pool row of every table position. →
+    (positions [N, k], valid [N, k], pool rows [N, k]), k = min(index_topk,
+    table capacity); where fewer than k positions are live the tail is
+    marked invalid."""
+    N, S = scores.shape
+    k = min(topk, S)
+    neg = jnp.where(live, -scores, jnp.inf)
+    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (N, S))
+    neg, pos, rows = jax.lax.sort(
+        (neg, pos, jnp.broadcast_to(slots, (N, S))), dimension=1,
+        is_stable=True, num_keys=1)
+    return pos[:, :k], neg[:, :k] < jnp.inf, rows[:, :k]
+
+
+def _table_slots(tables_l, bsz: int) -> jax.Array:
+    """[..., M] block ids → [..., M * bsz]: the pool row of every position
+    of the table (arithmetic, no gather)."""
+    rows = tables_l[..., None] * bsz + jnp.arange(bsz, dtype=tables_l.dtype)
+    return rows.reshape(tables_l.shape[:-1] + (-1,))
+
+
+def _attend_selected(q_lat, q_pe, kv_flat, slot_ids, valid, scale: float,
+                     rank: int, dr: int) -> jax.Array:
+    """Absorbed attention over the gathered latent rows only.
+    q_lat [N, H, rank], q_pe [N, H, dr] float32; slot_ids [N, k] rows of
+    kv_flat; valid [N, k]. → probs·c [N, H, rank] float32."""
+    # every id is a live slot of the table: no out-of-range fill to build
+    rows = jnp.take(kv_flat, slot_ids, axis=0, mode="clip")  # [N, k, W]
+    c, k_pe = rows[..., :rank], rows[..., rank:rank + dr]
+    f32 = jnp.float32
+    scores = (jnp.einsum("nhr,nkr->nhk", q_lat.astype(rows.dtype), c,
+                         preferred_element_type=f32)
+              + jnp.einsum("nhd,nkd->nhk", q_pe.astype(rows.dtype), k_pe,
+                           preferred_element_type=f32)) * scale
+    scores = jnp.where(valid[:, None, :], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("nhk,nkr->nhr", probs.astype(rows.dtype), c,
+                      preferred_element_type=f32)
+
+
+def _keys_by_block(idx_flat, tables_l, bsz: int) -> jax.Array:
+    """The index keys of whole blocks: idx_flat [L*NTOK, dI] read as
+    [L*NB, bsz, dI] (splitting the row axis costs no relayout; one
+    bsz*dI-lane row per block would), tables_l [...] block ids →
+    [..., bsz, dI]. A block is 4 KB at the published sizes: the read is
+    by block, not by 256-byte row."""
+    dI = idx_flat.shape[-1]
+    return jnp.take(idx_flat.reshape(-1, bsz, dI), tables_l, axis=0,
+                    mode="clip")
+
+
+def _sparse_rows(q_lat, q_pe, index, kv_flat, tables_l, seq_lens,
+                 cfg: ModelConfig, bsz: int, scale: float) -> jax.Array:
+    """Select, then attend, for N query rows that each have a block table
+    of their own (decode; tables_l [N, M] holds layer-offset block ids,
+    seq_lens [N] the live positions). → probs·c [N, H, rank]."""
+    qI, w, idx_flat = index
+    N, M = tables_l.shape
+    S, dI = M * bsz, idx_flat.shape[-1]
+    with jax.named_scope("dsa_select"):
+        keys = _keys_by_block(idx_flat, tables_l, bsz).reshape(N, S, dI)
+        live = jnp.arange(S)[None, :] < seq_lens[:, None]
+        _, valid, slot_ids = _select(_index_scores(qI, w, keys), live,
+                                     cfg.index_topk,
+                                     _table_slots(tables_l, bsz))
+    with jax.named_scope("sparse_attention"):
+        return _attend_selected(q_lat, q_pe, kv_flat, slot_ids, valid,
+                                scale, cfg.kv_lora_rank,
+                                cfg.qk_rope_head_dim)
+
+
+def _sparse_chunk(q_nope, q_pe, w_k, index, kv_flat, table_l, positions,
+                  seq_len, cfg: ModelConfig, bsz: int,
+                  scale: float) -> jax.Array:
+    """Select, then attend, for the T queries of one prefill chunk, which
+    share one block table (table_l [M], layer-offset block ids), a block
+    of DSA_QUERY_BLOCK queries at a time: the index scores of a block are
+    [J, block, table] and the rows it gathers [block, topk, W]; nothing
+    of size chunk × table × heads exists. → probs·c [T, H, rank]."""
+    import math
+    qI, w, idx_flat = index
+    T, H = q_nope.shape[0], q_nope.shape[1]
+    S, dI = table_l.shape[0] * bsz, idx_flat.shape[-1]
+    keys = _keys_by_block(idx_flat, table_l, bsz).reshape(S, dI)
+    kpos = jnp.arange(S)[None, :]
+    slots = _table_slots(table_l, bsz)
+    TQ = math.gcd(T, DSA_QUERY_BLOCK)
+
+    def block(xs):
+        qn, qp, qi, wj, pos = xs
+        with jax.named_scope("dsa_select"):
+            live = (kpos <= pos[:, None]) & (kpos < seq_len)
+            _, valid, slot_ids = _select(_index_scores(qi, wj, keys), live,
+                                         cfg.index_topk, slots)
+        with jax.named_scope("sparse_attention"):
+            q_lat = jnp.einsum("thd,hrd->thr", qn.astype(jnp.float32),
+                               w_k.astype(jnp.float32))
+            return _attend_selected(q_lat, qp.astype(jnp.float32), kv_flat,
+                                    slot_ids, valid, scale,
+                                    cfg.kv_lora_rank, cfg.qk_rope_head_dim)
+
+    split = lambda a: a.reshape((T // TQ, TQ) + a.shape[1:])  # noqa: E731
+    ctx = jax.lax.map(block, tuple(split(a) for a in (
+        q_nope, q_pe, qI, w, positions)))
+    return ctx.reshape(T, H, cfg.kv_lora_rank)
 
 
 def _latent_rows(lp, hn, positions, cfg: ModelConfig):
@@ -323,7 +558,9 @@ def _moe_mlp(hn, lp, cfg: ModelConfig) -> jax.Array:
     renormalized over the top-k (+1e-20) when norm_topk_prob, then
     scaled. Shared experts are a plain additive swiglu either way.
     Experts run dense-over-E (llama.run_experts_dense)."""
-    N, E = hn.shape[0], cfg.num_experts
+    # E: the router's width — every published expert, of which this chip
+    # may hold a share (cfg.num_experts_total; below)
+    N, E = hn.shape[0], cfg.router_width
     logits = (hn.astype(jnp.float32)
               @ lp["router"].astype(jnp.float32))          # [N, E]
     if cfg.moe_routing == "sigmoid_noaux":
@@ -359,6 +596,13 @@ def _moe_mlp(hn, lp, cfg: ModelConfig) -> jax.Array:
         # NO renormalization: the HF-native reference never applies
         # norm_topk_prob (from_hf_config rejects true for deepseek_v2)
         top_w = top_w * cfg.routed_scaling
+    if cfg.num_experts_total:
+        # one chip's share: the choice and the weights above are over all
+        # the published experts; the stacks hold num_experts of them.
+        # run_experts_dense's one-hot is zero for an index outside
+        # [0, num_experts), so a chosen expert that lives elsewhere adds
+        # nothing here — and nothing stands in for it
+        top_idx = top_idx - cfg.expert_share_index * cfg.num_experts
     out = run_experts_dense(hn, lp.get("moe_gate"), lp.get("moe_up"),
                             lp["moe_down"], top_idx, top_w,
                             gateup_w=lp.get("moe_gateup"))
@@ -372,29 +616,36 @@ def _moe_mlp(hn, lp, cfg: ModelConfig) -> jax.Array:
 def _run_layers(params: Params, kv: KVCache, x: jax.Array,
                 positions: jax.Array, slots: jax.Array, cfg: ModelConfig,
                 attn_fn) -> Tuple[jax.Array, KVCache]:
-    """attn_fn(q_nope, q_pe, rows_new, kv_flat, lp, li) -> [N, H*v].
+    """attn_fn(q_nope, q_pe, rows_new, kv_flat, lp, li) -> [N, H*v]; with
+    an indexer (cfg.index_topk > 0) it is also given
+    ``index=(qI, w, idx_flat)``: this layer's index queries and head
+    weights, and the index-key cache flattened like kv_flat, with this
+    chunk's keys already written.
 
     deepseek hybrid sparsity (first_k_dense): the layer stacks split
     into a dense prefix and a MoE suffix, each its own lax.scan with the
-    SAME attention body — the latent pool carries across both, with li
+    SAME attention body — the pools carry across both, with li
     addressing rows globally."""
     L = cfg.num_layers
     stack = _layer_stack(params)
     NTOK = kv["kv"].shape[1]
     inv_np, att = rope_params(cfg)
     inv = jnp.asarray(inv_np)
+    dsa = cfg.index_topk > 0
 
     _ATTN = ("ln1", "ln2", "wq", "wq_a", "q_a_norm", "wq_b", "wkv_a",
-             "kv_norm", "wkv_b", "wo")
+             "kv_norm", "wkv_b", "wo", "idx_wq_b", "idx_wk",
+             "idx_k_norm_w", "idx_k_norm_b", "idx_w")
 
     quantized = kv["kv"].dtype == jnp.int8
 
     def make_layer(mlp_fn):
         def layer(carry, xs):
-            h, pool = carry
+            h, pools = carry
+            pool = pools["kv"]
             lp, li = xs["lp"], xs["i"]
             hn = rms_norm(h, lp["ln1"], cfg.rms_norm_eps)
-            q_nope, q_pe = _q_proj(lp, hn, cfg)
+            q_nope, q_pe, qr = _q_proj(lp, hn, cfg)
             q_pe = apply_rope_interleaved(q_pe, positions, inv, att)
             rows = _latent_rows(lp, hn, positions, cfg)
             if quantized:
@@ -418,15 +669,28 @@ def _run_layers(params: Params, kv: KVCache, x: jax.Array,
                 enc = jnp.pad(enc, ((0, 0), (0, pad)))
             pool = pool.at[li, slots, :].set(enc.astype(pool.dtype),
                                              mode="drop")
+            pools = dict(pools, kv=pool)
+            extra = {}
+            if dsa:
+                # the second row of the token: its index key, at the same
+                # slot of the same block
+                with jax.named_scope("indexer"):
+                    qI, kI, w = _indexer_proj(lp, hn, qr, positions, cfg)
+                idx = pools["idx"].at[li, slots, :].set(
+                    kI.astype(pools["idx"].dtype), mode="drop")
+                pools["idx"] = idx
+                extra["index"] = (qI, w,
+                                  idx.reshape(L * NTOK, idx.shape[2]))
             attn = attn_fn(q_nope, q_pe, rows,
-                           pool.reshape(L * NTOK, pool.shape[2]), lp, li)
+                           pool.reshape(L * NTOK, pool.shape[2]), lp, li,
+                           **extra)
             h = h + mm(attn, lp["wo"])
             hn2 = rms_norm(h, lp["ln2"], cfg.rms_norm_eps)
             h = h + mlp_fn(hn2, lp)
-            return (h, pool), None
+            return (h, pools), None
         return layer
 
-    pool = kv["kv"]
+    pools = dict(kv)
     if cfg.num_experts > 0:
         k = cfg.first_k_dense
         if k > 0:
@@ -437,11 +701,11 @@ def _run_layers(params: Params, kv: KVCache, x: jax.Array,
             else:
                 dense_lp.update({"gate": stack["dense_gate"],
                                  "up": stack["dense_up"]})
-            (x, pool), _ = jax.lax.scan(
+            (x, pools), _ = jax.lax.scan(
                 make_layer(lambda hn, lp: swiglu(
                     hn, lp.get("gate"), lp.get("up"), lp["down"],
                     cfg.hidden_act, gateup_w=lp.get("gateup"))),
-                (x, pool),
+                (x, pools),
                 {"lp": dense_lp, "i": jnp.arange(k, dtype=jnp.int32)})
         moe_lp = {n: stack[n][k:] for n in _ATTN if n in stack}
         for n in ("router", "router_bias", "moe_gate", "moe_up",
@@ -449,22 +713,50 @@ def _run_layers(params: Params, kv: KVCache, x: jax.Array,
                   "sh_down", "sh_gateup"):
             if n in stack:
                 moe_lp[n] = stack[n]
-        (x, pool), _ = jax.lax.scan(
+        (x, pools), _ = jax.lax.scan(
             make_layer(lambda hn, lp: _moe_mlp(hn, lp, cfg)),
-            (x, pool),
+            (x, pools),
             {"lp": moe_lp, "i": jnp.arange(k, L, dtype=jnp.int32)})
     else:
-        (x, pool), _ = jax.lax.scan(
+        (x, pools), _ = jax.lax.scan(
             make_layer(lambda hn, lp: swiglu(
                 hn, lp.get("gate"), lp.get("up"), lp["down"],
                 cfg.hidden_act, gateup_w=lp.get("gateup"))),
-            (x, pool),
+            (x, pools),
             {"lp": {k: v for k, v in stack.items()
                     if k in _ATTN or k in ("gate", "up", "down",
                                            "gateup")},
              "i": jnp.arange(L, dtype=jnp.int32)})
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    return x, {"kv": pool}
+    return x, pools
+
+
+def dsa_refusals(cfg: ModelConfig, engine_cfg, mesh) -> list:
+    """What this engine asks for that cannot carry the index-key cache
+    (or the expert share) yet — the refusal matrix of docs/dsa.md, read
+    once at engine build. → the offending options, by name; empty = go."""
+    bad = []
+    if cfg.index_topk > 0:
+        e = engine_cfg
+        checks = {
+            "--ragged (ragged_forward has no selection step)":
+                e.ragged_dispatch,
+            "--spec-k (the verify program is not tested with the "
+            "selection)": e.spec_k > 0,
+            "--kv-quantization (index keys have no int8 encoding)":
+                e.kv_quantization != "none",
+            "--host-kv-blocks / --kv-disk-* / --kv-remote-* (the tiers "
+            "ship latent rows only)": bool(
+                e.host_kv_blocks or e.kv_disk_blocks or e.kv_remote_dir),
+            "tp/sp/pp/ep/dp meshes (the index-key cache has no sharding "
+            "rule)": mesh is not None or max(
+                e.tp, e.sp, e.pp, e.ep, e.dp) > 1,
+        }
+        bad += [name for name, on in checks.items() if on]
+    if cfg.num_experts_total and (mesh is not None or engine_cfg.ep > 1):
+        bad.append("a mesh with an expert share (the share IS this chip's "
+                   "part of an expert-parallel layer)")
+    return bad
 
 
 def _split_wkv_b(lp, cfg: ModelConfig):
@@ -500,8 +792,17 @@ def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
         valid, block_table[positions // bsz] * bsz + positions % bsz, 0)
     seq_len = start_pos + true_len
 
-    def attn(q_nope, q_pe, _rows, kv_flat, lp, li):
+    def attn(q_nope, q_pe, _rows, kv_flat, lp, li, index=None):
         NTOK = kv_flat.shape[0] // cfg.num_layers
+        if index is not None:
+            # deepseek_v32: select, then attend over the selected rows
+            # only, in the absorbed form, a block of queries at a time
+            w_k, w_v = _split_wkv_b(lp, cfg)
+            ctx = _sparse_chunk(q_nope, q_pe, w_k, index, kv_flat,
+                                block_table + li * (NTOK // bsz),
+                                positions, seq_len, cfg, bsz, scale)
+            out = jnp.einsum("thr,hrd->thd", ctx, w_v.astype(jnp.float32))
+            return out.reshape(T, H * cfg.v_head_dim).astype(q_nope.dtype)
         idx = (flat_token_indices(block_table[None, :], bsz)[0]
                + li * NTOK)
         S = idx.shape[0]
@@ -553,6 +854,10 @@ def prefill_forward_sp(params: Params, kv: KVCache, tokens: jax.Array,
     from ...parallel.ring_attention import ring_attention_mla
 
     cfg, bsz = statics.cfg, statics.block_size
+    if cfg.index_topk > 0:
+        raise NotImplementedError(
+            "sequence-parallel prefill has no selection step "
+            "(deepseek_v32; docs/dsa.md)")
     T = tokens.shape[0]
     H = cfg.num_heads
     rank = cfg.kv_lora_rank
@@ -613,6 +918,10 @@ def ragged_forward(params: Params, kv: KVCache, tokens: jax.Array,
     from ..attention import _on_tpu, ragged_supported
 
     cfg, bsz = statics.cfg, statics.block_size
+    if cfg.index_topk > 0:
+        raise NotImplementedError(
+            "ragged dispatch has no selection step (deepseek_v32; "
+            "docs/dsa.md)")
     TT = tokens.shape[0]
     H = cfg.num_heads
     rank, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
@@ -735,7 +1044,7 @@ def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
              + positions % bsz)
     seq_lens = positions + 1
 
-    def attn(q_nope, q_pe, _rows, kv_flat, lp, li):
+    def attn(q_nope, q_pe, _rows, kv_flat, lp, li, index=None):
         NTOK = kv_flat.shape[0] // cfg.num_layers
         num_blocks = NTOK // bsz
         tables_l = block_tables + li * num_blocks
@@ -743,7 +1052,12 @@ def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
         # absorb the k expansion into the query: [B, H, rank]
         q_lat = jnp.einsum("bhd,hrd->bhr", q_nope.astype(jnp.float32),
                            w_k.astype(jnp.float32))
-        if kv_flat.dtype != jnp.int8:
+        if index is not None:
+            # deepseek_v32: the indexer picks the rows this step reads
+            ctx = _sparse_rows(q_lat, q_pe.astype(jnp.float32), index,
+                               kv_flat, tables_l, seq_lens, cfg, bsz,
+                               scale)
+        elif kv_flat.dtype != jnp.int8:
             from ..attention import paged_attention
             W = kv_flat.shape[-1]
             # Deliberate: the kernel dots q against pool rows in the

@@ -304,7 +304,11 @@ _LAYER_MATMULS = ("wq", "wk", "wv", "wo", "gate", "up", "down",
                   # contracts it raw in einsums (_split_wkv_b), and its
                   # [rank, H*(dn+dv)] bytes are small
                   "wq_a", "wq_b", "wkv_a",
-                  "dense_gate", "dense_up", "dense_down")
+                  "dense_gate", "dense_up", "dense_down",
+                  # deepseek_v32 lightning indexer: query up-projection,
+                  # key projection and head-weight projection, all
+                  # through mm() (its LayerNorm stays full precision)
+                  "idx_wq_b", "idx_wk", "idx_w")
 # MoE expert tensors [L, E, D, F] → per (L, E, out-channel) scales. For
 # mixtral-class models the experts ARE the weights, so leaving them bf16
 # would forfeit the whole int8 HBM-read win; the router stays full

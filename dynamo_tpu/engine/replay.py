@@ -356,9 +356,12 @@ def replay(core, events: List[dict], fingerprint: bool = False) -> dict:
     # the pool LAYOUT must match the recording core's (an int8-KV engine
     # replayed against a bf16 pool would retrace the unquantized branch
     # and report phantom divergence)
-    kv = llama.init_kv_cache(core.model_cfg, core.cfg.num_kv_blocks,
-                             core.cfg.kv_block_size, dtype=dtype,
-                             quantization=core.cfg.kv_quantization)
+    # ... and the model family's: an MLA core replays on a latent pool
+    # (with deepseek_v32's index-key cache beside it)
+    family = core.model_mod if getattr(core, "is_mla", False) else llama
+    kv = family.init_kv_cache(core.model_cfg, core.cfg.num_kv_blocks,
+                              core.cfg.kv_block_size, dtype=dtype,
+                              quantization=core.cfg.kv_quantization)
     out = {"prefill": {}, "dispatch": {}, "verify": {}, "ragged": {},
            "fingerprints": []}
     disp_toks: Dict[int, object] = {}

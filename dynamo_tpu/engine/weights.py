@@ -65,6 +65,12 @@ _LAYER_MAP = {
     "self_attn.kv_a_proj_with_mqa.weight": ("wkv_a", True),
     "self_attn.kv_a_layernorm.weight": ("kv_norm", False),
     "self_attn.kv_b_proj.weight": ("wkv_b", True),
+    # deepseek_v32 lightning indexer (the model repository's names)
+    "self_attn.indexer.wq_b.weight": ("idx_wq_b", True),
+    "self_attn.indexer.wk.weight": ("idx_wk", True),
+    "self_attn.indexer.k_norm.weight": ("idx_k_norm_w", False),
+    "self_attn.indexer.k_norm.bias": ("idx_k_norm_b", False),
+    "self_attn.indexer.weights_proj.weight": ("idx_w", True),
 }
 
 # mixtral expert sub-weights: w1=gate, w3=up, w2=down (all torch [out, in])
@@ -87,7 +93,7 @@ def _layer_map_for(cfg: ModelConfig) -> Dict[str, tuple]:
         layer_map["post_attention_layernorm.weight"] = ("ln1_post", False)
         layer_map["pre_feedforward_layernorm.weight"] = ("ln2", False)
         layer_map["post_feedforward_layernorm.weight"] = ("ln2_post", False)
-    if (cfg.model_type in ("deepseek_v2", "deepseek_v3")
+    if (cfg.kv_lora_rank > 0
             and cfg.num_experts > 0):
         # hybrid sparsity: mlp.*_proj exists only on the dense-prefix
         # layers and lands in the dense_* stacks (_partial_ranges)
@@ -132,7 +138,7 @@ def _partial_ranges(cfg: ModelConfig):
     """Stacked keys that cover only a LAYER RANGE (deepseek hybrid
     sparsity): key -> (lo, hi) global layer bounds. Empty for uniform
     families."""
-    if (cfg.model_type not in ("deepseek_v2", "deepseek_v3")
+    if (cfg.kv_lora_rank == 0
             or cfg.num_experts == 0):
         return {}
     k, L = cfg.first_k_dense, cfg.num_layers
@@ -307,9 +313,15 @@ def load_llama_params(model_dir: str, cfg: Optional[ModelConfig] = None,
                 key = _EXPERT_MAP.get(wname)
                 if key is None:
                     continue
+                # one chip's share (ModelConfig.num_experts_total): the
+                # checkpoint names all the published experts; keep those
+                # held here under their local index
+                e_local = int(e_str) - cfg.expert_share_index * E
+                if cfg.num_experts_total and not 0 <= e_local < E:
+                    continue
                 grid = expert_staging.setdefault(
                     key, [[None] * E for _ in range(L)])
-                grid[int(idx_str)][int(e_str)] = tensor.T
+                grid[int(idx_str)][e_local] = tensor.T
                 continue
             if sub in fused:
                 # split the fused tensor's torch rows into our keys
@@ -638,7 +650,7 @@ def save_hf_style(params: Dict[str, jax.Array], cfg: ModelConfig,
     """Write params back out as a single HF-style safetensors file (used by
     tests to cross-check against the torch reference implementation)."""
     from safetensors.numpy import save_file
-    if (cfg.model_type in ("deepseek_v2", "deepseek_v3")
+    if (cfg.kv_lora_rank > 0
             and cfg.num_experts > 0):
         raise NotImplementedError(
             "save_hf_style cannot write the deepseek hybrid MoE layout "

@@ -60,6 +60,11 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--prefill-chunk", type=int, default=0,
                    help="split prompt prefill into fixed-size chunk "
                         "dispatches (0 = whole-prompt)")
+    p.add_argument("--prefill-buckets", default="",
+                   help="comma-separated prompt lengths the prefill "
+                        "program is compiled for (a prompt pads to the "
+                        "next one; --max-model-len is always the last); "
+                        "empty = the engine's default list")
     p.add_argument("--decode-steps-per-dispatch", type=int, default=1,
                    help="fuse K decode steps per XLA dispatch (amortizes "
                         "device→host token-harvest latency; EOS/cancel "
@@ -257,6 +262,9 @@ def engine_config(args):
         kv_remote_blocks=args.kv_remote_blocks,
         kv_remote_admission=args.kv_remote_admission,
         prefill_chunk=args.prefill_chunk,
+        **({"prefill_buckets": [int(b) for b in
+                                args.prefill_buckets.split(",") if b]}
+           if args.prefill_buckets else {}),
         decode_steps_per_dispatch=args.decode_steps_per_dispatch,
         decode_dispatch_pipeline=args.decode_dispatch_pipeline,
         lane_prefill_max_tokens=args.lane_prefill_max_tokens,

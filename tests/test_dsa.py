@@ -1,0 +1,529 @@
+"""DeepSeek-V3.2 (deepseek_v32) on models/mla.py: the lightning indexer, the
+second per-token cache for its keys, select-then-attend in prefill and decode,
+and one chip's share of the experts, held to the benchmark's plain reference
+(benchmark/references/deepseek_v32.py) at tiny widths on the CPU, with seeded
+random weights.
+
+Tolerances. Engine and reference both compute in float32 here (float32
+parameters and pools, ``jax.default_matmul_precision("highest")``), so what
+separates them is the order of float32 sums: a few 1e-6 of the logits'
+standard deviation (measured 4e-6 at these sizes). ``TOL_STD`` = 1e-4 leaves
+room for another summation order and fails anything else: a dropped term, a
+wrong rope convention, bf16 anywhere in the indexer (which moves index scores
+by 2^-9 of their size, flips near-tied selections at these widths and with
+them the logits by 0.1 to 3 standard deviations — measured while choosing the
+fixture, PERF.md section 6, PR 31). The selected sets are compared exactly:
+random weights make logits blind to a wrong selection at a long context, the
+sets are not.
+"""
+
+import dataclasses
+import importlib.util
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+from dynamo_tpu.engine.models import mla
+from dynamo_tpu.engine.models.llama import ModelStatics
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+BS = 16
+NUM_BLOCKS = 16
+TOL_STD = 1e-4
+
+
+@pytest.fixture(scope="module")
+def ref():
+    """benchmark/references/deepseek_v32.py (it imports the benchmark's
+    ``reference`` module by its bare name)."""
+    sys.path.insert(0, BENCH)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "reference_deepseek_v32",
+            os.path.join(BENCH, "references", "deepseek_v32.py"))
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        yield module
+    finally:
+        sys.path.remove(BENCH)
+
+
+def _hf(**over) -> dict:
+    with open(os.path.join(BENCH, "fixtures", "tiny-deepseek-v32.json")) as f:
+        hf = json.load(f)
+    for key in ("source", "reduced", "assumed", "deployment", "reference"):
+        hf.pop(key)
+    # the published score scale (mscale_all_dim) and a selection that binds
+    # from position 16 on: this file computes in float32, where neither
+    # needs the fixture's care for bf16
+    hf["rope_scaling"] = dict(hf["rope_scaling"], mscale_all_dim=1)
+    hf.update(num_hidden_layers=3, index_topk=16, routed_scaling_factor=2.5,
+              num_experts_per_tok=2, topk_group=1)
+    return dict(hf, **over)
+
+
+def _setup(hf: dict, seed: int = 1):
+    cfg = ModelConfig.from_hf_config(hf)
+    params = mla.init_params(cfg, jax.random.PRNGKey(seed),
+                             dtype=jnp.float32)
+    # a router bias that matters (it is zero at initialisation)
+    params["layers.router_bias"] = 0.1 * jax.random.normal(
+        jax.random.PRNGKey(seed + 1), params["layers.router_bias"].shape)
+    kv = mla.init_kv_cache(cfg, NUM_BLOCKS, BS, dtype=jnp.float32)
+    return cfg, params, kv, ModelStatics(cfg=cfg, block_size=BS,
+                                         attn_impl="xla")
+
+
+def _tokens(cfg, n: int, seed: int = 3) -> np.ndarray:
+    return np.random.default_rng(seed).integers(0, cfg.vocab_size, size=n)
+
+
+TABLE = jnp.arange(1, 9, dtype=jnp.int32)          # 8 blocks: 128 positions
+
+
+def _prefill(params, kv, statics, tokens, start=0, pad_to=64):
+    padded = np.zeros(pad_to, np.int32)
+    padded[:len(tokens)] = tokens
+    with jax.default_matmul_precision("highest"):
+        return mla.prefill_forward(
+            params, kv, jnp.asarray(padded), TABLE, jnp.asarray(start),
+            jnp.asarray(len(tokens)), statics)
+
+
+def _decode(params, kv, statics, token, pos):
+    """One step of a two-slot batch whose second slot is idle."""
+    with jax.default_matmul_precision("highest"):
+        logits, kv = mla.decode_forward(
+            params, kv, jnp.asarray([token, 0]), jnp.asarray([pos, 0]),
+            jnp.stack([TABLE, jnp.zeros_like(TABLE)]), statics)
+    return logits[0], kv
+
+
+def _err_std(got, want) -> float:
+    want = np.asarray(want, np.float32)
+    return float(np.abs(np.asarray(got, np.float32) - want).max()
+                 / want.std())
+
+
+def test_from_hf_config_reads_the_indexer_and_the_share():
+    cfg = ModelConfig.from_hf_config(_hf())
+    assert (cfg.model_type, cfg.index_n_heads, cfg.index_head_dim,
+            cfg.index_topk) == ("deepseek_v32", 4, 16, 16)
+    assert (cfg.num_experts, cfg.num_experts_total, cfg.router_width,
+            cfg.expert_share_index) == (4, 8, 8, 0)
+    assert cfg.moe_routing == "sigmoid_noaux" and cfg.is_deepseek_v3
+    shapes = mla.param_shapes(cfg)
+    assert shapes["layers.router"] == (2, 64, 8)
+    assert shapes["layers.router_bias"] == (2, 8)
+    assert shapes["layers.moe_gate"] == (2, 4, 64, 32)
+    assert shapes["layers.idx_wq_b"] == (3, 24, 4 * 16)
+    assert shapes["layers.idx_wk"] == (3, 64, 16)
+    assert shapes["layers.idx_w"] == (3, 64, 4)
+    # no indexer: the v3 tree and pool are what they were
+    v3 = ModelConfig.from_hf_config(
+        {k: v for k, v in _hf(model_type="deepseek_v3").items()
+         if not k.startswith("index_")})
+    assert v3.index_topk == 0
+    assert not any(n.startswith("layers.idx_") for n in mla.param_shapes(v3))
+    assert set(mla.init_kv_cache(v3, 4, BS)) == {"kv"}
+
+
+@pytest.mark.parametrize("bad, match", [
+    ({"index_topk": None}, "index_topk"),
+    ({"q_lora_rank": None}, "q_lora_rank"),
+    ({"scoring_func": "softmax"}, "scoring_func"),
+    ({"n_routed_experts_published": 6}, "does not divide"),
+    ({"expert_share_index": 2}, "does not divide"),
+    ({"model_type": "deepseek_v3"}, "indexer is deepseek_v32"),
+])
+def test_from_hf_config_refuses_what_is_not_computed(bad, match):
+    with pytest.raises(ValueError, match=match):
+        ModelConfig.from_hf_config(_hf(**bad))
+
+
+def test_both_caches_under_one_block_table():
+    cfg, params, kv, statics = _setup(_hf())
+    assert list(kv) == ["kv", "idx"]
+    assert kv["idx"].shape == (3, NUM_BLOCKS * BS, cfg.index_head_dim)
+    _, kv = _prefill(params, kv, statics, _tokens(cfg, 40))
+    written = np.asarray(jnp.any(kv["idx"] != 0, axis=-1))     # [L, NTOK]
+    latent = np.asarray(jnp.any(kv["kv"] != 0, axis=-1))
+    # 40 positions of blocks 1..3, in every layer, in both arrays (block 0
+    # is the trash block: the chunk's pad rows land there)
+    want = np.zeros_like(written[0])
+    want[BS:BS + 40] = True
+    for li in range(cfg.num_layers):
+        assert (written[li][BS:] == want[BS:]).all()
+        assert (latent[li][BS:] == want[BS:]).all()
+
+
+def test_prefill_and_decode_match_the_reference(ref):
+    """Prefill, then decode through both caches, against the reference's
+    full forward over the whole sequence: logits at every step."""
+    hf = _hf()
+    cfg, params, kv, statics = _setup(hf)
+    n, steps = 48, 6
+    seq = _tokens(cfg, n + steps)
+    logits, kv = _prefill(params, kv, statics, seq[:n])
+    got = [logits]
+    for i in range(steps):
+        logits, kv = _decode(params, kv, statics, seq[n + i], n + i)
+        got.append(logits)
+    want = ref.logits_for(params, hf, seq.tolist(), steps + 1)
+    for i, (g, w) in enumerate(zip(got, want)):
+        assert _err_std(g, w) < TOL_STD, (i, _err_std(g, w))
+
+
+def test_every_breakage_moves_the_reference(ref):
+    """The comparison above is not blind: each of the reference's listed
+    breakages moves its logits by far more than the tolerance."""
+    hf = _hf()
+    cfg, params, _, _ = _setup(hf)
+    seq = _tokens(cfg, 48).tolist()
+    want = ref.logits_for(params, hf, seq, 4)
+    for broken in ref.breakages_for(hf):
+        got = ref.logits_for(params, hf, seq, 4, broken)
+        assert _err_std(got, want) > 100 * TOL_STD, broken
+
+
+@pytest.fixture(scope="module")
+def chk():
+    """benchmark/references/deepseek_v32_check.py: the builder's check (it
+    puts benchmark/ on the path for the modules it imports when called)."""
+    before = list(sys.path)
+    sys.path.insert(0, os.path.join(BENCH, "references"))
+    try:
+        import deepseek_v32_check
+        yield deepseek_v32_check
+    finally:
+        sys.path[:] = before
+
+
+def _engine_selection(chk, cfg, params, kv, statics, seq, n):
+    """The sets the engine selects, per layer and query position: prefill
+    of ``n`` tokens, then one decode step per remaining token, carried out
+    of the traced layer scans by the check's tap on ``mla._select``."""
+    L = cfg.num_layers
+    with chk.Tap(mla) as tap:
+        _, kv = _prefill(params, kv, statics, seq[:n])
+        sets = [tap.take(L)[:, :n]]      # pad rows come after the true ones
+        for pos in range(n, len(seq)):
+            _, kv = _decode(params, kv, statics, seq[pos], pos)
+            sets.append(tap.take(L)[:, :1])       # slot 0; slot 1 is idle
+    return [[set(row[row >= 0].tolist()) for row in layer]
+            for layer in np.concatenate(sets, axis=1)]
+
+
+def test_selected_sets_match_the_reference(ref, chk):
+    hf = _hf(num_hidden_layers=2)
+    cfg, params, kv, statics = _setup(hf)
+    n, steps = 40, 3
+    seq = _tokens(cfg, n + steps)
+    picked = _engine_selection(chk, cfg, params, kv, statics, seq, n)
+    allowed = ref.selected_sets(params, hf, seq.tolist())
+    assert len(allowed) == cfg.num_layers
+    for li, rows in enumerate(allowed):
+        for pos in range(len(seq)):
+            want = set(np.flatnonzero(rows[pos]).tolist())
+            assert len(want) == min(cfg.index_topk, pos + 1)
+            assert picked[li][pos] == want, (li, pos)
+    # and the selection is one: neither everything nor the last 16
+    last = picked[-1][len(seq) - 1]
+    assert last != set(range(len(seq) - 16, len(seq)))
+
+
+def test_context_inside_topk_equals_dense_mla():
+    """ctx <= index_topk: every live row is selected, and the result is the
+    dense path's (the same parameters served with no indexer)."""
+    cfg, params, kv, statics = _setup(_hf(index_topk=64))
+    dense_cfg = dataclasses.replace(cfg, index_topk=0)
+    dense_statics = ModelStatics(cfg=dense_cfg, block_size=BS,
+                                 attn_impl="xla")
+    dense_kv = mla.init_kv_cache(dense_cfg, NUM_BLOCKS, BS,
+                                 dtype=jnp.float32)
+    seq = _tokens(cfg, 44)
+    a, kv = _prefill(params, kv, statics, seq[:40])
+    b, dense_kv = _prefill(params, dense_kv, dense_statics, seq[:40])
+    assert _err_std(a, b) < TOL_STD
+    for pos in range(40, 44):
+        a, kv = _decode(params, kv, statics, seq[pos], pos)
+        b, dense_kv = _decode(params, dense_kv, dense_statics, seq[pos], pos)
+        assert _err_std(a, b) < TOL_STD
+    np.testing.assert_allclose(np.asarray(kv["kv"]),
+                               np.asarray(dense_kv["kv"]), atol=1e-5)
+
+
+def test_chunked_prefill_equals_whole_prefill_in_both_caches():
+    cfg, params, kv, statics = _setup(_hf())
+    seq = _tokens(cfg, 56)
+    whole_logits, whole = _prefill(params, kv, statics, seq)
+    kv2 = mla.init_kv_cache(cfg, NUM_BLOCKS, BS, dtype=jnp.float32)
+    for lo in range(0, 56, 16):
+        logits, kv2 = _prefill(params, kv2, statics, seq[lo:lo + 16],
+                               start=lo, pad_to=16)
+    assert _err_std(logits, whole_logits) < TOL_STD
+    for name in ("kv", "idx"):
+        np.testing.assert_allclose(np.asarray(kv2[name]),
+                                   np.asarray(whole[name]), atol=1e-5)
+
+
+def test_block_moves_carry_both_rows():
+    """The defrag copy (block_copy.move_blocks) and the block gather /
+    scatter move every array of the pool: latent rows and index keys."""
+    from dynamo_tpu.engine.block_copy import (gather_blocks, move_blocks,
+                                              scatter_blocks)
+    cfg, params, kv, statics = _setup(_hf())
+    _, kv = _prefill(params, kv, statics, _tokens(cfg, 40))
+    before = {k: np.asarray(v) for k, v in kv.items()}
+    picked = gather_blocks(kv, jnp.asarray([1, 2], jnp.int32), BS)
+    assert set(picked) == {"kv", "idx"}
+    moved = move_blocks(kv, [1, 2, 3], [9, 10, 11], BS)
+    for name, arr in moved.items():
+        arr = np.asarray(arr)
+        assert np.abs(before[name][:, BS:4 * BS]).max() > 0
+        np.testing.assert_array_equal(arr[:, 9 * BS:12 * BS],
+                                      before[name][:, BS:4 * BS])
+    back = scatter_blocks(moved, jnp.asarray([12, 13], jnp.int32),
+                          {k: jnp.asarray(v) for k, v in picked.items()}, BS)
+    for name, arr in back.items():
+        np.testing.assert_array_equal(np.asarray(arr)[:, 12 * BS:14 * BS],
+                                      before[name][:, BS:3 * BS])
+
+
+def test_the_shares_add_up_to_the_uncut_layer(ref):
+    """Guide "model-configs" section 4: what the 2 shares of the 8 experts
+    give, with the shared expert counted once, adds up to the uncut layer's
+    output — in the engine, and against the uncut reference."""
+    hf_whole = _hf(n_routed_experts=8)
+    cfg_whole, params, _, _ = _setup(hf_whole)
+    m = jax.random.normal(jax.random.PRNGKey(5), (24, cfg_whole.hidden_size))
+    stack = {k[len("layers."):]: v for k, v in params.items()
+             if k.startswith("layers.")}
+    names = ("router", "router_bias", "moe_gate", "moe_up", "moe_down",
+             "sh_gate", "sh_up", "sh_down")
+    lp = {n: stack[n][0] for n in names}
+    with jax.default_matmul_precision("highest"):
+        whole = mla._moe_mlp(m, lp, cfg_whole)
+        shared_only = mla._moe_mlp(
+            m, dict(lp, moe_down=jnp.zeros_like(lp["moe_down"])), cfg_whole)
+        parts = []
+        for share in range(2):
+            cfg = ModelConfig.from_hf_config(_hf(expert_share_index=share))
+            held = slice(4 * share, 4 * share + 4)
+            lp_share = dict(lp, **{n: lp[n][held] for n in
+                                   ("moe_gate", "moe_up", "moe_down")})
+            parts.append(mla._moe_mlp(m, lp_share, cfg))
+            # the reference, given the same share, gives the same part
+            fam = ref.family(_hf(expert_share_index=share))
+            want = ref.moe_block(fam)(m, lp_share)
+            assert _err_std(parts[-1], want) < TOL_STD
+        total = parts[0] + parts[1] - shared_only
+        uncut = ref.moe_block(ref.family(hf_whole))(m, lp)
+    assert _err_std(whole, uncut) < TOL_STD
+    assert _err_std(total, uncut) < TOL_STD
+    # each share really leaves something out
+    assert _err_std(parts[0], uncut) > 0.05
+
+
+def _engine_cfg(**over) -> EngineConfig:
+    base = dict(max_model_len=128, kv_block_size=BS, num_kv_blocks=64,
+                max_num_seqs=2, prefill_buckets=[32, 64])
+    return EngineConfig(**dict(base, **over))
+
+
+@pytest.mark.parametrize("over, match", [
+    ({"ragged_dispatch": True}, "--ragged"),
+    ({"spec_k": 2}, "--spec-k"),
+    ({"kv_quantization": "int8"}, "--kv-quantization"),
+    ({"host_kv_blocks": 8}, "--host-kv-blocks"),
+    ({"tp": 2}, "meshes"),
+    ({"ep": 2}, "meshes"),
+])
+def test_engine_refuses_what_cannot_carry_the_index_keys(over, match):
+    from dynamo_tpu.engine.core import EngineCore
+    cfg = ModelConfig.from_hf_config(_hf())
+    with pytest.raises(NotImplementedError, match=match):
+        EngineCore(cfg, _engine_cfg(**over), attn_impl="xla",
+                   param_dtype=jnp.float32)
+
+
+def test_engine_refuses_a_mesh_and_the_forwards_that_do_not_select():
+    from dynamo_tpu.engine.core import EngineCore
+    from dynamo_tpu.parallel.sharding import make_mesh
+    cfg, params, kv, statics = _setup(_hf())
+    with pytest.raises(NotImplementedError, match="meshes"):
+        EngineCore(cfg, _engine_cfg(), attn_impl="xla",
+                   param_dtype=jnp.float32, mesh=make_mesh(tp=2))
+    tokens = jnp.zeros((8,), jnp.int32)
+    with pytest.raises(NotImplementedError, match="ragged"):
+        mla.ragged_forward(params, kv, tokens, tokens, TABLE[None],
+                           tokens, tokens[:1], tokens[:1], tokens[:1],
+                           statics)
+    with pytest.raises(NotImplementedError, match="sequence-parallel"):
+        mla.prefill_forward_sp(params, kv, tokens, TABLE, jnp.asarray(8),
+                               statics, None)
+    with pytest.raises(NotImplementedError, match="index-key cache"):
+        mla.init_kv_cache(cfg, 4, BS, quantization="int8")
+
+
+async def _serve(core, rid, prompt, n=6):
+    from dynamo_tpu.engine.core import FINISH_SENTINEL, EngineRequest
+    from dynamo_tpu.engine.sampling import SlotSampling
+    req = EngineRequest(rid=rid, prompt=list(prompt),
+                        sampling=SlotSampling(temperature=0.0),
+                        max_new_tokens=n, eos_ids=frozenset())
+    await core.submit(req)
+    toks, lps = [], []
+    while True:
+        item, lp = await req.out_queue.get()
+        if item is FINISH_SENTINEL:
+            break
+        toks.append(item)
+        lps.append(lp)
+    return toks, lps, req
+
+
+@pytest.mark.asyncio
+async def test_engine_serves_with_prefix_reuse_and_chunks(ref):
+    """EngineCore end to end: a prompt served whole, then a second prompt
+    that shares its first 48 tokens served by a prefix-cache hit and a
+    chunked suffix, give the tokens and logprobs of a cold engine and of
+    the reference; the flight records carry the attention's two counters;
+    neither disagg plane is accepted."""
+    from dynamo_tpu.engine.core import EngineCore, EngineRequest
+    from dynamo_tpu.engine.sampling import SlotSampling
+    hf = _hf()
+    cfg = ModelConfig.from_hf_config(hf)
+    params = mla.init_params(cfg, jax.random.PRNGKey(1), dtype=jnp.float32)
+
+    def engine(**over):
+        return EngineCore(cfg, _engine_cfg(**over), params=dict(params),
+                          attn_impl="xla", param_dtype=jnp.float32)
+
+    shared = _tokens(cfg, 48, seed=7).tolist()
+    first = shared + _tokens(cfg, 9, seed=8).tolist()
+    second = shared + _tokens(cfg, 21, seed=9).tolist()
+    warm = engine(prefill_chunk=16, prefill_buckets=[16, 64])
+    cold = engine()
+    try:
+        with jax.default_matmul_precision("highest"):
+            await _serve(warm, "a", first)
+            toks, lps, req = await _serve(warm, "b", second)
+            want_toks, want_lps, cold_req = await _serve(cold, "c", second)
+        assert req.prefix_hit_tokens == 48 and cold_req.prefix_hit_tokens == 0
+        assert toks == want_toks
+        np.testing.assert_allclose(lps, want_lps, atol=1e-4)
+        # ... and the reference's: logprob of each served token
+        logits = ref.logits_for(warm.params, hf, second + toks[:-1],
+                                len(toks))
+        for tok, lp, row in zip(toks, lps, logits):
+            row = row.astype(np.float64)
+            ref_lp = row[tok] - (row.max() + np.log(
+                np.exp(row - row.max()).sum()))
+            assert abs(ref_lp - lp) < TOL_STD * row.std() * 10
+        decode = [r for r in warm.flight.dump() if r["kind"] == "decode"]
+        assert decode and all(
+            0 < r["sel_tokens"] < r["ctx_tokens"] for r in decode
+            if r["batch_fill"])
+        one = [r for r in decode if r["batch_fill"] == 1][-1]
+        assert one["sel_tokens"] == cfg.index_topk * one["emitted"]
+        with pytest.raises(NotImplementedError, match="hand-off"):
+            await warm.submit(EngineRequest(
+                rid="d", prompt=first, sampling=SlotSampling(temperature=0.0),
+                max_new_tokens=2, eos_ids=frozenset(), handoff=object()))
+        with pytest.raises(NotImplementedError, match="fabric"):
+            warm.attach_kv_fabric(object())
+    finally:
+        await warm.stop()
+        await cold.stop()
+
+
+@pytest.mark.asyncio
+async def test_flight_records_count_context_on_a_model_with_no_indexer():
+    from dynamo_tpu.engine.core import EngineCore
+    cfg = ModelConfig(vocab_size=256, hidden_size=64, intermediate_size=128,
+                      num_layers=2, num_heads=4, num_kv_heads=2, head_dim=16,
+                      max_position_embeddings=256)
+    core = EngineCore(cfg, _engine_cfg(), attn_impl="xla",
+                      param_dtype=jnp.float32)
+    try:
+        await _serve(core, "l", list(range(3, 33)), n=5)
+        decode = [r for r in core.flight.dump() if r["kind"] == "decode"]
+        assert decode and all(r["sel_tokens"] == r["ctx_tokens"]
+                              for r in decode)
+        # 30 prompt tokens: the first decode step reads a context of 31
+        assert decode[0]["ctx_tokens"] == 31
+    finally:
+        await core.stop()
+
+
+def test_the_reference_blocked_equals_the_reference_whole(ref, monkeypatch):
+    """On the chip the reference works a 16k-token prompt in blocks of
+    queries, groups of heads and slices of the dense MLP; at these sizes it
+    works whole. Both give the same logits."""
+    hf = _hf()
+    cfg, params, _, _ = _setup(hf)
+    seq = _tokens(cfg, 54).tolist()
+    whole = ref.logits_for(params, hf, seq, 5)
+    monkeypatch.setattr(ref, "QUERY_BLOCK", 16)
+    monkeypatch.setattr(ref, "HEAD_GROUP", 2)
+    monkeypatch.setattr(ref, "MLP_SLICE", 32)
+    assert _err_std(ref.logits_for(params, hf, seq, 5), whole) < TOL_STD
+
+
+def test_seeded_weights_of_a_sparse_attention_model():
+    """llama.seeded_std: with an indexer the embedding and the projections
+    that write into the stream take SPARSE_SEEDED's scales; every other
+    matrix, and every matrix of a model with no indexer, fan_in^-0.5."""
+    from dynamo_tpu.engine.models import llama
+    cfg = ModelConfig.from_hf_config(_hf(vocab_size=4096))
+    plain = dataclasses.replace(cfg, index_topk=0)
+    key = jax.random.PRNGKey(0)
+
+    def std(c, name, shape):
+        return float(jnp.std(llama.init_one_param(c, name, shape, key,
+                                                  jnp.float32)))
+
+    usual = 256 ** -0.5
+    want = llama.SPARSE_SEEDED
+    assert abs(std(cfg, "embed", (4096, 64)) / want["embed"] - 1) < 0.05
+    assert abs(std(plain, "embed", (4096, 64)) - 4096 ** -0.5) < 0.001
+    for name, shape, key_ in (
+            ("layers.wo", (2, 256, 64), "wo"),
+            ("layers.dense_down", (2, 256, 64), "down"),
+            ("layers.sh_down", (2, 256, 64), "down"),
+            ("layers.moe_down", (2, 4, 256, 64), "moe_down")):
+        assert abs(std(cfg, name, shape) / usual / want[key_] - 1) < 0.05
+        assert abs(std(plain, name, shape) / usual - 1) < 0.05
+    assert abs(std(cfg, "layers.wq_b", (2, 256, 64)) / usual - 1) < 0.05
+
+
+def test_the_check_forces_the_engines_selection_on_the_reference(chk, capsys):
+    """benchmark/references/deepseek_v32_check.py on the fixture, in bf16 as
+    served: the tap carries the engine's sets out of its compiled programs;
+    with them forced on the reference's attention the engine sits well
+    inside the tolerance, the reference's own selection shares nearly every
+    key with the engine's, and a wrong selection does not."""
+    chk.main(["--fixture", "tiny-deepseek-v32", "--tokens", "80",
+              "--only", "recent_window"])
+    lines = [json.loads(line[len("CHECK "):])
+             for line in capsys.readouterr().out.splitlines()
+             if line.startswith("CHECK ")]
+    readings = {r["reading"]: r for r in lines if "reading" in r}
+    overlap = {r["overlap_with_engine_pct"]: r for r in lines
+               if "overlap_with_engine_pct" in r}
+    assert mla._select.__name__ == "_select"         # the tap is gone
+    assert readings["forced"]["ok"]
+    assert readings["forced"]["logprob_err_std"] < 0.1
+    assert not readings["recent_window"]["ok"]
+    assert min(overlap["reference"]["per_layer_mean"]) > 95.0
+    assert max(overlap["recent_window"]["per_layer_mean"]) < 50.0
+    assert max(overlap["no_selection"]["per_layer_mean"]) == 100.0

@@ -300,3 +300,56 @@ def test_engine_step_programs_carry_stable_names(one_chip, monkeypatch, moe):
         for scope in [f"jit({fn})/{top}/", "/lm_head/", "/sampling/",
                       f"/attention/{kernel}/pallas_call"] + mlp:
             assert scope in text, (top, scope)
+
+
+def test_sparse_attention_programs_carry_stable_names(one_chip, monkeypatch):
+    """deepseek_v32 on models/mla.py: the engine's prefill and decode
+    programs compile for the chip at narrow widths with both caches, and
+    name the three stages of DeepSeek Sparse Attention — ``indexer``,
+    ``dsa_select``, ``sparse_attention`` — beside the scopes every model
+    has. No Pallas kernel yet: the stages are XLA gathers, einsums and
+    a sort."""
+    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+    from dynamo_tpu.engine.core import EngineCore
+    from dynamo_tpu.engine.models import llama
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    monkeypatch.setattr(llama, "_on_tpu", lambda: True)
+    cfg = ModelConfig.from_hf_config({
+        "model_type": "deepseek_v32", "vocab_size": 2048, "hidden_size": 256,
+        "intermediate_size": 512, "moe_intermediate_size": 128,
+        "num_hidden_layers": 2, "num_attention_heads": 8,
+        "q_lora_rank": 128, "kv_lora_rank": 128, "qk_nope_head_dim": 64,
+        "qk_rope_head_dim": 32, "v_head_dim": 64, "index_n_heads": 4,
+        "index_head_dim": 128, "index_topk": 64, "first_k_dense_replace": 1,
+        "n_routed_experts": 4, "n_routed_experts_published": 8,
+        "expert_share_index": 1, "n_group": 2,
+        "topk_group": 1, "num_experts_per_tok": 2, "n_shared_experts": 1,
+        "rms_norm_eps": 1e-6, "max_position_embeddings": 1024})
+    B, M, T = 8, 16, 128
+    core = EngineCore(cfg, EngineConfig(
+        max_model_len=256, kv_block_size=16, num_kv_blocks=64,
+        max_num_seqs=B, prefill_buckets=[T]), attn_impl="pallas")
+    assert list(core.kv) == ["kv", "idx"]
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, kv = jax.tree.map(lambda x: s(x.shape, x.dtype),
+                              (core.params, core.kv))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    i32, f32 = jnp.int32, jnp.float32
+    decode = core._decode_k_jit.lower(
+        params, kv, s((B,), i32), s((B,), i32), s((B, M), i32),
+        s((B,), i32), s((B,), i32), s((B,), f32), s((B,), i32),
+        s((B,), f32), s((1, B), i32), s((1, B), jnp.bool_),
+        s(key.shape, key.dtype)).compile().as_text()
+    prefill = core._prefill_jit.lower(
+        params, kv, s((T,), i32), s((M,), i32), s((), i32), s((), i32),
+        s(key.shape, key.dtype), s((), f32), s((), i32),
+        s((), f32)).compile().as_text()
+    for text, fn, top in ((decode, "decode_k", "decode"),
+                          (prefill, "prefill", "prefill")):
+        for scope in (f"jit({fn})/{top}/", "/lm_head/", "/sampling/",
+                      "/indexer/", "/dsa_select/", "/sparse_attention/",
+                      "run_experts_dense"):
+            assert scope in text, (top, scope)
