@@ -1143,10 +1143,13 @@ def _param_bytes(params) -> int:
 def _matmul_flops_per_token(mcfg) -> float:
     """2·(matmul weight count) per token: qkv + wo + mlp per layer, + lm
     head. Embedding lookup is free; attention score/update flops are
-    accounted separately (they scale with seq len). MoE geometries run
-    the dense-over-experts einsum — ALL E experts execute per token
-    (engine moe_mlp) — plus any shared expert, and the MFU must count
-    those real flops (earlier MoE history lines understated this).
+    accounted separately (they scale with seq len). MoE geometries are
+    counted dense over the experts — ALL E experts per token, which is
+    what a decode step executes (engine moe_mlp, run_experts_dense) —
+    plus any shared expert (earlier MoE history lines understated
+    this). A single-device prefill of llama.GROUPED_MIN_ROWS rows or
+    more executes only the routed top-k of them (run_experts_grouped):
+    a prefill figure from this count overstates what the chip did there.
     Hybrid deepseek sparsity: the first_k_dense prefix runs its own
     dense MLP; MLA attention counts the latent projections plus the
     ABSORBED per-token wkv_b contractions (models/mla.py decode)."""

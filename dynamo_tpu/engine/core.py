@@ -250,7 +250,10 @@ class EngineCore:
             # shard (llama._per_tp_shard). The pp stage ring is already
             # inside its own shard_map and hands kernels local arrays.
             mesh=(mesh if mesh is not None and self.pp == 1
-                  and mesh.shape.get("tp", 1) > 1 else None))
+                  and mesh.shape.get("tp", 1) > 1 else None),
+            # any mesh may shard the expert stacks: the experts then
+            # stay dense over E (llama.experts_run_grouped)
+            sharded=mesh is not None)
         if engine_cfg.quantization not in ("none", "int8", "int8-noembed",
                                            "int4", "int4-noembed"):
             raise ValueError(
@@ -2172,6 +2175,10 @@ class EngineCore:
             self._admit_lane(req, slot, n_already)
             return True
         defer = False
+        # prompt rows whose expert layers run grouped (the prefill
+        # record's grouped_rows): the model's own chooser, asked per
+        # dispatched program shape
+        grouped_rows = 0
         remote_admit = req.precomputed is not None
         if remote_admit:
             from ..llm.kv.stream import LayerStreamPayload
@@ -2236,6 +2243,8 @@ class EngineCore:
                     and len(chunk) > self.cfg.prefill_chunk):
                 tok, logprob = self._chunked_prefill(req, chunk, table, key,
                                                      slot=slot)
+                grouped_rows = llama.grouped_prefill_rows(
+                    self.statics, self.cfg.prefill_chunk, len(chunk))
             else:
                 padded = np.zeros((bucket,), np.int32)
                 padded[:len(chunk)] = chunk
@@ -2253,6 +2262,8 @@ class EngineCore:
                     jnp.asarray(req.sampling.temperature, jnp.float32),
                     jnp.asarray(req.sampling.top_k, jnp.int32),
                     jnp.asarray(req.sampling.top_p, jnp.float32))
+                grouped_rows = llama.grouped_prefill_rows(
+                    self.statics, bucket, len(chunk))
             self.total_prefill_tokens += len(chunk)
             self.clock.admits += 1
             # measured prefill rate (fabric admission gate + the
@@ -2330,7 +2341,7 @@ class EngineCore:
             hit_device=plan.hit_tokens, hit_host=plan.host_hit_tokens,
             hit_disk=plan.disk_hit_tokens,
             hit_remote=plan.remote_hit_tokens,
-            precomputed=remote_admit,
+            precomputed=remote_admit, grouped_rows=grouped_rows,
             host_ms=round(1e3 * (now - t0), 3),
             # of host_ms: plan to the prefill program's return (argument
             # build and transfers included), and the blocking fetch of
@@ -2571,7 +2582,7 @@ class EngineCore:
             hit_device=plan.hit_tokens, hit_host=plan.host_hit_tokens,
             hit_disk=plan.disk_hit_tokens,
             hit_remote=plan.remote_hit_tokens,
-            precomputed=True,
+            precomputed=True, grouped_rows=0,
             queue_wait_ms=round(1e3 * (t_admit - req.enqueue_time), 3))
         task = asyncio.get_running_loop().create_task(
             self._stream_onboard(req, plan, n_already, n_prompt_blocks),

@@ -20,6 +20,7 @@ Weight layout matches HF llama checkpoints after transpose (see weights.py).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -36,6 +37,8 @@ from ..attention import (KV_SCALE_LANES, RAGGED_WIN_SENTINEL, _on_tpu,
                          ragged_supported,
                          softcap_scores as _softcap)
 from ..config import ModelConfig
+from ..grouped_matmul import (ROW_TILE, grouped_matmul,
+                              grouped_matmul_eligible)
 from ..quant import QuantizedArray, mm, qeinsum
 
 Params = Dict[str, jax.Array]
@@ -185,15 +188,62 @@ def fuse_stacked_matmuls(params: dict, cfg: ModelConfig) -> dict:
     return params
 
 
+# Where the dense-over-experts form stops being the cheap one. It reads
+# every expert's weights once and runs all E experts for all N rows, so
+# it costs max(weight bytes / HBM bandwidth, 2*N*E*D*F / MXU peak): the
+# two meet at N = peak / (2 * bandwidth) rows per int8 weight byte. On a
+# TPU v5e (197e12 bf16 FLOP/s, 819e9 B/s: Google Cloud's "TPU v5e" page)
+# that ridge is ~120 rows. Below it the dense form costs the weight
+# stream, which the grouped form reads too, and nothing can beat it;
+# above it the dense form costs FLOPs that grow with E / top_k. The
+# grouped form adds a sort, a row gather and an un-permute, and tiles of
+# ROW_TILE rows that straddle the groups; timed on the chip against the
+# dense form at the benchmark's Qwen1.5-MoE widths, buckets 128-2048
+# (PERF.md section 5, PR 32), it wins by more than noise from here up.
+GROUPED_MIN_ROWS = 256
+
+
+def experts_run_grouped(n_rows: int, num_experts: int, top_k: int,
+                        d_model: int, d_ff: int, sharded: bool) -> bool:
+    """Which form ``run_experts`` takes, from what the program can see:
+    its static row count, the expert stacks' shapes and their layout.
+    The one chooser: the model code and the engine's ``grouped_rows``
+    counter both ask it.
+
+    Dense wherever the stacks are sharded over a mesh (the "ep" layout
+    relies on E staying a contracted axis so that XLA turns the combine
+    into a psum, and a Pallas call has no partitioning rule), wherever
+    the row count leaves the dense form bandwidth-bound
+    (GROUPED_MIN_ROWS), where every expert is picked anyway, and at
+    widths the kernel does not tile."""
+    return (not sharded and n_rows >= GROUPED_MIN_ROWS
+            and top_k < num_experts
+            and grouped_matmul_eligible(d_model, d_ff)
+            and grouped_matmul_eligible(d_ff, d_model))
+
+
+def grouped_prefill_rows(statics: "ModelStatics", bucket: int,
+                         true_len: int) -> int:
+    """The rows of one prefill dispatch (``true_len`` valid rows in a
+    ``bucket``-row program) whose expert layers run grouped: all of them
+    or none. For the prefill flight record's ``grouped_rows``."""
+    cfg = statics.cfg
+    if cfg.num_experts <= 0:
+        return 0
+    return true_len if experts_run_grouped(
+        bucket, cfg.num_experts, cfg.num_experts_per_tok, cfg.hidden_size,
+        cfg.intermediate_size, statics.sharded) else 0
+
+
 @jax.named_scope("run_experts_dense")
 def run_experts_dense(x: jax.Array, gate_w: jax.Array, up_w: jax.Array,
                       down_w: jax.Array, top_idx: jax.Array,
                       top_w: jax.Array, gateup_w=None) -> jax.Array:
-    """Dense-over-E expert execution + one-hot combine — the ONE home of
-    the expert einsum layout (E stays a batched/contracted axis so the
-    mesh "ep" sharding turns the combine into an XLA psum; see moe_mlp's
-    rationale). Shared by moe_mlp and mla._moe_mlp so their layouts
-    cannot diverge."""
+    """Dense-over-E expert execution + one-hot combine: every expert for
+    every row, the unpicked ones multiplied by zero. E stays a
+    batched/contracted axis, so the mesh "ep" sharding turns the combine
+    into an XLA psum, and a ``top_idx`` outside [0, E) adds nothing. The
+    form for few rows and for every mesh (``experts_run_grouped``)."""
     E = down_w.shape[0]
     combine = jnp.sum(
         jax.nn.one_hot(top_idx, E, dtype=jnp.float32)
@@ -209,13 +259,116 @@ def run_experts_dense(x: jax.Array, gate_w: jax.Array, up_w: jax.Array,
     return jnp.einsum("ne,end->nd", combine.astype(y.dtype), y)
 
 
+@jax.named_scope("run_experts_grouped")
+def run_experts_grouped(x: jax.Array, gate_w: jax.Array, up_w: jax.Array,
+                        down_w: jax.Array, top_idx: jax.Array,
+                        top_w: jax.Array, gateup_w=None,
+                        valid_rows: Optional[jax.Array] = None,
+                        layer: Optional[jax.Array] = None,
+                        interpret: bool = False) -> jax.Array:
+    """The routed experts only: same arguments and result as
+    ``run_experts_dense``, computed over the N*k (row, expert) pairs
+    sorted by expert (grouped_matmul.py). With ``layer`` (a traced
+    scalar) the stacks are every layer's, ``[L, E, ...]``, and the
+    kernel reads that layer's part in place.
+
+    A pair that must compute nothing sorts behind the last group and
+    belongs to none: a ``top_idx`` outside [0, E) (an expert another
+    chip holds) and, with ``valid_rows`` (a traced scalar), every pair
+    of a row at or past it (a prefill bucket's padding). Their rows of
+    the grouped matmuls are never computed, and never read back: the
+    combine selects zero for them."""
+    N, D = x.shape
+    k = top_idx.shape[1]
+    E = down_w.shape[-3]
+    P = N * k
+    pair_expert = top_idx.reshape(P).astype(jnp.int32)
+    live = (pair_expert >= 0) & (pair_expert < E)
+    if valid_rows is not None:
+        live &= jnp.arange(P, dtype=jnp.int32) // k < valid_rows
+    key = jnp.where(live, pair_expert, E)
+    pad = -P % ROW_TILE                       # whole row tiles
+    key_p = jnp.concatenate([key, jnp.full((pad,), E, jnp.int32)])
+    # sorted position -> pair (stable: a group keeps its rows in order)
+    order = jnp.argsort(key_p, stable=True).astype(jnp.int32)
+    group_sizes = jnp.bincount(key_p, length=E + 1)[:E].astype(jnp.int32)
+    xs = jnp.take(x, order // k, axis=0, mode="clip")         # [P+pad, D]
+
+    gmm = functools.partial(grouped_matmul, group_sizes=group_sizes,
+                            layer=layer, interpret=interpret)
+    if gateup_w is not None:      # fused gate|up (fuse_stacked_matmuls)
+        gu = gmm(xs, gateup_w)
+        F = gu.shape[-1] // 2
+        g, u = gu[..., :F], gu[..., F:]
+    else:
+        g, u = gmm(xs, gate_w), gmm(xs, up_w)
+    y = gmm(jax.nn.silu(g) * u, down_w)                       # [P+pad, D]
+
+    # pair -> sorted position, then the weighted sum over a row's k pairs
+    where = jnp.zeros((P + pad,), jnp.int32).at[order].set(
+        jnp.arange(P + pad, dtype=jnp.int32), unique_indices=True)[:P]
+    picked = jnp.where(live[:, None], jnp.take(y, where, axis=0), 0)
+    out = jnp.sum(picked.reshape(N, k, D).astype(jnp.float32)
+                  * top_w[..., None].astype(jnp.float32), axis=1)
+    return out.astype(x.dtype)
+
+
+def run_experts(x: jax.Array, gate_w: jax.Array, up_w: jax.Array,
+                down_w: jax.Array, top_idx: jax.Array, top_w: jax.Array,
+                gateup_w=None, *, sharded: bool = True,
+                valid_rows: Optional[jax.Array] = None,
+                layer: Optional[jax.Array] = None) -> jax.Array:
+    """The expert MLPs of one MoE layer: ``top_idx`` / ``top_w`` [N, k]
+    are each row's experts and mixing weights, whatever the family's
+    router made them. ONE entry point (moe_mlp and mla._moe_mlp both
+    call it, so their layouts cannot diverge) over two forms of the same
+    function, picked by ``experts_run_grouped`` from the static row
+    count, the stacks' shapes and ``sharded`` (True, the default for a
+    caller that does not know, keeps the dense form). With ``layer`` the
+    stacks are every layer's: only where ``split_expert_stacks``, which
+    asks the same chooser, kept them whole for the grouped form."""
+    E, F, D = down_w.shape[-3:]
+    if experts_run_grouped(x.shape[0], E, top_idx.shape[1], D, F, sharded):
+        return run_experts_grouped(x, gate_w, up_w, down_w, top_idx, top_w,
+                                   gateup_w=gateup_w, valid_rows=valid_rows,
+                                   layer=layer, interpret=not _on_tpu())
+    return run_experts_dense(x, gate_w, up_w, down_w, top_idx, top_w,
+                             gateup_w=gateup_w)
+
+
+EXPERT_STACKS = ("moe_gate", "moe_up", "moe_down", "moe_gateup")
+
+
+def split_expert_stacks(stack: dict, n_rows: int, top_k: int,
+                        sharded: bool) -> Tuple[dict, dict]:
+    """→ (what the layer scan slices, what it must not): where the
+    experts of an ``n_rows`` program run grouped, their stacks stay whole
+    beside the scan and the layer body hands them on with the layer's
+    index (``run_experts(..., layer=)``). A Pallas call is a custom call
+    and wants its operands whole: given the scan's per-layer slice, XLA
+    first copies ``[E, D, 2F]`` and ``[E, F, D]`` out of the stacks,
+    every layer (measured, PR 32: 0.73 s of a 2.36 s prefill program at
+    the Qwen1.5-MoE widths; an XLA fusion reads the slice in place)."""
+    down = stack.get("moe_down")
+    if down is None:
+        return stack, {}
+    E, F, D = down.shape[-3:]
+    if not experts_run_grouped(n_rows, E, top_k, D, F, sharded):
+        return stack, {}
+    return ({k: v for k, v in stack.items() if k not in EXPERT_STACKS},
+            {k: v for k, v in stack.items() if k in EXPERT_STACKS})
+
+
 @jax.named_scope("moe_mlp")
 def moe_mlp(x: jax.Array, router_w: jax.Array, gate_w: jax.Array,
             up_w: jax.Array, down_w: jax.Array, top_k: int,
             norm_topk: bool = True,
             shared: Optional[tuple] = None,
-            gateup_w=None, shared_gateup=None) -> jax.Array:
-    """Sparse MoE MLP, computed densely over the expert axis.
+            gateup_w=None, shared_gateup=None, *,
+            sharded: bool = True,
+            valid_rows: Optional[jax.Array] = None,
+            layer: Optional[jax.Array] = None) -> jax.Array:
+    """Sparse MoE MLP: route, run the experts, add the shared expert.
 
     x: [N, D]; router_w: [D, E]; gate/up: [E, D, F]; down: [E, F, D].
     ``norm_topk``: True = softmax renormalized over the top-k logits
@@ -226,13 +379,17 @@ def moe_mlp(x: jax.Array, router_w: jax.Array, gate_w: jax.Array,
     (sh_gate [D,Fs], sh_up, sh_down [Fs,D], sh_router [D,1]) — a dense
     swiglu added to every token, scaled by a learned sigmoid gate.
 
-    The expert einsums keep E as a contracted/batched axis, so sharding
-    E over the mesh "ep" axis makes XLA compute E/ep experts per device
-    and psum the combine — expert parallelism as a compiler layout, no
-    explicit dispatch. Dense compute trades FLOPs (E/top_k× the
-    active-expert cost) for static shapes — the right call for
-    serving-batch sizes where a GShard-style sort/permute dispatch would
-    be latency-bound on reshuffles anyway.
+    The experts run in one of two forms (``run_experts``). Dense over
+    the expert axis where the row count leaves it bandwidth-bound
+    (decode) and under every mesh: E stays a contracted/batched axis, so
+    sharding E over the mesh "ep" axis makes XLA compute E/ep experts
+    per device and psum the combine, expert parallelism as a compiler
+    layout with no explicit dispatch, at E/top_k times the routed FLOPs.
+    Grouped, the routed pairs only, where that factor is what the
+    device's time goes to: a single-device prefill (``sharded`` False)
+    of GROUPED_MIN_ROWS rows or more, of which only ``valid_rows`` are
+    computed. ``layer``: gate/up/down are every layer's stacks,
+    ``[L, E, ...]``, and this is the layer to read (run_experts).
     """
     N, E = x.shape[0], router_w.shape[-1]
     logits = (x @ router_w).astype(jnp.float32)                  # [N, E]
@@ -242,8 +399,9 @@ def moe_mlp(x: jax.Array, router_w: jax.Array, gate_w: jax.Array,
     else:
         probs = jax.nn.softmax(logits, axis=-1)
         top_w, top_idx = jax.lax.top_k(probs, top_k)
-    out = run_experts_dense(x, gate_w, up_w, down_w, top_idx, top_w,
-                            gateup_w=gateup_w)
+    out = run_experts(x, gate_w, up_w, down_w, top_idx, top_w,
+                      gateup_w=gateup_w, sharded=sharded,
+                      valid_rows=valid_rows, layer=layer)
     if shared is not None:
         sh_gate, sh_up, sh_down, sh_router = shared
         with jax.named_scope("shared_expert"):
@@ -435,10 +593,13 @@ class ModelStatics:
     # cannot be automatically partitioned"), so the attention kernels
     # run per tp shard under shard_map (_per_tp_shard)
     mesh: Optional[Any] = None
+    # the parameters are placed over a mesh (any mesh: tp, ep, sp, pp),
+    # so the expert stacks may be sharded: what experts_run_grouped asks
+    sharded: bool = False
 
     def __hash__(self):
         return hash((id(self.cfg), self.block_size, self.attn_impl,
-                     self.kv_coalesce, id(self.mesh)))
+                     self.kv_coalesce, id(self.mesh), self.sharded))
 
     @property
     def tp(self) -> int:
@@ -448,12 +609,19 @@ class ModelStatics:
 def _run_layers(params: Params, kv: KVCache, x: jax.Array,
                 positions: jax.Array, slots: jax.Array, cfg: ModelConfig,
                 attn_fn, final_norm: bool = True,
-                reduce_axis: Optional[str] = None
+                reduce_axis: Optional[str] = None,
+                experts_sharded: bool = True,
+                valid_rows: Optional[jax.Array] = None
                 ) -> Tuple[jax.Array, KVCache]:
     """Shared transformer stack: per layer — qkv projection, rope, KV
     scatter into the paged pool, ``attn_fn`` (the only thing the three
     forward paths differ in), wo residual, swiglu MLP; scanned over the
     stacked layer params.
+
+    ``experts_sharded`` / ``valid_rows``: what ``moe_mlp`` needs to pick
+    the experts' form (``ModelStatics.sharded``; a prefill's
+    ``true_len``). The defaults, for a caller that knows neither (the pp
+    stage ring), keep the dense form over every row.
 
     attn_fn(q, k_chunk, v_chunk, k_flat, v_flat, li, sliding) -> [N, H, Dh]
     where N is the leading axis of x (tokens for prefill, batch for
@@ -485,7 +653,9 @@ def _run_layers(params: Params, kv: KVCache, x: jax.Array,
     L = cfg.num_layers
     inv_freq = jnp.asarray(rope_inv_freq(cfg))
     rope_att = rope_attention_scaling(cfg)
-    layer_params = _layer_stack(params)
+    # experts that run grouped read their stacks whole, by layer index
+    layer_params, whole = split_expert_stacks(
+        _layer_stack(params), N, cfg.num_experts_per_tok, experts_sharded)
     sliding_flags = jnp.asarray(sliding_layer_mask(cfg))
     NTOK = kv["k"].shape[1]
 
@@ -551,13 +721,17 @@ def _run_layers(params: Params, kv: KVCache, x: jax.Array,
             shared = (tuple(lp.get(k) for k in ("sh_gate", "sh_up",
                                                 "sh_down", "sh_router"))
                       if cfg.shared_expert_size > 0 else None)
-            mlp_out = moe_mlp(hn2, lp["router"], lp.get("moe_gate"),
-                              lp.get("moe_up"), lp["moe_down"],
+            ex = whole or lp
+            mlp_out = moe_mlp(hn2, lp["router"], ex.get("moe_gate"),
+                              ex.get("moe_up"), ex["moe_down"],
                               cfg.num_experts_per_tok,
                               norm_topk=cfg.moe_norm_topk,
                               shared=shared,
-                              gateup_w=lp.get("moe_gateup"),
-                              shared_gateup=lp.get("sh_gateup"))
+                              gateup_w=ex.get("moe_gateup"),
+                              shared_gateup=lp.get("sh_gateup"),
+                              sharded=experts_sharded,
+                              valid_rows=valid_rows,
+                              layer=li if whole else None)
         else:
             mlp_out = swiglu(hn2, lp.get("gate"), lp.get("up"),
                              lp["down"], cfg.hidden_act,
@@ -823,7 +997,9 @@ def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
             T, cfg.num_heads, cfg.head_dim)
 
     x = _embed(params, tokens, cfg)  # activation dtype follows param dtype
-    x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn)
+    x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn,
+                            experts_sharded=statics.sharded,
+                            valid_rows=true_len)
     last = x[jnp.maximum(true_len - 1, 0)]
     return _logits(params, last, cfg), kv_new
 
@@ -978,7 +1154,8 @@ def ragged_forward(params: Params, kv: KVCache, tokens: jax.Array,
                                 win_lo, scale)
 
     x = _embed(params, tokens, cfg)  # [TT, D]
-    x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn)
+    x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn,
+                            experts_sharded=statics.sharded)
     if sample_all_rows:
         return _logits(params, x, cfg), kv_new             # [TT, V]
     sel = jnp.take(x, sample_rows, axis=0)                     # [S, D]
@@ -1017,5 +1194,6 @@ def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
                                 win_lo, scale)
 
     x = _embed(params, tokens, cfg)  # [B, D]
-    x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn)
+    x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn,
+                            experts_sharded=statics.sharded)
     return _logits(params, x, cfg), kv_new

@@ -77,7 +77,7 @@ The multi-token-prediction layer is not served (weights.py skips it).
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -89,8 +89,8 @@ from ..attention import (dequant_kv_rows_sections,
 from ..config import ModelConfig
 from ..quant import mm
 from .llama import (ModelStatics, _embed, _layer_stack, _logits,
-                    flat_token_indices, rms_norm, run_experts_dense,
-                    swiglu)
+                    flat_token_indices, rms_norm, run_experts,
+                    split_expert_stacks, swiglu)
 
 Params = Dict[str, jax.Array]
 KVCache = Dict[str, jax.Array]   # {"kv": [L, NTOK, rank + rope]}
@@ -545,7 +545,9 @@ def _latent_rows(lp, hn, positions, cfg: ModelConfig):
     return jnp.concatenate([c, k_pe], axis=-1)
 
 
-def _moe_mlp(hn, lp, cfg: ModelConfig) -> jax.Array:
+def _moe_mlp(hn, lp, cfg: ModelConfig, sharded: bool = True,
+             valid_rows: Optional[jax.Array] = None,
+             layer: Optional[jax.Array] = None) -> jax.Array:
     """deepseek routing, both generations (verified by the parity
     tests). v2 (HF DeepseekV2MoEGate): f32 softmax over ALL experts,
     greedy (or group-limited greedy) top-k of the SCORES without
@@ -557,7 +559,10 @@ def _moe_mlp(hn, lp, cfg: ModelConfig) -> jax.Array:
     weights are the UNBIASED sigmoid scores of the chosen experts,
     renormalized over the top-k (+1e-20) when norm_topk_prob, then
     scaled. Shared experts are a plain additive swiglu either way.
-    Experts run dense-over-E (llama.run_experts_dense)."""
+    The experts run through llama.run_experts: dense over E or, on one
+    device from llama.GROUPED_MIN_ROWS rows up, the routed pairs only
+    (``sharded`` / ``valid_rows``: what its chooser needs; ``layer``:
+    the expert stacks in ``lp`` are every layer's, read at this one)."""
     # E: the router's width — every published expert, of which this chip
     # may hold a share (cfg.num_experts_total; below)
     N, E = hn.shape[0], cfg.router_width
@@ -599,13 +604,16 @@ def _moe_mlp(hn, lp, cfg: ModelConfig) -> jax.Array:
     if cfg.num_experts_total:
         # one chip's share: the choice and the weights above are over all
         # the published experts; the stacks hold num_experts of them.
-        # run_experts_dense's one-hot is zero for an index outside
-        # [0, num_experts), so a chosen expert that lives elsewhere adds
-        # nothing here — and nothing stands in for it
+        # in both forms of run_experts an index outside
+        # [0, num_experts) computes nothing (the dense one-hot is zero
+        # there, the grouped pair joins no group), so a chosen expert
+        # that lives elsewhere adds nothing here — and nothing stands in
+        # for it
         top_idx = top_idx - cfg.expert_share_index * cfg.num_experts
-    out = run_experts_dense(hn, lp.get("moe_gate"), lp.get("moe_up"),
-                            lp["moe_down"], top_idx, top_w,
-                            gateup_w=lp.get("moe_gateup"))
+    out = run_experts(hn, lp.get("moe_gate"), lp.get("moe_up"),
+                      lp["moe_down"], top_idx, top_w,
+                      gateup_w=lp.get("moe_gateup"), sharded=sharded,
+                      valid_rows=valid_rows, layer=layer)
     if cfg.shared_expert_size > 0:
         out = out + swiglu(hn, lp.get("sh_gate"), lp.get("sh_up"),
                            lp["sh_down"], cfg.hidden_act,
@@ -615,7 +623,9 @@ def _moe_mlp(hn, lp, cfg: ModelConfig) -> jax.Array:
 
 def _run_layers(params: Params, kv: KVCache, x: jax.Array,
                 positions: jax.Array, slots: jax.Array, cfg: ModelConfig,
-                attn_fn) -> Tuple[jax.Array, KVCache]:
+                attn_fn, experts_sharded: bool = True,
+                valid_rows: Optional[jax.Array] = None
+                ) -> Tuple[jax.Array, KVCache]:
     """attn_fn(q_nope, q_pe, rows_new, kv_flat, lp, li) -> [N, H*v]; with
     an indexer (cfg.index_topk > 0) it is also given
     ``index=(qI, w, idx_flat)``: this layer's index queries and head
@@ -625,7 +635,10 @@ def _run_layers(params: Params, kv: KVCache, x: jax.Array,
     deepseek hybrid sparsity (first_k_dense): the layer stacks split
     into a dense prefix and a MoE suffix, each its own lax.scan with the
     SAME attention body — the pools carry across both, with li
-    addressing rows globally."""
+    addressing rows globally.
+
+    ``experts_sharded`` / ``valid_rows`` go to ``_moe_mlp`` (as in
+    llama._run_layers: ModelStatics.sharded, a prefill's true_len)."""
     L = cfg.num_layers
     stack = _layer_stack(params)
     NTOK = kv["kv"].shape[1]
@@ -686,7 +699,7 @@ def _run_layers(params: Params, kv: KVCache, x: jax.Array,
                            **extra)
             h = h + mm(attn, lp["wo"])
             hn2 = rms_norm(h, lp["ln2"], cfg.rms_norm_eps)
-            h = h + mlp_fn(hn2, lp)
+            h = h + mlp_fn(hn2, lp, li)
             return (h, pools), None
         return layer
 
@@ -702,7 +715,7 @@ def _run_layers(params: Params, kv: KVCache, x: jax.Array,
                 dense_lp.update({"gate": stack["dense_gate"],
                                  "up": stack["dense_up"]})
             (x, pools), _ = jax.lax.scan(
-                make_layer(lambda hn, lp: swiglu(
+                make_layer(lambda hn, lp, _li: swiglu(
                     hn, lp.get("gate"), lp.get("up"), lp["down"],
                     cfg.hidden_act, gateup_w=lp.get("gateup"))),
                 (x, pools),
@@ -713,13 +726,20 @@ def _run_layers(params: Params, kv: KVCache, x: jax.Array,
                   "sh_down", "sh_gateup"):
             if n in stack:
                 moe_lp[n] = stack[n]
+        # experts that run grouped read their stacks whole, at the
+        # layer's index among the expert layers (llama.split_expert_stacks)
+        moe_lp, whole = split_expert_stacks(
+            moe_lp, x.shape[0], cfg.num_experts_per_tok, experts_sharded)
         (x, pools), _ = jax.lax.scan(
-            make_layer(lambda hn, lp: _moe_mlp(hn, lp, cfg)),
+            make_layer(lambda hn, lp, li: _moe_mlp(
+                hn, {**lp, **whole}, cfg, sharded=experts_sharded,
+                valid_rows=valid_rows,
+                layer=li - k if whole else None)),
             (x, pools),
             {"lp": moe_lp, "i": jnp.arange(k, L, dtype=jnp.int32)})
     else:
         (x, pools), _ = jax.lax.scan(
-            make_layer(lambda hn, lp: swiglu(
+            make_layer(lambda hn, lp, _li: swiglu(
                 hn, lp.get("gate"), lp.get("up"), lp["down"],
                 cfg.hidden_act, gateup_w=lp.get("gateup"))),
             (x, pools),
@@ -831,7 +851,9 @@ def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
         return out.reshape(T, H * cfg.v_head_dim).astype(q_nope.dtype)
 
     x = _embed(params, tokens, cfg)
-    x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn)
+    x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn,
+                            experts_sharded=statics.sharded,
+                            valid_rows=true_len)
     last = x[jnp.maximum(true_len - 1, 0)]
     return _logits(params, last, cfg), kv_new
 
@@ -1003,7 +1025,8 @@ def ragged_forward(params: Params, kv: KVCache, tokens: jax.Array,
         return out.reshape(TT, H * cfg.v_head_dim).astype(q_nope.dtype)
 
     x = _embed(params, tokens, cfg)
-    x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn)
+    x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn,
+                            experts_sharded=statics.sharded)
     if sample_all_rows:
         # ragged×spec variant (llama.ragged_forward): per-row logits
         # for lockstep acceptance over speculative spans
@@ -1120,5 +1143,6 @@ def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
         return out.reshape(B, H * cfg.v_head_dim).astype(q_nope.dtype)
 
     x = _embed(params, tokens, cfg)
-    x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn)
+    x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn,
+                            experts_sharded=statics.sharded)
     return _logits(params, x, cfg), kv_new

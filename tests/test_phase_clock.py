@@ -162,6 +162,9 @@ async def test_flight_records_split_the_cycle_on_every_path(kind, kw):
         assert len(prefills) == 2
         assert all(0 < r["dispatch_ms"] <= r["host_ms"]
                    and r["wait_ms"] >= 0 for r in prefills)
+        # every prefill record says how many rows ran grouped experts:
+        # none on a model without experts
+        assert all(r["grouped_rows"] == 0 for r in prefills)
         assert sum(r["admits"] for r in core.flight.dump()
                    if "admits" in r) == 2
 
@@ -289,3 +292,72 @@ def test_benchmark_reader(name, want):
     assert read({"flight": [], "spans": []}) is None
     # a program from before the clock: nothing to read, and no error
     assert read(OLD_PROGRAM) is None
+
+
+# ------------------------------------------- the grouped experts' readers (PR 32)
+
+def _prefill(prompt, hit, grouped=None):
+    rec = {"kind": "prefill", "prompt": prompt, "hit_device": hit}
+    return rec if grouped is None else dict(rec, grouped_rows=grouped)
+
+
+@pytest.mark.parametrize("flight,want", [
+    # every computed row grouped; a prefix hit is not a computed row
+    ([_prefill(1100, 0, 1100), _prefill(2000, 512, 1488)], 100.0),
+    # a mix of buckets on both sides of the crossover
+    ([_prefill(200, 0, 200), _prefill(100, 0, 0), _prefill(100, 0, 0)], 50.0),
+    # the dense form everywhere: a number, not nothing
+    ([_prefill(64, 0, 0), {"kind": "decode", "batch_fill": 4}], 0.0),
+    # a program from before the counter, or no prefill: nothing to read
+    ([_prefill(1100, 0)], None),
+    ([{"kind": "decode", "batch_fill": 4}], None),
+], ids=["all-grouped", "half", "dense-reads-zero", "no-counter",
+        "no-prefill"])
+def test_grouped_rows_share_is_read_from_the_prefill_records(flight, want):
+    got = reader("moe.grouped_rows_pct")({"flight": flight})
+    assert got == want and (want is None or isinstance(got, float))
+
+
+def test_grouped_experts_time_is_read_per_prefill():
+    read = reader("kernel.grouped_experts_ms")
+    trace = {"ops": [("%grouped_experts.26", 0.2, 96),
+                     ("%grouped_experts.27", 0.1, 96),
+                     ("%paged_attention.12", 0.4, 100),
+                     ("%fusion.9", 1.0, 8)],
+             "programs": [("jit_prefill", 2.0, 8), ("jit_decode_k", 1.5, 100)]}
+    assert read({"trace": trace}) == pytest.approx(0.3 / 8 * 1e3)
+    # the parent's program has no such kernel; a window may hold no prefill
+    assert read({"trace": dict(trace, ops=trace["ops"][2:])}) is None
+    assert read({"trace": dict(trace, programs=trace["programs"][1:])}) is None
+    assert read({"trace": {}}) is None
+
+
+@pytest.mark.asyncio
+async def test_prefill_records_count_the_rows_that_ran_grouped():
+    """An expert model served on one device: a 200-token prompt lands in
+    the 256-row bucket, whose experts run grouped (the Pallas call, in
+    interpret mode here), a 40-token one in the 64-row bucket, dense; the
+    records say so with the rows each dispatch computed, and the reader
+    turns them into the share."""
+    cfg = ModelConfig(
+        vocab_size=256, hidden_size=128, intermediate_size=128,
+        num_layers=2, num_heads=2, num_kv_heads=2, head_dim=64,
+        max_position_embeddings=512, num_experts=6, num_experts_per_tok=2,
+        moe_norm_topk=False, shared_expert_size=128)
+    core = EngineCore(cfg, EngineConfig(
+        max_model_len=320, kv_block_size=16, num_kv_blocks=48,
+        max_num_seqs=2, prefill_buckets=[64, 256]), attn_impl="xla",
+        param_dtype=jnp.float32)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, 256, size=n).tolist() for n in (200, 40)]
+    try:
+        assert await serve(core, prompts, max_new=3) == [3, 3]
+    finally:
+        await core.stop()
+    prefills = {r["prompt"]: r for r in core.flight.dump()
+                if r["kind"] == "prefill"}
+    assert prefills[200]["grouped_rows"] == 200
+    assert prefills[40]["grouped_rows"] == 0
+    assert reader("moe.grouped_rows_pct")(
+        {"flight": list(prefills.values())}) == pytest.approx(
+            100.0 * 200 / 240)

@@ -353,3 +353,79 @@ def test_sparse_attention_programs_carry_stable_names(one_chip, monkeypatch):
                       "/indexer/", "/dsa_select/", "/sparse_attention/",
                       "run_experts_dense"):
             assert scope in text, (top, scope)
+
+
+@pytest.mark.parametrize("case", ["kernel-qwen-widths", "prefill-1024",
+                                  "prefill-2048", "decode"])
+def test_routed_experts_run_grouped_in_prefill(one_chip, monkeypatch, case):
+    """Above the crossover the engine's prefill program of a Qwen-like
+    model (experts top-2 of 6, unnormalised, a gated shared expert, int8
+    weights, the fused gate|up stack) runs the routed experts through the
+    Pallas call ``grouped_experts`` under the scope ``moe_mlp``: no
+    ``[E, N, ·]`` dense-over-experts intermediate, and the int8 stacks
+    reach the kernel as int8 (no dequantised ``[E, D, F]`` copy). The
+    decode program keeps ``run_experts_dense``. The kernel alone also
+    builds at the benchmark's Qwen1.5-MoE widths."""
+    import re
+
+    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+    from dynamo_tpu.engine.core import EngineCore
+    from dynamo_tpu.engine.grouped_matmul import grouped_matmul
+    from dynamo_tpu.engine.models import llama
+    from dynamo_tpu.engine.quant import QuantizedArray
+    if case == "kernel-qwen-widths":
+        E, D, F, pairs = 60, 2048, 1408, 8192
+        for K, N in ((D, 2 * F), (F, D)):
+            _compile(lambda x, q, s, g: grouped_matmul(
+                x, QuantizedArray(q, s), g), one_chip,
+                ((pairs, K), jnp.bfloat16), ((E, K, N), jnp.int8),
+                ((E, 1, N), jnp.float32), ((E,), jnp.int32))
+        return
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    monkeypatch.setattr(llama, "_on_tpu", lambda: True)
+    E, D, F = 6, 256, 384
+    cfg = ModelConfig(
+        vocab_size=2048, hidden_size=D, intermediate_size=F,
+        num_layers=2, num_heads=4, num_kv_heads=2, head_dim=64,
+        max_position_embeddings=4096, num_experts=E, num_experts_per_tok=2,
+        moe_norm_topk=False, shared_expert_size=512)
+    B, T = 8, 2048 if case == "prefill-2048" else 1024
+    core = EngineCore(cfg, EngineConfig(
+        max_model_len=T + 64, kv_block_size=16, num_kv_blocks=T // 16 + 8,
+        max_num_seqs=B, prefill_buckets=[T], quantization="int8"),
+        attn_impl="pallas")
+    M = core.M
+    assert not core.statics.sharded
+    assert isinstance(core.params["layers.moe_gateup"], QuantizedArray)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, kv = jax.tree.map(lambda x: s(x.shape, x.dtype),
+                              (core.params, core.kv))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    i32, f32 = jnp.int32, jnp.float32
+    if case == "decode":
+        text = core._decode_k_jit.lower(
+            params, kv, s((B,), i32), s((B,), i32), s((B, M), i32),
+            s((B,), i32), s((B,), i32), s((B,), f32), s((B,), i32),
+            s((B,), f32), s((1, B), i32), s((1, B), jnp.bool_),
+            s(key.shape, key.dtype)).compile().as_text()
+        assert "moe_mlp/run_experts_dense" in text
+        assert "grouped_experts" not in text
+        return
+    text = core._prefill_jit.lower(
+        params, kv, s((T,), i32), s((M,), i32), s((), i32), s((), i32),
+        s(key.shape, key.dtype), s((), f32), s((), i32),
+        s((), f32)).compile().as_text()
+    assert re.search(r"moe_mlp/run_experts_grouped/.*grouped_experts", text)
+    assert "moe_mlp/shared_expert/swiglu" in text
+    assert "run_experts_dense" not in text
+    # nothing dense over the experts: no [E, T, 2F | F | D] of any dtype
+    assert not re.search(rf"\[{E},{T},({2 * F}|{F}|{D})\]", text)
+    # the expert stacks enter as int8 and are never widened whole
+    assert re.search(rf"s8\[2,{E},{D},{2 * F}\]", text)
+    # … nor copied layer by layer out of the stack for the custom call
+    assert not re.search(rf"s8\[{E},({D},{2 * F}|{F},{D})\]", text)
+    assert not re.search(
+        rf"(bf16|f32|f16|s32)\[(\d+,)?{E},({D},{2 * F}|{F},{D})\]", text)
