@@ -64,10 +64,8 @@ def spec_mode_k() -> int:
 
 def pp_mode() -> int:
     """Pipeline-parallel bench mode (--pp[=N] or BENCH_PP=N): 0 = off.
-    One parse home for main() and the smoke tests. Measures the v2
-    token-interleaved stage ring against the v1 bubbled loop under one
-    protocol (ISSUE 4 acceptance: v2 steady-state step < 0.6x v1 at
-    B=8 microbatched on the CPU mesh)."""
+    One parse home for main() and the smoke tests. Measures the
+    token-interleaved stage ring's steady-state step."""
     n = int(os.environ.get("BENCH_PP", "0"))
     for a in sys.argv[1:]:
         if a == "--pp":
@@ -78,25 +76,18 @@ def pp_mode() -> int:
 
 
 def run_pp_bench(pp: int) -> dict:
-    """Interleaved-vs-bubbled pipeline decode measurement.
+    """Token-interleaved pipeline decode measurement.
 
-    Both variants run the SAME geometry, weights, and greedy token
-    chains on a pp-stage mesh; per-step device time comes from the
-    chained-dispatch slope (utils/timing.py — the same protocol as the
-    baseline row, so constants and fetch costs cancel):
+    The K-step dispatch (`pp_decode_k_forward`: pp microbatches
+    round-robin the stage ring, utilization K·pp/(K·pp+pp-1)) on a
+    pp-stage mesh; per-step device time comes from the chained-dispatch
+    slope (utils/timing.py — the same protocol as the baseline row, so
+    constants and fetch costs cancel).
 
-    - v1: the bubbled stage loop (`pp_decode_forward`), one full-batch
-      step per dispatch — every rank computes every stage iteration,
-      utilization 1/pp.
-    - v2: the token-interleaved K-step dispatch
-      (`pp_decode_k_forward`) — pp microbatches round-robin the ring,
-      utilization K·pp/(K·pp+pp-1).
-
-    Reports the measured step-time ratio, the schedule's analytic
-    utilization/bubble, greedy-token equality between the two loops,
-    and the modeled DCN boundary economics
-    (parallel/ici_model.pp_step_model) for the cross-host deployment
-    the CPU mesh stands in for."""
+    Reports the step time, the schedule's analytic utilization/bubble,
+    greedy-token equality with the single-device step chain, and the
+    modeled DCN boundary economics (parallel/ici_model.pp_step_model)
+    for the cross-host deployment the CPU mesh stands in for."""
     import numpy as np
     import jax
     import jax.numpy as jnp
@@ -105,8 +96,8 @@ def run_pp_bench(pp: int) -> dict:
     from dynamo_tpu.engine.models import llama
     from dynamo_tpu.parallel.ici_model import pp_step_model
     from dynamo_tpu.parallel.pipeline_parallel import (
-        make_pp_mesh, place_pp, pp_bubble_fraction, pp_decode_forward,
-        pp_decode_k_forward, pp_dispatch_ticks, pp_dispatch_utilization)
+        make_pp_mesh, place_pp, pp_bubble_fraction, pp_decode_k_forward,
+        pp_dispatch_ticks, pp_dispatch_utilization)
     from dynamo_tpu.utils.timing import slope_per_unit
 
     if len(jax.devices()) < pp:
@@ -116,16 +107,10 @@ def run_pp_bench(pp: int) -> dict:
 
     B = int(os.environ.get("BENCH_PP_BATCH", "8"))
     K = int(os.environ.get("BENCH_PP_HARVEST", "8"))
-    # decode at realistic context depth (default seq 512): the
-    # interleave win is in ROW-SCALED work — attention/KV reads at
-    # depth, which dominate production decode — while the per-tick
+    # decode at realistic context depth: the interleave pays in
+    # ROW-SCALED work (attention/KV reads at depth), while the per-tick
     # weight stream is row-independent (each rank re-reads its L/pp
-    # stack per tick regardless of microbatch rows). At trivial depth
-    # the weight stream dominates and the measured ratio degrades
-    # toward ~0.7 on this mesh (same physics on real HBM); at the seq-1024
-    # default the B=8 ratio lands ~0.45 (< the 0.6 acceptance bar). The lm
-    # head costs B rows/step under BOTH loops (v1 replicated outside
-    # the ring, v2 on the last stage).
+    # stack per tick regardless of microbatch rows)
     seq0 = int(os.environ.get("BENCH_PP_SEQ", "1024"))
     mcfg = ModelConfig(vocab_size=2048, hidden_size=256,
                        intermediate_size=1024, num_layers=8,
@@ -150,25 +135,25 @@ def run_pp_bench(pp: int) -> dict:
                         .astype(np.int32))
     pos0 = seq0
     seeds = jnp.asarray(np.zeros(B, np.int64))
-    temp = jnp.zeros((B,), jnp.float32)        # greedy: both loops agree
+    temp = jnp.zeros((B,), jnp.float32)        # greedy
     topk = jnp.zeros((B,), jnp.int32)
     topp = jnp.ones((B,), jnp.float32)
     planned = jnp.zeros((K, B), jnp.int32)
     pmask = jnp.zeros((K, B), bool)
 
-    fn_v1 = jax.jit(pp_decode_forward, static_argnums=(5, 6))
+    fn_single = jax.jit(llama.decode_forward, static_argnums=5)
     fn_v2 = jax.jit(
         lambda pr, kv, t, p, s0: pp_decode_k_forward(
             pr, kv, t, p, tables, seeds, s0, temp, topk, topp,
             planned, pmask, statics, mesh, K, 0))
 
-    def v1_tokens(n_steps):
-        kv = pkv
+    def single_device_tokens(n_steps):
+        kv = kv0
         t = toks0
         p = jnp.full((B,), pos0, jnp.int32)
         out = []
         for _ in range(n_steps):
-            lg, kv = fn_v1(pparams, kv, t, p, tables, statics, mesh)
+            lg, kv = fn_single(params, kv, t, p, tables, statics)
             t = jnp.argmax(lg, -1).astype(jnp.int32)
             p = p + 1
             out.append(t)
@@ -188,22 +173,11 @@ def run_pp_bench(pp: int) -> dict:
             out.append(np.asarray(tk))
         return np.concatenate(out, axis=0)
 
-    # greedy-token equality between the two loops (the serving contract
-    # the tier-1 tests pin against single-device; here it guards the
-    # bench itself from comparing diverged programs)
-    tokens_match = bool(np.array_equal(v1_tokens(K), v2_tokens(1)))
-
-    def chain_v1(m):
-        kv = pkv
-        t = toks0
-        p = jnp.full((B,), pos0, jnp.int32)
-        t0 = time.monotonic()
-        for _ in range(m * K):
-            lg, kv = fn_v1(pparams, kv, t, p, tables, statics, mesh)
-            t = jnp.argmax(lg, -1).astype(jnp.int32)
-            p = p + 1
-        np.asarray(t)                       # the one barrier fetch
-        return time.monotonic() - t0
+    # greedy-token equality with the single-device chain (the serving
+    # contract the tier-1 tests pin; here it guards the bench itself
+    # from timing a diverged program)
+    tokens_match = bool(np.array_equal(single_device_tokens(K),
+                                       v2_tokens(1)))
 
     def chain_v2(m):
         kv = pkv
@@ -220,9 +194,7 @@ def run_pp_bench(pp: int) -> dict:
         return time.monotonic() - t0
 
     m1, m2 = SLOPE_M1, SLOPE_M2
-    v1_step_s = max(slope_per_unit(chain_v1, m1, m2) / K, 1e-9)
     v2_step_s = max(slope_per_unit(chain_v2, m1, m2) / K, 1e-9)
-    ratio = v2_step_s / v1_step_s
     ticks = pp_dispatch_ticks(pp, K)
     # per-tick device time, for the DCN boundary model: one interleaved
     # dispatch is `ticks` uniform ticks
@@ -236,11 +208,8 @@ def run_pp_bench(pp: int) -> dict:
         "geometry": {"hidden": mcfg.hidden_size,
                      "layers": mcfg.num_layers,
                      "vocab": mcfg.vocab_size},
-        "v1_bubbled_step_ms": round(v1_step_s * 1e3, 3),
         "v2_interleaved_step_ms": round(v2_step_s * 1e3, 3),
-        "ratio_v2_over_v1": round(ratio, 3),
-        "speedup_vs_v1": round(1.0 / ratio, 2) if ratio > 0 else 0.0,
-        "tokens_match_v1": tokens_match,
+        "tokens_match": tokens_match,
         "dispatch_ticks": ticks,
         "utilization_model": round(pp_dispatch_utilization(pp, K), 4),
         "bubble_fraction": round(pp_bubble_fraction(pp, K), 4),
@@ -1604,8 +1573,8 @@ def main() -> None:
     pp_res = None
     if pp_mode() > 0:
         # independent small pp-mesh setup (its own geometry — the
-        # baseline row above is untouched): v1 bubbled vs v2
-        # interleaved steady-state step time + the modeled DCN story
+        # baseline row above is untouched): the interleaved
+        # steady-state step time + the modeled DCN story
         pp_res = run_pp_bench(pp_mode())
 
     ragged_res = None
@@ -1699,8 +1668,8 @@ def main() -> None:
         # step-time A/B per layout
         result["kv_frag"] = kv_frag_res
     if pp_res is not None:
-        # pipeline-parallel provenance: interleaved-vs-bubbled step
-        # ratio, per-stage utilization, modeled DCN boundary economics
+        # pipeline-parallel provenance: interleaved step time,
+        # per-stage utilization, modeled DCN boundary economics
         result["pp"] = pp_res
     if ragged_res is not None:
         # unified-ragged-dispatch provenance: dispatches/token and

@@ -352,7 +352,10 @@ class MockTokenWorker:
             # closure: a field the mock can't feed is a panel no
             # no-hardware test can ever prove works)
             served = eng.requests_served
-            d["num_requests_waiting"] = max(live - 4, 0)
+            # base + live, as request_active_slots above: a test that
+            # sets queue pressure on self.metrics must reach the planner
+            d["num_requests_waiting"] = (self.metrics.num_requests_waiting
+                                         + max(live - 4, 0))
             d["gpu_cache_usage_perc"] = min(0.1 + 0.01 * live, 0.9)
             d["gpu_prefix_cache_hit_rate"] = 0.45
             d["host_stored_total"] = 2 * served

@@ -18,7 +18,6 @@ Two decode implementations with identical semantics:
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +36,19 @@ NEG_INF = -1e30
 # rejects sub-tile slices; measured on v5e). quantize_kv_rows /
 # dequant_kv_rows below are the encoding's single home.
 KV_SCALE_LANES = 128
+
+# The paged kernels' tiling, shared by the decode and ragged kernels, the
+# host-side DMA counters that mirror their wave walk, and ragged_supported's
+# VMEM budget. Callers that sweep pass chunk_blocks= / seqs_per_program=,
+# which key the compile cache.
+# DMA wave depth in blocks: 16 = 256 tokens a wave at block size 16. Deeper
+# waves amortize the per-wave DMA issue cost at long sequences (16 beat 8
+# by 1-2 ms a step at seq 512-1024, llama-1B shapes, on the setup before
+# PR 25; not re-measured on this chip).
+ATTN_CHUNK_BLOCKS = 16
+# sequences per grid program of the decode kernel: amortizes the
+# per-program fixed costs (_paged_attn_kernel's docstring)
+ATTN_SEQS_PER_PROGRAM = 8
 
 
 def kv_value_lanes(k_cache: jax.Array) -> int:
@@ -577,7 +589,7 @@ def dma_copy_counts(block_tables, seq_lens, *, block_size: int,
     sl = np.asarray(seq_lens)
     B, M = bt.shape
     if chunk_blocks is None:
-        chunk_blocks = int(os.environ.get("DYN_ATTN_CHUNK_BLOCKS", "16"))
+        chunk_blocks = ATTN_CHUNK_BLOCKS
     chunk = max(1, min(chunk_blocks, M))
     contig = (wave_contig_table(bt, sl, block_size=block_size,
                                 chunk=chunk, pool_blocks=pool_blocks,
@@ -1009,20 +1021,11 @@ def paged_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     g = H // KVH
     M = block_tables.shape[1]
     if chunk_blocks is None:
-        # DMA wave depth; 16 blocks = 256 tokens/wave at bs=16. Tuned
-        # on-chip (v5e, llama-1B shapes): 16 beats 8 by ~1 ms at
-        # B=128/seq=512 and ~2 ms at seq=1024, ties elsewhere — deeper
-        # waves amortize per-wave DMA issue cost at long seq (PERF.md).
-        # Both env overrides are read at TRACE time: under jit the value
-        # bakes into the compiled program, so sweeps must use a fresh
-        # process per setting (or pass the parameter, which keys caches).
-        chunk_blocks = int(os.environ.get("DYN_ATTN_CHUNK_BLOCKS", "16"))
+        chunk_blocks = ATTN_CHUNK_BLOCKS
     chunk = max(1, min(chunk_blocks, M))
     Hp = max(8, H)   # sublane-pad the head rows for tiny models
     if seqs_per_program is None:
-        # sequences per grid program (fixed-cost amortization; kernel doc)
-        seqs_per_program = int(os.environ.get("DYN_ATTN_SEQS_PER_PROG",
-                                              "8"))
+        seqs_per_program = ATTN_SEQS_PER_PROGRAM
     G = max(1, min(seqs_per_program, B))
     Bp = ((B + G - 1) // G) * G
     # sparse slot placement: row h carries q[h] at its kv head's lane group
@@ -1480,7 +1483,7 @@ def ragged_paged_attention_pallas(q: jax.Array, k_cache: jax.Array,
     Cv = C if v_lanes is None else v_lanes
     g = H // KVH
     if chunk_blocks is None:
-        chunk_blocks = int(os.environ.get("DYN_ATTN_CHUNK_BLOCKS", "16"))
+        chunk_blocks = ATTN_CHUNK_BLOCKS
     chunk = max(1, min(chunk_blocks, M))
     Hp = max(8, H)
     Lmax = max(8, int(max_rows))     # 8-sublane floor for the q window
@@ -1580,7 +1583,7 @@ def ragged_prefetch_counts(seq_counts, seq_lens, win_base=None, *,
     counts = np.asarray(seq_counts)
     sl = np.asarray(seq_lens)
     if chunk_blocks is None:
-        chunk_blocks = int(os.environ.get("DYN_ATTN_CHUNK_BLOCKS", "16"))
+        chunk_blocks = ATTN_CHUNK_BLOCKS
     chunk = max(1, (min(chunk_blocks, blocks_per_table)
                     if blocks_per_table else chunk_blocks))
     nb = -(-sl // block_size)
@@ -1650,7 +1653,7 @@ def ragged_supported(num_heads: int, num_kv_heads: int, head_dim: int,
         return False
     rows = max(8, max_rows) * Hp                          # Lmax * Hp
     C = num_kv_heads * head_dim
-    wave = int(os.environ.get("DYN_ATTN_CHUNK_BLOCKS", "16")) * block_size
+    wave = ATTN_CHUNK_BLOCKS * block_size
     lanes = C + KV_SCALE_LANES if kv_dtype == jnp.int8 else C
     itemsize = jnp.dtype(kv_dtype or jnp.bfloat16).itemsize
     kv_waves = 2 * 2 * wave * lanes * itemsize

@@ -687,10 +687,6 @@ class EngineConfig:
     # greedy argmax at near-tie logits (root-caused via engine/replay.py;
     # previously misattributed to a pipelined-dispatch race).
     decode_dispatch_pipeline: bool = False
-    # admission prefills start an async device→host copy of their sampled
-    # token and complete after the next decode dispatch, so the fetch
-    # overlaps decode instead of stalling the engine loop. Emission order per request is unchanged.
-    overlap_admission_fetch: bool = True
     # continuous-batching lane prefill: when the engine is ALREADY decoding,
     # an admission whose un-hit prompt suffix is <= this many tokens skips
     # the dedicated prefill program and instead rides the decode batch —

@@ -20,7 +20,6 @@ from __future__ import annotations
 import asyncio
 import dataclasses
 import logging
-import os
 import time
 from typing import Dict, List, Optional
 
@@ -92,8 +91,9 @@ class EngineRequest:
     key_step: int = 0
     last_token: int = -1
     # False while the admission prefill's sampled token is still being
-    # fetched from the device (overlap_admission_fetch): the slot is held
-    # but excluded from decode until completion
+    # fetched from the device (it completes after the next decode
+    # dispatch, _admit_with_plan's `defer`): the slot is held but excluded
+    # from decode until completion
     ready: bool = True
     prefix_hit_tokens: int = 0
     seq: Optional[TokenBlockSequence] = None   # full token history + hashes
@@ -278,8 +278,7 @@ class EngineCore:
             from .quant import quantize_params
             params = quantize_params(
                 params, include_embed=qembed, bits=qbits)
-        if (mesh is None
-                and os.environ.get("DYN_FUSE_MATMULS", "1") != "0"):
+        if mesh is None:
             # single-device decode perf: wq|wk|wv → wqkv, gate|up →
             # gateup (llama.fuse_stacked_matmuls). The gate is ANY mesh,
             # not just tp: under tp the fused out axis cannot carry the
@@ -2196,8 +2195,7 @@ class EngineCore:
             # prefill side never fetched it — one round-trip saved); defer
             # our fetch behind the next decode dispatch like a local
             # admission
-            defer = (self.cfg.overlap_admission_fetch
-                     and hasattr(tok, "copy_to_host_async"))
+            defer = hasattr(tok, "copy_to_host_async")
             fetch = not defer
             t_dispatched = time.monotonic()
         else:
@@ -2279,14 +2277,11 @@ class EngineCore:
             # handoff needs the host value immediately; DEVICE handoff
             # never needs it at all — the token rides the payload as a
             # device scalar and the decode side defers its own fetch.
-            defer = (self.cfg.overlap_admission_fetch
-                     and req.handoff is None)
+            defer = req.handoff is None
             fetch = not defer and not req.handoff_device
         if fetch:
             with self.clock.phase("wait"):
                 tok, logprob = int(tok), float(logprob)
-        if req.handoff is not None:
-            defer = False
         req.pos = n_prompt
         req.generated = 1
         req.key_step += 1

@@ -757,11 +757,7 @@ def _lm_head_kernel_ok(head: QuantizedArray,
     """Use the fused Pallas head on real TPUs when the vocab tiles evenly
     AND the head is unsharded — under tensor parallelism the vocab axis is
     mesh-sharded and pallas_call has no GSPMD partitioning rule (the
-    engine clears cfg.lm_head_pallas when it shards params over tp>1).
-    DYN_LMHEAD_KERNEL=0 is the escape hatch back to the XLA paths."""
-    import os
-    if os.environ.get("DYN_LMHEAD_KERNEL", "1") == "0":
-        return False
+    engine clears cfg.lm_head_pallas when it shards params over tp>1)."""
     if cfg is not None and not cfg.lm_head_pallas:
         return False
     if head.group or head.q.dtype != jnp.int8:
@@ -789,8 +785,8 @@ def _logits(params: Params, x: jax.Array,
     # Fused Pallas dequant-matmul (engine/lm_head.py): pins the int8 head
     # at its weights-read floor regardless of batch — XLA's int8 matmul
     # heuristics are batch-dependent (the pre-transposed head collapses
-    # 4.5ms → 82ms between B=16 and B=64 on v5e). DYN_LMHEAD_KERNEL=0
-    # falls back to the XLA paths below.
+    # 4.5ms → 82ms between B=16 and B=64 on v5e). Where the kernel does
+    # not apply (_lm_head_kernel_ok) the XLA paths below serve.
     if (isinstance(head, QuantizedArray) and head.q.ndim == 2
             and _lm_head_kernel_ok(head, cfg)):
         from ..lm_head import lm_head_int8

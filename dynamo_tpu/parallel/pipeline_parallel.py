@@ -10,7 +10,7 @@ ONE [B, D] activation per stage boundary per step — the only viable
 cross-host axis, and the capacity enabler for checkpoints that exceed a
 host's HBM (DeepSeek-V3 int8 ≈ 336 GB > any single v5e/v5p host).
 
-v2 (this round): PP is a THROUGHPUT axis, not just a capacity axis.
+PP is a THROUGHPUT axis, not just a capacity axis.
 
 - **Token-interleaved decode** (`pp_decode_k_forward`): the decode batch
   B splits into ``pp`` microbatches of B/pp rows and round-robins them
@@ -22,8 +22,9 @@ v2 (this round): PP is a THROUGHPUT axis, not just a capacity axis.
   sampled-token → next-step dependency rides the same boundary hop and
   every rank computes a LIVE microbatch every tick. A K-step dispatch
   runs ``K*pp + (pp-1)`` ticks: steady-state utilization
-  K·pp/(K·pp+pp-1) → ~1 (vs the v1 bubbled loop's 1/pp), with the
-  (pp-1)-tick fill/drain ramp amortized over the dispatch.
+  K·pp/(K·pp+pp-1) → ~1 (a stage loop that is not microbatched
+  keeps 1/pp), with the (pp-1)-tick fill/drain ramp amortized over the
+  dispatch.
 - **Microbatched prefill** (`pp_prefill_forward`): a padded [T] prompt
   chunk splits into pp sequential C=T/pp sub-chunks pipelined through
   the same schedule (chunk m at stage r on tick m+r, 2·pp-1 ticks) —
@@ -56,7 +57,7 @@ dropped by mode="drop". (-1 would NOT work: advanced-index scatter
 normalizes negatives first, so -1 silently overwrites the pool's LAST
 row — round-5 review catch.)
 
-Remaining v2 limits (refused loudly by EngineCore, not silently wrong):
+Remaining limits (refused loudly by EngineCore, not silently wrong):
 weight/KV quantization (QuantizedArray leaves under the stage shard_map
 are unvalidated), MLA, speculative decoding (the verify program has no
 interleaved form yet), sp composition, and sliding-window families (the
@@ -130,88 +131,7 @@ def _local_cfg_for(statics, pp: int, tp: int):
     return dataclasses.replace(local_statics, cfg=local_cfg)
 
 
-# ------------------------------------------------------------- v1 (bubbled)
-def pp_decode_forward(params: Dict[str, jax.Array], kv, tokens, positions,
-                      block_tables, statics, mesh) -> Tuple[jax.Array, dict]:
-    """v1 bubbled single-step decode over a pp-sharded layer stack — kept
-    as the regression/bench baseline the interleaved loop is judged
-    against (`bench.py --pp` measures both under one protocol).
-
-    Same contract as llama.decode_forward; params' ``layers.*`` stacks
-    and the kv pools must be sharded P("pp") on their leading axis (the
-    caller places them — pp_param_pspecs/pp_kv_pspecs). Every rank runs
-    its local stack each of the pp stage iterations; only the rank whose
-    turn it is has the real activation (utilization 1/pp — the bubble
-    pp_decode_k_forward removes)."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    cfg = statics.cfg
-    pp = mesh.shape["pp"]
-    local_statics = pp_split_config(statics, pp)
-    local_cfg = local_statics.cfg
-    B = tokens.shape[0]
-    bsz = statics.block_size
-    scale = llama._attn_scale(cfg)
-    slots = (block_tables[jnp.arange(B), positions // bsz] * bsz
-             + positions % bsz)
-    seq_lens = positions + 1
-
-    stacks = {k: v for k, v in params.items() if k.startswith("layers.")}
-    x0 = llama._embed(params, tokens, cfg)            # [B, D], replicated
-
-    ring = [(i, (i + 1) % pp) for i in range(pp)]
-
-    def stage_fn(stacks_l, kv_l, x, positions, slots, seq_lens,
-                 block_tables):
-        r = jax.lax.axis_index("pp")
-
-        def attn(q, _k, _v, k_flat, v_flat, li, sliding):
-            num_blocks = k_flat.shape[0] // (local_cfg.num_layers * bsz)
-            return llama.paged_attention(
-                q, k_flat, v_flat, block_tables + li * num_blocks,
-                seq_lens, block_size=bsz, scale=scale,
-                impl=local_statics.attn_impl,
-                softcap=local_cfg.attn_logit_softcap,
-                kv_heads=local_cfg.num_kv_heads,
-                coalesce=local_statics.kv_coalesce)
-
-        for s in range(pp):
-            if s:
-                x = jax.lax.ppermute(x, "pp", ring)
-            my_turn = r == s
-            # off-turn ranks run the same program on garbage input (the
-            # un-microbatched bubble) — their KV scatters are masked to
-            # index NTOK (OOB, dropped by mode="drop"; see module
-            # docstring on why -1 would corrupt the pool's last row)
-            ntok = kv_l["k"].shape[1]
-            slots_eff = jnp.where(my_turn, slots, ntok)
-            x2, kv_l = llama._run_layers(stacks_l, kv_l, x, positions,
-                                         slots_eff, local_cfg, attn,
-                                         final_norm=False)
-            x = jnp.where(my_turn, x2, x)
-        # rank pp-1 holds the final activation; hand it around the ring
-        # once and psum a rank-0 mask so every rank returns the same x
-        x = jax.lax.ppermute(x, "pp", ring)
-        x = jax.lax.psum(
-            jnp.where(jax.lax.axis_index("pp") == 0, x, 0.0), "pp")
-        return x, kv_l
-
-    stack_specs = {k: P("pp") for k in stacks}
-    kv_specs = {k: P("pp") for k in kv}
-    fn = shard_map(
-        stage_fn, mesh=mesh,
-        in_specs=(stack_specs, kv_specs, P(), P(), P(), P(), P()),
-        out_specs=(P(), kv_specs),
-        check_rep=False)
-    x, kv_new = fn(stacks, kv, x0, positions, slots, seq_lens,
-                   block_tables)
-    x = llama.rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
-                       cfg.norm_plus_one)
-    return llama._logits(params, x, cfg), kv_new
-
-
-# --------------------------------------------------- v2: token interleaving
+# ------------------------------------------------------- token interleaving
 def pp_decode_k_forward(params, kv, tokens, positions, block_tables,
                         seeds, steps0, temperature, top_k, top_p,
                         planned, planned_mask, statics, mesh, K: int,

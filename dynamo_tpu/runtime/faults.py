@@ -192,7 +192,7 @@ def disarm(site: str) -> None:
 
 def disarm_all() -> None:
     """Disarm every site but KEEP the fired counters (the chaos suite's
-    per-test isolation; the coverage gate reads the counters after)."""
+    per-test isolation)."""
     _ARMED.clear()
 
 

@@ -26,6 +26,7 @@ from dynamo_tpu.llm.protocols.disagg import (KvPayload, RemotePrefillRequest,
 from dynamo_tpu.runtime import Context
 from dynamo_tpu.runtime.distributed import DistributedRuntime
 from dynamo_tpu.runtime.engine import EngineContext
+from tests.fixtures import wait_until
 
 pytestmark = pytest.mark.asyncio
 
@@ -467,6 +468,29 @@ def make_seeded_request(prompt, rid) -> Context:
     return Context(pre, ctx=EngineContext(rid))
 
 
+def hold_stream_until_admitted(engine, core):
+    """Which of two legitimate paths a layer stream admits through is a
+    race between the frames' arrival and the engine loop's admission
+    tick: a payload that is whole by then takes the monolithic
+    precomputed path (by design, `LayerStreamPayload.values`) and the
+    per-layer counters these tests read stay 0 — six busy xdist workers
+    made that happen now and then. Hold the drain (the frames queue in
+    the receiver) until the core has admitted against the manifest, so
+    that the streamed path is the one that runs."""
+    spawn = engine._spawn_stream_drain
+
+    def held(rid, rx, payload):
+        async def later():
+            await wait_until(lambda: core.disagg_stream_admits >= 1,
+                             "admission against the stream's manifest")
+            spawn(rid, rx, payload)
+        task = asyncio.get_running_loop().create_task(later())
+        engine._drain_tasks.add(task)
+        task.add_done_callback(engine._drain_tasks.discard)
+
+    engine._spawn_stream_drain = held
+
+
 async def _wire_disagg_run(prompt, rid, layer_stream, seeded=False):
     rt = DistributedRuntime.in_process()
     prefill_core = make_core()
@@ -475,6 +499,8 @@ async def _wire_disagg_run(prompt, rid, layer_stream, seeded=False):
                                  conditional=False)
     engine = DisaggEngine(decode_core, rt, router, device_plane=False,
                           layer_stream=layer_stream)
+    if layer_stream:
+        hold_stream_until_admitted(engine, decode_core)
     worker = await PrefillWorker(prefill_core, rt).start()
     try:
         req = (make_seeded_request(prompt, rid) if seeded
@@ -541,6 +567,7 @@ async def test_layer_stream_recorded_replay(prompt):
                                  conditional=False)
     engine = DisaggEngine(decode_core, rt, router, device_plane=False,
                           layer_stream=True)
+    hold_stream_until_admitted(engine, decode_core)
     worker = await PrefillWorker(prefill_core, rt).start()
     try:
         got = await collect_tokens(

@@ -1,11 +1,11 @@
 """Pipeline parallelism (parallel/pipeline_parallel.py): pp-sharded
 layer stacks must serve IDENTICALLY to the single-device model —
 including the KV the stages write (ramp-tick garbage must land on
-dropped slots, never in the pool). v2 (token interleaving) raises the
-bar from the v1 bubbled loop's logits-allclose to BIT-EQUAL sampled
-token streams and pool bytes over chained dispatches, through the full
-EngineCore serving path, and across a preemption landing mid-stream
-(the stage ring's fill/drain ramps straddle the preempted dispatch).
+dropped slots, never in the pool). The token-interleaved loop is held
+to BIT-EQUAL sampled token streams and pool bytes over chained
+dispatches, through the full EngineCore serving path, and across a
+preemption landing mid-stream (the stage ring's fill/drain ramps
+straddle the preempted dispatch).
 Reference analog: the vLLM engines' pipeline_parallel_size flag
 (subprocess.rs:41); ours is the cross-host THROUGHPUT axis since this
 round (module docstring has the DCN arithmetic and the interleave
@@ -26,7 +26,6 @@ from dynamo_tpu.engine.sampling import make_slot_keys, sample_tokens
 from dynamo_tpu.parallel.pipeline_parallel import (make_pp_mesh,
                                                    place_pp,
                                                    pp_bubble_fraction,
-                                                   pp_decode_forward,
                                                    pp_decode_k_forward,
                                                    pp_dispatch_ticks,
                                                    pp_dispatch_utilization,
@@ -56,47 +55,57 @@ def _place(params, kv, mesh):
 
 @pytest.mark.parametrize("pp", [2, 4])
 def test_pp_decode_matches_single_device(pp):
-    """v1 bubbled loop regression (kept as the bench baseline)."""
+    """One interleaved dispatch against the plain single-device walk:
+    `decode_forward` one step at a time, greedy — not the scan
+    `_decode_k_ref` holds it to below. Tokens equal, the chosen tokens'
+    logprobs (all the dispatch returns of its logits) and the pool the
+    stages wrote allclose."""
     statics = llama.ModelStatics(cfg=TINY, block_size=8, attn_impl="xla")
     params = llama.init_params(TINY, jax.random.PRNGKey(3),
                                dtype=jnp.float32)
     kv0 = llama.init_kv_cache(TINY, 32, 8, dtype=jnp.float32)
-    rng = np.random.default_rng(5)
-    B, M = 2, 4
-    # seq 0 decodes AT the pool's final row (block 31, offset 7 = row
-    # NTOK-1): the off-turn KV mask must never touch it — a -1 mask
-    # would overwrite exactly that row every stage (review catch:
-    # advanced-index scatter normalizes -1 BEFORE mode="drop")
-    tables = jnp.asarray(rng.integers(1, 31, size=(B, M)).astype(np.int32))
-    tables = tables.at[0, M - 1].set(31)
-    toks = jnp.asarray([5, 9], jnp.int32)
-    pos = jnp.asarray([31, 7], jnp.int32)
+    B, M, K = 4, 4, 3
+    # disjoint per-slot tables; seq 0 decodes AT the pool's final row
+    # (block 31, offset 7 = row NTOK-1): a ramp tick's masked KV scatter
+    # must never touch it — a -1 mask would overwrite exactly that row
+    # (review catch: advanced-index scatter normalizes -1 BEFORE
+    # mode="drop")
+    grid = np.arange(1, B * M + 1, dtype=np.int32).reshape(B, M)
+    grid[0, M - 1] = 31
+    tables = jnp.asarray(grid)
+    toks = jnp.asarray([5, 9, 17, 33], jnp.int32)
+    pos = jnp.asarray([31, 7, 12, 0], jnp.int32)
 
-    # single-device truth: THREE chained steps (the pp pool writes must
-    # feed later steps exactly)
-    want_logits = []
+    # single-device truth: K chained steps (the pp pool writes must feed
+    # later steps exactly)
+    want_toks, want_lps = [], []
     kv = jax.tree.map(jnp.copy, kv0)
     t, p = toks, pos
-    for _ in range(3):
-        lg, kv = jax.jit(llama.decode_forward, static_argnums=5)(
-            params, kv, t, p, tables, statics)
-        want_logits.append(np.asarray(lg))
+    step = jax.jit(llama.decode_forward, static_argnums=5)
+    for _ in range(K):
+        lg, kv = step(params, kv, t, p, tables, statics)
         t = jnp.argmax(lg, -1).astype(jnp.int32)
+        want_toks.append(np.asarray(t))
+        want_lps.append(np.asarray(jnp.take_along_axis(
+            jax.nn.log_softmax(lg, -1), t[:, None], -1)[:, 0]))
         p = p + 1
 
     mesh = make_pp_mesh(pp)
     pparams, pkv = _place(params, jax.tree.map(jnp.copy, kv0), mesh)
-    got_logits = []
-    t, p = toks, pos
-    fn = jax.jit(pp_decode_forward, static_argnums=(5, 6))
-    for _ in range(3):
-        lg, pkv = fn(pparams, pkv, t, p, tables, statics, mesh)
-        got_logits.append(np.asarray(lg))
-        t = jnp.argmax(lg, -1).astype(jnp.int32)
-        p = p + 1
+    zeros = jnp.zeros((B,), jnp.int32)
+    tk, lps, pkv = jax.jit(lambda pr, kv_: pp_decode_k_forward(
+        pr, kv_, toks, pos, tables, zeros, zeros,
+        jnp.zeros((B,), jnp.float32), zeros, jnp.ones((B,), jnp.float32),
+        jnp.zeros((K, B), jnp.int32), jnp.zeros((K, B), bool),
+        statics, mesh, K, 0))(pparams, pkv)
 
-    for w, g in zip(want_logits, got_logits):
-        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(tk), np.stack(want_toks))
+    np.testing.assert_allclose(np.asarray(lps), np.stack(want_lps),
+                               rtol=1e-5, atol=1e-5)
+    for key in ("k", "v"):
+        np.testing.assert_allclose(np.asarray(pkv[key]),
+                                   np.asarray(kv[key]),
+                                   rtol=1e-5, atol=1e-5)
 
 
 def _decode_k_ref(params, kv, tables, statics, seeds, temp, topk, topp,
