@@ -57,8 +57,16 @@ Attention on top of the v3 block:
   copies of engine/block_copy.py move every array of the pool dict, so
   prefix reuse, defrag and preemption carry both;
 - **select, then attend**: an exact top-k of the masked scores,
-  ``k = min(index_topk, table capacity)`` (one stable sort that carries
-  positions and pool rows: ``_select``), then the absorbed attention
+  ``k = min(index_topk, table capacity)``, ties to the lower position,
+  that never orders the table by score (``_select`` →
+  ``engine/select_compact.py``, a Pallas kernel): the k-th largest score
+  by a bisection on the scores' bits (a compare and a row count a step),
+  the ties at it by position, and then a compaction that moves ONE
+  uint32 per taken position to the front (position above, layer-offset
+  block id below), from which the pool rows come back by arithmetic.
+  Where position and block id need more than 32 bits (``TableSlots``:
+  the table's length and the pool's block count, static shapes) the
+  position and the row move as two values. Then the absorbed attention
   over the gathered rows only. ``ctx <= index_topk`` selects every valid
   row and equals the dense path. Prefill blocks its queries
   (``DSA_QUERY_BLOCK``) so that nothing of size heads × chunk × table
@@ -77,6 +85,8 @@ The multi-token-prediction layer is not served (weights.py skips it).
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -88,6 +98,7 @@ from ..attention import (dequant_kv_rows_sections,
                          ragged_paged_attention_pallas)
 from ..config import ModelConfig
 from ..quant import mm
+from ..select_compact import NOT_TAKEN, compact_top_k
 from .llama import (ModelStatics, _embed, _layer_stack, _logits,
                     flat_token_indices, rms_norm, run_experts,
                     split_expert_stacks, swiglu)
@@ -423,29 +434,79 @@ def _index_scores(qI, w, keys) -> jax.Array:
     return jnp.einsum("nj,njs->ns", w, jax.nn.relu(dots))
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["blocks"],
+                   meta_fields=["bsz", "pool_blocks"])
+@dataclasses.dataclass(frozen=True)
+class TableSlots:
+    """The pool rows of a block table's positions, kept as the arithmetic
+    that gives them: position p lives in pool row
+    ``blocks[..., p // bsz] * bsz + p % bsz``. ``pool_blocks`` bounds the
+    ids (a static shape): with the table's length it decides whether a
+    position and its block id share one 32-bit value in ``_select``."""
+    blocks: jax.Array     # [..., M] layer-offset block ids
+    bsz: int
+    pool_blocks: int
+
+    def ids(self) -> jax.Array:
+        """[..., M * bsz]: the block id of every position (a broadcast)."""
+        return jnp.repeat(self.blocks, self.bsz, axis=-1)
+
+    def rows(self) -> jax.Array:
+        """[..., M * bsz]: the pool row of every position (no gather)."""
+        rows = (self.blocks[..., None] * self.bsz
+                + jnp.arange(self.bsz, dtype=self.blocks.dtype))
+        return rows.reshape(self.blocks.shape[:-1] + (-1,))
+
+
+_U32 = jnp.uint32
+
+
+def _order_bits(scores, live) -> jax.Array:
+    """float32 scores → uint32 that order the same way (−0.0 equals +0.0,
+    as a sort's comparator has them), 0 for a position that is not live;
+    every live one is above 0. A live −inf or NaN counts as not live: the
+    stable sort this replaced gave it the dead positions' key."""
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0, 0.0, scores).astype(jnp.float32), _U32)
+    up = jnp.where(bits >> 31 == 1, ~bits, bits | _U32(1 << 31))
+    return jnp.where(live & (scores > -jnp.inf), up, _U32(0))
+
+
 def _select(scores, live, topk: int, slots):
-    """Exact top-k of the live positions, by one stable sort that carries
-    each position and its pool slot along (ties go to the lower position,
-    as lax.top_k's do): no gather of 4-byte ids follows it. scores, live
-    [N, S]; slots [N, S] or [S], the pool row of every table position. →
-    (positions [N, k], valid [N, k], pool rows [N, k]), k = min(index_topk,
-    table capacity); where fewer than k positions are live the tail is
-    marked invalid."""
+    """Exact top-k of the live positions, ties to the lower position (as a
+    stable sort by score has them, and lax.top_k), without ordering the
+    table by score: ``select_compact.compact_top_k`` finds the k-th
+    largest score by a bisection on the scores' bits, takes the positions
+    above it and the first ties in position order, and moves what
+    describes the taken positions to the front (a Pallas kernel; it runs
+    interpreted off the TPU). What moves holds no score: ONE uint32 per
+    position, the position above and its block id below (``TableSlots``;
+    the pool row comes back by arithmetic), where the two fit 32 bits;
+    else, or where ``slots`` is a plain array of pool rows ([N, S] or
+    [S]), the position and the row, two values. scores, live [N, S]. →
+    (positions [N, k], valid [N, k], pool rows [N, k]) in position order,
+    k = min(index_topk, table capacity); where fewer than k positions are
+    live the tail is invalid (position 0, row 0)."""
+    from ..attention import _on_tpu
     N, S = scores.shape
     k = min(topk, S)
-    neg = jnp.where(live, -scores, jnp.inf)
-    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (N, S))
-    neg, pos, rows = jax.lax.sort(
-        (neg, pos, jnp.broadcast_to(slots, (N, S))), dimension=1,
-        is_stable=True, num_keys=1)
-    return pos[:, :k], neg[:, :k] < jnp.inf, rows[:, :k]
-
-
-def _table_slots(tables_l, bsz: int) -> jax.Array:
-    """[..., M] block ids → [..., M * bsz]: the pool row of every position
-    of the table (arithmetic, no gather)."""
-    rows = tables_l[..., None] * bsz + jnp.arange(bsz, dtype=tables_l.dtype)
-    return rows.reshape(tables_l.shape[:-1] + (-1,))
+    take = functools.partial(compact_top_k, _order_bits(scores, live), k=k,
+                             interpret=not _on_tpu())
+    pos = jnp.arange(S, dtype=_U32)
+    packed = isinstance(slots, TableSlots)
+    id_bits = slots.pool_blocks.bit_length() if packed else 32
+    if (S - 1).bit_length() + id_bits <= 32:
+        # ids stay under 2**id_bits − 1: no taken key is NOT_TAKEN
+        key, = take((pos << id_bits | slots.ids().astype(_U32),))
+        valid = key != NOT_TAKEN
+        key = jnp.where(valid, key, _U32(0))
+        at = (key >> id_bits).astype(jnp.int32)
+        ids = (key & _U32((1 << id_bits) - 1)).astype(jnp.int32)
+        return at, valid, ids * slots.bsz + at % slots.bsz
+    at, rows = take((pos, slots.rows() if packed else slots))
+    valid = at != NOT_TAKEN
+    return jnp.where(valid, at, _U32(0)).astype(jnp.int32), valid, rows
 
 
 def _attend_selected(q_lat, q_pe, kv_flat, slot_ids, valid, scale: float,
@@ -490,8 +551,9 @@ def _sparse_rows(q_lat, q_pe, index, kv_flat, tables_l, seq_lens,
         keys = _keys_by_block(idx_flat, tables_l, bsz).reshape(N, S, dI)
         live = jnp.arange(S)[None, :] < seq_lens[:, None]
         _, valid, slot_ids = _select(_index_scores(qI, w, keys), live,
-                                     cfg.index_topk,
-                                     _table_slots(tables_l, bsz))
+                                     cfg.index_topk, TableSlots(
+                                         tables_l, bsz,
+                                         kv_flat.shape[0] // bsz))
     with jax.named_scope("sparse_attention"):
         return _attend_selected(q_lat, q_pe, kv_flat, slot_ids, valid,
                                 scale, cfg.kv_lora_rank,
@@ -512,7 +574,7 @@ def _sparse_chunk(q_nope, q_pe, w_k, index, kv_flat, table_l, positions,
     S, dI = table_l.shape[0] * bsz, idx_flat.shape[-1]
     keys = _keys_by_block(idx_flat, table_l, bsz).reshape(S, dI)
     kpos = jnp.arange(S)[None, :]
-    slots = _table_slots(table_l, bsz)
+    slots = TableSlots(table_l, bsz, kv_flat.shape[0] // bsz)
     TQ = math.gcd(T, DSA_QUERY_BLOCK)
 
     def block(xs):

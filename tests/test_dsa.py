@@ -240,6 +240,167 @@ def test_selected_sets_match_the_reference(ref, chk):
     assert last != set(range(len(seq) - 16, len(seq)))
 
 
+def _select_by_stable_sort(scores, live, topk, slots):
+    """The definition ``mla._select`` is held to: the plain reference it
+    replaced (PR 31), one stable sort by negated score that carries each
+    position and its pool row; ties go to the lower position."""
+    N, S = scores.shape
+    k = min(topk, S)
+    neg = jnp.where(live, -scores, jnp.inf)
+    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (N, S))
+    neg, pos, rows = jax.lax.sort(
+        (neg, pos, jnp.broadcast_to(slots, (N, S))), dimension=1,
+        is_stable=True, num_keys=1)
+    return pos[:, :k], neg[:, :k] < jnp.inf, rows[:, :k]
+
+
+def _case_scores(kind: str, N: int, S: int, rng) -> np.ndarray:
+    x = rng.standard_normal((N, S)).astype(np.float32)
+    if kind == "ties8":          # eight values: hundreds of ties at the cut
+        x = np.round(x * 1.5).clip(-4, 3).astype(np.float32)
+    elif kind == "equal":
+        x = np.full((N, S), 0.25, np.float32)
+    elif kind == "zeros":        # −0.0 beside +0.0 (equal), around the cut
+        x = np.where(np.abs(x) < 0.8,
+                     np.where(rng.random((N, S)) < 0.5, -0.0, 0.0), x
+                     ).astype(np.float32)
+    elif kind == "nonfinite":    # a live −inf or NaN is never selected
+        x[rng.random((N, S)) < 0.3] = -np.inf
+        x[rng.random((N, S)) < 0.2] = np.nan
+        x[rng.random((N, S)) < 0.05] = np.inf
+    elif kind == "relu":         # the indexer's: many exact zeros, ≥ 0
+        x = np.maximum(x, 0)
+    return x
+
+
+def _case_live(kind: str, N: int, S: int, rng) -> np.ndarray:
+    pos = np.arange(S)[None, :]
+    if kind == "lens":           # decode: a length per row, one row empty
+        lens = rng.integers(0, S + 1, (N, 1))
+        lens[0], lens[-1] = 0, S
+        return pos < lens
+    if kind == "causal":         # prefill: query n sees up to start + n
+        start = S - N
+        return (pos <= start + np.arange(N)[:, None]) & (pos < S - 3)
+    if kind == "holes":
+        return rng.random((N, S)) < 0.6
+    return np.ones((N, S), bool)
+
+
+# name, N, S, k, scores, live, slots form, block size, pool blocks
+_SELECT_CASES = [
+    ("random", 8, 256, 32, "normal", "all", "table", 16, 64),
+    ("random-rows-per-query", 8, 256, 32, "normal", "lens", "tables", 16, 64),
+    ("ties-8-values", 8, 256, 32, "ties8", "all", "table", 16, 64),
+    ("ties-8-values-holes", 8, 240, 50, "ties8", "holes", "tables", 16, 64),
+    ("all-equal", 4, 256, 32, "equal", "all", "table", 16, 64),
+    ("all-equal-lens", 4, 256, 32, "equal", "lens", "tables", 16, 64),
+    ("signed-zeros", 8, 256, 100, "zeros", "all", "table", 16, 64),
+    ("relu-zeros", 8, 256, 200, "relu", "holes", "tables", 16, 64),
+    ("nonfinite", 8, 256, 64, "nonfinite", "lens", "tables", 16, 64),
+    ("fewer-live-than-k", 8, 256, 200, "normal", "lens", "tables", 16, 64),
+    ("k-equals-S", 4, 64, 64, "normal", "holes", "table", 16, 8),
+    ("k-above-S", 4, 48, 2048, "ties8", "lens", "tables", 16, 8),
+    ("causal-prefill-block", 32, 304, 16, "normal", "causal", "table", 16,
+     112),
+    ("causal-ties", 32, 304, 16, "ties8", "causal", "table", 16, 112),
+    ("S-not-a-power-of-two", 5, 17 * 24, 33, "normal", "lens", "tables", 24,
+     40),
+    ("S-one-block", 3, 16, 5, "ties8", "all", "table", 16, 4),
+    ("rows-as-array-S", 8, 256, 32, "ties8", "holes", "array", 16, 64),
+    ("rows-as-array-NS", 8, 256, 32, "ties8", "lens", "arrays", 16, 64),
+    ("published-sizes", 3, 17408, 2048, "relu", "lens", "tables", 16,
+     7 * 12288),
+    ("published-sizes-prefill", 2, 17408, 2048, "ties8", "causal", "table",
+     16, 7 * 12288),
+    ("pool-overflows-the-key", 4, 17408, 2048, "ties8", "lens", "tables", 16,
+     1 << 17),
+    ("small-table-large-pool", 8, 256, 32, "normal", "holes", "table", 16,
+     1 << 24),
+]
+
+
+@pytest.mark.parametrize("case", _SELECT_CASES, ids=lambda c: c[0])
+def test_select_is_the_stable_sorts_top_k(case):
+    """``mla._select`` (a threshold search, then a compaction of one packed
+    key: ``engine/select_compact.py``, interpreted here) keeps exactly the
+    set the stable three-operand sort keeps, ties to the lower position,
+    on the same float32 scores."""
+    name, N, S, k, kind, mask, form, bsz, pool = case
+    rng = np.random.default_rng(sum(map(ord, name)))
+    scores = _case_scores(kind, N, S, rng)
+    live = _case_live(mask, N, S, rng)
+    M = S // bsz
+    shape = (N, M) if form in ("tables", "arrays") else (M,)
+    blocks = rng.integers(0, pool, shape).astype(np.int32)
+    blocks.flat[0] = pool - 1                   # the largest id there is
+    table = mla.TableSlots(jnp.asarray(blocks), bsz, pool)
+    rows_of = np.asarray(table.rows())
+    assert rows_of.shape == shape[:-1] + (S,)
+    assert (rows_of // bsz == np.repeat(blocks, bsz, -1)).all()
+    slots = table if form in ("table", "tables") else jnp.asarray(rows_of)
+
+    select = jax.jit(mla._select, static_argnums=2)
+    pos, valid, rows = map(np.asarray, select(scores, live, k, slots))
+    w_pos, w_valid, _ = map(np.asarray, jax.jit(
+        _select_by_stable_sort, static_argnums=2)(scores, live, k, rows_of))
+    kk = min(k, S)
+    assert pos.shape == valid.shape == rows.shape == (N, kk)
+    rows_of = np.broadcast_to(rows_of, (N, S))
+    for n in range(N):
+        got, want = pos[n][valid[n]], w_pos[n][w_valid[n]]
+        assert len(set(got.tolist())) == len(got)
+        assert set(got.tolist()) == set(want.tolist()), (name, n)
+        assert (rows[n][valid[n]] == rows_of[n][got]).all(), (name, n)
+    assert ((rows >= 0) & (rows < pool * bsz)).all()
+    assert ((pos >= 0) & (pos < S)).all()
+
+    # nothing is sorted; beside the order bits ONE value moves where
+    # position and block id fit 32 bits, else two; never a score
+    eqns = jax.make_jaxpr(
+        lambda s, m: mla._select(s, m, k, slots))(scores, live).eqns
+    assert not [e for e in eqns if e.primitive.name == "sort"]
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    fits = (form in ("table", "tables")
+            and (S - 1).bit_length() + pool.bit_length() <= 32)
+    assert [len(e.invars) for e in calls] == [2 if fits else 3]
+    assert all(v.aval.dtype == jnp.int32 for e in calls for v in e.invars)
+
+
+@pytest.mark.parametrize("N, S, k, shared", [
+    (8, 256, 32, False), (3, 200, 7, True), (9, 384, 1, False),
+    (2, 128, 128, True), (16, 1000, 999, False)],
+    ids=["aligned", "ragged-rows-and-lanes", "k-1", "k-S", "nearly-all"])
+def test_compact_top_k_on_raw_order_bits(N, S, k, shared):
+    """``select_compact.compact_top_k`` alone, on order bits over the whole
+    uint32 range (the top bit, all ones, few distinct values) and shapes it
+    has to pad: the k best positions, ties to the lower, in position
+    order, and every value array carried along."""
+    from dynamo_tpu.engine.select_compact import NOT_TAKEN, compact_top_k
+    rng = np.random.default_rng(N * S + k)
+    order = rng.integers(0, 1 << 32, (N, S), dtype=np.uint64)
+    order[:, ::3] = rng.integers(0, 4, (N, len(range(0, S, 3)))) << 30
+    order[rng.random((N, S)) < 0.2] = 0
+    order[rng.random((N, S)) < 0.05] = 0xFFFFFFFF
+    order[0, k // 2:] = 0                        # fewer live than k
+    order = order.astype(np.uint32)
+    first = rng.integers(0, 0xFFFFFFFF, (S,) if shared else (N, S),
+                         dtype=np.uint32)
+    second = rng.integers(-2 ** 31, 2 ** 31, (N, S)).astype(np.int32)
+    got1, got2 = map(np.asarray, jax.jit(
+        lambda o, a, b: compact_top_k(o, (a, b), k, interpret=True))(
+        order, first, second))
+    assert got1.dtype == np.uint32 and got2.dtype == np.int32
+    first = np.broadcast_to(first, (N, S))
+    for n in range(N):
+        best = np.lexsort((np.arange(S), -order[n].astype(np.int64)))[:k]
+        best = np.sort(best[order[n][best] > 0])
+        c = len(best)
+        assert (got1[n, :c] == first[n][best]).all(), n
+        assert (got2[n, :c] == second[n][best]).all(), n
+        assert (got1[n, c:] == NOT_TAKEN).all() and not got2[n, c:].any()
+
+
 def test_context_inside_topk_equals_dense_mla():
     """ctx <= index_topk: every live row is selected, and the result is the
     dense path's (the same parameters served with no indexer)."""

@@ -307,8 +307,11 @@ def test_sparse_attention_programs_carry_stable_names(one_chip, monkeypatch):
     programs compile for the chip at narrow widths with both caches, and
     name the three stages of DeepSeek Sparse Attention — ``indexer``,
     ``dsa_select``, ``sparse_attention`` — beside the scopes every model
-    has. No Pallas kernel yet: the stages are XLA gathers, einsums and
-    a sort."""
+    has. The gathers and einsums are XLA's; the exact top-k is the Pallas
+    call ``dsa_select_compact`` (a threshold search and a compaction of
+    ONE packed key, engine/select_compact.py): under ``dsa_select`` no
+    program sorts anything, by score or otherwise."""
+    import re
     from dynamo_tpu.engine.config import EngineConfig, ModelConfig
     from dynamo_tpu.engine.core import EngineCore
     from dynamo_tpu.engine.models import llama
@@ -353,6 +356,34 @@ def test_sparse_attention_programs_carry_stable_names(one_chip, monkeypatch):
                       "/indexer/", "/dsa_select/", "/sparse_attention/",
                       "run_experts_dense"):
             assert scope in text, (top, scope)
+        select = [line for line in text.splitlines()
+                  if "/dsa_select/" in line]
+        assert not [line for line in select
+                    if re.search(r"[\])}] sort\(", line)], top
+        calls = [line for line in select if "tpu_custom_call" in line
+                 and "dsa_select_compact" in line]
+        assert calls, top
+        # order bits and one packed key in, one array out: no score, and
+        # nothing rides along
+        for line in calls:
+            operands = re.search(r" custom-call\(([^)]*)\)", line).group(1)
+            assert len(operands.split(",")) == 2, (top, line)
+            assert re.search(r"= s32\[", line), (top, line)
+
+
+@pytest.mark.parametrize("rows, values", [(64, 1), (32, 1), (64, 2)],
+                         ids=["decode-64", "prefill-32", "two-values"])
+def test_select_compact_builds_at_the_published_sizes(one_chip, rows, values):
+    """``dsa_select_compact`` at DeepSeek-V3.2's table (17,408 positions,
+    top 2,048): one packed key for a decode step's 64 slots and a prefill
+    block's 32 queries, and the two-value form of a pool whose ids do not
+    fit beside the position."""
+    from dynamo_tpu.engine.select_compact import compact_top_k
+    s = jax.ShapeDtypeStruct((rows, 17408), jnp.uint32, sharding=one_chip)
+    text = jax.jit(lambda o, *v: compact_top_k(o, v, 2048)).lower(
+        s, *[s] * values).compile().as_text()
+    assert "dsa_select_compact" in text and "tpu_custom_call" in text
+    assert not [line for line in text.splitlines() if " sort(" in line]
 
 
 @pytest.mark.parametrize("case", ["kernel-qwen-widths", "prefill-1024",
