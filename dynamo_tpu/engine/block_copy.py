@@ -81,6 +81,10 @@ def _pad_pow2(n: int) -> int:
 def _move_blocks(kv: KVCache, src_ids: jax.Array, dst_ids: jax.Array,
                  block_size: int) -> KVCache:
     def one(arr: jax.Array) -> jax.Array:
+        if arr.ndim != 3:
+            # a per-slot array of a hybrid cache (models/sambay.py: window
+            # rings, recurrent state): it holds no blocks, nothing moves
+            return arr
         L, _T, HD = arr.shape
         paged = arr.reshape(L, -1, block_size, HD)
         vals = jnp.take(paged, src_ids, axis=1)

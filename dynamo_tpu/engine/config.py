@@ -143,6 +143,21 @@ class ModelConfig:
     # even-layers-local default)
     sliding_window: Optional[int] = None
     layer_types: Optional[List[str]] = None
+    # phi4flash (models/sambay.py, docs/hybrid_cache.md): the SambaY
+    # decoder-hybrid-decoder. mamba_d_state > 0 is what EngineCore
+    # dispatches on (ModelConfig.is_sambay). Mamba-1 layers of d_inner =
+    # mamba_expand * hidden_size channels with mamba_d_state states each,
+    # a causal conv of mamba_d_conv taps and a dt projection of rank
+    # mamba_dt_rank; every mb_per_layer-th layer is of the state-space
+    # kind, the others attend (sliding_window is the window of the first
+    # half's attention layers). Norms are LayerNorm with bias
+    # (rms_norm_eps holds layer_norm_eps); there is no positional
+    # encoding. Layer kinds by index: sambay.layer_kinds.
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 0
+    mamba_expand: int = 0
+    mamba_dt_rank: int = 0
+    mb_per_layer: int = 0
     # runtime switch, not model geometry: the engine clears this when the
     # head is mesh-sharded (tp>1) — the fused Pallas head has no GSPMD
     # partitioning rule (models/llama.py _lm_head_kernel_ok)
@@ -155,6 +170,16 @@ class ModelConfig:
         return self.num_experts_total or self.num_experts
 
     @property
+    def is_sambay(self) -> bool:
+        """State-space, window and shared-cache layers in one model
+        (phi4flash): models/sambay.py serves it."""
+        return self.mamba_d_state > 0
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
     def is_deepseek_v3(self) -> bool:
         """The v3 generation's attention-score and routing conventions
         (deepseek_v32 is v3 plus the indexer)."""
@@ -163,6 +188,26 @@ class ModelConfig:
     @classmethod
     def from_hf_config(cls, cfg: Dict[str, Any]) -> "ModelConfig":
         mt = str(cfg.get("model_type", "llama"))
+        if mt == "phi4flash":
+            return cls._from_phi4flash(cfg)
+        # a family with no branch here falls through to the llama block:
+        # right for its many renamings, wrong for one whose layers keep a
+        # recurrent state or come in kinds this parser does not know. It
+        # says so in keys the llama branch never reads, so refuse by name
+        # instead of serving the nearest family's mathematics
+        unread = [k for k in ("mb_per_layer", "linear_attn_config")
+                  if cfg.get(k)]
+        kinds = sorted(set(cfg.get("layer_types") or ())
+                       - {"sliding_attention", "full_attention"})
+        if unread or kinds:
+            raise ValueError(
+                f"model_type {mt!r} is not implemented: its config carries "
+                + " and ".join(
+                    ([f"the key(s) {', '.join(unread)}"] if unread else [])
+                    + ([f"layer_types of kind {', '.join(kinds)}"]
+                       if kinds else []))
+                + ", which no model module here reads (state-space layers "
+                "are served for phi4flash only); it is not parsed as llama")
         if mt.startswith("gemma") and mt not in ("gemma", "gemma2"):
             # gemma3+ has different norms/attention — half-detecting it
             # via the gemma defaults would load garbage silently
@@ -486,6 +531,60 @@ class ModelConfig:
                              if mt == "phi3" and cfg.get("sliding_window")
                              else None)),
         )
+
+    @classmethod
+    def _from_phi4flash(cls, cfg: Dict[str, Any]) -> "ModelConfig":
+        """Phi-4-mini-flash-reasoning's published keys. The Mamba sizes
+        are the modelling code's constants (d_state 16, d_conv 4, expand
+        2, dt_rank ceil(hidden / 16)); a config may state them."""
+        hidden = int(cfg["hidden_size"])
+        n_heads = int(cfg["num_attention_heads"])
+        n_kv = int(cfg.get("num_key_value_heads", n_heads))
+        layers = int(cfg["num_hidden_layers"])
+        head_dim = int(cfg.get("head_dim") or hidden // n_heads)
+        problems = []
+        if int(cfg.get("mb_per_layer", 2)) != 2:
+            problems.append("mb_per_layer must be 2 (state-space and "
+                            "attention layers alternate)")
+        if layers < 4 or layers % 2:
+            problems.append("num_hidden_layers must be even and at least "
+                            "4 (pairs of layers around the exporting "
+                            "state-space layer and the full-attention one)")
+        if not cfg.get("sliding_window"):
+            problems.append("sliding_window is missing")
+        if n_heads % 2 or n_kv % 2 or n_heads % n_kv:
+            problems.append("differential attention pairs adjacent heads: "
+                            "both head counts must be even and divide")
+        if (int(cfg.get("mamba_expand", 2)) * hidden) % 128:
+            problems.append("the state-space width (mamba_expand * "
+                            "hidden_size) must be a multiple of 128 lanes "
+                            "(engine/ssm.py)")
+        if cfg.get("rope_scaling") or cfg.get("mlp_bias") \
+                or cfg.get("lm_head_bias"):
+            problems.append("rope_scaling / mlp_bias / lm_head_bias are "
+                            "not implemented (the published config has "
+                            "none)")
+        if problems:
+            raise ValueError("phi4flash: " + "; ".join(problems))
+        return cls(
+            model_type="phi4flash",
+            vocab_size=int(cfg["vocab_size"]),
+            hidden_size=hidden,
+            intermediate_size=int(cfg["intermediate_size"]),
+            num_layers=layers, num_heads=n_heads, num_kv_heads=n_kv,
+            head_dim=head_dim,
+            max_position_embeddings=int(
+                cfg.get("max_position_embeddings", 262144)),
+            rms_norm_eps=float(cfg.get("layer_norm_eps", 1e-5)),
+            tie_word_embeddings=bool(cfg.get("tie_word_embeddings", True)),
+            attention_bias=True,
+            hidden_act=str(cfg.get("hidden_act") or "silu"),
+            sliding_window=int(cfg["sliding_window"]),
+            mamba_d_state=int(cfg.get("mamba_d_state", 16)),
+            mamba_d_conv=int(cfg.get("mamba_d_conv", 4)),
+            mamba_expand=int(cfg.get("mamba_expand", 2)),
+            mamba_dt_rank=int(cfg.get("mamba_dt_rank") or -(-hidden // 16)),
+            mb_per_layer=2)
 
     @classmethod
     def from_model_dir(cls, model_dir: str) -> "ModelConfig":

@@ -199,7 +199,21 @@ class EngineCore:
         # full-precision first — each unsupported combination refuses
         # loudly below rather than serving garbage.
         self.is_mla = model_cfg.kv_lora_rank > 0
-        if self.is_mla:
+        # state-space, window and shared-cache layers in one model
+        # (phi4flash; models/sambay.py, docs/hybrid_cache.md)
+        self.is_hybrid = model_cfg.is_sambay
+        if self.is_hybrid:
+            from .models import sambay
+            self.model_mod = sambay
+            refused = sambay.hybrid_refusals(model_cfg, engine_cfg, mesh)
+            if refused:
+                # every path that ships, quantises or re-runs cache rows
+                # either carries a slot's state and window rows or refuses
+                # HERE, at build (the matrix: docs/hybrid_cache.md)
+                raise NotImplementedError(
+                    "phi4flash (per-slot recurrent state and window rows) "
+                    "is not implemented with: " + "; ".join(refused))
+        elif self.is_mla:
             from .models import mla
             self.model_mod = mla
             if engine_cfg.quantization.startswith("int4"):
@@ -222,7 +236,7 @@ class EngineCore:
                     + "; ".join(refused))
         else:
             self.model_mod = llama
-        if (model_cfg.sliding_window is not None
+        if (model_cfg.sliding_window is not None and not self.is_hybrid
                 and engine_cfg.max_model_len <= model_cfg.sliding_window):
             # the window can never bind at this serving length: drop it so
             # decode keeps the Pallas-eligible path (window masking forces
@@ -308,7 +322,14 @@ class EngineCore:
                     f"({model_cfg.num_kv_heads}) — each tp shard must "
                     f"own whole heads to carry its own in-row scale "
                     f"group")
-        if self.is_mla:
+        if self.is_hybrid:
+            # three kinds: --num-kv-blocks sizes the paged pool,
+            # --max-num-seqs the window rings and the recurrent state
+            self.kv = self.model_mod.init_kv_cache(
+                model_cfg, engine_cfg.num_kv_blocks,
+                engine_cfg.kv_block_size, engine_cfg.max_num_seqs,
+                dtype=param_dtype)
+        elif self.is_mla:
             self.kv = self.model_mod.init_kv_cache(
                 model_cfg, engine_cfg.num_kv_blocks,
                 engine_cfg.kv_block_size, dtype=param_dtype,
@@ -401,7 +422,11 @@ class EngineCore:
             enable_reuse=engine_cfg.enable_prefix_reuse,
             on_stored=self._on_block_stored,
             on_removed=self._on_block_removed, host_pool=host_pool,
-            disk_store=self.disk_store, remote_store=self.remote_store)
+            disk_store=self.disk_store, remote_store=self.remote_store,
+            layout=(self.model_mod.cache_layout(
+                model_cfg, engine_cfg.kv_block_size,
+                jnp.dtype(param_dtype).itemsize)
+                if self.is_hybrid else None))
         if host_pool is not None:
             self.offload_engine = KvOffloadEngine(
                 host_pool, engine_cfg.kv_block_size,
@@ -411,6 +436,14 @@ class EngineCore:
                 on_store=self._emit_kv_store)
         self.M = engine_cfg.max_blocks_per_seq
         self.B = engine_cfg.max_num_seqs
+        # flight-record arithmetic of a hybrid cache: the window a window
+        # layer reads of a context, and the recurrent bytes one slot-step
+        # reads and writes (None / 0 on every other model)
+        layout = self.kv_manager.layout
+        self._window = model_cfg.sliding_window if self.is_hybrid else None
+        self._step_state_bytes = (
+            2 * layout.state_layers * layout.state_bytes
+            if self.is_hybrid else 0)
         # jitted cross-quant repack converters, keyed by the payload's
         # (lane width, dtype); shapes re-specialize inside each jit cache
         self._repack_jits: dict = {}
@@ -631,8 +664,16 @@ class EngineCore:
         def prefill(params, kv, tokens, block_table, start_pos, true_len,
                     key, temperature, top_k, top_p):
             params = unpack_params(params)
+            extra = {}
+            if self.is_hybrid:
+                # the slot rides behind the table's M entries
+                # (_prefill_table); a hand-driven [M] table is slot 0
+                extra = {"slot": (block_table[self.M]
+                                  if block_table.shape[0] > self.M else 0)}
+                block_table = block_table[:self.M]
             logits, kv = self.model_mod.prefill_forward(
-                params, kv, tokens, block_table, start_pos, true_len, statics)
+                params, kv, tokens, block_table, start_pos, true_len,
+                statics, **extra)
             tok, logprob = sample_tokens(
                 logits[None, :], key[None], temperature[None], top_k[None],
                 top_p[None])
@@ -1090,14 +1131,16 @@ class EngineCore:
 
     # ------------------------------------------------------------- frontend
     async def submit(self, req: EngineRequest) -> None:
-        if self.model_cfg.index_topk > 0 and (
+        if (self.model_cfg.index_topk > 0 or self.is_hybrid) and (
                 req.precomputed is not None or req.handoff is not None
                 or req.handoff_device):
-            # neither disagg plane ships the index keys (docs/dsa.md);
+            # neither disagg plane ships the index keys (docs/dsa.md) or
+            # a slot's state and window rows (docs/hybrid_cache.md);
             # raised here, to the caller, not inside the engine loop
             raise NotImplementedError(
                 "disaggregated prefill/decode hand-off is not implemented "
-                "with deepseek_v32's index-key cache")
+                "with deepseek_v32's index-key cache or phi4flash's "
+                "per-slot state")
         if req.precomputed is not None:
             # validate the payload layout HERE, synchronously: the caller
             # gets the error; a raise inside the engine loop's admission
@@ -1754,10 +1797,11 @@ class EngineCore:
         construction (kv_remote_dir) may already have built an
         object-backed store — the fabric wraps that same store, so this
         is idempotent on the manager side."""
-        if self.model_cfg.index_topk > 0:
+        if self.model_cfg.index_topk > 0 or self.is_hybrid:
             raise NotImplementedError(
-                "the KV fabric ships latent rows only; it is not "
-                "implemented with deepseek_v32's index-key cache")
+                "the KV fabric ships paged rows only; it is not "
+                "implemented with deepseek_v32's index-key cache or "
+                "phi4flash's per-slot state")
         self.kv_fabric = fabric
         self.remote_store = fabric.store
         self.kv_manager.remote_store = fabric.store
@@ -2203,8 +2247,7 @@ class EngineCore:
             # in the pool's blocks (this is the TTFT win of prefix reuse)
             chunk = req.prompt[req.prefix_hit_tokens:]
             bucket = self.cfg.bucket_for(len(chunk))
-            table = np.zeros((self.M,), np.int32)
-            table[:len(req.blocks)] = req.blocks
+            table = self._prefill_table(req.blocks, slot)
             key = make_slot_keys(self.cfg.seed,
                                  jnp.asarray([req.sampling.seed]),
                                  jnp.asarray(req.key_step))[0]
@@ -2337,6 +2380,10 @@ class EngineCore:
             hit_disk=plan.disk_hit_tokens,
             hit_remote=plan.remote_hit_tokens,
             precomputed=remote_admit, grouped_rows=grouped_rows,
+            # prompt tokens a state-space scan ran over (0 on a model
+            # without such layers)
+            scan_tokens=(suffix_len if self.is_hybrid and not remote_admit
+                         else 0),
             host_ms=round(1e3 * (now - t0), 3),
             # of host_ms: plan to the prefill program's return (argument
             # build and transfers included), and the blocking fetch of
@@ -2400,6 +2447,15 @@ class EngineCore:
                 hit=hit, prompt=list(req.prompt), lane=True)
         logger.debug("lane-admitted %s into slot %d (prompt=%d, hit=%d)",
                      req.rid, slot, n_prompt, hit)
+
+    def _prefill_table(self, blocks: list, slot: int) -> np.ndarray:
+        """The block table a prefill dispatch takes: M entries, and for a
+        model with per-slot state (is_hybrid) the slot behind them."""
+        table = np.zeros((self.M + int(self.is_hybrid),), np.int32)
+        table[:len(blocks)] = blocks
+        if self.is_hybrid:
+            table[self.M] = slot
+        return table
 
     def _rec_prefill(self, req: "EngineRequest", slot: int,
                      padded: np.ndarray, table: np.ndarray, *,
@@ -3044,7 +3100,12 @@ class EngineCore:
         # with no indexer). Host arithmetic on positions: a descriptor of
         # the traffic for cost models, not a reading of the device
         topk = self.model_cfg.index_topk
-        ctx_tokens = sel_tokens = 0
+        # win_tokens: the rows a window layer reads of that context
+        # (min(context, window); the same number on a model without window
+        # layers of bounded rows). state_bytes: the recurrent state the
+        # applied slot-steps read and write (0 on every other model)
+        window = self._window
+        ctx_tokens = sel_tokens = win_tokens = steps_applied = 0
         for i, req in enumerate(pending["reqs"]):
             if req is None or self.slots[i] is not req:
                 continue
@@ -3095,9 +3156,11 @@ class EngineCore:
                     break                      # finished: drop device overrun
                 input_tok = tok
             applied.append((i, req.rid, n_applied))
+            steps_applied += n_applied
             for ctx in range(pos0 + 1, pos0 + 1 + n_applied):
                 ctx_tokens += ctx
                 sel_tokens += min(ctx, topk) if topk else ctx
+                win_tokens += min(ctx, window) if window else ctx
         if self.recorder is not None and pending.get("id") is not None:
             self.recorder.rec("harvest", id=pending["id"],
                               toks=toks_k.copy(), applied=applied)
@@ -3116,6 +3179,8 @@ class EngineCore:
             planned_tokens=K * len(applied),
             emitted=sum(n for _i, _r, n in applied),
             ctx_tokens=ctx_tokens, sel_tokens=sel_tokens,
+            win_tokens=win_tokens,
+            state_bytes=steps_applied * self._step_state_bytes,
             **({"drain": pending["drain"]} if "drain" in pending else {}))
 
     # --------------------------------------------------------------- ragged

@@ -265,12 +265,23 @@ def _mm_grouped(x: jax.Array, w: QuantizedArray) -> jax.Array:
     return jnp.einsum("...gf,gf->...f", part, w.scale.astype(x.dtype))
 
 
-def mm(x: jax.Array, w) -> jax.Array:
+def mm(x: jax.Array, w, out_dtype=None) -> jax.Array:
     """x @ w for a plain array or a QuantizedArray (dequant fused into the
-    matmul: XLA reads int8/int4 and converts in-register)."""
-    if isinstance(w, QuantizedArray):
-        if w.group:
-            return _mm_grouped(x, w)
+    matmul: XLA reads int8/int4 and converts in-register). ``out_dtype``
+    (models/sambay.py asks for float32): the accumulator's precision is
+    kept in the result and the scale is applied in it, where the default
+    rounds both to x's dtype."""
+    quantized = isinstance(w, QuantizedArray)
+    if quantized and w.group:
+        y = _mm_grouped(x, w)
+        return y if out_dtype is None else y.astype(out_dtype)
+    if out_dtype is not None:
+        y = jnp.dot(x, (w.q if quantized else w).astype(x.dtype),
+                    preferred_element_type=out_dtype)
+        if quantized:
+            y = y * w.scale.astype(out_dtype).reshape(w.scale.shape[-1])
+        return y
+    if quantized:
         y = x @ w.q.astype(x.dtype)
         return y * w.scale.astype(x.dtype).reshape(w.scale.shape[-1])
     return x @ w
@@ -308,7 +319,16 @@ _LAYER_MATMULS = ("wq", "wk", "wv", "wo", "gate", "up", "down",
                   # deepseek_v32 lightning indexer: query up-projection,
                   # key projection and head-weight projection, all
                   # through mm() (its LayerNorm stays full precision)
-                  "idx_wq_b", "idx_wk", "idx_w")
+                  "idx_wq_b", "idx_wk", "idx_w",
+                  # phi4flash (models/sambay.py; names are
+                  # layers.<kind>.<leaf>): the MLP, the state-space
+                  # layer's four projections, the attention layers' qkv /
+                  # q / output projections and the gated memory unit's
+                  # two. A_log, D, dt_b, the conv taps, the lambda vectors,
+                  # norms and biases stay as they are
+                  "mlp_gateup", "mlp_down", "ssm_in", "ssm_x", "ssm_dt",
+                  "ssm_out", "attn_qkv", "attn_out", "cross_q", "gmu_in",
+                  "gmu_out")
 # MoE expert tensors [L, E, D, F] → per (L, E, out-channel) scales. For
 # mixtral-class models the experts ARE the weights, so leaving them bf16
 # would forfeit the whole int8 HBM-read win; the router stays full
@@ -349,7 +369,8 @@ def _quantize_named(name: str, w: jax.Array, include_embed: bool,
                     tied: bool, bits: int = 8) -> Dict[str, object]:
     """The per-tensor dispatch shared by quantize_params (whole-tree,
     eager) and init_params_quantized (streaming, one jit per tensor)."""
-    suffix = name.split(".", 1)[1] if name.startswith("layers.") else name
+    # the leaf: "wq" of layers.wq, "ssm_in" of layers.mamba.ssm_in
+    suffix = name.rsplit(".", 1)[1] if name.startswith("layers.") else name
     if name.startswith("layers.") and suffix in _LAYER_MATMULS:
         if bits == 4:
             # stacked [L, D, F]: int4, scale [L, D/128, F]
@@ -405,6 +426,8 @@ def init_params_quantized(cfg, key: jax.Array, dtype=jnp.bfloat16,
     if cfg.kv_lora_rank > 0:
         # MLA geometry: same init_one_param, different shape map
         from .models.mla import param_shapes
+    elif cfg.is_sambay:
+        from .models.sambay import init_one_param, param_shapes
 
     shapes = param_shapes(cfg)
     tied = "lm_head" not in shapes

@@ -160,6 +160,10 @@ def load_params_auto(model_dir: str, cfg: Optional[ModelConfig] = None,
     per-rank shard loaders, lib/llm vllm subprocess.rs:37-41). Without a
     mesh, the replicated reader stages the whole model in host numpy."""
     cfg = cfg or ModelConfig.from_model_dir(model_dir)
+    if cfg.is_sambay:
+        # phi4flash: its own names and stacks; no mesh serves it yet
+        # (sambay.hybrid_refusals raises at engine build)
+        return load_sambay_params(model_dir, cfg, dtype=dtype)
     if mesh is not None:
         return load_params_sharded(model_dir, mesh, cfg, dtype=dtype)
     return load_llama_params(model_dir, cfg, dtype=dtype)
@@ -634,6 +638,130 @@ def load_params_sharded(model_dir: str, mesh,
 # Backwards-compatible name (pre-round-5 the streaming loader was
 # llama-family-only; it now covers MoE and MLA too).
 load_llama_params_sharded = load_params_sharded
+
+
+# phi4flash checkpoint names (the model repository's modeling_phi4flash.py,
+# from memory: no network here), per layer under ``model.layers.{i}.``: the
+# mixer is ``attn`` whatever its kind. -> (leaf of engine/models/sambay.py's
+# ``layers.<kind>.<leaf>`` stacks, how the torch tensor becomes ours)
+_T = "transpose"          # torch Linear [out, in] -> [in, out]
+_SAMBAY_BLOCK = {
+    "input_layernorm.weight": ("ln1_w", None),
+    "input_layernorm.bias": ("ln1_b", None),
+    "post_attention_layernorm.weight": ("ln2_w", None),
+    "post_attention_layernorm.bias": ("ln2_b", None),
+    "mlp.fc1.weight": ("mlp_gateup", _T),
+    "mlp.fc2.weight": ("mlp_down", _T),
+}
+_SAMBAY_SSM = {
+    "attn.in_proj.weight": ("ssm_in", _T),
+    "attn.conv1d.weight": ("conv_w", "conv"),     # [Di, 1, K] -> [K, Di]
+    "attn.conv1d.bias": ("conv_b", None),
+    "attn.x_proj.weight": ("ssm_x", _T),
+    "attn.dt_proj.weight": ("ssm_dt", _T),
+    "attn.dt_proj.bias": ("dt_b", None),
+    "attn.A_log": ("A_log", _T),                  # [Di, N] -> [N, Di]
+    "attn.D": ("D", None),
+    "attn.out_proj.weight": ("ssm_out", _T),
+}
+_SAMBAY_DIFF = {
+    "attn.out_proj.weight": ("attn_out", _T),
+    "attn.out_proj.bias": ("attn_out_b", None),
+    "attn.inner_cross_attn.subln.weight": ("subnorm", None),
+}
+_SAMBAY_LAMBDAS = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
+_SAMBAY_MIXER = {
+    "mamba": _SAMBAY_SSM, "export": _SAMBAY_SSM,
+    "window": {"attn.Wqkv.weight": ("attn_qkv", _T),
+               "attn.Wqkv.bias": ("attn_qkv_b", None), **_SAMBAY_DIFF},
+    "gmu": {"attn.in_proj.weight": ("gmu_in", _T),
+            "attn.out_proj.weight": ("gmu_out", _T)},
+    "cross": {"attn.Wqkv.weight": ("cross_q", _T),
+              "attn.Wqkv.bias": ("cross_q_b", None), **_SAMBAY_DIFF},
+}
+_SAMBAY_MIXER["full"] = _SAMBAY_MIXER["window"]
+_SAMBAY_TOP = {"model.embed_tokens.weight": "embed",
+               "model.final_layernorm.weight": "final_norm",
+               "model.final_layernorm.bias": "final_norm_b"}
+_SAMBAY_FLOAT32 = ("A_log", "D", "dt_b", "lam")
+
+
+def _sambay_tensor_names(cfg: ModelConfig) -> Dict[str, tuple]:
+    """checkpoint tensor name -> (engine parameter, index in its stack,
+    transform, row of ``lam``)."""
+    from .models.sambay import layer_kinds
+    names: Dict[str, tuple] = {k: (v, None, None, None)
+                               for k, v in _SAMBAY_TOP.items()}
+    seen: Dict[str, int] = {}
+    for l, kind in enumerate(layer_kinds(cfg)):
+        i = seen.get(kind, 0)
+        seen[kind] = i + 1
+        for sub, (leaf, how) in {**_SAMBAY_BLOCK,
+                                 **_SAMBAY_MIXER[kind]}.items():
+            names[f"model.layers.{l}.{sub}"] = (
+                f"layers.{kind}.{leaf}", i, how, None)
+        if kind in ("window", "full", "cross"):
+            for row, lam in enumerate(_SAMBAY_LAMBDAS):
+                names[f"model.layers.{l}.attn.inner_cross_attn.{lam}"] = (
+                    f"layers.{kind}.lam", i, None, row)
+    return names
+
+
+def load_sambay_params(model_dir: str, cfg: Optional[ModelConfig] = None,
+                       dtype=jnp.bfloat16) -> Dict[str, jax.Array]:
+    """Load a phi4flash checkpoint into ``models/sambay.py``'s stacks. A
+    tensor this map does not know, or a parameter the checkpoint lacks,
+    fails loudly: the names are from memory."""
+    from .models.sambay import param_shapes
+    cfg = cfg or ModelConfig.from_model_dir(model_dir)
+    shapes = param_shapes(cfg)
+    names = _sambay_tensor_names(cfg)
+    out = {name: np.zeros(shape, _np_dtype(
+        jnp.float32 if name.rsplit(".", 1)[-1] in _SAMBAY_FLOAT32
+        else dtype)) for name, shape in shapes.items()}
+    missing = {(name, i, row) for name, i, _, row in names.values()}
+    for tname, tensor in _iter_safetensors(model_dir):
+        if tname == "lm_head.weight" and cfg.tie_word_embeddings:
+            continue
+        if tname not in names:
+            raise ValueError(f"phi4flash checkpoint tensor {tname!r} has no "
+                             f"place in engine/models/sambay.py's parameters")
+        name, i, how, row = names[tname]
+        t = np.asarray(tensor, np.float32)
+        if how == _T:
+            t = t.T
+        elif how == "conv":
+            t = t[:, 0, :].T
+        target = out[name] if i is None else out[name][i]
+        if row is not None:
+            target = target[row]
+        if target.shape != t.shape:
+            raise ValueError(f"{tname}: shape {t.shape}, the engine holds "
+                             f"{target.shape} for {name}")
+        target[...] = t
+        missing.discard((name, i, row))
+    if missing:
+        raise ValueError(f"phi4flash checkpoint lacks {len(missing)} "
+                         f"tensor(s), e.g. {sorted(map(str, missing))[:3]}")
+    return {name: jnp.asarray(_note_handoff(a)) for name, a in out.items()}
+
+
+def save_sambay_hf_style(params: Dict[str, jax.Array], cfg: ModelConfig,
+                         out_dir: str) -> None:
+    """The inverse of ``load_sambay_params`` (tests)."""
+    from safetensors.numpy import save_file
+    os.makedirs(out_dir, exist_ok=True)
+    out = {}
+    for tname, (name, i, how, row) in _sambay_tensor_names(cfg).items():
+        t = np.asarray(params[name], np.float32)
+        t = t if i is None else t[i]
+        t = t if row is None else t[row]
+        if how == _T:
+            t = t.T
+        elif how == "conv":
+            t = t.T[:, None, :]
+        out[tname] = np.ascontiguousarray(t)
+    save_file(out, os.path.join(out_dir, "model.safetensors"))
 
 
 def _np_dtype(dtype):

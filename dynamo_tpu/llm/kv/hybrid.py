@@ -1,0 +1,67 @@
+"""The kinds of per-sequence memory of a model whose layers are not all
+alike (docs/hybrid_cache.md), as the block manager sees them.
+
+A uniform model keeps one paged row per token per layer; ``KvBlockManager``
+pages them and nothing else exists. A hybrid model (``models/sambay.py``)
+keeps three kinds, and only the first is made of blocks that the pool hands
+out and takes back:
+
+* **paged**: rows of the layers that attend over the whole context, under
+  the block table, allocated a block at a time as the context grows and
+  released at finish, cancel and preemption, as ever;
+* **window**: rows of the layers that attend over the last ``window``
+  positions: a ring of ``ring_blocks`` blocks per slot and layer, inside
+  arrays sized by ``max_num_seqs``. A slot owns its rings for as long as it
+  holds a request; nothing is allocated or released, and a context of any
+  length holds ``ring_blocks`` blocks a layer;
+* **state**: a fixed number of bytes per slot and state-space layer, owned
+  the same way. It is not made of tokens: a block's hash says nothing of
+  the state at its boundary, so a prefix hit could not be resumed. A
+  manager with a layout that has state therefore matches no prefix and
+  registers no block (``KvBlockManager.enable_reuse`` is forced off: the
+  router is told of no block it could not use).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridCacheLayout:
+    block_size: int
+    row_bytes: int             # one token's K and V rows of one layer
+    paged_layers: int          # layers whose rows are paged...
+    readers_of_paged: int      # ...and the layers that read those rows
+    window_layers: int
+    window: int
+    state_layers: int
+    state_bytes: int           # one slot's state of one layer
+
+    @property
+    def ring_blocks(self) -> int:
+        """Blocks of one window layer's ring: the window and one more, so
+        that the block being written holds no row the window still needs."""
+        return -(-self.window // self.block_size) + 1
+
+    @property
+    def has_state(self) -> bool:
+        return self.state_layers > 0
+
+    def blocks_by_kind(self, context_tokens: int) -> dict:
+        """Blocks (state: slots) that one sequence of ``context_tokens``
+        holds, per layer of each kind."""
+        bs = self.block_size
+        return {"paged": -(-context_tokens // bs),
+                "window": min(-(-context_tokens // bs), self.ring_blocks),
+                "state": 1}
+
+    def bytes_by_kind(self, num_blocks: int, max_num_seqs: int) -> dict:
+        """Device bytes of each kind for a pool of ``num_blocks`` and
+        ``max_num_seqs`` slots."""
+        block = self.block_size * self.row_bytes
+        return {
+            "paged": self.paged_layers * num_blocks * block,
+            "window": (self.window_layers * max_num_seqs
+                       * self.ring_blocks * block),
+            "state": self.state_layers * max_num_seqs * self.state_bytes}

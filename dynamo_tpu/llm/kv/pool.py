@@ -568,7 +568,16 @@ class KvBlockManager:
     def __init__(self, num_blocks: int, block_size: int,
                  on_stored=None, on_removed=None, enable_reuse: bool = True,
                  host_pool=None, disk_store=None, remote_store=None,
-                 prefer_native: bool = True):
+                 prefer_native: bool = True, layout=None):
+        # a hybrid model's kinds of memory (llm/kv/hybrid.py): the pool
+        # below pages the first kind; the window rings and the recurrent
+        # state are per slot and need no allocator. With state, no prefix
+        # can be resumed from a block boundary: nothing is matched and
+        # nothing is registered
+        self.layout = layout
+        self.stateful = layout is not None and layout.has_state
+        if self.stateful:
+            enable_reuse = False
         self.block_size = block_size
         self.pool = make_kv_block_pool(num_blocks, on_stored=on_stored,
                                        on_removed=on_removed,
@@ -695,6 +704,8 @@ class KvBlockManager:
         block-hash order). Returns the new count of registered blocks.
         ``tenant`` attributes the blocks for per-tenant quota accounting
         (llm/tenancy.py; no-op without an attached ledger)."""
+        if self.stateful:
+            return already_registered
         n_full = seq.num_full_blocks
         for i in range(already_registered, n_full):
             if i >= len(plan_blocks):

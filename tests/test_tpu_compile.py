@@ -463,3 +463,117 @@ def test_routed_experts_run_grouped_in_prefill(one_chip, monkeypatch, case):
     assert not re.search(rf"s8\[{E},({D},{2 * F}|{F},{D})\]", text)
     assert not re.search(
         rf"(bf16|f32|f16|s32)\[(\d+,)?{E},({D},{2 * F}|{F},{D})\]", text)
+
+
+def test_hybrid_cache_programs_carry_stable_names(one_chip, monkeypatch):
+    """phi4flash on models/sambay.py: the engine's prefill and decode
+    programs compile for the chip at narrow widths with the three kinds of
+    cache, and name the new mixers — ``mamba`` (``causal_conv``, and the
+    Pallas calls ``ssm_scan`` in prefill, ``ssm_step`` in decode), ``gmu``,
+    ``window_attention``, ``full_attention``, ``cross_attention`` — beside
+    the scopes every model has. The window layers' decode is the paged
+    kernel over the slot's ring (33 blocks at the published sizes, 3 here),
+    the full layer's prefill the flash kernel, and the window layers'
+    prefill never holds a [T, S] float32 score tensor: a block of 256
+    queries against the 256 + window keys it can reach."""
+    import re
+    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+    from dynamo_tpu.engine.core import EngineCore
+    from dynamo_tpu.engine.models import llama, sambay
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    monkeypatch.setattr(llama, "_on_tpu", lambda: True)
+    monkeypatch.setattr(sambay, "_on_tpu", lambda: True)
+    cfg = ModelConfig.from_hf_config({
+        "model_type": "phi4flash", "vocab_size": 2048, "hidden_size": 256,
+        "intermediate_size": 512, "num_hidden_layers": 6,
+        "num_attention_heads": 4, "num_key_value_heads": 2,
+        "sliding_window": 32, "mb_per_layer": 2, "layer_norm_eps": 1e-5,
+        "tie_word_embeddings": True})
+    B, M, T, W = 8, 64, 1024, 32
+    core = EngineCore(cfg, EngineConfig(
+        max_model_len=1024, kv_block_size=16, num_kv_blocks=96,
+        max_num_seqs=B, prefill_buckets=[T]), attn_impl="pallas")
+    R = (W // 16 + 1) * 16
+    assert {k: (v.shape, v.dtype) for k, v in core.kv.items()} == {
+        "k": ((1, 96 * 16, 128), jnp.bfloat16),
+        "v": ((1, 96 * 16, 128), jnp.bfloat16),
+        "win_k": ((1, B, R, 128), jnp.bfloat16),
+        "win_v": ((1, B, R, 128), jnp.bfloat16),
+        "ssm": ((2, 8, 16, 512), jnp.float32),
+        "conv": ((2, 8, 3, 512), jnp.bfloat16)}
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, kv = jax.tree.map(lambda x: s(x.shape, x.dtype),
+                              (core.params, core.kv))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    i32, f32 = jnp.int32, jnp.float32
+    decode = core._decode_k_jit.lower(
+        params, kv, s((B,), i32), s((B,), i32), s((B, M), i32),
+        s((B,), i32), s((B,), i32), s((B,), f32), s((B,), i32),
+        s((B,), f32), s((1, B), i32), s((1, B), jnp.bool_),
+        s(key.shape, key.dtype)).compile().as_text()
+    prefill = core._prefill_jit.lower(
+        params, kv, s((T,), i32), s((M + 1,), i32), s((), i32), s((), i32),
+        s(key.shape, key.dtype), s((), f32), s((), i32),
+        s((), f32)).compile().as_text()
+    common = ["/lm_head/", "/sampling/", "/mamba/causal_conv/", "/gmu/",
+              "/window_attention/", "/full_attention/", "/cross_attention/",
+              "/swiglu/"]
+    for text, fn, top, own in (
+            (decode, "decode_k", "decode",
+             ["/mamba/ssm_step/", "/window_attention/paged_attention/",
+              "/full_attention/paged_attention/",
+              "/cross_attention/paged_attention/"]),
+            (prefill, "prefill", "prefill",
+             ["/mamba/ssm_scan/", "/full_attention/flash_prefill/",
+              "/cross_attention/paged_attention/"])):
+        for scope in [f"jit({fn})/{top}/"] + common + own:
+            assert scope in text, (top, scope)
+    for text, kernel in ((decode, "ssm_step"), (prefill, "ssm_scan")):
+        assert [line for line in text.splitlines()
+                if "tpu_custom_call" in line and f"%{kernel}" in line
+                and f"/mamba/{kernel}/" in line], kernel
+    # the window layers' prefill: no float32 array of T x (ring + T)
+    # scores, whatever the heads in front of it
+    window = [line for line in prefill.splitlines()
+              if "/window_attention/" in line]
+    assert window
+    S = R + T                      # the ring's rows before the chunk's
+    last_dims = set()
+    for line in window:
+        for dims in re.findall(r"f32\[([\d,]+)\]", line):
+            dims = [int(d) for d in dims.split(",")]
+            # scores are [.., queries, keys]: never the chunk against all
+            # of its keys, never the whole table
+            assert dims[-1] != S and not (T in dims and S in dims), line
+            if len(dims) >= 3:
+                last_dims.add(dims[-1])
+    assert 256 + W in last_dims, last_dims      # a block's reach
+
+
+@pytest.mark.parametrize("kernel", ["ssm_scan", "ssm_step"])
+def test_state_space_kernels_build_at_the_published_sizes(one_chip, kernel):
+    """Phi-4-mini-flash-reasoning's widths (d_inner 5120, 16 states): the
+    prompt scan at the 4,096-token bucket, and the decode update of 64
+    slots in place inside the nine layers' state array."""
+    from dynamo_tpu.engine import ssm
+    f32, Di, N = jnp.float32, 5120, 16
+    if kernel == "ssm_scan":
+        T = 4096
+        _compile(ssm.ssm_scan, one_chip, ((T, Di), f32), ((T, Di), f32),
+                 ((T, N), f32), ((T, N), f32), ((N, Di), f32), ((N, Di), f32))
+        return
+    B, L = 64, 9
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in (
+                ((B, Di), f32), ((B, Di), f32), ((B,), f32), ((B, N), f32),
+                ((B, N), f32), ((N, Di), f32), ((L * B, N, Di), f32),
+                ((), jnp.int32))]
+    compiled = jax.jit(ssm.ssm_step, donate_argnums=(6,)).lower(
+        *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the state is rewritten where it lies: no second copy of 189 MB
+    assert compiled.memory_analysis().alias_size_in_bytes >= L * B * N * Di * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
