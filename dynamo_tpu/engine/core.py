@@ -33,7 +33,9 @@ from ..llm.kv.pool import KvBlockManager
 from .block_copy import scatter_blocks_from_host
 from ..llm.kv_router.protocols import ForwardPassMetrics
 from ..llm.protocols.common import FinishReason
+from .attention import wave_contig_table
 from .config import EngineConfig, ModelConfig
+from .index_scores import key_wave_blocks
 from .models import llama
 from .sampling import SlotSampling, make_slot_keys, sample_tokens
 
@@ -444,6 +446,13 @@ class EngineCore:
         self._step_state_bytes = (
             2 * layout.state_layers * layout.state_bytes
             if self.is_hybrid else 0)
+        # blocks per wave of the index-key read (engine/index_scores.py),
+        # for the decode records' key_waves / key_run_waves; 0 = no indexer
+        self._key_wave_blocks = (
+            key_wave_blocks(self.M, engine_cfg.kv_block_size,
+                            model_cfg.index_head_dim,
+                            self.kv["idx"].dtype.itemsize)
+            if model_cfg.index_topk > 0 else 0)
         # jitted cross-quant repack converters, keyed by the payload's
         # (lane width, dtype); shapes re-specialize inside each jit cache
         self._repack_jits: dict = {}
@@ -3077,7 +3086,34 @@ class EngineCore:
             self.params, self.kv, *args)
         self.clock.enter("build")
         return {"toks": toks_k, "logprobs": logprobs_k, "K": K, "id": did,
-                "reqs": riders, "mask": mask}
+                "reqs": riders, "mask": mask,
+                **self._key_wave_counts(tables, riders, K)}
+
+    def _key_wave_counts(self, tables: np.ndarray, riders: list,
+                         K: int) -> dict:
+        """{key_waves, key_run_waves} of one dispatch of a model with an
+        indexer ({} otherwise): the waves the ``index_scores`` kernel walks
+        over the riding slots' index keys in one layer, summed over the K
+        steps, and those of them whose blocks lie adjacent in the pool
+        (one copy, not one per block). The kernel's own predicate and
+        depth on the dispatch's numpy tables, with the last layer's bound
+        at the pool's end; whole-array arithmetic, no walk over slots."""
+        chunk = self._key_wave_blocks
+        if not chunk:
+            return {}
+        bsz = self.cfg.kv_block_size
+        riding = np.array([s is not None for s in riders])
+        waves = run_waves = 0
+        for k in range(1, K + 1):
+            lens = np.where(riding, self._positions + k, 0)
+            walked = -(-lens // (chunk * bsz))                       # [B]
+            contig = wave_contig_table(
+                tables, lens, block_size=bsz, chunk=chunk,
+                pool_blocks=self.kv["idx"].shape[1] // bsz, xp=np)
+            waves += int(walked.sum())
+            run_waves += int(contig[np.arange(contig.shape[1])[None, :]
+                                    < walked[:, None]].sum())
+        return {"key_waves": waves, "key_run_waves": run_waves}
 
     def _harvest(self, pending: dict) -> None:
         """Apply one dispatch's results: emissions, seq bookkeeping,
@@ -3181,7 +3217,8 @@ class EngineCore:
             ctx_tokens=ctx_tokens, sel_tokens=sel_tokens,
             win_tokens=win_tokens,
             state_bytes=steps_applied * self._step_state_bytes,
-            **({"drain": pending["drain"]} if "drain" in pending else {}))
+            **{k: pending[k] for k in ("key_waves", "key_run_waves", "drain")
+               if k in pending})
 
     # --------------------------------------------------------------- ragged
     def _ragged_step(self) -> None:

@@ -97,6 +97,7 @@ from ..attention import (dequant_kv_rows_sections,
                          quantize_kv_rows_sections,
                          ragged_paged_attention_pallas)
 from ..config import ModelConfig
+from ..index_scores import index_scores_pallas, index_scores_supported
 from ..quant import mm
 from ..select_compact import NOT_TAKEN, compact_top_k
 from .llama import (ModelStatics, _embed, _layer_stack, _logits,
@@ -543,17 +544,28 @@ def _sparse_rows(q_lat, q_pe, index, kv_flat, tables_l, seq_lens,
                  cfg: ModelConfig, bsz: int, scale: float) -> jax.Array:
     """Select, then attend, for N query rows that each have a block table
     of their own (decode; tables_l [N, M] holds layer-offset block ids,
-    seq_lens [N] the live positions). → probs·c [N, H, rank]."""
+    seq_lens [N] the live positions). → probs·c [N, H, rank].
+
+    On the TPU, at a geometry the kernel builds for, the index scores come
+    from ONE Pallas call that streams each row's keys from the pool as it
+    lies (engine/index_scores.py: a run of adjacent blocks is one copy);
+    elsewhere (the CPU, tiny widths) the keys are gathered by block and
+    scored by XLA, as a prefill chunk's one shared table always is."""
+    from ..attention import _on_tpu
     qI, w, idx_flat = index
     N, M = tables_l.shape
     S, dI = M * bsz, idx_flat.shape[-1]
     with jax.named_scope("dsa_select"):
-        keys = _keys_by_block(idx_flat, tables_l, bsz).reshape(N, S, dI)
+        if _on_tpu() and index_scores_supported(qI.shape[1], dI, bsz):
+            scores = index_scores_pallas(qI, w, idx_flat, tables_l,
+                                         seq_lens, block_size=bsz)
+        else:
+            keys = _keys_by_block(idx_flat, tables_l, bsz).reshape(N, S, dI)
+            scores = _index_scores(qI, w, keys)
         live = jnp.arange(S)[None, :] < seq_lens[:, None]
-        _, valid, slot_ids = _select(_index_scores(qI, w, keys), live,
-                                     cfg.index_topk, TableSlots(
-                                         tables_l, bsz,
-                                         kv_flat.shape[0] // bsz))
+        _, valid, slot_ids = _select(scores, live, cfg.index_topk,
+                                     TableSlots(tables_l, bsz,
+                                                kv_flat.shape[0] // bsz))
     with jax.named_scope("sparse_attention"):
         return _attend_selected(q_lat, q_pe, kv_flat, slot_ids, valid,
                                 scale, cfg.kv_lora_rank,

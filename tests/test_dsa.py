@@ -401,6 +401,217 @@ def test_compact_top_k_on_raw_order_bits(N, S, k, shared):
         assert (got1[n, c:] == NOT_TAKEN).all() and not got2[n, c:].any()
 
 
+# ---- index scores straight from the pool (engine/index_scores.py, PR 36)
+
+_KJ, _KD, _KM, _KPOOL, _KCHUNK = 8, 128, 12, 64, 4   # heads, lanes, table
+_KLAYER = 1                        # the tables point into the second layer
+
+
+def _key_tables(kind: str, rng):
+    """→ (tables [B, M] of blocks inside one layer, seq_lens [B]) at block
+    size BS, depth _KCHUNK (a wave is 64 positions, a table 3 waves)."""
+    run = lambda lo, n=_KM: np.arange(lo, lo + n)            # noqa: E731
+    scattered = lambda n=_KM: rng.permutation(_KPOOL)[:n]    # noqa: E731
+    wave, cap = _KCHUNK * BS, _KM * BS
+    if kind == "contiguous":
+        return np.stack([run(3), run(20), run(40)]), [cap, 150, 70]
+    if kind == "fragmented":
+        return np.stack([scattered() for _ in range(3)]), [cap, 150, 70]
+    if kind == "mixed":          # a document's run, then blocks of its own
+        return np.stack([np.r_[run(8, 8), scattered(4)],
+                         np.r_[scattered(5), run(30, 7)],
+                         np.r_[run(8, 6), run(50, 6)]]), [cap, 177, 190]
+    if kind == "len-1":
+        return np.stack([run(5), scattered()]), [1, 1]
+    if kind == "block-edge":
+        return np.stack([run(5), scattered(), run(30), scattered()]), [
+            BS, BS + 1, 5 * BS, 5 * BS - 1]
+    if kind == "wave-edge":
+        return np.stack([run(5), scattered(), run(30), scattered()]), [
+            wave, wave + 1, 2 * wave, 2 * wave - 1]
+    if kind == "table-end":
+        return np.stack([run(5), scattered()]), [cap, cap]
+    if kind == "run-ends-at-the-pools-last-block":
+        # the whole table is the pool's last run; and a run whose live
+        # blocks end at the last block while its wave would pass it: the
+        # predicate's in_bounds arm sends that wave down the per-block path
+        short = np.r_[run(_KPOOL - 2, 2), np.zeros(_KM - 2, np.int64)]
+        return np.stack([run(_KPOOL - _KM), short]), [cap, 2 * BS]
+    if kind == "two-sequences-share-a-document":
+        doc = run(10, 8)
+        return np.stack([np.r_[doc, run(40, 4)], np.r_[doc, scattered(4)],
+                         np.r_[doc, run(50, 4)]]), [cap, cap, 129]
+    if kind == "empty-rows-and-a-ragged-batch":   # 11 rows: two programs
+        lens = rng.integers(0, cap + 1, 11)
+        lens[[0, 7, 10]] = 0
+        return np.stack([run(3) if i % 2 else scattered()
+                         for i in range(11)]), lens
+    raise KeyError(kind)
+
+
+_KEY_CASES = ["contiguous", "fragmented", "mixed", "len-1", "block-edge",
+              "wave-edge", "table-end", "run-ends-at-the-pools-last-block",
+              "two-sequences-share-a-document",
+              "empty-rows-and-a-ragged-batch"]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", _KEY_CASES)
+def test_index_scores_kernel_equals_the_gathered_form(kind, dtype):
+    """``index_scores_pallas`` (interpreted) against ``_keys_by_block`` +
+    ``_index_scores`` on the same pool: the scores of every live position,
+    0 at every other whatever the pool holds there, and in float32 the
+    same selected SETS through ``_select``, exactly. The same at other
+    depths (one block a wave, the whole table in one), whichever of the
+    waves are one copy and whichever per block."""
+    from dynamo_tpu.engine.attention import dma_copy_counts
+    from dynamo_tpu.engine.index_scores import index_scores_pallas
+    rng = np.random.default_rng(sum(map(ord, kind)))
+    blocks, lens = _key_tables(kind, rng)
+    lens = np.asarray(lens)
+    B = len(lens)
+    S = _KM * BS
+    layers = _KLAYER + 1
+    pool = rng.standard_normal((layers * _KPOOL * BS, _KD)).astype(np.float32)
+    tables = jnp.asarray(blocks + _KLAYER * _KPOOL, jnp.int32)
+    live = np.arange(S)[None, :] < lens[:, None]
+    # rows no live position maps to hold NaN: a wave that is one copy
+    # reads its tail from the neighbouring blocks, and nothing of them may
+    # reach a score
+    owned = np.zeros(layers * _KPOOL * BS, bool)
+    rows_of = np.asarray(mla.TableSlots(tables, BS, layers * _KPOOL).rows())
+    owned[rows_of[live]] = True
+    pool[~owned] = np.nan
+    idx = jnp.asarray(pool, dtype)
+    qI = jnp.asarray(rng.standard_normal((B, _KJ, _KD)), dtype)
+    w = jnp.asarray(rng.standard_normal((B, _KJ)), jnp.float32)
+
+    with jax.default_matmul_precision("highest"):
+        keys = mla._keys_by_block(idx, tables, BS).reshape(B, S, _KD)
+        want = np.where(live, np.asarray(mla._index_scores(qI, w, keys)), 0)
+        got = {c: np.asarray(jax.jit(
+            lambda c=c: index_scores_pallas(
+                qI, w, idx, tables, jnp.asarray(lens), block_size=BS,
+                chunk_blocks=c, interpret=True))())
+               for c in (_KCHUNK, 12 if dtype == "float32" else 1)}
+    for chunk, scores in got.items():
+        assert scores.shape == (B, S) and scores.dtype == np.float32
+        assert not scores[~live].any(), (kind, chunk)
+        np.testing.assert_allclose(scores, want, rtol=2e-5, atol=2e-5)
+    # the case walks the path its name says (the kernel's predicate)
+    counts = dma_copy_counts(np.asarray(tables), lens, block_size=BS,
+                             pool_blocks=layers * _KPOOL,
+                             chunk_blocks=_KCHUNK, dual_stream=False)
+    if kind == "contiguous":
+        assert counts["copies"] == counts["waves"] > 0
+    if kind == "fragmented":     # but a wave of one live block is a run
+        assert counts["copies"] > 3 * counts["waves"]
+    if kind in ("mixed", "two-sequences-share-a-document",
+                "run-ends-at-the-pools-last-block"):
+        assert 0 < counts["coalesced_waves"] < counts["waves"]
+    if dtype == "float32":
+        k = 20
+        slots = mla.TableSlots(tables, BS, layers * _KPOOL)
+        select = jax.jit(mla._select, static_argnums=2)
+        pos, valid, _ = map(np.asarray, select(got[_KCHUNK], live, k, slots))
+        w_pos, w_valid, _ = map(np.asarray, select(want, live, k, slots))
+        for n in range(B):
+            assert (set(pos[n][valid[n]].tolist())
+                    == set(w_pos[n][w_valid[n]].tolist())), (kind, n)
+            assert valid[n].sum() == min(k, lens[n])
+
+
+@pytest.mark.parametrize("M, bsz, lanes, itemsize, want", [
+    (1088, 16, 128, 2, 64),      # DeepSeek-V3.2 as served: 17 waves of 256 KB
+    (1088, 16, 128, 4, 32),
+    (1024, 16, 128, 2, 64),
+    (16, 16, 128, 2, 16),        # a short table is one wave
+    (17, 16, 128, 2, 17),
+    (131, 16, 128, 2, 1),        # a prime table longer than a wave
+    (1088, 64, 128, 2, 16),
+], ids=lambda v: str(v))
+def test_key_wave_depth_comes_from_the_rows_width(M, bsz, lanes, itemsize,
+                                                  want):
+    from dynamo_tpu.engine.index_scores import (KEY_WAVE_BYTES,
+                                                 key_wave_blocks)
+    depth = key_wave_blocks(M, bsz, lanes, itemsize)
+    assert depth == want and M % depth == 0
+    assert depth == 1 or depth * bsz * lanes * itemsize <= KEY_WAVE_BYTES
+
+
+def test_index_scores_kernel_is_for_lane_aligned_geometries_on_the_tpu(
+        monkeypatch):
+    """Where it runs is read from the input: per-row tables on the TPU at
+    128-lane keys and a head count on the sublane tiling. The fixture's
+    16-lane keys, and every program on the CPU, keep the gathered form;
+    so does a prefill chunk (one table shared by its queries) anywhere."""
+    from dynamo_tpu.engine import attention as A
+    from dynamo_tpu.engine.index_scores import index_scores_supported
+    assert index_scores_supported(64, 128, 16)
+    assert not index_scores_supported(4, 16, 16)
+    assert not index_scores_supported(64, 128, 8)
+    assert not index_scores_supported(12, 128, 16)
+
+    def programs(hf):
+        cfg, params, kv, statics = _setup(hf)
+        B = 2
+        tables = jnp.stack([TABLE, jnp.zeros_like(TABLE)])
+        decode = jax.make_jaxpr(lambda: mla.decode_forward(
+            params, kv, jnp.zeros((B,), jnp.int32),
+            jnp.asarray([40, 0]), tables, statics))()
+        prefill = jax.make_jaxpr(lambda: mla.prefill_forward(
+            params, kv, jnp.zeros((16,), jnp.int32), TABLE,
+            jnp.asarray(0), jnp.asarray(16), statics))()
+        return str(decode), str(prefill)
+
+    wide = _hf(index_head_dim=128, index_n_heads=8)
+    for on_tpu, hf, in_decode in ((False, wide, False), (True, _hf(), False),
+                                  (True, wide, True)):
+        monkeypatch.setattr(A, "_on_tpu", lambda on_tpu=on_tpu: on_tpu)
+        decode, prefill = programs(hf)
+        assert ("index_scores" in decode) == in_decode, (on_tpu, in_decode)
+        assert "index_scores" not in prefill
+
+
+def test_key_wave_counts_are_the_kernels_walk():
+    """The loop's ``key_waves`` / ``key_run_waves`` (whole-array numpy on
+    the dispatch's tables) against ``attention.dma_copy_counts``, which
+    walks slot by slot as the kernel does."""
+    from dynamo_tpu.engine.attention import dma_copy_counts
+    from dynamo_tpu.engine.core import EngineCore
+    cfg = ModelConfig.from_hf_config(_hf())
+    core = EngineCore(cfg, _engine_cfg(max_num_seqs=6, max_model_len=256,
+                                       num_kv_blocks=48),
+                      attn_impl="xla", param_dtype=jnp.float32)
+    assert core._key_wave_blocks == core.M == 16   # one wave at these sizes
+    core._key_wave_blocks = 4
+    rng = np.random.default_rng(5)
+    M, pool = core.M, 48
+    tables = np.stack([np.arange(1, 1 + M), rng.permutation(pool)[:M],
+                       np.r_[np.arange(20, 30), rng.permutation(16)[:6]],
+                       np.arange(pool - M, pool), np.zeros(M, np.int64),
+                       np.arange(5, 5 + M)]).astype(np.int32)
+    core._positions[:] = [200, 77, 253, 252, 0, 63]
+    riders = [object(), object(), object(), object(), None, object()]
+    for K in (1, 3):
+        want_w = want_r = 0
+        for k in range(1, K + 1):
+            lens = np.where([r is not None for r in riders],
+                            core._positions + k, 0)
+            c = dma_copy_counts(tables, lens, block_size=BS,
+                                pool_blocks=pool, chunk_blocks=4,
+                                dual_stream=False)
+            want_w, want_r = want_w + c["waves"], want_r + c["coalesced_waves"]
+        got = core._key_wave_counts(tables, riders, K)
+        assert got == {"key_waves": want_w, "key_run_waves": want_r}
+        assert 0 < got["key_run_waves"] < got["key_waves"]
+    plain = EngineCore(dataclasses.replace(cfg, index_topk=0,
+                                           model_type="deepseek_v3"),
+                       _engine_cfg(), attn_impl="xla",
+                       param_dtype=jnp.float32)
+    assert plain._key_wave_counts(tables[:2], [object()] * 2, 1) == {}
+
+
 def test_context_inside_topk_equals_dense_mla():
     """ctx <= index_topk: every live row is selected, and the result is the
     dense path's (the same parameters served with no indexer)."""
@@ -596,6 +807,11 @@ async def test_engine_serves_with_prefix_reuse_and_chunks(ref):
             if r["batch_fill"])
         one = [r for r in decode if r["batch_fill"] == 1][-1]
         assert one["sel_tokens"] == cfg.index_topk * one["emitted"]
+        # the index keys' waves: one a slot at these sizes (a table of 8
+        # blocks is one wave), each a run (a prompt's blocks are one
+        # allocation)
+        assert all(r["key_waves"] == r["key_run_waves"] == r["batch_fill"]
+                   for r in decode)
         with pytest.raises(NotImplementedError, match="hand-off"):
             await warm.submit(EngineRequest(
                 rid="d", prompt=first, sampling=SlotSampling(temperature=0.0),
@@ -620,6 +836,7 @@ async def test_flight_records_count_context_on_a_model_with_no_indexer():
         decode = [r for r in core.flight.dump() if r["kind"] == "decode"]
         assert decode and all(r["sel_tokens"] == r["ctx_tokens"]
                               for r in decode)
+        assert not any("key_waves" in r for r in decode)
         # 30 prompt tokens: the first decode step reads a context of 31
         assert decode[0]["ctx_tokens"] == 31
     finally:

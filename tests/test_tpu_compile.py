@@ -302,6 +302,21 @@ def test_engine_step_programs_carry_stable_names(one_chip, monkeypatch, moe):
             assert scope in text, (top, scope)
 
 
+# deepseek_v32 at narrow widths, 128-lane index keys: 4 index heads keep the
+# gathered form of the index scores, 8 take the kernel (index_scores_supported)
+V32_NARROW = {
+    "model_type": "deepseek_v32", "vocab_size": 2048, "hidden_size": 256,
+    "intermediate_size": 512, "moe_intermediate_size": 128,
+    "num_hidden_layers": 2, "num_attention_heads": 8,
+    "q_lora_rank": 128, "kv_lora_rank": 128, "qk_nope_head_dim": 64,
+    "qk_rope_head_dim": 32, "v_head_dim": 64, "index_n_heads": 4,
+    "index_head_dim": 128, "index_topk": 64, "first_k_dense_replace": 1,
+    "n_routed_experts": 4, "n_routed_experts_published": 8,
+    "expert_share_index": 1, "n_group": 2,
+    "topk_group": 1, "num_experts_per_tok": 2, "n_shared_experts": 1,
+    "rms_norm_eps": 1e-6, "max_position_embeddings": 1024}
+
+
 def test_sparse_attention_programs_carry_stable_names(one_chip, monkeypatch):
     """deepseek_v32 on models/mla.py: the engine's prefill and decode
     programs compile for the chip at narrow widths with both caches, and
@@ -317,17 +332,7 @@ def test_sparse_attention_programs_carry_stable_names(one_chip, monkeypatch):
     from dynamo_tpu.engine.models import llama
     monkeypatch.setattr(A, "_on_tpu", lambda: True)
     monkeypatch.setattr(llama, "_on_tpu", lambda: True)
-    cfg = ModelConfig.from_hf_config({
-        "model_type": "deepseek_v32", "vocab_size": 2048, "hidden_size": 256,
-        "intermediate_size": 512, "moe_intermediate_size": 128,
-        "num_hidden_layers": 2, "num_attention_heads": 8,
-        "q_lora_rank": 128, "kv_lora_rank": 128, "qk_nope_head_dim": 64,
-        "qk_rope_head_dim": 32, "v_head_dim": 64, "index_n_heads": 4,
-        "index_head_dim": 128, "index_topk": 64, "first_k_dense_replace": 1,
-        "n_routed_experts": 4, "n_routed_experts_published": 8,
-        "expert_share_index": 1, "n_group": 2,
-        "topk_group": 1, "num_experts_per_tok": 2, "n_shared_experts": 1,
-        "rms_norm_eps": 1e-6, "max_position_embeddings": 1024})
+    cfg = ModelConfig.from_hf_config(V32_NARROW)
     B, M, T = 8, 16, 128
     core = EngineCore(cfg, EngineConfig(
         max_model_len=256, kv_block_size=16, num_kv_blocks=64,
@@ -369,6 +374,146 @@ def test_sparse_attention_programs_carry_stable_names(one_chip, monkeypatch):
             operands = re.search(r" custom-call\(([^)]*)\)", line).group(1)
             assert len(operands.split(",")) == 2, (top, line)
             assert re.search(r"= s32\[", line), (top, line)
+
+
+def test_index_scores_come_straight_from_the_pool_in_decode(one_chip,
+                                                           monkeypatch):
+    """With index heads on the sublane tiling (8 here, 64 published) the
+    decode program of a deepseek_v32 engine scores the index keys in the
+    Pallas call ``index_scores`` under ``dsa_select``, handed the pool
+    whole: no index key is gathered by block and no ``[B, S, dI]`` copy of
+    them exists. The prefill chunk keeps the gathered form: its compiled
+    text is the one it has with the kernel ruled out."""
+    import re
+    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+    from dynamo_tpu.engine.core import EngineCore
+    from dynamo_tpu.engine.models import llama, mla
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    monkeypatch.setattr(llama, "_on_tpu", lambda: True)
+    cfg = ModelConfig.from_hf_config(dict(V32_NARROW, index_n_heads=8))
+    B, M, T, bs, dI = 8, 16, 128, 16, 128
+    core = EngineCore(cfg, EngineConfig(
+        max_model_len=256, kv_block_size=bs, num_kv_blocks=64,
+        max_num_seqs=B, prefill_buckets=[T]), attn_impl="pallas")
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, kv = jax.tree.map(lambda x: s(x.shape, x.dtype),
+                              (core.params, core.kv))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    i32, f32 = jnp.int32, jnp.float32
+
+    def decode_text():
+        return core._decode_k_jit.lower(
+            params, kv, s((B,), i32), s((B,), i32), s((B, M), i32),
+            s((B,), i32), s((B,), i32), s((B,), f32), s((B,), i32),
+            s((B,), f32), s((1, B), i32), s((1, B), jnp.bool_),
+            s(key.shape, key.dtype)).compile().as_text()
+
+    def prefill_text():      # lowered: the compiled text names line numbers
+        return core._prefill_jit.lower(
+            params, kv, s((T,), i32), s((M,), i32), s((), i32), s((), i32),
+            s(key.shape, key.dtype), s((), f32), s((), i32),
+            s((), f32)).as_text()
+
+    def kernel_calls(text):      # (the text also names this test's frames)
+        return [line for line in text.splitlines()
+                if "tpu_custom_call" in line and "index_scores" in line]
+
+    decode, prefill = decode_text(), prefill_text()
+    calls = kernel_calls(decode)
+    assert calls and all("/dsa_select/" in line for line in calls)
+    # the pool of both layers goes in as it lies, [L * rows, dI]
+    rows = 2 * 64 * bs
+    assert all(f"bf16[{rows},{dI}]" in line for line in calls), calls[0]
+    gathered = re.compile(
+        rf"bf16\[{B * M},{bs},{dI}\]|bf16\[{B},{M * bs},{dI}\]"
+        rf"|bf16\[{B},{M},{bs},{dI}\]")
+    assert not [line for line in decode.splitlines()
+                if gathered.search(line.split(" = ")[-1].split("(")[0])]
+    assert "dsa_select_compact" in decode
+    assert "dsa_select_compact" in prefill and "index_scores" not in prefill
+    # the chunk's one shared table: gathered once ([M, bs, dI]), as before
+    monkeypatch.setattr(mla, "index_scores_supported", lambda *a: False)
+    core._compile_jits()
+    assert prefill_text() == prefill
+    assert not kernel_calls(decode_text())
+
+
+def test_index_scores_kernel_builds_at_the_published_sizes(one_chip):
+    """``index_scores`` at DeepSeek-V3.2's decode step as the benchmark's
+    cell serves it: 64 slots, 64 index heads of 128, tables of 1,088
+    blocks of 16 into a seven-layer pool of 12,288 blocks, at the depth
+    ``key_wave_blocks`` gives (64) and at the two it was measured against."""
+    from dynamo_tpu.engine.index_scores import (index_scores_pallas,
+                                                 key_wave_blocks)
+    B, J, dI, bs, M, rows = 64, 64, 128, 16, 1088, 7 * 12288 * 16
+    assert key_wave_blocks(M, bs, dI) == 64
+    for depth in (None, 16, 32):
+        text = jax.jit(lambda q, w, k, t, n, depth=depth: index_scores_pallas(
+            q, w, k, t, n, block_size=bs, chunk_blocks=depth)).lower(
+            *[jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+              for shape, dtype in (((B, J, dI), jnp.bfloat16),
+                                   ((B, J), jnp.float32),
+                                   ((rows, dI), jnp.bfloat16),
+                                   ((B, M), jnp.int32), ((B,), jnp.int32))]
+        ).compile().as_text()
+        assert "tpu_custom_call" in text and "index_scores" in text
+        assert f"bf16[{B},{M * bs},{dI}]" not in text
+
+
+@pytest.mark.parametrize("family", ["tiny-dense", "tiny-qwen2moe",
+                                    "tiny-deepseek-v2", "tiny-phi4flash"])
+def test_other_families_never_reach_the_index_scores_kernel(family, one_chip,
+                                                            monkeypatch):
+    """The four families without an indexer lower their decode and prefill
+    programs (kernels on, as on the chip) without a call into
+    engine/index_scores.py: what PR 36 added is not in their programs.
+    (Their lowered text on the CPU was byte-equal to the parent's when the
+    kernel went in: CHANGES.md, PR 36.)"""
+    import json
+    from dynamo_tpu.engine import index_scores
+    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+    from dynamo_tpu.engine.core import EngineCore
+    from dynamo_tpu.engine.models import llama, mla
+
+    def never(*a, **k):
+        raise AssertionError("index_scores reached")
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    monkeypatch.setattr(llama, "_on_tpu", lambda: True)
+    monkeypatch.setattr(mla, "index_scores_pallas", never)
+    monkeypatch.setattr(index_scores, "index_scores_pallas", never)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "fixtures",
+                           family + ".json")) as f:
+        hf = json.load(f)
+    extras = ("source", "reduced", "assumed", "deployment",
+              "memory_analysis", "notes", "reference")
+    cfg = ModelConfig.from_hf_config(
+        {k: v for k, v in hf.items() if k not in extras})
+    B, T = 4, 128
+    core = EngineCore(cfg, EngineConfig(
+        max_model_len=256, kv_block_size=16, num_kv_blocks=64,
+        max_num_seqs=B, prefill_buckets=[T], quantization="int8", seed=1))
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, kv = jax.tree.map(lambda x: s(x.shape, x.dtype),
+                              (core.params, core.kv))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    key = s(key.shape, key.dtype)
+    i32, f32 = jnp.int32, jnp.float32
+    decode = core._decode_k_jit.lower(
+        params, kv, s((B,), i32), s((B,), i32), s((B, core.M), i32),
+        s((B,), i32), s((B,), i32), s((B,), f32), s((B,), i32),
+        s((B,), f32), s((1, B), i32), s((1, B), jnp.bool_), key).as_text()
+    prefill = core._prefill_jit.lower(
+        params, kv, s((T,), i32), s((core.M,), i32), s((), i32), s((), i32),
+        key, s((), f32), s((), i32), s((), f32)).as_text()
+    assert "index_scores" not in decode and "index_scores" not in prefill
 
 
 @pytest.mark.parametrize("rows, values", [(64, 1), (32, 1), (64, 2)],
