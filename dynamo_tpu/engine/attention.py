@@ -1126,13 +1126,15 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
                     win_lo: jax.Array | None = None,
                     kv_heads: int | None = None,
                     v_lanes: int | None = None,
-                    coalesce: bool = True) -> jax.Array:
+                    coalesce: bool = True,
+                    chunk_blocks: int | None = None) -> jax.Array:
     """Dispatch: pallas on TPU (block-major streaming kernel, incl. sliding
     windows, soft-capping, and int8 pools w/ in-row per-token scales), XLA
     gather fallback elsewhere and for geometries the kernel can't tile
     (lane width KVH*Dh < 128; int8 pools with block_size % 32 != 0).
     ``coalesce`` gates the kernel's run-coalesced DMA path (ignored by
-    the XLA gather, which has no per-block copy structure).
+    the XLA gather, which has no per-block copy structure);
+    ``chunk_blocks`` is the kernel's wave depth (None: ATTN_CHUNK_BLOCKS).
 
     ``kv_heads``: the true KV head count — required to size the value
     lanes of a tp-GROUPED int8 pool (g scale groups per row; without it
@@ -1167,13 +1169,15 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
                                       seq_lens, block_size=block_size,
                                       scale=scale, softcap=softcap,
                                       win_lo=win_lo, v_lanes=v_lanes,
-                                      coalesce=coalesce)
+                                      coalesce=coalesce,
+                                      chunk_blocks=chunk_blocks)
     if impl == "pallas_interpret":
         return paged_attention_pallas(q, k_cache, v_cache, block_tables,
                                       seq_lens, block_size=block_size,
                                       scale=scale, softcap=softcap,
                                       win_lo=win_lo, v_lanes=v_lanes,
                                       coalesce=coalesce,
+                                      chunk_blocks=chunk_blocks,
                                       interpret=True)
     if v_lanes is not None:
         # the v-aliases-k CONTRACT holds on every impl: v IS k's first

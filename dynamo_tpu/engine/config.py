@@ -56,6 +56,12 @@ def _rope_type(raw_rs: Dict[str, Any]) -> str:
     return "longrope" if rt == "su" else rt
 
 
+# model_types whose configs may carry routed experts (from_hf_config refuses
+# any other that does, by name)
+MOE_FAMILIES = ("mixtral", "qwen2_moe", "qwen3_moe", "deepseek_v2",
+                "deepseek_v3", "deepseek_v32", "kimi_k2")
+
+
 @dataclasses.dataclass
 class ModelConfig:
     """Transformer shape config (llama / qwen / mixtral families)."""
@@ -182,8 +188,9 @@ class ModelConfig:
     @property
     def is_deepseek_v3(self) -> bool:
         """The v3 generation's attention-score and routing conventions
-        (deepseek_v32 is v3 plus the indexer)."""
-        return self.model_type in ("deepseek_v3", "deepseek_v32")
+        (deepseek_v32 is v3 plus the indexer; kimi_k2 is v3's block at its
+        own sizes)."""
+        return self.model_type in ("deepseek_v3", "deepseek_v32", "kimi_k2")
 
     @classmethod
     def from_hf_config(cls, cfg: Dict[str, Any]) -> "ModelConfig":
@@ -220,6 +227,27 @@ class ModelConfig:
             raise ValueError(
                 f"unsupported shared-expert MoE family {mt!r} "
                 f"(qwen2_moe is the implemented shared-expert family)")
+        if (mt not in MOE_FAMILIES and any(cfg.get(k) for k in (
+                "n_routed_experts", "num_local_experts", "num_experts"))):
+            # the nearest family's routing function and attention would be
+            # served under this one's name, silently
+            raise ValueError(
+                f"model_type {mt!r} is not implemented: its config carries "
+                f"routed experts, and the expert families served here are "
+                f"{', '.join(MOE_FAMILIES)}; it is not parsed as one of them")
+        if mt == "kimi_k2":
+            # v3's block (MLA with the q-LoRA pair, sigmoid noaux_tc routing
+            # with the v3 score scale) at this family's own sizes: what
+            # DeepseekV3Config would default is v3's, not this family's
+            missing = [k for k in ("n_routed_experts", "n_group",
+                                   "topk_group", "routed_scaling_factor",
+                                   "first_k_dense_replace")
+                       if cfg.get(k) is None]
+            if missing:
+                raise ValueError(
+                    f"kimi_k2 needs {', '.join(missing)} in its config "
+                    f"(deepseek_v3's class defaults are not this family's)")
+            mt = "deepseek_v3"          # the v3 branch, from here on
         v32 = mt == "deepseek_v32"
         if v32:
             # v3's block plus the lightning indexer: every v3 check below

@@ -160,6 +160,11 @@ _FINISH = object()  # queue sentinel
 class EngineCore:
     """The model-executing scheduler. Owns params + KV cache on device."""
 
+    # set by __init__ from the model; the default is for a shell that
+    # builds the step functions only (benchmark/compile_check.py's, which
+    # sets what _compile_jits read before the hybrid family came)
+    is_hybrid = False
+
     def __init__(self, model_cfg: ModelConfig, engine_cfg: EngineConfig,
                  params: Optional[dict] = None, attn_impl: str = "auto",
                  param_dtype=jnp.bfloat16, mesh=None,
@@ -2393,6 +2398,13 @@ class EngineCore:
             # without such layers)
             scan_tokens=(suffix_len if self.is_hybrid and not remote_admit
                          else 0),
+            # Σ over the prefilled rows of the keys each attended: a dense
+            # latent-attention prefill reads every earlier row (0 for the
+            # other families, and where an indexer selects the rows)
+            key_tokens=(suffix_len * (n_prompt - suffix_len)
+                        + suffix_len * (suffix_len + 1) // 2
+                        if self.is_mla and not self.model_cfg.index_topk
+                        and not remote_admit else 0),
             host_ms=round(1e3 * (now - t0), 3),
             # of host_ms: plan to the prefill program's return (argument
             # build and transfers included), and the blocking fetch of

@@ -503,17 +503,32 @@ def init_one_param(cfg: ModelConfig, name: str, shape: tuple,
 # what they were measured against: PERF.md section 6, PR 31.
 SPARSE_SEEDED = {"embed": 1.0, "wo": 0.5, "down": 0.25, "moe_down": 0.1}
 
+# Seeded weights of a model that holds one chip's share of its experts
+# (ModelConfig.num_experts_total > 0) and has no indexer. The router's
+# top-k is a step too, and with a share held a flipped choice is not one
+# expert for another but a held expert's output there or not: at
+# fan_in^-0.5 one flip moves a logit by 0.1-0.4 of the logits' standard
+# deviation (docs/mla_dense.md "Random weights"). Only what a flip moves
+# is damped: the routed experts' down-projection; the embedding stands at
+# the scale of a normalised branch input, so that it is a part of the
+# stream (a quarter of its variance after eight layers) and not nothing.
+# Attention, the dense and the shared MLPs keep fan_in^-0.5: a layer left
+# out, a lower precision and the router cut to the share all stay visible
+# (what the factors were measured against: PERF.md section 6, PR 37).
+SHARE_SEEDED = {"embed": 1.0, "moe_down": 0.5}
+
 
 def seeded_std(cfg: ModelConfig, name: str, fan_in: int) -> float:
     """Standard deviation of a --random-weights matrix: fan_in^-0.5, but
-    see SPARSE_SEEDED."""
+    see SPARSE_SEEDED and SHARE_SEEDED."""
     std = fan_in ** -0.5
-    if cfg.index_topk > 0:
-        if name == "embed":
-            return SPARSE_SEEDED["embed"]
-        for suffix in ("moe_down", "down", "wo"):
-            if name.endswith(suffix):
-                return std * SPARSE_SEEDED[suffix]
+    rule = (SPARSE_SEEDED if cfg.index_topk > 0
+            else SHARE_SEEDED if cfg.num_experts_total > 0 else {})
+    if name == "embed":
+        return rule.get("embed", std)
+    for suffix in ("moe_down", "down", "wo"):
+        if name.endswith(suffix):
+            return std * rule.get(suffix, 1.0)
     return std
 
 

@@ -76,6 +76,13 @@ Attention on top of the v3 block:
   stacks hold ``num_experts`` of them, and ``_moe_mlp`` adds what the
   held experts give for the tokens routed to them and drops the rest.
 
+Without an indexer (deepseek_v2, deepseek_v3, kimi_k2 — the v3 block at
+its own sizes, one routing group; docs/mla_dense.md) every query reads
+every cached row: a prefill chunk in the EXPANDED form, by key blocks of
+the live table with a running max and sum (``_dense_chunk``; on the TPU
+one Pallas call a key block, engine/mla_prefill.py), a decode step in the
+ABSORBED form over the paged pool (``decode_forward``).
+
 What carries ``idx`` and what refuses is decided once, at engine build
 (``dsa_refusals``): ragged dispatch, speculative verify, sequence-parallel
 prefill, every mesh (tp/sp/pp/ep/dp), int8 KV pools, the host/disk/remote
@@ -93,11 +100,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..attention import (dequant_kv_rows_sections,
+from ..attention import (ATTN_CHUNK_BLOCKS, dequant_kv_rows_sections,
                          quantize_kv_rows_sections,
                          ragged_paged_attention_pallas)
 from ..config import ModelConfig
 from ..index_scores import index_scores_pallas, index_scores_supported
+from ..mla_prefill import (K_TILE, Q_TILE, mla_prefill_block,
+                           mla_prefill_supported)
 from ..quant import mm
 from ..select_compact import NOT_TAKEN, compact_top_k
 from .llama import (ModelStatics, _embed, _layer_stack, _logits,
@@ -862,8 +871,99 @@ def _split_wkv_b(lp, cfg: ModelConfig):
 
 
 # ---------------------------------------------------------------------------
-# Prefill: expand k/v from latent rows, dense causal attention
+# Prefill: expand k/v from latent rows by key blocks, causal attention
 # ---------------------------------------------------------------------------
+
+# rows of the table a dense prefill chunk reads, expands and attends at a
+# time (a whole number of the pool's blocks is taken): bounds what one call
+# of the kernel streams and, off the kernel, the expanded keys and values
+# [H, block, dn | dv] and the scores [H, chunk, block]
+MLA_KEY_BLOCK = 2048
+
+
+def _dense_chunk(q_nope, q_pe, lp, kv_flat, table_l, start_pos, seq_len,
+                 cfg: ModelConfig, bsz: int, scale: float,
+                 impl: str) -> jax.Array:
+    """Causal attention of the T queries of one prefill chunk (query t at
+    position start_pos + t) over the live rows of its table (table_l [M],
+    layer-offset block ids; positions < seq_len), in the expanded form and
+    by key blocks with a running max and sum (docs/mla_dense.md): each
+    block of MLA_KEY_BLOCK rows is read from the pool by its blocks,
+    expanded through wkv_b once a head (in the activations' dtype, float32
+    accumulation), attended, and folded into the running state — on the
+    TPU in ONE Pallas call a key block (engine/mla_prefill.py), elsewhere
+    (the CPU, a mesh, widths off the lane tiling) the same three steps in
+    XLA. Nothing of size heads × chunk × table or heads × table × head_dim
+    exists, and the walk ends at the live length, not at the table's
+    capacity. → [T, H, dv] float32."""
+    from ..attention import _on_tpu
+    T, H = q_nope.shape[0], q_nope.shape[1]
+    rank, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    cd, f32 = q_nope.dtype, jnp.float32
+    M, W = table_l.shape[0], kv_flat.shape[-1]
+    nb = max(1, min(MLA_KEY_BLOCK // bsz, M))      # pool blocks a key block
+    kernel = ("interpret" if impl == "pallas_interpret" else
+              impl in ("auto", "pallas") and _on_tpu()
+              and mla_prefill_supported(rank, dn, dr, dv))
+    if kernel and nb * bsz > K_TILE:
+        # whole row tiles of the kernel, where blocks divide a tile
+        if K_TILE % bsz:
+            kernel = False
+        else:
+            nb -= nb % (K_TILE // bsz)
+    KB = nb * bsz
+    table_l = jnp.pad(table_l, (0, -M % nb))       # the trash block: masked
+    # head-major, the query rows on whole tiles of the kernel
+    Tp = -(-T // Q_TILE) * Q_TILE if T > Q_TILE else -(-T // 8) * 8
+    qn = jnp.pad(jnp.moveaxis(q_nope, 1, 0), ((0, 0), (0, Tp - T), (0, 0)))
+    qp = jnp.pad(jnp.moveaxis(q_pe.astype(cd), 1, 0),
+                 ((0, 0), (0, Tp - T), (0, 0)))
+    w_k, w_v = (w.astype(cd) for w in _split_wkv_b(lp, cfg))
+    pool = kv_flat.reshape(-1, bsz, W)
+
+    def block(j, state):
+        acc, ml = state
+        ids = jax.lax.dynamic_slice(table_l, (j * nb,), (nb,))
+        rows = jnp.take(pool, ids, axis=0, mode="clip").reshape(KB, W)
+        if rows.dtype == jnp.int8:
+            rows = dequant_kv_rows_sections(rows, (rank, dr), f32)
+        rows = rows.astype(cd)
+        # the block's frame: its first row is position 0
+        q_lo, live = start_pos - j * KB, jnp.clip(seq_len - j * KB, 0, KB)
+        if kernel:
+            return mla_prefill_block(
+                qn, qp, rows, w_k, w_v, acc, ml, q_lo=q_lo, live=live,
+                scale=scale, rank=rank, dr=dr,
+                interpret=(kernel == "interpret"))
+        c, k_pe = rows[:, :rank], rows[:, rank:rank + dr]
+        k_nope = jnp.einsum("sr,hrd->hsd", c, w_k,
+                            preferred_element_type=f32).astype(cd)
+        v = jnp.einsum("sr,hrd->hsd", c, w_v,
+                       preferred_element_type=f32).astype(cd)
+        s = (jnp.einsum("htd,hsd->hts", qn, k_nope,
+                        preferred_element_type=f32)
+             + jnp.einsum("htd,sd->hts", qp, k_pe,
+                          preferred_element_type=f32)) * scale
+        kpos = jnp.arange(KB)[None, :]
+        mask = (kpos <= q_lo + jnp.arange(Tp)[:, None]) & (kpos < live)
+        s = jnp.where(mask[None], s, NEG_INF)
+        m, l = ml[..., 0], ml[..., 1]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        # a row with nothing to read yet: exp(NEG_INF - NEG_INF) is not 0
+        p = jnp.where(mask[None], jnp.exp(s - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "hts,hsd->htd", p.astype(cd), v, preferred_element_type=f32)
+        return acc, jnp.stack([m_new, l * alpha + jnp.sum(p, axis=-1)], -1)
+
+    acc, ml = jax.lax.fori_loop(
+        0, (seq_len + KB - 1) // KB, block,
+        (jnp.zeros((H, Tp, dv), f32),
+         jnp.stack([jnp.full((H, Tp), NEG_INF, f32),
+                    jnp.zeros((H, Tp), f32)], -1)))
+    out = acc / jnp.maximum(ml[..., 1:], 1e-20)
+    return jnp.moveaxis(out[:, :T], 0, 1)
 
 
 def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
@@ -873,12 +973,12 @@ def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
     """Same contract as llama.prefill_forward: tokens [T] (padded),
     block_table [M], returns (last-token logits [V], new kv). Supports a
     cached prefix (start_pos > 0 — chunked prefill / prefix reuse): the
-    chunk's rows are scattered first and attention expands k/v for the
-    WHOLE table from the latent pool."""
+    chunk's rows are scattered first and attention reads the live rows of
+    the table back from the latent pool, a key block at a time
+    (``_dense_chunk``; with an indexer, ``_sparse_chunk``)."""
     cfg, bsz = statics.cfg, statics.block_size
     T = tokens.shape[0]
     H = cfg.num_heads
-    rank, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
     scale = softmax_scale(cfg)
     positions = start_pos + jnp.arange(T, dtype=jnp.int32)
     valid = jnp.arange(T) < true_len
@@ -897,31 +997,14 @@ def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
                                 positions, seq_len, cfg, bsz, scale)
             out = jnp.einsum("thr,hrd->thd", ctx, w_v.astype(jnp.float32))
             return out.reshape(T, H * cfg.v_head_dim).astype(q_nope.dtype)
-        idx = (flat_token_indices(block_table[None, :], bsz)[0]
-               + li * NTOK)
-        S = idx.shape[0]
-        rows = jnp.take(kv_flat, idx, axis=0)            # [S, W]
-        if rows.dtype == jnp.int8:
-            rows = dequant_kv_rows_sections(rows, (rank, dr),
-                                            jnp.float32)
-        c, k_pe = rows[..., :rank], rows[..., rank:rank + dr]
-        w_k, w_v = _split_wkv_b(lp, cfg)
-        # expand: k_nope [H, S, dn], v [H, S, dv]
-        k_nope = jnp.einsum("sr,hrd->hsd", c.astype(jnp.float32),
-                            w_k.astype(jnp.float32))
-        v = jnp.einsum("sr,hrd->hsd", c.astype(jnp.float32),
-                       w_v.astype(jnp.float32))
-        qn = q_nope.astype(jnp.float32)                  # [T, H, dn]
-        qp = q_pe.astype(jnp.float32)                    # [T, H, dr]
-        scores = (jnp.einsum("thd,hsd->hts", qn, k_nope)
-                  + jnp.einsum("thd,sd->hts", qp,
-                               k_pe.astype(jnp.float32))) * scale
-        qpos = positions[:, None]
-        kpos = jnp.arange(S)[None, :]
-        mask = (kpos <= qpos) & (kpos < seq_len)
-        scores = jnp.where(mask[None, :, :], scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("hts,hsd->thd", probs, v)       # [T, H, dv]
+        with jax.named_scope("mla_prefill_attention"):
+            # a Pallas call has no partitioning rule: over a mesh the key
+            # blocks are attended by XLA
+            out = _dense_chunk(q_nope, q_pe, lp, kv_flat,
+                               block_table + li * (NTOK // bsz), start_pos,
+                               seq_len, cfg, bsz, scale,
+                               "xla" if statics.sharded
+                               else statics.attn_impl)
         return out.reshape(T, H * cfg.v_head_dim).astype(q_nope.dtype)
 
     x = _embed(params, tokens, cfg)
@@ -1109,6 +1192,20 @@ def ragged_forward(params: Params, kv: KVCache, tokens: jax.Array,
     return _logits(params, sel, cfg), kv_new
 
 
+# rows of a DMA wave of the latent decode read: one 640-lane row serves
+# every head, so a wave of the llama-path depth (16 blocks: 256 rows of
+# block size 16) is consumed faster than the next is issued. 1,024 rows
+# (1.3 MB a buffer) read 73% of the HBM peak at 8k-25k contexts where 256
+# read 50% and 512 read 68% (PERF.md section 5, PR 37)
+LATENT_WAVE_ROWS = 1024
+
+
+def latent_wave_blocks(bsz: int) -> int:
+    """The wave depth, in pool blocks, of the latent one-head form of the
+    paged-attention kernel: never shallower than the kernel's own."""
+    return max(ATTN_CHUNK_BLOCKS, LATENT_WAVE_ROWS // bsz)
+
+
 def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
                    positions: jax.Array, block_tables: jax.Array,
                    statics: ModelStatics) -> Tuple[jax.Array, KVCache]:
@@ -1171,12 +1268,14 @@ def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
             # and returns probs·c directly. Ranks that don't lane-align
             # (tiny test geometries) slice after instead
             vl = rank if rank % 128 == 0 else None
-            ctx = paged_attention(
-                qc, kv_flat, kv_flat, tables_l, seq_lens,
-                block_size=bsz, scale=scale, impl=statics.attn_impl,
-                kv_heads=1, v_lanes=vl,
-                coalesce=statics.kv_coalesce)[..., :rank].astype(
-                    jnp.float32)
+            with jax.named_scope("mla_decode_attention"):
+                ctx = paged_attention(
+                    qc, kv_flat, kv_flat, tables_l, seq_lens,
+                    block_size=bsz, scale=scale, impl=statics.attn_impl,
+                    kv_heads=1, v_lanes=vl,
+                    coalesce=statics.kv_coalesce,
+                    chunk_blocks=latent_wave_blocks(bsz))[..., :rank].astype(
+                        jnp.float32)
         else:
             from ..attention import (_on_tpu, paged_attention_pallas,
                                      pallas_supported)

@@ -859,11 +859,15 @@ def test_the_reference_blocked_equals_the_reference_whole(ref, monkeypatch):
 
 def test_seeded_weights_of_a_sparse_attention_model():
     """llama.seeded_std: with an indexer the embedding and the projections
-    that write into the stream take SPARSE_SEEDED's scales; every other
-    matrix, and every matrix of a model with no indexer, fan_in^-0.5."""
+    that write into the stream take SPARSE_SEEDED's scales; with one chip's
+    share of the experts held and no indexer (since PR 37) the embedding and
+    the routed experts' down-projection take SHARE_SEEDED's; every other
+    matrix, and every matrix of a model with neither, fan_in^-0.5."""
     from dynamo_tpu.engine.models import llama
     cfg = ModelConfig.from_hf_config(_hf(vocab_size=4096))
-    plain = dataclasses.replace(cfg, index_topk=0)
+    share = dataclasses.replace(cfg, index_topk=0)
+    assert share.num_experts_total > 0
+    plain = dataclasses.replace(share, num_experts_total=0)
     key = jax.random.PRNGKey(0)
 
     def std(c, name, shape):
@@ -871,17 +875,20 @@ def test_seeded_weights_of_a_sparse_attention_model():
                                                   jnp.float32)))
 
     usual = 256 ** -0.5
-    want = llama.SPARSE_SEEDED
-    assert abs(std(cfg, "embed", (4096, 64)) / want["embed"] - 1) < 0.05
-    assert abs(std(plain, "embed", (4096, 64)) - 4096 ** -0.5) < 0.001
-    for name, shape, key_ in (
-            ("layers.wo", (2, 256, 64), "wo"),
-            ("layers.dense_down", (2, 256, 64), "down"),
-            ("layers.sh_down", (2, 256, 64), "down"),
-            ("layers.moe_down", (2, 4, 256, 64), "moe_down")):
-        assert abs(std(cfg, name, shape) / usual / want[key_] - 1) < 0.05
-        assert abs(std(plain, name, shape) / usual - 1) < 0.05
-    assert abs(std(cfg, "layers.wq_b", (2, 256, 64)) / usual - 1) < 0.05
+    rules = ((cfg, llama.SPARSE_SEEDED), (share, llama.SHARE_SEEDED),
+             (plain, {}))
+    assert set(llama.SHARE_SEEDED) == {"embed", "moe_down"}
+    for c, want in rules:
+        embed = want.get("embed", 4096 ** -0.5)
+        assert abs(std(c, "embed", (4096, 64)) / embed - 1) < 0.05
+        for name, shape, key_ in (
+                ("layers.wo", (2, 256, 64), "wo"),
+                ("layers.dense_down", (2, 256, 64), "down"),
+                ("layers.sh_down", (2, 256, 64), "down"),
+                ("layers.moe_down", (2, 4, 256, 64), "moe_down")):
+            factor = want.get(key_, 1.0)
+            assert abs(std(c, name, shape) / usual / factor - 1) < 0.05
+        assert abs(std(c, "layers.wq_b", (2, 256, 64)) / usual - 1) < 0.05
 
 
 def test_the_check_forces_the_engines_selection_on_the_reference(chk, capsys):

@@ -9,6 +9,10 @@ rejected in from_hf_config until the engine serves it.
 """
 
 import dataclasses
+import importlib.util
+import json
+import os
+import sys
 
 import numpy as np
 import pytest
@@ -17,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from dynamo_tpu.engine.config import ModelConfig
+from dynamo_tpu.engine import mla_prefill
 from dynamo_tpu.engine.models import mla
 from dynamo_tpu.engine.models.llama import ModelStatics
 
@@ -1333,3 +1338,509 @@ def test_deepseek_v3_checkpoint_roundtrip(tmp_path):
         np.testing.assert_allclose(np.asarray(loaded[k]),
                                    np.asarray(params[k]),
                                    rtol=0, atol=0, err_msg=k)
+
+
+# ---------------------------------------------------------------------------
+# The blocked dense prefill (mla._dense_chunk) and kimi_k2 (PR 37;
+# docs/mla_dense.md)
+# ---------------------------------------------------------------------------
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_BENCH = os.path.join(_ROOT, "benchmark")
+
+
+def _plain_dense_chunk(q_nope, q_pe, lp, kv_flat, table_l, start_pos,
+                       seq_len, cfg, bsz, scale):
+    """The parent's dense form, as the plain expression: the WHOLE table
+    gathered, expanded in float32, one [H, T, S] score array."""
+    T = q_nope.shape[0]
+    rank, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    f32 = jnp.float32
+    rows = jnp.take(kv_flat.reshape(-1, bsz, kv_flat.shape[-1]), table_l,
+                    axis=0).reshape(-1, kv_flat.shape[-1])
+    S = rows.shape[0]
+    c, k_pe = rows[:, :rank].astype(f32), rows[:, rank:rank + dr].astype(f32)
+    w_k, w_v = mla._split_wkv_b(lp, cfg)
+    k_nope = jnp.einsum("sr,hrd->hsd", c, w_k.astype(f32))
+    v = jnp.einsum("sr,hrd->hsd", c, w_v.astype(f32))
+    scores = (jnp.einsum("thd,hsd->hts", q_nope.astype(f32), k_nope)
+              + jnp.einsum("thd,sd->hts", q_pe.astype(f32), k_pe)) * scale
+    qpos = (start_pos + jnp.arange(T))[:, None]
+    kpos = jnp.arange(S)[None, :]
+    mask = (kpos <= qpos) & (kpos < seq_len)
+    probs = jax.nn.softmax(jnp.where(mask[None], scores, mla.NEG_INF), -1)
+    return jnp.einsum("hts,hsd->thd", probs, v)
+
+
+_CHUNK_CASES = {
+    # name: (T bucket, start_pos, true_len, table blocks in order)
+    "whole_prompt": (32, 0, 32, [1, 2, 3, 4, 5, 6, 7, 8]),
+    "cached_prefix": (16, 40, 16, [1, 2, 3, 4, 5, 6, 7, 8]),
+    "true_len_under_bucket": (32, 16, 11, [1, 2, 3, 4, 5, 6, 7, 8]),
+    "length_on_a_block_edge": (16, 16, 16, [1, 2, 3, 4, 5, 6, 7, 8]),
+    "length_on_a_key_block_edge": (16, 8, 8, [1, 2, 3, 4, 5, 6, 7, 8]),
+    "fragmented_table": (16, 24, 13, [9, 3, 14, 1, 7, 12, 2, 5]),
+    "table_not_a_multiple_of_the_key_block": (16, 24, 16,
+                                              [4, 5, 6, 7, 8, 9, 10]),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_CHUNK_CASES))
+def test_blocked_dense_chunk_equals_the_plain_dense_form(case, dtype,
+                                                         monkeypatch):
+    """The key-block walk (two blocks of the pool a key block here, so
+    every case crosses several) against the whole-table expression, on the
+    valid query rows: float32 to rounding, bf16 to bf16's."""
+    monkeypatch.setattr(mla, "MLA_KEY_BLOCK", 2 * BS)
+    T, start, true_len, blocks = _CHUNK_CASES[case]
+    cfg = _cfg(q_lora=12)
+    H, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    W = mla.latent_row_lanes(cfg)
+    kv_flat = jax.random.normal(keys[0], (NUM_BLOCKS * BS, W)).astype(dtype)
+    lp = {"wkv_b": (jax.random.normal(
+        keys[1], (cfg.kv_lora_rank, H * (dn + cfg.v_head_dim)))
+        * cfg.kv_lora_rank ** -0.5).astype(dtype)}
+    q_nope = jax.random.normal(keys[2], (T, H, dn)).astype(dtype)
+    q_pe = jax.random.normal(keys[3], (T, H, dr)).astype(dtype)
+    table = jnp.asarray(blocks, jnp.int32)
+    args = (q_nope, q_pe, lp, kv_flat, table, jnp.asarray(start),
+            jnp.asarray(start + true_len), cfg, BS, mla.softmax_scale(cfg))
+    got = np.asarray(jax.jit(
+        lambda *a: mla._dense_chunk(*a, cfg, BS, mla.softmax_scale(cfg),
+                                    "xla"))(*args[:7]), np.float32)
+    want = np.asarray(_plain_dense_chunk(*args), np.float32)
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(got[:true_len], want[:true_len], rtol=tol,
+                               atol=tol)
+
+
+@pytest.mark.parametrize("q_tile", [1024, 8], ids=["one_query_tile",
+                                                    "two_query_tiles"])
+@pytest.mark.parametrize("case", ["cached_prefix", "fragmented_table",
+                                  "true_len_under_bucket"])
+def test_blocked_dense_chunk_through_the_kernel(case, q_tile, monkeypatch):
+    """The same walk with each key block expanded, attended and merged in
+    the Pallas kernel ``mla_prefill`` (interpreted here; two row tiles a
+    key block, the state carried from one call to the next): the form the
+    chip runs."""
+    monkeypatch.setattr(mla, "MLA_KEY_BLOCK", 2 * BS)
+    for module in (mla, mla_prefill):
+        monkeypatch.setattr(module, "K_TILE", 8)
+        monkeypatch.setattr(module, "Q_TILE", q_tile)
+    T, start, true_len, blocks = _CHUNK_CASES[case]
+    cfg = _cfg(q_lora=12)
+    H, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    keys = jax.random.split(jax.random.PRNGKey(4), 4)
+    kv_flat = jax.random.normal(keys[0], (NUM_BLOCKS * BS,
+                                          mla.latent_row_lanes(cfg)))
+    lp = {"wkv_b": jax.random.normal(
+        keys[1], (cfg.kv_lora_rank, H * (dn + cfg.v_head_dim)))
+        * cfg.kv_lora_rank ** -0.5}
+    q_nope = jax.random.normal(keys[2], (T, H, dn))
+    q_pe = jax.random.normal(keys[3], (T, H, dr))
+    args = (q_nope, q_pe, lp, kv_flat, jnp.asarray(blocks, jnp.int32),
+            jnp.asarray(start), jnp.asarray(start + true_len), cfg, BS,
+            mla.softmax_scale(cfg))
+    got = np.asarray(mla._dense_chunk(*args, "pallas_interpret"))
+    want = np.asarray(_plain_dense_chunk(*args))
+    np.testing.assert_allclose(got[:true_len], want[:true_len], rtol=2e-5,
+                               atol=2e-5)
+
+
+def test_blocked_prefill_work_follows_the_live_length(monkeypatch):
+    """The walk's trip count is ceil(live length / key block), whatever the
+    table's capacity: rows past the live length are never read (NaNs
+    there change nothing)."""
+    monkeypatch.setattr(mla, "MLA_KEY_BLOCK", 2 * BS)
+    cfg = _cfg()
+    H, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    W = mla.latent_row_lanes(cfg)
+    kv_flat = jax.random.normal(keys[0], (NUM_BLOCKS * BS, W))
+    table = jnp.arange(1, 13, dtype=jnp.int32)      # capacity 96
+    # positions 32.. live nowhere: poison their blocks (5 and up)
+    poisoned = kv_flat.at[5 * BS:].set(jnp.nan)
+    lp = {"wkv_b": jax.random.normal(
+        keys[1], (cfg.kv_lora_rank, H * (dn + cfg.v_head_dim)))}
+    q_nope = jax.random.normal(keys[2], (16, H, dn))
+    q_pe = jax.random.normal(keys[3], (16, H, dr))
+    run = lambda pool: np.asarray(mla._dense_chunk(  # noqa: E731
+        q_nope, q_pe, lp, pool, table, jnp.asarray(16), jnp.asarray(32),
+        cfg, BS, mla.softmax_scale(cfg), "xla"))
+    assert np.isfinite(run(poisoned)).all()
+    np.testing.assert_array_equal(run(poisoned), run(kv_flat))
+    text = jax.jit(lambda: mla._dense_chunk(
+        q_nope, q_pe, lp, kv_flat, table, jnp.asarray(16), jnp.asarray(32),
+        cfg, BS, mla.softmax_scale(cfg), "xla")).lower().as_text()
+    assert "while" in text          # a loop with a traced trip count
+
+
+@pytest.fixture(scope="module")
+def kimi_ref():
+    """benchmark/references/kimi_k2.py (it imports the benchmark's
+    ``reference`` module by its bare name)."""
+    sys.path.insert(0, _BENCH)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "reference_kimi_k2",
+            os.path.join(_BENCH, "references", "kimi_k2.py"))
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        yield module
+    finally:
+        sys.path.remove(_BENCH)
+
+
+def _kimi_hf(**over) -> dict:
+    with open(os.path.join(_BENCH, "fixtures", "tiny-kimi-k2.json")) as f:
+        hf = json.load(f)
+    for key in ("source", "reduced", "assumed", "deployment", "reference"):
+        hf.pop(key)
+    return dict(hf, **over)
+
+
+def _kimi_setup(hf: dict, seed: int = 1):
+    cfg = ModelConfig.from_hf_config(hf)
+    params = mla.init_params(cfg, jax.random.PRNGKey(seed),
+                             dtype=jnp.float32)
+    # a router bias that matters (it is zero at initialisation)
+    params["layers.router_bias"] = 0.1 * jax.random.normal(
+        jax.random.PRNGKey(seed + 1), params["layers.router_bias"].shape)
+    kv = mla.init_kv_cache(cfg, NUM_BLOCKS, 16, dtype=jnp.float32)
+    return cfg, params, kv, ModelStatics(cfg=cfg, block_size=16,
+                                         attn_impl="xla")
+
+
+def _std_err(got, want) -> float:
+    want = np.asarray(want, np.float32)
+    return float(np.abs(np.asarray(got, np.float32) - want).max()
+                 / want.std())
+
+
+def test_kimi_k2_parses_onto_the_v3_block_with_one_group():
+    cfg = ModelConfig.from_hf_config(_kimi_hf())
+    assert (cfg.model_type, cfg.is_deepseek_v3, cfg.moe_routing,
+            cfg.n_group, cfg.topk_group, cfg.index_topk) == (
+        "kimi_k2", True, "sigmoid_noaux", 1, 1, 0)
+    assert (cfg.num_experts, cfg.num_experts_total, cfg.router_width,
+            cfg.first_k_dense, cfg.routed_scaling) == (12, 24, 24, 1, 2.827)
+    assert cfg.moe_norm_topk and cfg.num_nextn_predict_layers == 0
+    # the v3 score scale: mscale(64, 1)^2 on top of (dn+dr)^-0.5
+    m = 0.1 * np.log(64.0) + 1.0
+    assert abs(mla.softmax_scale(cfg) - 24 ** -0.5 * m * m) < 1e-9
+    # the family's own checkpoint names: the v3 router bias among them
+    from dynamo_tpu.engine.weights import _layer_map_for
+    names = _layer_map_for(cfg)
+    assert names["mlp.gate.e_score_correction_bias"] == ("router_bias",
+                                                          False)
+    assert names["self_attn.kv_a_proj_with_mqa.weight"] == ("wkv_a", True)
+    # the file's own values, not v3's class defaults
+    for key in ("n_routed_experts", "n_group", "routed_scaling_factor",
+                "first_k_dense_replace"):
+        hf = _kimi_hf()
+        hf.pop(key)
+        with pytest.raises(ValueError, match=key):
+            ModelConfig.from_hf_config(hf)
+
+
+@pytest.mark.parametrize("key", ["n_routed_experts", "num_local_experts",
+                                 "num_experts"])
+def test_unknown_expert_family_is_refused_by_name(key):
+    with pytest.raises(ValueError, match="some_new_moe"):
+        ModelConfig.from_hf_config({
+            "model_type": "some_new_moe", "vocab_size": 128,
+            "hidden_size": 64, "num_attention_heads": 4, key: 8})
+    # a dense unknown family still parses as the llama block
+    assert ModelConfig.from_hf_config({
+        "model_type": "some_new_dense", "vocab_size": 128,
+        "hidden_size": 64, "num_attention_heads": 4}).num_experts == 0
+
+
+def _kimi_engine_logits(hf, tokens, chunk=16, prefilled=48,
+                        monkeypatch=None):
+    """Prefill by chunks, then decode through the cache: the logits of the
+    last prefilled position and of every decoded one."""
+    cfg, params, kv, statics = _kimi_setup(hf)
+    table = jnp.arange(1, 9, dtype=jnp.int32)
+    out = []
+    with jax.default_matmul_precision("highest"):
+        for lo in range(0, prefilled, chunk):
+            logits, kv = mla.prefill_forward(
+                params, kv, jnp.asarray(tokens[lo:lo + chunk], jnp.int32),
+                table, jnp.asarray(lo), jnp.asarray(chunk), statics)
+        out.append(logits)
+        for pos in range(prefilled, len(tokens)):
+            logits, kv = mla.decode_forward(
+                params, kv, jnp.asarray([tokens[pos], 0]),
+                jnp.asarray([pos, 0]),
+                jnp.stack([table, jnp.zeros_like(table)]), statics)
+            out.append(logits[0])
+    return params, np.stack([np.asarray(x) for x in out])
+
+
+def test_kimi_k2_chunked_prefill_then_decode_equals_the_reference(
+        kimi_ref, monkeypatch):
+    """Logits, not tokens, in float32: the engine's blocked prefill (three
+    chunks, key blocks of 32 rows) and its absorbed decode through the
+    cache against the reference's full forward (1e-6 of the logits'
+    standard deviation apart; held to 1e-4), and every breakage of the
+    reference hundreds of times that tolerance away (the router's are the
+    nearest: the routed experts write into the stream at half of
+    fan_in^-0.5, llama.SHARE_SEEDED)."""
+    monkeypatch.setattr(mla, "MLA_KEY_BLOCK", 32)
+    hf = _kimi_hf()
+    tokens = np.random.default_rng(3).integers(0, hf["vocab_size"], size=55)
+    params, got = _kimi_engine_logits(hf, tokens)
+    want = kimi_ref.logits_for(params, hf, tokens.tolist(), 8)
+    assert _std_err(got, want) < 1e-4
+    breakages = kimi_ref.breakages_for(hf)
+    assert set(breakages) == set(kimi_ref.BREAKAGES)
+    for broken in breakages:
+        wrong = kimi_ref.logits_for(params, hf, tokens.tolist(), 8,
+                                    broken=broken)
+        assert _std_err(got, wrong) > 0.05, broken
+
+
+@pytest.mark.parametrize("control", ["fp8_activations", "int4_weights",
+                                     "default_precision"])
+def test_kimi_k2_reference_controls_are_the_same_mathematics_lower(
+        kimi_ref, control):
+    """The reference's controls (the next precision below the served one:
+    activations in float8, weights in 4 bits) move its logits, and by less
+    than leaving a layer out does; its default-precision pass is float32
+    on the CPU. An unknown name rounds nothing."""
+    hf = _kimi_hf()
+    tokens = np.random.default_rng(5).integers(
+        0, hf["vocab_size"], size=40).tolist()
+    _, params, _, _ = _kimi_setup(hf)
+    want = kimi_ref.logits_for(params, hf, tokens, 8)
+    if control == "default_precision":
+        got = kimi_ref.logits_for(params, hf, tokens, 8, precision="default")
+        assert _std_err(got, want) < 1e-4
+        act, wt = kimi_ref._rounding("drop_layer")
+        x = jnp.linspace(-3.0, 3.0, 64).reshape(2, 32)
+        assert (act(x) == x).all() and (wt(x) == x).all()
+        return
+    assert control in kimi_ref.CONTROLS
+    assert control not in kimi_ref.breakages_for(hf)
+    off = _std_err(kimi_ref.logits_for(params, hf, tokens, 8, control), want)
+    dropped = _std_err(kimi_ref.logits_for(params, hf, tokens, 8,
+                                           "drop_layer"), want)
+    assert 1e-3 < off < dropped
+    if control == "int4_weights":
+        w = jax.random.normal(jax.random.PRNGKey(0), (3, 256, 8))
+        q = kimi_ref._int4_groups(w)
+        # 15 levels a group of 128 rows and column, the largest kept
+        for g in (q[1, :128, 3], q[2, 128:, 0]):
+            assert len(np.unique(np.asarray(g))) <= 15
+        assert float(jnp.abs(q - w).max()) <= float(jnp.abs(w).max()) / 14
+    else:
+        x = jax.random.normal(jax.random.PRNGKey(0), (4, 64)) * 1e-3
+        r = kimi_ref._fp8_rows(x)
+        assert float(jnp.abs(r / x - 1).max()) < 2 ** -3     # its own scale
+        assert float(jnp.abs(r - x).max()) > 0
+
+
+def test_mla_prefill_roofline_takes_work_and_time_from_the_same_chunks(
+        monkeypatch):
+    """benchmark/references/kimi_k2_costs.py on a small recorded trace:
+    the kernel's calls per layer and dispatch tell the key blocks the
+    traced chunks walked, so the share is the same whichever lengths the
+    profiler's window caught at one speed of the kernel; the configuration
+    is the one whose deployment the engine shows; another engine, or a
+    trace without the kernel, reads nothing."""
+    import types
+    monkeypatch.syspath_prepend(_BENCH)      # references/, peaks.py
+    from references import kimi_k2_costs as costs
+    monkeypatch.setattr(jax, "devices", lambda: [types.SimpleNamespace(
+        device_kind="TPU v5 lite")])
+    engine = {"max_num_seqs": 16, "num_kv_blocks": 26624,
+              "kv_block_size": 16}
+    config, chunk = costs.served_config({"engine": engine})
+    assert (config["model_type"], chunk) == ("kimi_k2", 1024)
+    L = config["num_hidden_layers"]
+
+    def ctx(blocks, chunks, us_a_call=1000.0):
+        calls = blocks * L * chunks
+        return {"engine": engine, "trace": {
+            "programs": [["jit_prefill", 1.0, chunks]],
+            "ops": [["%mla_prefill.7 f32[64,1024,128] custom-call",
+                     0.75 * calls * us_a_call * 1e-6, 0.75 * calls],
+                    ["%mla_prefill.6 f32[64,1024,128] custom-call",
+                     0.25 * calls * us_a_call * 1e-6, 0.25 * calls],
+                    ["%fusion.1 bf16[1024,64,192] fusion", 9.0, calls]]}}
+
+    ms = costs.prefill_seconds_per_dispatch(ctx(6, 40)) * 1e3
+    assert ms == pytest.approx(6 * L * 1000.0 * 1e-3)    # the calls alone
+    short, long_ = (costs.prefill_roofline_pct(ctx(n, 40)) for n in (4, 10))
+    # a table of 3.5 or of 9.5 key blocks, at the same time a call:
+    # the share moves by the expansion's part alone, not by the length
+    assert 0 < short < 100 and abs(long_ / short - 1) < 0.15
+    live = (6 - 1) * costs.KEY_BLOCK + 1024
+    cost = costs.prefill_attention(
+        config, 1024 * (live - 1024) + 1024 * 1025 / 2, 1024, live)
+    want = 100 * cost["flops"] / 197e12 / (ms / 1e3)
+    assert costs.prefill_roofline_pct(ctx(6, 40)) == pytest.approx(want)
+    assert costs.prefill_roofline_pct(ctx(6, 7)) == pytest.approx(want)
+    other = dict(ctx(6, 40), engine=dict(engine, max_num_seqs=64))
+    assert costs.prefill_roofline_pct(other) is None
+    assert costs.decode_roofline_pct(other) is None
+    bare = {"engine": engine, "trace": {"programs": [["jit_prefill", 1, 4]],
+                                        "ops": []}}
+    assert costs.prefill_roofline_pct(bare) is None
+    assert costs.prefill_seconds_per_dispatch(bare) is None
+
+
+def test_the_32_shares_of_a_one_group_router_add_up_to_the_uncut_layer(
+        kimi_ref):
+    """Guide "model-configs" section 4 at a 384-like width: 96 experts in
+    one group, 32 shares of 3, the top 8; what the shares give, the shared
+    expert counted once, adds up to the uncut layer — in the engine, and
+    against the uncut reference."""
+    E, shares = 96, 32
+    held = E // shares
+    base = dict(n_routed_experts_published=E, num_experts_per_tok=8,
+                num_hidden_layers=2)
+    hf_whole = _kimi_hf(**dict(base, n_routed_experts=E))
+    cfg_whole, params, _, _ = _kimi_setup(hf_whole)
+    m = jax.random.normal(jax.random.PRNGKey(5), (24, cfg_whole.hidden_size))
+    stack = {k[len("layers."):]: v for k, v in params.items()
+             if k.startswith("layers.")}
+    names = ("router", "router_bias", "moe_gate", "moe_up", "moe_down",
+             "sh_gate", "sh_up", "sh_down")
+    lp = {n: stack[n][0] for n in names}
+    with jax.default_matmul_precision("highest"):
+        whole = mla._moe_mlp(m, lp, cfg_whole)
+        shared_only = mla._moe_mlp(
+            m, dict(lp, moe_down=jnp.zeros_like(lp["moe_down"])), cfg_whole)
+        total = -(shares - 1) * shared_only
+        for share in range(shares):
+            hf = _kimi_hf(**dict(base, n_routed_experts=held,
+                                 expert_share_index=share))
+            cfg = ModelConfig.from_hf_config(hf)
+            assert (cfg.num_experts, cfg.router_width, cfg.n_group) == (
+                held, E, 1)
+            lp_share = dict(lp, **{n: lp[n][held * share:held * (share + 1)]
+                                   for n in ("moe_gate", "moe_up",
+                                             "moe_down")})
+            part = mla._moe_mlp(m, lp_share, cfg)
+            total = total + part
+            if share in (0, 17, 31):
+                # the reference, given the same share, gives the same part
+                want = kimi_ref.moe_block(kimi_ref.family(hf))(m, lp_share)
+                assert _std_err(part, want) < 1e-4
+        uncut = kimi_ref.moe_block(kimi_ref.family(hf_whole))(m, lp)
+    assert _std_err(whole, uncut) < 1e-4
+    assert _std_err(total, uncut) < 1e-4
+    # one share really leaves most of the layer out
+    assert _std_err(part, uncut) > 0.05
+
+
+@pytest.mark.asyncio
+async def test_kimi_k2_serves_and_records_the_keys_it_read():
+    """Through EngineCore: chunked prefill then decode; the prefill record
+    carries key_tokens = Σ keys each prefilled row attended, decode records
+    ctx_tokens. Another family's records carry 0."""
+    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.engine.core import (FINISH_SENTINEL, EngineCore,
+                                        EngineRequest)
+    from dynamo_tpu.engine.sampling import SlotSampling
+    cfg = ModelConfig.from_hf_config(_kimi_hf())
+    core = EngineCore(cfg, EngineConfig(
+        max_model_len=128, kv_block_size=16, num_kv_blocks=64,
+        max_num_seqs=2, prefill_buckets=[64], prefill_chunk=16),
+        attn_impl="xla", param_dtype=jnp.float32)
+    assert core.is_mla and not core.is_hybrid
+    try:
+        prompt = np.random.default_rng(2).integers(
+            0, cfg.vocab_size, size=37).tolist()
+        req = EngineRequest(rid="r1", prompt=prompt,
+                            sampling=SlotSampling(temperature=0.0),
+                            max_new_tokens=4, eos_ids=frozenset())
+        await core.submit(req)
+        n = 0
+        while True:
+            item, _ = await req.out_queue.get()
+            if item is FINISH_SENTINEL:
+                break
+            n += 1
+        assert n == 4
+        records = core.flight.dump()
+        prefill = [r for r in records if r["kind"] == "prefill"]
+        assert prefill[-1]["key_tokens"] == 37 * 38 // 2
+        assert prefill[-1]["scan_tokens"] == 0
+        decode = [r for r in records if r["kind"] == "decode"]
+        assert decode and decode[0]["ctx_tokens"] >= 38
+    finally:
+        await core.stop()
+
+
+_KIMI_ENGINE = dict(max_model_len=128, kv_block_size=16, num_kv_blocks=64,
+                    max_num_seqs=2, prefill_buckets=[32, 64])
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("option", [
+    {"ragged_dispatch": True}, {"spec_k": 2}, {"kv_quantization": "int8"},
+    {"host_kv_blocks": 8}, {"prefill_chunk": 16},
+    {"decode_steps_per_dispatch": 4}],
+    ids=lambda o: next(iter(o)))
+async def test_kimi_k2_takes_what_mla_carries_without_an_indexer(option):
+    """docs/mla_dense.md's matrix, one line a claim: kimi_k2 has no index
+    keys, so every path models/mla.py serves a latent-only pool with is
+    open to it — ragged dispatch, speculative verify, an int8 latent pool,
+    the host tier, chunked prefill, several steps a dispatch — and serves
+    the plain engine's greedy tokens (an int8 pool: its own)."""
+    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.engine.core import EngineCore
+    cfg = ModelConfig.from_hf_config(_kimi_hf())
+    params = mla.init_params(cfg, jax.random.PRNGKey(7), dtype=jnp.float32)
+    prompt = np.random.default_rng(5).integers(
+        0, cfg.vocab_size, size=41).tolist()
+    tokens = []
+    for over in ({}, option):
+        core = EngineCore(cfg, EngineConfig(**dict(_KIMI_ENGINE, **over)),
+                          params=dict(params), attn_impl="xla",
+                          param_dtype=jnp.float32)
+        assert core.is_mla and core.wire_kv_heads == 1
+        try:
+            tokens.append(await _greedy_tokens(core, "k", prompt, n=6))
+        finally:
+            await core.stop()
+    assert len(tokens[1]) == 6
+    if "kv_quantization" not in option:
+        assert tokens[1] == tokens[0]
+
+
+@pytest.mark.asyncio
+async def test_kimi_k2_meshes_whole_model_yes_one_share_no():
+    """One chip's share of the experts refuses every mesh at build (the
+    share IS this chip's part of an expert-parallel layer); the whole
+    router's experts serve over a tp×ep mesh with the single chip's
+    tokens."""
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.engine.core import EngineCore
+    from dynamo_tpu.parallel.sharding import make_mesh
+    with pytest.raises(NotImplementedError, match="expert share"):
+        EngineCore(ModelConfig.from_hf_config(_kimi_hf()),
+                   EngineConfig(**_KIMI_ENGINE), attn_impl="xla",
+                   param_dtype=jnp.float32, mesh=make_mesh(tp=2))
+    cfg = ModelConfig.from_hf_config(_kimi_hf(n_routed_experts=24))
+    assert cfg.num_experts_total == 0 and cfg.num_experts == 24
+    params = mla.init_params(cfg, jax.random.PRNGKey(8), dtype=jnp.float32)
+    prompt = list(range(2, 40))
+    tokens = []
+    for mesh in (None, make_mesh(dp=1, tp=2, sp=1, ep=2)):
+        core = EngineCore(cfg, EngineConfig(**_KIMI_ENGINE),
+                          params=dict(params), attn_impl="xla",
+                          param_dtype=jnp.float32, mesh=mesh)
+        try:
+            tokens.append(await _greedy_tokens(core, "m", prompt, n=6))
+        finally:
+            await core.stop()
+    assert tokens[1] == tokens[0]

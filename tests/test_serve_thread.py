@@ -138,6 +138,9 @@ async def test_bind_error_reaches_the_caller(tiny_model_dir):
         held.listen(1)
         with pytest.raises(OSError):
             await asyncio.wait_for(run.run_http(args, pipeline, core), 30)
+    # the thread tells its error before it ends: give it the moment
+    for thread in _serve_threads():
+        await asyncio.to_thread(thread.join, 10)
     assert not _serve_threads() and not core.running
     await runtime.shutdown()
 

@@ -722,3 +722,127 @@ def test_state_space_kernels_build_at_the_published_sizes(one_chip, kernel):
     # the state is rewritten where it lies: no second copy of 189 MB
     assert compiled.memory_analysis().alias_size_in_bytes >= L * B * N * Di * 4
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
+
+
+def _benchmark_hf(path):
+    import json
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", path)) as f:
+        hf = json.load(f)
+    extras = ("source", "reduced", "assumed", "deployment",
+              "memory_analysis", "notes", "reference")
+    return {k: v for k, v in hf.items() if k not in extras}
+
+
+def _shape_dims(text):
+    """Every array shape named in a compiled program's text, as tuples."""
+    import re
+    return {tuple(int(d) for d in m.split(","))
+            for m in re.findall(r"\[(\d+(?:,\d+)+)\]", text)}
+
+
+@pytest.mark.parametrize("program", ["prefill-1024", "decode-B16"])
+def test_dense_latent_attention_builds_at_the_published_widths(
+        one_chip, monkeypatch, program):
+    """kimi-k2.7-code's prefill chunk and decode step (models/mla.py, no
+    indexer) at the published widths and the cell's shapes — 64 heads, a
+    25,600-position table, a 1,024-token chunk, 16 slots — at the depth of
+    one dense and one expert layer (the layers are scanned: depth adds
+    nothing to a build). The prefill walks the table by key blocks through
+    the Pallas call ``mla_prefill`` inside a loop with a traced trip count;
+    the decode reads the pool in the ``paged_attention`` kernel's one-head
+    form at the latent wave depth (64 blocks). Neither holds an array of
+    heads × chunk × table or of heads × table × head_dim in any layout or
+    dtype."""
+    from dynamo_tpu.engine.config import ModelConfig
+    from dynamo_tpu.engine.models import llama, mla
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    monkeypatch.setattr(llama, "_on_tpu", lambda: True)
+    hf = dict(_benchmark_hf("configs/kimi-k2.7-code.json"),
+              num_hidden_layers=2)
+    cfg = ModelConfig.from_hf_config(hf)
+    assert (cfg.num_heads, cfg.kv_lora_rank, cfg.hidden_size,
+            cfg.router_width, cfg.num_experts) == (64, 512, 7168, 384, 12)
+    bs, blocks, S, T, B = 16, 4096, 25600, 1024, 16
+    M = S // bs
+    statics = llama.ModelStatics(cfg=cfg, block_size=bs, attn_impl="pallas")
+
+    def placed(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(
+        lambda: mla.init_params(cfg, jax.random.PRNGKey(0))))
+    kv = placed(jax.eval_shape(lambda: mla.init_kv_cache(cfg, blocks, bs)))
+    i32 = jnp.int32
+
+    def s(shape):
+        return jax.ShapeDtypeStruct(shape, i32, sharding=one_chip)
+
+    if program.startswith("prefill"):
+        text = jax.jit(lambda p, c, t, bt, sp, tl: mla.prefill_forward(
+            p, c, t, bt, sp, tl, statics)).lower(
+            params, kv, s((T,)), s((M,)), s(()), s(())).compile().as_text()
+        assert "mla_prefill" in text and "while" in text
+    else:
+        text = jax.jit(lambda p, c, t, pos, bt: mla.decode_forward(
+            p, c, t, pos, bt, statics)).lower(
+            params, kv, s((B,)), s((B,)), s((B, M))).compile().as_text()
+        assert "paged_attention" in text
+        # a wave's double buffer: 2 x 1,024 rows of the pool
+        assert mla.latent_wave_blocks(bs) == 64
+    assert "tpu_custom_call" in text
+    H, widths = cfg.num_heads, (128, 192, 256, 320)
+    heads = {H} | {H * w for w in widths}
+    for dims in _shape_dims(text):
+        # the table's positions beside the heads (or heads × a head width):
+        # heads × chunk × table, heads × table × head_dim, any order
+        assert not (S in dims and heads & set(dims)), dims
+        assert not (set(dims) >= {H, T, S}), dims
+
+
+@pytest.mark.parametrize("family", ["tiny-dense", "tiny-qwen2moe",
+                                    "tiny-deepseek-v32", "tiny-phi4flash"])
+def test_other_families_never_reach_the_blocked_dense_prefill(
+        family, one_chip, monkeypatch):
+    """The four families that are not dense latent attention lower their
+    decode and prefill programs (kernels on, as on the chip) without
+    entering ``mla._dense_chunk`` or asking for the latent decode's waves
+    (``mla.latent_wave_blocks``, the one line PR 37 changed in the dense
+    branch of ``decode_forward``):
+    what PR 37 added is not in their programs (their lowered text on the
+    CPU is byte-equal to the parent's: CHANGES.md, PR 37)."""
+    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+    from dynamo_tpu.engine.core import EngineCore
+    from dynamo_tpu.engine.models import llama, mla
+
+    def never(*a, **k):
+        raise AssertionError("the dense latent-attention branch reached")
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    monkeypatch.setattr(llama, "_on_tpu", lambda: True)
+    monkeypatch.setattr(mla, "_dense_chunk", never)
+    monkeypatch.setattr(mla, "latent_wave_blocks", never)
+    cfg = ModelConfig.from_hf_config(
+        _benchmark_hf(f"fixtures/{family}.json"))
+    B, T = 4, 128
+    core = EngineCore(cfg, EngineConfig(
+        max_model_len=256, kv_block_size=16, num_kv_blocks=64,
+        max_num_seqs=B, prefill_buckets=[T], quantization="int8", seed=1))
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, kv = jax.tree.map(lambda x: s(x.shape, x.dtype),
+                              (core.params, core.kv))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    key = s(key.shape, key.dtype)
+    i32, f32 = jnp.int32, jnp.float32
+    M = core.M
+    core._decode_k_jit.lower(
+        params, kv, s((B,), i32), s((B,), i32), s((B, M), i32),
+        s((B,), i32), s((B,), i32), s((B,), f32), s((B,), i32),
+        s((B,), f32), s((1, B), i32), s((1, B), jnp.bool_), key)
+    core._prefill_jit.lower(
+        params, kv, s((T,), i32), s((M,), i32), s((), i32), s((), i32),
+        key, s((), f32), s((), i32), s((), f32))
