@@ -715,10 +715,16 @@ def _run_layers(params: Params, kv: KVCache, x: jax.Array,
     weights, and the index-key cache flattened like kv_flat, with this
     chunk's keys already written.
 
-    deepseek hybrid sparsity (first_k_dense): the layer stacks split
-    into a dense prefix and a MoE suffix, each its own lax.scan with the
-    SAME attention body — the pools carry across both, with li
-    addressing rows globally.
+    deepseek hybrid sparsity (first_k_dense): the layers run as a dense
+    prefix and a MoE suffix, each its own lax.scan with the SAME attention
+    body — the pools carry across both, with li addressing rows globally.
+    Each scan slices only the stacks of its own layer kind (one stack per
+    kind: dense_*, router / moe_* / sh_*); the attention stacks hold every
+    layer, stay whole beside both scans, and the body reads layer li from
+    them in place — the read lax.scan lowers its own operands to. Handing
+    the scans ``stack[n][:k]`` / ``stack[n][k:]`` made XLA copy every
+    attention weight of the model in every dispatch (measured, PR 36: 4.1
+    of a 39.2 ms decode step at the DeepSeek-V3.2 widths).
 
     ``experts_sharded`` / ``valid_rows`` go to ``_moe_mlp`` (as in
     llama._run_layers: ModelStatics.sharded, a prefill's true_len)."""
@@ -734,12 +740,20 @@ def _run_layers(params: Params, kv: KVCache, x: jax.Array,
              "idx_k_norm_w", "idx_k_norm_b", "idx_w")
 
     quantized = kv["kv"].dtype == jnp.int8
+    k = cfg.first_k_dense if cfg.num_experts > 0 else 0
+    attn_stacks = {n: stack[n] for n in _ATTN if n in stack}
+    # two scans over one [L, ...] stack: it stays whole beside both and the
+    # body reads it at li; a single scan slices it as its own operand
+    whole_attn = attn_stacks if k > 0 else {}
 
     def make_layer(mlp_fn):
         def layer(carry, xs):
             h, pools = carry
             pool = pools["kv"]
-            lp, li = xs["lp"], xs["i"]
+            li = xs["i"]
+            lp = {**xs["lp"], **jax.tree.map(
+                lambda w: jax.lax.dynamic_index_in_dim(w, li, keepdims=False),
+                whole_attn)}
             hn = rms_norm(h, lp["ln1"], cfg.rms_norm_eps)
             q_nope, q_pe, qr = _q_proj(lp, hn, cfg)
             q_pe = apply_rope_interleaved(q_pe, positions, inv, att)
@@ -788,10 +802,8 @@ def _run_layers(params: Params, kv: KVCache, x: jax.Array,
 
     pools = dict(kv)
     if cfg.num_experts > 0:
-        k = cfg.first_k_dense
         if k > 0:
-            dense_lp = {n: stack[n][:k] for n in _ATTN if n in stack}
-            dense_lp["down"] = stack["dense_down"]
+            dense_lp = {"down": stack["dense_down"]}
             if "dense_gateup" in stack:   # fused (fuse_stacked_matmuls)
                 dense_lp["gateup"] = stack["dense_gateup"]
             else:
@@ -803,7 +815,7 @@ def _run_layers(params: Params, kv: KVCache, x: jax.Array,
                     cfg.hidden_act, gateup_w=lp.get("gateup"))),
                 (x, pools),
                 {"lp": dense_lp, "i": jnp.arange(k, dtype=jnp.int32)})
-        moe_lp = {n: stack[n][k:] for n in _ATTN if n in stack}
+        moe_lp = {} if whole_attn else dict(attn_stacks)
         for n in ("router", "router_bias", "moe_gate", "moe_up",
                   "moe_down", "moe_gateup", "sh_gate", "sh_up",
                   "sh_down", "sh_gateup"):
@@ -826,9 +838,9 @@ def _run_layers(params: Params, kv: KVCache, x: jax.Array,
                 hn, lp.get("gate"), lp.get("up"), lp["down"],
                 cfg.hidden_act, gateup_w=lp.get("gateup"))),
             (x, pools),
-            {"lp": {k: v for k, v in stack.items()
-                    if k in _ATTN or k in ("gate", "up", "down",
-                                           "gateup")},
+            {"lp": {**attn_stacks,
+                    **{n: stack[n] for n in ("gate", "up", "down", "gateup")
+                       if n in stack}},
              "i": jnp.arange(L, dtype=jnp.int32)})
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     return x, pools

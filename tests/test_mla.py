@@ -1494,12 +1494,16 @@ def kimi_ref():
         sys.path.remove(_BENCH)
 
 
-def _kimi_hf(**over) -> dict:
-    with open(os.path.join(_BENCH, "fixtures", "tiny-kimi-k2.json")) as f:
+def _fixture_hf(name: str, **over) -> dict:
+    with open(os.path.join(_BENCH, "fixtures", name + ".json")) as f:
         hf = json.load(f)
     for key in ("source", "reduced", "assumed", "deployment", "reference"):
         hf.pop(key)
     return dict(hf, **over)
+
+
+def _kimi_hf(**over) -> dict:
+    return _fixture_hf("tiny-kimi-k2", **over)
 
 
 def _kimi_setup(hf: dict, seed: int = 1):
@@ -1844,3 +1848,164 @@ async def test_kimi_k2_meshes_whole_model_yes_one_share_no():
         finally:
             await core.stop()
     assert tokens[1] == tokens[0]
+
+
+# ---------------------------------------------------------------------------
+# Hybrid models (a dense prefix, then expert layers): two scans over ONE
+# [L, ...] stack of every attention weight
+# ---------------------------------------------------------------------------
+
+_ATTN_STACKS = ("ln1", "ln2", "wq", "wq_a", "q_a_norm", "wq_b", "wkv_a",
+                "kv_norm", "wkv_b", "wo", "idx_wq_b", "idx_wk",
+                "idx_k_norm_w", "idx_k_norm_b", "idx_w")
+_DENSE_STACKS = ("dense_gate", "dense_up", "dense_down", "dense_gateup")
+_HYBRID_STACKS = {       # the fixture's attention stacks, by name
+    "tiny-deepseek-v2": {"ln1", "ln2", "wq", "wkv_a", "kv_norm", "wkv_b",
+                         "wo"},
+    "tiny-deepseek-v32": {"ln1", "ln2", "wq_a", "q_a_norm", "wq_b", "wkv_a",
+                          "kv_norm", "wkv_b", "wo", "idx_wq_b", "idx_wk",
+                          "idx_k_norm_w", "idx_k_norm_b", "idx_w"},
+    "tiny-kimi-k2": {"ln1", "ln2", "wq_a", "q_a_norm", "wq_b", "wkv_a",
+                     "kv_norm", "wkv_b", "wo"},
+}
+
+
+class _PerLayerLoop:
+    """The parent's arithmetic, plainly: a Python loop over the layers, the
+    layer's attention tensors taken as ``stack[n][i]`` (a static index, made
+    before the layer is traced) and the stacks of its own kind as
+    ``stack[n][i - first]``. Stands in for ``lax.scan`` over
+    ``{"lp", "i"}`` and for the body's ``lax.dynamic_index_in_dim``, and
+    notes every (leading dimension, layer) read. The layer's body still
+    compiles as ONE program, a scan of one, as the served body does: the
+    CPU fuses a compiled body otherwise than it runs the same operations
+    one by one, and the two differ in the last bit."""
+
+    def __init__(self, monkeypatch):
+        self.reads, self.layer = [], None
+        self.scan, self.index = jax.lax.scan, jax.lax.dynamic_index_in_dim
+        monkeypatch.setattr(jax.lax, "scan", self._scan)
+        monkeypatch.setattr(jax.lax, "dynamic_index_in_dim", self._index)
+
+    def _scan(self, f, init, xs=None, *a, **k):
+        if not (isinstance(xs, dict) and set(xs) == {"lp", "i"}):
+            return self.scan(f, init, xs, *a, **k)
+        carry = init
+        for j, li in enumerate(np.asarray(xs["i"])):
+            self.layer = int(li)
+            one = jax.tree.map(lambda w: w[j:j + 1], xs)
+            # a function of its own a layer: scan keeps the traced body of
+            # a function it has seen, with layer 0's tensors in it
+            carry, _ = self.scan(lambda c, x: f(c, x), carry, one)
+        self.layer = None
+        return carry, None
+
+    def _index(self, w, index, axis=0, keepdims=True):
+        if self.layer is None:
+            return self.index(w, index, axis, keepdims)
+        assert axis == 0 and not keepdims
+        self.reads.append((w.shape[0], self.layer))
+        return w[self.layer]
+
+
+def _three_chunks_and_a_decode_step(params, cfg, tokens):
+    """→ the logits behind a prefill of tokens[:16] (the first chunk: a
+    whole prompt), behind the third chunk of 16 over the cache of the two
+    before it, and of a decode step behind that."""
+    statics = ModelStatics(cfg=cfg, block_size=16, attn_impl="xla")
+    table = jnp.arange(1, 9, dtype=jnp.int32)
+    kv = mla.init_kv_cache(cfg, NUM_BLOCKS, 16, dtype=jnp.float32)
+    out = []
+    for lo in (0, 16, 32):
+        logits, kv = mla.prefill_forward(
+            params, kv, jnp.asarray(tokens[lo:lo + 16], jnp.int32),
+            table, jnp.asarray(lo), jnp.asarray(16), statics)
+        out.append(logits)
+    logits, kv = mla.decode_forward(
+        params, kv, jnp.asarray([tokens[48], 0]), jnp.asarray([48, 0]),
+        jnp.stack([table, jnp.zeros_like(table)]), statics)
+    return [np.asarray(x) for x in (out[0], out[2], logits[0])]
+
+
+@pytest.fixture
+def executables_dropped():
+    """Forwards run operation by operation, and a program a layer: a case
+    below leaves ~3,500 memory mappings of compiled code in the process
+    (measured; ``/proc/self/maps``), which may hold 65,530
+    (``vm.max_map_count``) — past it XLA's next compile is a segmentation
+    fault, and this file alone crossed it. Dropping JAX's caches unmaps
+    them (4,108 → 696 behind one case)."""
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("family,weights", [
+    (f, w) for f in sorted(_HYBRID_STACKS) for w in ("float32", "int8")
+] + [("two-dense", "float32")])
+def test_hybrid_scans_read_the_whole_stacks_like_a_per_layer_loop(
+        family, weights, monkeypatch, executables_dropped):
+    """A hybrid model's two scans take no ``stack[n][:k]`` / ``stack[n][k:]``
+    copy of an attention stack: the stacks stay whole beside them and the
+    body reads layer ``li`` in place. A prefill, a chunk of a chunked
+    prefill and a decode step behind it give logits BIT-equal to the per-layer loop over
+    ``stack[n][i]`` (``_PerLayerLoop``), on the engine's own parameter
+    tree, in float32 and with int8 weights (``q`` and ``scale`` are read at
+    the same layer). ``two-dense`` is DeepSeek-V2's fixture with two dense
+    and three expert layers, so that a layer's index among its kind and
+    among all layers differ on both sides.
+
+    The tree keeps its keys and shapes: the benchmark's references
+    (benchmark/reference.py, benchmark/references/deepseek_v32.py,
+    kimi_k2.py, deepseek_v2.py) take the ENGINE's parameters by name and
+    GLOBAL layer index — ``params["layers.wo"]`` is ``[L, H·v, D]`` and
+    layer ``li`` is ``[li]`` — so a tree re-laid by layer kind would break
+    ``correct`` in every MLA cell."""
+    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.engine.core import EngineCore
+    from dynamo_tpu.engine.quant import QuantizedArray
+    deep = family == "two-dense"
+    hf = (_fixture_hf("tiny-deepseek-v2", num_hidden_layers=5,
+                      first_k_dense_replace=2) if deep
+          else _fixture_hf(family))
+    cfg = ModelConfig.from_hf_config(hf)
+    L, k = cfg.num_layers, cfg.first_k_dense
+    assert 0 < k < L and cfg.num_experts > 0
+    core = EngineCore(cfg, EngineConfig(
+        max_model_len=256, kv_block_size=16, num_kv_blocks=32,
+        max_num_seqs=2, prefill_buckets=[64], seed=1,
+        quantization="none" if weights == "float32" else "int8"),
+        attn_impl="xla", param_dtype=jnp.float32)
+    params = core.params
+    # what the references read: every attention stack whole, [L, ...],
+    # under its name; one stack per layer kind for the rest
+    attn = _HYBRID_STACKS["tiny-deepseek-v2" if deep else family]
+    shapes = mla.param_shapes(cfg)
+    for tree in (shapes, {n: w.shape for n, w in params.items()}):
+        layers = {n[len("layers."):]: s for n, s in tree.items()
+                  if n.startswith("layers.")}
+        assert {n for n in layers if n in _ATTN_STACKS} == attn
+        for n, shape in layers.items():
+            assert shape[0] == (L if n in attn else
+                                k if n in _DENSE_STACKS else L - k), n
+    assert all(tuple(params["layers." + n].shape) == shapes["layers." + n]
+               for n in attn)
+    assert isinstance(params["layers.wo"], QuantizedArray) == (
+        weights == "int8")
+    assert not isinstance(params["layers.wkv_b"], QuantizedArray)
+
+    tokens = np.random.default_rng(5).integers(0, cfg.vocab_size, size=49)
+    got = _three_chunks_and_a_decode_step(params, cfg, tokens)
+    loop = _PerLayerLoop(monkeypatch)
+    want = _three_chunks_and_a_decode_step(params, cfg, tokens)
+    monkeypatch.undo()
+    # the loop read each whole stack (leading dimension L) at every layer,
+    # and nothing else through the index
+    assert {r[0] for r in loop.reads} == {L}
+    assert sorted({r[1] for r in loop.reads}) == list(range(L))
+    leaves = len(jax.tree.leaves(
+        {n: params["layers." + n] for n in attn}))
+    assert len(loop.reads) == 4 * L * leaves      # three chunks and a step
+    for name, a, b in zip(("prefill", "chunked prefill", "decode"),
+                          got, want):
+        assert np.isfinite(a).all() and a.std() > 0, name
+        assert np.array_equal(a, b), (name, float(np.abs(a - b).max()))

@@ -317,6 +317,112 @@ V32_NARROW = {
     "rms_norm_eps": 1e-6, "max_position_embeddings": 1024}
 
 
+def _benchmark_hf(path):
+    import json
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", path)) as f:
+        hf = json.load(f)
+    extras = ("source", "reduced", "assumed", "deployment",
+              "memory_analysis", "notes", "reference")
+    return {k: v for k, v in hf.items() if k not in extras}
+
+
+# kimi-k2.7-code's cell: table positions, prefill chunk, slots
+KIMI_TABLE, KIMI_CHUNK, KIMI_SLOTS = 25600, 1024, 16
+
+
+# The hybrid MLA models of this file's builds (a dense prefix, then expert
+# layers: mla._run_layers' two scans), each at two dense and three expert
+# layers — so that a stack of every layer (5), of one kind (2 or 3) and a
+# layer alone (1) differ in their leading dimension — with int8 weights, as
+# the benchmark's cells serve them.
+_HYBRID_DEPTH = {"num_hidden_layers": 5, "first_k_dense_replace": 2}
+_HYBRID_MODELS = ("deepseek_v32-narrow", "kimi-k2.7-code",
+                  "tiny-deepseek-v2")
+_HYBRID_COMPILED = {}       # (model, program) -> (cfg, params, kv, text)
+
+
+def _hybrid_lowered(model, program, place, attn_impl):
+    """→ (cfg, params, kv, the lowered ``program``: "prefill" or "decode").
+    ``place`` makes an argument's ShapeDtypeStruct from a shape and a
+    dtype. The narrow deepseek_v32 and the benchmark's tiny-deepseek-v2
+    through an engine's own ``_prefill_jit`` / ``_decode_k_jit``;
+    kimi-k2.7-code at the published widths and the cell's shapes (a
+    25,600-position table, a 1,024-token chunk, 16 slots) through the
+    model's forwards, nothing placed."""
+    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+    from dynamo_tpu.engine.core import EngineCore
+    from dynamo_tpu.engine.models import llama, mla
+    from dynamo_tpu.engine.quant import init_params_quantized
+    i32, f32 = jnp.int32, jnp.float32
+
+    def placed(tree):
+        return jax.tree.map(lambda x: place(x.shape, x.dtype), tree)
+
+    if model == "kimi-k2.7-code":
+        cfg = ModelConfig.from_hf_config(dict(
+            _benchmark_hf("configs/kimi-k2.7-code.json"), **_HYBRID_DEPTH))
+        bs, blocks, M = 16, 4096, KIMI_TABLE // 16
+        statics = llama.ModelStatics(cfg=cfg, block_size=bs,
+                                     attn_impl=attn_impl)
+        params = placed(jax.eval_shape(lambda: llama.fuse_stacked_matmuls(
+            dict(init_params_quantized(cfg, jax.random.PRNGKey(0))), cfg)))
+        kv = placed(jax.eval_shape(
+            lambda: mla.init_kv_cache(cfg, blocks, bs)))
+        if program == "prefill":
+            lowered = jax.jit(lambda p, c, t, bt, sp, tl: mla.prefill_forward(
+                p, c, t, bt, sp, tl, statics)).lower(
+                params, kv, place((KIMI_CHUNK,), i32), place((M,), i32),
+                place((), i32), place((), i32))
+        else:
+            lowered = jax.jit(lambda p, c, t, pos, bt: mla.decode_forward(
+                p, c, t, pos, bt, statics)).lower(
+                params, kv, place((KIMI_SLOTS,), i32),
+                place((KIMI_SLOTS,), i32), place((KIMI_SLOTS, M), i32))
+        return cfg, params, kv, lowered
+    # dense_down [k, F, D] apart from wo [L, H·v, D]: F is not H·v
+    hf = (dict(V32_NARROW, intermediate_size=768)
+          if model == "deepseek_v32-narrow"
+          else _benchmark_hf(f"fixtures/{model}.json"))
+    cfg = ModelConfig.from_hf_config(dict(hf, **_HYBRID_DEPTH))
+    B, M, T = 8, 16, 128
+    core = EngineCore(cfg, EngineConfig(
+        max_model_len=256, kv_block_size=16, num_kv_blocks=64,
+        max_num_seqs=B, prefill_buckets=[T], quantization="int8"),
+        attn_impl=attn_impl)
+    params, kv = placed((core.params, core.kv))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    key = place(key.shape, key.dtype)
+    if program == "prefill":
+        lowered = core._prefill_jit.lower(
+            params, kv, place((T,), i32), place((M,), i32), place((), i32),
+            place((), i32), key, place((), f32), place((), i32),
+            place((), f32))
+    else:
+        lowered = core._decode_k_jit.lower(
+            params, kv, place((B,), i32), place((B,), i32),
+            place((B, M), i32), place((B,), i32), place((B,), i32),
+            place((B,), f32), place((B,), i32), place((B,), f32),
+            place((1, B), i32), place((1, B), jnp.bool_), key)
+    return cfg, params, kv, lowered
+
+
+def _hybrid_compiled(model, program, one_chip, monkeypatch):
+    """→ (cfg, params, kv, the program's compiled text for the described
+    chip, kernels on), built once for the tests of this file that read it."""
+    from dynamo_tpu.engine.models import llama
+    if (model, program) not in _HYBRID_COMPILED:
+        # code that asks jax.devices() sees the CPU here
+        monkeypatch.setattr(A, "_on_tpu", lambda: True)
+        monkeypatch.setattr(llama, "_on_tpu", lambda: True)
+        cfg, params, kv, lowered = _hybrid_lowered(
+            model, program, lambda shape, dtype: jax.ShapeDtypeStruct(
+                shape, dtype, sharding=one_chip), "pallas")
+        _HYBRID_COMPILED[model, program] = (
+            cfg, params, kv, lowered.compile().as_text())
+    return _HYBRID_COMPILED[model, program]
+
+
 def test_sparse_attention_programs_carry_stable_names(one_chip, monkeypatch):
     """deepseek_v32 on models/mla.py: the engine's prefill and decode
     programs compile for the chip at narrow widths with both caches, and
@@ -327,34 +433,11 @@ def test_sparse_attention_programs_carry_stable_names(one_chip, monkeypatch):
     ONE packed key, engine/select_compact.py): under ``dsa_select`` no
     program sorts anything, by score or otherwise."""
     import re
-    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
-    from dynamo_tpu.engine.core import EngineCore
-    from dynamo_tpu.engine.models import llama
-    monkeypatch.setattr(A, "_on_tpu", lambda: True)
-    monkeypatch.setattr(llama, "_on_tpu", lambda: True)
-    cfg = ModelConfig.from_hf_config(V32_NARROW)
-    B, M, T = 8, 16, 128
-    core = EngineCore(cfg, EngineConfig(
-        max_model_len=256, kv_block_size=16, num_kv_blocks=64,
-        max_num_seqs=B, prefill_buckets=[T]), attn_impl="pallas")
-    assert list(core.kv) == ["kv", "idx"]
-
-    def s(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    params, kv = jax.tree.map(lambda x: s(x.shape, x.dtype),
-                              (core.params, core.kv))
-    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
-    i32, f32 = jnp.int32, jnp.float32
-    decode = core._decode_k_jit.lower(
-        params, kv, s((B,), i32), s((B,), i32), s((B, M), i32),
-        s((B,), i32), s((B,), i32), s((B,), f32), s((B,), i32),
-        s((B,), f32), s((1, B), i32), s((1, B), jnp.bool_),
-        s(key.shape, key.dtype)).compile().as_text()
-    prefill = core._prefill_jit.lower(
-        params, kv, s((T,), i32), s((M,), i32), s((), i32), s((), i32),
-        s(key.shape, key.dtype), s((), f32), s((), i32),
-        s((), f32)).compile().as_text()
+    _cfg, _params, kv, decode = _hybrid_compiled(
+        "deepseek_v32-narrow", "decode", one_chip, monkeypatch)
+    prefill = _hybrid_compiled(
+        "deepseek_v32-narrow", "prefill", one_chip, monkeypatch)[3]
+    assert set(kv) == {"kv", "idx"}
     for text, fn, top in ((decode, "decode_k", "decode"),
                           (prefill, "prefill", "prefill")):
         for scope in (f"jit({fn})/{top}/", "/lm_head/", "/sampling/",
@@ -724,16 +807,6 @@ def test_state_space_kernels_build_at_the_published_sizes(one_chip, kernel):
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
 
 
-def _benchmark_hf(path):
-    import json
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", path)) as f:
-        hf = json.load(f)
-    extras = ("source", "reduced", "assumed", "deployment",
-              "memory_analysis", "notes", "reference")
-    return {k: v for k, v in hf.items() if k not in extras}
-
-
 def _shape_dims(text):
     """Every array shape named in a compiled program's text, as tuples."""
     import re
@@ -747,50 +820,27 @@ def test_dense_latent_attention_builds_at_the_published_widths(
     """kimi-k2.7-code's prefill chunk and decode step (models/mla.py, no
     indexer) at the published widths and the cell's shapes — 64 heads, a
     25,600-position table, a 1,024-token chunk, 16 slots — at the depth of
-    one dense and one expert layer (the layers are scanned: depth adds
-    nothing to a build). The prefill walks the table by key blocks through
+    two dense and three expert layers (``_hybrid_lowered``; the layers are
+    scanned: depth adds nothing to a build), int8 weights. The prefill
+    walks the table by key blocks through
     the Pallas call ``mla_prefill`` inside a loop with a traced trip count;
     the decode reads the pool in the ``paged_attention`` kernel's one-head
     form at the latent wave depth (64 blocks). Neither holds an array of
     heads × chunk × table or of heads × table × head_dim in any layout or
     dtype."""
-    from dynamo_tpu.engine.config import ModelConfig
-    from dynamo_tpu.engine.models import llama, mla
-    monkeypatch.setattr(A, "_on_tpu", lambda: True)
-    monkeypatch.setattr(llama, "_on_tpu", lambda: True)
-    hf = dict(_benchmark_hf("configs/kimi-k2.7-code.json"),
-              num_hidden_layers=2)
-    cfg = ModelConfig.from_hf_config(hf)
+    from dynamo_tpu.engine.models import mla
+    cfg, _params, _kv, text = _hybrid_compiled(
+        "kimi-k2.7-code", program.split("-")[0], one_chip, monkeypatch)
     assert (cfg.num_heads, cfg.kv_lora_rank, cfg.hidden_size,
             cfg.router_width, cfg.num_experts) == (64, 512, 7168, 384, 12)
-    bs, blocks, S, T, B = 16, 4096, 25600, 1024, 16
-    M = S // bs
-    statics = llama.ModelStatics(cfg=cfg, block_size=bs, attn_impl="pallas")
-
-    def placed(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one_chip), tree)
-
-    params = placed(jax.eval_shape(
-        lambda: mla.init_params(cfg, jax.random.PRNGKey(0))))
-    kv = placed(jax.eval_shape(lambda: mla.init_kv_cache(cfg, blocks, bs)))
-    i32 = jnp.int32
-
-    def s(shape):
-        return jax.ShapeDtypeStruct(shape, i32, sharding=one_chip)
-
+    S, T = KIMI_TABLE, KIMI_CHUNK
+    assert program in (f"prefill-{T}", f"decode-B{KIMI_SLOTS}")
     if program.startswith("prefill"):
-        text = jax.jit(lambda p, c, t, bt, sp, tl: mla.prefill_forward(
-            p, c, t, bt, sp, tl, statics)).lower(
-            params, kv, s((T,)), s((M,)), s(()), s(())).compile().as_text()
         assert "mla_prefill" in text and "while" in text
     else:
-        text = jax.jit(lambda p, c, t, pos, bt: mla.decode_forward(
-            p, c, t, pos, bt, statics)).lower(
-            params, kv, s((B,)), s((B,)), s((B, M))).compile().as_text()
         assert "paged_attention" in text
         # a wave's double buffer: 2 x 1,024 rows of the pool
-        assert mla.latent_wave_blocks(bs) == 64
+        assert mla.latent_wave_blocks(16) == 64
     assert "tpu_custom_call" in text
     H, widths = cfg.num_heads, (128, 192, 256, 320)
     heads = {H} | {H * w for w in widths}
@@ -846,3 +896,110 @@ def test_other_families_never_reach_the_blocked_dense_prefill(
     core._prefill_jit.lower(
         params, kv, s((T,), i32), s((M,), i32), s((), i32), s((), i32),
         key, s((), f32), s((), i32), s((), f32))
+
+
+def _outside_fusions(text):
+    """(result dtype, result dims, opcode, line) of every instruction of a
+    compiled program's text that stands outside its fused computations: in
+    the entry, a loop's body or condition. What a fusion holds inside (a
+    layer's ``dynamic-slice`` of a stack among them) is read by the
+    fusion's consumer in place; what stands outside is an array of its own
+    in memory."""
+    import re
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", text))
+    inside = None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            inside = head.group(1)
+        elif line.startswith("}"):
+            inside = None
+        elif inside is not None and inside not in fused:
+            m = re.match(r"^\s+(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]*)\]\S* "
+                         r"([\w\-]+)\(", line)
+            if m:
+                yield (m.group(1), tuple(int(d) for d in m.group(2).split(",")
+                                         if d), m.group(3), line)
+
+
+def _layer_stacks(params, cfg):
+    """→ (the arrays of the attention stacks, which hold every layer:
+    leading dimension L; those of the stacks of one layer kind: k or
+    L - k), each {(dtype, shape)}, matrices only."""
+    L, k = cfg.num_layers, cfg.first_k_dense
+    assert len({1, k, L - k, L}) == 4
+    whole, kind = set(), set()
+    for name, w in params.items():
+        if not name.startswith("layers."):
+            continue
+        for x in jax.tree.leaves(w):
+            if x.ndim >= 3:
+                (whole if x.shape[0] == L else kind).add(
+                    (jnp.dtype(x.dtype).name, tuple(x.shape)))
+    assert whole and all(s[0] in (k, L - k) for _d, s in kind)
+    return whole, kind
+
+
+_HLO_DTYPES = {"int8": "s8", "bfloat16": "bf16", "float32": "f32"}
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+@pytest.mark.parametrize("model", _HYBRID_MODELS)
+def test_hybrid_scans_copy_no_part_of_an_attention_stack(
+        model, program, one_chip, monkeypatch):
+    """A hybrid MLA model's two scans (mla._run_layers: a dense prefix,
+    then expert layers) leave the ``[L, ...]`` attention stacks whole
+    beside them and read layer ``li`` in place. In the program compiled
+    for the chip no instruction outside the fusions — no ``slice``,
+    ``dynamic-slice``, ``copy``, nor a fusion of one — RESULTS in an array
+    of a stack's trailing shape behind a leading ``k`` or ``L - k`` (the
+    parent's ``stack[n][:k]`` / ``stack[n][k:]``: 4.1 of a 39.2 ms decode
+    step at the DeepSeek-V3.2 widths, PERF.md PR 36), and none in a layer
+    of an int8 stack alone: every int8 weight goes into its consumer's
+    fusion from where it lies. (``wkv_b``, bf16, is relaid a layer for
+    the absorbed einsums, as before: mla._split_wkv_b.)"""
+    cfg, params, _kv, text = _hybrid_compiled(model, program, one_chip,
+                                              monkeypatch)
+    L, k = cfg.num_layers, cfg.first_k_dense
+    whole, kind = _layer_stacks(params, cfg)
+    parts = {(d, (n,) + s[1:]) for d, s in whole for n in (k, L - k)}
+    layers = {(d, lead + s[1:]) for d, s in whole if d == "int8"
+              for lead in ((), (1,))
+              if not any(s[1:] == t[1:] for _d, t in kind)}
+    forbidden = {(_HLO_DTYPES[d], shape)
+                 for d, shape in (parts - kind) | layers}
+    seen = False
+    for dtype, dims, op, line in _outside_fusions(text):
+        if op in ("parameter", "get-tuple-element"):
+            continue
+        seen = True
+        assert (dtype, dims) not in forbidden, line[:300]
+    assert seen and "while" in text
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+@pytest.mark.parametrize("model", _HYBRID_MODELS)
+def test_hybrid_scans_lower_no_slice_of_an_attention_stack(model, program):
+    """The same programs as JAX lowers them here, for the CPU (no chip
+    described, no kernel): no ``stablehlo.slice`` takes an ``[L, ...]``
+    attention stack as its operand — vectors (``ln1``, the norms) and
+    scales among them — and each stack reaches a loop whole, where a
+    ``dynamic_slice`` reads the layer."""
+    import re
+    cfg, params, _kv, lowered = _hybrid_lowered(
+        model, program, jax.ShapeDtypeStruct, "xla")
+    L = cfg.num_layers
+    mlir = {"int8": "i8", "bfloat16": "bf16", "float32": "f32"}
+    stacks = {"tensor<" + "x".join(map(str, x.shape)) + "x"
+              + mlir[jnp.dtype(x.dtype).name] + ">"
+              for name, w in params.items() if name.startswith("layers.")
+              for x in jax.tree.leaves(w) if x.shape[0] == L}
+    assert len(stacks) >= 6
+    text = lowered.as_text()
+    sliced = [line for line in text.splitlines()
+              if re.search(r"stablehlo\.slice ", line)
+              and line.split(" : (")[-1].split(")")[0] in stacks]
+    assert not sliced, sliced[0][:300]
+    for stack in stacks:
+        assert re.search(r"stablehlo\.dynamic_slice .*\(" + re.escape(stack),
+                         text), stack
