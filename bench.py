@@ -736,7 +736,6 @@ def run_kv_remote_bench(mcfg) -> dict:
             # the warmup's XLA compile dominates the measured prefill
             # rate; reset and take one steady-state sample so the
             # admission model prices recompute honestly
-            core_c.prefill_wall_s = 0.0
             core_c.total_prefill_tokens = 0
             steady = [int(t) for t in rng.integers(
                 1, mcfg.vocab_size, size=prompt_len)]

@@ -132,6 +132,11 @@ class EngineRequest:
     # the lane admission itself) returned: the start of the trace's
     # engine.first_token span
     dispatched_time: Optional[float] = None
+    # when a slot and a KV plan existed for it (the end of its queue
+    # wait), and how many prefill dispatches that admission issued: the
+    # `first_token` flight record's stamps, overwritten by a recompute
+    admitted_time: Optional[float] = None
+    prefill_chunks: int = 0
     # the request's runtime Trace (runtime/tracing.py) — attached by
     # submit() from the ambient contextvar so the engine can feed
     # per-phase spans (queue wait, KV onboard incl. fabric fetch,
@@ -568,12 +573,10 @@ class EngineCore:
         # measured prefill rate feed for the fabric's admission gate and
         # the router's NetKV scoring: wall seconds spent in prefill
         # admissions (dispatch + host glue — an upper bound, so the
-        # modeled recompute it feeds is conservative). The cumulative
-        # totals stay for bench provenance; the RATE the gate prices
-        # with is age-weighted (fabric.PrefillRateEstimator) so XLA-
-        # compile-inflated early admissions on a young engine don't skew
-        # fetch-vs-recompute pricing.
-        self.prefill_wall_s = 0.0
+        # modeled recompute it feeds is conservative). The RATE the gate
+        # prices with is age-weighted (fabric.PrefillRateEstimator) so
+        # XLA-compile-inflated early admissions on a young engine don't
+        # skew fetch-vs-recompute pricing.
         from ..llm.kv.fabric import PrefillRateEstimator
         self.prefill_rate_estimator = PrefillRateEstimator()
         # ragged-dispatch stats (nv_llm_ragged_* metrics feed;
@@ -2128,7 +2131,8 @@ class EngineCore:
     def _admit_with_plan(self, req: EngineRequest, slot: int, plan,
                          onboard, remote_values=None) -> bool:
         n_prompt = len(req.prompt)
-        _t_admit = time.monotonic()
+        _t_admit = req.admitted_time = time.monotonic()
+        req.prefill_chunks = 0
         if req.trace is not None:
             # queue-wait phase on the request's fleet trace: enqueue →
             # the moment a slot + KV plan existed for it
@@ -2262,6 +2266,7 @@ class EngineCore:
             chunk = req.prompt[req.prefix_hit_tokens:]
             bucket = self.cfg.bucket_for(len(chunk))
             table = self._prefill_table(req.blocks, slot)
+            req.prefill_chunks = 1      # _chunked_prefill counts its own
             key = make_slot_keys(self.cfg.seed,
                                  jnp.asarray([req.sampling.seed]),
                                  jnp.asarray(req.key_step))[0]
@@ -2321,14 +2326,14 @@ class EngineCore:
                     self.statics, bucket, len(chunk))
             self.total_prefill_tokens += len(chunk)
             self.clock.admits += 1
+            self.clock.admit_tokens += len(chunk)
             # measured prefill rate (fabric admission gate + the
             # router's NetKV recompute model): wall time from plan to
             # dispatched prefill — an upper bound on the true compute
             # cost, so the modeled recompute stays conservative
             t_dispatched = time.monotonic()
-            admit_wall_s = t_dispatched - t0
-            self.prefill_wall_s += admit_wall_s
-            self.prefill_rate_estimator.observe(len(chunk), admit_wall_s)
+            self.prefill_rate_estimator.observe(len(chunk),
+                                                t_dispatched - t0)
             # defer the device→host fetch of the first token: it overlaps
             # the next decode dispatch instead of stalling the loop. Wire
             # handoff needs the host value immediately; DEVICE handoff
@@ -2508,6 +2513,7 @@ class EngineCore:
         C = self.cfg.prefill_chunk
         off = req.prefix_hit_tokens
         tok = logprob = None
+        req.prefill_chunks = -(-len(chunk) // C)
         for lo in range(0, len(chunk), C):
             piece = chunk[lo:lo + C]
             # the tail pads to C too: exactly ONE compiled prefill shape
@@ -2630,6 +2636,7 @@ class EngineCore:
         req.key_step += 1
         req.ready = False
         req.last_token = -1
+        req.dispatched_time = time.monotonic()   # no prefill to wait for
         self.disagg_stream_admits += 1
         if self.recorder is not None:
             self.recorder.rec(
@@ -2755,7 +2762,7 @@ class EngineCore:
             tenant=req.tenant or None)
         tok, logprob = int(pc.first_token), float(pc.first_logprob)
         req.last_token = tok
-        req.first_token_time = time.monotonic()
+        self._mark_first_token(req)
         req.ready = True
         if self.recorder is not None:
             self.recorder.rec("first_token", rid=req.rid, pf_seq=None,
@@ -3881,15 +3888,36 @@ class EngineCore:
     def _mark_first_token(self, req: EngineRequest) -> None:
         """The request's first token is about to be emitted (a no-op on
         every later call, a preempted request's recompute included): stamp
-        it, and put the wait since its admission's dispatch returned on
-        the request's trace. A lane admission has no prefill dispatch: its
-        span covers the prompt's ride through the decode batches."""
+        it, put the wait since its admission's dispatch returned on the
+        request's trace, and write the request's one ``first_token`` flight
+        record. A lane admission has no prefill dispatch: its wait covers
+        the prompt's ride through the decode batches. A request submitted
+        with no trace has no origin: the record holds the engine's three
+        stages and leaves ``server_ms`` / ``ingest_ms`` out."""
         if req.first_token_time is not None:
             return
-        req.first_token_time = time.monotonic()
-        if req.trace is not None and req.dispatched_time is not None:
-            req.trace.add_span("engine.first_token", req.dispatched_time,
-                               req.first_token_time)
+        now = req.first_token_time = time.monotonic()
+        trace, enqueued = req.trace, req.enqueue_time
+        admitted, dispatched = req.admitted_time, req.dispatched_time
+        if admitted is None or dispatched is None:
+            return                 # emitted by no admission path of ours
+        origin = {}
+        if trace is not None:
+            trace.add_span("engine.first_token", dispatched, now)
+            # the trace's origin (the front end's first byte; a worker's
+            # child trace inherits it over the wire) to the enqueue: the
+            # origin lies before this trace's own start by the two epochs
+            before = trace.start_epoch - trace.origin_ts - trace.start
+            origin = {"server_ms": round(1e3 * (before + now), 3),
+                      "ingest_ms": round(1e3 * (before + enqueued), 3)}
+        # one record a request, every field from two of its stamps: the
+        # four stages tile server_ms (docs/observability.md)
+        self.flight.record(
+            "first_token", rid=req.rid, prompt=len(req.prompt),
+            hit=req.prefix_hit_tokens, chunks=req.prefill_chunks, **origin,
+            queue_wait_ms=round(1e3 * (admitted - enqueued), 3),
+            prefill_ms=round(1e3 * (dispatched - admitted), 3),
+            first_token_wait_ms=round(1e3 * (now - dispatched), 3))
 
     def _emit(self, req: EngineRequest, token: int, logprob: float) -> None:
         req.emitted_total += 1

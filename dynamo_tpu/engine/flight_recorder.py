@@ -90,6 +90,7 @@ class PhaseClock:
         self._at_cycle_start = dict(self.seconds)
         self._annotation = None
         self.admits = 0      # prefill dispatches issued in the open cycle
+        self.admit_tokens = 0    # prompt tokens those dispatches prefilled
 
     def enter(self, phase: str) -> float:
         """Close the running phase and open ``phase`` at one timestamp
@@ -117,17 +118,19 @@ class PhaseClock:
     def close_cycle(self) -> Dict[str, float]:
         """End the open cycle now: ``<phase>_ms`` for each phase since the
         last close, ``cycle_ms`` between the two closes by the timestamps
-        alone (so a reader can check the tiling), and ``admits``. The
-        running phase carries on into the next cycle."""
+        alone (so a reader can check the tiling), ``admits`` and
+        ``admit_tokens``. The running phase carries on into the next
+        cycle."""
         now = self.enter(self.running)
         out = {f"{p}_ms": round(
             1e3 * (self.seconds[p] - self._at_cycle_start[p]), 3)
             for p in PHASES}
         out["cycle_ms"] = round(1e3 * (now - self._cycle_start), 3)
         out["admits"] = self.admits
+        out["admit_tokens"] = self.admit_tokens
         self._cycle_start = now
         self._at_cycle_start = dict(self.seconds)
-        self.admits = 0
+        self.admits = self.admit_tokens = 0
         return out
 
 
@@ -154,9 +157,10 @@ class FlightRecorder:
 
     def record_cycle(self, kind: str, **fields) -> None:
         """One ``decode`` / ``ragged`` / ``verify`` record, closing the
-        clock's cycle: the phase split, ``admits``, ``device_ms`` (the
-        cycle's ``wait``: what the loop blocked on the device) and
-        ``host_gap_ms`` (the rest of the harvest-to-harvest cycle)."""
+        clock's cycle: the phase split, ``admits`` / ``admit_tokens``,
+        ``device_ms`` (the cycle's ``wait``: what the loop blocked on the
+        device) and ``host_gap_ms`` (the rest of the harvest-to-harvest
+        cycle)."""
         split = self.clock.close_cycle()
         cycle_ms = split.pop("cycle_ms")
         self.record(kind, **fields, device_ms=split["wait_ms"],

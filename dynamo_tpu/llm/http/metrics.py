@@ -63,24 +63,27 @@ class ServiceMetrics:
     def render(self) -> bytes:
         return generate_latest(self.registry)
 
-    def inflight_guard(self, model: str, endpoint: str,
-                       streaming: bool) -> "InflightGuard":
-        return InflightGuard(self, model, endpoint, streaming)
+    def inflight_guard(self, model: str, endpoint: str, streaming: bool,
+                       start: Optional[float] = None) -> "InflightGuard":
+        return InflightGuard(self, model, endpoint, streaming, start)
 
 
 class InflightGuard:
     """RAII-style inflight/request-status guard (reference metrics.rs
     `InflightGuard`): create on request admission, call `mark_ok()` on clean
-    completion; anything else counts as error/cancelled on close."""
+    completion; anything else counts as error/cancelled on close. ``start``
+    (monotonic) is when the request's first byte arrived, where the caller
+    knows it: the duration and time-to-first-token histograms count from
+    there, so they hold what the client waited for."""
 
     def __init__(self, metrics: ServiceMetrics, model: str, endpoint: str,
-                 streaming: bool):
+                 streaming: bool, start: Optional[float] = None):
         self._m = metrics
         self.model = model
         self.endpoint = endpoint
         self.request_type = "stream" if streaming else "unary"
         self._status = REQUEST_STATUS_ERROR
-        self._start = time.monotonic()
+        self._start = time.monotonic() if start is None else start
         self._first_token_at: Optional[float] = None
         self._last_token_at: float = 0.0
         self._m.inflight.labels(model, endpoint).inc()

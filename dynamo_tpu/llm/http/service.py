@@ -18,6 +18,7 @@ import asyncio
 import json
 import logging
 import time
+import uuid
 from typing import Dict, Optional
 
 from aiohttp import web
@@ -102,8 +103,8 @@ class _FanoutContext(EngineContext):
 
     __slots__ = ("children",)
 
-    def __init__(self):
-        super().__init__()
+    def __init__(self, request_id: Optional[str] = None):
+        super().__init__(request_id)
         self.children: list = []
 
     def stop_generating(self) -> None:
@@ -224,6 +225,48 @@ async def _start_fanout(engine, body: dict, ectx: "_FanoutContext",
     return _merge_choice_streams(list(results), ectx)
 
 
+class _StampingHandler(web.RequestHandler):
+    """aiohttp's per-connection protocol, stamping when a request's first
+    bytes reached this process: ``received_at`` (monotonic) is set by the
+    first inbound segment while none is pending, read by ``_handle`` as
+    the start of the request's trace, and cleared when the request's
+    response is prepared (``HttpService._forget_stamp``) — after its whole
+    body was read, so the body's later segments stamp nothing and a
+    kept-alive connection's next request gets a stamp of its own."""
+
+    __slots__ = ("received_at",)
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self.received_at: Optional[float] = None
+
+    def data_received(self, data: bytes) -> None:
+        if self.received_at is None:
+            self.received_at = time.monotonic()
+        super().data_received(data)
+
+
+class _StampingServer(web.Server):
+    """``web.Server`` names its protocol class in ``__call__`` alone."""
+
+    def __call__(self) -> web.RequestHandler:
+        return _StampingHandler(self, loop=self._loop, **self._kwargs)
+
+
+class _StampingRunner(web.AppRunner):
+    """The one private seam (aiohttp 3.13; pinned by
+    tests/test_ttft_timeline.py): ``_make_server`` is where the runner
+    gets its protocol factory, so the ``Server`` the application built is
+    rebuilt as one that makes stamping handlers."""
+
+    async def _make_server(self) -> web.Server:
+        built = await super()._make_server()
+        return _StampingServer(
+            built.request_handler, request_factory=built.request_factory,
+            handler_cancellation=built.handler_cancellation,
+            loop=built._loop, **built._kwargs)
+
+
 class HttpService:
     """The frontend server (reference `HttpService` service_v2 builder)."""
 
@@ -243,6 +286,7 @@ class HttpService:
         self.app.router.add_get("/live", self._health)
         self.app.router.add_get("/traces", self._traces)
         self.app.router.add_get("/debug", self._debug)
+        self.app.on_response_prepare.append(self._forget_stamp)
         self._runner: Optional[web.AppRunner] = None
         self._site: Optional[web.TCPSite] = None
 
@@ -250,7 +294,7 @@ class HttpService:
     async def start(self) -> None:
         if self._runner is not None:
             return  # already serving (run_forever after start is fine)
-        self._runner = web.AppRunner(self.app)
+        self._runner = _StampingRunner(self.app)
         await self._runner.setup()
         self._site = web.TCPSite(self._runner, self.host, self.port)
         await self._site.start()
@@ -334,21 +378,48 @@ class HttpService:
     async def _completions(self, request: web.Request) -> web.StreamResponse:
         return await self._handle(request, "completions")
 
+    @staticmethod
+    async def _forget_stamp(request: web.Request, response) -> None:
+        """on_response_prepare, every route: the request is read, so the
+        connection's next inbound segment opens the next request."""
+        if isinstance(request.protocol, _StampingHandler):
+            request.protocol.received_at = None
+
     async def _handle(self, request: web.Request,
                       endpoint: str) -> web.StreamResponse:
-        try:
-            body = await request.json()
-        except json.JSONDecodeError as e:
-            return _error_response(400, f"invalid JSON body: {e}")
+        # per-request trace (reference egress/push.rs:134-151): stage
+        # latencies from the request's first byte through dispatch to the
+        # last byte written, keyed by the request id the control plane
+        # carries everywhere. It opens FIRST, at the protocol's stamp (a
+        # server that is not ours has none: now): http.wire is the parser
+        # and the event-loop hops to this task
+        ftrace = Trace(uuid.uuid4().hex, role="frontend",
+                       start=getattr(request.protocol, "received_at", None))
+        ftrace.add_span("http.wire", ftrace.start, time.monotonic())
+        with use_trace(ftrace):
+            return await self._serve(request, endpoint, ftrace)
+
+    async def _serve(self, request: web.Request, endpoint: str,
+                     ftrace: Trace) -> web.StreamResponse:
+        def refuse(status: int, message: str,
+                   err_type: str = "invalid_request_error"):
+            ftrace.set_error(message)
+            return _error_response(status, message, err_type)
+
+        with ftrace.span("http.read_body") as read:
+            try:
+                body = await request.json()
+            except json.JSONDecodeError as e:
+                return refuse(400, f"invalid JSON body: {e}")
         model = body.get("model")
         if not model:
-            return _error_response(400, "missing 'model'")
+            return refuse(400, "missing 'model'")
         is_chat = endpoint == "chat_completions"
         engine = (self.manager.chat_engine(model) if is_chat
                   else self.manager.completion_engine(model))
         if engine is None:
-            return _error_response(
-                404, f"model '{model}' not found", "model_not_found")
+            return refuse(404, f"model '{model}' not found",
+                          "model_not_found")
         raw_n = body.get("n")
         if raw_n is None:
             n_choices = 1
@@ -356,13 +427,12 @@ class HttpService:
             n_choices = raw_n
         else:
             # 2.9 must not silently truncate to 2, nor true to 1
-            return _error_response(400, "'n' must be an integer")
+            return refuse(400, "'n' must be an integer")
         if not 1 <= n_choices <= MAX_N:
-            return _error_response(
-                400, f"'n' must be between 1 and {MAX_N}")
+            return refuse(400, f"'n' must be between 1 and {MAX_N}")
         streaming = bool(body.get("stream", False))
-        guard = self.metrics.inflight_guard(model, endpoint, streaming)
-        ectx = EngineContext() if n_choices == 1 else _FanoutContext()
+        ectx = (EngineContext(ftrace.request_id) if n_choices == 1
+                else _FanoutContext(ftrace.request_id))
         # multi-tenant identity (llm/tenancy.py): tenant + QoS class ride
         # the EngineContext so egress stamps them on the request-plane
         # control message (codec.RequestControlMessage tenant/priority)
@@ -375,44 +445,41 @@ class HttpService:
         # X-Request-Deadline-Ms header arms a budget that rides the
         # request plane (codec.RequestControlMessage.deadline_ms) all
         # the way into the engine's per-tick cancellation sweep
-        deadline_ms = ((body.get("nvext") or {}).get("deadline_ms")
+        deadline_ms = (nvext.get("deadline_ms")
                        or request.headers.get("X-Request-Deadline-Ms"))
         if deadline_ms is not None:
             try:
                 ectx.set_deadline_ms(float(deadline_ms))
             except (TypeError, ValueError):
-                return _error_response(
-                    400, f"invalid deadline_ms: {deadline_ms!r}")
-        # per-request trace (reference egress/push.rs:134-151): stage
-        # latencies from HTTP ingress through dispatch to last byte, keyed
-        # by the request id the control plane already carries everywhere
-        with use_trace(Trace(ectx.id, role="frontend")) as ftrace:
-            with span("dispatch", model=model, endpoint=endpoint):
-                try:
-                    if n_choices == 1:
-                        stream = await engine.generate(Context(body, ectx))
-                    else:
-                        stream = await _start_fanout(engine, body, ectx,
-                                                     n_choices)
-                except ValueError as e:
-                    ftrace.set_error(str(e))
-                    guard.close()
-                    return _error_response(400, str(e))
-                except Exception as e:  # noqa: BLE001 — engine boundary
-                    ftrace.set_error(str(e))
-                    logger.exception("engine error on %s", endpoint)
-                    guard.close()
-                    return _error_response(
-                        500, f"engine error: {e}", "internal_error")
+                return refuse(400, f"invalid deadline_ms: {deadline_ms!r}")
+        # the operator's histograms count from the first byte too: what
+        # the client waited for, not what followed the parse
+        guard = self.metrics.inflight_guard(model, endpoint, streaming,
+                                            start=ftrace.start)
+        ftrace.add_span("http.validate", read.end, time.monotonic())
+        with span("dispatch", model=model, endpoint=endpoint):
+            try:
+                if n_choices == 1:
+                    stream = await engine.generate(Context(body, ectx))
+                else:
+                    stream = await _start_fanout(engine, body, ectx,
+                                                 n_choices)
+            except ValueError as e:
+                guard.close()
+                return refuse(400, str(e))
+            except Exception as e:  # noqa: BLE001 — engine boundary
+                logger.exception("engine error on %s", endpoint)
+                guard.close()
+                return refuse(500, f"engine error: {e}", "internal_error")
 
-            if streaming:
-                include_usage = bool((body.get("stream_options") or {})
-                                     .get("include_usage"))
-                with span("stream"):
-                    return await self._stream_sse(request, stream, ectx,
-                                                  guard, include_usage)
-            with span("aggregate"):
-                return await self._unary(stream, ectx, guard, is_chat)
+        if streaming:
+            include_usage = bool((body.get("stream_options") or {})
+                                 .get("include_usage"))
+            with span("stream"):
+                return await self._stream_sse(request, stream, ectx,
+                                              guard, include_usage)
+        with span("aggregate"):
+            return await self._unary(stream, ectx, guard, is_chat)
 
     async def _unary(self, stream, ectx: EngineContext, guard,
                      is_chat: bool) -> web.Response:

@@ -201,11 +201,12 @@ class OpenAIPreprocessor(Operator):
     async def generate(self, request: SingleIn, next_engine: AsyncEngine) -> ManyOut:
         from ..runtime.tracing import span
         req = request.data
-        if isinstance(req, dict):
-            req = (ChatCompletionRequest.model_validate(req)
-                   if "messages" in req else CompletionRequest.model_validate(req))
-        is_chat = isinstance(req, ChatCompletionRequest)
+        is_chat = ("messages" in req if isinstance(req, dict)
+                   else isinstance(req, ChatCompletionRequest))
         with span("preprocess", chat=is_chat):
+            if isinstance(req, dict):
+                req = (ChatCompletionRequest if is_chat
+                       else CompletionRequest).model_validate(req)
             if is_chat:
                 pre, formatted_prompt = self._preprocess_chat(req)
             else:

@@ -103,14 +103,20 @@ class Trace:
     def __init__(self, request_id: str, role: str = "",
                  trace_id: Optional[str] = None,
                  parent_span: Optional[str] = None,
-                 origin_ts: Optional[float] = None):
+                 origin_ts: Optional[float] = None,
+                 start: Optional[float] = None):
         self.request_id = request_id
         self.role = role
         self.trace_id = trace_id or _new_id()
         self.span_id = _new_id(6)
         self.parent_span = parent_span
-        self.start = time.monotonic()
-        self.start_epoch = time.time()
+        # `start` (monotonic): a moment already past at which the trace
+        # begins — the HTTP front end's stamp of the request's first byte.
+        # The wall-clock anchors are derived from it, so the wire context
+        # every downstream process inherits is anchored there too.
+        now = time.monotonic()
+        self.start = now if start is None else start
+        self.start_epoch = time.time() - (now - self.start)
         # origin_ts: wall clock at the ORIGIN root's start; roots anchor
         # themselves, children inherit the wire value
         self.origin_ts = self.start_epoch if origin_ts is None else origin_ts

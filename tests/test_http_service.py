@@ -369,3 +369,35 @@ async def test_unary_logprobs_folded(logprob_service):
     lp = out["choices"][0]["logprobs"]
     assert lp["token_logprobs"] == [-0.5, -0.25]
     assert lp["tokens"] == ["he", "llo"]
+
+
+@pytest.mark.asyncio
+async def test_histograms_count_from_the_first_byte(service):
+    """ISSUE 39: a request whose body trickles in is timed from its first
+    byte, not from the handler's parse of the whole body: the operator's
+    time-to-first-token and duration histograms hold what the client
+    waited for."""
+    payload = json.dumps({
+        "model": "echo", "stream": True,
+        "messages": [{"role": "user", "content": "a b c"}]}).encode()
+    head = (f"POST /v1/chat/completions HTTP/1.1\r\nHost: x\r\n"
+            f"Content-Type: application/json\r\n"
+            f"Content-Length: {len(payload)}\r\n"
+            f"Connection: close\r\n\r\n").encode()
+    reader, writer = await asyncio.open_connection("127.0.0.1", service.port)
+    writer.write(head + payload[:10])
+    await writer.drain()
+    await asyncio.sleep(0.3)
+    writer.write(payload[10:])
+    await writer.drain()
+    answer = await reader.read()
+    writer.close()
+    assert answer.startswith(b"HTTP/1.1 200") and b"[DONE]" in answer
+    sums = {line.split("{")[0]: float(line.rsplit(" ", 1)[1])
+            for line in service.metrics.render().decode().splitlines()
+            if line.startswith("nv_llm_http_service_")
+            and "_seconds_sum{" in line}
+    assert sums["nv_llm_http_service_time_to_first_token_seconds_sum"] >= 0.3
+    assert sums["nv_llm_http_service_request_duration_seconds_sum"] >= 0.3
+    # the gap between tokens is not moved by it
+    assert sums["nv_llm_http_service_inter_token_latency_seconds_sum"] < 0.3

@@ -40,7 +40,8 @@ def test_phases_tile_the_cycle():
     spent = sum(clock.seconds[p] - before[p] for p in PHASES)
     assert abs(spent - (t1 - t0)) < 1e-6           # to 1 µs
     split = clock.close_cycle()
-    assert set(split) == {f"{p}_ms" for p in PHASES} | {"cycle_ms", "admits"}
+    assert set(split) == {f"{p}_ms" for p in PHASES} | {
+        "cycle_ms", "admits", "admit_tokens"}
     # ten values rounded to a microsecond each
     assert abs(sum(split[f"{p}_ms"] for p in PHASES)
                - split["cycle_ms"]) < 0.01
@@ -95,6 +96,24 @@ def test_record_cycle_carries_the_split_and_counts_admits():
     assert "cycle_ms" not in rec
     fr.record_cycle("decode", K=1, batch_fill=3)
     assert fr.dump()[-1]["admits"] == 0
+
+
+def test_close_cycle_returns_and_resets_the_admitted_tokens():
+    """``admit_tokens`` beside ``admits``: the prompt tokens the open
+    cycle's prefill dispatches took, closed into the cycle's record."""
+    fr = FlightRecorder(capacity=4)
+    fr.clock.admits += 2
+    fr.clock.admit_tokens += 700 + 41
+    split = fr.clock.close_cycle()
+    assert (split["admits"], split["admit_tokens"]) == (2, 741)
+    assert (fr.clock.admits, fr.clock.admit_tokens) == (0, 0)
+    fr.clock.admits += 1
+    fr.clock.admit_tokens += 5
+    fr.record_cycle("ragged", K=1, batch_fill=1)
+    fr.record_cycle("verify", K=1, batch_fill=1)
+    ragged, verify = fr.dump()[-2:]
+    assert (ragged["admits"], ragged["admit_tokens"]) == (1, 5)
+    assert (verify["admits"], verify["admit_tokens"]) == (0, 0)
 
 
 # ------------------------------------------------- every dispatch path's record

@@ -67,6 +67,29 @@ async def test_wire_context_opens_child_trace():
     assert TraceContext.from_dict({"parent_span": "zz"}) is None
 
 
+async def test_trace_started_in_the_past_anchors_its_wire_context_there():
+    """ISSUE 39: ``start`` (monotonic) is the HTTP front end's stamp of
+    the request's first byte; the wall-clock anchors are derived from it,
+    so a downstream child's offset counts from the first byte too."""
+    import time
+    stamp = time.monotonic() - 0.25
+    wall = time.time()
+    root = Trace("r", role="frontend", start=stamp)
+    assert root.start == stamp
+    assert root.start_epoch == pytest.approx(wall - 0.25, abs=0.01)
+    assert root.origin_ts == root.start_epoch
+    root.add_span("http.wire", stamp, stamp + 0.2)
+    d = root.to_dict()
+    assert d["spans"][0]["at_ms"] == 0.0 and d["total_ms"] >= 250.0
+    child = Trace.from_wire(root.wire_context(), "r", role="worker")
+    assert child.origin_ts == root.origin_ts
+    assert child.to_dict()["origin_offset_ms"] == pytest.approx(250.0, abs=15)
+    # with no start a trace begins now, as it always did
+    now = Trace("n")
+    assert now.start_epoch == pytest.approx(time.time(), abs=0.01)
+    assert time.monotonic() - now.start < 0.01
+
+
 async def test_log_sampling_counts_dropped_lines(caplog):
     """Satellite: at fleet QPS one INFO line per request is log-spam.
     log_every=N logs every Nth; slow/errored traces ALWAYS log; skips
