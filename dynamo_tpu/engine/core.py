@@ -161,6 +161,30 @@ class EngineRequest:
 
 _FINISH = object()  # queue sentinel
 
+# The most event-loop iterations one engine cycle's yield runs. A request on
+# a new connection needs six or seven of them from the poll that finds it to
+# its submit(): accept, the accepted transport's task, connection_made and
+# add_reader, the first read, the handler task's wake-up, a body that came
+# in a second segment, the pipeline's hand-over. Twice that, so a chain that
+# grows a hop still fits one cycle. The bound is what lets a task that spins
+# on ``await asyncio.sleep(0)`` beside the engine (the KV tiers' pumps, the
+# router indexer's drain) share the loop: beside one the ready queue never
+# empties, and an unbounded drain would never dispatch again.
+YIELD_DRAIN_MAX_ITERS = 16
+
+
+def _loop_is_quiet(loop: asyncio.AbstractEventLoop) -> bool:
+    """Nothing else is ready to run on ``loop`` right now, asked by a task
+    that has just resumed from ``asyncio.sleep(0)``. CPython's
+    ``BaseEventLoop`` keeps its ready handles in ``_ready``: the caller's
+    own has been popped, what this iteration's poll found and what its
+    callbacks scheduled lie behind it. A loop that keeps no such queue
+    (uvloop, a test's loop) reads as quiet: one iteration a yield."""
+    try:
+        return len(loop._ready) == 0        # type: ignore[attr-defined]
+    except (AttributeError, TypeError):
+        return True
+
 
 class EngineCore:
     """The model-executing scheduler. Owns params + KV cache on device."""
@@ -1552,8 +1576,21 @@ class EngineCore:
                 except asyncio.TimeoutError:
                     pass
             else:
-                await asyncio.sleep(0)  # let producers/consumers run
+                await self._yield_until_quiet()
         logger.info("engine loop stopped")
+
+    async def _yield_until_quiet(self) -> None:
+        """Let producers and consumers run until the shared event loop is
+        quiet: one iteration polls the sockets once and runs what was
+        ready, and what those callbacks schedule runs in the next, so a
+        chain of hops (a new connection up to its ``submit()``) crosses
+        inside this yield, not one engine cycle a hop. At most
+        ``YIELD_DRAIN_MAX_ITERS`` iterations."""
+        for _ in range(YIELD_DRAIN_MAX_ITERS):
+            await asyncio.sleep(0)
+            self.clock.yield_iters += 1
+            if _loop_is_quiet(self._loop):
+                break
 
     # --------------------------------------------------------------- defrag
     def _maybe_defrag(self) -> bool:

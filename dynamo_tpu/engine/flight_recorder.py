@@ -67,7 +67,9 @@ _ids = itertools.count()
 #             finishes, the flight record itself
 #   complete  deferred admissions and tier onboards (minus their wait)
 #   yield     what the loop gives the shared event loop (HTTP, detokeniser,
-#             SSE), and its idle wait
+#             SSE): a drain of the loop until it is quiet (EngineCore.
+#             _yield_until_quiet counts its iterations in ``yield_iters``),
+#             and the idle wait
 PHASES = ("sweep", "admit", "build", "dispatch", "wait", "post", "complete",
           "yield")
 
@@ -91,6 +93,7 @@ class PhaseClock:
         self._annotation = None
         self.admits = 0      # prefill dispatches issued in the open cycle
         self.admit_tokens = 0    # prompt tokens those dispatches prefilled
+        self.yield_iters = 0     # event-loop iterations its yield has run
 
     def enter(self, phase: str) -> float:
         """Close the running phase and open ``phase`` at one timestamp
@@ -118,9 +121,9 @@ class PhaseClock:
     def close_cycle(self) -> Dict[str, float]:
         """End the open cycle now: ``<phase>_ms`` for each phase since the
         last close, ``cycle_ms`` between the two closes by the timestamps
-        alone (so a reader can check the tiling), ``admits`` and
-        ``admit_tokens``. The running phase carries on into the next
-        cycle."""
+        alone (so a reader can check the tiling), ``admits``,
+        ``admit_tokens`` and ``yield_iters``. The running phase carries on
+        into the next cycle."""
         now = self.enter(self.running)
         out = {f"{p}_ms": round(
             1e3 * (self.seconds[p] - self._at_cycle_start[p]), 3)
@@ -128,9 +131,10 @@ class PhaseClock:
         out["cycle_ms"] = round(1e3 * (now - self._cycle_start), 3)
         out["admits"] = self.admits
         out["admit_tokens"] = self.admit_tokens
+        out["yield_iters"] = self.yield_iters
         self._cycle_start = now
         self._at_cycle_start = dict(self.seconds)
-        self.admits = self.admit_tokens = 0
+        self.admits = self.admit_tokens = self.yield_iters = 0
         return out
 
 
@@ -157,10 +161,10 @@ class FlightRecorder:
 
     def record_cycle(self, kind: str, **fields) -> None:
         """One ``decode`` / ``ragged`` / ``verify`` record, closing the
-        clock's cycle: the phase split, ``admits`` / ``admit_tokens``,
-        ``device_ms`` (the cycle's ``wait``: what the loop blocked on the
-        device) and ``host_gap_ms`` (the rest of the harvest-to-harvest
-        cycle)."""
+        clock's cycle: the phase split, ``admits`` / ``admit_tokens`` /
+        ``yield_iters``, ``device_ms`` (the cycle's ``wait``: what the loop
+        blocked on the device) and ``host_gap_ms`` (the rest of the
+        harvest-to-harvest cycle)."""
         split = self.clock.close_cycle()
         cycle_ms = split.pop("cycle_ms")
         self.record(kind, **fields, device_ms=split["wait_ms"],

@@ -492,7 +492,10 @@ async def test_engine_defrag_restores_contiguity(tiny_model_dir):
                             sampling=SlotSampling(temperature=0.0),
                             max_new_tokens=24, eos_ids=frozenset())
         await core.submit(req)
-        while req.slot < 0:                 # admitted (fragmented)
+        # admitted (fragmented) and decoding: a step is in flight from the
+        # first decode dispatch on, so the pass below has one to harvest
+        # (a pass that finds nothing in flight drains nothing)
+        while req.slot < 0 or core._pending is None:
             await asyncio.sleep(0.005)
         assert pool.count_runs(
             core.slots[req.slot].blocks) >= 2

@@ -41,7 +41,7 @@ def test_phases_tile_the_cycle():
     assert abs(spent - (t1 - t0)) < 1e-6           # to 1 µs
     split = clock.close_cycle()
     assert set(split) == {f"{p}_ms" for p in PHASES} | {
-        "cycle_ms", "admits", "admit_tokens"}
+        "cycle_ms", "admits", "admit_tokens", "yield_iters"}
     # ten values rounded to a microsecond each
     assert abs(sum(split[f"{p}_ms"] for p in PHASES)
                - split["cycle_ms"]) < 0.01

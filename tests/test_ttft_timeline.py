@@ -297,9 +297,9 @@ def first_token(server, ingest, queue, prefill, wait):
             "first_token_wait_ms": wait}
 
 
-def cycle(ms, admits=0, tokens=0, kind="decode"):
+def cycle(ms, admits=0, tokens=0, kind="decode", iters=1):
     return {"kind": kind, "t": 0.0, "device_ms": 1.0, "host_gap_ms": ms - 1.0,
-            "admits": admits, "admit_tokens": tokens}
+            "admits": admits, "admit_tokens": tokens, "yield_iters": iters}
 
 
 UNTRACED = {k: v for k, v in first_token(0, 0, 2.0, 8.0, 30.0).items()
@@ -312,10 +312,11 @@ FLIGHT = [
     {"kind": "prefill", "t": 0.0, "host_ms": 30.0, "queue_wait_ms": 1.0},
     # nineteen quiet cycles of 16 ms, one that dispatched two prefills of
     # 1,500 tokens in all (40 ms) and the one after it, which waited the
-    # prefills out (66 ms); a verify cycle is not a decode cycle
+    # prefills out (66 ms) and whose yield drained a new connection's seven
+    # iterations; a verify cycle is not a decode cycle
     *[cycle(16.0) for _ in range(19)],
-    cycle(40.0, admits=2, tokens=1500), cycle(66.0),
-    cycle(500.0, kind="verify"),
+    cycle(40.0, admits=2, tokens=1500), cycle(66.0, iters=7),
+    cycle(500.0, kind="verify", iters=16),
 ]
 SPANS = [
     {"request_id": "a", "role": "frontend", "spans": [
@@ -348,6 +349,7 @@ EMPTY = {"flight": [], "spans": [], "load": {"ttft_ms": []}}
     ("step.cycle_p95_ms", 40.0),            # the 20th of 21 by rank
     ("step.admit_excess_ms_per_ktok",
      (19 * 16.0 + 40.0 + 66.0 - 21 * 16.0) / 1.5),
+    ("loop.yield_iters", (20 * 1 + 7) / 21),    # a mean: the median reads 1
 ])
 def test_benchmark_reader(name, want):
     read = reader(name)
@@ -379,7 +381,8 @@ def test_benchmark_lists_every_reader_in_both_groups():
     entries = {m["name"]: m for m in bench["per_layer"]}
     for name in ("ttft.server_ms", "ttft.ingest_ms", "ttft.prefill_ms",
                  "ttft.unaccounted_pct", "ttft.unseen_ms", "http.wire_ms",
-                 "step.cycle_p95_ms", "step.admit_excess_ms_per_ktok"):
+                 "step.cycle_p95_ms", "step.admit_excess_ms_per_ktok",
+                 "loop.yield_iters"):
         assert os.path.exists(os.path.join(
             ROOT, "benchmark", "layer_metrics", f"{name}.py"))
         assert entries[f"{name}.open"]["workloads"] == ["mistral-7b.chat-open"]
