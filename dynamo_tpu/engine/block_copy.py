@@ -76,35 +76,52 @@ def _pad_pow2(n: int) -> int:
     return p
 
 
+# arrays of a cache's WINDOW group (llm/kv/hybrid.py), whose blocks carry
+# ids of a pool of their own: models/mla.py dots3_note's window layers
+WINDOW_GROUP = ("win",)
+
+
 @functools.partial(jax.jit, static_argnames=("block_size",),
                    donate_argnums=(0,))
 def _move_blocks(kv: KVCache, src_ids: jax.Array, dst_ids: jax.Array,
+                 win_src: jax.Array, win_dst: jax.Array,
                  block_size: int) -> KVCache:
-    def one(arr: jax.Array) -> jax.Array:
+    def one(name: str, arr: jax.Array) -> jax.Array:
         if arr.ndim != 3:
             # a per-slot array of a hybrid cache (models/sambay.py: window
             # rings, recurrent state): it holds no blocks, nothing moves
             return arr
+        # every array of a group moves under that group's ids
+        src, dst = ((win_src, win_dst) if name in WINDOW_GROUP
+                    else (src_ids, dst_ids))
         L, _T, HD = arr.shape
         paged = arr.reshape(L, -1, block_size, HD)
-        vals = jnp.take(paged, src_ids, axis=1)
-        paged = paged.at[:, dst_ids].set(vals)
+        vals = jnp.take(paged, src, axis=1)
+        paged = paged.at[:, dst].set(vals)
         return paged.reshape(L, -1, HD)
 
-    return {k: one(v) for k, v in kv.items()}
+    return {k: one(k, v) for k, v in kv.items()}
 
 
-def move_blocks(kv: KVCache, src_ids, dst_ids, block_size: int) -> KVCache:
+def move_blocks(kv: KVCache, src_ids, dst_ids, block_size: int,
+                win_src=(), win_dst=()) -> KVCache:
     """On-device block migration src→dst inside the same paged pool (the
     defrag pass, engine/core.py _maybe_defrag): gather + in-place scatter
     in ONE donated jit, never staging through the host. Id counts pad to
     a power of two with trash-block self-copies (block 0 → block 0, its
-    content is never read) so XLA compiles O(log n) programs."""
-    n = len(src_ids)
-    pad = _pad_pow2(n) - n
-    src = jnp.asarray(np.asarray(list(src_ids) + [0] * pad, np.int32))
-    dst = jnp.asarray(np.asarray(list(dst_ids) + [0] * pad, np.int32))
-    return _move_blocks(kv, src, dst, block_size)
+    content is never read) so XLA compiles O(log n) programs.
+    ``win_src`` / ``win_dst`` (at most as many): the same for the arrays of
+    the window group, under that pool's ids, in the same program."""
+    n = _pad_pow2(len(src_ids))
+    if len(win_src) > n:
+        raise ValueError("more window-group moves than paged moves")
+
+    def ids(xs):
+        return jnp.asarray(np.asarray(list(xs) + [0] * (n - len(xs)),
+                                      np.int32))
+
+    return _move_blocks(kv, ids(src_ids), ids(dst_ids), ids(win_src),
+                        ids(win_dst), block_size)
 
 
 def to_wire_format(picked: np.ndarray, num_heads: int) -> np.ndarray:

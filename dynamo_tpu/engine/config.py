@@ -59,7 +59,7 @@ def _rope_type(raw_rs: Dict[str, Any]) -> str:
 # model_types whose configs may carry routed experts (from_hf_config refuses
 # any other that does, by name)
 MOE_FAMILIES = ("mixtral", "qwen2_moe", "qwen3_moe", "deepseek_v2",
-                "deepseek_v3", "deepseek_v32", "kimi_k2")
+                "deepseek_v3", "deepseek_v32", "kimi_k2", "dots3_note")
 
 
 @dataclasses.dataclass
@@ -159,6 +159,24 @@ class ModelConfig:
     # half's attention layers). Norms are LayerNorm with bias
     # (rms_norm_eps holds layer_norm_eps); there is no positional
     # encoding. Layer kinds by index: sambay.layer_kinds.
+    # dots3_note (models/mla.py, docs/hybrid_cache.md): a second latent-
+    # attention geometry for the layers whose layer_types entry is
+    # "sliding_attention" (the config's swa_* keys; swa_kv_lora_rank > 0 is
+    # what says the model has them), which attend over the last swa_window
+    # positions, the query's own included, and keep their rows in a pool of
+    # their own (kv["win"]); a sigmoid gate a head on the attention output
+    # of both kinds (attention_gate), and the two LoRA latents rescaled by
+    # sqrt(hidden / rank) after their norms (mla_lora_rescale)
+    swa_num_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 0.0
+    swa_window: int = 0
+    attention_gate: bool = False
+    mla_lora_rescale: bool = False
     mamba_d_state: int = 0
     mamba_d_conv: int = 0
     mamba_expand: int = 0
@@ -176,6 +194,23 @@ class ModelConfig:
         return self.num_experts_total or self.num_experts
 
     @property
+    def has_swa_latent(self) -> bool:
+        """Window layers with a latent geometry of their own (dots3_note)."""
+        return self.swa_kv_lora_rank > 0
+
+    def swa_geometry(self) -> "ModelConfig":
+        """This model as its window layers see it: the swa_* sizes under
+        the names models/mla.py reads, no indexer, no yarn."""
+        return dataclasses.replace(
+            self, num_heads=self.swa_num_heads,
+            q_lora_rank=self.swa_q_lora_rank,
+            kv_lora_rank=self.swa_kv_lora_rank,
+            qk_nope_head_dim=self.swa_qk_nope_head_dim,
+            qk_rope_head_dim=self.swa_qk_rope_head_dim,
+            v_head_dim=self.swa_v_head_dim, rope_theta=self.swa_rope_theta,
+            index_n_heads=0, index_head_dim=0, index_topk=0)
+
+    @property
     def is_sambay(self) -> bool:
         """State-space, window and shared-cache layers in one model
         (phi4flash): models/sambay.py serves it."""
@@ -190,7 +225,8 @@ class ModelConfig:
         """The v3 generation's attention-score and routing conventions
         (deepseek_v32 is v3 plus the indexer; kimi_k2 is v3's block at its
         own sizes)."""
-        return self.model_type in ("deepseek_v3", "deepseek_v32", "kimi_k2")
+        return self.model_type in ("deepseek_v3", "deepseek_v32", "kimi_k2",
+                                   "dots3_note")
 
     @classmethod
     def from_hf_config(cls, cfg: Dict[str, Any]) -> "ModelConfig":
@@ -248,7 +284,14 @@ class ModelConfig:
                     f"kimi_k2 needs {', '.join(missing)} in its config "
                     f"(deepseek_v3's class defaults are not this family's)")
             mt = "deepseek_v3"          # the v3 branch, from here on
-        v32 = mt == "deepseek_v32"
+        dots = mt == "dots3_note"
+        if dots:
+            cls._check_dots3_note(cfg)
+            # one routing group, no multi-token-prediction layer served:
+            # not v3's class defaults
+            cfg = {"n_group": 1, "topk_group": 1,
+                   "num_nextn_predict_layers": 0, **cfg}
+        v32 = mt == "deepseek_v32" or dots
         if v32:
             # v3's block plus the lightning indexer: every v3 check below
             # applies, and the three indexer sizes must be there
@@ -545,6 +588,7 @@ class ModelConfig:
             if cfg.get("topk_method") == "group_limited_greedy"
             else int(cfg.get("topk_group", 4) or 0)
             if mt == "deepseek_v3" else 0,
+            **(cls._dots3_note_fields(cfg) if dots else {}),
             sliding_window=(int(cfg.get("sliding_window") or 4096)
                             if mt == "gemma2"
                             else int(cfg["sliding_window"])
@@ -553,12 +597,83 @@ class ModelConfig:
             # phi3 windows EVERY layer (HF Phi3Attention), unlike
             # gemma2's interleave — synthesize explicit layer_types so
             # sliding_layer_mask can't fall back to the gemma2 default
-            layer_types=(cfg.get("layer_types")
+            layer_types=(list(cfg["layer_types"][:int(
+                cfg["num_hidden_layers"])]) if dots
+                         else cfg.get("layer_types")
                          or (["sliding_attention"]
                              * int(cfg.get("num_hidden_layers", 32))
                              if mt == "phi3" and cfg.get("sliding_window")
                              else None)),
         )
+
+    @staticmethod
+    def _check_dots3_note(cfg: Dict[str, Any]) -> None:
+        """dots3_note: v3.2's block on its "full_attention" layers, a latent
+        geometry of its own (the swa_* keys) on its "sliding_attention"
+        ones. Nothing of v3's class defaults is this family's: every size
+        must be in the file, and the layer kinds must be ones the program
+        runs."""
+        swa = ("swa_num_attention_heads", "swa_q_lora_rank",
+               "swa_kv_lora_rank", "swa_qk_nope_head_dim",
+               "swa_qk_rope_head_dim", "swa_v_head_dim", "swa_rope_theta")
+        missing = [k for k in (
+            "n_routed_experts", "routed_scaling_factor",
+            "first_k_dense_replace", "layer_types", "sliding_window_size",
+            "num_hidden_layers", "q_lora_rank", "kv_lora_rank",
+            "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+            "rope_theta") + swa if cfg.get(k) is None]
+        if missing:
+            raise ValueError(
+                f"dots3_note needs {', '.join(missing)} in its config "
+                f"(deepseek_v3's class defaults are not this family's)")
+        n = int(cfg["num_hidden_layers"])
+        kinds = list(cfg["layer_types"])
+        problems = []
+        if len(kinds) < n:
+            problems.append(f"layer_types names {len(kinds)} layers of "
+                            f"num_hidden_layers {n}")
+        kinds = kinds[:n]                 # a cut depth keeps the leading ones
+        dense = int(cfg["first_k_dense_replace"])
+        if any(k != "full_attention" for k in kinds[:dense]):
+            problems.append(
+                f"layer_types: the first_k_dense_replace = {dense} dense "
+                f"layer(s) must be full_attention (the window layers' "
+                f"stacks hold expert layers only)")
+        if "sliding_attention" not in kinds:
+            problems.append("layer_types has no sliding_attention layer "
+                            "(that model is deepseek_v32)")
+        if int(cfg.get("index_topk") or 0) and "full_attention" not in kinds:
+            problems.append("index_topk > 0 with no full_attention layer in "
+                            "layer_types: the indexer selects for those")
+        for key in ("attention_gate_type", "swa_attention_gate_type"):
+            if cfg.get(key, "headwise") != "headwise":
+                problems.append(f"{key} {cfg[key]!r} (headwise is "
+                                f"implemented)")
+        if cfg.get("rope_scaling"):
+            problems.append("rope_scaling (the two thetas are read "
+                            "unscaled)")
+        if int(cfg.get("moe_layer_freq", 1) or 1) != 1:
+            problems.append("moe_layer_freq other than 1")
+        if int(cfg["sliding_window_size"]) < 1:
+            problems.append("sliding_window_size < 1")
+        if problems:
+            raise ValueError("dots3_note is not implemented with: "
+                             + "; ".join(problems))
+
+    @staticmethod
+    def _dots3_note_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
+        return dict(
+            swa_num_heads=int(cfg["swa_num_attention_heads"]),
+            swa_q_lora_rank=int(cfg["swa_q_lora_rank"]),
+            swa_kv_lora_rank=int(cfg["swa_kv_lora_rank"]),
+            swa_qk_nope_head_dim=int(cfg["swa_qk_nope_head_dim"]),
+            swa_qk_rope_head_dim=int(cfg["swa_qk_rope_head_dim"]),
+            swa_v_head_dim=int(cfg["swa_v_head_dim"]),
+            swa_rope_theta=float(cfg["swa_rope_theta"]),
+            # counts the query's own position: 513 keys (assumed reading 3)
+            swa_window=int(cfg["sliding_window_size"]),
+            attention_gate=True,
+            mla_lora_rescale=bool(cfg.get("apply_mla_qkv_lora_rescale")))
 
     @classmethod
     def _from_phi4flash(cls, cfg: Dict[str, Any]) -> "ModelConfig":

@@ -51,6 +51,26 @@ def _owned(a: np.ndarray) -> jax.Array:
     return jnp.asarray(a.copy())
 
 
+def _own_suffix_start(pool, blocks: list, step: int = 128) -> int:
+    """Where the trailing run of blocks that ``blocks``' sequence alone
+    holds (refcount 1) begins: what the defrag pass may move. Asked of the
+    pool from the end, ``step`` blocks at a time: the scan runs every cycle
+    over every slot, and a context of 2,000 shared blocks behind 50 of a
+    sequence's own (a long cached document) must not cost 2,000 lookups a
+    slot (measured, PR 42: 42 ms a cycle at 64 slots of 33k tokens)."""
+    j = len(blocks)
+    while j > 0:
+        lo = max(0, j - step)
+        rcs = pool.refcounts(blocks[lo:j])
+        k = len(rcs)
+        while k > 0 and rcs[k - 1] == 1:
+            k -= 1
+        if k > 0:
+            return lo + k
+        j = lo
+    return 0
+
+
 @dataclasses.dataclass
 class EngineRequest:
     """One sequence's engine-side state."""
@@ -100,6 +120,9 @@ class EngineRequest:
     prefix_hit_tokens: int = 0
     seq: Optional[TokenBlockSequence] = None   # full token history + hashes
     registered_blocks: int = 0
+    # the window-pool blocks it holds (llm/kv/pool.py WindowBlocks; None on
+    # a model whose window rows are not pool blocks)
+    win: object = None
     emitted_total: int = 0        # tokens the client has seen (across lives)
     # lane-prefill mode (EngineConfig.lane_prefill_max_tokens): the FULL
     # prompt (incl. any prefix-hit tokens); while pos < len(lane_prompt)
@@ -267,8 +290,11 @@ class EngineCore:
                 # rows either carries the index keys or refuses HERE, at
                 # build (the matrix: docs/dsa.md)
                 raise NotImplementedError(
-                    "deepseek_v32 sparse attention (index_topk > 0) / an "
-                    "expert share is not implemented with: "
+                    (f"{model_cfg.model_type} (window layers with a latent "
+                     "geometry and a block pool of their own)"
+                     if model_cfg.has_swa_latent else
+                     "deepseek_v32 sparse attention (index_topk > 0) / an "
+                     "expert share") + " is not implemented with: "
                     + "; ".join(refused))
         else:
             self.model_mod = llama
@@ -296,6 +322,7 @@ class EngineCore:
             cfg=model_cfg, block_size=engine_cfg.kv_block_size,
             attn_impl=attn_impl,
             kv_coalesce=engine_cfg.kv_contig_alloc,
+            table_blocks=engine_cfg.max_blocks_per_seq,
             # heads shard over "tp": the attention kernels then run per
             # shard (llama._per_tp_shard). The pp stage ring is already
             # inside its own shard_map and hands kernels local arrays.
@@ -366,10 +393,22 @@ class EngineCore:
                 engine_cfg.kv_block_size, engine_cfg.max_num_seqs,
                 dtype=param_dtype)
         elif self.is_mla:
+            # window layers of a geometry of their own (dots3_note): a
+            # second group of pool blocks, sized from the layout and the
+            # paged pool (no flag; docs/hybrid_cache.md)
+            cache_layout = self.model_mod.cache_layout(
+                model_cfg, engine_cfg.kv_block_size,
+                jnp.dtype(param_dtype).itemsize)
+            win_blocks = 0 if cache_layout is None else \
+                cache_layout.window_pool_blocks(
+                    engine_cfg.num_kv_blocks, engine_cfg.max_num_seqs,
+                    engine_cfg.prefill_chunk
+                    or max(engine_cfg.prefill_buckets))
             self.kv = self.model_mod.init_kv_cache(
                 model_cfg, engine_cfg.num_kv_blocks,
                 engine_cfg.kv_block_size, dtype=param_dtype,
-                quantization=engine_cfg.kv_quantization)
+                quantization=engine_cfg.kv_quantization,
+                **({"win_blocks": win_blocks} if win_blocks else {}))
         else:
             self.kv = llama.init_kv_cache(
                 model_cfg, engine_cfg.num_kv_blocks,
@@ -462,7 +501,9 @@ class EngineCore:
             layout=(self.model_mod.cache_layout(
                 model_cfg, engine_cfg.kv_block_size,
                 jnp.dtype(param_dtype).itemsize)
-                if self.is_hybrid else None))
+                if self.is_hybrid else cache_layout if self.is_mla
+                else None),
+            win_blocks=win_blocks if self.is_mla else 0)
         if host_pool is not None:
             self.offload_engine = KvOffloadEngine(
                 host_pool, engine_cfg.kv_block_size,
@@ -476,7 +517,13 @@ class EngineCore:
         # layer reads of a context, and the recurrent bytes one slot-step
         # reads and writes (None / 0 on every other model)
         layout = self.kv_manager.layout
-        self._window = model_cfg.sliding_window if self.is_hybrid else None
+        # window rows as blocks of a second pool (dots3_note): a decode
+        # table carries their ring of R entries behind its M
+        self.has_window_pool = self.kv_manager.win_pool is not None
+        self.R = layout.ring_blocks if self.has_window_pool else 0
+        self._window = (model_cfg.sliding_window if self.is_hybrid
+                        else model_cfg.swa_window if self.has_window_pool
+                        else None)
         self._step_state_bytes = (
             2 * layout.state_layers * layout.state_bytes
             if self.is_hybrid else 0)
@@ -512,7 +559,8 @@ class EngineCore:
         self._stopping = False
         self._step = 0
         # host mirrors of per-slot state
-        self._block_tables = np.zeros((self.B, self.M), dtype=np.int32)
+        self._block_tables = np.zeros((self.B, self.M + self.R),
+                                      dtype=np.int32)
         self._positions = np.zeros((self.B,), dtype=np.int32)
         self._tokens = np.zeros((self.B,), dtype=np.int32)
         self._samp = {
@@ -1172,7 +1220,8 @@ class EngineCore:
 
     # ------------------------------------------------------------- frontend
     async def submit(self, req: EngineRequest) -> None:
-        if (self.model_cfg.index_topk > 0 or self.is_hybrid) and (
+        if (self.model_cfg.index_topk > 0 or self.is_hybrid
+                or self.has_window_pool) and (
                 req.precomputed is not None or req.handoff is not None
                 or req.handoff_device):
             # neither disagg plane ships the index keys (docs/dsa.md) or
@@ -1287,7 +1336,7 @@ class EngineCore:
         if not seq_lens.any():
             return 0.0
         counts = dma_copy_counts(
-            self._block_tables, seq_lens,
+            self._block_tables[:, :self.M], seq_lens,
             block_size=self.cfg.kv_block_size,
             pool_blocks=self.cfg.num_kv_blocks,
             dual_stream=not self.is_mla,
@@ -1619,10 +1668,7 @@ class EngineCore:
         for i, req in enumerate(self.slots):
             if req is None or not req.ready or len(req.blocks) < 2:
                 continue
-            rcs = pool.refcounts(req.blocks)
-            j = len(req.blocks)
-            while j > 0 and rcs[j - 1] == 1:
-                j -= 1
+            j = _own_suffix_start(pool, req.blocks)
             suffix = req.blocks[j:][:cfg.kv_defrag_max_blocks]
             if len(suffix) < 2:
                 continue
@@ -1651,12 +1697,20 @@ class EngineCore:
             pool.release(new)       # no layout win — don't thrash
             return False
         from .block_copy import move_blocks
-        self.kv = move_blocks(self.kv, old, new, cfg.kv_block_size)
-        pool.relocate(zip(old, new))
         req = self.slots[slot]
+        win_old, win_new = self._defrag_window_moves(req, len(old))
+        self.kv = move_blocks(self.kv, old, new, cfg.kv_block_size,
+                              win_src=win_old, win_dst=win_new)
+        pool.relocate(zip(old, new))
         req.blocks[j:j + len(old)] = new
         self._block_tables[slot, :] = 0
         self._block_tables[slot, :len(req.blocks)] = req.blocks
+        if self.has_window_pool:
+            self.kv_manager.win_pool.relocate(zip(win_old, win_new))
+            moved = dict(zip(win_old, win_new))
+            req.win.held = {i: moved.get(b, b)
+                            for i, b in req.win.held.items()}
+            self._window_ring(slot, req)
         self.defrag_passes += 1
         self._defrag_last_step = self._step
         self.flight.record("defrag", moved=len(old), runs_before=runs)
@@ -1664,6 +1718,28 @@ class EngineCore:
                      "pool frag %.2f", slot, len(old), runs,
                      pool.count_runs(new), pool_frag)
         return True
+
+    def _defrag_window_moves(self, req: "EngineRequest",
+                             n: int) -> tuple:
+        """The window-group half of a defrag pass that moves ``n`` paged
+        blocks of ``req``: the window blocks it alone holds (at most as
+        many, so that the copy program's shape is the paged move's), onto
+        one fresh run of the window pool. ([], []) where there is no
+        window pool, nothing to gain, or no free run."""
+        if not self.has_window_pool:
+            return [], []
+        wp = self.kv_manager.win_pool
+        held = [b for _i, b in sorted(req.win.held.items())]
+        old = [b for b, rc in zip(held, wp.refcounts(held)) if rc == 1][-n:]
+        if len(old) < 2 or wp.count_runs(old) < 2 \
+                or wp.free_uninit_blocks < len(old):
+            return [], []
+        new = wp.alloc_uninit(len(old))
+        if new is None or wp.count_runs(new) >= wp.count_runs(old):
+            if new:
+                wp.release(new)
+            return [], []
+        return old, new
 
     def _sweep_cancelled(self) -> bool:
         """One pass of the end-to-end cancellation contract
@@ -1851,7 +1927,8 @@ class EngineCore:
         construction (kv_remote_dir) may already have built an
         object-backed store — the fabric wraps that same store, so this
         is idempotent on the manager side."""
-        if self.model_cfg.index_topk > 0 or self.is_hybrid:
+        if (self.model_cfg.index_topk > 0 or self.is_hybrid
+                or self.has_window_pool):
             raise NotImplementedError(
                 "the KV fabric ships paged rows only; it is not "
                 "implemented with deepseek_v32's index-key cache or "
@@ -2178,6 +2255,7 @@ class EngineCore:
         req.slot = slot
         req.blocks = plan.all_blocks
         req.seq = plan.seq
+        req.win = plan.win
         # host-tier hits: scatter the prepared (block-major, padded) values
         # into their device slots before prefill (reference
         # prepare_prefill_offload; the +40% TTFT multi-turn win,
@@ -2345,6 +2423,8 @@ class EngineCore:
             else:
                 padded = np.zeros((bucket,), np.int32)
                 padded[:len(chunk)] = chunk
+                table = self._window_before(
+                    req, table, req.prefix_hit_tokens, n_prompt)
                 if self.recorder is not None:
                     req._pf_seq = self._rec_prefill(
                         req, slot, padded, table,
@@ -2361,6 +2441,7 @@ class EngineCore:
                     jnp.asarray(req.sampling.top_p, jnp.float32))
                 grouped_rows = llama.grouped_prefill_rows(
                     self.statics, bucket, len(chunk))
+                self._window_after(req, n_prompt)
             self.total_prefill_tokens += len(chunk)
             self.clock.admits += 1
             self.clock.admit_tokens += len(chunk)
@@ -2417,6 +2498,8 @@ class EngineCore:
         # host mirrors
         self._block_tables[slot, :] = 0
         self._block_tables[slot, :len(req.blocks)] = req.blocks
+        if self.has_window_pool:
+            self._window_ring(slot, req)
         self._samp["temperature"][slot] = req.sampling.temperature
         self._samp["top_k"][slot] = req.sampling.top_k
         self._samp["top_p"][slot] = req.sampling.top_p
@@ -2435,6 +2518,11 @@ class EngineCore:
             hit_device=plan.hit_tokens, hit_host=plan.host_hit_tokens,
             hit_disk=plan.disk_hit_tokens,
             hit_remote=plan.remote_hit_tokens,
+            # the hit as taken, and what of a longer paged match was given
+            # up because the window blocks before its boundary were gone
+            # (0 without a window pool)
+            hit_tokens=req.prefix_hit_tokens,
+            hit_cut_tokens=plan.hit_cut_tokens,
             precomputed=remote_admit, grouped_rows=grouped_rows,
             # prompt tokens a state-space scan ran over (0 on a model
             # without such layers)
@@ -2513,12 +2601,55 @@ class EngineCore:
 
     def _prefill_table(self, blocks: list, slot: int) -> np.ndarray:
         """The block table a prefill dispatch takes: M entries, and for a
-        model with per-slot state (is_hybrid) the slot behind them."""
-        table = np.zeros((self.M + int(self.is_hybrid),), np.int32)
+        model with per-slot state (is_hybrid) the slot behind them; for one
+        with a window pool M more, the window block of every logical block
+        (filled in before each dispatch: _window_before)."""
+        table = np.zeros((self.M * (1 + int(self.has_window_pool))
+                          + int(self.is_hybrid),), np.int32)
         table[:len(blocks)] = blocks
         if self.is_hybrid:
             table[self.M] = slot
         return table
+
+    # ------------------------------------------------- the window group
+    def _window_before(self, req: "EngineRequest", table: np.ndarray,
+                       lo: int, hi: int) -> np.ndarray:
+        """Before a prefill dispatch over the positions [lo, hi): take the
+        window blocks its rows go to. → the dispatch's table, its window
+        half filled in (the blocks its first query's window reaches are
+        still held: a hit's, or the chunk's before). A table of its OWN:
+        the dispatch before it may still be queued on the one it was given
+        (jnp.asarray of an ndarray need not copy it)."""
+        if not self.has_window_pool:
+            return table
+        bs = self.cfg.kv_block_size
+        if not self.kv_manager.window_grow(req.win, lo // bs,
+                                           -(-hi // bs)):
+            # sized so that this cannot be (window_pool_blocks)
+            raise RuntimeError("the window pool has no block left for a "
+                               "prefill dispatch")
+        table = table.copy()
+        table[self.M:2 * self.M] = req.win.table(self.M)
+        return table
+
+    def _window_after(self, req: "EngineRequest", position: int) -> None:
+        """After the rows before ``position`` were dispatched: register
+        the window blocks that are full, and let go of those the query at
+        ``position`` no longer reaches."""
+        if not self.has_window_pool:
+            return
+        if req.seq is not None:
+            self.kv_manager.window_register(req.win, req.seq, req.blocks,
+                                            position)
+        self.kv_manager.window_slide(req.win, position)
+
+    def _window_ring(self, slot: int, req: "EngineRequest") -> None:
+        """The slot's decode table, window part: logical block b at entry
+        b % R."""
+        ring = self._block_tables[slot, self.M:]
+        ring[:] = 0
+        for i, bid in req.win.held.items():
+            ring[i % self.R] = bid
 
     def _rec_prefill(self, req: "EngineRequest", slot: int,
                      padded: np.ndarray, table: np.ndarray, *,
@@ -2557,6 +2688,7 @@ class EngineCore:
             # regardless of prompt length or bucket list
             padded = np.zeros((C,), np.int32)
             padded[:len(piece)] = piece
+            table = self._window_before(req, table, off, off + len(piece))
             if self.recorder is not None:
                 pf = self._rec_prefill(req, slot, padded, table,
                                        start_pos=off, true_len=len(piece))
@@ -2572,6 +2704,9 @@ class EngineCore:
                 jnp.asarray(req.sampling.top_k, jnp.int32),
                 jnp.asarray(req.sampling.top_p, jnp.float32))
             off += len(piece)
+            # the device runs programs in dispatch order: a window block
+            # let go here is rewritten only by a later dispatch
+            self._window_after(req, off)
         return tok, logprob
 
     def _complete_admissions(self) -> None:
@@ -3016,6 +3151,17 @@ class EngineCore:
                     continue
                 s.blocks.extend(new)
                 self._block_tables[i, :len(s.blocks)] = s.blocks
+            if self.has_window_pool and need - 1 not in s.win.held:
+                # the write lands in a new block: let go of what the
+                # window has left behind, then take the block (a step in
+                # flight still reads what is let go: it runs first)
+                self.kv_manager.window_slide(s.win, pos_eff)
+                if not self.kv_manager.window_grow(s.win, need - 1, need):
+                    if in_flight:
+                        return False
+                    self._preempt_or_finish(s)
+                    continue
+                self._window_ring(i, s)
         return any(s is not None and s.ready for s in self.slots)
 
     def _dispatch_pipelined(self, K: int) -> tuple:
@@ -3164,7 +3310,7 @@ class EngineCore:
             lens = np.where(riding, self._positions + k, 0)
             walked = -(-lens // (chunk * bsz))                       # [B]
             contig = wave_contig_table(
-                tables, lens, block_size=bsz, chunk=chunk,
+                tables[:, :self.M], lens, block_size=bsz, chunk=chunk,
                 pool_blocks=self.kv["idx"].shape[1] // bsz, xp=np)
             waves += int(walked.sum())
             run_waves += int(contig[np.arange(contig.shape[1])[None, :]
@@ -3220,6 +3366,10 @@ class EngineCore:
                         self.kv_manager.register_full_blocks(
                             req.blocks, req.seq, req.registered_blocks,
                             tenant=req.tenant or None)
+                    if self.has_window_pool:
+                        self.kv_manager.window_register(
+                            req.win, req.seq, req.blocks,
+                            len(req.seq.tokens))
                 req.pos += 1
                 req.key_step += 1
                 n_applied += 1
@@ -3272,6 +3422,12 @@ class EngineCore:
             emitted=sum(n for _i, _r, n in applied),
             ctx_tokens=ctx_tokens, sel_tokens=sel_tokens,
             win_tokens=win_tokens,
+            # window-pool blocks the fullest slot holds (a layer's; the
+            # bound is the ring: R1 of docs/hybrid_cache.md); 0 elsewhere
+            win_blocks_live=max(
+                (len(r.win.held) for r in self.slots
+                 if r is not None and r.win is not None), default=0)
+            if self.has_window_pool else 0,
             state_bytes=steps_applied * self._step_state_bytes,
             **{k: pending[k] for k in ("key_waves", "key_run_waves", "drain")
                if k in pending})
@@ -4004,6 +4160,7 @@ class EngineCore:
                               blocks=list(req.blocks))
         self.kv_manager.pool.release(req.blocks)
         req.blocks = []
+        self.kv_manager.window_release(req.win)
 
     def _finish_request(self, req: EngineRequest,
                         reason: FinishReason) -> None:

@@ -480,6 +480,14 @@ def init_one_param(cfg: ModelConfig, name: str, shape: tuple,
                       "q_norm", "k_norm",
                       "kv_norm", "q_a_norm",
                       "idx_k_norm_w")) or name == "final_norm":
+        if cfg.mla_lora_rescale and name.endswith("q_a_norm"):
+            # dots3_note multiplies its normed LoRA latents by
+            # sqrt(hidden / rank). The q latent's norm carries the inverse
+            # (a trained weight would); the kv latent's stays 1, so keys and
+            # values stand sqrt(hidden / rank) over a normalised input: see
+            # MIXED_SEEDED for what the two were measured against
+            return jnp.full(shape, (shape[-1] / cfg.hidden_size) ** 0.5,
+                            dtype=dtype)
         return (jnp.zeros(shape, dtype=dtype)
                 if cfg.norm_plus_one
                 else jnp.ones(shape, dtype=dtype))
@@ -518,15 +526,35 @@ SPARSE_SEEDED = {"embed": 1.0, "wo": 0.5, "down": 0.25, "moe_down": 0.1}
 SHARE_SEEDED = {"embed": 1.0, "moe_down": 0.5}
 
 
+# Seeded weights of a model whose full layers (indexer) stand beside window
+# layers of a geometry of their own and whose LoRA latents are rescaled
+# (dots3_note; ModelConfig.has_swa_latent). SPARSE_SEEDED as it stands
+# averages 2,048 or 513 random values under a softmax of unit scores: every
+# attention branch is a few percent of the stream at 33k tokens, and a gate,
+# a window, a rope base or a selection left out reads 0.03-0.10 of the
+# logits' standard deviation, inside the tolerance. With both LoRA norms at 1
+# (scores 7 times a unit model's, a softmax near one-hot) the bf16 program
+# stands 1.3 off the float32 reference. Between the two: q_a_norm at the
+# inverse of its rescale and kv_norm at 1 (init_one_param: scores 2-2.7
+# times a unit model's, values 2.2-3.2), the window layers' wo as
+# SPARSE_SEEDED has it, and the full layers' wo at half of that, because
+# what their top-k's flipped members move is passed on through it. Measured
+# on the chip at 32,832 tokens (PERF.md section 6, PR 42, second session):
+# the program 0.07 off the reference and the reference in bf16 0.08 off the
+# program; the gate left out 1.13, the selection 0.45, the kv rescale 0.62.
+MIXED_SEEDED = dict(SPARSE_SEEDED, wo=0.25, swa_wo=0.5)
+
+
 def seeded_std(cfg: ModelConfig, name: str, fan_in: int) -> float:
     """Standard deviation of a --random-weights matrix: fan_in^-0.5, but
-    see SPARSE_SEEDED and SHARE_SEEDED."""
+    see SPARSE_SEEDED, MIXED_SEEDED and SHARE_SEEDED."""
     std = fan_in ** -0.5
-    rule = (SPARSE_SEEDED if cfg.index_topk > 0
+    rule = (MIXED_SEEDED if cfg.has_swa_latent
+            else SPARSE_SEEDED if cfg.index_topk > 0
             else SHARE_SEEDED if cfg.num_experts_total > 0 else {})
     if name == "embed":
         return rule.get("embed", std)
-    for suffix in ("moe_down", "down", "wo"):
+    for suffix in ("moe_down", "down", "swa_wo", "wo"):
         if name.endswith(suffix):
             return std * rule.get(suffix, 1.0)
     return std
@@ -611,10 +639,16 @@ class ModelStatics:
     # the parameters are placed over a mesh (any mesh: tp, ep, sp, pp),
     # so the expert stacks may be sharded: what experts_run_grouped asks
     sharded: bool = False
+    # entries of a sequence's block table (the engine's max_blocks_per_seq):
+    # a model whose window layers keep their rows under ids of their own
+    # (models/mla.py dots3_note) finds their table behind these entries; 0
+    # = every table is a plain one
+    table_blocks: int = 0
 
     def __hash__(self):
         return hash((id(self.cfg), self.block_size, self.attn_impl,
-                     self.kv_coalesce, id(self.mesh), self.sharded))
+                     self.kv_coalesce, id(self.mesh), self.sharded,
+                     self.table_blocks))
 
     @property
     def tp(self) -> int:

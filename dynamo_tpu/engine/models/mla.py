@@ -83,6 +83,27 @@ the live table with a running max and sum (``_dense_chunk``; on the TPU
 one Pallas call a key block, engine/mla_prefill.py), a decode step in the
 ABSORBED form over the paged pool (``decode_forward``).
 
+dots3_note (``cfg.has_swa_latent``; docs/hybrid_cache.md) has TWO latent
+geometries in one model. Its "full_attention" layers are the v3.2 block
+above at this model's sizes; its "sliding_attention" layers are the same
+MLA form at sizes of their own (``ModelConfig.swa_geometry``: other head
+count, q-LoRA and latent ranks, head dims and rope theta, no indexer) and
+attend over the last ``swa_window`` positions, the query's own included.
+Both kinds multiply each head's attention output by a sigmoid gate of the
+layer's normed input (``wg`` / ``swa_wg``: the headwise **gate**) before
+``wo``, and rescale the normed q and kv latents by sqrt(hidden / rank)
+(``mla_lora_rescale``; the cache holds the scaled latent, the indexer
+reads the scaled q latent). The parameters are two attention stacks of
+different shapes (``layers.<leaf>`` [full layers, ...], ``layers.swa_<leaf>``
+[window layers, ...]) beside the MLP stacks; ``_run_layers_mixed`` runs
+them in the published order by a scan over the period of ``layer_types``
+and reads every stack whole and in place. The window layers' rows live in
+``kv["win"]`` [window layers, NTOK_S, rank_s + rope_s padded], a pool of its
+own block ids: a decode step reads them through a table of ``ring_blocks``
+entries a sequence (``_swa_ring_view``), a prefill chunk expands the keys
+and values of ``chunk + window`` rows once and attends by blocks of queries
+(``_swa_chunk``).
+
 What carries ``idx`` and what refuses is decided once, at engine build
 (``dsa_refusals``): ragged dispatch, speculative verify, sequence-parallel
 prefill, every mesh (tp/sp/pp/ep/dp), int8 KV pools, the host/disk/remote
@@ -224,19 +245,87 @@ def apply_rope_interleaved(x: jax.Array, positions: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def param_shapes(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
-    L, D, H = cfg.num_layers, cfg.hidden_size, cfg.num_heads
+def _attn_shapes(cfg: ModelConfig, n: int, prefix: str = "") -> tuple:
+    """The attention leaves of ``n`` layers of one latent geometry, in the
+    two runs ``param_shapes`` lists them in (its order is the order the
+    seeded weights' keys are split in): the latent side, then the query
+    side with the indexer where cfg has one and the headwise gate where it
+    has that."""
+    D, H = cfg.hidden_size, cfg.num_heads
     qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    kv_side = {
+        "wkv_a": (n, D, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+        "kv_norm": (n, cfg.kv_lora_rank),
+        "wkv_b": (n, cfg.kv_lora_rank,
+                  H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+        "wo": (n, H * cfg.v_head_dim, D),
+    }
+    q_side = {}
+    if cfg.q_lora_rank > 0:
+        q_side.update({
+            "wq_a": (n, D, cfg.q_lora_rank),
+            "q_a_norm": (n, cfg.q_lora_rank),
+            "wq_b": (n, cfg.q_lora_rank, H * qk),
+        })
+    else:
+        q_side["wq"] = (n, D, H * qk)
+    if cfg.index_topk > 0:
+        # deepseek_v32 lightning indexer (int8 under --quantization int8:
+        # idx_wq_b, idx_wk and idx_w go through mm(); the key LayerNorm's
+        # weight and bias stay in the load dtype)
+        J, dI = cfg.index_n_heads, cfg.index_head_dim
+        q_side.update({
+            "idx_wq_b": (n, cfg.q_lora_rank, J * dI),
+            "idx_wk": (n, D, dI),
+            "idx_k_norm_w": (n, dI),
+            "idx_k_norm_b": (n, dI),
+            "idx_w": (n, D, J),
+        })
+    if cfg.attention_gate:
+        # the headwise gate: one sigmoid scalar a head (bf16 under
+        # --quantization int8: H out-channels, and a sigmoid behind them)
+        q_side["wg"] = (n, D, H)
+    return tuple({f"layers.{prefix}{k}": v for k, v in part.items()}
+                 for part in (kv_side, q_side))
+
+
+def layer_kinds(cfg: ModelConfig) -> Tuple[str, ...]:
+    """"F" (attends over the whole context) or "S" (over the window, with
+    the second geometry) for every layer; all "F" without window layers."""
+    if not cfg.has_swa_latent:
+        return ("F",) * cfg.num_layers
+    return tuple("S" if t == "sliding_attention" else "F"
+                 for t in cfg.layer_types)
+
+
+def _n_kind(kinds, kind: str) -> int:
+    """How many of ``kinds`` are ``kind``."""
+    return sum(1 for k in kinds if k == kind)
+
+
+def layer_plan(cfg: ModelConfig):
+    """→ (dense prefix k, the period of the kinds after it, whole periods,
+    the kinds left over): how ``_run_layers_mixed`` walks the layers, e.g.
+    F | F S S S | F S S S -> (1, ("F","S","S","S"), 2, ())."""
+    kinds = layer_kinds(cfg)
+    k = cfg.first_k_dense if cfg.num_experts > 0 else 0
+    rest = kinds[k:]
+    p = next(p for p in range(1, len(rest) + 1)
+             if all(rest[i] == rest[i % p] for i in range(len(rest))))
+    n = len(rest) // p
+    return k, rest[:p], n, rest[n * p:]
+
+
+def param_shapes(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
+    L, D = cfg.num_layers, cfg.hidden_size
+    kinds = layer_kinds(cfg)
+    kv_side, q_side = _attn_shapes(cfg, _n_kind(kinds, "F"))
     shapes: Dict[str, Tuple[int, ...]] = {
         "embed": (cfg.vocab_size, D),
         "final_norm": (D,),
         "layers.ln1": (L, D),
         "layers.ln2": (L, D),
-        "layers.wkv_a": (L, D, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
-        "layers.kv_norm": (L, cfg.kv_lora_rank),
-        "layers.wkv_b": (L, cfg.kv_lora_rank,
-                         H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
-        "layers.wo": (L, H * cfg.v_head_dim, D),
+        **kv_side,
     }
     if cfg.num_experts > 0:
         # deepseek hybrid: the first k layers are DENSE (their own
@@ -279,26 +368,12 @@ def param_shapes(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
             "layers.up": (L, D, cfg.intermediate_size),
             "layers.down": (L, cfg.intermediate_size, D),
         })
-    if cfg.q_lora_rank > 0:
-        shapes.update({
-            "layers.wq_a": (L, D, cfg.q_lora_rank),
-            "layers.q_a_norm": (L, cfg.q_lora_rank),
-            "layers.wq_b": (L, cfg.q_lora_rank, H * qk),
-        })
-    else:
-        shapes["layers.wq"] = (L, D, H * qk)
-    if cfg.index_topk > 0:
-        # deepseek_v32 lightning indexer (int8 under --quantization int8:
-        # idx_wq_b, idx_wk and idx_w go through mm(); the key LayerNorm's
-        # weight and bias stay in the load dtype)
-        J, dI = cfg.index_n_heads, cfg.index_head_dim
-        shapes.update({
-            "layers.idx_wq_b": (L, cfg.q_lora_rank, J * dI),
-            "layers.idx_wk": (L, D, dI),
-            "layers.idx_k_norm_w": (L, dI),
-            "layers.idx_k_norm_b": (L, dI),
-            "layers.idx_w": (L, D, J),
-        })
+    shapes.update(q_side)
+    if cfg.has_swa_latent:
+        # the window layers' stack, at their own sizes
+        for part in _attn_shapes(cfg.swa_geometry(), _n_kind(kinds, "S"),
+                                 "swa_"):
+            shapes.update(part)
     if not cfg.tie_word_embeddings:
         shapes["lm_head"] = (D, cfg.vocab_size)
     return shapes
@@ -329,9 +404,28 @@ def latent_row_lanes(cfg: ModelConfig, quantization: str = "none") -> int:
     return -(-C // 128) * 128
 
 
+def cache_layout(cfg: ModelConfig, block_size: int, dtype_bytes: int = 2):
+    """What the block manager needs to know of a model with window layers
+    of their own geometry (dots3_note): two groups, both made of pool
+    blocks. None for every other MLA model (one uniform paged pool)."""
+    if not cfg.has_swa_latent:
+        return None
+    from ...llm.kv.hybrid import HybridCacheLayout
+    kinds = layer_kinds(cfg)
+    lanes = latent_row_lanes(cfg) + (cfg.index_head_dim
+                                     if cfg.index_topk > 0 else 0)
+    n_f, n_s = _n_kind(kinds, "F"), _n_kind(kinds, "S")
+    return HybridCacheLayout(
+        block_size=block_size, row_bytes=lanes * dtype_bytes,
+        paged_layers=n_f, readers_of_paged=n_f,
+        window_layers=n_s, window=cfg.swa_window,
+        state_layers=0, state_bytes=0, window_pool=True)
+
+
 def init_kv_cache(cfg: ModelConfig, num_blocks: int,
                   block_size: int, dtype=jnp.bfloat16,
-                  quantization: str = "none") -> KVCache:
+                  quantization: str = "none",
+                  win_blocks: int = 0) -> KVCache:
     """quantization="int8": the latent row quantizes with one in-row
     (e, m) scale pair PER c_kv/k_pe section
     (attention.quantize_kv_rows_sections — both pairs share one
@@ -345,8 +439,11 @@ def init_kv_cache(cfg: ModelConfig, num_blocks: int,
         raise ValueError(f"unknown kv quantization {quantization!r} "
                          f"(none|int8)")
     W = latent_row_lanes(cfg, quantization)
+    # the layers whose rows this pool holds: all of them, or the
+    # full-attention ones of a model with window layers (below)
+    n_pool = _n_kind(layer_kinds(cfg), "F")
     kv = {"kv": jnp.zeros(
-        (cfg.num_layers, num_blocks * block_size, W),
+        (n_pool, num_blocks * block_size, W),
         dtype=jnp.int8 if quantization == "int8" else dtype)}
     if cfg.index_topk > 0:
         if quantization != "none":
@@ -356,8 +453,21 @@ def init_kv_cache(cfg: ModelConfig, num_blocks: int,
         # the indexer's second per-token cache, under the same block ids
         # ("kv" stays the first key: pool-agnostic code reads it)
         kv["idx"] = jnp.zeros(
-            (cfg.num_layers, num_blocks * block_size, cfg.index_head_dim),
+            (n_pool, num_blocks * block_size, cfg.index_head_dim),
             dtype=dtype)
+    if cfg.has_swa_latent:
+        if quantization != "none":
+            raise NotImplementedError(
+                "kv_quantization with window-layer latent rows is not "
+                "implemented (they have no int8 encoding)")
+        # the window layers' rows, at their own width and under the block
+        # ids of a pool of their own (win_blocks of them: what
+        # HybridCacheLayout.window_pool_blocks derives; 0 = as many as the
+        # paged pool, for a caller that drives one table for both)
+        kv["win"] = jnp.zeros(
+            (_n_kind(layer_kinds(cfg), "S"),
+             (win_blocks or num_blocks) * block_size,
+             latent_row_lanes(cfg.swa_geometry())), dtype=dtype)
     return kv
 
 
@@ -375,6 +485,8 @@ def _q_proj(lp, hn, cfg: ModelConfig):
     qa = None
     if cfg.q_lora_rank > 0:
         qa = rms_norm(mm(hn, lp["wq_a"]), lp["q_a_norm"], cfg.rms_norm_eps)
+        if cfg.mla_lora_rescale:
+            qa = qa * (cfg.hidden_size / cfg.q_lora_rank) ** 0.5
         q = mm(qa, lp["wq_b"])
     else:
         q = mm(hn, lp["wq"])
@@ -623,6 +735,8 @@ def _latent_rows(lp, hn, positions, cfg: ModelConfig):
     ckv = mm(hn, lp["wkv_a"])
     c, k_pe = ckv[..., :cfg.kv_lora_rank], ckv[..., cfg.kv_lora_rank:]
     c = rms_norm(c, lp["kv_norm"], cfg.rms_norm_eps)
+    if cfg.mla_lora_rescale:
+        c = c * (cfg.hidden_size / cfg.kv_lora_rank) ** 0.5
     inv, att = rope_params(cfg)
     k_pe = apply_rope_interleaved(k_pe, positions, jnp.asarray(inv), att)
     return jnp.concatenate([c, k_pe], axis=-1)
@@ -846,6 +960,261 @@ def _run_layers(params: Params, kv: KVCache, x: jax.Array,
     return x, pools
 
 
+# ---------------------------------------------------------------------------
+# dots3_note: window layers of a latent geometry of their own
+# ---------------------------------------------------------------------------
+
+# queries a prefill chunk's window layers attend at a time, against the
+# block + window keys they can reach
+SWA_QUERY_BLOCK = 256
+
+
+def swa_ring_blocks(cfg: ModelConfig, bsz: int) -> int:
+    """Window blocks a decoding sequence holds a layer: the window's and
+    one more (HybridCacheLayout.ring_blocks)."""
+    return -(-cfg.swa_window // bsz) + 1
+
+
+def _swa_tables(block_tables, M: int, R: int, doubled: bool):
+    """The tables a dispatch carries → (the full-attention layers' [.., M],
+    the window layers' part). The engine's prefill table is [2M] (the
+    window pool's block of every logical block behind the paged pool's, 0
+    where it was released), its decode tables [B, M + R] (logical block b
+    at entry b % R). A plain [M] / [B, M] table names blocks of both pools
+    by one id: what benchmark/selftest.py's ``greedy`` hands the engine's
+    own programs for every family (an accepted benchmark file), so the
+    format is told by the width, the one thing such a caller states."""
+    width = block_tables.shape[-1]
+    if M and width == (2 * M if doubled else M + R):
+        return block_tables[..., :M], block_tables[..., M:]
+    return block_tables, None
+
+
+def _swa_ring_view(window: int, bsz: int, positions, tables_f, ring, R: int):
+    """A decode step's window rows as ``paged_attention`` reads them (as
+    sambay._ring_view): per row a table of R window-pool blocks, oldest
+    first, so that the live window is one interval of it. The newest row
+    sits at index n = (R - 1) * bsz + p % bsz; live: the last
+    min(window, p + 1) positions. → (tables [B, R], seq_lens, win_lo)."""
+    blk = positions // bsz
+    j = jnp.arange(R, dtype=jnp.int32)
+    if ring is not None:
+        view = jnp.take_along_axis(ring, (blk[:, None] + 1 + j) % R, axis=1)
+    else:
+        at = blk[:, None] - (R - 1) + j
+        view = jnp.where(at >= 0, jnp.take_along_axis(
+            tables_f, jnp.clip(at, 0, tables_f.shape[1] - 1), axis=1), 0)
+    newest = (R - 1) * bsz + positions % bsz
+    return (view, newest + 1,
+            newest - jnp.minimum(window, positions + 1))
+
+
+def _swa_chunk(q_nope, q_pe, lp, win_flat, table_l, start_pos, seq_len,
+               cfg: ModelConfig, window: int, bsz: int,
+               scale: float) -> jax.Array:
+    """Window attention of the T queries of one prefill chunk (query t at
+    position start_pos + t reads the keys s with t - window < s <= t), in
+    the expanded form: the rows [start_pos - window + 1, start_pos + T) are
+    read from the window pool by their blocks (table_l: the layer-offset
+    block of every logical block) and expanded through wkv_b ONCE, then a
+    block of SWA_QUERY_BLOCK queries at a time attends the block + window
+    keys it can reach: no [T, S] tensor exists. cfg: the window layers'
+    geometry. → [T, H, dv] float32."""
+    import math
+    T, H = q_nope.shape[0], q_nope.shape[1]
+    rank, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    cd, f32 = q_nope.dtype, jnp.float32
+    W = win_flat.shape[-1]
+    QB = math.gcd(T, SWA_QUERY_BLOCK)
+    back = window - 1
+    nb = (T + back) // bsz + 2           # covers the chunk, the window
+    KB = QB + back + bsz                 # ... and a block's keys, misaligned
+    b0 = jnp.maximum(start_pos - back, 0) // bsz
+    ids = jax.lax.dynamic_slice(jnp.pad(table_l, (0, nb)), (b0,), (nb,))
+    rows = jnp.take(win_flat.reshape(-1, bsz, W), ids, axis=0,
+                    mode="clip").reshape(nb * bsz, W).astype(cd)
+    w_k, w_v = (w.astype(cd) for w in _split_wkv_b(lp, cfg))
+    c, k_pe = rows[:, :rank], rows[:, rank:rank + dr]
+    k_nope = jnp.einsum("sr,hrd->hsd", c, w_k,
+                        preferred_element_type=f32).astype(cd)
+    v = jnp.einsum("sr,hrd->hsd", c, w_v,
+                   preferred_element_type=f32).astype(cd)
+    qn = jnp.moveaxis(q_nope, 1, 0).reshape(H, T // QB, QB, -1)
+    qp = jnp.moveaxis(q_pe.astype(cd), 1, 0).reshape(H, T // QB, QB, -1)
+
+    def block(i):
+        q_lo = start_pos + i * QB                    # first query's position
+        lo = jnp.clip(q_lo - back - b0 * bsz, 0, nb * bsz - KB)
+        kn = jax.lax.dynamic_slice_in_dim(k_nope, lo, KB, axis=1)
+        kp = jax.lax.dynamic_slice_in_dim(k_pe, lo, KB, axis=0)
+        vv = jax.lax.dynamic_slice_in_dim(v, lo, KB, axis=1)
+        s_ = (jnp.einsum("htd,hsd->hts", qn[:, i], kn,
+                         preferred_element_type=f32)
+              + jnp.einsum("htd,sd->hts", qp[:, i], kp,
+                           preferred_element_type=f32)) * scale
+        kpos = (b0 * bsz + lo + jnp.arange(KB))[None, :]
+        qpos = (q_lo + jnp.arange(QB))[:, None]
+        mask = (kpos <= qpos) & (kpos > qpos - window) & (kpos < seq_len)
+        s_ = jnp.where(mask[None], s_, NEG_INF)
+        # a padded query row reads nothing: its softmax is over NEG_INF
+        # alone and its output is dropped with the row
+        probs = jax.nn.softmax(s_, axis=-1)
+        return jnp.einsum("hts,hsd->htd", probs.astype(cd), vv,
+                          preferred_element_type=f32)
+
+    out = jax.lax.map(block, jnp.arange(T // QB))    # [T/QB, H, QB, dv]
+    return jnp.moveaxis(out, 1, 2).reshape(T, H, -1)
+
+
+def _run_layers_mixed(params: Params, kv: KVCache, x: jax.Array,
+                      positions: jax.Array, slots: jax.Array,
+                      slots_s: jax.Array, cfg: ModelConfig, attn_fn,
+                      attn_s_fn, experts_sharded: bool = True,
+                      valid_rows: Optional[jax.Array] = None
+                      ) -> Tuple[jax.Array, KVCache]:
+    """``_run_layers`` for a model of two latent geometries (dots3_note):
+    the layers run in the published order, the dense prefix unrolled, then
+    ONE lax.scan over the periods of ``layer_types`` whose body holds a
+    period's layers, then what a cut depth leaves of a last period. The
+    program's size is that of one period, whatever the depth.
+
+    Every stack stays whole beside the scan and the body reads its layer
+    in place (the rule of _run_layers): ln1 / ln2 at the layer's index,
+    the full-attention stack (``layers.<leaf>``) at its index among the
+    full layers, the window stack (``layers.swa_<leaf>``) among the window
+    layers, the expert stacks among the expert layers.
+
+    attn_fn: as _run_layers gives it, with li the layer's index in the
+    paged pool. attn_s_fn(q_nope, q_pe, win_flat, lp, si) -> [N, Hs*dv]:
+    the window layers' read of kv["win"], whose rows for this dispatch go
+    to ``slots_s``."""
+    cfg_s = cfg.swa_geometry()
+    stack = _layer_stack(params)
+    k, period, n_periods, tail = layer_plan(cfg)
+    kinds = layer_kinds(cfg)
+    NTOK = kv["kv"].shape[1]
+    n_f = kv["kv"].shape[0]
+    f_names = [n for n in stack if n in (
+        "wq", "wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_norm", "wkv_b", "wo",
+        "wg", "idx_wq_b", "idx_wk", "idx_k_norm_w", "idx_k_norm_b", "idx_w")]
+    s_names = [n for n in stack if n.startswith("swa_")]
+    moe_all = {n: stack[n] for n in (
+        "router", "router_bias", "moe_gate", "moe_up", "moe_down",
+        "moe_gateup", "sh_gate", "sh_up", "sh_down", "sh_gateup")
+        if n in stack}
+    moe_lp, whole = split_expert_stacks(
+        moe_all, x.shape[0], cfg.num_experts_per_tok, experts_sharded)
+    dense_lp = {n[len("dense_"):]: stack[n] for n in stack
+                if n.startswith("dense_")}
+
+    def at(tree, i):
+        return jax.tree.map(
+            lambda w: jax.lax.dynamic_index_in_dim(w, i, keepdims=False),
+            tree)
+
+    def gated(attn, hn, lp, heads):
+        # the headwise gate: g_h = sigmoid(n(x)·Wg), o_h <- g_h · o_h
+        g = jax.nn.sigmoid(mm(hn, lp["wg"]).astype(jnp.float32))
+        N = attn.shape[0]
+        return (attn.reshape(N, heads, -1).astype(jnp.float32)
+                * g[..., None]).reshape(N, -1).astype(attn.dtype)
+
+    def layer(h, pools, li, ai, kind):
+        """li: the layer; ai: its index among the layers of its kind."""
+        ln = at({"ln1": stack["ln1"], "ln2": stack["ln2"]}, li)
+        hn = rms_norm(h, ln["ln1"], cfg.rms_norm_eps)
+        if kind == "F":
+            lp = at({n: stack[n] for n in f_names}, ai)
+            inv_np, att = rope_params(cfg)
+            q_nope, q_pe, qr = _q_proj(lp, hn, cfg)
+            q_pe = apply_rope_interleaved(q_pe, positions,
+                                          jnp.asarray(inv_np), att)
+            rows = _latent_rows(lp, hn, positions, cfg)
+            pool = pools["kv"]
+            enc = jnp.pad(rows.astype(pool.dtype),
+                          ((0, 0), (0, pool.shape[2] - rows.shape[1])))
+            pool = pool.at[ai, slots, :].set(enc, mode="drop")
+            pools = dict(pools, kv=pool)
+            extra = {}
+            if cfg.index_topk > 0:
+                with jax.named_scope("indexer"):
+                    qI, kI, w = _indexer_proj(lp, hn, qr, positions, cfg)
+                idx = pools["idx"].at[ai, slots, :].set(
+                    kI.astype(pools["idx"].dtype), mode="drop")
+                pools["idx"] = idx
+                extra["index"] = (qI, w,
+                                  idx.reshape(n_f * NTOK, idx.shape[2]))
+            attn = attn_fn(q_nope, q_pe, rows,
+                           pool.reshape(n_f * NTOK, pool.shape[2]), lp, ai,
+                           **extra)
+            heads = cfg.num_heads
+        else:
+            lp = {n[len("swa_"):]: w for n, w in
+                  at({n: stack[n] for n in s_names}, ai).items()}
+            inv_np, att = rope_params(cfg_s)
+            q_nope, q_pe, _qr = _q_proj(lp, hn, cfg_s)
+            q_pe = apply_rope_interleaved(q_pe, positions,
+                                          jnp.asarray(inv_np), att)
+            rows = _latent_rows(lp, hn, positions, cfg_s)
+            win = pools["win"]
+            enc = jnp.pad(rows.astype(win.dtype),
+                          ((0, 0), (0, win.shape[2] - rows.shape[1])))
+            win = win.at[ai, slots_s, :].set(enc, mode="drop")
+            pools = dict(pools, win=win)
+            attn = attn_s_fn(q_nope, q_pe,
+                             win.reshape(-1, win.shape[2]), lp, ai)
+            heads = cfg_s.num_heads
+        if cfg.attention_gate:
+            attn = gated(attn, hn, lp, heads)
+        h = h + mm(attn, lp["wo"])
+        hn2 = rms_norm(h, ln["ln2"], cfg.rms_norm_eps)
+        return h, pools, hn2
+
+    def dense_mlp(hn2, li):
+        lp = at(dense_lp, li)
+        return swiglu(hn2, lp.get("gate"), lp.get("up"), lp["down"],
+                      cfg.hidden_act, gateup_w=lp.get("gateup"))
+
+    def expert_mlp(hn2, mi):
+        return _moe_mlp(hn2, {**at(moe_lp, mi), **whole}, cfg,
+                        sharded=experts_sharded, valid_rows=valid_rows,
+                        layer=mi if whole else None)
+
+    pools = dict(kv)
+    for li in range(k):                  # the dense prefix: full layers
+        x, pools, hn2 = layer(x, pools, li, li, "F")
+        x = x + dense_mlp(hn2, li)
+    # a layer's index among its kind: those of its kind before the scan,
+    # a period's worth for every period gone by, and its rank in the period
+    before = {"F": _n_kind(kinds[:k], "F"), "S": 0}
+    per = {"F": _n_kind(period, "F"), "S": _n_kind(period, "S")}
+
+    def run(carry, li0, ai0, some_kinds):
+        h, pools = carry
+        seen = {"F": 0, "S": 0}
+        for j, kind in enumerate(some_kinds):
+            h, pools, hn2 = layer(h, pools, li0 + j,
+                                  ai0[kind] + seen[kind], kind)
+            h = h + expert_mlp(hn2, li0 + j - k)
+            seen[kind] += 1
+        return h, pools
+
+    if n_periods:
+        def body(carry, pi):
+            return run(carry, k + pi * len(period),
+                       {kd: before[kd] + pi * per[kd] for kd in per},
+                       period), None
+        (x, pools), _ = jax.lax.scan(
+            body, (x, pools), jnp.arange(n_periods, dtype=jnp.int32))
+    if tail:
+        x, pools = run((x, pools), k + n_periods * len(period),
+                       {kd: before[kd] + n_periods * per[kd] for kd in per},
+                       tail)
+    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    return x, pools
+
+
+
 def dsa_refusals(cfg: ModelConfig, engine_cfg, mesh) -> list:
     """What this engine asks for that cannot carry the index-key cache
     (or the expert share) yet — the refusal matrix of docs/dsa.md, read
@@ -871,6 +1240,32 @@ def dsa_refusals(cfg: ModelConfig, engine_cfg, mesh) -> list:
     if cfg.num_experts_total and (mesh is not None or engine_cfg.ep > 1):
         bad.append("a mesh with an expert share (the share IS this chip's "
                    "part of an expert-parallel layer)")
+    if cfg.has_swa_latent:
+        # dots3_note: what cannot carry the window layers' second pool and
+        # table (one list; an indexer's refusals above are a part of it)
+        e = engine_cfg
+        checks = {
+            "--ragged (ragged_forward has no window layers)":
+                e.ragged_dispatch,
+            "--spec-k (the verify program has no window layers)":
+                e.spec_k > 0,
+            "--lane-prefill-max-tokens (a lane's rows take no window "
+            "blocks)": e.lane_prefill_max_tokens > 0,
+            "--decode-steps-per-dispatch > 1 (window blocks are taken and "
+            "released a step at a time)": e.decode_steps_per_dispatch > 1,
+            "--kv-quantization (window rows have no int8 encoding)":
+                e.kv_quantization != "none",
+            "--host-kv-blocks / --kv-disk-* / --kv-remote-* (the tiers "
+            "ship the paged pool's rows only)": bool(
+                e.host_kv_blocks or e.kv_disk_blocks or e.kv_remote_dir),
+            "tp/sp/pp/ep/dp meshes (the window pool has no sharding "
+            "rule)": mesh is not None or max(
+                e.tp, e.sp, e.pp, e.ep, e.dp) > 1,
+        }
+        # an option the indexer's list names already is not named twice
+        named = {b.split(" ", 1)[0] for b in bad}
+        bad += [name for name, on in checks.items()
+                if on and name.split(" ", 1)[0] not in named]
     return bad
 
 
@@ -994,12 +1389,18 @@ def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
     scale = softmax_scale(cfg)
     positions = start_pos + jnp.arange(T, dtype=jnp.int32)
     valid = jnp.arange(T) < true_len
+    table_s = None
+    if cfg.has_swa_latent:
+        block_table, table_s = _swa_tables(
+            block_table, statics.table_blocks, 0, doubled=True)
     slots = jnp.where(
         valid, block_table[positions // bsz] * bsz + positions % bsz, 0)
     seq_len = start_pos + true_len
 
     def attn(q_nope, q_pe, _rows, kv_flat, lp, li, index=None):
-        NTOK = kv_flat.shape[0] // cfg.num_layers
+        # li: the layer's index in the pool (among the full-attention
+        # layers, where the model has window layers too)
+        NTOK = kv_flat.shape[0] // kv["kv"].shape[0]
         if index is not None:
             # deepseek_v32: select, then attend over the selected rows
             # only, in the absorbed form, a block of queries at a time
@@ -1020,9 +1421,28 @@ def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
         return out.reshape(T, H * cfg.v_head_dim).astype(q_nope.dtype)
 
     x = _embed(params, tokens, cfg)
-    x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn,
-                            experts_sharded=statics.sharded,
-                            valid_rows=true_len)
+    if cfg.has_swa_latent:
+        cfg_s = cfg.swa_geometry()
+        table_s = block_table if table_s is None else table_s
+        slots_s = jnp.where(
+            valid, table_s[positions // bsz] * bsz + positions % bsz, 0)
+        win_blocks = kv["win"].shape[1] // bsz
+
+        def attn_s(q_nope, q_pe, win_flat, lp, si):
+            with jax.named_scope("swa_prefill_attention"):
+                out = _swa_chunk(q_nope, q_pe, lp, win_flat,
+                                 table_s + si * win_blocks, start_pos,
+                                 seq_len, cfg_s, cfg.swa_window, bsz,
+                                 softmax_scale(cfg_s))
+            return out.reshape(T, -1).astype(q_nope.dtype)
+
+        x, kv_new = _run_layers_mixed(
+            params, kv, x, positions, slots, slots_s, cfg, attn, attn_s,
+            experts_sharded=statics.sharded, valid_rows=true_len)
+    else:
+        x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn,
+                                experts_sharded=statics.sharded,
+                                valid_rows=true_len)
     last = x[jnp.maximum(true_len - 1, 0)]
     return _logits(params, last, cfg), kv_new
 
@@ -1246,12 +1666,17 @@ def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
     H = cfg.num_heads
     rank, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
     scale = softmax_scale(cfg)
+    ring = None
+    if cfg.has_swa_latent:
+        R = swa_ring_blocks(cfg, bsz)
+        block_tables, ring = _swa_tables(
+            block_tables, statics.table_blocks, R, doubled=False)
     slots = (block_tables[jnp.arange(B), positions // bsz] * bsz
              + positions % bsz)
     seq_lens = positions + 1
 
     def attn(q_nope, q_pe, _rows, kv_flat, lp, li, index=None):
-        NTOK = kv_flat.shape[0] // cfg.num_layers
+        NTOK = kv_flat.shape[0] // kv["kv"].shape[0]
         num_blocks = NTOK // bsz
         tables_l = block_tables + li * num_blocks
         w_k, w_v = _split_wkv_b(lp, cfg)
@@ -1328,6 +1753,48 @@ def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
         return out.reshape(B, H * cfg.v_head_dim).astype(q_nope.dtype)
 
     x = _embed(params, tokens, cfg)
-    x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn,
-                            experts_sharded=statics.sharded)
+    if cfg.has_swa_latent:
+        from ..attention import paged_attention
+        cfg_s = cfg.swa_geometry()
+        rank_s, dr_s = cfg_s.kv_lora_rank, cfg_s.qk_rope_head_dim
+        view, view_len, view_lo = _swa_ring_view(
+            cfg.swa_window, bsz, positions, block_tables, ring, R)
+        # the newest row's block is the view's last entry
+        slots_s = view[:, R - 1] * bsz + positions % bsz
+        win_blocks = kv["win"].shape[1] // bsz
+        vl = rank_s if rank_s % 128 == 0 else None
+        # under jit so that the kernel is traced and lowered once for all
+        # the window layers of a period, not once a layer: its waves unroll
+        # in Python, 5 s of the host a trace at 64 slots x 34 blocks
+        read_window = jax.jit(functools.partial(
+            paged_attention, block_size=bsz, scale=softmax_scale(cfg_s),
+            impl=statics.attn_impl, kv_heads=1, v_lanes=vl,
+            coalesce=statics.kv_coalesce,
+            chunk_blocks=max(ATTN_CHUNK_BLOCKS, R)))
+
+        def attn_s(q_nope, q_pe, win_flat, lp, si):
+            # the absorbed form over the window's rows: at most R blocks a
+            # sequence, one 1,152-lane row for all heads (as attn above)
+            w_k, w_v = _split_wkv_b(lp, cfg_s)
+            q_lat = jnp.einsum("bhd,hrd->bhr", q_nope.astype(jnp.float32),
+                               w_k.astype(jnp.float32))
+            Ws = win_flat.shape[-1]
+            qc = jnp.concatenate(
+                [q_lat, q_pe.astype(jnp.float32),
+                 jnp.zeros((B, cfg_s.num_heads, Ws - rank_s - dr_s),
+                           jnp.float32)], axis=-1).astype(win_flat.dtype)
+            with jax.named_scope("swa_decode_attention"):
+                ctx = read_window(
+                    qc, win_flat, win_flat, view + si * win_blocks,
+                    view_len, win_lo=view_lo
+                )[..., :rank_s].astype(jnp.float32)
+            out = jnp.einsum("bhr,hrd->bhd", ctx, w_v.astype(jnp.float32))
+            return out.reshape(B, -1).astype(q_nope.dtype)
+
+        x, kv_new = _run_layers_mixed(
+            params, kv, x, positions, slots, slots_s, cfg, attn, attn_s,
+            experts_sharded=statics.sharded)
+    else:
+        x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn,
+                                experts_sharded=statics.sharded)
     return _logits(params, x, cfg), kv_new

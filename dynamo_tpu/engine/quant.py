@@ -320,6 +320,11 @@ _LAYER_MATMULS = ("wq", "wk", "wv", "wo", "gate", "up", "down",
                   # key projection and head-weight projection, all
                   # through mm() (its LayerNorm stays full precision)
                   "idx_wq_b", "idx_wk", "idx_w",
+                  # dots3_note's window layers (models/mla.py): the same
+                  # leaves at the second geometry; swa_wkv_b stays full
+                  # precision like wkv_b, and the headwise gates (wg,
+                  # swa_wg: H out-channels behind a sigmoid) like a router
+                  "swa_wq_a", "swa_wq_b", "swa_wkv_a", "swa_wo",
                   # phi4flash (models/sambay.py; names are
                   # layers.<kind>.<leaf>): the MLP, the state-space
                   # layer's four projections, the attention layers' qkv /

@@ -71,7 +71,15 @@ _LAYER_MAP = {
     "self_attn.indexer.k_norm.weight": ("idx_k_norm_w", False),
     "self_attn.indexer.k_norm.bias": ("idx_k_norm_b", False),
     "self_attn.indexer.weights_proj.weight": ("idx_w", True),
+    # dots3_note's headwise attention gate (no modelling code is public:
+    # the name is the gated-attention papers' g_proj, assumed)
+    "self_attn.g_proj.weight": ("wg", True),
 }
+
+# the attention leaves that a model with window layers of a latent geometry
+# of their own (dots3_note) keeps in two stacks, by the layer's kind
+_SWA_LEAVES = ("wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_norm", "wkv_b", "wo",
+               "wg")
 
 # mixtral expert sub-weights: w1=gate, w3=up, w2=down (all torch [out, in])
 _EXPERT_MAP = {"w1": "moe_gate", "w3": "moe_up", "w2": "moe_down",
@@ -148,6 +156,18 @@ def _partial_ranges(cfg: ModelConfig):
                 "moe_down", "sh_gate", "sh_up", "sh_down"):
         out[key] = (k, L)
     return out
+
+
+def _layers_of_stack(cfg: ModelConfig, key: str, lo: int, hi: int) -> list:
+    """The layers whose tensors the stack ``key`` holds, in order: the range
+    [lo, hi), or with two attention geometries (dots3_note) the layers of
+    the stack's kind."""
+    if not cfg.has_swa_latent or key in ("ln1", "ln2") or lo or hi != \
+            cfg.num_layers:
+        return list(range(lo, hi))
+    kind = "sliding_attention" if key.startswith("swa_") else \
+        "full_attention"
+    return [i for i, t in enumerate(cfg.layer_types) if t == kind]
 
 
 def load_params_auto(model_dir: str, cfg: Optional[ModelConfig] = None,
@@ -337,6 +357,9 @@ def load_llama_params(model_dir: str, cfg: Optional[ModelConfig] = None,
             if mapped is None:
                 continue  # rotary inv_freq buffers etc.
             key, transpose = mapped
+            if key in _SWA_LEAVES and cfg.has_swa_latent and (
+                    cfg.layer_types[int(idx_str)] == "sliding_attention"):
+                key = "swa_" + key        # the window layers' own stack
             arr = tensor.T if transpose else tensor
             staging.setdefault(key, [None] * L)[int(idx_str)] = arr
 
@@ -346,10 +369,11 @@ def load_llama_params(model_dir: str, cfg: Optional[ModelConfig] = None,
         params[key] = jnp.asarray(arr, dtype=dtype)
     for key, per_layer in staging.items():
         lo, hi = partial.get(key, (0, L))
-        rows = per_layer[lo:hi]
-        missing = [lo + i for i, a in enumerate(rows) if a is None]
+        want = _layers_of_stack(cfg, key, lo, hi)
+        rows = [per_layer[i] for i in want]
+        missing = [i for i in want if per_layer[i] is None]
         extra = [i for i, a in enumerate(per_layer) if a is not None
-                 and not (lo <= i < hi)]
+                 and i not in want]
         if missing or extra:
             raise ValueError(
                 f"checkpoint layer coverage wrong for {key}: missing "

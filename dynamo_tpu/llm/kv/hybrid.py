@@ -20,6 +20,18 @@ out and takes back:
   manager with a layout that has state therefore matches no prefix and
   registers no block (``KvBlockManager.enable_reuse`` is forced off: the
   router is told of no block it could not use).
+
+The three kinds above are ``models/sambay.py``'s layout. ``models/mla.py``'s
+dots3_note has two groups of layers, both made of pool blocks
+(``window_pool``): paged rows of the full-attention layers (TWO arrays under
+one block id: the latent and the index key) and window rows of ANOTHER width
+for the window layers, under block ids of a pool of their own. Window blocks
+are allocated as a context grows and released once wholly behind the window,
+so a running sequence holds at most ``ring_blocks`` of them a layer at any
+length; a released block whose hash is registered stays as evictable cache,
+and a prefix hit needs the paged blocks of the whole prefix AND the window
+blocks of its last ``window`` rows (``KvBlockManager``,
+docs/hybrid_cache.md). With no state, reuse stays on.
 """
 
 from __future__ import annotations
@@ -37,6 +49,9 @@ class HybridCacheLayout:
     window: int
     state_layers: int
     state_bytes: int           # one slot's state of one layer
+    # window rows are blocks of a second pool (dots3_note), not per-slot
+    # rings (phi4flash)
+    window_pool: bool = False
 
     @property
     def ring_blocks(self) -> int:
@@ -47,6 +62,23 @@ class HybridCacheLayout:
     @property
     def has_state(self) -> bool:
         return self.state_layers > 0
+
+    @property
+    def window_reach_blocks(self) -> int:
+        """Window blocks before a block boundary P that a prefix hit ending
+        at P needs: those of the rows [P - window, P)."""
+        return -(-self.window // self.block_size)
+
+    def window_pool_blocks(self, num_blocks: int, max_num_seqs: int,
+                           prefill_tokens: int) -> int:
+        """Blocks of the window pool that goes with a paged pool of
+        ``num_blocks``: what every slot's live window and one prefill
+        dispatch of ``prefill_tokens`` can hold at once (an allocation
+        there never fails), and for half of the paged pool's blocks their
+        window sibling as evictable cache. Derived, not a flag."""
+        live = max_num_seqs * self.ring_blocks
+        prefill = -(-prefill_tokens // self.block_size) + self.ring_blocks
+        return 1 + live + prefill + num_blocks // 2
 
     def blocks_by_kind(self, context_tokens: int) -> dict:
         """Blocks (state: slots) that one sequence of ``context_tokens``
@@ -60,6 +92,8 @@ class HybridCacheLayout:
         """Device bytes of each kind for a pool of ``num_blocks`` and
         ``max_num_seqs`` slots."""
         block = self.block_size * self.row_bytes
+        if self.window_pool:
+            raise ValueError("a window pool is sized by window_pool_blocks")
         return {
             "paged": self.paged_layers * num_blocks * block,
             "window": (self.window_layers * max_num_seqs

@@ -499,6 +499,69 @@ class KvBlockPool:
         return n
 
 
+# priority of a window block that a prefix hit has ended on: what a next
+# hit at that boundary needs, kept while window blocks no hit ended on
+# (priority 0: a prefix's body, a finished request's own tail) go first
+WINDOW_TAIL_PRIORITY = 1
+
+
+class WindowBlockPool(KvBlockPool):
+    """The pool of a window group's blocks (llm/kv/hybrid.py: dots3_note's
+    window layers): block ids of its own, the same hashes as the paged
+    pool's. A running sequence holds only the blocks its window still
+    reaches; a released block whose hash is registered stays as evictable
+    cache for a prefix hit that ends within a window after it."""
+
+    def __init__(self, num_blocks: int, on_tail_evicted=None):
+        super().__init__(num_blocks)
+        self.on_tail_evicted = on_tail_evicted
+        self.released = 0      # blocks let go because the window moved on
+        self.evicted = 0       # cached blocks taken back for new rows
+
+    def has(self, seq_hash: int) -> bool:
+        return seq_hash in self._by_hash
+
+    def hold_hash(self, seq_hash: int) -> int:
+        """One more reference to the block registered under ``seq_hash``
+        (a prefix hit's); it becomes a tail (WINDOW_TAIL_PRIORITY)."""
+        bid = self._by_hash[seq_hash]
+        meta = self._meta[bid]
+        if meta.refcount == 0:
+            self._reusable.pop(bid, None)
+        meta.refcount += 1
+        meta.priority = WINDOW_TAIL_PRIORITY
+        return bid
+
+    def _invalidate(self, bid: int) -> None:
+        meta = self._meta[bid]
+        seq_hash, tail = meta.seq_hash, meta.priority > 0
+        super()._invalidate(bid)
+        meta.priority = 0
+        if seq_hash is not None:
+            self.evicted += 1
+            if tail and self.on_tail_evicted is not None:
+                self.on_tail_evicted(seq_hash)
+
+
+class WindowBlocks:
+    """The window-pool blocks one sequence holds: logical block index →
+    block id, and how many of its leading full blocks were registered."""
+
+    __slots__ = ("held", "registered")
+
+    def __init__(self):
+        self.held: Dict[int, int] = {}
+        self.registered = 0
+
+    def table(self, n: int) -> List[int]:
+        """The block of every logical block < n (0: not held)."""
+        out = [0] * n
+        for i, bid in self.held.items():
+            if i < n:
+                out[i] = bid
+        return out
+
+
 def make_kv_block_pool(num_blocks: int, on_stored=None, on_removed=None,
                        prefer_native: bool = True):
     """Pool factory: the C++ pool (csrc/kv_reuse_pool.cpp) unless the
@@ -540,6 +603,11 @@ class PrefillPlan:
     # off-thread onboard path; a fetch failure clears this list and the
     # engine gracefully recomputes the tail (never an error).
     remote_hashes: List[int] = dataclasses.field(default_factory=list)
+    # a layout with a window pool: the window blocks the hit needs (held),
+    # and the tokens of a longer paged match that were given up because
+    # the window blocks before its boundary were gone
+    win: Optional["WindowBlocks"] = None
+    hit_cut_tokens: int = 0
 
     @property
     def all_blocks(self) -> List[int]:
@@ -568,17 +636,30 @@ class KvBlockManager:
     def __init__(self, num_blocks: int, block_size: int,
                  on_stored=None, on_removed=None, enable_reuse: bool = True,
                  host_pool=None, disk_store=None, remote_store=None,
-                 prefer_native: bool = True, layout=None):
+                 prefer_native: bool = True, layout=None,
+                 win_blocks: int = 0):
         # a hybrid model's kinds of memory (llm/kv/hybrid.py): the pool
-        # below pages the first kind; the window rings and the recurrent
-        # state are per slot and need no allocator. With state, no prefix
-        # can be resumed from a block boundary: nothing is matched and
-        # nothing is registered
+        # below pages the first kind; window rings and recurrent state are
+        # per slot and need no allocator. With state, no prefix can be
+        # resumed from a block boundary: nothing is matched and nothing is
+        # registered. Window rows that are pool blocks (layout.window_pool)
+        # get a pool of their own, and a prefix hit needs both pools' blocks
         self.layout = layout
         self.stateful = layout is not None and layout.has_state
         if self.stateful:
             enable_reuse = False
         self.block_size = block_size
+        self.win_pool: Optional[WindowBlockPool] = None
+        if layout is not None and layout.window_pool:
+            self.win_pool = WindowBlockPool(
+                win_blocks or num_blocks,
+                on_tail_evicted=self._on_window_tail_evicted)
+            # hashes the router was told are gone because a hit through
+            # them would be cut back (their window tail was evicted),
+            # though the paged pool still holds them
+            self._hidden: set = set()
+            self._announce, self._retract = on_stored, on_removed
+            on_removed = self._on_paged_removed
         self.pool = make_kv_block_pool(num_blocks, on_stored=on_stored,
                                        on_removed=on_removed,
                                        prefer_native=prefer_native)
@@ -612,6 +693,19 @@ class KvBlockManager:
             matchable = matchable[:-1]
         hit_blocks = (self.pool.match_prefix(matchable)
                       if self.enable_reuse else [])
+        win, hit_cut = None, 0
+        if self.win_pool is not None:
+            # the hit rule over both groups: keep the longest boundary
+            # whose window blocks are there too, and hold those
+            keep = self._window_cut(matchable, len(hit_blocks))
+            hit_cut = (len(hit_blocks) - keep) * self.block_size
+            self.pool.release(hit_blocks[keep:])
+            hit_blocks = hit_blocks[:keep]
+            win = WindowBlocks()
+            for i in range(max(0, keep - self.layout.window_reach_blocks),
+                           keep):
+                win.held[i] = self.win_pool.hold_hash(matchable[i])
+            win.registered = keep
         hit_tokens = len(hit_blocks) * self.block_size
         host_slots: List[int] = []
         disk_hashes: List[int] = []
@@ -652,6 +746,7 @@ class KvBlockManager:
             new_blocks = self.pool.alloc_uninit(n_new)
             if new_blocks is None:
                 self.pool.release(hit_blocks)
+                self.window_release(win)
                 if disk_hashes:
                     self.disk_store.unpin(disk_hashes)
                 if remote_hashes:
@@ -677,6 +772,7 @@ class KvBlockManager:
                     f"{len(prompt)}, device hits {len(hit_blocks)})")
         except Exception:
             self.pool.release(hit_blocks)
+            self.window_release(win)
             if disk_hashes:
                 self.disk_store.unpin(disk_hashes)
             if remote_hashes:
@@ -685,12 +781,14 @@ class KvBlockManager:
         return PrefillPlan(hit_blocks=hit_blocks, new_blocks=new_blocks,
                            hit_tokens=hit_tokens, seq=seq,
                            host_slots=host_slots, disk_hashes=disk_hashes,
-                           remote_hashes=remote_hashes)
+                           remote_hashes=remote_hashes, win=win,
+                           hit_cut_tokens=hit_cut)
 
     def abort_plan(self, plan: "PrefillPlan") -> None:
         """Release a plan that will never admit: device block holds drop
         and the disk/remote-tier pins (taken at match) release."""
         self.pool.release(plan.all_blocks)
+        self.window_release(plan.win)
         if plan.disk_hashes and self.disk_store is not None:
             self.disk_store.unpin(plan.disk_hashes)
         if plan.remote_hashes and self.remote_store is not None:
@@ -714,3 +812,85 @@ class KvBlockManager:
             self.pool.register(plan_blocks[i], seq.sequence_hashes[i],
                                seq.block_hashes[i], parent, tenant=tenant)
         return min(n_full, len(plan_blocks))
+
+    # ------------------------------------------------- the window group
+    def _window_cut(self, hashes: Sequence[int], n: int) -> int:
+        """The longest boundary n' <= n (in blocks, possibly 0) whose
+        window blocks [n' - reach, n') are all registered: what a hit of n
+        paged blocks is cut back to (docs/hybrid_cache.md, the hit rule)."""
+        reach, has = self.layout.window_reach_blocks, self.win_pool.has
+        while n > 0:
+            gap = next((i for i in range(n - 1, max(0, n - reach) - 1, -1)
+                        if not has(hashes[i])), None)
+            if gap is None:
+                return n
+            n = gap          # no boundary in (gap, gap + reach] can be hit
+        return 0
+
+    def window_grow(self, win: "WindowBlocks", lo: int, hi: int) -> bool:
+        """Window blocks for the logical blocks [lo, hi) that ``win`` does
+        not hold yet. False: the pool is out of blocks (nothing taken)."""
+        need = [i for i in range(lo, hi) if i not in win.held]
+        new = self.win_pool.alloc_uninit(len(need))
+        if new is None:
+            return False
+        win.held.update(zip(need, new))
+        return True
+
+    def window_register(self, win: "WindowBlocks", seq: TokenBlockSequence,
+                        blocks: Sequence[int], computed: int) -> None:
+        """Register the window blocks of ``seq``'s full blocks whose rows
+        are written (those before position ``computed``) under the paged
+        blocks' hashes (``blocks``: the paged table, for the router's sake:
+        a hash it was told is gone comes back with its window block)."""
+        if not self.enable_reuse:
+            return
+        n_full = min(seq.num_full_blocks, computed // self.block_size)
+        for i in range(win.registered, n_full):
+            bid = win.held.get(i)
+            if bid is None:
+                continue
+            h = seq.sequence_hashes[i]
+            parent = seq.sequence_hashes[i - 1] if i > 0 else None
+            self.win_pool.register(bid, h, seq.block_hashes[i], parent)
+            if h in self._hidden and i < len(blocks):
+                self._hidden.discard(h)
+                if self._announce is not None:
+                    self._announce(blocks[i], h, seq.block_hashes[i], parent)
+        win.registered = max(win.registered, n_full)
+
+    def window_slide(self, win: "WindowBlocks", position: int) -> None:
+        """Let go of the blocks wholly behind the window of the query at
+        ``position``: to the evictable cache where registered, else free."""
+        first = max(0, position - (self.layout.window - 1)) // self.block_size
+        gone = [i for i in win.held if i < first]
+        self.win_pool.release([win.held.pop(i) for i in gone])
+        self.win_pool.released += len(gone)
+
+    def window_release(self, win: Optional["WindowBlocks"]) -> None:
+        """Everything ``win`` holds, at finish, cancel and preemption."""
+        if win is not None and win.held:
+            self.win_pool.release(list(win.held.values()))
+            win.held.clear()
+
+    def window_stats(self) -> dict:
+        w = self.win_pool
+        return {"window_blocks_released": w.released,
+                "window_blocks_evicted": w.evicted,
+                "window_blocks_cached": w.reusable_blocks,
+                "window_blocks_used": w.used_blocks}
+
+    def _on_window_tail_evicted(self, seq_hash: int) -> None:
+        # a hit through this block's boundaries would now be cut back: the
+        # router is told the paged block is gone until the window block is
+        # computed again (window_register)
+        if self.pool.peek_prefix([seq_hash]) and seq_hash not in self._hidden:
+            self._hidden.add(seq_hash)
+            if self._retract is not None:
+                self._retract([seq_hash])
+
+    def _on_paged_removed(self, seq_hashes: list) -> None:
+        told = [h for h in seq_hashes if h not in self._hidden]
+        self._hidden.difference_update(seq_hashes)
+        if told and self._retract is not None:
+            self._retract(told)

@@ -44,6 +44,33 @@ def pool_cls(request):
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("counts", [
+    [], [1], [2], [1, 1, 1], [2, 2, 1], [1, 2, 1, 1],
+    [2] * 300 + [1] * 40,            # a cached document behind own blocks
+    [2] * 5 + [1] * 300,             # the own run is longer than a step
+    [1] * 128 + [2] + [1] * 128,     # the shared block sits on a step's edge
+    [1] * 127 + [2] + [1] * 128, [2] * 256, [1] * 256])
+def test_own_suffix_start_is_the_whole_walks_answer(counts):
+    """The defrag scan asks the pool from the end, 128 ids at a time (PR 42):
+    the same index as the walk over every block's refcount that it
+    replaced."""
+    from dynamo_tpu.engine.core import _own_suffix_start
+
+    class Pool:
+        asked = 0
+
+        def refcounts(self, ids):
+            self.asked += len(ids)
+            return [counts[i] for i in ids]
+
+    pool, blocks = Pool(), list(range(len(counts)))
+    j = len(counts)
+    while j > 0 and counts[j - 1] == 1:
+        j -= 1
+    assert _own_suffix_start(pool, blocks) == j
+    assert pool.asked <= len(counts) - j + 128
+
+
 def test_free_run_index_coalesces():
     idx = FreeRunIndex()
     for b in (3, 5, 4, 9, 1):      # 1, 3-4-5 coalesce; 9 alone

@@ -546,6 +546,36 @@ def test_index_scores_kernel_builds_at_the_published_sizes(one_chip):
         assert f"bf16[{B},{M * bs},{dI}]" not in text
 
 
+def test_window_latent_read_builds_at_the_published_sizes(one_chip):
+    """dots3-note-prev's window layers in a decode step as the benchmark's
+    cell serves them (models/mla.py ``attn_s``): the paged-attention kernel
+    in its one-head latent form at 64 heads x 1,152 lanes (rank 1,024 + rope
+    64, padded), v aliased to the first 1,024 lanes, over a table of 34
+    window-pool blocks a sequence with a lower bound (``win_lo``), one wave
+    of 34 blocks, into a six-layer pool of 9,395 blocks."""
+    from dynamo_tpu.engine.config import ModelConfig
+    from dynamo_tpu.engine.models import mla
+    cfg = ModelConfig.from_hf_config(
+        _benchmark_hf("configs/dots3-note-prev.json"))
+    geo = cfg.swa_geometry()
+    B, H, W, bs = 64, geo.num_heads, mla.latent_row_lanes(geo), 16
+    R = mla.swa_ring_blocks(cfg, bs)
+    assert (H, W, R, geo.kv_lora_rank) == (64, 1152, 34, 1024)
+    assert A.pallas_supported(H, 1, W, bs, kv_dtype=jnp.bfloat16)
+
+    def fn(q, pool, tables, lens, lo):
+        return A.paged_attention_pallas(
+            q, pool, pool, tables, lens, block_size=bs, scale=256 ** -0.5,
+            win_lo=lo, v_lanes=geo.kv_lora_rank,
+            chunk_blocks=max(A.ATTN_CHUNK_BLOCKS, R))
+
+    text = _compile(fn, one_chip, ((B, H, W), jnp.bfloat16),
+                    ((6 * 9395 * bs, W), jnp.bfloat16), ((B, R), jnp.int32),
+                    ((B,), jnp.int32), ((B,), jnp.int32)).as_text()
+    assert "paged_attention" in text
+    assert f"bf16[{B},{H},{geo.kv_lora_rank}]" in text      # probs . c
+
+
 @pytest.mark.parametrize("family", ["tiny-dense", "tiny-qwen2moe",
                                     "tiny-deepseek-v2", "tiny-phi4flash"])
 def test_other_families_never_reach_the_index_scores_kernel(family, one_chip,
