@@ -60,6 +60,17 @@ class ServiceMetrics:
             buckets=(0.002, 0.005, 0.01, 0.02, 0.05, 0.1, 0.25, 0.5,
                      1.0, 2.5))
 
+        # the SSE write collector (sse_flush.py): chunks per flush is the
+        # number of streams whose tokens one loop iteration wrote together
+        self.sse_flushes = Counter(
+            f"{PREFIX}_sse_flushes_total",
+            "Passes of the SSE write collector that wrote to a transport",
+            registry=self.registry)
+        self.sse_flushed_chunks = Counter(
+            f"{PREFIX}_sse_flushed_chunks_total",
+            "SSE chunks those passes wrote",
+            registry=self.registry)
+
     def render(self) -> bytes:
         return generate_latest(self.registry)
 

@@ -30,6 +30,7 @@ from ..protocols.openai import (aggregate_chat_stream,
                                 aggregate_completion_stream)
 from ..protocols.sse import encode_annotated, encode_done
 from .metrics import ServiceMetrics
+from .sse_flush import SseWriteCollector
 
 logger = logging.getLogger("dynamo_tpu.http")
 
@@ -277,6 +278,8 @@ class HttpService:
         self.host = host
         self.manager = manager or ModelManager()
         self.metrics = metrics or ServiceMetrics()
+        # the streams' SSE writes, one pass a loop iteration (sse_flush.py)
+        self.sse_writes = SseWriteCollector(self.metrics)
         self.app = web.Application()
         self.app.router.add_post("/v1/chat/completions", self._chat)
         self.app.router.add_post("/v1/completions", self._completions)
@@ -345,6 +348,7 @@ class HttpService:
             last = 64
         return web.json_response({
             "tracer": tracer.stats(),
+            "sse_writes": self.sse_writes.stats(),
             "flight_recorders": {
                 name: {"stats": fr.stats(), "records": fr.dump(last=last)}
                 for name, fr in all_recorders().items()},
@@ -515,6 +519,10 @@ class HttpService:
             ectx.kill()
             raise
 
+        def on_reset():
+            guard.mark_cancelled()
+            ectx.kill()
+
         # Disconnect monitor (reference openai.rs:406): if the client goes
         # away mid-stream, kill() the context so the engine frees its slot.
         # aiohttp has no disconnect future, so poll the transport.
@@ -523,15 +531,18 @@ class HttpService:
                 await asyncio.sleep(0.25)
                 tr = request.transport
                 if tr is None or tr.is_closing():
-                    guard.mark_cancelled()
-                    ectx.kill()
+                    on_reset()
                     return
 
         monitor_task = asyncio.create_task(monitor())
+        # the chunks go out through the service's write collector: handed
+        # over here, written with the other streams' in the loop's next
+        # iteration (sse_flush.py)
+        out = self.sse_writes.open(request, on_reset)
         first_chunk = True
         # stream.first_write (the request's trace): the first token chunk
-        # has been written — with the engine's spans it tiles TTFT from
-        # inside the server (docs/observability.md)
+        # has reached the transport — with the engine's spans it tiles TTFT
+        # from inside the server (docs/observability.md)
         trace = current_trace()
         try:
             async for ann in stream:
@@ -560,22 +571,32 @@ class HttpService:
                 n_tok = _chunk_token_count(chunk)
                 if n_tok:
                     guard.note_token(n_tok)
-                try:
-                    await resp.write(encode_annotated(ann).encode())
-                except (ConnectionResetError, asyncio.CancelledError):
-                    guard.mark_cancelled()
-                    ectx.kill()
-                    return resp
-                if n_tok and trace is not None:
-                    trace.event("stream.first_write")
+                out.put(encode_annotated(ann).encode(),
+                        first_write=trace if n_tok else None)
+                if n_tok:
                     trace = None
+                if out.over_mark:
+                    # back-pressure, this stream's alone: its bytes
+                    # written, its transport drained
+                    await out.drained()
+                if out.broken:
+                    return resp
+            # the last chunk and [DONE] are on the transport before the
+            # response ends
             if not ectx.is_killed:
-                try:
-                    await resp.write(encode_done().encode())
-                    guard.mark_ok()
-                except (ConnectionResetError, asyncio.CancelledError):
-                    guard.mark_cancelled()
+                out.put(encode_done().encode())
+            await out.flushed()
+            if not ectx.is_killed and not out.broken:
+                guard.mark_ok()
+        except asyncio.CancelledError:
+            on_reset()
+            raise
+        except Exception:
+            # what the stream gave before it failed still goes out
+            await out.flushed()
+            raise
         finally:
+            out.close()
             monitor_task.cancel()
             guard.close()
         return resp

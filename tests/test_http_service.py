@@ -401,3 +401,91 @@ async def test_histograms_count_from_the_first_byte(service):
     assert sums["nv_llm_http_service_request_duration_seconds_sum"] >= 0.3
     # the gap between tokens is not moved by it
     assert sums["nv_llm_http_service_inter_token_latency_seconds_sum"] < 0.3
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 44: the SSE write collector (llm/http/sse_flush.py) as the service
+# shows it: the two counters beside the other series, /debug, and a stream
+# that fails after its first chunks
+# ---------------------------------------------------------------------------
+
+
+def _sse_counters(svc) -> dict:
+    return {line.split()[0]: float(line.split()[1])
+            for line in svc.metrics.render().decode().splitlines()
+            if line.startswith("nv_llm_http_service_sse_")
+            and "_total" in line}
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("endpoint,body", [
+    ("/v1/chat/completions",
+     {"messages": [{"role": "user", "content": "a b c"}]}),
+    ("/v1/completions", {"prompt": "a b c"}),
+])
+async def test_sse_flush_counters_are_exported(service, endpoint, body):
+    """One stream alone: a pass a wake-up, a chunk (or the few one wake-up
+    gave) a pass; both series sit in /metrics and add up to the events the
+    client read."""
+    assert _sse_counters(service) == {
+        "nv_llm_http_service_sse_flushes_total": 0.0,
+        "nv_llm_http_service_sse_flushed_chunks_total": 0.0}
+    async with aiohttp.ClientSession() as s:
+        async with s.post(_url(service, endpoint), json={
+                "model": "echo", "stream": True, **body}) as r:
+            assert r.status == 200
+            raw = await r.read()
+        async with s.get(_url(service, "/metrics")) as r:
+            text = await r.text()
+    got = _sse_counters(service)
+    assert got["nv_llm_http_service_sse_flushed_chunks_total"] == \
+        raw.count(b"\n\n")
+    assert 1 <= got["nv_llm_http_service_sse_flushes_total"] <= \
+        got["nv_llm_http_service_sse_flushed_chunks_total"]
+    assert "nv_llm_http_service_sse_flushes_total" in text
+    assert "nv_llm_http_service_sse_flushed_chunks_total" in text
+
+
+@pytest.mark.asyncio
+async def test_debug_shows_the_write_collectors_pending_bytes(service):
+    async with aiohttp.ClientSession() as s:
+        async with s.post(_url(service, "/v1/chat/completions"), json={
+                "model": "echo", "stream": True,
+                "messages": [{"role": "user", "content": "a b"}]}) as r:
+            await r.read()
+        async with s.get(_url(service, "/debug")) as r:
+            body = await r.json()
+    # nothing is left behind a finished stream
+    assert body["sse_writes"] == {"pending_responses": 0, "pending_bytes": 0}
+
+
+class RaisingStreamEngine:
+    """Two chunks, then the stream itself raises (not an error event)."""
+
+    async def generate(self, request):
+        async def gen():
+            yield {"choices": [{"index": 0, "delta": {"content": "one"}}]}
+            yield {"choices": [{"index": 0, "delta": {"content": "two"}}]}
+            raise RuntimeError("stream died")
+        return ResponseStream(gen(), request.ctx)
+
+
+@pytest.mark.asyncio
+async def test_chunks_before_a_stream_failure_still_reach_the_client(service):
+    """What a stream gave before it raised went out when each chunk was
+    written at once; handed to the collector, it still does, and no
+    [DONE] follows."""
+    service.manager.add_chat_model("raising", RaisingStreamEngine())
+    got = b""
+    async with aiohttp.ClientSession() as s:
+        try:
+            async with s.post(_url(service, "/v1/chat/completions"), json={
+                    "model": "raising", "stream": True,
+                    "messages": [{"role": "user", "content": "x"}]}) as r:
+                assert r.status == 200
+                async for piece in r.content.iter_any():
+                    got += piece
+        except aiohttp.ClientError:
+            pass                    # the connection is dropped mid-body
+    assert b'"one"' in got and b'"two"' in got and b"[DONE]" not in got
+    assert 'status="error"' in service.metrics.render().decode()
