@@ -73,9 +73,12 @@ def served_config() -> tuple:
 
 
 # A stage that a Pallas kernel serves is read by the kernel's ``name=``, as
-# ``layer_metrics/kernel.paged_attention_ms.py`` reads its kernel: the names
-# ISSUE 31 reserved. Today both stages are plain XLA and no op carries one.
-KERNELS = {"dsa_select": ("%index_scores",),
+# ``layer_metrics/kernel.paged_attention_ms.py`` reads its kernel. The
+# selection's two: the index scores (``engine/index_scores.py``, PR 36) and
+# the exact top-k (``engine/select_compact.py``, PR 34; its result is
+# ``[B, K]``, which no shape of ``stage_patterns`` holds). The sparse read
+# is plain XLA: no op carries the name ISSUE 31 reserved for it.
+KERNELS = {"dsa_select": ("%index_scores", "%dsa_select_compact"),
            "sparse_attention": ("%sparse_latent_attention",)}
 
 
@@ -122,13 +125,19 @@ def stage_patterns(engine: dict) -> dict:
 
 def stage_ops(ctx: dict, stage: str) -> list:
     """[name, seconds, count] of the traced ops that are ``stage``'s: those
-    a kernel of KERNELS names, and those the shapes tell. Where a kernel
-    serves ``sparse_attention`` the shapes are not asked (its result is the
-    ``[B, H, rank]`` that the absorbed query also has); the selection keeps
-    them beside a kernel for the index scores, because its top-k stays an
-    XLA sort."""
+    a kernel of KERNELS names, and those the shapes tell. A kernel's call
+    counts where its result has the decode batch first: a prefill chunk
+    selects through the same ``dsa_select_compact`` for its 256 rows
+    (``s32[256, K]`` against the step's ``s32[B, K]``), and the stage is a
+    decode step's. Where a kernel serves ``sparse_attention`` the shapes
+    are not asked (its result is the ``[B, H, rank]`` that the absorbed
+    query also has); the selection keeps them beside its kernels, because
+    the ``[B, S]`` ops around those (the scores' relayout, the order bits,
+    the packed key, the runs table) stay plain XLA."""
     ops = (ctx.get("trace") or {}).get("ops", ())
-    named = [op for op in ops if op[0].startswith(KERNELS[stage])]
+    batch = (ctx.get("engine") or {}).get("max_num_seqs")
+    named = [op for op in ops if op[0].startswith(KERNELS[stage])
+             and (not batch or f"[{batch}," in op[0])]
     if named and stage == "sparse_attention":
         return named
     pattern = stage_patterns(ctx.get("engine") or {}).get(stage)

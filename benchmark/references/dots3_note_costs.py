@@ -32,8 +32,9 @@ PROGRAM = "jit_decode_k"
 # the window read is the paged-attention kernel in its one-head latent form;
 # the full-attention layers of this model never call it (they select)
 SWA_KERNEL = "%paged_attention"
-# stages a Pallas kernel serves, by the kernel's ``name=``
-KERNELS = {"dsa_select": ("%index_scores",),
+# stages a Pallas kernel serves, by the kernel's ``name=`` (the selection's
+# index scores and its exact top-k)
+KERNELS = {"dsa_select": ("%index_scores", "%dsa_select_compact"),
            "sparse_attention": ("%sparse_latent_attention",)}
 
 
@@ -122,11 +123,15 @@ def stage_patterns(engine: dict) -> dict:
 
 
 def stage_ops(ctx: dict, stage: str) -> list:
-    """[name, seconds, count] of the traced ops that are ``stage``'s."""
+    """[name, seconds, count] of the traced ops that are ``stage``'s; a
+    kernel's call counts where its result has the decode batch first (a
+    prefill chunk selects through the same ``dsa_select_compact``)."""
     ops = (ctx.get("trace") or {}).get("ops", ())
     if stage == "swa_latent":
         return [op for op in ops if op[0].startswith(SWA_KERNEL)]
-    named = [op for op in ops if op[0].startswith(KERNELS[stage])]
+    batch = (ctx.get("engine") or {}).get("max_num_seqs")
+    named = [op for op in ops if op[0].startswith(KERNELS[stage])
+             and (not batch or f"[{batch}," in op[0])]
     if named and stage == "sparse_attention":
         return named
     pattern = stage_patterns(ctx.get("engine") or {}).get(stage)

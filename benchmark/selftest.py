@@ -220,6 +220,121 @@ def reader_check() -> None:
           and read({"trace": {"busy_s": 1.0, "window_s": 2.0}}) is None
           and read({"trace": dict(trace, ops=trace["ops"][:1])}) is None,
           "no decode dispatch or no such op in the trace: nothing to read")
+    # the selection's two kernels by their names, in both configurations'
+    # readers: per step as PERF.md §5 has them (PR 36's traced run of
+    # deepseek-v3.2.docqa-closed, 85 steps of 7 layers: index scores 4.27
+    # ms, the exact top-k 1.14, unseen until PR 45); a prefill chunk's
+    # top-k for its 256 rows is the same kernel and not the step's
+    trace = {"ops": [["%fusion.1473 bf16[131072,640] fusion", 1.18235, 595],
+                     ["%index_scores.12 f32[64,17,1024] custom-call "
+                      "tpu_custom_call", 0.36295, 595],
+                     ["%dsa_select_compact.5 s32[64,2048] custom-call "
+                      "tpu_custom_call", 0.0969, 595],
+                     ["%dsa_select_compact.9 s32[256,2048] custom-call "
+                      "tpu_custom_call", 0.0598, 91]],
+             "programs": [["jit_decode_k", 3.329, 85],
+                          ["jit_prefill", 1.105, 13]]}
+    engine = {"max_num_seqs": 64}
+    for name in ("kernel.dsa_select_ms", "kernel.dsa_select_ms.dots"):
+        read = bench_run.metric_reader("per_layer", name)
+        got = read({"trace": trace, "engine": engine})
+        check(abs(got - 5.41) < 1e-9
+              and abs(read({"trace": dict(trace, ops=trace["ops"][:2]),
+                            "engine": engine}) - 4.27) < 1e-9,
+              f"{name}: %index_scores* and %dsa_select_compact* with the "
+              f"decode batch first, over the dispatches of jit_decode_k, "
+              f"in ms ({got:.2f})")
+
+
+PER_LAYER_LIMIT = 128       # the contract's
+OPEN_LOOP_METRICS = ("ttft_p50_ms", "itl_p95_ms")
+
+
+def reader_file(name: str):
+    """The file under ``layer_metrics/`` that ``run.metric_reader`` loads
+    for the ``per_layer`` entry ``name``; None where it finds none."""
+    try:
+        read = bench_run.metric_reader("per_layer", name)
+    except FileNotFoundError:
+        return None
+    return os.path.basename(read.__code__.co_filename)
+
+
+def reader_files() -> list:
+    return sorted(os.path.basename(p) for p in glob.glob(
+        os.path.join(HERE, "layer_metrics", "*.py")))
+
+
+def named_reader_files(bench: dict) -> set:
+    return {reader_file(e["name"]) for e in bench["per_layer"]}
+
+
+def entry_cells(bench: dict, entry: dict) -> list:
+    return entry.get("workloads", [w["name"] for w in bench["workloads"]])
+
+
+def entry_faults(bench: dict, entry: dict) -> list:
+    """What is wrong with one ``per_layer`` entry: no reader file behind
+    its name, a listed cell that is none, or a cell that does not report
+    the end-to-end metric the entry moves (``ttft_p50_ms`` / ``itl_p95_ms``:
+    the open loops alone)."""
+    name, moves = entry["name"], entry["moves"]
+    faults = []
+    if reader_file(name) is None:
+        faults.append(f"{name}: no layer_metrics/ file reads it")
+    cells = {w["name"]: w for w in bench["workloads"]}
+    target = next(m for m in bench["end_to_end"] if m["name"] == moves)
+    for cell in entry_cells(bench, entry):
+        if cell not in cells:
+            faults.append(f"{name}: lists {cell}, which is no cell")
+            continue
+        if cell not in entry_cells(bench, target):
+            faults.append(f"{name}: {cell} does not report {moves}")
+        if moves in OPEN_LOOP_METRICS:
+            with open(os.path.join(HERE, "traffic",
+                                   f"{cells[cell]['traffic']}.json")) as f:
+                loop = json.load(f)["loop"]
+            if loop != "open":
+                faults.append(f"{name}: moves {moves} in the {loop} loop "
+                              f"{cell}")
+    return faults
+
+
+def double_reports(bench: dict) -> list:
+    """(reader file, cell, moves) reported under two entries: a copy. One
+    quantity under two names is what filled ``per_layer`` to its limit by
+    PR 42 (a ``model_config`` PR brings ``<metric>.<group>`` entries; the
+    next ``benchmark`` PR folds them into the lists: README.md)."""
+    seen, twice = {}, []
+    for entry in bench["per_layer"]:
+        file = reader_file(entry["name"])
+        for cell in entry_cells(bench, entry):
+            key = (file, cell, entry["moves"])
+            if key in seen:
+                twice.append(f"{file} in {cell} moving {entry['moves']}: "
+                             f"{seen[key]} and {entry['name']}")
+            seen[key] = entry["name"]
+    return twice
+
+
+def layout() -> None:
+    """``BENCHMARK.json``'s ``per_layer`` against ``layer_metrics/``
+    (``test_layout.py`` runs the same rules one entry and one file a
+    case)."""
+    bench = bench_run.load_benchmark()
+    faults = [f for e in bench["per_layer"] for f in entry_faults(bench, e)]
+    check(not faults, "every per_layer entry has its reader file, lists "
+          f"cells that exist and that report what it moves {faults or ''}")
+    named = named_reader_files(bench)
+    idle = [f for f in reader_files() if f not in named]
+    check(not idle, f"every file under layer_metrics/ is named by an entry "
+          f"{idle or ''}")
+    twice = double_reports(bench)
+    check(not twice, "no reader is reported twice in one cell under the "
+          f"same moves {twice or ''}")
+    check(len(bench["per_layer"]) <= PER_LAYER_LIMIT,
+          f"per_layer holds {len(bench['per_layer'])} entries of the "
+          f"contract's {PER_LAYER_LIMIT}")
 
 
 def reference_lookup() -> None:
@@ -393,6 +508,7 @@ def main() -> int:
     window_arithmetic()
     trace_reduction()
     reader_check()
+    layout()
     reference_lookup()
     names = fixtures()
     check(len(names) >= 3, f"fixtures found by their file names: {names}")

@@ -20,7 +20,7 @@ import selftest  # noqa: E402
 
 LIMIT_S = 60
 CHECKS = (selftest.arithmetic, selftest.generator, selftest.window_arithmetic,
-          selftest.trace_reduction, selftest.reader_check,
+          selftest.trace_reduction, selftest.reader_check, selftest.layout,
           selftest.reference_lookup)
 
 
