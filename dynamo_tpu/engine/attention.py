@@ -184,11 +184,30 @@ def softcap_scores(scores: jax.Array, cap) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+def sink_softmax(scores: jax.Array, sink: jax.Array | None) -> jax.Array:
+    """softmax over the last axis; with ``sink`` (broadcastable to
+    scores[..., 0]) the denominator also holds exp(sink): a learned scalar
+    a query head that takes mass and adds no value (mimo_v2's window
+    layers). It is no extra key: the result keeps scores' shape and its
+    rows sum to less than 1."""
+    if sink is None:
+        return jax.nn.softmax(scores, axis=-1)
+    sink = sink.astype(scores.dtype)[..., None]
+    m = jnp.maximum(jnp.max(scores, axis=-1, keepdims=True), sink)
+    p = jnp.exp(scores - m)
+    return p / (jnp.sum(p, axis=-1, keepdims=True) + jnp.exp(sink - m))
+
+
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      *, scale: float, kv_offset: int = 0,
-                     length: jax.Array | None = None) -> jax.Array:
-    """q: [T, H, Dh], k/v: [S, KVH, Dh]. Causal with query i attending to
-    kv j where j <= i + kv_offset. `length` masks padded kv positions."""
+                     length: jax.Array | None = None,
+                     window: int | None = None,
+                     sink: jax.Array | None = None) -> jax.Array:
+    """q: [T, H, Dh], k: [S, KVH, Dh], v: [S, KVH, Dv] (Dv may differ from
+    Dh). Causal with query i attending to kv j where j <= i + kv_offset.
+    `length` masks padded kv positions; ``window`` keeps the last `window`
+    of them, the query's own included; ``sink`` [H]: see sink_softmax.
+    Returns [T, H, Dv]."""
     T, H, Dh = q.shape
     S, KVH, _ = k.shape
     g = H // KVH
@@ -199,10 +218,16 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     mask = kpos <= qpos
     if length is not None:
         mask = mask & (kpos < length)
+    if window is not None:
+        mask = mask & (kpos > qpos - window)
     scores = jnp.where(mask[None, None, :, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    if sink is None:
+        probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    else:
+        probs = sink_softmax(
+            scores, sink.reshape(KVH, g, 1)).astype(v.dtype)
     out = jnp.einsum("kgts,skd->tkgd", probs, v)
-    return out.reshape(T, H, Dh)
+    return out.reshape(T, H, v.shape[-1])
 
 
 # ---------------------------------------------------------------------------
@@ -230,10 +255,14 @@ def _flash_prefill_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref,
                           *, q_chunk: int, kv_chunk: int, g: int,
                           scale: float, window: int | None,
                           softcap: float | None,
-                          ml_ref=None):
+                          ml_ref=None, sink_ref=None):
     """meta_ref (SMEM): [start_pos, seq_len, sliding]; q_ref: [1, TQ*g, Dh];
-    k_ref/v_ref: [1, SC, Dh]; o_ref: [1, TQ*g, Dh]; m/l: [TQ*g, 1] f32;
-    acc: [TQ*g, Dh] f32.
+    k_ref: [1, SC, Dh]; v_ref: [1, SC, Dv]; o_ref: [1, TQ*g, Dv]; m/l:
+    [TQ*g, 1] f32; acc: [TQ*g, Dv] f32.
+
+    ``sink_ref`` [1, TQ*g, 1] f32 set → every row's running state starts
+    at (m, l) = (sink, 1) = exp(sink - m): the sink is in the softmax's
+    denominator from the first chunk on, and adds no value.
 
     ``ml_ref`` set → PARTIAL mode (ring attention, attention.py
     flash_prefill_partial): o gets the UNNORMALIZED f32 accumulator and
@@ -273,8 +302,12 @@ def _flash_prefill_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref,
     def _():
         @pl.when(sc == first)
         def _():
-            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-            l_ref[:] = jnp.zeros_like(l_ref)
+            if sink_ref is None:
+                m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+                l_ref[:] = jnp.zeros_like(l_ref)
+            else:
+                m_ref[:] = sink_ref[0]
+                l_ref[:] = jnp.ones_like(l_ref)
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
         q = q_ref[0]                               # [TQ*g, Dh]
@@ -340,22 +373,29 @@ def _flash_layout(q, k, v, q_chunk: int, kv_chunk: int):
 
 
 def _flash_grid_spec(KVH: int, n_tq: int, n_sc: int, tqg: int, Dh: int,
-                     kv_chunk: int, out_specs):
+                     kv_chunk: int, out_specs, Dv: int | None = None,
+                     sink: bool = False):
+    Dv = Dh if Dv is None else Dv
+    in_specs = [
+        pl.BlockSpec((1, tqg, Dh), lambda kh, tq, sc, *_: (kh, tq, 0)),
+        pl.BlockSpec((1, kv_chunk, Dh),
+                     lambda kh, tq, sc, *_: (kh, sc, 0)),
+        pl.BlockSpec((1, kv_chunk, Dv),
+                     lambda kh, tq, sc, *_: (kh, sc, 0)),
+    ]
+    if sink:
+        # one q chunk's rows of the kv head's sinks: the same for every tq
+        in_specs.append(pl.BlockSpec((1, tqg, 1),
+                                     lambda kh, tq, sc, *_: (kh, 0, 0)))
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(KVH, n_tq, n_sc),
-        in_specs=[
-            pl.BlockSpec((1, tqg, Dh), lambda kh, tq, sc, *_: (kh, tq, 0)),
-            pl.BlockSpec((1, kv_chunk, Dh),
-                         lambda kh, tq, sc, *_: (kh, sc, 0)),
-            pl.BlockSpec((1, kv_chunk, Dh),
-                         lambda kh, tq, sc, *_: (kh, sc, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((tqg, 1), jnp.float32),     # m
             pltpu.VMEM((tqg, 1), jnp.float32),     # l
-            pltpu.VMEM((tqg, Dh), jnp.float32),    # acc
+            pltpu.VMEM((tqg, Dv), jnp.float32),    # acc
         ],
     )
 
@@ -371,14 +411,20 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   window: int | None = None,
                   softcap: float | None = None,
                   q_chunk: int = 128, kv_chunk: int = 256,
-                  interpret: bool = False) -> jax.Array:
+                  interpret: bool = False,
+                  sink: jax.Array | None = None,
+                  name: str = "flash_prefill") -> jax.Array:
     """Flash causal attention for prefill. q: [T, H, Dh] (query t sits at
-    absolute position start_pos + t); k/v: [S, KVH, Dh] dense, positions
-    0..S (prefix + chunk, as gathered from the paged pool); seq_len masks
-    kv padding; `sliding` (traced bool) applies the static `window` to
-    this layer (gemma2 interleaving). Returns [T, H, Dh]."""
+    absolute position start_pos + t); k: [S, KVH, Dh], v: [S, KVH, Dv]
+    dense, positions 0..S (prefix + chunk, as gathered from the paged
+    pool; Dv may differ from Dh); seq_len masks kv padding; `sliding`
+    (traced bool) applies the static `window` to this layer (gemma2
+    interleaving); ``sink`` [H] float32: one scalar a query head in the
+    softmax's denominator (the kernel's docstring); ``name``: the Pallas
+    call's, for a trace to tell one read from another. Returns
+    [T, H, Dv]."""
     T, H, Dh = q.shape
-    KVH = k.shape[1]
+    KVH, Dv = k.shape[1], v.shape[2]
     qr, kr, vr, Tp, Sp, g = _flash_layout(q, k, v, q_chunk, kv_chunk)
     meta = jnp.stack([jnp.asarray(start_pos, jnp.int32),
                       jnp.asarray(seq_len, jnp.int32),
@@ -388,39 +434,54 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
     tqg = q_chunk * g
     grid_spec = _flash_grid_spec(
         KVH, n_tq, n_sc, tqg, Dh, kv_chunk,
-        out_specs=pl.BlockSpec((1, tqg, Dh),
-                               lambda kh, tq, sc, *_: (kh, tq, 0)))
+        out_specs=pl.BlockSpec((1, tqg, Dv),
+                               lambda kh, tq, sc, *_: (kh, tq, 0)),
+        Dv=Dv, sink=sink is not None)
     kernel = functools.partial(
         _flash_prefill_kernel, q_chunk=q_chunk, kv_chunk=kv_chunk, g=g,
         scale=scale, window=window, softcap=softcap)
+    operands = (meta, qr, kr, vr)
+    if sink is not None:
+        plain = kernel
+
+        def kernel(meta_ref, q_ref, k_ref, v_ref, sink_ref, o_ref, m_ref,
+                   l_ref, acc_ref):
+            plain(meta_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                  acc_ref, sink_ref=sink_ref)
+
+        # row (t, j) of kv head kh's q chunk is query head kh * g + j
+        operands += (jnp.tile(
+            sink.astype(jnp.float32).reshape(KVH, 1, g),
+            (1, q_chunk, 1)).reshape(KVH, tqg, 1),)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((KVH, Tp * g, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((KVH, Tp * g, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_prefill",
-    )(meta, qr, kr, vr)
-    return _flash_unpack(out, KVH, Tp, g, Dh, T)
+        name=name,
+    )(*operands)
+    return _flash_unpack(out, KVH, Tp, g, Dv, T)
 
 
 def flash_prefill_partial(q: jax.Array, k: jax.Array, v: jax.Array, *,
                           scale: float, start_pos: jax.Array,
                           seq_len: jax.Array,
                           q_chunk: int = 128, kv_chunk: int = 256,
-                          interpret: bool = False) -> tuple:
+                          interpret: bool = False,
+                          name: str = "flash_prefill_partial") -> tuple:
     """Flash attention returning UNNORMALIZED partial state for cross-chunk
     combination (ring attention: each hop computes a partial against one
     KV chunk; hops merge with the online-softmax recurrence).
 
     q: [T, H, Dh] at absolute positions start_pos + t (start_pos may be
     NEGATIVE — queries before this KV chunk are fully masked and
-    contribute zeros); k/v: [S, KVH, Dh] at positions 0..seq_len.
-    Returns (acc [T, H, Dh] f32, m [T, H] f32, l [T, H] f32).
+    contribute zeros); k: [S, KVH, Dh], v: [S, KVH, Dv] at positions
+    0..seq_len. Returns (acc [T, H, Dv] f32, m [T, H] f32, l [T, H] f32).
     """
     T, H, Dh = q.shape
-    KVH = k.shape[1]
+    KVH, Dv = k.shape[1], v.shape[2]
     qr, kr, vr, Tp, Sp, g = _flash_layout(q, k, v, q_chunk, kv_chunk)
     meta = jnp.stack([jnp.asarray(start_pos, jnp.int32),
                       jnp.asarray(seq_len, jnp.int32),
@@ -431,9 +492,9 @@ def flash_prefill_partial(q: jax.Array, k: jax.Array, v: jax.Array, *,
     grid_spec = _flash_grid_spec(
         KVH, n_tq, n_sc, tqg, Dh, kv_chunk,
         out_specs=[
-            pl.BlockSpec((1, tqg, Dh), lambda kh, tq, sc, *_: (kh, tq, 0)),
+            pl.BlockSpec((1, tqg, Dv), lambda kh, tq, sc, *_: (kh, tq, 0)),
             pl.BlockSpec((1, tqg, 2), lambda kh, tq, sc, *_: (kh, tq, 0)),
-        ])
+        ], Dv=Dv)
 
     def kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, ml_ref,
                m_ref, l_ref, acc_ref):
@@ -445,26 +506,27 @@ def flash_prefill_partial(q: jax.Array, k: jax.Array, v: jax.Array, *,
     acc, ml = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((KVH, Tp * g, Dh), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((KVH, Tp * g, Dv), jnp.float32),
                    jax.ShapeDtypeStruct((KVH, Tp * g, 2), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_prefill_partial",
+        name=name,
     )(meta, qr, kr, vr)
 
-    acc = _flash_unpack(acc, KVH, Tp, g, Dh, T)
+    acc = _flash_unpack(acc, KVH, Tp, g, Dv, T)
     ml = _flash_unpack(ml, KVH, Tp, g, 2, T)
     return acc, ml[:, :, 0], ml[:, :, 1]
 
 
 def flash_prefill_supported(num_heads: int, num_kv_heads: int,
-                            head_dim: int) -> bool:
+                            head_dim: int, v_dim: int | None = None) -> bool:
     """The flash prefill kernel handles any GQA geometry with 8-aligned
     head dims (lanes are padded to 128 by Mosaic; sub-8 dims aren't worth
-    tiling)."""
+    tiling); ``v_dim``: the value heads', where they differ."""
     return (num_heads % num_kv_heads == 0 and head_dim % 8 == 0
-            and head_dim >= 8)
+            and head_dim >= 8
+            and (v_dim is None or (v_dim % 8 == 0 and v_dim >= 8)))
 
 
 # ---------------------------------------------------------------------------
@@ -493,25 +555,33 @@ def paged_attention_xla(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                         *, block_size: int, scale: float,
                         softcap: float | None = None,
                         win_lo: jax.Array | None = None,
-                        kv_heads: int | None = None) -> jax.Array:
+                        kv_heads: int | None = None,
+                        v_dim: int | None = None,
+                        sink: jax.Array | None = None) -> jax.Array:
     """q: [B, H, Dh]; k_cache/v_cache: [NTOK, KVH*Dh] (block-major pool;
     int8 pools carry KV_SCALE_LANES extra in-row scale lanes — one group,
     or ``kv_heads`` sizes the value lanes of a tp-grouped row — and
     dequantize after the gather); block_tables: [B, M] int32; seq_lens:
-    [B] (kv length incl. current token). Returns [B, H, Dh]."""
+    [B] (kv length incl. current token). ``v_dim``: the value heads' size
+    where it is not Dh (v_cache [NTOK, KVH*v_dim]; full-precision pools);
+    ``sink`` [H]: see sink_softmax. Returns [B, H, v_dim or Dh]."""
     B, H, Dh = q.shape
     C = kv_heads * Dh if kv_heads is not None else kv_value_lanes(k_cache)
     KVH = C // Dh
     g = H // KVH
+    Dv = Dh if v_dim is None else v_dim
     idx = flat_token_indices(block_tables, block_size)        # [B, T]
     T = idx.shape[1]
     k = jnp.take(k_cache, idx, axis=0)
     v = jnp.take(v_cache, idx, axis=0)
     if k_cache.dtype == jnp.int8:
+        if Dv != Dh:
+            raise ValueError("int8 pools have no encoding for value heads "
+                             "of another size than the keys'")
         k = dequant_kv_rows(k, C, q.dtype)
         v = dequant_kv_rows(v, C, q.dtype)
     k = k.reshape(B, T, KVH, Dh)
-    v = v.reshape(B, T, KVH, Dh)
+    v = v.reshape(B, T, KVH, Dv)
     qg = q.reshape(B, KVH, g, Dh)
     scores = jnp.einsum("bkgd,btkd->bkgt", qg, k).astype(jnp.float32) * scale
     if softcap:
@@ -520,9 +590,12 @@ def paged_attention_xla(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     if win_lo is not None:   # sliding-window layers: trailing window only
         mask = mask & (jnp.arange(T)[None, :] > win_lo[:, None])
     scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    if sink is None:
+        probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    else:
+        probs = sink_softmax(scores, sink.reshape(1, KVH, g)).astype(v.dtype)
     out = jnp.einsum("bkgt,btkd->bkgd", probs, v)
-    return out.reshape(B, H, Dh)
+    return out.reshape(B, H, Dv)
 
 
 # ---------------------------------------------------------------------------
@@ -761,11 +834,15 @@ def _paged_attn_kernel(block_tables_ref, seq_lens_ref, win_lo_ref,
                        quant_lanes: int | None = None,
                        v_lanes: int | None = None,
                        quant_sections: tuple | None = None,
-                       coalesce: bool = True):
+                       coalesce: bool = True, sink_ref=None):
     """q_ref: [G, Hp, C] sparse-slotted (VMEM); k_hbm/v_hbm: [NTOK, Cx]
-    (HBM); o_ref: [G, Hp, C]; k_bufs/v_bufs: [2, chunk*block_size, Cx]
-    double buffers; sems: DMA semaphore pair; m/l: [Hp, 1]; acc: [Hp, C]
-    f32; wave_ref: [1] SMEM global wave-parity carried ACROSS programs;
+    (HBM; value heads of another size than the keys': v_hbm, v_bufs, acc
+    and o_ref are KVH*Dv wide, nothing else differs); o_ref: [G, Hp, C];
+    k_bufs/v_bufs: [2, chunk*block_size, Cx] double buffers; sems: DMA
+    semaphore pair; m/l: [Hp, 1]; acc: [Hp, C] f32; sink_ref: [Hp, 1] f32
+    or None: one scalar a query head that starts the running state at
+    (m, l) = (sink, 1), so it is in the softmax's denominator and adds no
+    value; wave_ref: [1] SMEM global wave-parity carried ACROSS programs;
     runs_ref: [B, n_waves] SMEM per-wave coalescibility
     (wave_contig_table) — with ``coalesce`` a flagged wave streams as
     ONE contiguous chunk-block copy per KV stream instead of `chunk`
@@ -924,16 +1001,24 @@ def _paged_attn_kernel(block_tables_ref, seq_lens_ref, win_lo_ref,
             # scratch init, no carry reads, no epilogue divide pass
             sm, v = ws(start_ci, jax.lax.rem(p0, 2))
             m = jnp.max(sm, axis=1, keepdims=True)
+            if sink_ref is not None:
+                m = jnp.maximum(m, sink_ref[:])
             p = jnp.exp(sm - m)
             l = jnp.sum(p, axis=1, keepdims=True)
+            if sink_ref is not None:
+                l = l + jnp.exp(sink_ref[:] - m)
             o_ref[s] = (jax.lax.dot_general(
                 p, v, (((1,), (0,)), ((), ())))
                 / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
 
         @pl.when(~one_wave)
         def _(s=s, start_ci=start_ci, num_chunks=num_chunks, body=body):
-            m_ref[:] = jnp.full_like(m_ref, NEG_INF)  # online-softmax
-            l_ref[:] = jnp.zeros_like(l_ref)          # carry state
+            if sink_ref is None:
+                m_ref[:] = jnp.full_like(m_ref, NEG_INF)  # online-softmax
+                l_ref[:] = jnp.zeros_like(l_ref)          # carry state
+            else:
+                m_ref[:] = sink_ref[:]
+                l_ref[:] = jnp.ones_like(l_ref)
             acc_ref[:] = jnp.zeros_like(acc_ref)
             jax.lax.fori_loop(start_ci, num_chunks, body, 0)
             o_ref[s] = (acc_ref[:] /
@@ -955,7 +1040,10 @@ def paged_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                            v_lanes: int | None = None,
                            quant_sections: tuple | None = None,
                            coalesce: bool = True,
-                           interpret: bool = False) -> jax.Array:
+                           interpret: bool = False,
+                           v_dim: int | None = None,
+                           sink: jax.Array | None = None,
+                           name: str = "paged_attention") -> jax.Array:
     """Same contract as `paged_attention_xla`; KV stays in HBM and streams
     chunk-by-chunk with double buffering (no [B, M*BS] gather). Sliding
     windows are in-kernel (win_lo: [B], -1 for global layers). int8 pools
@@ -990,12 +1078,17 @@ def paged_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         C = kv_value_lanes(k_cache)
     KVH = C // Dh
     if not pallas_supported(H, KVH, Dh, block_size,
-                            kv_dtype=k_cache.dtype):
+                            kv_dtype=k_cache.dtype, v_dim=v_dim):
         raise ValueError(
             f"unsupported pallas geometry (H={H}, KVH={KVH}, Dh={Dh}, "
-            f"block_size={block_size}, kv={k_cache.dtype}): needs "
-            f"KVH*Dh % 128 == 0 and block_size % 8 == 0 (int8 pools: "
-            f"% 32, the int8 sublane tile) — see pallas_supported")
+            f"v_dim={v_dim}, block_size={block_size}, "
+            f"kv={k_cache.dtype}): needs KVH*Dh % 128 == 0 (and "
+            f"KVH*v_dim, on a full-precision pool) and block_size % 8 == 0 "
+            f"(int8 pools: % 32, the int8 sublane tile) — see "
+            f"pallas_supported")
+    if v_dim is not None and v_lanes is not None:
+        raise ValueError("v_dim (a v pool of its own width) and v_lanes "
+                         "(v aliases k) exclude each other")
     if v_lanes is not None and (KVH != 1 or v_lanes % 128 != 0
                                 or v_lanes > C):
         raise ValueError(
@@ -1017,7 +1110,9 @@ def paged_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         raise ValueError(
             "v_lanes on a single-scale int8 pool is not supported "
             "(sectioned MLA pools pass quant_sections)")
-    Cv = C if v_lanes is None else v_lanes
+    Dv = Dh if v_dim is None else v_dim
+    Cv = KVH * Dv if v_lanes is None else v_lanes
+    Cvx = Cx if v_dim is None else v_cache.shape[1]
     g = H // KVH
     M = block_tables.shape[1]
     if chunk_blocks is None:
@@ -1050,14 +1145,21 @@ def paged_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             if coalesce else
             jnp.zeros((Bp, -(-M // chunk)), jnp.int32))
 
+    operands = (qm, k_cache, v_cache)
+    in_specs = [
+        pl.BlockSpec((G, Hp, C), lambda b, *_: (b, 0, 0)),
+        pl.BlockSpec(memory_space=pltpu.ANY),   # k_cache stays in HBM
+        pl.BlockSpec(memory_space=pltpu.ANY),   # v_cache stays in HBM
+    ]
+    if sink is not None:
+        in_specs.append(pl.BlockSpec((Hp, 1), lambda b, *_: (0, 0)))
+        operands += (jnp.zeros((Hp, 1), jnp.float32).at[:H, 0].set(
+            sink.astype(jnp.float32)),)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(Bp // G,),
-        in_specs=[
-            pl.BlockSpec((G, Hp, C), lambda b, *_: (b, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # k_cache stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),   # v_cache stays in HBM
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((G, Hp, Cv), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((Hp, 1), jnp.float32),                 # m
@@ -1066,7 +1168,7 @@ def paged_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             pltpu.VMEM((2, chunk * block_size, Cx), k_cache.dtype),
             # v buffers shrink to a dummy tile when v aliases k
             # (32 sublanes: the int8 tile, legal for every dtype)
-            pltpu.VMEM((2, chunk * block_size, Cx)
+            pltpu.VMEM((2, chunk * block_size, Cvx)
                        if v_lanes is None else (1, 32, 128),
                        v_cache.dtype),
             pltpu.SemaphoreType.DMA((2,)),
@@ -1075,48 +1177,55 @@ def paged_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     )
 
     def kernel(block_tables_ref, seq_lens_ref, win_lo_ref, runs_ref,
-               q_ref, k_hbm, v_hbm, o_ref, m_ref, l_ref, acc_ref,
-               k_bufs, v_bufs, sems, wave_ref):
+               q_ref, k_hbm, v_hbm, *rest):
+        # rest: [sink_ref,] o_ref, m_ref, l_ref, acc_ref, k_bufs, v_bufs,
+        # sems, wave_ref
+        sink_ref = rest[0] if sink is not None else None
         _paged_attn_kernel(
             block_tables_ref, seq_lens_ref, win_lo_ref, runs_ref,
-            q_ref, k_hbm, v_hbm, o_ref,
-            m_ref, l_ref, acc_ref, k_bufs, v_bufs, sems, wave_ref,
+            q_ref, k_hbm, v_hbm, *rest[sink is not None:],
             block_size=block_size, chunk=chunk, scale=scale,
             num_seqs=Bp, seqs_per_program=G, softcap=softcap,
             quant_lanes=(C if quantized and quant_sections is None
                          else None),
             v_lanes=v_lanes, quant_sections=quant_sections,
-            coalesce=coalesce)
+            coalesce=coalesce, sink_ref=sink_ref)
 
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Bp, Hp, Cv), q.dtype),
         interpret=interpret,
-        name="paged_attention",
-    )(block_tables, seq_lens, jnp.asarray(win_lo, jnp.int32), runs, qm,
-      k_cache, v_cache)
+        name=name,
+    )(block_tables, seq_lens, jnp.asarray(win_lo, jnp.int32), runs,
+      *operands)
     if v_lanes is not None:
         # MQA: every head's slot is the whole row — no extraction
         return out[:B, :H]
     # row h's useful lanes are its kv head's slot; the rest is cross-slot
     # garbage by construction
-    out = out.reshape(Bp, Hp, KVH, Dh)[:B, :H]
+    out = out.reshape(Bp, Hp, KVH, Dv)[:B, :H]
     kh = (jnp.arange(H) // g)[None, :, None, None]
-    return jnp.take_along_axis(out, kh, axis=2)[:, :, 0].reshape(B, H, Dh)
+    return jnp.take_along_axis(out, kh, axis=2)[:, :, 0].reshape(B, H, Dv)
 
 
 def pallas_supported(num_heads: int, num_kv_heads: int, head_dim: int,
-                     block_size: int, kv_dtype=None) -> bool:
+                     block_size: int, kv_dtype=None,
+                     v_dim: int | None = None) -> bool:
     """True if the Pallas decode kernel handles this geometry: the packed
     lane width KVH*Dh must be lane-aligned (128) and KV blocks must be
     8-sublane aligned — 32 for int8 pools (the int8 sublane tile; DMA
     slices must be tile-aligned). Tiny test models (KVH*Dh < 128) fall
-    back to XLA."""
+    back to XLA. ``v_dim`` (value heads of another size than the keys'):
+    the v row KVH*v_dim must be lane-aligned too, on a full-precision
+    pool (int8 rows have one encoding, of one width)."""
     sublane = 32 if kv_dtype == jnp.int8 else 8
     return ((num_kv_heads * head_dim) % 128 == 0
             and block_size % sublane == 0
-            and num_heads % num_kv_heads == 0)
+            and num_heads % num_kv_heads == 0
+            and (v_dim is None or v_dim == head_dim
+                 or ((num_kv_heads * v_dim) % 128 == 0
+                     and kv_dtype != jnp.int8)))
 
 
 def paged_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
@@ -1127,7 +1236,10 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
                     kv_heads: int | None = None,
                     v_lanes: int | None = None,
                     coalesce: bool = True,
-                    chunk_blocks: int | None = None) -> jax.Array:
+                    chunk_blocks: int | None = None,
+                    v_dim: int | None = None,
+                    sink: jax.Array | None = None,
+                    name: str = "paged_attention") -> jax.Array:
     """Dispatch: pallas on TPU (block-major streaming kernel, incl. sliding
     windows, soft-capping, and int8 pools w/ in-row per-token scales), XLA
     gather fallback elsewhere and for geometries the kernel can't tile
@@ -1140,8 +1252,17 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
     lanes of a tp-GROUPED int8 pool (g scale groups per row; without it
     the row width is assumed to carry exactly one group). Grouped pools
     take the XLA path: the kernel's in-score dequant reads a single
-    tail scale group."""
+    tail scale group.
+
+    ``v_dim`` / ``sink`` / ``name``: value heads of another size than the
+    keys', a scalar a query head in the softmax's denominator, the Pallas
+    call's name (paged_attention_pallas; the XLA form takes the first
+    two)."""
     B, H, Dh = q.shape
+    # what only a caller with the new geometry passes: every other call
+    # reaches the two forms with the arguments it always had
+    extra = {k: v for k, v in (("v_dim", v_dim), ("sink", sink))
+             if v is not None}
     groups = 1
     if k_cache.dtype == jnp.int8:
         if kv_heads is None:
@@ -1159,7 +1280,8 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
                else kv_value_lanes(k_cache) // Dh)
         impl = ("pallas" if kernel_wanted(impl) and groups == 1
                 and pallas_supported(H, KVH, Dh, block_size,
-                                     kv_dtype=k_cache.dtype) else "xla")
+                                     kv_dtype=k_cache.dtype, v_dim=v_dim)
+                else "xla")
     if groups > 1 and impl in ("pallas", "pallas_interpret"):
         raise ValueError(
             f"pallas decode kernel cannot read a tp-grouped int8 pool "
@@ -1170,7 +1292,8 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
                                       scale=scale, softcap=softcap,
                                       win_lo=win_lo, v_lanes=v_lanes,
                                       coalesce=coalesce,
-                                      chunk_blocks=chunk_blocks)
+                                      chunk_blocks=chunk_blocks,
+                                      name=name, **extra)
     if impl == "pallas_interpret":
         return paged_attention_pallas(q, k_cache, v_cache, block_tables,
                                       seq_lens, block_size=block_size,
@@ -1178,7 +1301,7 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
                                       win_lo=win_lo, v_lanes=v_lanes,
                                       coalesce=coalesce,
                                       chunk_blocks=chunk_blocks,
-                                      interpret=True)
+                                      interpret=True, name=name, **extra)
     if v_lanes is not None:
         # the v-aliases-k CONTRACT holds on every impl: v IS k's first
         # v_lanes lanes and v_cache is ignored — same validation as the
@@ -1197,7 +1320,7 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
     return paged_attention_xla(q, k_cache, v_cache, block_tables, seq_lens,
                                block_size=block_size, scale=scale,
                                softcap=softcap, win_lo=win_lo,
-                               kv_heads=kv_heads)
+                               kv_heads=kv_heads, **extra)
 
 
 # ---------------------------------------------------------------------------

@@ -78,7 +78,9 @@ def _pad_pow2(n: int) -> int:
 
 # arrays of a cache's WINDOW group (llm/kv/hybrid.py), whose blocks carry
 # ids of a pool of their own: models/mla.py dots3_note's window layers
-WINDOW_GROUP = ("win",)
+# ("win"), models/mimo.py's ("win_k", "win_v": block arrays there, per-slot
+# rings that hold no blocks in models/sambay.py, told apart by their rank)
+WINDOW_GROUP = ("win", "win_k", "win_v")
 
 
 @functools.partial(jax.jit, static_argnames=("block_size",),

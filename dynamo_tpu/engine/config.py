@@ -59,7 +59,8 @@ def _rope_type(raw_rs: Dict[str, Any]) -> str:
 # model_types whose configs may carry routed experts (from_hf_config refuses
 # any other that does, by name)
 MOE_FAMILIES = ("mixtral", "qwen2_moe", "qwen3_moe", "deepseek_v2",
-                "deepseek_v3", "deepseek_v32", "kimi_k2", "dots3_note")
+                "deepseek_v3", "deepseek_v32", "kimi_k2", "dots3_note",
+                "mimo_v2")
 
 
 @dataclasses.dataclass
@@ -177,6 +178,22 @@ class ModelConfig:
     swa_window: int = 0
     attention_gate: bool = False
     mla_lora_rescale: bool = False
+    # mimo_v2 (models/mimo.py, docs/hybrid_cache.md part three): plain
+    # grouped-query attention of TWO geometries. The layers whose
+    # layer_types entry is "sliding_attention" have swa_num_heads query and
+    # swa_num_kv_heads key/value heads (> 0 is what says the model has
+    # them) of swa_head_dim / swa_v_head_dim lanes, rope base
+    # swa_rope_theta, attend over the last swa_window positions (the
+    # query's own included), keep their rows in a pool of their own
+    # (kv["win_k"], kv["win_v"]) and, with swa_sink, add one learned scalar
+    # a query head to the softmax's denominator. Both kinds: keys of
+    # head_dim and values of v_head_dim lanes, rope on the first rotary_dim
+    # lanes of a head (0 = all of them), values times value_scale
+    swa_num_kv_heads: int = 0
+    swa_head_dim: int = 0
+    swa_sink: bool = False
+    rotary_dim: int = 0
+    value_scale: float = 1.0
     mamba_d_state: int = 0
     mamba_d_conv: int = 0
     mamba_expand: int = 0
@@ -211,6 +228,20 @@ class ModelConfig:
             index_n_heads=0, index_head_dim=0, index_topk=0)
 
     @property
+    def has_swa_gqa(self) -> bool:
+        """Window layers with a grouped-query geometry of their own
+        (mimo_v2)."""
+        return self.swa_num_kv_heads > 0
+
+    def swa_gqa_geometry(self) -> "ModelConfig":
+        """This model as its window layers see it: the swa_* sizes under
+        the names the grouped-query block reads (models/mimo.py)."""
+        return dataclasses.replace(
+            self, num_heads=self.swa_num_heads,
+            num_kv_heads=self.swa_num_kv_heads, head_dim=self.swa_head_dim,
+            v_head_dim=self.swa_v_head_dim, rope_theta=self.swa_rope_theta)
+
+    @property
     def is_sambay(self) -> bool:
         """State-space, window and shared-cache layers in one model
         (phi4flash): models/sambay.py serves it."""
@@ -233,6 +264,8 @@ class ModelConfig:
         mt = str(cfg.get("model_type", "llama"))
         if mt == "phi4flash":
             return cls._from_phi4flash(cfg)
+        if mt == "mimo_v2":
+            return cls._from_mimo_v2(cfg)
         # a family with no branch here falls through to the llama block:
         # right for its many renamings, wrong for one whose layers keep a
         # recurrent state or come in kinds this parser does not know. It
@@ -674,6 +707,139 @@ class ModelConfig:
             swa_window=int(cfg["sliding_window_size"]),
             attention_gate=True,
             mla_lora_rescale=bool(cfg.get("apply_mla_qkv_lora_rescale")))
+
+    @classmethod
+    def _from_mimo_v2(cls, cfg: Dict[str, Any]) -> "ModelConfig":
+        """MiMo-V2's published keys (models/mimo.py): full and window
+        grouped-query layers by ``hybrid_layer_pattern`` (0 full, 1
+        window), dense and expert MLPs by ``moe_layer_freq`` (0 dense, 1
+        experts), both lists. No family's class defaults: every size is
+        the file's, and what the program does not run is refused by name."""
+        need = ("hidden_size", "num_hidden_layers", "num_attention_heads",
+                "num_key_value_heads", "head_dim", "v_head_dim",
+                "swa_num_attention_heads", "swa_num_key_value_heads",
+                "swa_head_dim", "swa_v_head_dim", "swa_rope_theta",
+                "rope_theta", "sliding_window", "hybrid_layer_pattern",
+                "moe_layer_freq", "intermediate_size",
+                "moe_intermediate_size", "n_routed_experts",
+                "num_experts_per_tok", "partial_rotary_factor",
+                "vocab_size")
+        missing = [k for k in need if cfg.get(k) is None]
+        if missing:
+            raise ValueError(f"mimo_v2 needs {', '.join(missing)} in its "
+                             f"config (no family's class defaults are "
+                             f"this one's)")
+        n = int(cfg["num_hidden_layers"])
+        pattern = list(cfg["hybrid_layer_pattern"])
+        freq = list(cfg["moe_layer_freq"])
+        problems = []
+        for key, lst in (("hybrid_layer_pattern", pattern),
+                         ("moe_layer_freq", freq)):
+            if len(lst) < n:
+                problems.append(f"{key} names {len(lst)} layers of "
+                                f"num_hidden_layers {n}")
+            if any(v not in (0, 1) for v in lst):
+                problems.append(f"{key} holds entries other than 0 and 1")
+        # a cut depth keeps the leading entries
+        pattern, freq = pattern[:n], freq[:n]
+        dense = next((i for i, f in enumerate(freq) if f), len(freq))
+        if dense == 0 or (pattern and pattern[0] != 0):
+            problems.append(
+                "a leading layer that is not dense or not full attention "
+                "(moe_layer_freq[0] and hybrid_layer_pattern[0] must be 0: "
+                "the window layers' stacks hold expert layers only)")
+        if any(f == 0 for f in freq[dense:]):
+            problems.append("moe_layer_freq with a dense layer behind an "
+                            "expert layer")
+        if any(pattern[:dense]):
+            problems.append("hybrid_layer_pattern: a window layer among the "
+                            "leading dense layers")
+        if 1 not in pattern:
+            problems.append("hybrid_layer_pattern has no window layer "
+                            "(that model is a plain grouped-query one)")
+        if cfg.get("add_full_attention_sink_bias"):
+            problems.append("add_full_attention_sink_bias (a sink on the "
+                            "full layers)")
+        if int(cfg.get("n_shared_experts") or 0):
+            problems.append("n_shared_experts (a shared expert)")
+        rs = cfg.get("rope_scaling") or {}
+        if rs and _rope_type(rs) != "default":
+            problems.append(f"rope_scaling {_rope_type(rs)!r} (the two "
+                            f"bases are read unscaled)")
+        if cfg.get("scoring_func", "sigmoid") != "sigmoid" or cfg.get(
+                "topk_method", "noaux_tc") != "noaux_tc":
+            problems.append("a routing other than sigmoid / noaux_tc")
+        if int(cfg.get("n_group") or 1) != 1 or int(
+                cfg.get("topk_group") or 1) != 1:
+            problems.append("n_group / topk_group other than 1")
+        if cfg.get("attention_bias"):
+            problems.append("attention_bias")
+        if int(cfg.get("sliding_window_size") or cfg["sliding_window"]) \
+                != int(cfg["sliding_window"]) or int(
+                    cfg["sliding_window"]) < 1:
+            problems.append("sliding_window and sliding_window_size that "
+                            "differ, or a window < 1")
+        H, KVH = int(cfg["num_attention_heads"]), int(
+            cfg["num_key_value_heads"])
+        Hs, KVHs = int(cfg["swa_num_attention_heads"]), int(
+            cfg["swa_num_key_value_heads"])
+        if H % KVH or Hs % KVHs or KVH < 1 or KVHs < 1:
+            problems.append("query heads that the key/value heads do not "
+                            "divide")
+        dk = int(cfg["head_dim"])
+        rot = int(dk * float(cfg["partial_rotary_factor"]))
+        rot -= rot % 2
+        if int(cfg["swa_head_dim"]) != dk:
+            problems.append("swa_head_dim other than head_dim (one rotary "
+                            "width is read for both kinds)")
+        if not 2 <= rot <= dk:
+            problems.append(f"partial_rotary_factor gives {rot} rotating "
+                            f"lanes of {dk}")
+        if problems:
+            raise ValueError("mimo_v2 is not implemented with: "
+                             + "; ".join(problems))
+        n_experts = int(cfg["n_routed_experts"])
+        n_total = int(cfg.get("n_routed_experts_published") or 0)
+        share = int(cfg.get("expert_share_index") or 0)
+        if n_total and (n_total % n_experts
+                        or not 0 <= share < n_total // n_experts):
+            raise ValueError(
+                f"mimo_v2: n_routed_experts {n_experts} is not a share of "
+                f"n_routed_experts_published {n_total}, or "
+                f"expert_share_index {share} is outside it")
+        return cls(
+            model_type="mimo_v2",
+            vocab_size=int(cfg["vocab_size"]),
+            hidden_size=int(cfg["hidden_size"]),
+            intermediate_size=int(cfg["moe_intermediate_size"]),
+            dense_intermediate_size=int(cfg["intermediate_size"]),
+            num_layers=n, num_heads=H, num_kv_heads=KVH, head_dim=dk,
+            v_head_dim=int(cfg["v_head_dim"]),
+            max_position_embeddings=int(
+                cfg.get("max_position_embeddings", 1048576)),
+            rms_norm_eps=float(cfg.get("layernorm_epsilon", 1e-5)),
+            rope_theta=float(cfg["rope_theta"]),
+            tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+            hidden_act=str(cfg.get("hidden_act") or "silu"),
+            num_experts=n_experts,
+            num_experts_total=n_total if n_total != n_experts else 0,
+            expert_share_index=share,
+            num_experts_per_tok=int(cfg["num_experts_per_tok"]),
+            moe_norm_topk=bool(cfg.get("norm_topk_prob", True)),
+            moe_routing="sigmoid_noaux", n_group=1, topk_group=1,
+            routed_scaling=float(cfg.get("routed_scaling_factor") or 1.0),
+            first_k_dense=dense,
+            layer_types=["sliding_attention" if p else "full_attention"
+                         for p in pattern],
+            swa_num_heads=Hs, swa_num_kv_heads=KVHs,
+            swa_head_dim=int(cfg["swa_head_dim"]),
+            swa_v_head_dim=int(cfg["swa_v_head_dim"]),
+            swa_rope_theta=float(cfg["swa_rope_theta"]),
+            # counts the query's own position: 128 keys (assumed)
+            swa_window=int(cfg["sliding_window"]),
+            swa_sink=bool(cfg.get("add_swa_attention_sink_bias")),
+            rotary_dim=rot,
+            value_scale=float(cfg.get("attention_value_scale") or 1.0))
 
     @classmethod
     def _from_phi4flash(cls, cfg: Dict[str, Any]) -> "ModelConfig":

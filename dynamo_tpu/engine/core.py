@@ -33,7 +33,7 @@ from ..llm.kv.pool import KvBlockManager
 from .block_copy import scatter_blocks_from_host
 from ..llm.kv_router.protocols import ForwardPassMetrics
 from ..llm.protocols.common import FinishReason
-from .attention import wave_contig_table
+from .attention import _on_tpu, wave_contig_table
 from .config import EngineConfig, ModelConfig
 from .index_scores import key_wave_blocks
 from .models import llama
@@ -298,6 +298,28 @@ class EngineCore:
                     + "; ".join(refused))
         else:
             self.model_mod = llama
+            if model_cfg.has_swa_gqa:
+                # mimo_v2: the grouped-query family with a second group of
+                # pool blocks for its window layers (models/mimo.py, which
+                # llama's entry points hand it to)
+                from .models import mimo
+                refused = mimo.refusals(model_cfg, engine_cfg, mesh)
+                if refused:
+                    raise NotImplementedError(
+                        "mimo_v2 (window layers with a grouped-query "
+                        "geometry and a block pool of their own) is not "
+                        "implemented with: " + "; ".join(refused))
+                if _on_tpu() and not mimo.decode_kernels_tile(
+                        model_cfg, engine_cfg.kv_block_size):
+                    # never silently: attn_impl "auto" would take the XLA
+                    # gather for a row width the kernel does not tile
+                    logger.warning(
+                        "mimo_v2: key/value rows of %s / %s lanes at "
+                        "--kv-block-size %d are off the Pallas decode "
+                        "kernel's tiles: both decode reads take the XLA "
+                        "gather", mimo.row_lanes(model_cfg),
+                        mimo.row_lanes(model_cfg.swa_gqa_geometry()),
+                        engine_cfg.kv_block_size)
         if (model_cfg.sliding_window is not None and not self.is_hybrid
                 and engine_cfg.max_model_len <= model_cfg.sliding_window):
             # the window can never bind at this serving length: drop it so
@@ -392,10 +414,10 @@ class EngineCore:
                 model_cfg, engine_cfg.num_kv_blocks,
                 engine_cfg.kv_block_size, engine_cfg.max_num_seqs,
                 dtype=param_dtype)
-        elif self.is_mla:
-            # window layers of a geometry of their own (dots3_note): a
-            # second group of pool blocks, sized from the layout and the
-            # paged pool (no flag; docs/hybrid_cache.md)
+        else:
+            # window layers of a geometry of their own (dots3_note,
+            # mimo_v2): a second group of pool blocks, sized from the
+            # layout and the paged pool (no flag; docs/hybrid_cache.md)
             cache_layout = self.model_mod.cache_layout(
                 model_cfg, engine_cfg.kv_block_size,
                 jnp.dtype(param_dtype).itemsize)
@@ -408,13 +430,8 @@ class EngineCore:
                 model_cfg, engine_cfg.num_kv_blocks,
                 engine_cfg.kv_block_size, dtype=param_dtype,
                 quantization=engine_cfg.kv_quantization,
-                **({"win_blocks": win_blocks} if win_blocks else {}))
-        else:
-            self.kv = llama.init_kv_cache(
-                model_cfg, engine_cfg.num_kv_blocks,
-                engine_cfg.kv_block_size, dtype=param_dtype,
-                quantization=engine_cfg.kv_quantization,
-                kv_shards=kv_shards)
+                **({"win_blocks": win_blocks} if win_blocks else {}),
+                **({} if self.is_mla else {"kv_shards": kv_shards}))
         if mesh is not None and self.pp > 1:
             # pp(×tp) placement: layer stacks + KV pool shard L over the
             # stage ring; embed/final_norm/lm_head replicate (the last
@@ -501,9 +518,8 @@ class EngineCore:
             layout=(self.model_mod.cache_layout(
                 model_cfg, engine_cfg.kv_block_size,
                 jnp.dtype(param_dtype).itemsize)
-                if self.is_hybrid else cache_layout if self.is_mla
-                else None),
-            win_blocks=win_blocks if self.is_mla else 0)
+                if self.is_hybrid else cache_layout),
+            win_blocks=0 if self.is_hybrid else win_blocks)
         if host_pool is not None:
             self.offload_engine = KvOffloadEngine(
                 host_pool, engine_cfg.kv_block_size,

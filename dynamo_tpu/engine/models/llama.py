@@ -178,6 +178,8 @@ def fuse_stacked_matmuls(params: dict, cfg: ModelConfig) -> dict:
             del params[f"layers.{k}"]
 
     cat(("wq", "wk", "wv"), "wqkv")
+    # mimo_v2's window layers: the same three at their own geometry
+    cat(("swa_wq", "swa_wk", "swa_wv"), "swa_wqkv")
     cat(("gate", "up"), "gateup")
     # MoE families: expert grids, shared experts, and the deepseek
     # hybrid's dense-prefix stacks fuse the same way (cat skips any
@@ -419,6 +421,14 @@ def moe_mlp(x: jax.Array, router_w: jax.Array, gate_w: jax.Array,
 
 
 def param_shapes(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
+    if cfg.has_swa_gqa:
+        # grouped-query layers of two geometries (mimo_v2): a sibling
+        # module, entered through this family's four entry points so that
+        # every caller that knows the grouped-query family by this module
+        # (the engine, quant.init_params_quantized, engine/replay.py,
+        # benchmark/compile_check.py) gets the model it was given
+        from . import mimo
+        return mimo.param_shapes(cfg)
     L, D = cfg.num_layers, cfg.hidden_size
     H, KVH, Dh, F = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.intermediate_size
     shapes = {
@@ -491,8 +501,22 @@ def init_one_param(cfg: ModelConfig, name: str, shape: tuple,
         return (jnp.zeros(shape, dtype=dtype)
                 if cfg.norm_plus_one
                 else jnp.ones(shape, dtype=dtype))
+    if cfg.has_swa_gqa and name.endswith("router_bias"):
+        # mimo_v2: a trained e_score_correction_bias is not 0, and one that
+        # is says nothing of whether the choice reads it (it biases the
+        # choice alone, never the weights)
+        return (ROUTER_BIAS_SEEDED * jax.random.normal(
+            sub, shape, dtype=jnp.float32)).astype(dtype)
     if name.endswith(("bq", "bk", "bv", "router_bias", "idx_k_norm_b")):
         return jnp.zeros(shape, dtype=dtype)
+    if name.endswith("swa_sink"):
+        # mimo_v2's learned sinks, float32 whatever the load dtype: seeded
+        # around the log of what a window's keys sum to under the seeded
+        # scores (GQA_MIXED_SEEDED), so that a sink takes a share of a
+        # window row's mass that a comparison can see, wide enough that
+        # the heads differ
+        mean, std = SINK_SEEDED
+        return mean + std * jax.random.normal(sub, shape, dtype=jnp.float32)
     fan_in = shape[-2] if len(shape) > 1 else shape[-1]
     return (jax.random.normal(sub, shape, dtype=jnp.float32)
             * seeded_std(cfg, name, fan_in)).astype(dtype)
@@ -545,15 +569,46 @@ SHARE_SEEDED = {"embed": 1.0, "moe_down": 0.5}
 MIXED_SEEDED = dict(SPARSE_SEEDED, wo=0.25, swa_wo=0.5)
 
 
+# Seeded weights of a model of two grouped-query geometries with a sink in
+# its window layers' softmax (mimo_v2; ModelConfig.has_swa_gqa). At
+# fan_in^-0.5 throughout the scores are of unit variance: a softmax over
+# 33k (or 128) such keys is nearly flat, attention averages that many random
+# values, and a window, a rope base, a sink or a value scale left out moves
+# the logits by less than the tolerance. So wq and wk stand at 1.6 times
+# fan_in^-0.5 (scores of standard deviation ~2.6: a query's mass lies on a
+# few keys, as in a trained model, and short of the near-one-hot softmax at
+# which a bf16 program and a float32 reference part), the stream carries the
+# embedding at the scale of a normalised branch input, and the branches that
+# write into it are perturbations of it (SPARSE_SEEDED's reasoning). Larger
+# output projections do not make the comparison sharper: with wo and swa_wo
+# at 1.0 the served program's own bf16 error at 32,832 tokens rose from 0.03
+# to 0.22 of the logits' standard deviation beside the breakages, at 1.5 it
+# left the tolerance (my chip runs, PR 46: PERF.md section 6).
+GQA_MIXED_SEEDED = {"embed": 1.0, "wq": 1.6, "wk": 1.6, "wo": 0.5,
+                    "swa_wo": 0.5, "down": 0.25, "moe_down": 0.5}
+# mean and standard deviation of mimo_v2's seeded sinks (init_one_param):
+# 128 window keys under scores of standard deviation s sum to about
+# 128 · exp(s^2 / 2); at s ~ 2.6 its log is ~8, and a sink a little under it
+# takes a fifth to a half of a row's mass
+SINK_SEEDED = (7.0, 1.0)
+# standard deviation of mimo_v2's seeded router bias (init_one_param), on
+# sigmoid scores in (0, 1): enough to change which experts a token's top-8
+# holds, not so much that the bias alone chooses
+ROUTER_BIAS_SEEDED = 0.1
+
+
 def seeded_std(cfg: ModelConfig, name: str, fan_in: int) -> float:
     """Standard deviation of a --random-weights matrix: fan_in^-0.5, but
-    see SPARSE_SEEDED, MIXED_SEEDED and SHARE_SEEDED."""
+    see SPARSE_SEEDED, MIXED_SEEDED, GQA_MIXED_SEEDED and SHARE_SEEDED."""
     std = fan_in ** -0.5
     rule = (MIXED_SEEDED if cfg.has_swa_latent
+            else GQA_MIXED_SEEDED if cfg.has_swa_gqa
             else SPARSE_SEEDED if cfg.index_topk > 0
             else SHARE_SEEDED if cfg.num_experts_total > 0 else {})
     if name == "embed":
         return rule.get("embed", std)
+    if cfg.has_swa_gqa and name.endswith(("wq", "wk")):
+        return std * rule[name[-2:]]
     for suffix in ("moe_down", "down", "swa_wo", "wo"):
         if name.endswith(suffix):
             return std * rule.get(suffix, 1.0)
@@ -580,7 +635,7 @@ def init_params(cfg: ModelConfig, key: jax.Array,
 
 def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                   dtype=jnp.bfloat16, quantization: str = "none",
-                  kv_shards: int = 1) -> KVCache:
+                  kv_shards: int = 1, win_blocks: int = 0) -> KVCache:
     """quantization="int8": per-token int8 KV with in-row scales (see
     KV_SCALE_LANES). At seq >= ~1k the KV read stream rivals the weights
     stream during decode (VERDICT r3 next #6); int8 KV cuts that term
@@ -590,7 +645,14 @@ def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     ``kv_shards`` (int8 + tensor parallelism): rows carry one
     (values, scales) section per tp shard — g·(C/g + KV_SCALE_LANES)
     lanes — so the lane-axis tp sharding (parallel/sharding.kv_pspecs)
-    gives each shard whole sections; see attention.quantize_kv_rows."""
+    gives each shard whole sections; see attention.quantize_kv_rows.
+
+    ``win_blocks``: mimo_v2 alone (models/mimo.py: the window pool's)."""
+    if cfg.has_swa_gqa:
+        from . import mimo
+        return mimo.init_kv_cache(cfg, num_blocks, block_size, dtype=dtype,
+                                  quantization=quantization,
+                                  win_blocks=win_blocks, kv_shards=kv_shards)
     C = cfg.num_kv_heads * cfg.head_dim
     if quantization == "int8":
         if C % kv_shards != 0:
@@ -609,6 +671,17 @@ def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
             "v": jnp.zeros(shape, dtype=dtype)}
 
 
+
+
+def cache_layout(cfg: ModelConfig, block_size: int, dtype_bytes: int = 2):
+    """What the block manager needs to know of a model whose layers keep
+    rows of more than one kind: mimo_v2's two groups of pool blocks
+    (models/mimo.py); None for every other model of this family (one
+    uniform paged pool)."""
+    if not cfg.has_swa_gqa:
+        return None
+    from . import mimo
+    return mimo.cache_layout(cfg, block_size, dtype_bytes)
 
 
 def _layer_stack(params: Params):
@@ -976,6 +1049,10 @@ def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
     out of attention reads.
     """
     cfg = statics.cfg
+    if cfg.has_swa_gqa:
+        from . import mimo
+        return mimo.prefill_forward(params, kv, tokens, block_table,
+                                    start_pos, true_len, statics)
     T = tokens.shape[0]
     bsz = statics.block_size
     scale = _attn_scale(cfg)
@@ -1217,6 +1294,10 @@ def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
     block_tables: [B, M]. Returns (logits [B, V], updated kv).
     """
     cfg = statics.cfg
+    if cfg.has_swa_gqa:
+        from . import mimo
+        return mimo.decode_forward(params, kv, tokens, positions,
+                                   block_tables, statics)
     B = tokens.shape[0]
     bsz = statics.block_size
     scale = _attn_scale(cfg)

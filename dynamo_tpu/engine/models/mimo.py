@@ -1,0 +1,508 @@
+"""mimo_v2: grouped-query attention of two geometries in one model.
+
+MiMo-V2's layers are plain grouped-query attention (no latent), of two
+kinds by ``hybrid_layer_pattern``: full layers (``layer_types`` entry
+"full_attention": H query / KVH key-value heads, rope base ``rope_theta``)
+attend over the whole context, window layers ("sliding_attention":
+``swa_num_heads`` / ``swa_num_kv_heads``, base ``swa_rope_theta``) over the
+last ``swa_window`` positions, the query's own included, with one learned
+scalar a query head in their softmax's denominator (``swa_sink``). Both
+kinds: keys of ``head_dim`` lanes and values of ``v_head_dim`` lanes
+(192 / 128 published), rope in HF's half-split pairing on the first
+``rotary_dim`` lanes of every query and key head, values times
+``value_scale``. Layer 0 is dense (SwiGLU at ``dense_intermediate_size``),
+the others route ``mla._moe_mlp``'s sigmoid_noaux branch over one group,
+of which this chip may hold a share. docs/hybrid_cache.md part three.
+
+The block of a layer of kind k (H, KVH, theta of that kind; dk, dv, r =
+head_dim, v_head_dim, rotary_dim):
+
+    q = n(h)·Wq -> [H, dk]     k = n(h)·Wk -> [KVH, dk]
+    v = value_scale · n(h)·Wv -> [KVH, dv]
+    rope(theta) on lanes [0, r) of q and k; lanes [r, dk) pass
+    s_tj = q_t·k_j / sqrt(dk), head h reads kv head h // (H / KVH)
+    F: p = softmax_j(s) over j <= t
+    S: p_tj = exp(s_tj) / (exp(b_h) + sum_j' exp(s_tj')), t-window < j <= t
+    h' = h + Wo·(sum_j p_tj v_j)       h'' = h' + M(n(h'))
+
+**One walker.** The layers run through ``mla.walk_layer_kinds`` (dense
+prefix unrolled, ONE lax.scan over the periods of the layer kinds, a tail),
+which this model shares with dots3_note; only the attention block of each
+kind is this module's. Parameter leaves: ``layers.<leaf>`` [n_F, ...] for
+the full layers, ``layers.swa_<leaf>`` [n_S, ...] for the window layers
+(``swa_sink`` [n_S, Hs] float32, never quantised), ``dense_*``, the expert
+stacks as deepseek_v3's.
+
+**Two pools.** ``kv["k"]`` / ``kv["v"]`` [n_F, NTOK, KVH·dk | KVH·dv] hold
+the full layers' rows under the paged pool's block ids; ``kv["win_k"]`` /
+``kv["win_v"]`` [n_S, WTOK, KVHs·dk | KVHs·dv] the window layers' under the
+window pool's (llm/kv/hybrid.py ``window_pool``; engine/core.py keeps the
+second table). A row is the heads side by side, unpadded: head kh's key
+starts at lane dk·kh. With dk = 192 that is no lane-tile boundary, and it
+need not be: the decode kernel dots a sparse-slotted query against whole
+rows, so it is the ROW that must lie on 128-lane tiles (4·192 = 768,
+8·192 = 1,536), and the prefill kernels take dense [S, KVH, dk] operands.
+A head padded to 256 lanes would cost 25% more bytes a row for nothing.
+
+**Reads.** Decode: ``attention.paged_attention`` with ``v_dim`` over the
+whole table (full, Pallas name ``gqa_full_read``) and over a ring view of
+at most ``ring_blocks`` window-pool blocks with ``win_lo`` and the sink
+(window, ``gqa_window_read``). Prefill: a full layer walks its table by key
+blocks of GQA_KEY_BLOCK rows up to the live length and folds each block's
+partial softmax state (``flash_prefill_partial``, ``gqa_full_prefill``); a
+window layer gathers the chunk's own rows and the window - 1 before them
+from the window pool by their blocks and runs ``flash_prefill`` with the
+window and the sink (``gqa_window_prefill``). Off the TPU and at widths the
+kernels do not tile, the same reads in XLA.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..attention import (ATTN_CHUNK_BLOCKS, NEG_INF, causal_attention,
+                         flash_prefill, flash_prefill_partial,
+                         flash_prefill_supported, kernel_wanted,
+                         paged_attention, pallas_supported)
+from ..config import ModelConfig
+from ..quant import mm
+from .llama import (KVCache, ModelStatics, Params, _embed, _layer_stack,
+                    _logits, apply_rope)
+from .mla import (_n_kind, _swa_ring_view, _swa_tables, layer_kinds,
+                  stack_at, swa_ring_blocks, walk_layer_kinds)
+
+# rows of the table a full layer's prefill chunk reads and attends at a
+# time (a whole number of the pool's blocks): bounds what one call of the
+# kernel is handed; the walk ends at the live length, not at the table's
+GQA_KEY_BLOCK = 2048
+# rows of a DMA wave of the full layers' decode read (a 768-lane key row
+# and a 512-lane value row serve all 64 heads of a sequence)
+GQA_WAVE_ROWS = 512
+
+
+def _attn_shapes(cfg: ModelConfig, n: int, prefix: str = "") -> Dict:
+    """The attention leaves of ``n`` layers of one geometry."""
+    D, H, KVH = cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads
+    dk, dv = cfg.head_dim, cfg.v_head_dim
+    return {f"layers.{prefix}wq": (n, D, H * dk),
+            f"layers.{prefix}wk": (n, D, KVH * dk),
+            f"layers.{prefix}wv": (n, D, KVH * dv),
+            f"layers.{prefix}wo": (n, H * dv, D)}
+
+
+def param_shapes(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
+    """The order is the order the seeded weights' keys are split in."""
+    L, D = cfg.num_layers, cfg.hidden_size
+    kinds = layer_kinds(cfg)
+    k, Lm = cfg.first_k_dense, L - cfg.first_k_dense
+    E, F, R = cfg.num_experts, cfg.intermediate_size, cfg.router_width
+    Fd = cfg.dense_intermediate_size
+    cfg_s = cfg.swa_gqa_geometry()
+    shapes = {
+        "embed": (cfg.vocab_size, D),
+        "final_norm": (D,),
+        "layers.ln1": (L, D),
+        "layers.ln2": (L, D),
+        **_attn_shapes(cfg, _n_kind(kinds, "F")),
+        "layers.dense_gate": (k, D, Fd),
+        "layers.dense_up": (k, D, Fd),
+        "layers.dense_down": (k, Fd, D),
+        "layers.router": (Lm, D, R),
+        "layers.moe_gate": (Lm, E, D, F),
+        "layers.moe_up": (Lm, E, D, F),
+        "layers.moe_down": (Lm, E, F, D),
+        "layers.router_bias": (Lm, R),
+        **_attn_shapes(cfg_s, _n_kind(kinds, "S"), "swa_"),
+    }
+    if cfg.swa_sink:
+        shapes["layers.swa_sink"] = (_n_kind(kinds, "S"), cfg_s.num_heads)
+    if not cfg.tie_word_embeddings:
+        shapes["lm_head"] = (D, cfg.vocab_size)
+    return shapes
+
+
+def row_lanes(cfg: ModelConfig) -> Tuple[int, int]:
+    """(key row, value row) lanes of one layer of ``cfg``'s geometry: the
+    heads side by side, unpadded."""
+    return cfg.num_kv_heads * cfg.head_dim, cfg.num_kv_heads * cfg.v_head_dim
+
+
+def cache_layout(cfg: ModelConfig, block_size: int, dtype_bytes: int = 2):
+    """What the block manager needs to know: two groups of pool blocks,
+    paged rows of the full layers and window rows (the wider ones) of the
+    window layers."""
+    from ...llm.kv.hybrid import HybridCacheLayout
+    kinds = layer_kinds(cfg)
+    n_f, n_s = _n_kind(kinds, "F"), _n_kind(kinds, "S")
+    return HybridCacheLayout(
+        block_size=block_size, row_bytes=sum(row_lanes(cfg)) * dtype_bytes,
+        paged_layers=n_f, readers_of_paged=n_f,
+        window_layers=n_s, window=cfg.swa_window,
+        state_layers=0, state_bytes=0, window_pool=True,
+        window_row_bytes=sum(row_lanes(cfg.swa_gqa_geometry())) * dtype_bytes)
+
+
+def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
+                  dtype=jnp.bfloat16, quantization: str = "none",
+                  win_blocks: int = 0, kv_shards: int = 1) -> KVCache:
+    """``win_blocks``: what HybridCacheLayout.window_pool_blocks derives
+    (0 = as many as the paged pool, for a caller that drives one table for
+    both groups)."""
+    if quantization != "none" or kv_shards != 1:
+        raise NotImplementedError(
+            "kv_quantization / a sharded pool with mimo_v2's two row widths "
+            "is not implemented (int8 rows have one encoding, of one width)")
+    kinds = layer_kinds(cfg)
+    n_f, n_s = _n_kind(kinds, "F"), _n_kind(kinds, "S")
+    ck, cv = row_lanes(cfg)
+    sk, sv = row_lanes(cfg.swa_gqa_geometry())
+    ntok, wtok = num_blocks * block_size, (win_blocks
+                                           or num_blocks) * block_size
+    return {"k": jnp.zeros((n_f, ntok, ck), dtype),
+            "v": jnp.zeros((n_f, ntok, cv), dtype),
+            "win_k": jnp.zeros((n_s, wtok, sk), dtype),
+            "win_v": jnp.zeros((n_s, wtok, sv), dtype)}
+
+
+def refusals(cfg: ModelConfig, engine_cfg, mesh) -> list:
+    """What this engine asks for that a window group of pool blocks does
+    not run under yet, by name; empty = go (read once, at engine build)."""
+    e = engine_cfg
+    checks = {
+        "--ragged (ragged_forward has no window layers)": e.ragged_dispatch,
+        "--spec-k (the verify program has no window layers)": e.spec_k > 0,
+        "--lane-prefill-max-tokens (a lane's rows take no window blocks)":
+            e.lane_prefill_max_tokens > 0,
+        "--decode-steps-per-dispatch > 1 (window blocks are taken and "
+        "released a step at a time)": e.decode_steps_per_dispatch > 1,
+        "--kv-quantization (rows of two widths have no int8 encoding)":
+            e.kv_quantization != "none",
+        "--quantization int4 (the grouped-int4 paths are not validated "
+        "for this family)": e.quantization.startswith("int4"),
+        "--host-kv-blocks / --kv-disk-* / --kv-remote-* (the tiers ship "
+        "the paged pool's rows only)": bool(
+            e.host_kv_blocks or e.kv_disk_blocks or e.kv_remote_dir),
+        "tp/sp/pp/ep/dp meshes (the window pool has no sharding rule; an "
+        "expert share IS this chip's part of an expert-parallel layer)":
+            mesh is not None or max(e.tp, e.sp, e.pp, e.ep, e.dp) > 1,
+    }
+    return [name for name, on in checks.items() if on]
+
+
+# ---------------------------------------------------------------------------
+# The attention block
+# ---------------------------------------------------------------------------
+
+
+def _inv_freq(cfg: ModelConfig) -> np.ndarray:
+    r = cfg.rotary_dim or cfg.head_dim
+    return 1.0 / (cfg.rope_theta ** (np.arange(0, r, 2, dtype=np.float64)
+                                     / r)).astype(np.float32)
+
+
+def _rope(x: jax.Array, positions: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Rope on the first rotary_dim lanes of every head of x [N, heads,
+    dk] (HF's half-split pairs within them); the other lanes pass."""
+    r = cfg.rotary_dim or cfg.head_dim
+    rot = apply_rope(x[..., :r], positions, jnp.asarray(_inv_freq(cfg)))
+    return rot if r == x.shape[-1] else jnp.concatenate(
+        [rot, x[..., r:]], axis=-1)
+
+
+def _qkv(lp, hn: jax.Array, positions: jax.Array, cfg: ModelConfig):
+    """→ (q [N, H, dk], k [N, KVH, dk], v [N, KVH, dv]) of one layer of
+    ``cfg``'s geometry: projected (one fused matmul where
+    llama.fuse_stacked_matmuls made one), roped, the values scaled."""
+    N = hn.shape[0]
+    H, KVH, dk, dv = (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+                      cfg.v_head_dim)
+    if "wqkv" in lp:
+        qkv = mm(hn, lp["wqkv"])
+        q, k, v = (qkv[:, :H * dk], qkv[:, H * dk:(H + KVH) * dk],
+                   qkv[:, (H + KVH) * dk:])
+    else:
+        q, k, v = mm(hn, lp["wq"]), mm(hn, lp["wk"]), mm(hn, lp["wv"])
+    q = _rope(q.reshape(N, H, dk), positions, cfg)
+    k = _rope(k.reshape(N, KVH, dk), positions, cfg)
+    v = v.reshape(N, KVH, dv)
+    if cfg.value_scale != 1.0:
+        v = (v.astype(jnp.float32) * cfg.value_scale).astype(v.dtype)
+    return q, k, v
+
+
+def _kernel_form(statics: ModelStatics, supported: bool, what: str):
+    """How a read runs under ``statics.attn_impl``: True (the Pallas
+    kernel), "interpret", or False (XLA). A forced kernel on a geometry it
+    does not tile raises: nothing falls to XLA silently."""
+    impl = statics.attn_impl
+    if not kernel_wanted(impl) or (impl == "auto" and not supported):
+        return False
+    if not supported:
+        raise ValueError(f"attn_impl {impl!r} forced but the {what} "
+                         f"kernel does not tile this geometry")
+    return "interpret" if impl == "pallas_interpret" else True
+
+
+def _take_blocks(pool: jax.Array, ai, ids: jax.Array, bsz: int,
+                 heads: int) -> jax.Array:
+    """The blocks ``ids`` of layer ``ai`` of a pool [n, NTOK, C] as rows
+    [len(ids)·bsz, heads, C/heads]: gathered from the pool as ONE array of
+    blocks under layer-global ids (a view, as the decode reads take it), so
+    that no layer's slice of the pool is copied to be read from."""
+    n, ntok, C = pool.shape
+    blocks = pool.reshape(n * (ntok // bsz), bsz, C)
+    ids = jnp.clip(ids, 0, ntok // bsz - 1) + ai * (ntok // bsz)
+    return jnp.take(blocks, ids, axis=0).reshape(-1, heads, C // heads)
+
+
+def _full_chunk(q, k_pool, v_pool, ai, table, start_pos, seq_len,
+                cfg: ModelConfig, bsz: int, kernel) -> jax.Array:
+    """Causal attention of the T queries of one prefill chunk (query t at
+    position start_pos + t) over the live rows of its table (positions <
+    seq_len), by key blocks of GQA_KEY_BLOCK rows with a running max and
+    sum: each block is read from the pool by its blocks and attended (the
+    Pallas flash kernel in its partial form, or the same in XLA), its state
+    folded in. Nothing of size chunk × table exists and the walk ends at
+    the live length. → [T, H, dv] float32."""
+    T, H, dk = q.shape
+    KVH, dv, g = cfg.num_kv_heads, cfg.v_head_dim, H // cfg.num_kv_heads
+    scale = dk ** -0.5
+    M = table.shape[0]
+    nb = max(1, min(GQA_KEY_BLOCK // bsz, M))
+    KB = nb * bsz
+    table = jnp.pad(table, (0, -M % nb))           # the trash block: masked
+    f32 = jnp.float32
+
+    def block(j, state):
+        acc, m, l = state
+        ids = jax.lax.dynamic_slice(table, (j * nb,), (nb,))
+        ks = _take_blocks(k_pool, ai, ids, bsz, KVH)
+        vs = _take_blocks(v_pool, ai, ids, bsz, KVH)
+        # the block's frame: its first row is position 0
+        q_lo, live = start_pos - j * KB, jnp.clip(seq_len - j * KB, 0, KB)
+        if kernel:
+            acc_j, m_j, l_j = flash_prefill_partial(
+                q, ks, vs, scale=scale, start_pos=q_lo, seq_len=live,
+                interpret=(kernel == "interpret"), name="gqa_full_prefill")
+        else:
+            s = jnp.einsum("tkgd,skd->tkgs", q.reshape(T, KVH, g, dk), ks,
+                           preferred_element_type=f32) * scale
+            kpos = jnp.arange(KB)[None, :]
+            mask = ((kpos <= q_lo + jnp.arange(T)[:, None])
+                    & (kpos < live))[:, None, None, :]
+            s = jnp.where(mask, s, NEG_INF)
+            m_j = jnp.max(s, axis=-1)
+            # a row with nothing to read: exp(NEG_INF - NEG_INF) is not 0
+            p = jnp.where(mask, jnp.exp(s - m_j[..., None]), 0.0)
+            l_j = jnp.sum(p, axis=-1).reshape(T, H)
+            acc_j = jnp.einsum("tkgs,skd->tkgd", p.astype(vs.dtype), vs,
+                               preferred_element_type=f32).reshape(T, H, dv)
+            m_j = m_j.reshape(T, H)
+        m_new = jnp.maximum(m, m_j)
+        a, b = jnp.exp(m - m_new), jnp.exp(m_j - m_new)
+        return (acc * a[..., None] + acc_j * b[..., None], m_new,
+                l * a + l_j * b)
+
+    acc, _m, l = jax.lax.fori_loop(
+        0, (seq_len + KB - 1) // KB, block,
+        (jnp.zeros((T, H, dv), f32), jnp.full((T, H), NEG_INF, f32),
+         jnp.zeros((T, H), f32)))
+    return acc / jnp.maximum(l, 1e-20)[..., None]
+
+
+def _window_chunk(q, k_pool, v_pool, ai, table, start_pos, seq_len,
+                  cfg: ModelConfig, window: int, bsz: int, sink,
+                  kernel) -> jax.Array:
+    """Window attention of the T queries of one prefill chunk (query t at
+    position start_pos + t reads the keys s with t - window < s <= t): the
+    rows [start_pos - window + 1, start_pos + T) are read from the window
+    pool by their blocks (table: the block of every logical block) and
+    attended in the frame of the first block read. cfg: the window layers'
+    geometry. → [T, H, dv]."""
+    T, KVH = q.shape[0], cfg.num_kv_heads
+    scale = q.shape[2] ** -0.5
+    back = window - 1
+    nb = (T + back) // bsz + 2           # the chunk, the window, misaligned
+    b0 = jnp.maximum(start_pos - back, 0) // bsz
+    ids = jax.lax.dynamic_slice(jnp.pad(table, (0, nb)), (b0,), (nb,))
+    ks = _take_blocks(k_pool, ai, ids, bsz, KVH)
+    vs = _take_blocks(v_pool, ai, ids, bsz, KVH)
+    q0, live = start_pos - b0 * bsz, seq_len - b0 * bsz
+    if kernel:
+        return flash_prefill(
+            q, ks, vs, scale=scale, start_pos=q0, seq_len=live,
+            sliding=True, window=window, sink=sink,
+            interpret=(kernel == "interpret"), name="gqa_window_prefill")
+    return causal_attention(q, ks, vs, scale=scale, kv_offset=q0,
+                            length=live, window=window, sink=sink)
+
+
+def _attend_fn(params: Params, cfg: ModelConfig, positions, slots, slots_s,
+               read_full, read_window):
+    """``walk_layer_kinds``' attention block for this model: project, write
+    the layer's rows into its pool, read (``read_full(q, pools, ai)`` /
+    ``read_window(q, pools, ai, sink)`` -> [N, H·dv]) and project out."""
+    cfg_s = cfg.swa_gqa_geometry()
+    stack = _layer_stack(params)
+    names = {"F": [n for n in ("wq", "wk", "wv", "wqkv", "wo")
+                   if n in stack],
+             "S": [n for n in stack if n.startswith("swa_")]}
+
+    def attend(kind, hn, pools, ai):
+        lp = stack_at({n: stack[n] for n in names[kind]}, ai)
+        N = hn.shape[0]
+        if kind == "F":
+            q, k, v = _qkv(lp, hn, positions, cfg)
+            pools = dict(
+                pools,
+                k=pools["k"].at[ai, slots, :].set(
+                    k.reshape(N, -1).astype(pools["k"].dtype), mode="drop"),
+                v=pools["v"].at[ai, slots, :].set(
+                    v.reshape(N, -1).astype(pools["v"].dtype), mode="drop"))
+            attn = read_full(q, pools, ai)
+        else:
+            lp = {n[len("swa_"):]: w for n, w in lp.items()}
+            q, k, v = _qkv(lp, hn, positions, cfg_s)
+            pools = dict(
+                pools,
+                win_k=pools["win_k"].at[ai, slots_s, :].set(
+                    k.reshape(N, -1).astype(pools["win_k"].dtype),
+                    mode="drop"),
+                win_v=pools["win_v"].at[ai, slots_s, :].set(
+                    v.reshape(N, -1).astype(pools["win_v"].dtype),
+                    mode="drop"))
+            attn = read_window(q, pools, ai, lp.get("sink"))
+        return mm(attn.reshape(N, -1).astype(hn.dtype), lp["wo"]), pools
+
+    return attend
+
+
+# ---------------------------------------------------------------------------
+# Forward passes (llama.prefill_forward / decode_forward's contracts)
+# ---------------------------------------------------------------------------
+
+
+def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
+                    block_table: jax.Array, start_pos: jax.Array,
+                    true_len: jax.Array, statics: ModelStatics
+                    ) -> Tuple[jax.Array, KVCache]:
+    """tokens [T] (padded), block_table [M] or the engine's [2M] (the window
+    pool's block of every logical block behind the paged pool's:
+    mla._swa_tables) → (last-token logits [V], new kv). The chunk's rows
+    are scattered first; the full layers read the live rows of the table
+    back by key blocks, the window layers the chunk's and the window - 1
+    before them."""
+    cfg, bsz = statics.cfg, statics.block_size
+    cfg_s = cfg.swa_gqa_geometry()
+    T = tokens.shape[0]
+    positions = start_pos + jnp.arange(T, dtype=jnp.int32)
+    valid = jnp.arange(T) < true_len
+    block_table, table_s = _swa_tables(block_table, statics.table_blocks, 0,
+                                       doubled=True)
+    table_s = block_table if table_s is None else table_s
+    slots = jnp.where(
+        valid, block_table[positions // bsz] * bsz + positions % bsz, 0)
+    slots_s = jnp.where(
+        valid, table_s[positions // bsz] * bsz + positions % bsz, 0)
+    seq_len = start_pos + true_len
+    kernel = _kernel_form(
+        statics,
+        flash_prefill_supported(cfg.num_heads, cfg.num_kv_heads,
+                                cfg.head_dim, cfg.v_head_dim)
+        and flash_prefill_supported(cfg_s.num_heads, cfg_s.num_kv_heads,
+                                    cfg_s.head_dim, cfg_s.v_head_dim),
+        "flash prefill")
+
+    def read_full(q, pools, ai):
+        with jax.named_scope("gqa_full_prefill_attention"):
+            return _full_chunk(q, pools["k"], pools["v"], ai, block_table,
+                               start_pos, seq_len, cfg, bsz, kernel)
+
+    def read_window(q, pools, ai, sink):
+        with jax.named_scope("gqa_window_prefill_attention"):
+            return _window_chunk(q, pools["win_k"], pools["win_v"], ai,
+                                 table_s, start_pos, seq_len, cfg_s,
+                                 cfg.swa_window, bsz, sink, kernel)
+
+    x = _embed(params, tokens, cfg)
+    x, kv_new = walk_layer_kinds(
+        params, kv, x, cfg,
+        _attend_fn(params, cfg, positions, slots, slots_s, read_full,
+                   read_window),
+        experts_sharded=statics.sharded, valid_rows=true_len)
+    last = x[jnp.maximum(true_len - 1, 0)]
+    return _logits(params, last, cfg), kv_new
+
+
+def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
+                   positions: jax.Array, block_tables: jax.Array,
+                   statics: ModelStatics) -> Tuple[jax.Array, KVCache]:
+    """tokens [B], positions [B], block_tables [B, M] or the engine's
+    [B, M + R] (logical window block b at entry M + b % R) → (logits
+    [B, V], new kv). Both reads are ``attention.paged_attention``: the full
+    layers' over the whole table, the window layers' over a ring view of R
+    window-pool blocks with the window's lower bound and the sink."""
+    cfg, bsz = statics.cfg, statics.block_size
+    cfg_s = cfg.swa_gqa_geometry()
+    B = tokens.shape[0]
+    R = swa_ring_blocks(cfg, bsz)
+    block_tables, ring = _swa_tables(block_tables, statics.table_blocks, R,
+                                     doubled=False)
+    slots = (block_tables[jnp.arange(B), positions // bsz] * bsz
+             + positions % bsz)
+    seq_lens = positions + 1
+    view, view_len, view_lo = _swa_ring_view(
+        cfg.swa_window, bsz, positions, block_tables, ring, R)
+    # the newest row's block is the view's last entry
+    slots_s = view[:, R - 1] * bsz + positions % bsz
+    num_blocks = kv["k"].shape[1] // bsz
+    win_blocks = kv["win_k"].shape[1] // bsz
+
+    def reader(c: ModelConfig, name: str, chunk_blocks: int):
+        # under jit so that the kernel is traced and lowered once for all
+        # the layers of a kind in a period, not once a layer
+        return jax.jit(functools.partial(
+            paged_attention, block_size=bsz, scale=c.head_dim ** -0.5,
+            impl=statics.attn_impl, kv_heads=c.num_kv_heads,
+            v_dim=c.v_head_dim, coalesce=statics.kv_coalesce,
+            chunk_blocks=chunk_blocks, name=name))
+
+    full = reader(cfg, "gqa_full_read",
+                  max(ATTN_CHUNK_BLOCKS, GQA_WAVE_ROWS // bsz))
+    window = reader(cfg_s, "gqa_window_read", max(ATTN_CHUNK_BLOCKS, R))
+
+    def read_full(q, pools, ai):
+        k, v = pools["k"], pools["v"]
+        with jax.named_scope("gqa_full_decode_attention"):
+            return full(q, k.reshape(-1, k.shape[2]),
+                        v.reshape(-1, v.shape[2]),
+                        block_tables + ai * num_blocks, seq_lens)
+
+    def read_window(q, pools, ai, sink):
+        k, v = pools["win_k"], pools["win_v"]
+        with jax.named_scope("gqa_window_decode_attention"):
+            return window(q, k.reshape(-1, k.shape[2]),
+                          v.reshape(-1, v.shape[2]),
+                          view + ai * win_blocks, view_len, win_lo=view_lo,
+                          sink=sink)
+
+    x = _embed(params, tokens, cfg)
+    x, kv_new = walk_layer_kinds(
+        params, kv, x, cfg,
+        _attend_fn(params, cfg, positions, slots, slots_s, read_full,
+                   read_window),
+        experts_sharded=statics.sharded)
+    return _logits(params, x, cfg), kv_new
+
+
+def decode_kernels_tile(cfg: ModelConfig, block_size: int) -> bool:
+    """Whether both decode reads run as the Pallas kernel on a TPU (a
+    geometry it refuses takes the XLA gather: paged_attention's "auto")."""
+    return all(pallas_supported(c.num_heads, c.num_kv_heads, c.head_dim,
+                                block_size, v_dim=c.v_head_dim)
+               for c in (cfg, cfg.swa_gqa_geometry()))

@@ -292,7 +292,7 @@ def _attn_shapes(cfg: ModelConfig, n: int, prefix: str = "") -> tuple:
 def layer_kinds(cfg: ModelConfig) -> Tuple[str, ...]:
     """"F" (attends over the whole context) or "S" (over the window, with
     the second geometry) for every layer; all "F" without window layers."""
-    if not cfg.has_swa_latent:
+    if not (cfg.has_swa_latent or cfg.has_swa_gqa):
         return ("F",) * cfg.num_layers
     return tuple("S" if t == "sliding_attention" else "F"
                  for t in cfg.layer_types)
@@ -419,7 +419,8 @@ def cache_layout(cfg: ModelConfig, block_size: int, dtype_bytes: int = 2):
         block_size=block_size, row_bytes=lanes * dtype_bytes,
         paged_layers=n_f, readers_of_paged=n_f,
         window_layers=n_s, window=cfg.swa_window,
-        state_layers=0, state_bytes=0, window_pool=True)
+        state_layers=0, state_bytes=0, window_pool=True,
+        window_row_bytes=latent_row_lanes(cfg.swa_geometry()) * dtype_bytes)
 
 
 def init_kv_cache(cfg: ModelConfig, num_blocks: int,
@@ -1066,38 +1067,30 @@ def _swa_chunk(q_nope, q_pe, lp, win_flat, table_l, start_pos, seq_len,
     return jnp.moveaxis(out, 1, 2).reshape(T, H, -1)
 
 
-def _run_layers_mixed(params: Params, kv: KVCache, x: jax.Array,
-                      positions: jax.Array, slots: jax.Array,
-                      slots_s: jax.Array, cfg: ModelConfig, attn_fn,
-                      attn_s_fn, experts_sharded: bool = True,
-                      valid_rows: Optional[jax.Array] = None
-                      ) -> Tuple[jax.Array, KVCache]:
-    """``_run_layers`` for a model of two latent geometries (dots3_note):
-    the layers run in the published order, the dense prefix unrolled, then
-    ONE lax.scan over the periods of ``layer_types`` whose body holds a
-    period's layers, then what a cut depth leaves of a last period. The
-    program's size is that of one period, whatever the depth.
+def walk_layer_kinds(params: Params, kv: KVCache, x: jax.Array,
+                     cfg: ModelConfig, attend, experts_sharded: bool = True,
+                     valid_rows: Optional[jax.Array] = None
+                     ) -> Tuple[jax.Array, KVCache]:
+    """The layers of a model of two attention geometries (dots3_note's two
+    latent ones; models/mimo.py's two grouped-query ones), in the
+    published order: the dense prefix unrolled, then ONE lax.scan over the
+    periods of ``layer_types`` whose body holds a period's layers, then
+    what a cut depth leaves of a last period. The program's size is that
+    of one period, whatever the depth.
 
-    Every stack stays whole beside the scan and the body reads its layer
-    in place (the rule of _run_layers): ln1 / ln2 at the layer's index,
-    the full-attention stack (``layers.<leaf>``) at its index among the
-    full layers, the window stack (``layers.swa_<leaf>``) among the window
-    layers, the expert stacks among the expert layers.
+    Every stack stays whole beside the scan and is read at its layer in
+    place (the rule of _run_layers): ln1 / ln2 at the layer's index, the
+    expert stacks among the expert layers, and the attention stacks by
+    ``attend``, which is the model's:
 
-    attn_fn: as _run_layers gives it, with li the layer's index in the
-    paged pool. attn_s_fn(q_nope, q_pe, win_flat, lp, si) -> [N, Hs*dv]:
-    the window layers' read of kv["win"], whose rows for this dispatch go
-    to ``slots_s``."""
-    cfg_s = cfg.swa_geometry()
+    attend(kind, hn, pools, ai) -> (what the attention block adds to the
+    stream [N, D], pools): kind "F" or "S", hn the layer's normed input,
+    pools the cache arrays as the layers before left them, ai the layer's
+    index among the layers of its kind (its row of that kind's stacks and
+    of that kind's pool)."""
     stack = _layer_stack(params)
     k, period, n_periods, tail = layer_plan(cfg)
     kinds = layer_kinds(cfg)
-    NTOK = kv["kv"].shape[1]
-    n_f = kv["kv"].shape[0]
-    f_names = [n for n in stack if n in (
-        "wq", "wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_norm", "wkv_b", "wo",
-        "wg", "idx_wq_b", "idx_wk", "idx_k_norm_w", "idx_k_norm_b", "idx_w")]
-    s_names = [n for n in stack if n.startswith("swa_")]
     moe_all = {n: stack[n] for n in (
         "router", "router_bias", "moe_gate", "moe_up", "moe_down",
         "moe_gateup", "sh_gate", "sh_up", "sh_down", "sh_gateup")
@@ -1107,76 +1100,22 @@ def _run_layers_mixed(params: Params, kv: KVCache, x: jax.Array,
     dense_lp = {n[len("dense_"):]: stack[n] for n in stack
                 if n.startswith("dense_")}
 
-    def at(tree, i):
-        return jax.tree.map(
-            lambda w: jax.lax.dynamic_index_in_dim(w, i, keepdims=False),
-            tree)
-
-    def gated(attn, hn, lp, heads):
-        # the headwise gate: g_h = sigmoid(n(x)·Wg), o_h <- g_h · o_h
-        g = jax.nn.sigmoid(mm(hn, lp["wg"]).astype(jnp.float32))
-        N = attn.shape[0]
-        return (attn.reshape(N, heads, -1).astype(jnp.float32)
-                * g[..., None]).reshape(N, -1).astype(attn.dtype)
-
     def layer(h, pools, li, ai, kind):
         """li: the layer; ai: its index among the layers of its kind."""
-        ln = at({"ln1": stack["ln1"], "ln2": stack["ln2"]}, li)
+        ln = stack_at({"ln1": stack["ln1"], "ln2": stack["ln2"]}, li)
         hn = rms_norm(h, ln["ln1"], cfg.rms_norm_eps)
-        if kind == "F":
-            lp = at({n: stack[n] for n in f_names}, ai)
-            inv_np, att = rope_params(cfg)
-            q_nope, q_pe, qr = _q_proj(lp, hn, cfg)
-            q_pe = apply_rope_interleaved(q_pe, positions,
-                                          jnp.asarray(inv_np), att)
-            rows = _latent_rows(lp, hn, positions, cfg)
-            pool = pools["kv"]
-            enc = jnp.pad(rows.astype(pool.dtype),
-                          ((0, 0), (0, pool.shape[2] - rows.shape[1])))
-            pool = pool.at[ai, slots, :].set(enc, mode="drop")
-            pools = dict(pools, kv=pool)
-            extra = {}
-            if cfg.index_topk > 0:
-                with jax.named_scope("indexer"):
-                    qI, kI, w = _indexer_proj(lp, hn, qr, positions, cfg)
-                idx = pools["idx"].at[ai, slots, :].set(
-                    kI.astype(pools["idx"].dtype), mode="drop")
-                pools["idx"] = idx
-                extra["index"] = (qI, w,
-                                  idx.reshape(n_f * NTOK, idx.shape[2]))
-            attn = attn_fn(q_nope, q_pe, rows,
-                           pool.reshape(n_f * NTOK, pool.shape[2]), lp, ai,
-                           **extra)
-            heads = cfg.num_heads
-        else:
-            lp = {n[len("swa_"):]: w for n, w in
-                  at({n: stack[n] for n in s_names}, ai).items()}
-            inv_np, att = rope_params(cfg_s)
-            q_nope, q_pe, _qr = _q_proj(lp, hn, cfg_s)
-            q_pe = apply_rope_interleaved(q_pe, positions,
-                                          jnp.asarray(inv_np), att)
-            rows = _latent_rows(lp, hn, positions, cfg_s)
-            win = pools["win"]
-            enc = jnp.pad(rows.astype(win.dtype),
-                          ((0, 0), (0, win.shape[2] - rows.shape[1])))
-            win = win.at[ai, slots_s, :].set(enc, mode="drop")
-            pools = dict(pools, win=win)
-            attn = attn_s_fn(q_nope, q_pe,
-                             win.reshape(-1, win.shape[2]), lp, ai)
-            heads = cfg_s.num_heads
-        if cfg.attention_gate:
-            attn = gated(attn, hn, lp, heads)
-        h = h + mm(attn, lp["wo"])
+        delta, pools = attend(kind, hn, pools, ai)
+        h = h + delta
         hn2 = rms_norm(h, ln["ln2"], cfg.rms_norm_eps)
         return h, pools, hn2
 
     def dense_mlp(hn2, li):
-        lp = at(dense_lp, li)
+        lp = stack_at(dense_lp, li)
         return swiglu(hn2, lp.get("gate"), lp.get("up"), lp["down"],
                       cfg.hidden_act, gateup_w=lp.get("gateup"))
 
     def expert_mlp(hn2, mi):
-        return _moe_mlp(hn2, {**at(moe_lp, mi), **whole}, cfg,
+        return _moe_mlp(hn2, {**stack_at(moe_lp, mi), **whole}, cfg,
                         sharded=experts_sharded, valid_rows=valid_rows,
                         layer=mi if whole else None)
 
@@ -1212,6 +1151,95 @@ def _run_layers_mixed(params: Params, kv: KVCache, x: jax.Array,
                        tail)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     return x, pools
+
+
+def stack_at(tree, i):
+    """Layer ``i`` of every stack in ``tree``, read in place."""
+    return jax.tree.map(
+        lambda w: jax.lax.dynamic_index_in_dim(w, i, keepdims=False), tree)
+
+
+def _run_layers_mixed(params: Params, kv: KVCache, x: jax.Array,
+                      positions: jax.Array, slots: jax.Array,
+                      slots_s: jax.Array, cfg: ModelConfig, attn_fn,
+                      attn_s_fn, experts_sharded: bool = True,
+                      valid_rows: Optional[jax.Array] = None
+                      ) -> Tuple[jax.Array, KVCache]:
+    """``_run_layers`` for a model of two latent geometries (dots3_note):
+    ``walk_layer_kinds`` with the latent attention block of each kind. The
+    full-attention stack (``layers.<leaf>``) is read at the layer's index
+    among the full layers, the window stack (``layers.swa_<leaf>``) among
+    the window layers.
+
+    attn_fn: as _run_layers gives it, with li the layer's index in the
+    paged pool. attn_s_fn(q_nope, q_pe, win_flat, lp, si) -> [N, Hs*dv]:
+    the window layers' read of kv["win"], whose rows for this dispatch go
+    to ``slots_s``."""
+    cfg_s = cfg.swa_geometry()
+    stack = _layer_stack(params)
+    NTOK = kv["kv"].shape[1]
+    n_f = kv["kv"].shape[0]
+    f_names = [n for n in stack if n in (
+        "wq", "wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_norm", "wkv_b", "wo",
+        "wg", "idx_wq_b", "idx_wk", "idx_k_norm_w", "idx_k_norm_b", "idx_w")]
+    s_names = [n for n in stack if n.startswith("swa_")]
+
+    def gated(attn, hn, lp, heads):
+        # the headwise gate: g_h = sigmoid(n(x)·Wg), o_h <- g_h · o_h
+        g = jax.nn.sigmoid(mm(hn, lp["wg"]).astype(jnp.float32))
+        N = attn.shape[0]
+        return (attn.reshape(N, heads, -1).astype(jnp.float32)
+                * g[..., None]).reshape(N, -1).astype(attn.dtype)
+
+    def attend(kind, hn, pools, ai):
+        if kind == "F":
+            lp = stack_at({n: stack[n] for n in f_names}, ai)
+            inv_np, att = rope_params(cfg)
+            q_nope, q_pe, qr = _q_proj(lp, hn, cfg)
+            q_pe = apply_rope_interleaved(q_pe, positions,
+                                          jnp.asarray(inv_np), att)
+            rows = _latent_rows(lp, hn, positions, cfg)
+            pool = pools["kv"]
+            enc = jnp.pad(rows.astype(pool.dtype),
+                          ((0, 0), (0, pool.shape[2] - rows.shape[1])))
+            pool = pool.at[ai, slots, :].set(enc, mode="drop")
+            pools = dict(pools, kv=pool)
+            extra = {}
+            if cfg.index_topk > 0:
+                with jax.named_scope("indexer"):
+                    qI, kI, w = _indexer_proj(lp, hn, qr, positions, cfg)
+                idx = pools["idx"].at[ai, slots, :].set(
+                    kI.astype(pools["idx"].dtype), mode="drop")
+                pools["idx"] = idx
+                extra["index"] = (qI, w,
+                                  idx.reshape(n_f * NTOK, idx.shape[2]))
+            attn = attn_fn(q_nope, q_pe, rows,
+                           pool.reshape(n_f * NTOK, pool.shape[2]), lp, ai,
+                           **extra)
+            heads = cfg.num_heads
+        else:
+            lp = {n[len("swa_"):]: w for n, w in
+                  stack_at({n: stack[n] for n in s_names}, ai).items()}
+            inv_np, att = rope_params(cfg_s)
+            q_nope, q_pe, _qr = _q_proj(lp, hn, cfg_s)
+            q_pe = apply_rope_interleaved(q_pe, positions,
+                                          jnp.asarray(inv_np), att)
+            rows = _latent_rows(lp, hn, positions, cfg_s)
+            win = pools["win"]
+            enc = jnp.pad(rows.astype(win.dtype),
+                          ((0, 0), (0, win.shape[2] - rows.shape[1])))
+            win = win.at[ai, slots_s, :].set(enc, mode="drop")
+            pools = dict(pools, win=win)
+            attn = attn_s_fn(q_nope, q_pe,
+                             win.reshape(-1, win.shape[2]), lp, ai)
+            heads = cfg_s.num_heads
+        if cfg.attention_gate:
+            attn = gated(attn, hn, lp, heads)
+        return mm(attn, lp["wo"]), pools
+
+    return walk_layer_kinds(params, kv, x, cfg, attend,
+                            experts_sharded=experts_sharded,
+                            valid_rows=valid_rows)
 
 
 

@@ -325,6 +325,10 @@ _LAYER_MATMULS = ("wq", "wk", "wv", "wo", "gate", "up", "down",
                   # precision like wkv_b, and the headwise gates (wg,
                   # swa_wg: H out-channels behind a sigmoid) like a router
                   "swa_wq_a", "swa_wq_b", "swa_wkv_a", "swa_wo",
+                  # mimo_v2's window layers (models/mimo.py): plain
+                  # grouped-query projections at the second geometry
+                  # (swa_sink, one float32 scalar a head, stays as it is)
+                  "swa_wq", "swa_wk", "swa_wv",
                   # phi4flash (models/sambay.py; names are
                   # layers.<kind>.<leaf>): the MLP, the state-space
                   # layer's four projections, the attention layers' qkv /

@@ -74,12 +74,22 @@ _LAYER_MAP = {
     # dots3_note's headwise attention gate (no modelling code is public:
     # the name is the gated-attention papers' g_proj, assumed)
     "self_attn.g_proj.weight": ("wg", True),
+    # mimo_v2's learned sinks, one scalar a query head of a window layer (no
+    # checkpoint or modelling code is on this machine: the name is assumed)
+    "self_attn.attention_sink_bias": ("sink", False),
 }
 
-# the attention leaves that a model with window layers of a latent geometry
-# of their own (dots3_note) keeps in two stacks, by the layer's kind
+# the attention leaves that a model with window layers of a geometry of
+# their own keeps in two stacks, by the layer's kind: latent (dots3_note)
+# and grouped-query (mimo_v2)
 _SWA_LEAVES = ("wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_norm", "wkv_b", "wo",
                "wg")
+_SWA_GQA_LEAVES = ("wq", "wk", "wv", "wo", "sink")
+
+
+def _swa_leaves(cfg: ModelConfig) -> tuple:
+    return (_SWA_LEAVES if cfg.has_swa_latent
+            else _SWA_GQA_LEAVES if cfg.has_swa_gqa else ())
 
 # mixtral expert sub-weights: w1=gate, w3=up, w2=down (all torch [out, in])
 _EXPERT_MAP = {"w1": "moe_gate", "w3": "moe_up", "w2": "moe_down",
@@ -101,7 +111,7 @@ def _layer_map_for(cfg: ModelConfig) -> Dict[str, tuple]:
         layer_map["post_attention_layernorm.weight"] = ("ln1_post", False)
         layer_map["pre_feedforward_layernorm.weight"] = ("ln2", False)
         layer_map["post_feedforward_layernorm.weight"] = ("ln2_post", False)
-    if (cfg.kv_lora_rank > 0
+    if ((cfg.kv_lora_rank > 0 or cfg.has_swa_gqa)
             and cfg.num_experts > 0):
         # hybrid sparsity: mlp.*_proj exists only on the dense-prefix
         # layers and lands in the dense_* stacks (_partial_ranges)
@@ -126,13 +136,20 @@ def _layer_map_for(cfg: ModelConfig) -> Dict[str, tuple]:
 def _fused_sections(cfg: ModelConfig) -> Dict[str, list]:
     """Fused HF layer tensors → the row sections (torch [out, in]
     orientation) that map onto our split keys: phi3 packs q/k/v into
-    ``qkv_proj`` and gate/up into ``gate_up_proj`` (HF Phi3Config).
+    ``qkv_proj`` and gate/up into ``gate_up_proj`` (HF Phi3Config);
+    mimo_v2's ``attention_projection_layout: fused_qkv`` is read as q | k | v
+    rows at the sizes of ``cfg``'s geometry (a window layer's:
+    ``cfg.swa_gqa_geometry()``; the tensor's name is assumed).
     Returns {suffix: [(key, row_offset, row_count)]}; one home for both
     loaders."""
-    if cfg.model_type != "phi3":
-        return {}
     qd = cfg.num_heads * cfg.head_dim
     kvd = cfg.num_kv_heads * cfg.head_dim
+    if cfg.has_swa_gqa:
+        return {"self_attn.qkv_proj.weight": [
+            ("wq", 0, qd), ("wk", qd, kvd),
+            ("wv", qd + kvd, cfg.num_kv_heads * cfg.v_head_dim)]}
+    if cfg.model_type != "phi3":
+        return {}
     return {
         "self_attn.qkv_proj.weight": [
             ("wq", 0, qd), ("wk", qd, kvd), ("wv", qd + kvd, kvd)],
@@ -146,7 +163,7 @@ def _partial_ranges(cfg: ModelConfig):
     """Stacked keys that cover only a LAYER RANGE (deepseek hybrid
     sparsity): key -> (lo, hi) global layer bounds. Empty for uniform
     families."""
-    if (cfg.kv_lora_rank == 0
+    if ((cfg.kv_lora_rank == 0 and not cfg.has_swa_gqa)
             or cfg.num_experts == 0):
         return {}
     k, L = cfg.first_k_dense, cfg.num_layers
@@ -160,9 +177,9 @@ def _partial_ranges(cfg: ModelConfig):
 
 def _layers_of_stack(cfg: ModelConfig, key: str, lo: int, hi: int) -> list:
     """The layers whose tensors the stack ``key`` holds, in order: the range
-    [lo, hi), or with two attention geometries (dots3_note) the layers of
-    the stack's kind."""
-    if not cfg.has_swa_latent or key in ("ln1", "ln2") or lo or hi != \
+    [lo, hi), or with two attention geometries (dots3_note, mimo_v2) the
+    layers of the stack's kind."""
+    if not _swa_leaves(cfg) or key in ("ln1", "ln2") or lo or hi != \
             cfg.num_layers:
         return list(range(lo, hi))
     kind = "sliding_attention" if key.startswith("swa_") else \
@@ -303,6 +320,9 @@ def load_llama_params(model_dir: str, cfg: Optional[ModelConfig] = None,
     L, E = cfg.num_layers, cfg.num_experts
     layer_map = _layer_map_for(cfg)
     fused = _fused_sections(cfg)
+    swa_leaves = _swa_leaves(cfg)
+    fused_swa = (_fused_sections(cfg.swa_gqa_geometry())
+                 if cfg.has_swa_gqa else fused)
     staging: Dict[str, list] = {}
     expert_staging: Dict[str, list] = {}   # key → [L][E] tensors
     singles: Dict[str, np.ndarray] = {}
@@ -347,19 +367,21 @@ def load_llama_params(model_dir: str, cfg: Optional[ModelConfig] = None,
                     key, [[None] * E for _ in range(L)])
                 grid[int(idx_str)][e_local] = tensor.T
                 continue
+            # a window layer's attention leaves go to that kind's own stack
+            swa = "swa_" if swa_leaves and cfg.layer_types[
+                int(idx_str)] == "sliding_attention" else ""
             if sub in fused:
                 # split the fused tensor's torch rows into our keys
-                for key, off, cnt in fused[sub]:
-                    staging.setdefault(key, [None] * L)[int(idx_str)] = \
-                        tensor[off:off + cnt].T
+                for key, off, cnt in (fused_swa if swa else fused)[sub]:
+                    staging.setdefault(swa + key, [None] * L)[
+                        int(idx_str)] = tensor[off:off + cnt].T
                 continue
             mapped = layer_map.get(sub)
             if mapped is None:
                 continue  # rotary inv_freq buffers etc.
             key, transpose = mapped
-            if key in _SWA_LEAVES and cfg.has_swa_latent and (
-                    cfg.layer_types[int(idx_str)] == "sliding_attention"):
-                key = "swa_" + key        # the window layers' own stack
+            if key in swa_leaves:
+                key = swa + key
             arr = tensor.T if transpose else tensor
             staging.setdefault(key, [None] * L)[int(idx_str)] = arr
 
@@ -380,7 +402,9 @@ def load_llama_params(model_dir: str, cfg: Optional[ModelConfig] = None,
                 f"{missing[:4]}, outside-range {extra[:4]} "
                 f"(expected layers [{lo}, {hi}))")
         params[f"layers.{key}"] = jnp.asarray(
-            _track(np.stack(rows, axis=0)), dtype=dtype)
+            _track(np.stack(rows, axis=0)),
+            # mimo_v2's sinks stay float32 whatever the load dtype
+            dtype=jnp.float32 if key == "swa_sink" else dtype)
     for key, grid in expert_staging.items():
         lo, hi = partial.get(key, (0, L))
         rows = grid[lo:hi]
@@ -802,12 +826,13 @@ def save_hf_style(params: Dict[str, jax.Array], cfg: ModelConfig,
     """Write params back out as a single HF-style safetensors file (used by
     tests to cross-check against the torch reference implementation)."""
     from safetensors.numpy import save_file
-    if (cfg.kv_lora_rank > 0
+    if ((cfg.kv_lora_rank > 0 or cfg.has_swa_gqa)
             and cfg.num_experts > 0):
         raise NotImplementedError(
             "save_hf_style cannot write the deepseek hybrid MoE layout "
-            "(partial layer stacks + deepseek expert naming); the MLA "
-            "tests carry their own converter")
+            "(partial layer stacks + deepseek expert naming), nor "
+            "mimo_v2's (attention stacks by layer kind); the MLA and "
+            "mimo_v2 tests carry their own converters")
     os.makedirs(out_dir, exist_ok=True)
 
     def c(a) -> np.ndarray:

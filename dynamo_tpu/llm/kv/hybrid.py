@@ -32,11 +32,23 @@ length; a released block whose hash is registered stays as evictable cache,
 and a prefix hit needs the paged blocks of the whole prefix AND the window
 blocks of its last ``window`` rows (``KvBlockManager``,
 docs/hybrid_cache.md). With no state, reuse stays on.
+
+``models/mimo.py``'s mimo_v2 has the same two groups with plain
+grouped-query rows: K and V arrays of the full layers under the paged ids
+(``kv["k"]``, ``kv["v"]``), K and V arrays of the window layers under the
+window pool's (``kv["win_k"]``, ``kv["win_v"]``), and there the window rows
+are the wider ones (``window_row_bytes``; docs/hybrid_cache.md part three).
 """
 
 from __future__ import annotations
 
 import dataclasses
+
+
+# the evictable part of a window pool (HybridCacheLayout.window_pool_blocks):
+# hit boundaries kept a slot, and its bytes against the paged pool's
+WINDOW_TAILS_PER_SLOT = 4
+WINDOW_CACHE_BYTES_RATIO = (3, 2)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,9 +61,12 @@ class HybridCacheLayout:
     window: int
     state_layers: int
     state_bytes: int           # one slot's state of one layer
-    # window rows are blocks of a second pool (dots3_note), not per-slot
-    # rings (phi4flash)
+    # window rows are blocks of a second pool (dots3_note, mimo_v2), not
+    # per-slot rings (phi4flash)
     window_pool: bool = False
+    # one token's rows of one WINDOW layer, where they are of another width
+    # than the paged layers' (0: row_bytes)
+    window_row_bytes: int = 0
 
     @property
     def ring_blocks(self) -> int:
@@ -74,11 +89,26 @@ class HybridCacheLayout:
         """Blocks of the window pool that goes with a paged pool of
         ``num_blocks``: what every slot's live window and one prefill
         dispatch of ``prefill_tokens`` can hold at once (an allocation
-        there never fails), and for half of the paged pool's blocks their
-        window sibling as evictable cache. Derived, not a flag."""
+        there never fails), and an evictable part: the window sibling of
+        half the paged pool's blocks, but no more than
+        WINDOW_TAILS_PER_SLOT hit boundaries' worth a slot (a cached window
+        block serves a hit only as one of the ``window_reach_blocks`` before
+        the boundary the hit ends at) and never more bytes than
+        WINDOW_CACHE_BYTES_RATIO of the paged pool's: a window block may be
+        several times a paged one (mimo_v2: 6.7), and the count alone would
+        then ask for more cache than the pool it serves. Derived, not a
+        flag (docs/hybrid_cache.md part three)."""
         live = max_num_seqs * self.ring_blocks
         prefill = -(-prefill_tokens // self.block_size) + self.ring_blocks
-        return 1 + live + prefill + num_blocks // 2
+        paged_block = self.paged_layers * self.row_bytes
+        window_block = self.window_layers * (self.window_row_bytes
+                                             or self.row_bytes)
+        num, den = WINDOW_CACHE_BYTES_RATIO
+        cached = min(
+            num_blocks // 2,
+            WINDOW_TAILS_PER_SLOT * max_num_seqs * self.window_reach_blocks,
+            (num * num_blocks * paged_block) // (den * window_block))
+        return 1 + live + prefill + cached
 
     def blocks_by_kind(self, context_tokens: int) -> dict:
         """Blocks (state: slots) that one sequence of ``context_tokens``

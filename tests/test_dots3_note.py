@@ -673,8 +673,11 @@ async def test_a_context_of_forty_windows_holds_a_ring(ref):
     core = _engine(params, cfg, max_model_len=1024, num_kv_blocks=128,
                    prefill_chunk=32, prefill_buckets=[32])
     wp = core.kv_manager.win_pool
-    # sized from the layout: every slot's ring, one dispatch, half the pool
-    assert wp.num_blocks == 1 + 2 * 3 + (2 + 3) + 64
+    # sized from the layout: every slot's ring, one dispatch, and the
+    # evictable part, which since PR 46 is bounded by four hit boundaries a
+    # slot (2 slots x 4 x a reach of 2) before half the pool (64) or the
+    # bytes bound (docs/hybrid_cache.md part three)
+    assert wp.num_blocks == 1 + 2 * 3 + (2 + 3) + 16
     prompt = _tokens(cfg, 840, seed=12)
     peak = []
     grow = core.kv_manager.window_grow

@@ -1033,3 +1033,87 @@ def test_hybrid_scans_lower_no_slice_of_an_attention_stack(model, program):
     for stack in stacks:
         assert re.search(r"stablehlo\.dynamic_slice .*\(" + re.escape(stack),
                          text), stack
+
+
+# ---- mimo_v2: grouped-query reads of two geometries (models/mimo.py) ------
+
+def _mimo_cfg():
+    from dynamo_tpu.engine.config import ModelConfig
+    cfg = ModelConfig.from_hf_config(_benchmark_hf("configs/mimo-v2.5.json"))
+    geo = cfg.swa_gqa_geometry()
+    assert (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+            cfg.v_head_dim) == (64, 4, 192, 128)
+    assert (geo.num_heads, geo.num_kv_heads, geo.head_dim,
+            geo.v_head_dim) == (64, 8, 192, 128)
+    return cfg, geo
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_gqa_decode_reads_build_at_the_published_sizes(one_chip, kind):
+    """MiMo-V2.5's two decode reads as the benchmark's cell serves them
+    (models/mimo.py ``decode_forward``): 64 slots, 64 query heads of 192
+    lanes over value heads of 128. Full: 4 kv heads (rows of 768 | 512
+    lanes: the ROW lies on 128-lane tiles, a head's key at lane 192 x kh
+    does not), tables of 2,272 blocks of 16 (36,352 tokens) into a
+    three-layer pool of 24,576 blocks, waves of 512 rows. Window: 8 kv
+    heads (rows of 1,536 | 1,024), a ring of 9 window-pool blocks with a
+    lower bound and the sink, one wave, a ten-layer pool of 2,650."""
+    from dynamo_tpu.engine.models import mimo, mla
+    cfg, geo = _mimo_cfg()
+    B, bs = 64, 16
+    R = mla.swa_ring_blocks(cfg, bs)
+    assert R == 9 and mimo.decode_kernels_tile(cfg, bs)
+    c, M, layers, blocks, name, chunk = (
+        (cfg, 36352 // bs, 3, 24576, "gqa_full_read",
+         mimo.GQA_WAVE_ROWS // bs) if kind == "full" else
+        (geo, R, 10, 2650, "gqa_window_read", R))
+    ck, cv = mimo.row_lanes(c)
+
+    def fn(q, k, v, tables, lens, lo, sink):
+        return A.paged_attention(
+            q, k, v, tables, lens, block_size=bs, scale=192 ** -0.5,
+            impl="pallas", kv_heads=c.num_kv_heads, v_dim=c.v_head_dim,
+            chunk_blocks=chunk, name=name,
+            **({"win_lo": lo, "sink": sink} if kind == "window" else {}))
+
+    text = _compile(fn, one_chip, ((B, 64, 192), jnp.bfloat16),
+                    ((layers * blocks * bs, ck), jnp.bfloat16),
+                    ((layers * blocks * bs, cv), jnp.bfloat16),
+                    ((B, M), jnp.int32), ((B,), jnp.int32),
+                    ((B,), jnp.int32), ((64,), jnp.float32)).as_text()
+    assert name in text
+    assert f"bf16[{B},{M * bs}," not in text        # no gathered table
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_gqa_prefill_reads_build_at_the_published_sizes(one_chip, kind):
+    """The two prefill reads of a 256-token chunk (models/mimo.py
+    ``_full_chunk`` / ``_window_chunk``): the full layers' key-block walk
+    over a 36,352-token table in the flash kernel's partial form, keys of
+    192 and values of 128 lanes; the window layers' chunk + 127 rows from
+    the window pool with the window and the sink."""
+    from dynamo_tpu.engine.models import mimo
+    from dynamo_tpu.engine.models.llama import ModelStatics
+    cfg, geo = _mimo_cfg()
+    bs, T, M = 16, 256, 36352 // 16
+    statics = ModelStatics(cfg=cfg, block_size=bs, attn_impl="pallas")
+    assert mimo._kernel_form(statics, True, "flash prefill") is True
+    c, layers, blocks = (cfg, 3, 24576) if kind == "full" else (geo, 10, 2650)
+    ck, cv = mimo.row_lanes(c)
+
+    def fn(q, k, v, table, start, seq_len, sink):
+        if kind == "full":
+            return mimo._full_chunk(q, k, v, 1, table, start, seq_len, cfg,
+                                    bs, True)
+        return mimo._window_chunk(q, k, v, 7, table, start, seq_len, geo,
+                                  cfg.swa_window, bs, sink, True)
+
+    text = _compile(fn, one_chip, ((T, 64, 192), jnp.bfloat16),
+                    ((layers, blocks * bs, ck), jnp.bfloat16),
+                    ((layers, blocks * bs, cv), jnp.bfloat16),
+                    ((M,), jnp.int32), ((), jnp.int32), ((), jnp.int32),
+                    ((64,), jnp.float32)).as_text()
+    assert f"gqa_{kind}_prefill" in text
+    # no layer's slice of the pool is copied to be read from
+    assert f"bf16[{blocks * bs},{ck}]" not in text
+    assert f"bf16[1,{blocks * bs},{ck}]" not in text
