@@ -15,6 +15,8 @@ from typing import Optional
 from prometheus_client import (CollectorRegistry, Counter, Gauge, Histogram,
                                generate_latest)
 
+from ..preprocessor import TOKENIZED_PROMPT_TOKENS
+
 PREFIX = "nv_llm_http_service"
 
 REQUEST_STATUS_SUCCESS = "success"
@@ -70,6 +72,9 @@ class ServiceMetrics:
             f"{PREFIX}_sse_flushed_chunks_total",
             "SSE chunks those passes wrote",
             registry=self.registry)
+        # how often the preprocessor's off-thread encode engages: its
+        # own process-wide counter, shown beside this service's series
+        self.registry.register(TOKENIZED_PROMPT_TOKENS)
 
     def render(self) -> bytes:
         return generate_latest(self.registry)

@@ -13,13 +13,18 @@ It is a pipeline :class:`Operator` on both the chat and completion types, so
 
 from __future__ import annotations
 
+import asyncio
 import datetime
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import AsyncIterator, List, Optional
 
 import jinja2
+from prometheus_client import Counter
 
 from ..runtime.engine import AsyncEngine, ManyOut, ResponseStream, SingleIn
 from ..runtime.pipeline import Operator
+from ..runtime.tracing import span
 from .model_card import ModelDeploymentCard
 from .protocols.annotated import Annotated
 from .protocols.common import (BackendOutput, FinishReason, OutputOptions,
@@ -32,6 +37,35 @@ from .tools import ToolCallingMatcher, ToolChoice
 
 ANNOTATION_TOKEN_IDS = "token_ids"
 ANNOTATION_FORMATTED_PROMPT = "formatted_prompt"
+
+# A prompt of at least this many characters is encoded on a worker thread
+# (`OpenAIPreprocessor._tokenize`): the encode of a 33k-token body holds the
+# event loop, and with it a served engine's cycle, for 50 ms. The hop costs
+# the request up to one cycle of the loop's wake-up, so a text whose inline
+# encode stays under ~2 ms keeps to the loop's thread; that is where this
+# tokenizer's encode passes 2 ms on the chip's host (CHANGES.md, PR 47).
+OFFTHREAD_MIN_CHARS = 12_288
+
+# how often the hop engages, process-wide; in no registry of its own: the
+# HTTP front end registers it beside its other series (llm/http/metrics.py)
+TOKENIZED_PROMPT_TOKENS = Counter(
+    "nv_llm_http_service_tokenized_prompt_tokens",
+    "Prompt tokens encoded, by the thread that encoded them",
+    ["branch"], registry=None)
+for _branch in ("inline", "offthread"):
+    TOKENIZED_PROMPT_TOKENS.labels(_branch)
+
+_tokenize_pool: Optional[ThreadPoolExecutor] = None
+
+
+def _pool() -> ThreadPoolExecutor:
+    global _tokenize_pool
+    if _tokenize_pool is None:
+        _tokenize_pool = ThreadPoolExecutor(
+            max_workers=max(1, min(4, (os.cpu_count() or 2) - 1)),
+            thread_name_prefix="tokenize")
+    return _tokenize_pool
+
 
 _FALLBACK_TEMPLATE = (
     "{% for message in messages %}"
@@ -107,44 +141,62 @@ class OpenAIPreprocessor(Operator):
 
     # ------------------------------------------------------------------ fwd
     def preprocess_chat(self, req: ChatCompletionRequest) -> PreprocessedRequest:
-        return self._preprocess_chat(req)[0]
+        return self._common(
+            req, self.tokenizer.encode_ids(self._chat_prompt(req)),
+            req.effective_max_tokens())
 
-    def _preprocess_chat(self, req: ChatCompletionRequest
-                         ) -> tuple[PreprocessedRequest, str]:
-        """Returns (request, formatted_prompt) — kept stateless so one
-        operator instance serves concurrent requests."""
+    def _chat_prompt(self, req: ChatCompletionRequest) -> str:
         use_raw = bool(req.nvext and req.nvext.use_raw_prompt)
         if use_raw and len(req.messages) == 1:
-            prompt = req.messages[0].text()
-        else:
-            messages = []
-            for m in req.messages:
-                d = {"role": m.role, "content": m.text()}
-                if m.name:
-                    d["name"] = m.name
-                if m.tool_calls:
-                    d["tool_calls"] = m.tool_calls
-                messages.append(d)
-            prompt = self.formatter.render(messages, tools=req.tools)
-        token_ids = self.tokenizer.encode(prompt).ids
-        pre = self._common(req, token_ids, req.effective_max_tokens(),
-                           req.stop_list())
-        pre.annotations = list((req.nvext.annotations if req.nvext else None) or [])
-        return pre, prompt
+            return req.messages[0].text()
+        messages = []
+        for m in req.messages:
+            d = {"role": m.role, "content": m.text()}
+            if m.name:
+                d["name"] = m.name
+            if m.tool_calls:
+                d["tool_calls"] = m.tool_calls
+            messages.append(d)
+        return self.formatter.render(messages, tools=req.tools)
 
     def preprocess_completion(self, req: CompletionRequest) -> PreprocessedRequest:
-        if isinstance(req.prompt, str):
-            token_ids = self.tokenizer.encode(req.prompt).ids
-        elif req.prompt and isinstance(req.prompt[0], int):
-            token_ids = list(req.prompt)  # pre-tokenized
-        else:
-            raise ValueError("batch prompts must be fanned out before preprocessing")
-        pre = self._common(req, token_ids, req.max_tokens, req.stop_list())
-        pre.annotations = list((req.nvext.annotations if req.nvext else None) or [])
-        return pre
+        text = self._completion_text(req)
+        return self._common(
+            req, list(req.prompt) if text is None
+            else self.tokenizer.encode_ids(text), req.max_tokens)
 
-    def _common(self, req, token_ids: List[int], max_tokens: Optional[int],
-                stops: List[str]) -> PreprocessedRequest:
+    @staticmethod
+    def _completion_text(req: CompletionRequest) -> Optional[str]:
+        """The prompt to tokenize; None for a pre-tokenized one."""
+        if isinstance(req.prompt, str):
+            return req.prompt
+        if req.prompt and isinstance(req.prompt[0], int):
+            return None
+        raise ValueError("batch prompts must be fanned out before preprocessing")
+
+    async def _tokenize(self, text: str) -> List[int]:
+        """The prompt's ids, the same from both branches. A long text is
+        encoded on a worker thread through the tokenizer's lock-releasing
+        entry, and the event loop goes on meanwhile; a short one, and any
+        text of a tokenizer kind that declares no such entry, is encoded
+        here. A cancelled request leaves the worker to finish and drops
+        its result; the worker's exception is raised here."""
+        unlocked = getattr(self.tokenizer, "encode_ids_unlocked", None)
+        offthread = unlocked is not None and len(text) >= OFFTHREAD_MIN_CHARS
+        with span("tokenize", offthread=offthread) as s:
+            if offthread:
+                ids = await asyncio.get_running_loop().run_in_executor(
+                    _pool(), unlocked, text)
+            else:
+                ids = self.tokenizer.encode_ids(text)
+            if s is not None:
+                s.attrs["tokens"] = len(ids)
+        TOKENIZED_PROMPT_TOKENS.labels(
+            "offthread" if offthread else "inline").inc(len(ids))
+        return ids
+
+    def _common(self, req, token_ids: List[int],
+                max_tokens: Optional[int]) -> PreprocessedRequest:
         info = self.mdc.model_info
         budget = info.context_length - len(token_ids)
         if budget <= 0:
@@ -155,7 +207,7 @@ class OpenAIPreprocessor(Operator):
         ignore_eos = bool(nvext and nvext.ignore_eos)
         stop_conditions = StopConditions(
             max_tokens=min(max_tokens, budget) if max_tokens is not None else budget,
-            stop=stops or None,
+            stop=req.stop_list() or None,
             stop_token_ids_hidden=list(info.eos_token_ids),
             ignore_eos=ignore_eos,
         )
@@ -186,6 +238,7 @@ class OpenAIPreprocessor(Operator):
             output_options=output,
             eos_token_ids=list(info.eos_token_ids),
             mdc_sum=None,
+            annotations=list((nvext.annotations if nvext else None) or []),
             # per-request draft budget (engine/spec/); None falls back
             # to the serving engine's live default
             speculation=(nvext.speculation if nvext else None),
@@ -199,7 +252,6 @@ class OpenAIPreprocessor(Operator):
 
     # ------------------------------------------------------------- operator
     async def generate(self, request: SingleIn, next_engine: AsyncEngine) -> ManyOut:
-        from ..runtime.tracing import span
         req = request.data
         is_chat = ("messages" in req if isinstance(req, dict)
                    else isinstance(req, ChatCompletionRequest))
@@ -208,10 +260,16 @@ class OpenAIPreprocessor(Operator):
                 req = (ChatCompletionRequest if is_chat
                        else CompletionRequest).model_validate(req)
             if is_chat:
-                pre, formatted_prompt = self._preprocess_chat(req)
+                formatted_prompt = self._chat_prompt(req)
+                token_ids = await self._tokenize(formatted_prompt)
+                max_tokens = req.effective_max_tokens()
             else:
-                pre = self.preprocess_completion(req)
                 formatted_prompt = None
+                text = self._completion_text(req)
+                token_ids = (list(req.prompt) if text is None  # pre-tokenized
+                             else await self._tokenize(text))
+                max_tokens = req.max_tokens
+            pre = self._common(req, token_ids, max_tokens)
         prompt_len = len(pre.token_ids)
         annotations: List[Annotated] = []
         if ANNOTATION_TOKEN_IDS in pre.annotations:
