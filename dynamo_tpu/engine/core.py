@@ -2371,6 +2371,7 @@ class EngineCore:
         # record's grouped_rows): the model's own chooser, asked per
         # dispatched program shape
         grouped_rows = 0
+        dsa_counts = {}
         remote_admit = req.precomputed is not None
         if remote_admit:
             from ..llm.kv.stream import LayerStreamPayload
@@ -2436,6 +2437,8 @@ class EngineCore:
                                                      slot=slot)
                 grouped_rows = llama.grouped_prefill_rows(
                     self.statics, self.cfg.prefill_chunk, len(chunk))
+                dsa_counts = self._dsa_block_counts(self.cfg.prefill_chunk,
+                                                    len(chunk))
             else:
                 padded = np.zeros((bucket,), np.int32)
                 padded[:len(chunk)] = chunk
@@ -2457,6 +2460,7 @@ class EngineCore:
                     jnp.asarray(req.sampling.top_p, jnp.float32))
                 grouped_rows = llama.grouped_prefill_rows(
                     self.statics, bucket, len(chunk))
+                dsa_counts = self._dsa_block_counts(bucket, len(chunk))
                 self._window_after(req, n_prompt)
             self.total_prefill_tokens += len(chunk)
             self.clock.admits += 1
@@ -2551,6 +2555,9 @@ class EngineCore:
                         + suffix_len * (suffix_len + 1) // 2
                         if self.is_mla and not self.model_cfg.index_topk
                         and not remote_admit else 0),
+            # the query blocks of the sparse attention's walk, and those
+            # that held a live row and ran (a model with an indexer only)
+            **dsa_counts,
             host_ms=round(1e3 * (now - t0), 3),
             # of host_ms: plan to the prefill program's return (argument
             # build and transfers included), and the blocking fetch of
@@ -2724,6 +2731,22 @@ class EngineCore:
             # let go here is rewritten only by a later dispatch
             self._window_after(req, off)
         return tok, logprob
+
+    def _dsa_block_counts(self, bucket: int, n: int) -> dict:
+        """{dsa_blocks, dsa_blocks_run} of a prefill of ``n`` prompt rows in
+        ``bucket``-row dispatches by a model with an indexer ({} otherwise):
+        the query blocks of the sparse attention's walk in one layer, summed
+        over the dispatches, and those of them that held a live row and ran
+        (the tail dispatch's padding runs none). The model's own arithmetic
+        (``mla.sparse_query_blocks``: what the program computes from
+        ``true_len``)."""
+        if not self.model_cfg.index_topk:
+            return {}
+        counts = [self.model_mod.sparse_query_blocks(bucket,
+                                                     min(bucket, n - lo))
+                  for lo in range(0, n, bucket)]
+        return {"dsa_blocks": sum(b for b, _ in counts),
+                "dsa_blocks_run": sum(r for _, r in counts)}
 
     def _complete_admissions(self) -> None:
         """Finish deferred admissions: the async device→host copies have

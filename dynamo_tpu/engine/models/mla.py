@@ -70,7 +70,7 @@ Attention on top of the v3 block:
   over the gathered rows only. ``ctx <= index_topk`` selects every valid
   row and equals the dense path. Prefill blocks its queries
   (``DSA_QUERY_BLOCK``) so that nothing of size heads × chunk × table
-  exists;
+  exists, and walks only the blocks that hold a live row of the chunk;
 - **one chip's share of the experts** (``num_experts_total > 0``): the
   router, its bias and the groups keep the published width; the expert
   stacks hold ``num_experts`` of them, and ``_moe_mlp`` adds what the
@@ -115,6 +115,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -694,25 +695,42 @@ def _sparse_rows(q_lat, q_pe, index, kv_flat, tables_l, seq_lens,
                                 cfg.qk_rope_head_dim)
 
 
+def sparse_query_blocks(T: int, true_len):
+    """→ (blocks, blocks_run): the DSA_QUERY_BLOCK-row query blocks of a
+    T-row prefill chunk, and those that hold one of its ``true_len`` live
+    rows: what ``_sparse_chunk`` runs. ``true_len`` is the host's int (the
+    prefill flight record's ``dsa_blocks`` / ``dsa_blocks_run``) or the
+    traced length inside the program: one arithmetic for both."""
+    TQ = math.gcd(T, DSA_QUERY_BLOCK)
+    blocks = T // TQ
+    run = (true_len + TQ - 1) // TQ
+    if isinstance(true_len, jax.Array):
+        return blocks, jnp.clip(run, 0, blocks)
+    return blocks, min(max(int(run), 0), blocks)
+
+
 def _sparse_chunk(q_nope, q_pe, w_k, index, kv_flat, table_l, positions,
                   seq_len, cfg: ModelConfig, bsz: int,
                   scale: float) -> jax.Array:
-    """Select, then attend, for the T queries of one prefill chunk, which
+    """Select, then attend, for the queries of one prefill chunk, which
     share one block table (table_l [M], layer-offset block ids), a block
     of DSA_QUERY_BLOCK queries at a time: the index scores of a block are
     [J, block, table] and the rows it gathers [block, topk, W]; nothing
-    of size chunk × table × heads exists. → probs·c [T, H, rank]."""
-    import math
+    of size chunk × table × heads exists. Only the blocks that hold a live
+    query (a position below seq_len) run: a chunk's padded tail selects
+    and reads nothing, and its rows of the result are zero (the last live
+    block's own padded rows are computed with it and discarded by the
+    caller). → probs·c [T, H, rank]."""
     qI, w, idx_flat = index
     T, H = q_nope.shape[0], q_nope.shape[1]
     S, dI = table_l.shape[0] * bsz, idx_flat.shape[-1]
     keys = _keys_by_block(idx_flat, table_l, bsz).reshape(S, dI)
     kpos = jnp.arange(S)[None, :]
     slots = TableSlots(table_l, bsz, kv_flat.shape[0] // bsz)
-    TQ = math.gcd(T, DSA_QUERY_BLOCK)
+    blocks, n_live = sparse_query_blocks(T, seq_len - positions[0])
+    TQ = T // blocks
 
-    def block(xs):
-        qn, qp, qi, wj, pos = xs
+    def block(qn, qp, qi, wj, pos):
         with jax.named_scope("dsa_select"):
             live = (kpos <= pos[:, None]) & (kpos < seq_len)
             _, valid, slot_ids = _select(_index_scores(qi, wj, keys), live,
@@ -724,9 +742,17 @@ def _sparse_chunk(q_nope, q_pe, w_k, index, kv_flat, table_l, positions,
                                     slot_ids, valid, scale,
                                     cfg.kv_lora_rank, cfg.qk_rope_head_dim)
 
-    split = lambda a: a.reshape((T // TQ, TQ) + a.shape[1:])  # noqa: E731
-    ctx = jax.lax.map(block, tuple(split(a) for a in (
-        q_nope, q_pe, qI, w, positions)))
+    xs = tuple(a.reshape((blocks, TQ) + a.shape[1:])
+               for a in (q_nope, q_pe, qI, w, positions))
+
+    def step(i, ctx):
+        # the trip count is the traced n_live: a while loop on the device
+        out = block(*(a[i] for a in xs))
+        return jax.lax.dynamic_update_index_in_dim(ctx, out, i, 0)
+
+    ctx = jax.lax.fori_loop(
+        0, n_live, step,
+        jnp.zeros((blocks, TQ, H, cfg.kv_lora_rank), jnp.float32))
     return ctx.reshape(T, H, cfg.kv_lora_rank)
 
 

@@ -647,6 +647,126 @@ def test_chunked_prefill_equals_whole_prefill_in_both_caches():
                                    np.asarray(whole[name]), atol=1e-5)
 
 
+# a chunk of four query blocks over a cached prefix that ends inside a block
+LIVE_TQ, LIVE_T, LIVE_START = 8, 32, 24
+
+
+@pytest.fixture(scope="module")
+def live_walk(chk):
+    """One compiled 32-row prefill chunk at a query block of 8 (four
+    blocks), ``true_len`` traced, over a 24-token cached prefix: the
+    product's walk (under the check's tap on ``_select``) and the walk over
+    all four blocks, the rule before PR 48, rebuilt from the same per-block
+    body by fixing the bound; and ``_sparse_chunk`` alone, both ways, on
+    random operands."""
+    # the programs of the tests above are unmapped here and those of this
+    # file at the end: the compiled code a worker's process holds is tens of
+    # thousands of memory mappings by now, of the 65,530 it may hold, and
+    # past them a later compile is a segmentation fault (tests/test_mla.py
+    # ``executables_dropped``)
+    jax.clear_caches()
+    cfg, params, kv, statics = _setup(_hf())
+    seq = _tokens(cfg, LIVE_START + LIVE_T, seed=11)
+    _, kv = _prefill(params, kv, statics, seq[:LIVE_START])
+    rng = np.random.default_rng(5)
+    H, NTOK = cfg.num_heads, NUM_BLOCKS * BS
+
+    def rand(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    operands = dict(
+        q_nope=rand(LIVE_T, H, cfg.qk_nope_head_dim),
+        q_pe=rand(LIVE_T, H, cfg.qk_rope_head_dim),
+        w_k=rand(H, cfg.kv_lora_rank, cfg.qk_nope_head_dim),
+        index=(rand(LIVE_T, cfg.index_n_heads, cfg.index_head_dim),
+               rand(LIVE_T, cfg.index_n_heads),
+               rand(NTOK, cfg.index_head_dim)),
+        kv_flat=rand(NTOK, cfg.kv_lora_rank + cfg.qk_rope_head_dim))
+
+    def forward(tokens, true_len):
+        with jax.default_matmul_precision("highest"):
+            return mla.prefill_forward(params, kv, tokens, TABLE,
+                                       jnp.asarray(LIVE_START), true_len,
+                                       statics)
+
+    def chunk(true_len):
+        return mla._sparse_chunk(
+            table_l=TABLE, positions=LIVE_START + jnp.arange(LIVE_T),
+            seq_len=LIVE_START + true_len, cfg=cfg, bsz=BS, scale=0.1,
+            **operands)
+
+    def all_blocks(T, true_len):
+        return LIVE_T // LIVE_TQ, LIVE_T // LIVE_TQ
+
+    full = jnp.asarray(LIVE_T)
+    with pytest.MonkeyPatch.context() as patch, chk.Tap(mla) as tap:
+        patch.setattr(mla, "DSA_QUERY_BLOCK", LIVE_TQ)
+        walks = {"forward": jax.jit(forward), "chunk": jax.jit(chunk)}
+        walks["forward"](jnp.zeros(LIVE_T, jnp.int32), full)  # traced here
+        walks["chunk"](full)
+        patch.setattr(mla, "sparse_query_blocks", all_blocks)
+        # (a second jit of one function would share the first's trace)
+        walks["forward_all"] = jax.jit(lambda *a: forward(*a))
+        walks["chunk_all"] = jax.jit(lambda n: chunk(n))
+        walks["forward_all"](jnp.zeros(LIVE_T, jnp.int32), full)
+        walks["chunk_all"](full)
+    # the compiled programs keep the tap's callback and the patched bound
+    tap.take(1)
+    yield cfg, seq, tap, walks
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("true_len", [
+    1, LIVE_TQ - 1, LIVE_TQ, LIVE_TQ + 1, LIVE_T - LIVE_TQ, LIVE_T - 1,
+    LIVE_T])
+def test_a_chunk_walks_its_live_query_blocks_only(live_walk, true_len):
+    """A partly filled chunk selects and reads for the query blocks that
+    hold a live row, and for no other: ``_select`` runs ceil(true_len / TQ)
+    times a layer; the last token's logits and the live rows of both caches
+    are bit-equal to the walk over every block; in ``_sparse_chunk``'s own
+    result the live rows are bit-equal too and the rows of the blocks that
+    did not run are exactly zero."""
+    cfg, seq, tap, walks = live_walk
+    n_run = -(-true_len // LIVE_TQ)
+    # the host's count, at the product's block of 32 and four times the rows
+    assert mla.sparse_query_blocks(4 * LIVE_T, 4 * true_len) == (4, n_run)
+    tokens = np.zeros(LIVE_T, np.int32)
+    tokens[:true_len] = seq[LIVE_START:LIVE_START + true_len]
+    logits, kv = walks["forward"](jnp.asarray(tokens),
+                                  jnp.asarray(true_len))
+    calls = tap.take(cfg.num_layers)       # [L, queries selected for, k]
+    assert calls.shape[1] == n_run * LIVE_TQ
+    want_logits, want_kv = walks["forward_all"](jnp.asarray(tokens),
+                                                jnp.asarray(true_len))
+    tap.take(cfg.num_layers)
+    np.testing.assert_array_equal(np.asarray(logits),
+                                  np.asarray(want_logits))
+    live = np.arange(LIVE_START + true_len)
+    rows = np.asarray(TABLE)[live // BS] * BS + live % BS
+    for name in ("kv", "idx"):
+        np.testing.assert_array_equal(np.asarray(kv[name])[:, rows],
+                                      np.asarray(want_kv[name])[:, rows])
+    ctx = np.asarray(walks["chunk"](jnp.asarray(true_len)))
+    want_ctx = np.asarray(walks["chunk_all"](jnp.asarray(true_len)))
+    tap.take(1)
+    np.testing.assert_array_equal(ctx[:true_len], want_ctx[:true_len])
+    assert ctx[:true_len].any(axis=(1, 2)).all()      # every live row ran
+    assert not ctx[n_run * LIVE_TQ:].any()
+    assert want_ctx[n_run * LIVE_TQ:].any() or n_run == LIVE_T // LIVE_TQ
+
+
+@pytest.mark.parametrize("T, true_len, want", [
+    (256, 160, (8, 5)), (256, 256, (8, 8)), (256, 1, (8, 1)),
+    (256, 0, (8, 0)), (256, 225, (8, 8)), (64, 33, (2, 2)),
+    (16, 9, (1, 1)), (48, 17, (3, 2))])
+def test_sparse_query_blocks_is_one_arithmetic_on_host_and_device(
+        T, true_len, want):
+    assert mla.sparse_query_blocks(T, true_len) == want
+    blocks, run = jax.jit(mla.sparse_query_blocks, static_argnums=0)(
+        T, jnp.asarray(true_len, jnp.int32))
+    assert (int(blocks), int(run)) == want
+
+
 def test_block_moves_carry_both_rows():
     """The defrag copy (block_copy.move_blocks) and the block gather /
     scatter move every array of the pool: latent rows and index keys."""
@@ -824,6 +944,45 @@ async def test_engine_serves_with_prefix_reuse_and_chunks(ref):
 
 
 @pytest.mark.asyncio
+async def test_prefill_records_count_the_query_blocks_that_ran():
+    """Every ``prefill`` flight record of a model with an indexer says how
+    many query blocks its chunks hold and how many the walk ran (a layer's):
+    a cold prompt in chunks, summed over them, then a hit whose suffix is
+    one partly filled bucket; the counts are the model's own arithmetic and
+    the benchmark's reader gives their share."""
+    from dynamo_tpu.engine.core import EngineCore
+    spec = importlib.util.spec_from_file_location(
+        "reader_dsa_prefill_blocks_run_pct", os.path.join(
+            BENCH, "layer_metrics", "dsa.prefill_blocks_run_pct.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    cfg = ModelConfig.from_hf_config(_hf())
+    core = EngineCore(cfg, _engine_cfg(max_model_len=256, prefill_chunk=64,
+                                       prefill_buckets=[64]),
+                      attn_impl="xla", param_dtype=jnp.float32)
+    cold = _tokens(cfg, 150, seed=7).tolist()
+    hit = cold[:144] + _tokens(cfg, 10, seed=8).tolist()
+    try:
+        await _serve(core, "a", cold, n=2)
+        _, _, req = await _serve(core, "b", hit, n=2)
+        assert req.prefix_hit_tokens == 144 and req.prefill_chunks == 1
+        a, b = [r for r in core.flight.dump() if r["kind"] == "prefill"]
+        # 64 + 64 + 22 rows: three chunks of two blocks, the last runs one
+        assert (a["dsa_blocks"], a["dsa_blocks_run"]) == (6, 5)
+        assert (b["dsa_blocks"], b["dsa_blocks_run"]) == (2, 1)
+        for rec, pieces in ((a, (64, 64, 22)), (b, (10,))):
+            counts = [mla.sparse_query_blocks(64, n) for n in pieces]
+            assert rec["dsa_blocks"] == sum(c[0] for c in counts)
+            assert rec["dsa_blocks_run"] == sum(c[1] for c in counts)
+        assert reader.read({"flight": [a, b]}) == pytest.approx(75.0)
+        assert reader.read({"flight": [dict(a, kind="decode")]}) is None
+        assert reader.read({"flight": [{"kind": "prefill",
+                                        "prompt": 9}]}) is None
+    finally:
+        await core.stop()
+
+
+@pytest.mark.asyncio
 async def test_flight_records_count_context_on_a_model_with_no_indexer():
     from dynamo_tpu.engine.core import EngineCore
     cfg = ModelConfig(vocab_size=256, hidden_size=64, intermediate_size=128,
@@ -837,6 +996,9 @@ async def test_flight_records_count_context_on_a_model_with_no_indexer():
         assert decode and all(r["sel_tokens"] == r["ctx_tokens"]
                               for r in decode)
         assert not any("key_waves" in r for r in decode)
+        prefill = [r for r in core.flight.dump() if r["kind"] == "prefill"]
+        assert prefill and not any(
+            "dsa_blocks" in r or "dsa_blocks_run" in r for r in prefill)
         # 30 prompt tokens: the first decode step reads a context of 31
         assert decode[0]["ctx_tokens"] == 31
     finally:
