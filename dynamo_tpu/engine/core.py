@@ -33,10 +33,10 @@ from ..llm.kv.pool import KvBlockManager
 from .block_copy import scatter_blocks_from_host
 from ..llm.kv_router.protocols import ForwardPassMetrics
 from ..llm.protocols.common import FinishReason
-from .attention import _on_tpu, wave_contig_table
+from .attention import wave_contig_table
 from .config import EngineConfig, ModelConfig
 from .index_scores import key_wave_blocks
-from .models import llama
+from .models import llama, module_for
 from .sampling import SlotSampling, make_slot_keys, sample_tokens
 
 logger = logging.getLogger("dynamo_tpu.engine")
@@ -249,77 +249,19 @@ class EngineCore:
             # meshes directly): re-run the config-level pp validation
             # against the REAL stage count, then the model-level checks
             dataclasses.replace(engine_cfg, pp=self.pp)  # raises on misuse
-            if model_cfg.kv_lora_rank > 0:
-                raise NotImplementedError(
-                    "pp with MLA latent-KV attention is not implemented "
-                    "(the latent pool has no per-stage form yet)")
-        # model-family dispatch: MLA (deepseek-class latent-KV attention)
-        # vs the llama family. The MLA integration is single-chip,
-        # full-precision first — each unsupported combination refuses
-        # loudly below rather than serving garbage.
+        # the module that serves this configuration says what it cannot
+        # run under: every unsupported combination refuses loudly HERE, at
+        # build, not by serving garbage (docs/dsa.md, docs/hybrid_cache.md)
+        self.model_mod = module_for(model_cfg)
+        refused = self.model_mod.refusals(model_cfg, engine_cfg, mesh)
+        if refused:
+            raise NotImplementedError(
+                f"{model_cfg.model_type} is not implemented with: "
+                + "; ".join(refused))
+        # the pool row's format (one opaque latent row or heads), and
+        # per-slot state behind the prefill table
         self.is_mla = model_cfg.kv_lora_rank > 0
-        # state-space, window and shared-cache layers in one model
-        # (phi4flash; models/sambay.py, docs/hybrid_cache.md)
         self.is_hybrid = model_cfg.is_sambay
-        if self.is_hybrid:
-            from .models import sambay
-            self.model_mod = sambay
-            refused = sambay.hybrid_refusals(model_cfg, engine_cfg, mesh)
-            if refused:
-                # every path that ships, quantises or re-runs cache rows
-                # either carries a slot's state and window rows or refuses
-                # HERE, at build (the matrix: docs/hybrid_cache.md)
-                raise NotImplementedError(
-                    "phi4flash (per-slot recurrent state and window rows) "
-                    "is not implemented with: " + "; ".join(refused))
-        elif self.is_mla:
-            from .models import mla
-            self.model_mod = mla
-            if engine_cfg.quantization.startswith("int4"):
-                # int8 works (quant.py _LAYER_MATMULS carries the MLA
-                # names; wkv_b deliberately stays full precision for the
-                # absorbed einsums); the grouped-int4 paths (Pallas
-                # kernel lane alignment, hybrid-scan slicing of packed
-                # rows) are unvalidated for this family
-                raise NotImplementedError(
-                    "MLA + int4 weight quantization is not integrated "
-                    "yet (int8 is)")
-            refused = mla.dsa_refusals(model_cfg, engine_cfg, mesh)
-            if refused:
-                # deepseek_v32: every path that ships or quantises cache
-                # rows either carries the index keys or refuses HERE, at
-                # build (the matrix: docs/dsa.md)
-                raise NotImplementedError(
-                    (f"{model_cfg.model_type} (window layers with a latent "
-                     "geometry and a block pool of their own)"
-                     if model_cfg.has_swa_latent else
-                     "deepseek_v32 sparse attention (index_topk > 0) / an "
-                     "expert share") + " is not implemented with: "
-                    + "; ".join(refused))
-        else:
-            self.model_mod = llama
-            if model_cfg.has_swa_gqa:
-                # mimo_v2: the grouped-query family with a second group of
-                # pool blocks for its window layers (models/mimo.py, which
-                # llama's entry points hand it to)
-                from .models import mimo
-                refused = mimo.refusals(model_cfg, engine_cfg, mesh)
-                if refused:
-                    raise NotImplementedError(
-                        "mimo_v2 (window layers with a grouped-query "
-                        "geometry and a block pool of their own) is not "
-                        "implemented with: " + "; ".join(refused))
-                if _on_tpu() and not mimo.decode_kernels_tile(
-                        model_cfg, engine_cfg.kv_block_size):
-                    # never silently: attn_impl "auto" would take the XLA
-                    # gather for a row width the kernel does not tile
-                    logger.warning(
-                        "mimo_v2: key/value rows of %s / %s lanes at "
-                        "--kv-block-size %d are off the Pallas decode "
-                        "kernel's tiles: both decode reads take the XLA "
-                        "gather", mimo.row_lanes(model_cfg),
-                        mimo.row_lanes(model_cfg.swa_gqa_geometry()),
-                        engine_cfg.kv_block_size)
         if (model_cfg.sliding_window is not None and not self.is_hybrid
                 and engine_cfg.max_model_len <= model_cfg.sliding_window):
             # the window can never bind at this serving length: drop it so
@@ -407,31 +349,12 @@ class EngineCore:
                     f"({model_cfg.num_kv_heads}) — each tp shard must "
                     f"own whole heads to carry its own in-row scale "
                     f"group")
-        if self.is_hybrid:
-            # three kinds: --num-kv-blocks sizes the paged pool,
-            # --max-num-seqs the window rings and the recurrent state
-            self.kv = self.model_mod.init_kv_cache(
-                model_cfg, engine_cfg.num_kv_blocks,
-                engine_cfg.kv_block_size, engine_cfg.max_num_seqs,
-                dtype=param_dtype)
-        else:
-            # window layers of a geometry of their own (dots3_note,
-            # mimo_v2): a second group of pool blocks, sized from the
-            # layout and the paged pool (no flag; docs/hybrid_cache.md)
-            cache_layout = self.model_mod.cache_layout(
-                model_cfg, engine_cfg.kv_block_size,
-                jnp.dtype(param_dtype).itemsize)
-            win_blocks = 0 if cache_layout is None else \
-                cache_layout.window_pool_blocks(
-                    engine_cfg.num_kv_blocks, engine_cfg.max_num_seqs,
-                    engine_cfg.prefill_chunk
-                    or max(engine_cfg.prefill_buckets))
-            self.kv = self.model_mod.init_kv_cache(
-                model_cfg, engine_cfg.num_kv_blocks,
-                engine_cfg.kv_block_size, dtype=param_dtype,
-                quantization=engine_cfg.kv_quantization,
-                **({"win_blocks": win_blocks} if win_blocks else {}),
-                **({} if self.is_mla else {"kv_shards": kv_shards}))
+        # the arrays the engine holds, the layout the block manager pages
+        # them by (None: paged rows only) and the window pool's blocks; a
+        # replay builds its fresh pool by the same call (replay.py)
+        self.fresh_kv = lambda: self.model_mod.engine_cache(
+            model_cfg, engine_cfg, param_dtype, kv_shards)
+        self.kv, layout, win_blocks = self.fresh_kv()
         if mesh is not None and self.pp > 1:
             # pp(×tp) placement: layer stacks + KV pool shard L over the
             # stage ring; embed/final_norm/lm_head replicate (the last
@@ -515,11 +438,7 @@ class EngineCore:
             on_stored=self._on_block_stored,
             on_removed=self._on_block_removed, host_pool=host_pool,
             disk_store=self.disk_store, remote_store=self.remote_store,
-            layout=(self.model_mod.cache_layout(
-                model_cfg, engine_cfg.kv_block_size,
-                jnp.dtype(param_dtype).itemsize)
-                if self.is_hybrid else cache_layout),
-            win_blocks=0 if self.is_hybrid else win_blocks)
+            layout=layout, win_blocks=win_blocks)
         if host_pool is not None:
             self.offload_engine = KvOffloadEngine(
                 host_pool, engine_cfg.kv_block_size,
@@ -529,27 +448,32 @@ class EngineCore:
                 on_store=self._emit_kv_store)
         self.M = engine_cfg.max_blocks_per_seq
         self.B = engine_cfg.max_num_seqs
-        # flight-record arithmetic of a hybrid cache: the window a window
-        # layer reads of a context, and the recurrent bytes one slot-step
-        # reads and writes (None / 0 on every other model)
-        layout = self.kv_manager.layout
         # window rows as blocks of a second pool (dots3_note): a decode
         # table carries their ring of R entries behind its M
         self.has_window_pool = self.kv_manager.win_pool is not None
         self.R = layout.ring_blocks if self.has_window_pool else 0
-        self._window = (model_cfg.sliding_window if self.is_hybrid
-                        else model_cfg.swa_window if self.has_window_pool
-                        else None)
+        # flight-record arithmetic of a cache with a layout: the window a
+        # window layer reads of a context, and the recurrent bytes one
+        # slot-step reads and writes (None / 0 without one)
+        self._window = layout.window if layout is not None else None
         self._step_state_bytes = (
             2 * layout.state_layers * layout.state_bytes
-            if self.is_hybrid else 0)
+            if layout is not None else 0)
+        # the groups this cache holds beside the paged rows, which neither
+        # disagg plane nor the KV fabric ships (submit, attach_kv_fabric;
+        # docs/dsa.md, docs/hybrid_cache.md); empty: paged rows only
+        self.beside_paged_rows = tuple(name for name, held in (
+            ("an index-key array", "idx" in self.kv),
+            ("per-slot state and window rings",
+             layout is not None and not layout.window_pool),
+            ("a window pool", self.has_window_pool)) if held)
         # blocks per wave of the index-key read (engine/index_scores.py),
         # for the decode records' key_waves / key_run_waves; 0 = no indexer
         self._key_wave_blocks = (
             key_wave_blocks(self.M, engine_cfg.kv_block_size,
                             model_cfg.index_head_dim,
                             self.kv["idx"].dtype.itemsize)
-            if model_cfg.index_topk > 0 else 0)
+            if "idx" in self.kv else 0)
         # jitted cross-quant repack converters, keyed by the payload's
         # (lane width, dtype); shapes re-specialize inside each jit cache
         self._repack_jits: dict = {}
@@ -1236,17 +1160,14 @@ class EngineCore:
 
     # ------------------------------------------------------------- frontend
     async def submit(self, req: EngineRequest) -> None:
-        if (self.model_cfg.index_topk > 0 or self.is_hybrid
-                or self.has_window_pool) and (
+        if self.beside_paged_rows and (
                 req.precomputed is not None or req.handoff is not None
                 or req.handoff_device):
-            # neither disagg plane ships the index keys (docs/dsa.md) or
-            # a slot's state and window rows (docs/hybrid_cache.md);
             # raised here, to the caller, not inside the engine loop
             raise NotImplementedError(
-                "disaggregated prefill/decode hand-off is not implemented "
-                "with deepseek_v32's index-key cache or phi4flash's "
-                "per-slot state")
+                "disaggregated prefill/decode hand-off ships paged rows "
+                "only; it is not implemented with "
+                + " and ".join(self.beside_paged_rows))
         if req.precomputed is not None:
             # validate the payload layout HERE, synchronously: the caller
             # gets the error; a raise inside the engine loop's admission
@@ -1943,12 +1864,10 @@ class EngineCore:
         construction (kv_remote_dir) may already have built an
         object-backed store — the fabric wraps that same store, so this
         is idempotent on the manager side."""
-        if (self.model_cfg.index_topk > 0 or self.is_hybrid
-                or self.has_window_pool):
+        if self.beside_paged_rows:
             raise NotImplementedError(
                 "the KV fabric ships paged rows only; it is not "
-                "implemented with deepseek_v32's index-key cache or "
-                "phi4flash's per-slot state")
+                "implemented with " + " and ".join(self.beside_paged_rows))
         self.kv_fabric = fabric
         self.remote_store = fabric.store
         self.kv_manager.remote_store = fabric.store
@@ -2371,7 +2290,8 @@ class EngineCore:
         # record's grouped_rows): the model's own chooser, asked per
         # dispatched program shape
         grouped_rows = 0
-        dsa_counts = {}
+        # the module's prefill_counters (none of precomputed rows)
+        counters = {}
         remote_admit = req.precomputed is not None
         if remote_admit:
             from ..llm.kv.stream import LayerStreamPayload
@@ -2435,10 +2355,10 @@ class EngineCore:
                     and len(chunk) > self.cfg.prefill_chunk):
                 tok, logprob = self._chunked_prefill(req, chunk, table, key,
                                                      slot=slot)
+                # the shape that was dispatched
+                bucket = self.cfg.prefill_chunk
                 grouped_rows = llama.grouped_prefill_rows(
-                    self.statics, self.cfg.prefill_chunk, len(chunk))
-                dsa_counts = self._dsa_block_counts(self.cfg.prefill_chunk,
-                                                    len(chunk))
+                    self.statics, bucket, len(chunk))
             else:
                 padded = np.zeros((bucket,), np.int32)
                 padded[:len(chunk)] = chunk
@@ -2460,8 +2380,9 @@ class EngineCore:
                     jnp.asarray(req.sampling.top_p, jnp.float32))
                 grouped_rows = llama.grouped_prefill_rows(
                     self.statics, bucket, len(chunk))
-                dsa_counts = self._dsa_block_counts(bucket, len(chunk))
                 self._window_after(req, n_prompt)
+            counters = self.model_mod.prefill_counters(
+                self.model_cfg, bucket, len(chunk), n_prompt)
             self.total_prefill_tokens += len(chunk)
             self.clock.admits += 1
             self.clock.admit_tokens += len(chunk)
@@ -2544,20 +2465,9 @@ class EngineCore:
             hit_tokens=req.prefix_hit_tokens,
             hit_cut_tokens=plan.hit_cut_tokens,
             precomputed=remote_admit, grouped_rows=grouped_rows,
-            # prompt tokens a state-space scan ran over (0 on a model
-            # without such layers)
-            scan_tokens=(suffix_len if self.is_hybrid and not remote_admit
-                         else 0),
-            # Σ over the prefilled rows of the keys each attended: a dense
-            # latent-attention prefill reads every earlier row (0 for the
-            # other families, and where an indexer selects the rows)
-            key_tokens=(suffix_len * (n_prompt - suffix_len)
-                        + suffix_len * (suffix_len + 1) // 2
-                        if self.is_mla and not self.model_cfg.index_topk
-                        and not remote_admit else 0),
-            # the query blocks of the sparse attention's walk, and those
-            # that held a live row and ran (a model with an indexer only)
-            **dsa_counts,
+            # the family's own keys (scan_tokens / key_tokens: 0 elsewhere;
+            # dsa_blocks, dsa_blocks_run: a model with an indexer only)
+            **{"scan_tokens": 0, "key_tokens": 0, **counters},
             host_ms=round(1e3 * (now - t0), 3),
             # of host_ms: plan to the prefill program's return (argument
             # build and transfers included), and the blocking fetch of
@@ -2624,7 +2534,7 @@ class EngineCore:
 
     def _prefill_table(self, blocks: list, slot: int) -> np.ndarray:
         """The block table a prefill dispatch takes: M entries, and for a
-        model with per-slot state (is_hybrid) the slot behind them; for one
+        model with per-slot state the slot behind them; for one
         with a window pool M more, the window block of every logical block
         (filled in before each dispatch: _window_before)."""
         table = np.zeros((self.M * (1 + int(self.has_window_pool))
@@ -2731,22 +2641,6 @@ class EngineCore:
             # let go here is rewritten only by a later dispatch
             self._window_after(req, off)
         return tok, logprob
-
-    def _dsa_block_counts(self, bucket: int, n: int) -> dict:
-        """{dsa_blocks, dsa_blocks_run} of a prefill of ``n`` prompt rows in
-        ``bucket``-row dispatches by a model with an indexer ({} otherwise):
-        the query blocks of the sparse attention's walk in one layer, summed
-        over the dispatches, and those of them that held a live row and ran
-        (the tail dispatch's padding runs none). The model's own arithmetic
-        (``mla.sparse_query_blocks``: what the program computes from
-        ``true_len``)."""
-        if not self.model_cfg.index_topk:
-            return {}
-        counts = [self.model_mod.sparse_query_blocks(bucket,
-                                                     min(bucket, n - lo))
-                  for lo in range(0, n, bucket)]
-        return {"dsa_blocks": sum(b for b, _ in counts),
-                "dsa_blocks_run": sum(r for _, r in counts)}
 
     def _complete_admissions(self) -> None:
         """Finish deferred admissions: the async device→host copies have

@@ -1323,3 +1323,43 @@ def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
     x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn,
                             experts_sharded=statics.sharded)
     return _logits(params, x, cfg), kv_new
+
+
+# The door (models.module_for; docs/architecture.md "What a model family
+# brings"). New functions go HERE, at the end: a Pallas kernel's serialized
+# body names the frames it was traced under, file and line, so a line added
+# above a call site on that stack (here; in core.py above its jits and their
+# call sites) re-keys every cached executable that holds the kernel.
+
+def refusals(cfg: ModelConfig, engine_cfg, mesh) -> list:
+    """What this engine asks for that the model does not run under yet, by
+    name; empty = go. Only mimo_v2's window group refuses."""
+    if cfg.has_swa_gqa:
+        from . import mimo
+        return mimo.refusals(cfg, engine_cfg, mesh)
+    return []
+
+
+def engine_cache(cfg: ModelConfig, engine_cfg, dtype, kv_shards: int = 1):
+    """-> (kv, layout, win_blocks): the arrays an engine of ``engine_cfg``
+    holds, the layout its block manager pages them by (None: one uniform
+    paged pool) and the blocks of the window layers' pool, sized from the
+    layout and the paged pool (no flag; docs/hybrid_cache.md). One body for
+    every family whose cache is pools of blocks: ``mla`` imports it."""
+    from . import module_for
+    family, e = module_for(cfg), engine_cfg
+    layout = family.cache_layout(cfg, e.kv_block_size,
+                                 jnp.dtype(dtype).itemsize)
+    win_blocks = 0 if layout is None else layout.window_pool_blocks(
+        e.num_kv_blocks, e.max_num_seqs,
+        e.prefill_chunk or max(e.prefill_buckets))
+    kv = family.init_kv_cache(
+        cfg, e.num_kv_blocks, e.kv_block_size, dtype=dtype,
+        quantization=e.kv_quantization, win_blocks=win_blocks,
+        kv_shards=kv_shards)
+    return kv, layout, win_blocks
+
+
+def prefill_counters(cfg: ModelConfig, bucket: int, rows: int,
+                     prompt_len: int) -> dict:
+    return {}   # no key of a ``prefill`` flight record is this family's own

@@ -59,22 +59,25 @@ kernels do not tile, the same reads in XLA.
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..attention import (ATTN_CHUNK_BLOCKS, NEG_INF, causal_attention,
-                         flash_prefill, flash_prefill_partial,
-                         flash_prefill_supported, kernel_wanted,
-                         paged_attention, pallas_supported)
+from ..attention import (ATTN_CHUNK_BLOCKS, NEG_INF, _on_tpu,
+                         causal_attention, flash_prefill,
+                         flash_prefill_partial, flash_prefill_supported,
+                         kernel_wanted, paged_attention, pallas_supported)
 from ..config import ModelConfig
 from ..quant import mm
 from .llama import (KVCache, ModelStatics, Params, _embed, _layer_stack,
                     _logits, apply_rope)
 from .mla import (_n_kind, _swa_ring_view, _swa_tables, layer_kinds,
                   stack_at, swa_ring_blocks, walk_layer_kinds)
+
+logger = logging.getLogger("dynamo_tpu.engine")
 
 # rows of the table a full layer's prefill chunk reads and attends at a
 # time (a whole number of the pool's blocks): bounds what one call of the
@@ -161,6 +164,13 @@ def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     n_f, n_s = _n_kind(kinds, "F"), _n_kind(kinds, "S")
     ck, cv = row_lanes(cfg)
     sk, sv = row_lanes(cfg.swa_gqa_geometry())
+    if _on_tpu() and not decode_kernels_tile(cfg, block_size):
+        # never silently: attn_impl "auto" would take the XLA gather for a
+        # row width the kernel does not tile
+        logger.warning(
+            "mimo_v2: key/value rows of %s / %s lanes at --kv-block-size %d "
+            "are off the Pallas decode kernel's tiles: both decode reads "
+            "take the XLA gather", (ck, cv), (sk, sv), block_size)
     ntok, wtok = num_blocks * block_size, (win_blocks
                                            or num_blocks) * block_size
     return {"k": jnp.zeros((n_f, ntok, ck), dtype),

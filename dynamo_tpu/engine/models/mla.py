@@ -105,7 +105,7 @@ and values of ``chunk + window`` rows once and attends by blocks of queries
 (``_swa_chunk``).
 
 What carries ``idx`` and what refuses is decided once, at engine build
-(``dsa_refusals``): ragged dispatch, speculative verify, sequence-parallel
+(``refusals``): ragged dispatch, speculative verify, sequence-parallel
 prefill, every mesh (tp/sp/pp/ep/dp), int8 KV pools, the host/disk/remote
 tiers, the KV fabric and both disagg planes refuse while ``index_topk > 0``.
 The multi-token-prediction layer is not served (weights.py skips it).
@@ -131,8 +131,8 @@ from ..mla_prefill import (K_TILE, Q_TILE, mla_prefill_block,
                            mla_prefill_supported)
 from ..quant import mm
 from ..select_compact import NOT_TAKEN, compact_top_k
-from .llama import (ModelStatics, _embed, _layer_stack, _logits,
-                    flat_token_indices, rms_norm, run_experts,
+from .llama import (ModelStatics, _embed, _layer_stack, _logits, engine_cache,
+                    flat_token_indices, init_one_param, rms_norm, run_experts,
                     split_expert_stacks, swiglu)
 
 Params = Dict[str, jax.Array]
@@ -382,7 +382,6 @@ def param_shapes(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
 
 def init_params(cfg: ModelConfig, key: jax.Array,
                 dtype=jnp.bfloat16) -> Params:
-    from .llama import init_one_param
     params: Params = {}
     for name, shape in param_shapes(cfg).items():
         key, sub = jax.random.split(key)
@@ -427,16 +426,16 @@ def cache_layout(cfg: ModelConfig, block_size: int, dtype_bytes: int = 2):
 def init_kv_cache(cfg: ModelConfig, num_blocks: int,
                   block_size: int, dtype=jnp.bfloat16,
                   quantization: str = "none",
-                  win_blocks: int = 0) -> KVCache:
+                  win_blocks: int = 0, kv_shards: int = 1) -> KVCache:
     """quantization="int8": the latent row quantizes with one in-row
     (e, m) scale pair PER c_kv/k_pe section
     (attention.quantize_kv_rows_sections — both pairs share one
     128-lane pad, and the row then PADS to a 128-lane multiple like
     the full-precision layout: e.g. 576+128 -> 768, wider than the
     unpadded llama encoding). Unlike llama pools there is never a
-    per-tp-shard section: the latent pool replicates under tp
-    (parallel/sharding.shard_kv), so every rank reads whole rows. Row
-    widths: latent_row_lanes."""
+    per-tp-shard section (``kv_shards`` is not read): the latent pool
+    replicates under tp (parallel/sharding.shard_kv), so every rank reads
+    whole rows. Row widths: latent_row_lanes."""
     if quantization not in ("none", "int8"):
         raise ValueError(f"unknown kv quantization {quantization!r} "
                          f"(none|int8)")
@@ -1269,13 +1268,23 @@ def _run_layers_mixed(params: Params, kv: KVCache, x: jax.Array,
 
 
 
-def dsa_refusals(cfg: ModelConfig, engine_cfg, mesh) -> list:
-    """What this engine asks for that cannot carry the index-key cache
-    (or the expert share) yet — the refusal matrix of docs/dsa.md, read
-    once at engine build. → the offending options, by name; empty = go."""
+def refusals(cfg: ModelConfig, engine_cfg, mesh) -> list:
+    """What this engine asks for that the latent pool, the index-key cache,
+    the expert share or the window layers' pool cannot carry yet: the
+    refusal matrix of docs/dsa.md, read once at engine build. → the
+    offending options, by name; empty = go."""
+    e = engine_cfg
     bad = []
+    if e.quantization.startswith("int4"):
+        # int8 works (quant.py _LAYER_MATMULS carries the MLA names; wkv_b
+        # deliberately stays full precision for the absorbed einsums)
+        bad.append("--quantization int4 (int8 is integrated; the "
+                   "grouped-int4 kernel's lane alignment and the hybrid "
+                   "scans' slicing of packed rows are unvalidated for this "
+                   "family)")
+    if mesh is not None and mesh.shape.get("pp", 1) > 1:
+        bad.append("a pp mesh (the latent pool has no per-stage form yet)")
     if cfg.index_topk > 0:
-        e = engine_cfg
         checks = {
             "--ragged (ragged_forward has no selection step)":
                 e.ragged_dispatch,
@@ -1297,7 +1306,6 @@ def dsa_refusals(cfg: ModelConfig, engine_cfg, mesh) -> list:
     if cfg.has_swa_latent:
         # dots3_note: what cannot carry the window layers' second pool and
         # table (one list; an indexer's refusals above are a part of it)
-        e = engine_cfg
         checks = {
             "--ragged (ragged_forward has no window layers)":
                 e.ragged_dispatch,
@@ -1852,3 +1860,25 @@ def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
         x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn,
                                 experts_sharded=statics.sharded)
     return _logits(params, x, cfg), kv_new
+
+
+# The door (models.module_for), with ``refusals`` above and llama's
+# ``engine_cache`` and ``init_one_param`` imported: new functions go HERE,
+# at the end (llama.py says why)
+
+def prefill_counters(cfg: ModelConfig, bucket: int, rows: int,
+                     prompt_len: int) -> dict:
+    """Of a prefill of ``rows`` prompt rows, the last of ``prompt_len``, in
+    ``bucket``-row dispatches. Without an indexer ``key_tokens``: Σ over
+    the rows of the keys each attended (a dense prefill reads every earlier
+    row). With one, the query blocks of the sparse attention's walk in one
+    layer over the dispatches (``dsa_blocks``) and those that held a live
+    row and ran (``dsa_blocks_run``): ``sparse_query_blocks``, what the
+    program computes from ``true_len``."""
+    if not cfg.index_topk:
+        return {"key_tokens": (rows * (prompt_len - rows)
+                               + rows * (rows + 1) // 2)}
+    counts = [sparse_query_blocks(bucket, min(bucket, rows - lo))
+              for lo in range(0, rows, bucket)]
+    return {"dsa_blocks": sum(b for b, _ in counts),
+            "dsa_blocks_run": sum(r for _, r in counts)}

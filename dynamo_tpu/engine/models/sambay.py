@@ -257,7 +257,7 @@ def cache_layout(cfg: ModelConfig, block_size: int, dtype_bytes: int = 2):
                      + dtype_bytes * (cfg.mamba_d_conv - 1) * Di))
 
 
-def hybrid_refusals(cfg: ModelConfig, engine_cfg, mesh) -> list:
+def refusals(cfg: ModelConfig, engine_cfg, mesh) -> list:
     """What this engine asks for that cannot carry a slot's recurrent state
     or its window rows yet: the refusal matrix of docs/hybrid_cache.md,
     read once at engine build. -> the offending options, by name."""
@@ -780,3 +780,24 @@ def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
                        v_flat, block_table[None, :], seq_len[None])
     kv_new = _cache_like(kv, k_flat, v_flat, wk, wv, ssm, conv)
     return _final(params, x[0], cfg), kv_new
+
+
+# The door (models.module_for), with ``refusals`` above: new functions go
+# HERE, at the end (llama.py says why)
+
+def engine_cache(cfg: ModelConfig, engine_cfg, dtype, kv_shards: int = 1):
+    """-> (kv, layout, win_blocks) as ``llama.engine_cache``:
+    ``--num-kv-blocks`` sizes the paged pool, ``--max-num-seqs`` the rings
+    and the recurrent state; no window-pool blocks (the window rows are
+    per-slot rings) and, every mesh refused, one shard."""
+    e = engine_cfg
+    kv = init_kv_cache(cfg, e.num_kv_blocks, e.kv_block_size,
+                       e.max_num_seqs, dtype=dtype)
+    return kv, cache_layout(cfg, e.kv_block_size,
+                            jnp.dtype(dtype).itemsize), 0
+
+
+def prefill_counters(cfg: ModelConfig, bucket: int, rows: int,
+                     prompt_len: int) -> dict:
+    """The prompt tokens a state-space scan ran over."""
+    return {"scan_tokens": rows}

@@ -431,21 +431,16 @@ def init_params_quantized(cfg, key: jax.Array, dtype=jnp.bfloat16,
     values equal quantize_params(init_params(...)) for the same seed, up
     to one-step int8 rounding ties (jit fusion may contract the
     round(w/scale) arithmetic differently than the eager two-pass)."""
-    from .models.llama import init_one_param, param_shapes
-    if cfg.kv_lora_rank > 0:
-        # MLA geometry: same init_one_param, different shape map
-        from .models.mla import param_shapes
-    elif cfg.is_sambay:
-        from .models.sambay import init_one_param, param_shapes
-
-    shapes = param_shapes(cfg)
+    from .models import module_for
+    family = module_for(cfg)
+    shapes = family.param_shapes(cfg)
     tied = "lm_head" not in shapes
     out: Dict[str, object] = {}
     for name, shape in shapes.items():
         key, sub = jax.random.split(key)
 
         def build(sub, name=name, shape=shape):
-            w = init_one_param(cfg, name, shape, sub, dtype)
+            w = family.init_one_param(cfg, name, shape, sub, dtype)
             return _quantize_named(name, w, include_embed, tied, bits)
 
         out.update(jax.jit(build)(sub))

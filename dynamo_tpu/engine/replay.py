@@ -350,18 +350,12 @@ def replay(core, events: List[dict], fingerprint: bool = False) -> dict:
     """
     import jax
 
-    from .models import llama
-
-    dtype = jax.tree_util.tree_leaves(core.params)[0].dtype
-    # the pool LAYOUT must match the recording core's (an int8-KV engine
-    # replayed against a bf16 pool would retrace the unquantized branch
-    # and report phantom divergence)
-    # ... and the model family's: an MLA core replays on a latent pool
-    # (with deepseek_v32's index-key cache beside it)
-    family = core.model_mod if getattr(core, "is_mla", False) else llama
-    kv = family.init_kv_cache(core.model_cfg, core.cfg.num_kv_blocks,
-                              core.cfg.kv_block_size, dtype=dtype,
-                              quantization=core.cfg.kv_quantization)
+    # a zeroed cache of the recording core's own making, array for array
+    # (an int8-KV engine replayed against a bf16 pool would retrace the
+    # unquantized branch and report phantom divergence; so would a family's
+    # index keys, rings, state or window pool, or a group sized otherwise
+    # than the engine sizes it)
+    kv = core.fresh_kv()[0]
     out = {"prefill": {}, "dispatch": {}, "verify": {}, "ragged": {},
            "fingerprints": []}
     disp_toks: Dict[int, object] = {}
@@ -426,10 +420,11 @@ def replay(core, events: List[dict], fingerprint: bool = False) -> dict:
                         "the record offloaded to a host tier but the "
                         "replaying core has host_kv_blocks=0 — replay "
                         "with the recorded engine config")
+                rows = next(iter(core.kv.values()))
                 mirror = make_host_pool(
                     core.cfg.host_kv_blocks, core.model_cfg, bs,
-                    core.cfg.kv_quantization,
-                    int(next(iter(core.kv.values())).shape[-1]), dtype)
+                    core.cfg.kv_quantization, int(rows.shape[-1]),
+                    rows.dtype)
             top = max(it[1] for it in ev["items"])
             if top >= core.cfg.host_kv_blocks:
                 raise NotImplementedError(

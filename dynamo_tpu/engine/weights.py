@@ -199,7 +199,7 @@ def load_params_auto(model_dir: str, cfg: Optional[ModelConfig] = None,
     cfg = cfg or ModelConfig.from_model_dir(model_dir)
     if cfg.is_sambay:
         # phi4flash: its own names and stacks; no mesh serves it yet
-        # (sambay.hybrid_refusals raises at engine build)
+        # (sambay.refusals: raised at engine build)
         return load_sambay_params(model_dir, cfg, dtype=dtype)
     if mesh is not None:
         return load_params_sharded(model_dir, mesh, cfg, dtype=dtype)
@@ -549,12 +549,9 @@ def load_params_sharded(model_dir: str, mesh,
         else:
             specs = param_pspecs(cfg)
         params: Dict[str, jax.Array] = {}
-        if cfg.kv_lora_rank > 0:
-            from .models.mla import param_shapes
-        else:
-            from .models.llama import param_shapes
+        from .models import module_for
         expert_naming = None
-        for pkey, shape in param_shapes(cfg).items():
+        for pkey, shape in module_for(cfg).param_shapes(cfg).items():
             spec = fit_or_replicate(pkey, shape, specs.get(pkey, P()),
                                     mesh, _np_dtype(dtype).itemsize)
             sharding = NamedSharding(mesh, spec)
