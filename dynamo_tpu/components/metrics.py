@@ -45,6 +45,7 @@ _SPEC_GAUGES = {
     "spec_accepted_per_step": "nv_llm_spec_accepted_per_step",
     "spec_drafted_total": "nv_llm_spec_drafted_tokens",
     "spec_accepted_total": "nv_llm_spec_accepted_tokens",
+    "spec_rewound_rows_total": "nv_llm_spec_rewound_rows_total",
 }
 
 # contiguity-aware KV layout (llm/kv/pool.py run-tracking allocator +

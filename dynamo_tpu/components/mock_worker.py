@@ -278,6 +278,9 @@ class MockTokenWorker:
             # — shaped exactly like a real EngineCore.metrics() payload
             d["spec_drafted_total"] = eng.spec_drafted
             d["spec_accepted_total"] = eng.spec_accepted
+            # every draft not accepted is a scored row rolled back
+            d["spec_rewound_rows_total"] = (eng.spec_drafted
+                                            - eng.spec_accepted)
             d["spec_acceptance_rate"] = eng.spec_accepted / eng.spec_drafted
             d["spec_accepted_per_step"] = (eng.spec_accepted
                                            / max(eng.spec_steps, 1))

@@ -60,7 +60,7 @@ def _rope_type(raw_rs: Dict[str, Any]) -> str:
 # any other that does, by name)
 MOE_FAMILIES = ("mixtral", "qwen2_moe", "qwen3_moe", "deepseek_v2",
                 "deepseek_v3", "deepseek_v32", "kimi_k2", "dots3_note",
-                "mimo_v2")
+                "mimo_v2", "exaone_moe")
 
 
 @dataclasses.dataclass
@@ -194,6 +194,17 @@ class ModelConfig:
     swa_sink: bool = False
     rotary_dim: int = 0
     value_scale: float = 1.0
+    # exaone_moe (models/mimo.py at ONE head geometry for both kinds,
+    # docs/hybrid_cache.md part four): a norm on each sub-layer's OUTPUT and
+    # none on its input (norm_on_output: ln1 / ln2 are those), no rope in
+    # the full layers (nope_full), and mtp_layers multi-token-prediction
+    # modules held beside the layers (enorm, hnorm, eh_proj, one decoder
+    # block of kind "F" with rows of its own in the paged group, its final
+    # norm; embedding and head the model's): a drafter that is part of the
+    # model (engine/core.py, docs/speculative.md). 0 = none is held
+    norm_on_output: bool = False
+    nope_full: bool = False
+    mtp_layers: int = 0
     mamba_d_state: int = 0
     mamba_d_conv: int = 0
     mamba_expand: int = 0
@@ -266,6 +277,8 @@ class ModelConfig:
             return cls._from_phi4flash(cfg)
         if mt == "mimo_v2":
             return cls._from_mimo_v2(cfg)
+        if mt == "exaone_moe":
+            return cls._from_exaone_moe(cfg)
         # a family with no branch here falls through to the llama block:
         # right for its many renamings, wrong for one whose layers keep a
         # recurrent state or come in kinds this parser does not know. It
@@ -840,6 +853,122 @@ class ModelConfig:
             swa_sink=bool(cfg.get("add_swa_attention_sink_bias")),
             rotary_dim=rot,
             value_scale=float(cfg.get("attention_value_scale") or 1.0))
+
+    @classmethod
+    def _from_exaone_moe(cls, cfg: Dict[str, Any]) -> "ModelConfig":
+        """K-EXAONE's published keys (models/mimo.py): window and full
+        grouped-query layers by ``layer_types``, dense and expert MLPs by
+        ``mlp_layer_types``, the windows by ``sliding_windows``, all lists;
+        ``mtp_layer_types`` / ``num_nextn_predict_layers`` say what
+        multi-token-prediction modules the checkpoint holds. Every size is
+        the file's, and what the program does not run is refused by name."""
+        need = ("hidden_size", "num_hidden_layers", "num_attention_heads",
+                "num_key_value_heads", "head_dim", "layer_types",
+                "mlp_layer_types", "sliding_window", "intermediate_size",
+                "moe_intermediate_size", "num_experts",
+                "num_experts_per_tok", "rope_parameters", "vocab_size")
+        missing = [k for k in need if cfg.get(k) is None]
+        if missing:
+            raise ValueError(f"exaone_moe needs {', '.join(missing)} in its "
+                             f"config (no family's class defaults are "
+                             f"this one's)")
+        n = int(cfg["num_hidden_layers"])
+        window = int(cfg["sliding_window"])
+        kinds = list(cfg["layer_types"])
+        mlps = list(cfg["mlp_layer_types"])
+        windows = list(cfg.get("sliding_windows") or [
+            window if t == "sliding_attention" else 0 for t in kinds])
+        problems = []
+        for key, lst in (("layer_types", kinds), ("mlp_layer_types", mlps),
+                         ("sliding_windows", windows)):
+            if len(lst) < n:
+                problems.append(f"{key} names {len(lst)} layers of "
+                                f"num_hidden_layers {n}")
+        # a cut depth keeps the leading entries
+        kinds, mlps, windows = kinds[:n], mlps[:n], windows[:n]
+        other = sorted(set(kinds) - {"sliding_attention", "full_attention"})
+        if other:
+            problems.append(f"layer_types of kind {', '.join(other)}")
+        if set(mlps) - {"dense", "sparse"}:
+            problems.append("mlp_layer_types other than dense / sparse")
+        if any(w != (window if t == "sliding_attention" else 0)
+               for t, w in zip(kinds, windows)):
+            problems.append("sliding_windows that differ from sliding_window "
+                            "on a sliding layer or from 0 on a full one")
+        dense = next((i for i, m in enumerate(mlps) if m == "sparse"),
+                     len(mlps))
+        if dense == 0 or "dense" in mlps[dense:]:
+            problems.append("mlp_layer_types without a leading dense layer, "
+                            "or with a dense layer behind a sparse one")
+        if "sliding_attention" not in kinds or window < 1:
+            problems.append("no sliding layer, or a window < 1 (that model "
+                            "is a plain grouped-query one)")
+        rp = cfg["rope_parameters"] or {}
+        if _rope_type(rp) != "default":
+            problems.append(f"rope_parameters of type {_rope_type(rp)!r}")
+        if cfg.get("scoring_func", "sigmoid") != "sigmoid":
+            problems.append("a scoring_func other than sigmoid")
+        if int(cfg.get("n_group") or 1) != 1 or int(
+                cfg.get("topk_group") or 1) != 1:
+            problems.append("n_group / topk_group other than 1")
+        if cfg.get("attention_bias"):
+            problems.append("attention_bias")
+        H, KVH = int(cfg["num_attention_heads"]), int(
+            cfg["num_key_value_heads"])
+        if KVH < 1 or H % KVH:
+            problems.append("query heads that the key/value heads do not "
+                            "divide")
+        mtp = int(cfg.get("num_nextn_predict_layers") or 0)
+        mtp_kinds = list(cfg.get("mtp_layer_types") or [])[:mtp]
+        if mtp > 1 or (mtp and mtp_kinds != ["full_attention"]) or any(
+                cfg.get("mtp_sliding_windows") or ()):
+            problems.append("more than one multi-token-prediction module, "
+                            "or one that is not of kind full_attention")
+        if problems:
+            raise ValueError("exaone_moe is not implemented with: "
+                             + "; ".join(problems))
+        n_experts = int(cfg["num_experts"])
+        n_total = int(cfg.get("num_experts_published") or 0)
+        share = int(cfg.get("expert_share_index") or 0)
+        if n_total and (n_total % n_experts
+                        or not 0 <= share < n_total // n_experts):
+            raise ValueError(
+                f"exaone_moe: num_experts {n_experts} is not a share of "
+                f"num_experts_published {n_total}, or expert_share_index "
+                f"{share} is outside it")
+        dk = int(cfg["head_dim"])
+        theta = float(rp.get("rope_theta", cfg.get("rope_theta") or 1e6))
+        return cls(
+            model_type="exaone_moe",
+            vocab_size=int(cfg["vocab_size"]),
+            hidden_size=int(cfg["hidden_size"]),
+            intermediate_size=int(cfg["moe_intermediate_size"]),
+            dense_intermediate_size=int(cfg["intermediate_size"]),
+            num_layers=n, num_heads=H, num_kv_heads=KVH, head_dim=dk,
+            v_head_dim=dk,
+            max_position_embeddings=int(
+                cfg.get("max_position_embeddings", 262144)),
+            rms_norm_eps=float(cfg.get("rms_norm_eps", 1e-5)),
+            rope_theta=theta,
+            tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+            hidden_act=str(cfg.get("hidden_act") or "silu"),
+            num_experts=n_experts,
+            num_experts_total=n_total if n_total != n_experts else 0,
+            expert_share_index=share,
+            num_experts_per_tok=int(cfg["num_experts_per_tok"]),
+            moe_norm_topk=bool(cfg.get("norm_topk_prob", True)),
+            moe_routing="sigmoid_noaux", n_group=1, topk_group=1,
+            routed_scaling=float(cfg.get("routed_scaling_factor") or 1.0),
+            shared_expert_size=int(cfg.get("num_shared_experts") or 0)
+            * int(cfg["moe_intermediate_size"]),
+            first_k_dense=dense, layer_types=kinds,
+            # ONE head geometry: the window layers' is the full layers'
+            swa_num_heads=H, swa_num_kv_heads=KVH, swa_head_dim=dk,
+            swa_v_head_dim=dk, swa_rope_theta=theta,
+            # counts the query's own position: 128 keys (assumed)
+            swa_window=window,
+            qk_norm=True, norm_on_output=True, nope_full=True,
+            mtp_layers=mtp)
 
     @classmethod
     def _from_phi4flash(cls, cfg: Dict[str, Any]) -> "ModelConfig":

@@ -123,6 +123,9 @@ class EngineRequest:
     # the window-pool blocks it holds (llm/kv/pool.py WindowBlocks; None on
     # a model whose window rows are not pool blocks)
     win: object = None
+    # a resident drafter's guess at the token after last_token (a device
+    # scalar while the admission's fetch is deferred; -1: none yet)
+    draft: object = -1
     emitted_total: int = 0        # tokens the client has seen (across lives)
     # lane-prefill mode (EngineConfig.lane_prefill_max_tokens): the FULL
     # prompt (incl. any prefix-hit tokens); while pos < len(lane_prompt)
@@ -216,6 +219,10 @@ class EngineCore:
     # builds the step functions only (benchmark/compile_check.py's, which
     # sets what _compile_jits read before the hybrid family came)
     is_hybrid = False
+    # the model's own multi-token-prediction module drafts (--spec-k on a
+    # model with ``mtp_layers``): the prefill program returns a draft and
+    # the decode step scores two rows a slot (docs/speculative.md)
+    resident_drafter = False
 
     def __init__(self, model_cfg: ModelConfig, engine_cfg: EngineConfig,
                  params: Optional[dict] = None, attn_impl: str = "auto",
@@ -253,6 +260,14 @@ class EngineCore:
         # run under: every unsupported combination refuses loudly HERE, at
         # build, not by serving garbage (docs/dsa.md, docs/hybrid_cache.md)
         self.model_mod = module_for(model_cfg)
+        if model_cfg.mtp_layers and engine_cfg.spec_k <= 0:
+            # the module is served as the model's drafter or not held
+            model_cfg = dataclasses.replace(model_cfg, mtp_layers=0)
+            self.model_cfg = model_cfg
+            if params is not None:
+                params = {k: w for k, w in params.items()
+                          if not k.startswith("mtp.")}
+        self.resident_drafter = model_cfg.mtp_layers > 0
         refused = self.model_mod.refusals(model_cfg, engine_cfg, mesh)
         if refused:
             raise NotImplementedError(
@@ -517,7 +532,7 @@ class EngineCore:
         # cfg.spec_k+1 rows and never widens at runtime)
         self.spec_k_live = engine_cfg.spec_k
         self.drafter = None
-        if engine_cfg.spec_k > 0:
+        if engine_cfg.spec_k > 0 and not self.resident_drafter:
             from .spec import PromptLookupDrafter
             self.drafter = PromptLookupDrafter(
                 max_ngram=engine_cfg.spec_ngram_max,
@@ -615,6 +630,7 @@ class EngineCore:
         self.spec_drafted_tokens = 0   # draft tokens scored
         self.spec_accepted_tokens = 0  # drafts that matched their sample
         self.spec_emitted_tokens = 0   # tokens emitted by verify steps
+        self.spec_rewound_rows = 0     # rows scored and rolled back
         # flight recorder (engine/flight_recorder.py): bounded ring of
         # per-dispatch records + loop-lag probe, dumpable via /debug and
         # llmctl trace dump; per-phase spans feed each request's trace.
@@ -707,6 +723,25 @@ class EngineCore:
                 logits[None, :], key[None], temperature[None], top_k[None],
                 top_p[None])
             return tok[0], logprob[0], kv
+
+        if self.resident_drafter:
+            def prefill(params, kv, tokens, block_table, start_pos,  # noqa: F811
+                        true_len, key, temperature, top_k, top_p, next_tok):
+                """The prefill program with the module's tail: next_tok is
+                the prompt's token after the chunk (< 0 after the last
+                chunk: the token sampled here) → (..., kv, the first
+                draft)."""
+                def sample(logits):
+                    tok, logprob = sample_tokens(
+                        logits[None, :], key[None], temperature[None],
+                        top_k[None], top_p[None])
+                    return tok[0], logprob[0]
+                tok, logprob, draft_logits, kv = \
+                    self.model_mod.prefill_forward_mtp(
+                        unpack_params(params), kv, tokens, block_table,
+                        start_pos, true_len, next_tok, statics, sample)
+                return tok, logprob, kv, jnp.argmax(
+                    draft_logits, axis=-1).astype(jnp.int32)
 
         # named scopes: stable names in the compiled programs and the
         # profiler's trace, whatever the compiler calls its fusions
@@ -871,28 +906,64 @@ class EngineCore:
         if self.cfg.spec_k > 0:
             Tv = self.cfg.spec_k + 1
 
-            def verify(params, kv, tokens, positions, block_tables,
-                       seeds, steps0, temperature, top_k, top_p):
-                params = unpack_params(params)
+            def per_row(tokens, positions, block_tables, seeds, steps0,
+                        temperature, top_k, top_p):
+                """A [B, Tv] step's arguments a row: → (tokens, positions,
+                tables, each row a sequence of its own; ``sample``: logits
+                [B·Tv, V] → (tokens, logprobs) under the lockstep keys)."""
                 B = tokens.shape[0]
                 t_off = jnp.arange(Tv, dtype=jnp.int32)
-                flat_tokens = tokens.reshape(B * Tv)
-                flat_pos = (positions[:, None] + t_off[None, :]).reshape(
-                    B * Tv)
-                flat_tables = jnp.repeat(block_tables, Tv, axis=0)
-                logits, kv = self.model_mod.decode_forward(
-                    params, kv, flat_tokens, flat_pos, flat_tables,
-                    statics)
                 keys = make_slot_keys(
                     seed, jnp.repeat(seeds, Tv),
                     (steps0[:, None]
-                     + t_off.astype(steps0.dtype)[None, :]).reshape(
-                         B * Tv))
-                toks, logprobs = sample_tokens(
-                    logits, keys, jnp.repeat(temperature, Tv),
-                    jnp.repeat(top_k, Tv), jnp.repeat(top_p, Tv))
+                     + t_off.astype(steps0.dtype)[None, :]).reshape(B * Tv))
+
+                def sample(logits):
+                    return sample_tokens(
+                        logits, keys, jnp.repeat(temperature, Tv),
+                        jnp.repeat(top_k, Tv), jnp.repeat(top_p, Tv))
+                return (tokens.reshape(B * Tv),
+                        (positions[:, None] + t_off[None, :]).reshape(B * Tv),
+                        jnp.repeat(block_tables, Tv, axis=0), sample)
+
+            def verify(params, kv, tokens, positions, block_tables,
+                       seeds, steps0, temperature, top_k, top_p):
+                B = tokens.shape[0]
+                flat_tokens, flat_pos, flat_tables, sample = per_row(
+                    tokens, positions, block_tables, seeds, steps0,
+                    temperature, top_k, top_p)
+                logits, kv = self.model_mod.decode_forward(
+                    unpack_params(params), kv, flat_tokens, flat_pos,
+                    flat_tables, statics)
+                toks, logprobs = sample(logits)
                 return (toks.reshape(B, Tv), logprobs.reshape(B, Tv),
                         kv)
+
+            if self.resident_drafter:
+                def decode_mtp(params, kv, tokens, positions, block_tables,
+                               seeds, steps0, temperature, top_k, top_p):
+                    """The two-row step of a resident drafter, in verify's
+                    shape: rows (last token, draft) of every slot at pos,
+                    pos + 1 through every layer and both pools, sampled
+                    with the lockstep keys; then the module over both rows
+                    with the sampled tokens → (tokens [B, 2], logprobs
+                    [B, 2], kv, drafts [B, 2]: the guess at the token after
+                    each row's sample; the loop keeps the accepted
+                    row's)."""
+                    B = tokens.shape[0]
+                    flat_tokens, flat_pos, flat_tables, sample = per_row(
+                        tokens, positions, block_tables, seeds, steps0,
+                        temperature, top_k, top_p)
+                    toks, logprobs, draft_logits, kv = \
+                        self.model_mod.decode_forward_mtp(
+                            unpack_params(params), kv, flat_tokens, flat_pos,
+                            flat_tables, statics, sample)
+                    drafts = jnp.argmax(draft_logits, axis=-1).astype(
+                        jnp.int32)
+                    return (toks.reshape(B, Tv), logprobs.reshape(B, Tv),
+                            kv, drafts.reshape(B, Tv))
+
+                verify = jax.named_scope("decode")(decode_mtp)
 
             self._verify_jit = jax.jit(verify, donate_argnums=(1,))
 
@@ -1414,6 +1485,7 @@ class EngineCore:
             gpu_prefix_cache_hit_rate=self.kv_manager.pool.hit_rate(),
             spec_drafted_total=self.spec_drafted_tokens,
             spec_accepted_total=self.spec_accepted_tokens,
+            spec_rewound_rows_total=self.spec_rewound_rows,
             spec_acceptance_rate=(
                 self.spec_accepted_tokens / self.spec_drafted_tokens
                 if self.spec_drafted_tokens else 0.0),
@@ -2369,7 +2441,7 @@ class EngineCore:
                         req, slot, padded, table,
                         start_pos=req.prefix_hit_tokens,
                         true_len=len(chunk))
-                tok, logprob, self.kv = self._prefill_jit(
+                tok, logprob, self.kv, *draft = self._prefill_jit(
                     self.params, self.kv, jnp.asarray(padded),
                     jnp.asarray(table),
                     jnp.asarray(req.prefix_hit_tokens, jnp.int32),
@@ -2377,7 +2449,9 @@ class EngineCore:
                     key,
                     jnp.asarray(req.sampling.temperature, jnp.float32),
                     jnp.asarray(req.sampling.top_k, jnp.int32),
-                    jnp.asarray(req.sampling.top_p, jnp.float32))
+                    jnp.asarray(req.sampling.top_p, jnp.float32),
+                    *self._next_tok(-1))
+                req.draft = draft[0] if draft else -1
                 grouped_rows = llama.grouped_prefill_rows(
                     self.statics, bucket, len(chunk))
                 self._window_after(req, n_prompt)
@@ -2423,6 +2497,7 @@ class EngineCore:
         req.dispatched_time = t_dispatched
         if not defer:
             req.last_token = int(tok)
+            req.draft = int(np.asarray(req.draft))
             self._mark_first_token(req)
             if self.recorder is not None:
                 self.recorder.rec("first_token", rid=req.rid,
@@ -2431,7 +2506,7 @@ class EngineCore:
         else:
             req.ready = False
             req.last_token = -1
-            for a in (tok, logprob):
+            for a in (tok, logprob, req.draft):
                 if hasattr(a, "copy_to_host_async"):
                     a.copy_to_host_async()
             self._admissions.append((req, tok, logprob))
@@ -2584,9 +2659,15 @@ class EngineCore:
         for i, bid in req.win.held.items():
             ring[i % self.R] = bid
 
+    def _next_tok(self, tok: int) -> tuple:
+        """The prefill program's last argument under a resident drafter
+        (the token after the chunk; -1: the one it samples); () without."""
+        return (jnp.asarray(tok, jnp.int32),) if self.resident_drafter else ()
+
     def _rec_prefill(self, req: "EngineRequest", slot: int,
                      padded: np.ndarray, table: np.ndarray, *,
-                     start_pos: int, true_len: int) -> int:
+                     start_pos: int, true_len: int,
+                     next_tok: int = -1) -> int:
         """Record one plain-prefill event (the ONE home of its field set —
         whole-prompt admissions and each chunk of a chunked admission both
         go through here). Returns the event's pf_seq."""
@@ -2597,7 +2678,8 @@ class EngineCore:
             start_pos=start_pos, true_len=true_len,
             samp_seed=req.sampling.seed, key_step=req.key_step,
             temp=req.sampling.temperature,
-            top_k=req.sampling.top_k, top_p=req.sampling.top_p)
+            top_k=req.sampling.top_k, top_p=req.sampling.top_p,
+            **({"next_tok": next_tok} if self.resident_drafter else {}))
         return pf
 
     def _chunked_prefill(self, req: EngineRequest, chunk: list,
@@ -2622,12 +2704,16 @@ class EngineCore:
             padded = np.zeros((C,), np.int32)
             padded[:len(piece)] = piece
             table = self._window_before(req, table, off, off + len(piece))
+            # a resident drafter's tail takes the token AFTER the chunk:
+            # the prompt's next one, after the last chunk the sampled one
+            nxt = chunk[lo + C] if lo + C < len(chunk) else -1
             if self.recorder is not None:
                 pf = self._rec_prefill(req, slot, padded, table,
-                                       start_pos=off, true_len=len(piece))
+                                       start_pos=off, true_len=len(piece),
+                                       next_tok=nxt)
                 if lo + C >= len(chunk):
                     req._pf_seq = pf      # final chunk samples the token
-            tok, logprob, self.kv = self._prefill_jit(
+            tok, logprob, self.kv, *draft = self._prefill_jit(
                 self.params, self.kv, jnp.asarray(padded),
                 jnp.asarray(table),
                 jnp.asarray(off, jnp.int32),
@@ -2635,7 +2721,9 @@ class EngineCore:
                 key,
                 jnp.asarray(req.sampling.temperature, jnp.float32),
                 jnp.asarray(req.sampling.top_k, jnp.int32),
-                jnp.asarray(req.sampling.top_p, jnp.float32))
+                jnp.asarray(req.sampling.top_p, jnp.float32),
+                *self._next_tok(nxt))
+            req.draft = draft[0] if draft else -1
             off += len(piece)
             # the device runs programs in dispatch order: a window block
             # let go here is rewritten only by a later dispatch
@@ -2654,6 +2742,7 @@ class EngineCore:
             with self.clock.phase("wait"):
                 tok = int(np.asarray(tok_dev))
                 logprob = float(np.asarray(logprob_dev))
+                req.draft = int(np.asarray(req.draft))
             req.last_token = tok
             self._mark_first_token(req)
             req.ready = True
@@ -2969,6 +3058,11 @@ class EngineCore:
             # every ready slot's work — pending prompt rows and due
             # decode rows together (docs/ragged_attention.md)
             self._ragged_step()
+            return
+        if self.resident_drafter:
+            # the model drafts for itself: every step is the two-row step,
+            # built from harvested state (nothing is ever in flight)
+            self._decode_step_mtp()
             return
         if self._verify_jit is not None and self._spec_candidates():
             # speculation drafts from HARVESTED state, so the in-flight
@@ -3884,6 +3978,95 @@ class EngineCore:
                      for s in self.slots]})
         return True
 
+    def _prepare_rows(self, rows: int) -> bool:
+        """Before a step that scores ``rows`` adjacent rows a slot (a
+        resident drafter's): the blocks of both groups that the rows at
+        pos .. pos + rows - 1 are written to. The window group lets go only
+        of what lies wholly behind the window of the query at ``pos``, the
+        ACCEPTED position: a rejected row is rewound and its block, if it
+        was a new one, is the next step's. → whether anything decodes."""
+        bs = self.cfg.kv_block_size
+        capacity = self.M * bs
+        for i, s in enumerate(self.slots):
+            if s is None or not s.ready:
+                continue
+            if s.pos + rows > capacity:
+                # no position left for the draft row (--max-model-len
+                # counts it: docs/speculative.md)
+                self._release_slot(s)
+                self._finish_request(s, FinishReason.LENGTH)
+                continue
+            need = self._blocks_needed(s.pos + rows)
+            if need > len(s.blocks):
+                new = self.kv_manager.pool.alloc_uninit(need - len(s.blocks))
+                if new is None:
+                    self._preempt_or_finish(s)
+                    continue
+                s.blocks.extend(new)
+                self._block_tables[i, :len(s.blocks)] = s.blocks
+            first = s.pos // bs
+            if any(b not in s.win.held for b in range(first, need)):
+                self.kv_manager.window_slide(s.win, s.pos)
+                if not self.kv_manager.window_grow(s.win, first, need):
+                    self._preempt_or_finish(s)
+                    continue
+                self._window_ring(i, s)
+        return any(s is not None and s.ready for s in self.slots)
+
+    def _decode_step_mtp(self) -> None:
+        """One step of a model that drafts for itself: rows (last token,
+        draft) of every ready slot in ONE dispatch of the two-row program
+        (``_verify_jit`` in its resident form), harvested at once with
+        lockstep acceptance. Every decoding slot has a draft (a prefill, a
+        hit's chunk and a preemption's re-prefill each return one): no slot
+        rides along as a one-row step. Recorded as this model's ``decode``
+        step."""
+        Tv = self.cfg.spec_k + 1
+        if not self._prepare_rows(Tv):
+            return
+        steps = np.zeros((self.B,), np.int64)
+        tokens = np.zeros((self.B, Tv), np.int32)
+        n_rows = np.zeros((self.B,), np.int32)
+        dmap: Dict[int, List[int]] = {}
+        riders = [s if (s is not None and s.ready) else None
+                  for s in self.slots]
+        for i, s in enumerate(riders):
+            if s is None:
+                self._positions[i] = 0
+                if self.slots[i] is None:
+                    self._block_tables[i, :] = 0  # trash block
+                continue
+            dmap[i] = [max(int(s.draft), 0)]
+            tokens[i] = [s.last_token] + dmap[i]
+            self._positions[i] = s.pos
+            steps[i] = s.key_step
+            n_rows[i] = Tv
+        tables = self._tables_for_dispatch()
+        self._step += 1
+        did = None
+        if self.recorder is not None:
+            did = self.recorder.next_dispatch_id()
+            self.recorder.rec(
+                "verify", id=did, Tv=Tv, tokens=tokens.copy(),
+                positions=self._positions.copy(), tables=tables.copy(),
+                seeds=self._seeds.copy(), steps=steps.copy(),
+                temperature=self._samp["temperature"].copy(),
+                top_k=self._samp["top_k"].copy(),
+                top_p=self._samp["top_p"].copy(), n_rows=n_rows.copy(),
+                reqs=[s.rid if s is not None else None for s in riders])
+        args = (jnp.asarray(tokens), _owned(self._positions),
+                _owned(tables), _owned(self._seeds), jnp.asarray(steps),
+                _owned(self._samp["temperature"]),
+                _owned(self._samp["top_k"]), _owned(self._samp["top_p"]))
+        self.clock.enter("dispatch")
+        toks_T, lps_T, self.kv, drafts = self._verify_jit(
+            self.params, self.kv, *args)
+        self.spec_dispatches += 1
+        self.spec_drafted_tokens += len(dmap)
+        self._harvest_verify({
+            "toks": toks_T, "logprobs": lps_T, "drafts": dmap, "id": did,
+            "next_drafts": drafts, "reqs": riders})
+
     def _harvest_verify(self, pending: dict) -> None:
         """Apply one verify dispatch: walk each slot's sampled rows with
         lockstep acceptance (spec/drafter.py accept_lockstep semantics,
@@ -3895,7 +4078,14 @@ class EngineCore:
         self.clock.enter("wait")
         toks_T = np.asarray(pending["toks"])       # [B, Tv] — ONE fetch
         lps_T = np.asarray(pending["logprobs"])
+        # a resident drafter's step: the guess behind each row's sample
+        next_drafts = pending.get("next_drafts")
+        if next_drafts is not None:
+            next_drafts = np.asarray(next_drafts)
         self.clock.enter("post")
+        capacity = self.M * self.cfg.kv_block_size
+        window = self._window
+        ctx_tokens = win_tokens = rows = 0
         applied = []
         for i, req in enumerate(pending["reqs"]):
             if req is None or self.slots[i] is not req:
@@ -3904,6 +4094,14 @@ class EngineCore:
             inputs = [req.last_token] + d
             n_applied = 0
             accepted = 0
+            # what the step's rows read of this slot, each cached row once:
+            # the context up to its last row, and of it the window's reach
+            # of every row
+            ctx = req.pos + len(inputs)
+            ctx_tokens += ctx
+            win_tokens += min(ctx, window + len(inputs) - 1) if window \
+                else ctx
+            rows += len(inputs)
             for t in range(len(inputs)):
                 if req.cancelled:
                     self._release_slot(req)
@@ -3918,6 +4116,10 @@ class EngineCore:
                         self.kv_manager.register_full_blocks(
                             req.blocks, req.seq, req.registered_blocks,
                             tenant=req.tenant or None)
+                    if self.has_window_pool:
+                        self.kv_manager.window_register(
+                            req.win, req.seq, req.blocks,
+                            len(req.seq.tokens))
                 req.pos += 1
                 req.key_step += 1
                 req.generated += 1
@@ -3928,22 +4130,47 @@ class EngineCore:
                 if t > 0:          # reaching row t>0 accepted draft t
                     self.spec_accepted_tokens += 1
                     accepted += 1
+                if next_drafts is not None:
+                    req.draft = int(next_drafts[i, t])
                 self._mark_first_token(req)
                 self._emit(req, tok, float(lps_T[i, t]))
-                self._maybe_finish_after_emit(req)
+                if next_drafts is not None and req.pos >= capacity:
+                    # the context is full (as the one-step path's harvest)
+                    self._release_slot(req)
+                    self._finish_request(req, FinishReason.LENGTH)
+                else:
+                    self._maybe_finish_after_emit(req)
                 if self.slots[i] is not req:
                     break          # finished: drop the overrun rows
                 if t + 1 < len(inputs) and tok != int(inputs[t + 1]):
                     break          # draft rejected: rewind-rollback
+            self.spec_rewound_rows += len(inputs) - n_applied
             applied.append((i, req.rid, n_applied, accepted))
         if self.recorder is not None and pending.get("id") is not None:
             self.recorder.rec("spec_harvest", id=pending["id"],
                               toks=toks_T.copy(), applied=applied)
+        emitted = sum(n for _i, _r, n, _a in applied)
+        accepted = sum(a for _i, _r, _n, a in applied)
+        if next_drafts is None:
+            self.flight.record_cycle(
+                "verify", batch_fill=len(applied), spec_k=self.cfg.spec_k,
+                emitted=emitted, accepted=accepted)
+            return
+        # a resident drafter's step IS this model's decode step: a
+        # ``decode`` record with the one-step path's fields (one dispatch,
+        # nothing chained: it is harvested at once) and, beside them, the
+        # rows it scored and the drafts it accepted. ctx_tokens /
+        # win_tokens count each cached row ONCE a slot, however many of the
+        # slot's rows read it
         self.flight.record_cycle(
-            "verify", batch_fill=len(applied),
-            spec_k=self.cfg.spec_k,
-            emitted=sum(n for _i, _r, n, _a in applied),
-            accepted=sum(a for _i, _r, _n, a in applied))
+            "decode", K=1, batch_fill=len(applied), chained=0,
+            planned_tokens=len(applied), emitted=emitted, rows=rows,
+            accepted=accepted, ctx_tokens=ctx_tokens, sel_tokens=ctx_tokens,
+            win_tokens=win_tokens,
+            win_blocks_live=max(
+                (len(r.win.held) for r in self.slots
+                 if r is not None and r.win is not None), default=0),
+            state_bytes=0)
 
     # ----------------------------------------------------------- preemption
     def _preempt_or_finish(self, req: EngineRequest) -> None:

@@ -160,22 +160,22 @@ def fuse_stacked_matmuls(params: dict, cfg: ModelConfig) -> dict:
     same transform. Biases (bq/bk/bv) stay separate: they add after the
     split, bit-identically. Grouped (int4) weights are left unfused —
     the Pallas grouped kernel serves them per-tensor."""
-    def cat(keys, new):
-        ws = [params.get(f"layers.{k}") for k in keys]
+    def cat(keys, new, stack="layers."):
+        ws = [params.get(stack + k) for k in keys]
         if any(w is None for w in ws):
             return
         if all(isinstance(w, QuantizedArray) for w in ws):
             if any(w.group or w.packed4 for w in ws):
                 return
-            params[f"layers.{new}"] = QuantizedArray(
+            params[stack + new] = QuantizedArray(
                 jnp.concatenate([w.q for w in ws], axis=-1),
                 jnp.concatenate([w.scale for w in ws], axis=-1))
         elif not any(isinstance(w, QuantizedArray) for w in ws):
-            params[f"layers.{new}"] = jnp.concatenate(ws, axis=-1)
+            params[stack + new] = jnp.concatenate(ws, axis=-1)
         else:
             return
         for k in keys:
-            del params[f"layers.{k}"]
+            del params[stack + k]
 
     cat(("wq", "wk", "wv"), "wqkv")
     # mimo_v2's window layers: the same three at their own geometry
@@ -187,6 +187,11 @@ def fuse_stacked_matmuls(params: dict, cfg: ModelConfig) -> dict:
     cat(("moe_gate", "moe_up"), "moe_gateup")
     cat(("sh_gate", "sh_up"), "sh_gateup")
     cat(("dense_gate", "dense_up"), "dense_gateup")
+    # exaone_moe's resident multi-token-prediction block (models/mimo.py
+    # mtp_shapes): a stack of one layer, fused the same way
+    cat(("wq", "wk", "wv"), "wqkv", "mtp.")
+    cat(("moe_gate", "moe_up"), "moe_gateup", "mtp.")
+    cat(("sh_gate", "sh_up"), "sh_gateup", "mtp.")
     return params
 
 
@@ -486,10 +491,22 @@ def init_one_param(cfg: ModelConfig, name: str, shape: tuple,
     """Initialize a single (stacked) parameter tensor; factored out of
     init_params so quant.init_params_quantized can build+quantize one
     tensor at a time without materializing the full bf16 tree."""
+    if cfg.norm_on_output and name.endswith("q_norm"):
+        # exaone_moe norms every query and key head, so wq and wk set no
+        # scale: a trained q_norm does (QK_NORM_SEEDED)
+        return jnp.full(shape, QK_NORM_SEEDED, dtype=dtype)
+    if cfg.norm_on_output and name.endswith(("ln1", "ln2")):
+        # the weight a normed sub-layer output joins the stream at
+        return jnp.full(shape, OUTPUT_NORM_SEEDED, dtype=dtype)
+    if name.endswith("hnorm"):
+        # the module's norm on the main model's (already normed) hidden
+        # state: at 1 it would change nothing, and say nothing of whether
+        # the module reads it
+        return jnp.full(shape, MTP_HNORM_SEEDED, dtype=dtype)
     if name.endswith(("ln1", "ln2", "ln1_post", "ln2_post",
                       "q_norm", "k_norm",
                       "kv_norm", "q_a_norm",
-                      "idx_k_norm_w")) or name == "final_norm":
+                      "idx_k_norm_w", "final_norm", "enorm", "hnorm")):
         if cfg.mla_lora_rescale and name.endswith("q_a_norm"):
             # dots3_note multiplies its normed LoRA latents by
             # sqrt(hidden / rank). The q latent's norm carries the inverse
@@ -597,18 +614,58 @@ SINK_SEEDED = (7.0, 1.0)
 ROUTER_BIAS_SEEDED = 0.1
 
 
+# Seeded weights of a model that norms each sub-layer's OUTPUT and every
+# query and key head (exaone_moe; ModelConfig.norm_on_output). No projection
+# sets a scale: every branch joins the stream at its output norm's weight,
+# and a score is the dot product of two normed heads over sqrt(head_dim).
+# * q_norm at QK_NORM_SEEDED: at 1 the scores are of unit variance, a softmax
+#   over 128 (or 7k) such keys is nearly flat, and a window, a rope or a norm
+#   left out moves the logits by less than the tolerance (GQA_MIXED_SEEDED's
+#   reasoning); at 2.6 a query's mass lies on a few keys, as in a trained
+#   model.
+# * the output norms (ln1 / ln2) at OUTPUT_NORM_SEEDED: at 1 every one of the
+#   2L branches is a voice as loud as the embedding, and what a sharp softmax
+#   or a flipped expert choice does to one branch's rounding is passed on
+#   whole, layer after layer: the bf16 program stood 0.18 off the float32
+#   reference on the chip's first probe and 0.2-0.5 on the CPU at a quarter
+#   of the widths, seed by seed. At 0.2 the stream carries the embedding and
+#   the branches are perturbations of it (SPARSE_SEEDED's reasoning): 0.02-0.08
+#   over nine seeds there, with the breakages that are not small by nature at
+#   0.34-1.5 (what it was measured against: PERF.md section 6, PR 50).
+# * the routed experts' down-projection at a quarter of fan_in^-0.5
+#   (SHARE_SEEDED's reasoning: only what a flipped choice moves is damped).
+#   The router's top-8 of 128 is a step, a bf16 stream flips the members
+#   nearest the threshold, and with a share held a flip is a held expert's
+#   output there or not; under an output norm the routed part is not a small
+#   addend but a part of a branch's DIRECTION. At fan_in^-0.5 the program read
+#   0.15 off the reference on one of five chip probes (0.02-0.05 on the
+#   others) and up to 0.18 on the CPU at a quarter of the widths (0.09-0.49
+#   with every expert held: the flips are the mechanism); at a quarter 0.03-
+#   0.06 over twelve seeds there. The shared expert, attention and the dense
+#   MLP keep fan_in^-0.5.
+# * the module's hnorm at MTP_HNORM_SEEDED: it norms a state that the model's
+#   final norm has normed already, so at 1 it changes nothing and says
+#   nothing of whether the module reads it.
+OUTPUT_NORMED_SEEDED = {"embed": 1.0, "moe_down": 0.25}
+QK_NORM_SEEDED = 2.6
+OUTPUT_NORM_SEEDED = 0.2
+MTP_HNORM_SEEDED = 0.5
+
+
 def seeded_std(cfg: ModelConfig, name: str, fan_in: int) -> float:
     """Standard deviation of a --random-weights matrix: fan_in^-0.5, but
-    see SPARSE_SEEDED, MIXED_SEEDED, GQA_MIXED_SEEDED and SHARE_SEEDED."""
+    see SPARSE_SEEDED, MIXED_SEEDED, GQA_MIXED_SEEDED, SHARE_SEEDED and
+    OUTPUT_NORMED_SEEDED."""
     std = fan_in ** -0.5
     rule = (MIXED_SEEDED if cfg.has_swa_latent
+            else OUTPUT_NORMED_SEEDED if cfg.norm_on_output
             else GQA_MIXED_SEEDED if cfg.has_swa_gqa
             else SPARSE_SEEDED if cfg.index_topk > 0
             else SHARE_SEEDED if cfg.num_experts_total > 0 else {})
     if name == "embed":
         return rule.get("embed", std)
     if cfg.has_swa_gqa and name.endswith(("wq", "wk")):
-        return std * rule[name[-2:]]
+        return std * rule.get(name[-2:], 1.0)
     for suffix in ("moe_down", "down", "swa_wo", "wo"):
         if name.endswith(suffix):
             return std * rule.get(suffix, 1.0)
@@ -1338,6 +1395,19 @@ def refusals(cfg: ModelConfig, engine_cfg, mesh) -> list:
         from . import mimo
         return mimo.refusals(cfg, engine_cfg, mesh)
     return []
+
+
+def prefill_forward_mtp(*args, **kw):
+    """``prefill_forward`` with the resident multi-token-prediction
+    module's tail (models/mimo.py; a model with ``mtp_layers`` only)."""
+    from . import mimo
+    return mimo.prefill_forward_mtp(*args, **kw)
+
+
+def decode_forward_mtp(*args, **kw):
+    """``decode_forward`` with the module's tail over the same rows."""
+    from . import mimo
+    return mimo.decode_forward_mtp(*args, **kw)
 
 
 def engine_cache(cfg: ModelConfig, engine_cfg, dtype, kv_shards: int = 1):

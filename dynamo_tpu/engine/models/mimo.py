@@ -58,6 +58,7 @@ kernels do not tile, the same reads in XLA.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import logging
 from typing import Dict, Tuple
@@ -73,7 +74,7 @@ from ..attention import (ATTN_CHUNK_BLOCKS, NEG_INF, _on_tpu,
 from ..config import ModelConfig
 from ..quant import mm
 from .llama import (KVCache, ModelStatics, Params, _embed, _layer_stack,
-                    _logits, apply_rope)
+                    _logits, apply_rope, rms_norm)
 from .mla import (_n_kind, _swa_ring_view, _swa_tables, layer_kinds,
                   stack_at, swa_ring_blocks, walk_layer_kinds)
 
@@ -92,10 +93,49 @@ def _attn_shapes(cfg: ModelConfig, n: int, prefix: str = "") -> Dict:
     """The attention leaves of ``n`` layers of one geometry."""
     D, H, KVH = cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads
     dk, dv = cfg.head_dim, cfg.v_head_dim
-    return {f"layers.{prefix}wq": (n, D, H * dk),
-            f"layers.{prefix}wk": (n, D, KVH * dk),
-            f"layers.{prefix}wv": (n, D, KVH * dv),
-            f"layers.{prefix}wo": (n, H * dv, D)}
+    shapes = {f"layers.{prefix}wq": (n, D, H * dk),
+              f"layers.{prefix}wk": (n, D, KVH * dk),
+              f"layers.{prefix}wv": (n, D, KVH * dv),
+              f"layers.{prefix}wo": (n, H * dv, D)}
+    if cfg.qk_norm:
+        # exaone_moe: RMSNorm over a head's lanes on every query and key
+        # head, before the rope
+        shapes[f"layers.{prefix}q_norm"] = (n, dk)
+        shapes[f"layers.{prefix}k_norm"] = (n, dk)
+    return shapes
+
+
+def _expert_shapes(cfg: ModelConfig, n: int) -> Dict:
+    """The leaves of ``n`` expert layers: the router at its published
+    width, the held experts, the shared expert where the model has one."""
+    D, E, F, R = (cfg.hidden_size, cfg.num_experts, cfg.intermediate_size,
+                  cfg.router_width)
+    shapes = {"layers.router": (n, D, R),
+              "layers.moe_gate": (n, E, D, F),
+              "layers.moe_up": (n, E, D, F),
+              "layers.moe_down": (n, E, F, D),
+              "layers.router_bias": (n, R)}
+    if cfg.shared_expert_size:
+        Fs = cfg.shared_expert_size
+        shapes.update({"layers.sh_gate": (n, D, Fs),
+                       "layers.sh_up": (n, D, Fs),
+                       "layers.sh_down": (n, Fs, D)})
+    return shapes
+
+
+def mtp_shapes(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
+    """The resident multi-token-prediction module's leaves (``mtp.<leaf>``,
+    every one with a leading axis of 1: quant.py and the walker read them as
+    a stack of one layer): the two input norms, ``eh_proj`` [2D, D], one
+    decoder block of kind "F" with an expert MLP, its final norm. Embedding
+    and head are the model's."""
+    D = cfg.hidden_size
+    block = {**_attn_shapes(cfg, 1), **_expert_shapes(cfg, 1),
+             "layers.ln1": (1, D), "layers.ln2": (1, D)}
+    return {"mtp.enorm": (1, D), "mtp.hnorm": (1, D),
+            "mtp.eh_proj": (1, 2 * D, D),
+            **{"mtp." + k.split(".", 1)[1]: v for k, v in block.items()},
+            "mtp.final_norm": (1, D)}
 
 
 def param_shapes(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
@@ -103,7 +143,6 @@ def param_shapes(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
     L, D = cfg.num_layers, cfg.hidden_size
     kinds = layer_kinds(cfg)
     k, Lm = cfg.first_k_dense, L - cfg.first_k_dense
-    E, F, R = cfg.num_experts, cfg.intermediate_size, cfg.router_width
     Fd = cfg.dense_intermediate_size
     cfg_s = cfg.swa_gqa_geometry()
     shapes = {
@@ -115,17 +154,17 @@ def param_shapes(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
         "layers.dense_gate": (k, D, Fd),
         "layers.dense_up": (k, D, Fd),
         "layers.dense_down": (k, Fd, D),
-        "layers.router": (Lm, D, R),
-        "layers.moe_gate": (Lm, E, D, F),
-        "layers.moe_up": (Lm, E, D, F),
-        "layers.moe_down": (Lm, E, F, D),
-        "layers.router_bias": (Lm, R),
+        **_expert_shapes(cfg, Lm),
         **_attn_shapes(cfg_s, _n_kind(kinds, "S"), "swa_"),
     }
     if cfg.swa_sink:
         shapes["layers.swa_sink"] = (_n_kind(kinds, "S"), cfg_s.num_heads)
     if not cfg.tie_word_embeddings:
         shapes["lm_head"] = (D, cfg.vocab_size)
+    if cfg.mtp_layers:
+        # LAST: the seeded weights' keys are split in this order, so a
+        # model served without its module draws the same main weights
+        shapes.update(mtp_shapes(cfg))
     return shapes
 
 
@@ -142,12 +181,16 @@ def cache_layout(cfg: ModelConfig, block_size: int, dtype_bytes: int = 2):
     from ...llm.kv.hybrid import HybridCacheLayout
     kinds = layer_kinds(cfg)
     n_f, n_s = _n_kind(kinds, "F"), _n_kind(kinds, "S")
+    # a resident multi-token-prediction block keeps rows of its own in the
+    # paged group, under the same block ids: one more full layer's a token
+    n_f += cfg.mtp_layers
     return HybridCacheLayout(
         block_size=block_size, row_bytes=sum(row_lanes(cfg)) * dtype_bytes,
         paged_layers=n_f, readers_of_paged=n_f,
         window_layers=n_s, window=cfg.swa_window,
         state_layers=0, state_bytes=0, window_pool=True,
-        window_row_bytes=sum(row_lanes(cfg.swa_gqa_geometry())) * dtype_bytes)
+        window_row_bytes=sum(row_lanes(cfg.swa_gqa_geometry())) * dtype_bytes,
+        rows_read_next_token=cfg.mtp_layers > 0)
 
 
 def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
@@ -173,6 +216,7 @@ def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
             "take the XLA gather", (ck, cv), (sk, sv), block_size)
     ntok, wtok = num_blocks * block_size, (win_blocks
                                            or num_blocks) * block_size
+    n_f += cfg.mtp_layers            # the module's rows: pool index n_F
     return {"k": jnp.zeros((n_f, ntok, ck), dtype),
             "v": jnp.zeros((n_f, ntok, cv), dtype),
             "win_k": jnp.zeros((n_s, wtok, sk), dtype),
@@ -185,7 +229,11 @@ def refusals(cfg: ModelConfig, engine_cfg, mesh) -> list:
     e = engine_cfg
     checks = {
         "--ragged (ragged_forward has no window layers)": e.ragged_dispatch,
-        "--spec-k (the verify program has no window layers)": e.spec_k > 0,
+        "--spec-k with the n-gram drafter (its verify program takes no "
+        "window blocks; a model with a resident multi-token-prediction "
+        "module drafts for itself)": e.spec_k > 0 and not cfg.mtp_layers,
+        "--spec-k > 1 (one multi-token-prediction module gives one draft a "
+        "step; it is not run recurrently for a second)": e.spec_k > 1,
         "--lane-prefill-max-tokens (a lane's rows take no window blocks)":
             e.lane_prefill_max_tokens > 0,
         "--decode-steps-per-dispatch > 1 (window blocks are taken and "
@@ -224,10 +272,13 @@ def _rope(x: jax.Array, positions: jax.Array, cfg: ModelConfig) -> jax.Array:
         [rot, x[..., r:]], axis=-1)
 
 
-def _qkv(lp, hn: jax.Array, positions: jax.Array, cfg: ModelConfig):
+def _qkv(lp, hn: jax.Array, positions: jax.Array, cfg: ModelConfig,
+         rope: bool = True):
     """→ (q [N, H, dk], k [N, KVH, dk], v [N, KVH, dv]) of one layer of
     ``cfg``'s geometry: projected (one fused matmul where
-    llama.fuse_stacked_matmuls made one), roped, the values scaled."""
+    llama.fuse_stacked_matmuls made one), every head normed where the model
+    norms them, roped (``rope``: exaone_moe's full layers carry no
+    position), the values scaled."""
     N = hn.shape[0]
     H, KVH, dk, dv = (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
                       cfg.v_head_dim)
@@ -237,8 +288,13 @@ def _qkv(lp, hn: jax.Array, positions: jax.Array, cfg: ModelConfig):
                    qkv[:, (H + KVH) * dk:])
     else:
         q, k, v = mm(hn, lp["wq"]), mm(hn, lp["wk"]), mm(hn, lp["wv"])
-    q = _rope(q.reshape(N, H, dk), positions, cfg)
-    k = _rope(k.reshape(N, KVH, dk), positions, cfg)
+    def heads(x, n, norm):
+        x = x.reshape(N, n, dk)
+        if cfg.qk_norm:
+            x = rms_norm(x, lp[norm], cfg.rms_norm_eps)
+        return _rope(x, positions, cfg) if rope else x
+
+    q, k = heads(q, H, "q_norm"), heads(k, KVH, "k_norm")
     v = v.reshape(N, KVH, dv)
     if cfg.value_scale != 1.0:
         v = (v.astype(jnp.float32) * cfg.value_scale).astype(v.dtype)
@@ -271,7 +327,8 @@ def _take_blocks(pool: jax.Array, ai, ids: jax.Array, bsz: int,
 
 
 def _full_chunk(q, k_pool, v_pool, ai, table, start_pos, seq_len,
-                cfg: ModelConfig, bsz: int, kernel) -> jax.Array:
+                cfg: ModelConfig, bsz: int, kernel,
+                name: str = "gqa_full_prefill") -> jax.Array:
     """Causal attention of the T queries of one prefill chunk (query t at
     position start_pos + t) over the live rows of its table (positions <
     seq_len), by key blocks of GQA_KEY_BLOCK rows with a running max and
@@ -298,7 +355,7 @@ def _full_chunk(q, k_pool, v_pool, ai, table, start_pos, seq_len,
         if kernel:
             acc_j, m_j, l_j = flash_prefill_partial(
                 q, ks, vs, scale=scale, start_pos=q_lo, seq_len=live,
-                interpret=(kernel == "interpret"), name="gqa_full_prefill")
+                interpret=(kernel == "interpret"), name=name)
         else:
             s = jnp.einsum("tkgd,skd->tkgs", q.reshape(T, KVH, g, dk), ks,
                            preferred_element_type=f32) * scale
@@ -353,21 +410,25 @@ def _window_chunk(q, k_pool, v_pool, ai, table, start_pos, seq_len,
 
 
 def _attend_fn(params: Params, cfg: ModelConfig, positions, slots, slots_s,
-               read_full, read_window):
+               read_full, read_window, paged_at: int = 0):
     """``walk_layer_kinds``' attention block for this model: project, write
     the layer's rows into its pool, read (``read_full(q, pools, ai)`` /
-    ``read_window(q, pools, ai, sink)`` -> [N, H·dv]) and project out."""
+    ``read_window(q, pools, ai, sink)`` -> [N, H·dv]) and project out.
+    ``paged_at``: where the full layers of ``params`` start in the paged
+    pool (the multi-token-prediction block's rows lie behind the model's)."""
     cfg_s = cfg.swa_gqa_geometry()
     stack = _layer_stack(params)
-    names = {"F": [n for n in ("wq", "wk", "wv", "wqkv", "wo")
-                   if n in stack],
+    names = {"F": [n for n in ("wq", "wk", "wv", "wqkv", "wo", "q_norm",
+                               "k_norm") if n in stack],
              "S": [n for n in stack if n.startswith("swa_")]}
 
     def attend(kind, hn, pools, ai):
         lp = stack_at({n: stack[n] for n in names[kind]}, ai)
         N = hn.shape[0]
         if kind == "F":
-            q, k, v = _qkv(lp, hn, positions, cfg)
+            q, k, v = _qkv(lp, hn, positions, cfg, rope=not cfg.nope_full)
+            if paged_at:
+                ai = ai + paged_at
             pools = dict(
                 pools,
                 k=pools["k"].at[ai, slots, :].set(
@@ -397,16 +458,12 @@ def _attend_fn(params: Params, cfg: ModelConfig, positions, slots, slots_s,
 # ---------------------------------------------------------------------------
 
 
-def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
-                    block_table: jax.Array, start_pos: jax.Array,
-                    true_len: jax.Array, statics: ModelStatics
-                    ) -> Tuple[jax.Array, KVCache]:
-    """tokens [T] (padded), block_table [M] or the engine's [2M] (the window
-    pool's block of every logical block behind the paged pool's:
-    mla._swa_tables) → (last-token logits [V], new kv). The chunk's rows
-    are scattered first; the full layers read the live rows of the table
-    back by key blocks, the window layers the chunk's and the window - 1
-    before them."""
+def _prefill_plan(tokens, block_table, start_pos, true_len,
+                  statics: ModelStatics):
+    """What a prefill chunk's attention blocks share → (positions, slots,
+    slots_s, read_full(name), read_window): where the chunk's rows go in the
+    two pools, and the two reads (``read_full(name)`` gives the full
+    layers' read under a Pallas ``name=`` of the caller's)."""
     cfg, bsz = statics.cfg, statics.block_size
     cfg_s = cfg.swa_gqa_geometry()
     T = tokens.shape[0]
@@ -428,10 +485,13 @@ def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
                                     cfg_s.head_dim, cfg_s.v_head_dim),
         "flash prefill")
 
-    def read_full(q, pools, ai):
-        with jax.named_scope("gqa_full_prefill_attention"):
-            return _full_chunk(q, pools["k"], pools["v"], ai, block_table,
-                               start_pos, seq_len, cfg, bsz, kernel)
+    def full_reader(name: str):
+        def read_full(q, pools, ai):
+            with jax.named_scope(name + "_attention"):
+                return _full_chunk(q, pools["k"], pools["v"], ai,
+                                   block_table, start_pos, seq_len, cfg, bsz,
+                                   kernel, name=name)
+        return read_full
 
     def read_window(q, pools, ai, sink):
         with jax.named_scope("gqa_window_prefill_attention"):
@@ -439,27 +499,51 @@ def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
                                  table_s, start_pos, seq_len, cfg_s,
                                  cfg.swa_window, bsz, sink, kernel)
 
+    return positions, slots, slots_s, full_reader, read_window
+
+
+def _prefill_hidden(params: Params, kv: KVCache, tokens, block_table,
+                    start_pos, true_len, statics: ModelStatics):
+    """→ (the chunk's final hidden states [T, D] as the head reads them, new
+    kv, the plan): the chunk's rows are scattered first; the full layers
+    read the live rows of the table back by key blocks, the window layers
+    the chunk's and the window - 1 before them."""
+    cfg = statics.cfg
+    plan = _prefill_plan(tokens, block_table, start_pos, true_len, statics)
+    positions, slots, slots_s, full_reader, read_window = plan
     x = _embed(params, tokens, cfg)
     x, kv_new = walk_layer_kinds(
         params, kv, x, cfg,
-        _attend_fn(params, cfg, positions, slots, slots_s, read_full,
-                   read_window),
+        _attend_fn(params, cfg, positions, slots, slots_s,
+                   full_reader("gqa_full_prefill"), read_window),
         experts_sharded=statics.sharded, valid_rows=true_len)
+    return x, kv_new, plan
+
+
+def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
+                    block_table: jax.Array, start_pos: jax.Array,
+                    true_len: jax.Array, statics: ModelStatics
+                    ) -> Tuple[jax.Array, KVCache]:
+    """tokens [T] (padded), block_table [M] or the engine's [2M] (the window
+    pool's block of every logical block behind the paged pool's:
+    mla._swa_tables) → (last-token logits [V], new kv)."""
+    x, kv_new, _plan = _prefill_hidden(params, kv, tokens, block_table,
+                                       start_pos, true_len, statics)
     last = x[jnp.maximum(true_len - 1, 0)]
-    return _logits(params, last, cfg), kv_new
+    return _logits(params, last, statics.cfg), kv_new
 
 
-def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
-                   positions: jax.Array, block_tables: jax.Array,
-                   statics: ModelStatics) -> Tuple[jax.Array, KVCache]:
-    """tokens [B], positions [B], block_tables [B, M] or the engine's
-    [B, M + R] (logical window block b at entry M + b % R) → (logits
-    [B, V], new kv). Both reads are ``attention.paged_attention``: the full
-    layers' over the whole table, the window layers' over a ring view of R
-    window-pool blocks with the window's lower bound and the sink."""
+def _decode_plan(kv: KVCache, positions, block_tables,
+                 statics: ModelStatics):
+    """What a decode step's attention blocks share → (slots, slots_s,
+    read_full(name), read_window). Both reads are
+    ``attention.paged_attention``: the full layers' over the whole table
+    (under a Pallas ``name=`` of the caller's), the window layers' over a
+    ring view of R window-pool blocks with the window's lower bound and the
+    sink."""
     cfg, bsz = statics.cfg, statics.block_size
     cfg_s = cfg.swa_gqa_geometry()
-    B = tokens.shape[0]
+    B = positions.shape[0]
     R = swa_ring_blocks(cfg, bsz)
     block_tables, ring = _swa_tables(block_tables, statics.table_blocks, R,
                                      doubled=False)
@@ -482,16 +566,18 @@ def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
             v_dim=c.v_head_dim, coalesce=statics.kv_coalesce,
             chunk_blocks=chunk_blocks, name=name))
 
-    full = reader(cfg, "gqa_full_read",
-                  max(ATTN_CHUNK_BLOCKS, GQA_WAVE_ROWS // bsz))
-    window = reader(cfg_s, "gqa_window_read", max(ATTN_CHUNK_BLOCKS, R))
+    def full_reader(name: str):
+        full = reader(cfg, name, max(ATTN_CHUNK_BLOCKS, GQA_WAVE_ROWS // bsz))
 
-    def read_full(q, pools, ai):
-        k, v = pools["k"], pools["v"]
-        with jax.named_scope("gqa_full_decode_attention"):
-            return full(q, k.reshape(-1, k.shape[2]),
-                        v.reshape(-1, v.shape[2]),
-                        block_tables + ai * num_blocks, seq_lens)
+        def read_full(q, pools, ai):
+            k, v = pools["k"], pools["v"]
+            with jax.named_scope("gqa_full_decode_attention"):
+                return full(q, k.reshape(-1, k.shape[2]),
+                            v.reshape(-1, v.shape[2]),
+                            block_tables + ai * num_blocks, seq_lens)
+        return read_full
+
+    window = reader(cfg_s, "gqa_window_read", max(ATTN_CHUNK_BLOCKS, R))
 
     def read_window(q, pools, ai, sink):
         k, v = pools["win_k"], pools["win_v"]
@@ -501,13 +587,129 @@ def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
                           view + ai * win_blocks, view_len, win_lo=view_lo,
                           sink=sink)
 
+    return slots, slots_s, full_reader, read_window
+
+
+def _decode_hidden(params: Params, kv: KVCache, tokens, positions,
+                   block_tables, statics: ModelStatics):
+    """→ (the rows' final hidden states [B, D], new kv, the plan)."""
+    cfg = statics.cfg
+    plan = _decode_plan(kv, positions, block_tables, statics)
+    slots, slots_s, full_reader, read_window = plan
     x = _embed(params, tokens, cfg)
     x, kv_new = walk_layer_kinds(
         params, kv, x, cfg,
-        _attend_fn(params, cfg, positions, slots, slots_s, read_full,
-                   read_window),
+        _attend_fn(params, cfg, positions, slots, slots_s,
+                   full_reader("gqa_full_read"), read_window),
         experts_sharded=statics.sharded)
-    return _logits(params, x, cfg), kv_new
+    return x, kv_new, plan
+
+
+def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
+                   positions: jax.Array, block_tables: jax.Array,
+                   statics: ModelStatics) -> Tuple[jax.Array, KVCache]:
+    """tokens [B], positions [B], block_tables [B, M] or the engine's
+    [B, M + R] (logical window block b at entry M + b % R) → (logits
+    [B, V], new kv)."""
+    x, kv_new, _plan = _decode_hidden(params, kv, tokens, positions,
+                                      block_tables, statics)
+    return _logits(params, x, statics.cfg), kv_new
+
+
+# ---------------------------------------------------------------------------
+# The multi-token-prediction module, resident (exaone_moe; docs/
+# speculative.md "A resident drafter")
+# ---------------------------------------------------------------------------
+
+
+def _mtp_block(params: Params, kv: KVCache, h, next_tokens, positions, slots,
+               read_full, statics: ModelStatics, valid_rows=None):
+    """The module over N rows: row i, at position p = positions[i], takes
+    the main model's last hidden state of that position as its head reads
+    it (h [N, D]) and the token at p + 1 (next_tokens [N]):
+
+        u  = W_eh · [ N_e(Emb(x_{p+1})) ; N_h(h_p) ]
+        u' = Block_F(u)    over the module's OWN rows 0..p (pool index n_F)
+        → N_mtp(u') [N, D]: what the model's head turns into the logits of
+          the token at p + 2
+
+    The block is one layer of the model's kind "F" with an expert MLP, read
+    from the ``mtp.<leaf>`` stacks through the model's own walker and
+    attention block (fields of the configuration, not a copy)."""
+    cfg = statics.cfg
+    mtp = {n[len("mtp."):]: w for n, w in params.items()
+           if n.startswith("mtp.")}
+    own = ("enorm", "hnorm", "eh_proj", "final_norm")
+    head = stack_at({n: mtp[n] for n in own}, 0)
+    sub = {"final_norm": head["final_norm"],
+           **{"layers." + n: w for n, w in mtp.items() if n not in own}}
+    cfg_m = dataclasses.replace(cfg, num_layers=1, first_k_dense=0,
+                                layer_types=["full_attention"], mtp_layers=0)
+    eps = cfg.rms_norm_eps
+    u = mm(jnp.concatenate(
+        [rms_norm(_embed(params, next_tokens, cfg), head["enorm"], eps),
+         rms_norm(h, head["hnorm"], eps)], axis=-1), head["eh_proj"])
+    n_f = _n_kind(layer_kinds(cfg), "F")
+    return walk_layer_kinds(
+        sub, kv, u, cfg_m,
+        _attend_fn(sub, cfg_m, positions, slots, None, read_full, None,
+                   paged_at=n_f),
+        experts_sharded=statics.sharded, valid_rows=valid_rows)
+
+
+def _draft_logits(params: Params, u: jax.Array, cfg: ModelConfig):
+    """The module's logits, through the model's head. A function of its own
+    so that a check can carry them out of the compiled programs
+    (benchmark/references/exaone_moe_check.py taps it): the served path
+    keeps their argmax only, and lockstep acceptance hides a wrong drafter
+    completely (it only slows)."""
+    return _logits(params, u, cfg)
+
+
+def prefill_forward_mtp(params: Params, kv: KVCache, tokens: jax.Array,
+                        block_table: jax.Array, start_pos: jax.Array,
+                        true_len: jax.Array, next_token: jax.Array,
+                        statics: ModelStatics, sample):
+    """``prefill_forward`` with the module's tail → (token, logprob, draft
+    logits [V], new kv). ``sample``: last-token logits [V] → (token,
+    logprob), the engine's. The tail runs the module over the chunk's rows
+    shifted one token: row p takes h_p and x_{p+1}, which for the chunk's
+    last row is ``next_token`` (the prompt's next one; < 0 after the last
+    chunk: the token just sampled). The draft logits are the last row's:
+    the module's guess at the token after the sampled one."""
+    cfg = statics.cfg
+    x, kv, plan = _prefill_hidden(params, kv, tokens, block_table, start_pos,
+                                  true_len, statics)
+    positions, slots, _slots_s, full_reader, _read_window = plan
+    last = jnp.maximum(true_len - 1, 0)
+    tok, logprob = sample(_logits(params, x[last], cfg))
+    nxt = jnp.roll(tokens, -1).at[last].set(
+        jnp.where(next_token >= 0, next_token, tok).astype(tokens.dtype))
+    u, kv = _mtp_block(params, kv, x, nxt, positions, slots,
+                       full_reader("mtp_full_prefill"), statics,
+                       valid_rows=true_len)
+    return tok, logprob, _draft_logits(params, u[last], cfg), kv
+
+
+def decode_forward_mtp(params: Params, kv: KVCache, tokens: jax.Array,
+                       positions: jax.Array, block_tables: jax.Array,
+                       statics: ModelStatics, sample):
+    """``decode_forward`` with the module's tail over the same rows →
+    (tokens [N], logprobs [N], draft logits [N, V], new kv). ``sample``:
+    logits [N, V] → (tokens, logprobs), the engine's. Row i scores the
+    input token at positions[i]; the module then takes that row's hidden
+    state and the token sampled from it, and its logits are the guess at
+    the token AFTER the sampled one. The rows of one slot of a two-row step
+    are adjacent positions under the same table: row 1 reads row 0's fresh
+    rows in both pools (written before any read), and the module's too."""
+    cfg = statics.cfg
+    x, kv, plan = _decode_hidden(params, kv, tokens, positions, block_tables,
+                                 statics)
+    slots, _slots_s, full_reader, _read_window = plan
+    toks, logprobs = sample(_logits(params, x, cfg))
+    u, kv = _mtp_block(params, kv, x, toks, positions, slots,
+                       full_reader("mtp_full_read"), statics)
+    return toks, logprobs, _draft_logits(params, u, cfg), kv
 
 
 def decode_kernels_tile(cfg: ModelConfig, block_size: int) -> bool:

@@ -1125,14 +1125,21 @@ def walk_layer_kinds(params: Params, kv: KVCache, x: jax.Array,
     dense_lp = {n[len("dense_"):]: stack[n] for n in stack
                 if n.startswith("dense_")}
 
-    def layer(h, pools, li, ai, kind):
-        """li: the layer; ai: its index among the layers of its kind."""
+    def layer(h, pools, li, ai, kind, mlp):
+        """li: the layer; ai: its index among the layers of its kind; mlp:
+        what the layer's second sub-layer computes of its input."""
         ln = stack_at({"ln1": stack["ln1"], "ln2": stack["ln2"]}, li)
+        if cfg.norm_on_output:
+            # exaone_moe: each sub-layer reads the stream as it is, and its
+            # OUTPUT is normed (ln1 / ln2) before it joins the stream
+            delta, pools = attend(kind, h, pools, ai)
+            h = h + rms_norm(delta, ln["ln1"], cfg.rms_norm_eps)
+            return h + rms_norm(mlp(h), ln["ln2"], cfg.rms_norm_eps), pools
         hn = rms_norm(h, ln["ln1"], cfg.rms_norm_eps)
         delta, pools = attend(kind, hn, pools, ai)
         h = h + delta
         hn2 = rms_norm(h, ln["ln2"], cfg.rms_norm_eps)
-        return h, pools, hn2
+        return h + mlp(hn2), pools
 
     def dense_mlp(hn2, li):
         lp = stack_at(dense_lp, li)
@@ -1145,21 +1152,20 @@ def walk_layer_kinds(params: Params, kv: KVCache, x: jax.Array,
                         layer=mi if whole else None)
 
     pools = dict(kv)
-    for li in range(k):                  # the dense prefix: full layers
-        x, pools, hn2 = layer(x, pools, li, li, "F")
-        x = x + dense_mlp(hn2, li)
+    for li in range(k):                  # the dense prefix, of either kind
+        x, pools = layer(x, pools, li, _n_kind(kinds[:li], kinds[li]),
+                         kinds[li], lambda hn2, li=li: dense_mlp(hn2, li))
     # a layer's index among its kind: those of its kind before the scan,
     # a period's worth for every period gone by, and its rank in the period
-    before = {"F": _n_kind(kinds[:k], "F"), "S": 0}
+    before = {kd: _n_kind(kinds[:k], kd) for kd in ("F", "S")}
     per = {"F": _n_kind(period, "F"), "S": _n_kind(period, "S")}
 
     def run(carry, li0, ai0, some_kinds):
         h, pools = carry
         seen = {"F": 0, "S": 0}
         for j, kind in enumerate(some_kinds):
-            h, pools, hn2 = layer(h, pools, li0 + j,
-                                  ai0[kind] + seen[kind], kind)
-            h = h + expert_mlp(hn2, li0 + j - k)
+            h, pools = layer(h, pools, li0 + j, ai0[kind] + seen[kind], kind,
+                             lambda hn2, j=j: expert_mlp(hn2, li0 + j - k))
             seen[kind] += 1
         return h, pools
 

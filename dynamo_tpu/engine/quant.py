@@ -329,6 +329,10 @@ _LAYER_MATMULS = ("wq", "wk", "wv", "wo", "gate", "up", "down",
                   # grouped-query projections at the second geometry
                   # (swa_sink, one float32 scalar a head, stays as it is)
                   "swa_wq", "swa_wk", "swa_wv",
+                  # exaone_moe's resident multi-token-prediction module
+                  # (mtp.<leaf>, a stack of one layer): eh_proj int8 like
+                  # the block's own projections; its norms stay as they are
+                  "eh_proj",
                   # phi4flash (models/sambay.py; names are
                   # layers.<kind>.<leaf>): the MLP, the state-space
                   # layer's four projections, the attention layers' qkv /
@@ -378,15 +382,17 @@ def _quantize_named(name: str, w: jax.Array, include_embed: bool,
                     tied: bool, bits: int = 8) -> Dict[str, object]:
     """The per-tensor dispatch shared by quantize_params (whole-tree,
     eager) and init_params_quantized (streaming, one jit per tensor)."""
-    # the leaf: "wq" of layers.wq, "ssm_in" of layers.mamba.ssm_in
-    suffix = name.rsplit(".", 1)[1] if name.startswith("layers.") else name
-    if name.startswith("layers.") and suffix in _LAYER_MATMULS:
+    # the leaf: "wq" of layers.wq, "ssm_in" of layers.mamba.ssm_in; the
+    # multi-token-prediction block's leaves are a stack of one layer
+    stacked = name.startswith(("layers.", "mtp."))
+    suffix = name.rsplit(".", 1)[1] if stacked else name
+    if stacked and suffix in _LAYER_MATMULS:
         if bits == 4:
             # stacked [L, D, F]: int4, scale [L, D/128, F]
             return {name: quantize_array_grouped(w, bits=4)}
         # stacked [L, D, F]: per (layer, out-channel) → scale [L, 1, F]
         return {name: quantize_array(w, keep_axes=(0, -1))}
-    if name.startswith("layers.") and suffix in _MOE_MATMULS:
+    if stacked and suffix in _MOE_MATMULS:
         # stacked [L, E, D, F]: per (layer, expert, out-channel)
         # → scale [L, E, 1, F], which broadcasts over the expert
         # einsums' batched-N axis after the per-layer slice.

@@ -94,8 +94,12 @@ def _exec_prefill(core, kv, ev: dict, sp: bool):
             jnp.asarray(ev["temp"], jnp.float32),
             jnp.asarray(ev["top_k"], jnp.int32),
             jnp.asarray(ev["top_p"], jnp.float32))
+    if "next_tok" in ev:
+        # a resident drafter's prefill: the token after the chunk goes in,
+        # the first draft comes out behind kv (engine/core.py)
+        tail += (jnp.asarray(ev["next_tok"], jnp.int32),)
     fn = core._prefill_sp_jit if sp else core._prefill_jit
-    tok, _lp, kv = fn(core.params, kv, *head, *pos, *tail)
+    tok, _lp, kv, *_draft = fn(core.params, kv, *head, *pos, *tail)
     return tok, kv
 
 
@@ -254,7 +258,8 @@ def exec_verify_event(core, kv, ev: dict):
             f"recorded verify dispatch has {np.asarray(ev['tokens']).shape[1]}"
             f" rows/slot but this core compiled spec_k={core.cfg.spec_k} — "
             f"replay with the recorded engine config")
-    toks, _lps, kv = core._verify_jit(
+    # a resident drafter's two-row step returns its drafts behind kv
+    toks, _lps, kv, *_drafts = core._verify_jit(
         core.params, kv, jnp.array(np.asarray(ev["tokens"])),
         jnp.array(ev["positions"]), jnp.array(ev["tables"]),
         jnp.array(ev["seeds"]), jnp.array(ev["steps"]),

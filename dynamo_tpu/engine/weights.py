@@ -84,7 +84,19 @@ _LAYER_MAP = {
 # and grouped-query (mimo_v2)
 _SWA_LEAVES = ("wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_norm", "wkv_b", "wo",
                "wg")
-_SWA_GQA_LEAVES = ("wq", "wk", "wv", "wo", "sink")
+_SWA_GQA_LEAVES = ("wq", "wk", "wv", "wo", "sink", "q_norm", "k_norm")
+
+# a resident multi-token-prediction module's own tensors under
+# model.layers.{L + m}. (DeepSeek-V3's layout, which published the key
+# num_nextn_predict_layers; this family's names are assumed): the two input
+# norms, the projection of their concatenation, the norm before the shared
+# head. Its copies of the embedding and the head are the model's and are
+# not read; everything else is a decoder block's
+_MTP_OWN = {"enorm.weight": ("enorm", False),
+            "hnorm.weight": ("hnorm", False),
+            "eh_proj.weight": ("eh_proj", True),
+            "shared_head.norm.weight": ("final_norm", False)}
+_MTP_SHARED = ("embed_tokens.weight", "shared_head.head.weight")
 
 
 def _swa_leaves(cfg: ModelConfig) -> tuple:
@@ -105,6 +117,12 @@ def _layer_map_for(cfg: ModelConfig) -> Dict[str, tuple]:
     """HF layer-tensor suffix → (stacked key, transpose) for this family.
     One home — the replicated and sharded loaders must agree."""
     layer_map = dict(_LAYER_MAP)
+    if cfg.norm_on_output:
+        # exaone_moe (exaone4's names): no input norms; ln1 / ln2 are the
+        # norms on the attention's and the MLP's OUTPUT
+        del layer_map["input_layernorm.weight"]
+        layer_map["post_attention_layernorm.weight"] = ("ln1", False)
+        layer_map["post_feedforward_layernorm.weight"] = ("ln2", False)
     if cfg.post_norms:
         # gemma2: "post_attention_layernorm" is a true post-attn norm (not
         # llama's pre-MLP norm) and the MLP has its own pre/post pair
@@ -326,6 +344,8 @@ def load_llama_params(model_dir: str, cfg: Optional[ModelConfig] = None,
     staging: Dict[str, list] = {}
     expert_staging: Dict[str, list] = {}   # key → [L][E] tensors
     singles: Dict[str, np.ndarray] = {}
+    mtp: Dict[str, np.ndarray] = {}        # a resident module's leaves
+    mtp_experts: Dict[str, list] = {}      # key → [E] tensors
     for name, tensor in _iter_safetensors(model_dir):
         if name == "model.embed_tokens.weight":
             singles["embed"] = tensor
@@ -336,6 +356,11 @@ def load_llama_params(model_dir: str, cfg: Optional[ModelConfig] = None,
         elif name.startswith("model.layers."):
             rest = name[len("model.layers."):]
             idx_str, sub = rest.split(".", 1)
+            if L <= int(idx_str) < L + cfg.mtp_layers:
+                # exaone_moe: the module is part of the served model
+                # (models/mimo.py mtp_shapes: a stack of one layer)
+                _stage_mtp(cfg, sub, tensor, layer_map, mtp, mtp_experts)
+                continue
             if int(idx_str) >= L:
                 if int(idx_str) < L + cfg.num_nextn_predict_layers:
                     # deepseek_v3 MTP heads live at model.layers.{L}+ —
@@ -424,10 +449,41 @@ def load_llama_params(model_dir: str, cfg: Optional[ModelConfig] = None,
             _track(np.stack([_track(np.stack(row, axis=0))
                              for row in rows], axis=0)),
             dtype=dtype)
+    for key, arr in mtp.items():
+        params[f"mtp.{key}"] = jnp.asarray(arr[None], dtype=dtype)
+    for key, row in mtp_experts.items():
+        if any(a is None for a in row):
+            raise ValueError(f"checkpoint missing experts of the multi-"
+                             f"token-prediction module for {key}")
+        params[f"mtp.{key}"] = jnp.asarray(
+            _track(np.stack(row, axis=0))[None], dtype=dtype)
     if "lm_head" not in params and not cfg.tie_word_embeddings:
         # some checkpoints tie implicitly by omitting lm_head
         cfg.tie_word_embeddings = True
     return params
+
+
+def _stage_mtp(cfg: ModelConfig, sub: str, tensor, layer_map: dict,
+               mtp: dict, mtp_experts: dict) -> None:
+    """One tensor of the resident multi-token-prediction module (``sub``:
+    its name under model.layers.{L}.) into ``mtp`` / ``mtp_experts``."""
+    E = cfg.num_experts
+    prefix = next((p for p in _EXPERT_PREFIXES if sub.startswith(p)), None)
+    if prefix is not None:
+        e_str, wname, _ = sub[len(prefix):].split(".", 2)
+        key = _EXPERT_MAP.get(wname)
+        e_local = int(e_str) - cfg.expert_share_index * E
+        if key is not None and (not cfg.num_experts_total
+                                or 0 <= e_local < E):
+            mtp_experts.setdefault(key, [None] * E)[e_local] = tensor.T
+        return
+    mapped = _MTP_OWN.get(sub) or (
+        None if sub in _MTP_SHARED else layer_map.get(sub))
+    if mapped is not None:
+        key, transpose = mapped
+        # a dense_* name is the main model's leading layer's: the block's
+        # MLP is read as an expert layer (assumed; models/mimo.py)
+        mtp[key] = tensor.T if transpose else tensor
 
 
 def load_params_sharded(model_dir: str, mesh,

@@ -67,6 +67,12 @@ class HybridCacheLayout:
     # one token's rows of one WINDOW layer, where they are of another width
     # than the paged layers' (0: row_bytes)
     window_row_bytes: int = 0
+    # a paged row of some layer is computed from the token AFTER its own (a
+    # resident multi-token-prediction block's row p takes x_{p+1}:
+    # models/mimo.py). A block's hash covers its own tokens, so the last
+    # row of a prefix hit was computed from its PRODUCER's next token: a hit
+    # is cut back by one block and that block is computed again
+    rows_read_next_token: bool = False
 
     @property
     def ring_blocks(self) -> int:

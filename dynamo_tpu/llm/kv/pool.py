@@ -693,6 +693,12 @@ class KvBlockManager:
             matchable = matchable[:-1]
         hit_blocks = (self.pool.match_prefix(matchable)
                       if self.enable_reuse else [])
+        if (hit_blocks and self.layout is not None
+                and self.layout.rows_read_next_token):
+            # the last matched block's last row was computed from another
+            # sequence's next token (hybrid.py): compute that block again
+            self.pool.release(hit_blocks[-1:])
+            hit_blocks = hit_blocks[:-1]
         win, hit_cut = None, 0
         if self.win_pool is not None:
             # the hit rule over both groups: keep the longest boundary

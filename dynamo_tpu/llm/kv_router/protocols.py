@@ -33,6 +33,9 @@ class ForwardPassMetrics:
     # (from_dict drops unknown keys, absent keys take these zeros)
     spec_drafted_total: int = 0
     spec_accepted_total: int = 0
+    # rows a verify step scored and rolled back (rejected drafts and what
+    # stood behind them; a resident drafter's every rejected second row)
+    spec_rewound_rows_total: int = 0
     spec_acceptance_rate: float = 0.0
     spec_accepted_per_step: float = 0.0
     # KV tier ladder (llm/kv/offload.py host tier + llm/kv/diskstore.py

@@ -1,5 +1,5 @@
 """The one door into a model family (``engine/models/__init__.py``
-``module_for``), held on the eight configurations of
+``module_for``), held on the nine configurations of
 ``benchmark/fixtures/tiny-*.json`` under the launcher's flags: the module
 each is served by, what that module refuses, the pool a replay builds
 against the engine's own, and the key sets of the ``prefill`` and ``decode``
@@ -39,6 +39,10 @@ FAMILIES = {
     "tiny-dense": (llama, {"k", "v"}, set(), set()),
     "tiny-qwen2moe": (llama, {"k", "v"}, set(), set()),
     "tiny-mimo-v2": (llama, {"k", "v", "win_k", "win_v"}, set(), set()),
+    # served with its multi-token-prediction module resident (--spec-k 1):
+    # its decode step scores two rows a slot and says so
+    "tiny-exaone-moe": (llama, {"k", "v", "win_k", "win_v"}, set(),
+                        {"rows", "accepted"}),
     "tiny-deepseek-v2": (mla, {"kv"}, set(), set()),
     "tiny-kimi-k2": (mla, {"kv"}, set(), set()),
     "tiny-deepseek-v32": (mla, {"kv", "idx"},

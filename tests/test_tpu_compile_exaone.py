@@ -1,0 +1,124 @@
+"""K-EXAONE's deviceless builds for a described ``v5e:2x2`` (the helpers and
+fixtures are ``tests/test_tpu_compile.py``'s): the two served programs of a
+resident multi-token-prediction drafter, whole, at the published widths, and
+the three decode reads of its two-row step. A file of its own so that
+``--dist loadfile`` can give its ~2 minutes to another worker than
+``test_tpu_compile.py``'s, the longest file of tier 1."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from test_tpu_compile import (A, _benchmark_hf, _compile,  # noqa: F401
+                              one_chip, topo)
+
+def _exaone_shell(monkeypatch):
+    """k-exaone-236b's engine as ``benchmark/compile_check.py`` builds one
+    (no weights, no pool: the attributes ``_compile_jits`` reads), with the
+    drafter resident as ``EngineCore.__init__`` sets it under the
+    deployment's ``--spec-k 1``, and both pools at the ENGINE's sizes."""
+    import json
+    from dynamo_tpu.engine.config import ModelConfig
+    from dynamo_tpu.engine.core import EngineCore
+    from dynamo_tpu.engine.models import llama, mimo
+    from dynamo_tpu.engine.quant import init_params_quantized
+    from dynamo_tpu.launch import run as launcher
+    for mod in (A, llama, mimo):
+        monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "k-exaone-236b.json")) as f:
+        flags = json.load(f)["deployment"]["flags"]
+    cfg = ModelConfig.from_hf_config(
+        _benchmark_hf("configs/k-exaone-236b.json"))
+    e = launcher.engine_config(launcher.build_parser().parse_args(
+        ["in=http", "out=jax", *flags]))
+    assert (cfg.mtp_layers, e.spec_k, e.prefill_chunk) == (1, 1, 512)
+    core = object.__new__(EngineCore)
+    core.cfg, core.mesh, core.pp = e, None, 1
+    core.model_mod, core.resident_drafter = llama, True
+    core.statics = llama.ModelStatics(
+        cfg=cfg, block_size=e.kv_block_size, attn_impl="pallas",
+        kv_coalesce=e.kv_contig_alloc, table_blocks=e.max_blocks_per_seq)
+    core._compile_jits()
+    layout = llama.cache_layout(cfg, e.kv_block_size)
+    win = layout.window_pool_blocks(e.num_kv_blocks, e.max_num_seqs,
+                                    e.prefill_chunk)
+    params = jax.eval_shape(lambda: llama.fuse_stacked_matmuls(
+        dict(init_params_quantized(cfg, jax.random.PRNGKey(0))), cfg))
+    kv = jax.eval_shape(lambda: llama.init_kv_cache(
+        cfg, e.num_kv_blocks, e.kv_block_size, win_blocks=win))
+    return cfg, e, core, layout, params, kv
+
+
+@pytest.mark.parametrize("program", ["prefill-512", "decode_mtp-B64"])
+def test_exaone_served_programs_build_at_the_published_sizes(
+        one_chip, monkeypatch, program):
+    """K-EXAONE's two served programs, whole, for the described chip: the
+    chunk prefill with the module's tail and the two-row step of 64 slots
+    (128 rows through 2 F + 6 S layers, both pools and the module's block),
+    int8 weights, 20,480 paged and 2,666 window blocks. Every read is its
+    Pallas kernel under its own name, the module's apart from the model's;
+    weights + pools fill 60% of the chip and the program fits beside them."""
+    cfg, e, core, layout, params, kv = _exaone_shell(monkeypatch)
+    place = lambda t: jax.tree.map(lambda x: jax.ShapeDtypeStruct(  # noqa: E731
+        x.shape, x.dtype, sharding=one_chip), t)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    i32, f32, i64 = jnp.int32, jnp.float32, jnp.int64
+    M, B, R = e.max_blocks_per_seq, e.max_num_seqs, layout.ring_blocks
+    assert (M, B, R, kv["k"].shape[0], kv["win_k"].shape[0]) == (
+        449, 64, 9, 3, 6)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    key = s(key.shape, key.dtype)
+    if program == "prefill-512":
+        compiled = core._prefill_jit.lower(
+            place(params), place(kv), s((512,), i32), s((2 * M,), i32),
+            s((), i32), s((), i32), key, s((), f32), s((), i32), s((), f32),
+            s((), i32)).compile()
+        names = ("gqa_full_prefill", "gqa_window_prefill",
+                 "mtp_full_prefill")
+    else:
+        compiled = core._verify_jit.lower(
+            place(params), place(kv), s((B, 2), i32), s((B,), i32),
+            s((B, M + R), i32), s((B,), i64), s((B,), i64), s((B,), f32),
+            s((B,), i32), s((B,), f32)).compile()
+        names = ("gqa_full_read", "gqa_window_read", "mtp_full_read")
+    text = compiled.as_text()
+    assert all(n in text for n in names), [n for n in names if n not in text]
+    m = compiled.memory_analysis()
+    held = m.argument_size_in_bytes
+    limit = 16 * 2 ** 30
+    assert 0.60 * limit < held and held + m.temp_size_in_bytes < 0.9 * limit
+
+
+@pytest.mark.parametrize("read", ["gqa_full_read", "mtp_full_read",
+                                  "gqa_window_read"])
+def test_exaone_two_row_reads_build_at_the_published_sizes(one_chip, read):
+    """The three decode reads of the two-row step as the cell serves them:
+    128 rows (two a slot, each a sequence of its own to the kernel), 64
+    query heads over 8 kv heads of 128 lanes, rows of 1,024 | 1,024 lanes.
+    Full and the module's: tables of 449 blocks into the three-layer paged
+    pool of 20,480; window: a ring of 9 blocks of the six-layer pool of
+    2,666 with a lower bound, no sink."""
+    from dynamo_tpu.engine.models import mimo
+    rows, bs, H, KVH, d = 128, 16, 64, 8, 128
+    window = read == "gqa_window_read"
+    M, layers, blocks, chunk = ((9, 6, 2666, 9) if window else
+                                (449, 3, 20480, mimo.GQA_WAVE_ROWS // bs))
+
+    def fn(q, k, v, tables, lens, lo):
+        return A.paged_attention(
+            q, k, v, tables, lens, block_size=bs, scale=d ** -0.5,
+            impl="pallas", kv_heads=KVH, v_dim=d, chunk_blocks=chunk,
+            name=read, **({"win_lo": lo} if window else {}))
+
+    text = _compile(fn, one_chip, ((rows, H, d), jnp.bfloat16),
+                    ((layers * blocks * bs, KVH * d), jnp.bfloat16),
+                    ((layers * blocks * bs, KVH * d), jnp.bfloat16),
+                    ((rows, M), jnp.int32), ((rows,), jnp.int32),
+                    ((rows,), jnp.int32)).as_text()
+    assert read in text
+    assert f"bf16[{rows},{M * bs}," not in text        # no gathered table
