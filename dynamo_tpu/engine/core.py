@@ -941,16 +941,35 @@ class EngineCore:
 
             if self.resident_drafter:
                 def decode_mtp(params, kv, tokens, positions, block_tables,
-                               seeds, steps0, temperature, top_k, top_p):
+                               seeds, steps0, temperature, top_k, top_p,
+                               carry=None, mask=None):
                     """The two-row step of a resident drafter, in verify's
                     shape: rows (last token, draft) of every slot at pos,
                     pos + 1 through every layer and both pools, sampled
                     with the lockstep keys; then the module over both rows
                     with the sampled tokens → (tokens [B, 2], logprobs
                     [B, 2], kv, drafts [B, 2]: the guess at the token after
-                    each row's sample; the loop keeps the accepted
-                    row's)."""
+                    each row's sample).
+
+                    The loop's form takes the ``carry`` of the dispatch
+                    before it and a per-slot ``mask``, and returns its own
+                    behind the drafts: (adv [B]: 2 where row 0's sample is
+                    the draft that row 1 scored, else 1; the accepted
+                    row's sample; the draft behind it). A masked slot's rows
+                    are the carry's pair, adv positions and key steps beyond
+                    the host's; any other slot's are the host's. Acceptance
+                    and rewind are decided here, so a step can be queued
+                    behind one whose tokens no one has fetched. A caller
+                    that drives one step at a time passes neither."""
                     B = tokens.shape[0]
+                    if carry is not None:
+                        adv, last, draft = carry
+                        ahead = jnp.where(mask, adv, 0)
+                        tokens = jnp.where(mask[:, None],
+                                           jnp.stack([last, draft], axis=1),
+                                           tokens)
+                        positions = positions + ahead.astype(positions.dtype)
+                        steps0 = steps0 + ahead.astype(steps0.dtype)
                     flat_tokens, flat_pos, flat_tables, sample = per_row(
                         tokens, positions, block_tables, seeds, steps0,
                         temperature, top_k, top_p)
@@ -958,12 +977,26 @@ class EngineCore:
                         self.model_mod.decode_forward_mtp(
                             unpack_params(params), kv, flat_tokens, flat_pos,
                             flat_tables, statics, sample)
+                    toks = toks.reshape(B, Tv)
                     drafts = jnp.argmax(draft_logits, axis=-1).astype(
-                        jnp.int32)
-                    return (toks.reshape(B, Tv), logprobs.reshape(B, Tv),
-                            kv, drafts.reshape(B, Tv))
+                        jnp.int32).reshape(B, Tv)
+                    out = (toks, logprobs.reshape(B, Tv), kv, drafts)
+                    if carry is None:
+                        return out
+                    took = (toks[:, 0] == tokens[:, 1]).astype(jnp.int32)
+                    return (*out, (1 + took,
+                                   jnp.take_along_axis(
+                                       toks, took[:, None], axis=1)[:, 0],
+                                   jnp.take_along_axis(
+                                       drafts, took[:, None], axis=1)[:, 0]))
 
                 verify = jax.named_scope("decode")(decode_mtp)
+                # a fresh step's carry: nothing chained (cached on the
+                # device, as _planned_zero)
+                slots = (self.cfg.max_num_seqs,)
+                self._carry_zero = (
+                    tuple(jnp.zeros(slots, jnp.int32) for _ in range(3)),
+                    jnp.zeros(slots, bool))
 
             self._verify_jit = jax.jit(verify, donate_argnums=(1,))
 
@@ -3053,18 +3086,19 @@ class EngineCore:
 
     # --------------------------------------------------------------- decode
     def _decode_step(self) -> None:
+        """One decode step of whatever is ready, by the path this engine
+        was built for: the ragged program; the n-gram drafter's verify step
+        (drafted on the host from harvested state, harvested at once); else
+        the default path, which keeps one step in flight and queues the
+        next behind it: one row a slot, or the two rows of a model that
+        drafts for itself (``_step_path``)."""
         if self._ragged_jit is not None:
             # ragged serving: ONE dispatch per loop iteration carries
             # every ready slot's work — pending prompt rows and due
             # decode rows together (docs/ragged_attention.md)
             self._ragged_step()
             return
-        if self.resident_drafter:
-            # the model drafts for itself: every step is the two-row step,
-            # built from harvested state (nothing is ever in flight)
-            self._decode_step_mtp()
-            return
-        if self._verify_jit is not None and self._spec_candidates():
+        if self.drafter is not None and self._spec_candidates():
             # speculation drafts from HARVESTED state, so the in-flight
             # dispatch (if any) must drain first; spec mode therefore
             # forfeits the harvest/compute overlap — the multi-token
@@ -3088,7 +3122,17 @@ class EngineCore:
         self.pipeline_drains[cause] = self.pipeline_drains.get(cause, 0) + 1
         prev, self._pending = self._pending, None
         prev["drain"] = cause
-        self._harvest(prev)
+        _dispatch, harvest = self._step_path()
+        harvest(prev)
+
+    def _step_path(self) -> tuple:
+        """(dispatch, harvest) of the default path's step: one row a slot,
+        or the two rows (last token, draft) of a model that drafts for
+        itself. Both take ``_decode_step_multi``'s way: one step in flight,
+        the next queued behind it off its on-device results."""
+        if self.resident_drafter:
+            return self._dispatch_rows, self._harvest_verify
+        return self._dispatch_multi, self._harvest
 
     def _decode_step_multi(self, K: int) -> None:
         """K fused decode steps, one dispatch, one host harvest: sampled
@@ -3107,12 +3151,17 @@ class EngineCore:
         sum. Finish reaction widens by one dispatch (≤2K-1 steps). A
         replay recorder at K = 1 sees every step harvested before the
         next is built (the followers' stream was validated for K > 1
-        only)."""
+        only).
+
+        A resident drafter's two-row step (K = 1; ``_step_path``) goes the
+        same way: the program decides acceptance and rewind itself, so the
+        step behind it needs nothing the host has not got."""
+        dispatch, harvest = self._step_path()
         if self._pending is not None:
             nxt, cause = self._dispatch_pipelined(K)
             if nxt is not None:
                 prev, self._pending = self._pending, nxt
-                self._harvest(prev)
+                harvest(prev)
                 return
             # nothing could be launched ahead (K > 1: slot churn; growth
             # that needs harvested state; every in-flight token a last
@@ -3122,12 +3171,12 @@ class EngineCore:
             self.clock.enter("build")
         if not self._prepare_multi(K):
             return
-        pending = self._dispatch_multi(K)
+        pending = dispatch(K)
         if (self.cfg.decode_dispatch_pipeline if K > 1
                 else self.recorder is None):
             self._pending = pending
         else:
-            self._harvest(pending)
+            harvest(pending)
 
     def _prepare_multi(self, K: int, ahead_mask=None,
                        sit_out=None) -> bool:
@@ -3144,9 +3193,20 @@ class EngineCore:
         one-step path has always done, after each token instead of before
         it; a full context finishes at its harvest). K > 1 keeps a
         token of headroom beyond its K writes and finishes a sequence
-        that close to its capacity before the dispatch."""
-        capacity = self.M * self.cfg.kv_block_size
+        that close to its capacity before the dispatch.
+
+        A resident drafter's step (K is 1) writes two adjacent rows: from
+        harvested state at pos, pos + 1. A step in flight advances its slot
+        by one position or by two, which only the device knows yet, so the
+        rows lie somewhere in pos + 1 .. pos + 3: both groups grow for the
+        upper bound, and the window group lets go only of what lies wholly
+        behind the window of the query at the LOWER bound (a rejected row
+        is rewound and rewritten by the next step's first row; the ring
+        holds the whole union: HybridCacheLayout.rows_ahead)."""
+        bs = self.cfg.kv_block_size
+        capacity = self.M * bs
         reach = K + 1 if K > 1 else 1
+        rows = self.cfg.spec_k + 1 if self.resident_drafter else 0
         if ahead_mask is None:
             ahead_mask = self._no_slot
         if sit_out is None:
@@ -3155,9 +3215,16 @@ class EngineCore:
             if s is None or not s.ready or sit_out[i]:
                 continue
             in_flight = bool(ahead_mask[i])
-            pos_eff = s.pos + (K if in_flight else 0)
-            if pos_eff + reach > capacity:
-                # within K tokens of the context capacity: finish now
+            # lo: the lowest position the dispatch may query; hi: one past
+            # the highest it may write
+            if rows:
+                lo, hi = s.pos + in_flight, s.pos + rows * (1 + in_flight)
+            else:
+                lo = s.pos + (K if in_flight else 0)
+                hi = lo + reach
+            if hi > capacity:
+                # within K tokens of the context capacity (no position left
+                # for the draft row: --max-model-len counts it): finish now
                 # rather than let the scan write past the block table
                 # (bounded early stop, same K-granularity trade as EOS)
                 if in_flight:
@@ -3165,7 +3232,7 @@ class EngineCore:
                 self._release_slot(s)
                 self._finish_request(s, FinishReason.LENGTH)
                 continue
-            need = self._blocks_needed(pos_eff + reach)
+            need = self._blocks_needed(hi)
             if need > len(s.blocks):
                 new = self.kv_manager.pool.alloc_uninit(need - len(s.blocks))
                 if new is None:
@@ -3178,12 +3245,14 @@ class EngineCore:
                     continue
                 s.blocks.extend(new)
                 self._block_tables[i, :len(s.blocks)] = s.blocks
-            if self.has_window_pool and need - 1 not in s.win.held:
-                # the write lands in a new block: let go of what the
+            first = lo // bs
+            if self.has_window_pool and any(
+                    b not in s.win.held for b in range(first, need)):
+                # a write lands in a new block: let go of what the
                 # window has left behind, then take the block (a step in
                 # flight still reads what is let go: it runs first)
-                self.kv_manager.window_slide(s.win, pos_eff)
-                if not self.kv_manager.window_grow(s.win, need - 1, need):
+                self.kv_manager.window_slide(s.win, lo)
+                if not self.kv_manager.window_grow(s.win, first, need):
                     if in_flight:
                         return False
                     self._preempt_or_finish(s)
@@ -3204,7 +3273,10 @@ class EngineCore:
         in-flight token is known to be its last (token budget, context
         capacity, a cancel already seen) sits the dispatch out, so such
         a finish wastes nothing; a finish by EOS or stop discards one
-        slot-row at its harvest.
+        slot-row at its harvest. A two-row step in flight advances its
+        slot by one position or by two: the slot sits out where even one
+        is its last, and rides where only the second would be (its rows
+        are then discarded at their harvest).
 
         K > 1 chains all or nothing: the slot→request mapping must be
         IDENTICAL to the in-flight dispatch's, and any churn (admission,
@@ -3221,7 +3293,9 @@ class EngineCore:
             if prev["K"] != K or not all(same):
                 return None, "slot_churn"
         else:
-            full = self.M * self.cfg.kv_block_size - 1
+            # no room for the next step's rows even one position on
+            rows = self.cfg.spec_k + 1 if self.resident_drafter else 1
+            full = self.M * self.cfg.kv_block_size - rows
             sit_out = np.array(
                 [bool(m) and (s.generated + 1 >= s.max_new_tokens
                               or s.pos >= full or s.cancelled)
@@ -3232,9 +3306,9 @@ class EngineCore:
                 return None, "last_token"
         if not self._prepare_multi(K, ahead_mask=mask, sit_out=sit_out):
             return None, "kv_growth"
-        return self._dispatch_multi(K, chain=prev["toks"], mask=mask,
-                                    sit_out=sit_out,
-                                    chained_from=prev.get("id")), None
+        dispatch, _harvest = self._step_path()
+        return dispatch(K, chain=prev["chain"], mask=mask, sit_out=sit_out,
+                        chained_from=prev.get("id")), None
 
     def _dispatch_multi(self, K: int, chain=None, mask=None,
                         sit_out=None, chained_from=None) -> dict:
@@ -3315,7 +3389,7 @@ class EngineCore:
             self.params, self.kv, *args)
         self.clock.enter("build")
         return {"toks": toks_k, "logprobs": logprobs_k, "K": K, "id": did,
-                "reqs": riders, "mask": mask,
+                "chain": toks_k, "reqs": riders, "mask": mask,
                 **self._key_wave_counts(tables, riders, K)}
 
     def _key_wave_counts(self, tables: np.ndarray, riders: list,
@@ -3971,80 +4045,48 @@ class EngineCore:
         toks_T, lps_T, self.kv = self._verify_jit(
             self.params, self.kv, *args)
         self.spec_dispatches += 1
-        self.spec_drafted_tokens += sum(len(d) for d in dmap.values())
         self._harvest_verify({
             "toks": toks_T, "logprobs": lps_T, "drafts": dmap, "id": did,
             "reqs": [s if (s is not None and s.ready) else None
                      for s in self.slots]})
         return True
 
-    def _prepare_rows(self, rows: int) -> bool:
-        """Before a step that scores ``rows`` adjacent rows a slot (a
-        resident drafter's): the blocks of both groups that the rows at
-        pos .. pos + rows - 1 are written to. The window group lets go only
-        of what lies wholly behind the window of the query at ``pos``, the
-        ACCEPTED position: a rejected row is rewound and its block, if it
-        was a new one, is the next step's. → whether anything decodes."""
-        bs = self.cfg.kv_block_size
-        capacity = self.M * bs
-        for i, s in enumerate(self.slots):
-            if s is None or not s.ready:
-                continue
-            if s.pos + rows > capacity:
-                # no position left for the draft row (--max-model-len
-                # counts it: docs/speculative.md)
-                self._release_slot(s)
-                self._finish_request(s, FinishReason.LENGTH)
-                continue
-            need = self._blocks_needed(s.pos + rows)
-            if need > len(s.blocks):
-                new = self.kv_manager.pool.alloc_uninit(need - len(s.blocks))
-                if new is None:
-                    self._preempt_or_finish(s)
-                    continue
-                s.blocks.extend(new)
-                self._block_tables[i, :len(s.blocks)] = s.blocks
-            first = s.pos // bs
-            if any(b not in s.win.held for b in range(first, need)):
-                self.kv_manager.window_slide(s.win, s.pos)
-                if not self.kv_manager.window_grow(s.win, first, need):
-                    self._preempt_or_finish(s)
-                    continue
-                self._window_ring(i, s)
-        return any(s is not None and s.ready for s in self.slots)
-
-    def _decode_step_mtp(self) -> None:
-        """One step of a model that drafts for itself: rows (last token,
-        draft) of every ready slot in ONE dispatch of the two-row program
-        (``_verify_jit`` in its resident form), harvested at once with
-        lockstep acceptance. Every decoding slot has a draft (a prefill, a
-        hit's chunk and a preemption's re-prefill each return one): no slot
-        rides along as a one-row step. Recorded as this model's ``decode``
-        step."""
+    def _dispatch_rows(self, K: int, chain=None, mask=None, sit_out=None,
+                       chained_from=None) -> dict:
+        """``_dispatch_multi`` for a model that drafts for itself: launch
+        the two-row program (``_verify_jit`` in its resident form) over
+        rows (last token, draft) of every riding slot. ``mask`` flags the
+        slots chained off the dispatch in flight: the program takes their
+        pair, their advance and their key steps from ``chain``, that
+        dispatch's on-device carry, and the host's values (harvested
+        state, one step behind) are only what it adds to; every other slot
+        feeds host-known rows. Every decoding slot has a draft (a prefill,
+        a hit's chunk and a preemption's re-prefill each return one): no
+        slot rides along as a one-row step."""
         Tv = self.cfg.spec_k + 1
-        if not self._prepare_rows(Tv):
-            return
+        if mask is None:
+            mask = self._no_slot
+        if sit_out is None:
+            sit_out = self._no_slot
+        riders = [s if (s is not None and s.ready and not sit_out[i])
+                  else None for i, s in enumerate(self.slots)]
         steps = np.zeros((self.B,), np.int64)
         tokens = np.zeros((self.B, Tv), np.int32)
-        n_rows = np.zeros((self.B,), np.int32)
-        dmap: Dict[int, List[int]] = {}
-        riders = [s if (s is not None and s.ready) else None
-                  for s in self.slots]
         for i, s in enumerate(riders):
             if s is None:
                 self._positions[i] = 0
                 if self.slots[i] is None:
                     self._block_tables[i, :] = 0  # trash block
                 continue
-            dmap[i] = [max(int(s.draft), 0)]
-            tokens[i] = [s.last_token] + dmap[i]
+            tokens[i] = (s.last_token, max(int(s.draft), 0))
             self._positions[i] = s.pos
             steps[i] = s.key_step
-            n_rows[i] = Tv
-        tables = self._tables_for_dispatch()
+        tables = self._tables_for_dispatch(sit_out)
         self._step += 1
         did = None
         if self.recorder is not None:
+            # harvested at once, so never chained: the record is the whole
+            # step (replay.exec_verify_event passes the zero carry)
             did = self.recorder.next_dispatch_id()
             self.recorder.rec(
                 "verify", id=did, Tv=Tv, tokens=tokens.copy(),
@@ -4052,20 +4094,25 @@ class EngineCore:
                 seeds=self._seeds.copy(), steps=steps.copy(),
                 temperature=self._samp["temperature"].copy(),
                 top_k=self._samp["top_k"].copy(),
-                top_p=self._samp["top_p"].copy(), n_rows=n_rows.copy(),
+                top_p=self._samp["top_p"].copy(),
+                n_rows=np.where([s is not None for s in riders], Tv,
+                                0).astype(np.int32),
                 reqs=[s.rid if s is not None else None for s in riders])
+        carry, mask_dev = ((chain, jnp.asarray(mask)) if chain is not None
+                           else self._carry_zero)
         args = (jnp.asarray(tokens), _owned(self._positions),
                 _owned(tables), _owned(self._seeds), jnp.asarray(steps),
                 _owned(self._samp["temperature"]),
-                _owned(self._samp["top_k"]), _owned(self._samp["top_p"]))
+                _owned(self._samp["top_k"]), _owned(self._samp["top_p"]),
+                carry, mask_dev)
         self.clock.enter("dispatch")
-        toks_T, lps_T, self.kv, drafts = self._verify_jit(
+        toks_T, lps_T, self.kv, drafts, carry = self._verify_jit(
             self.params, self.kv, *args)
+        self.clock.enter("build")
         self.spec_dispatches += 1
-        self.spec_drafted_tokens += len(dmap)
-        self._harvest_verify({
-            "toks": toks_T, "logprobs": lps_T, "drafts": dmap, "id": did,
-            "next_drafts": drafts, "reqs": riders})
+        return {"toks": toks_T, "logprobs": lps_T, "next_drafts": drafts,
+                "chain": carry, "K": K, "id": did, "reqs": riders,
+                "mask": mask}
 
     def _harvest_verify(self, pending: dict) -> None:
         """Apply one verify dispatch: walk each slot's sampled rows with
@@ -4074,7 +4121,15 @@ class EngineCore:
         step's bookkeeping). Rejected draft rows roll back by REWIND:
         ``pos`` never advances over them, and every later dispatch
         rewrites a stale row before any query attends it (the same
-        write-then-read ordering plain decode relies on)."""
+        write-then-read ordering plain decode relies on).
+
+        A resident drafter's step (``next_drafts``) is harvested under the
+        NEXT step's device time: that step was queued behind this one with
+        acceptance decided by the program itself (``decode_mtp``'s carry),
+        and the walk below reaches the same verdict from the fetched
+        tokens, since a slot's rows were (last token, draft) of the state
+        the harvest before this one left. The n-gram drafter's step is
+        harvested at once."""
         self.clock.enter("wait")
         toks_T = np.asarray(pending["toks"])       # [B, Tv] — ONE fetch
         lps_T = np.asarray(pending["logprobs"])
@@ -4090,8 +4145,12 @@ class EngineCore:
         for i, req in enumerate(pending["reqs"]):
             if req is None or self.slots[i] is not req:
                 continue
-            d = pending["drafts"].get(i, [])
+            d = ([max(int(req.draft), 0)] if next_drafts is not None
+                 else pending["drafts"].get(i, []))
             inputs = [req.last_token] + d
+            # drafts scored: of the slots harvested (a chained slot that
+            # finished under its last step rode in vain and counts nowhere)
+            self.spec_drafted_tokens += len(d)
             n_applied = 0
             accepted = 0
             # what the step's rows read of this slot, each cached row once:
@@ -4157,20 +4216,23 @@ class EngineCore:
                 emitted=emitted, accepted=accepted)
             return
         # a resident drafter's step IS this model's decode step: a
-        # ``decode`` record with the one-step path's fields (one dispatch,
-        # nothing chained: it is harvested at once) and, beside them, the
-        # rows it scored and the drafts it accepted. ctx_tokens /
-        # win_tokens count each cached row ONCE a slot, however many of the
-        # slot's rows read it
+        # ``decode`` record with the one-step path's fields (chained: the
+        # slots fed from the device behind an un-harvested step; drain:
+        # why none was launched behind this one, where none was) and,
+        # beside them, the rows it scored and the drafts it accepted.
+        # ctx_tokens / win_tokens count each cached row ONCE a slot,
+        # however many of the slot's rows read it
         self.flight.record_cycle(
-            "decode", K=1, batch_fill=len(applied), chained=0,
+            "decode", K=1, batch_fill=len(applied),
+            chained=sum(1 for i, *_ in applied if pending["mask"][i]),
             planned_tokens=len(applied), emitted=emitted, rows=rows,
             accepted=accepted, ctx_tokens=ctx_tokens, sel_tokens=ctx_tokens,
             win_tokens=win_tokens,
             win_blocks_live=max(
                 (len(r.win.held) for r in self.slots
                  if r is not None and r.win is not None), default=0),
-            state_bytes=0)
+            state_bytes=0,
+            **({"drain": pending["drain"]} if "drain" in pending else {}))
 
     # ----------------------------------------------------------- preemption
     def _preempt_or_finish(self, req: EngineRequest) -> None:

@@ -190,7 +190,8 @@ def cache_layout(cfg: ModelConfig, block_size: int, dtype_bytes: int = 2):
         window_layers=n_s, window=cfg.swa_window,
         state_layers=0, state_bytes=0, window_pool=True,
         window_row_bytes=sum(row_lanes(cfg.swa_gqa_geometry())) * dtype_bytes,
-        rows_read_next_token=cfg.mtp_layers > 0)
+        rows_read_next_token=cfg.mtp_layers > 0,
+        rows_ahead=min(cfg.mtp_layers, 1))
 
 
 def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,

@@ -996,9 +996,9 @@ SWA_QUERY_BLOCK = 256
 
 
 def swa_ring_blocks(cfg: ModelConfig, bsz: int) -> int:
-    """Window blocks a decoding sequence holds a layer: the window's and
-    one more (HybridCacheLayout.ring_blocks)."""
-    return -(-cfg.swa_window // bsz) + 1
+    """Window blocks a decoding sequence holds a layer: the window's, one
+    more, ``rows_ahead`` of a drafter (HybridCacheLayout.ring_blocks)."""
+    return -(-(cfg.swa_window + min(cfg.mtp_layers, 1)) // bsz) + 1
 
 
 def _swa_tables(block_tables, M: int, R: int, doubled: bool):

@@ -258,13 +258,16 @@ def exec_verify_event(core, kv, ev: dict):
             f"recorded verify dispatch has {np.asarray(ev['tokens']).shape[1]}"
             f" rows/slot but this core compiled spec_k={core.cfg.spec_k} — "
             f"replay with the recorded engine config")
-    # a resident drafter's two-row step returns its drafts behind kv
+    # a resident drafter's two-row step takes a carry (a recorded step is
+    # harvested before the next is built: nothing chained) and returns its
+    # drafts and its own carry behind kv: the program that served
     toks, _lps, kv, *_drafts = core._verify_jit(
         core.params, kv, jnp.array(np.asarray(ev["tokens"])),
         jnp.array(ev["positions"]), jnp.array(ev["tables"]),
         jnp.array(ev["seeds"]), jnp.array(ev["steps"]),
         jnp.array(ev["temperature"]), jnp.array(ev["top_k"]),
-        jnp.array(ev["top_p"]))
+        jnp.array(ev["top_p"]),
+        *(core._carry_zero if core.resident_drafter else ()))
     return toks, kv
 
 

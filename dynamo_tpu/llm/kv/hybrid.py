@@ -73,12 +73,19 @@ class HybridCacheLayout:
     # row of a prefix hit was computed from its PRODUCER's next token: a hit
     # is cut back by one block and that block is computed again
     rows_read_next_token: bool = False
+    # positions by which a decode step's rows may lie beyond where harvested
+    # state would put them: a resident drafter's step is queued behind one
+    # that advances its slot by one position or by two, and only the device
+    # knows which (1; 0 wherever a queued step's positions are known)
+    rows_ahead: int = 0
 
     @property
     def ring_blocks(self) -> int:
         """Blocks of one window layer's ring: the window and one more, so
-        that the block being written holds no row the window still needs."""
-        return -(-self.window // self.block_size) + 1
+        that the block being written holds no row the window still needs
+        (the rows of a two-row step included); ``rows_ahead`` widens the
+        span that one table has to cover by as many positions."""
+        return -(-(self.window + self.rows_ahead) // self.block_size) + 1
 
     @property
     def has_state(self) -> bool:

@@ -394,66 +394,122 @@ def test_draft_logits_are_the_references(case):
 
 # ------------------------------------------------ exactness under the loop
 
-def _force(core, streams, accept):
-    """Drafts by fiat, in place of the module's: after every admission and
-    every harvest each slot's draft becomes the token its stream holds next
-    (``accept(step)``: an accepted draft) or one it does not (rejected).
-    Also holds the release rule before every step: the window blocks that
-    the row at the ACCEPTED position reads are all held."""
-    by_rid = {f"r{i}": ids for i, ids in enumerate(streams)}
+class Forced:
+    """Drafts by fiat, where a step reads them: the draft logits of both
+    served programs (``model_mod.prefill_forward_mtp``, ``decode_forward_mtp``)
+    become one-hot rows, so that the prefill's first draft and every
+    step's ``drafts`` output, the carry a chained step is fed from
+    included, are the token the row's stream holds next (``accept(q)`` of
+    its position q: an accepted draft) or one it does not (rejected). A
+    host callback looks the row up by (input token, position, sampled
+    token) in the known sequences, so one compiled engine serves under any
+    pattern (``use``). Patches the family's module: engines built inside
+    the test that asked for the fixture."""
+
+    def __init__(self, monkeypatch):
+        self.known, self.accept = [], lambda q: True
+        real = {n: getattr(llama, n)
+                for n in ("prefill_forward_mtp", "decode_forward_mtp")}
+
+        def forced(logits, inputs, positions, sampled):
+            want = jax.pure_callback(
+                self._lookup, jax.ShapeDtypeStruct(sampled.shape, jnp.int32),
+                inputs, positions, sampled)
+            return jax.nn.one_hot(want, logits.shape[-1], dtype=logits.dtype)
+
+        def prefill(params, kv, tokens, table, start_pos, true_len, nxt,
+                    statics, sample):
+            tok, lp, logits, kv = real["prefill_forward_mtp"](
+                params, kv, tokens, table, start_pos, true_len, nxt, statics,
+                sample)
+            last = jnp.maximum(true_len - 1, 0)
+            return tok, lp, forced(logits[None], tokens[last][None],
+                                   (start_pos + last)[None], tok[None])[0], kv
+
+        def decode(params, kv, tokens, positions, tables, statics, sample):
+            toks, lps, logits, kv = real["decode_forward_mtp"](
+                params, kv, tokens, positions, tables, statics, sample)
+            return toks, lps, forced(logits, tokens, positions, toks), kv
+
+        monkeypatch.setattr(llama, "prefill_forward_mtp", prefill)
+        monkeypatch.setattr(llama, "decode_forward_mtp", decode)
+
+    def use(self, prompts, streams, accept):
+        self.known = [np.asarray(list(p) + list(ids))
+                      for p, ids in zip(prompts, streams)]
+        self.accept = accept
+
+    def _lookup(self, inputs, positions, sampled):
+        """Row (x at p, sampled t) lies in the sequence with x at p and t
+        at p + 1 (a rejected row's is in none: whatever it drafts is
+        dropped) → that sequence's token at p + 2, or a miss."""
+        out = np.zeros(sampled.shape, np.int32)
+        for i, (x, p, t) in enumerate(zip(inputs, positions, sampled)):
+            for seq in self.known:
+                if p + 2 < len(seq) and seq[p] == x and seq[p + 1] == t:
+                    miss = not self.accept(int(p) + 2)
+                    out[i] = (seq[p + 2] + miss) % CFG.vocab_size
+                    break
+        return out
+
+
+@pytest.fixture
+def forced(monkeypatch):
+    return Forced(monkeypatch)
+
+
+ACCEPT = {"accepted": lambda q: True, "alternating": lambda q: q % 3 != 0,
+          "rejected": lambda q: False}
+
+
+def hold_the_window(core):
+    """Holds the release rule before every step: the window blocks that the
+    step's rows read, wherever the step in flight leaves them (the union
+    over the positions it may advance by), are all held, in a ring that no
+    two of them share an entry of. → {"held": slots checked, "ahead":
+    those with a step in flight}."""
     bs, W = core.cfg.kv_block_size, core.model_cfg.swa_window
-    state = {"step": 0, "held": 0}
+    rows = core.cfg.spec_k + 1
+    state = {"held": 0, "ahead": 0, "most": 0}
+    prepare = core._prepare_multi
 
-    def redraft():
-        for s in core.slots:
-            if s is None or not s.ready:
+    def prepared(K, ahead_mask=None, sit_out=None):
+        ok = prepare(K, ahead_mask=ahead_mask, sit_out=sit_out)
+        for i, s in enumerate(core.slots):
+            if s is None or not s.ready or (sit_out is not None
+                                            and sit_out[i]):
                 continue
-            ids = by_rid[s.rid]
-            nxt = ids[s.emitted_total] if s.emitted_total < len(ids) else 0
-            s.draft = nxt if accept(state["step"]) else (nxt + 1) % 512
-
-    complete, harvest, prepare = (core._complete_admissions,
-                                  core._harvest_verify, core._prepare_rows)
-
-    def completed():
-        complete()
-        redraft()
-
-    def harvested(pending):
-        harvest(pending)
-        state["step"] += 1
-        redraft()
-
-    def prepared(rows):
-        ok = prepare(rows)
-        for s in core.slots:
-            if s is not None and s.ready:
-                need = range(max(0, s.pos - (W - 1)) // bs,
-                             (s.pos + rows - 1) // bs + 1)
-                assert all(b in s.win.held for b in need), (s.pos, s.win.held)
-                assert len(s.win.held) <= core.R
-                state["held"] += 1
+            ahead = bool(ahead_mask is not None and ahead_mask[i])
+            if not ok and ahead:
+                continue          # drained: prepared again from its harvest
+            lo, hi = s.pos + ahead, s.pos + rows * (1 + ahead)
+            need = range(max(0, lo - (W - 1)) // bs, (hi - 1) // bs + 1)
+            assert all(b in s.win.held for b in need), (s.pos, s.win.held)
+            assert len(s.win.held) <= core.R
+            assert len({b % core.R for b in s.win.held}) == len(s.win.held)
+            state["held"] += 1
+            state["ahead"] += ahead
+            state["most"] = max(state["most"], len(s.win.held))
         return ok
 
-    core._complete_admissions, core._harvest_verify, core._prepare_rows = (
-        completed, harvested, prepared)
+    core._prepare_multi = prepared
     return state
 
 
 @pytest.mark.parametrize("drafts", ["accepted", "alternating", "rejected"])
-def test_forced_drafts_leave_the_stream_as_it_is(served, drafts):
+def test_forced_drafts_leave_the_stream_as_it_is(served, forced, drafts):
     """Across the window's edge, a ring wrap and block boundaries: every
     draft accepted (two tokens a step, the window advancing by two, two
-    tokens registered in a block), every other one, none."""
+    tokens registered in a block), two of three, none. The steps are
+    chained: acceptance is the program's own."""
     prompts, out = served
     want = [ids for ids, _ in out[0][1]]
     core = _engine(1)
-    accept = {"accepted": lambda i: True, "alternating": lambda i: i % 2 == 0,
-              "rejected": lambda i: False}[drafts]
-    state = _force(core, want, accept)
+    forced.use(prompts, want, ACCEPT[drafts])
+    state = hold_the_window(core)
     got = asyncio.run(_serve(core, prompts, 40))
     assert [ids for ids, _ in got] == want
-    assert state["held"] > 0
+    assert state["held"] > 0 and state["ahead"] > 0.8 * state["held"]
     emitted = core.spec_emitted_tokens
     if drafts == "accepted":
         # but for a request's last step, whose second token is over budget
@@ -461,14 +517,15 @@ def test_forced_drafts_leave_the_stream_as_it_is(served, drafts):
         assert core.spec_dispatches <= 0.62 * 39
     if drafts == "rejected":
         assert core.spec_accepted_tokens == 0
-        assert core.spec_rewound_rows == core.spec_drafted_tokens
+    assert core.spec_rewound_rows == (core.spec_drafted_tokens
+                                      - core.spec_accepted_tokens)
     # the logprobs are the one-row engine's too
     for (_, a), (_, b) in zip(got, out[0][1]):
         np.testing.assert_allclose(a, b, atol=1e-4)
 
 
 @pytest.mark.parametrize("drafts", ["model", "alternating"])
-def test_a_preemption_leaves_the_stream_as_it_is(drafts):
+def test_a_preemption_leaves_the_stream_as_it_is(request, drafts):
     """A paged pool too small for three growing sequences: one is preempted
     and prefilled again (its re-prefill returns a draft), with the module's
     own drafts and with every other one forced to be accepted. Sampled, not
@@ -480,9 +537,13 @@ def test_a_preemption_leaves_the_stream_as_it_is(drafts):
     want = asyncio.run(_serve(base, prompts, 36, samp))
     core = _engine(1, **small)
     if drafts == "alternating":
-        _force(core, [ids for ids, _ in want], lambda i: i % 2 == 0)
+        request.getfixturevalue("forced").use(
+            prompts, [ids for ids, _ in want], ACCEPT["alternating"])
     got = asyncio.run(_serve(core, prompts, 36, samp))
     assert base.preemptions > 0 and core.preemptions > 0
+    # a growth that fails under a step in flight preempts nothing: it
+    # drains, and the fresh step preempts from harvested state
+    assert core.pipeline_drains.get("kv_growth", 0) > 0
     assert [ids for ids, _ in got] == [ids for ids, _ in want]
 
 

@@ -59,7 +59,8 @@ def test_exaone_served_programs_build_at_the_published_sizes(
     """K-EXAONE's two served programs, whole, for the described chip: the
     chunk prefill with the module's tail and the two-row step of 64 slots
     (128 rows through 2 F + 6 S layers, both pools and the module's block),
-    int8 weights, 20,480 paged and 2,666 window blocks. Every read is its
+    int8 weights, 20,480 paged and 2,731 window blocks (a ring of 10: the
+    window's nine and the row a chained step may run ahead). Every read is its
     Pallas kernel under its own name, the module's apart from the model's;
     weights + pools fill 60% of the chip and the program fits beside them."""
     cfg, e, core, layout, params, kv = _exaone_shell(monkeypatch)
@@ -70,7 +71,7 @@ def test_exaone_served_programs_build_at_the_published_sizes(
     i32, f32, i64 = jnp.int32, jnp.float32, jnp.int64
     M, B, R = e.max_blocks_per_seq, e.max_num_seqs, layout.ring_blocks
     assert (M, B, R, kv["k"].shape[0], kv["win_k"].shape[0]) == (
-        449, 64, 9, 3, 6)
+        449, 64, 10, 3, 6)
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     key = s(key.shape, key.dtype)
     if program == "prefill-512":
@@ -81,10 +82,12 @@ def test_exaone_served_programs_build_at_the_published_sizes(
         names = ("gqa_full_prefill", "gqa_window_prefill",
                  "mtp_full_prefill")
     else:
+        # the loop's form: the carry of the dispatch before and the mask
         compiled = core._verify_jit.lower(
             place(params), place(kv), s((B, 2), i32), s((B,), i32),
             s((B, M + R), i32), s((B,), i64), s((B,), i64), s((B,), f32),
-            s((B,), i32), s((B,), f32)).compile()
+            s((B,), i32), s((B,), f32), (s((B,), i32),) * 3,
+            s((B,), jnp.bool_)).compile()
         names = ("gqa_full_read", "gqa_window_read", "mtp_full_read")
     text = compiled.as_text()
     assert all(n in text for n in names), [n for n in names if n not in text]
@@ -101,12 +104,12 @@ def test_exaone_two_row_reads_build_at_the_published_sizes(one_chip, read):
     128 rows (two a slot, each a sequence of its own to the kernel), 64
     query heads over 8 kv heads of 128 lanes, rows of 1,024 | 1,024 lanes.
     Full and the module's: tables of 449 blocks into the three-layer paged
-    pool of 20,480; window: a ring of 9 blocks of the six-layer pool of
-    2,666 with a lower bound, no sink."""
+    pool of 20,480; window: a ring of 10 blocks of the six-layer pool of
+    2,731 with a lower bound, no sink."""
     from dynamo_tpu.engine.models import mimo
     rows, bs, H, KVH, d = 128, 16, 64, 8, 128
     window = read == "gqa_window_read"
-    M, layers, blocks, chunk = ((9, 6, 2666, 9) if window else
+    M, layers, blocks, chunk = ((10, 6, 2731, 10) if window else
                                 (449, 3, 20480, mimo.GQA_WAVE_ROWS // bs))
 
     def fn(q, k, v, tables, lens, lo):
