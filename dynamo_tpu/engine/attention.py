@@ -834,7 +834,7 @@ def _paged_attn_kernel(block_tables_ref, seq_lens_ref, win_lo_ref,
                        quant_lanes: int | None = None,
                        v_lanes: int | None = None,
                        quant_sections: tuple | None = None,
-                       coalesce: bool = True, sink_ref=None):
+                       coalesce: bool = True, sink_ref=None, rows: int = 1):
     """q_ref: [G, Hp, C] sparse-slotted (VMEM); k_hbm/v_hbm: [NTOK, Cx]
     (HBM; value heads of another size than the keys': v_hbm, v_bufs, acc
     and o_ref are KVH*Dv wide, nothing else differs); o_ref: [G, Hp, C];
@@ -883,7 +883,16 @@ def _paged_attn_kernel(block_tables_ref, seq_lens_ref, win_lo_ref,
     first wave's DMA latency — at seq 512 / chunk 16 that is 1 exposed
     wave in 2, which measured as ~44% of HBM peak. Buffer slots follow a
     GLOBAL wave counter (wave_ref) rather than the per-sequence chunk
-    index so producer and consumer agree on parity across boundaries."""
+    index so producer and consumer agree on parity across boundaries.
+
+    ``rows`` = R > 1: a sequence brings R queries, at its last R positions
+    (the rows a slot scores in one step), stacked in the sublanes: q_ref,
+    o_ref [G, R·Hp, C], m / l / acc / sink_ref R·Hp rows. seq_lens_ref is
+    the LAST row's and row r sees R - 1 - r keys fewer; win_lo_ref [B·R]
+    holds a lower bound a ROW (a window pinned at the sequence's start does
+    not slide with the row), row 0's the lowest. The mask is a row's own;
+    every wave is fetched once and scored by all R rows. At R == 1 nothing
+    here differs from the kernel without it."""
     pb = pl.program_id(0)
     G = seqs_per_program
 
@@ -894,8 +903,9 @@ def _paged_attn_kernel(block_tables_ref, seq_lens_ref, win_lo_ref,
         nc = (nb + chunk - 1) // chunk
         # sliding-window layers: chunks entirely below the window would
         # be DMA'd and masked to nothing — start at the first in-window
-        # chunk
-        sc = jnp.maximum(win_lo_ref[bi] + 1, 0) // (chunk * block_size)
+        # chunk (of the sequence's first row: the lowest bound of its rows)
+        sc = jnp.maximum(win_lo_ref[bi * rows if rows > 1 else bi] + 1,
+                         0) // (chunk * block_size)
         return nb, nc, sc
 
     quantized = quant_lanes is not None
@@ -918,7 +928,18 @@ def _paged_attn_kernel(block_tables_ref, seq_lens_ref, win_lo_ref,
         sq = pb * G + s                        # program's sequence group
         num_blocks, num_chunks, start_ci = seq_shape(sq)
         seq_len = seq_lens_ref[sq]
-        win_lo = win_lo_ref[sq]
+        if rows == 1:
+            win_lo = win_lo_ref[sq]
+        else:
+            # a column a query sublane (row r = sublane // Hp): what the
+            # row's upper bound lies below the last row's, its lower bound
+            row = jax.lax.broadcasted_iota(
+                jnp.int32, (q_ref.shape[1], 1), 0) // (q_ref.shape[1] // rows)
+            seq_len = seq_len - (rows - 1 - row)
+            win_lo = win_lo_ref[sq * rows]
+            for r in range(1, rows):
+                win_lo = jnp.where(row == r, win_lo_ref[sq * rows + r],
+                                   win_lo)
 
         one_wave = (num_chunks - start_ci) == 1
 
@@ -1043,7 +1064,8 @@ def paged_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                            interpret: bool = False,
                            v_dim: int | None = None,
                            sink: jax.Array | None = None,
-                           name: str = "paged_attention") -> jax.Array:
+                           name: str = "paged_attention",
+                           rows: int = 1) -> jax.Array:
     """Same contract as `paged_attention_xla`; KV stays in HBM and streams
     chunk-by-chunk with double buffering (no [B, M*BS] gather). Sliding
     windows are in-kernel (win_lo: [B], -1 for global layers). int8 pools
@@ -1065,8 +1087,28 @@ def paged_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     ``quant_sections`` (int8 MLA pools; requires v_lanes): rows carry
     the sectioned in-row encoding and dequant to the query's width
     in-kernel (kernel docstring). The row width is
-    pad128(sum + KV_SCALE_LANES); q width must be pad128(sum)."""
-    B, H, Dh = q.shape
+    pad128(sum + KV_SCALE_LANES); q width must be pad128(sum).
+
+    ``rows`` = R > 1: the R rows a sequence scores in one step share ONE
+    pass over its cache. q [B·R, H, Dh], sequence-major (sequence b's rows
+    at its last R positions, oldest first, are q[b·R : (b+1)·R]);
+    block_tables [B, M]; seq_lens [B] as the LAST row sees them (row r sees
+    R - 1 - r keys fewer); win_lo [B·R], a ROW's own lower bound in the
+    sequence's table (a window that has not left the sequence's start does
+    not slide with the row), row 0's the lowest. Returns [B·R, H, v_dim or
+    Dh], equal to the call with every row a sequence of its own.
+    Full-precision pools with a v stream of their own (the reads that have
+    such a step)."""
+    N, H, Dh = q.shape
+    B = N // rows
+    if rows > 1 and (N % rows or v_lanes is not None
+                     or k_cache.dtype == jnp.int8
+                     or (win_lo is not None and win_lo.shape != (N,))):
+        raise ValueError(
+            f"rows={rows} needs a whole number of sequences (q has {N} "
+            f"rows), a lower bound a ROW where there is one, and a "
+            f"full-precision pool read without v_lanes: no other read has "
+            f"a step of several rows a sequence")
     NTOK, Cx = k_cache.shape
     quantized = k_cache.dtype == jnp.int8
     if quant_sections is not None:
@@ -1119,23 +1161,24 @@ def paged_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         chunk_blocks = ATTN_CHUNK_BLOCKS
     chunk = max(1, min(chunk_blocks, M))
     Hp = max(8, H)   # sublane-pad the head rows for tiny models
+    Hq = rows * Hp   # a sequence's query sublanes: its rows, stacked
     if seqs_per_program is None:
         seqs_per_program = ATTN_SEQS_PER_PROGRAM
     G = max(1, min(seqs_per_program, B))
     Bp = ((B + G - 1) // G) * G
     # sparse slot placement: row h carries q[h] at its kv head's lane group
-    qm = jnp.zeros((Bp, Hp, KVH, Dh), q.dtype)
-    qm = qm.at[:B, jnp.arange(H), jnp.arange(H) // g, :].set(q)
-    qm = qm.reshape(Bp, Hp, C)
+    qm = jnp.zeros((Bp * rows, Hp, KVH, Dh), q.dtype)
+    qm = qm.at[:N, jnp.arange(H), jnp.arange(H) // g, :].set(q)
+    qm = qm.reshape(Bp, Hq, C)
     if win_lo is None:
-        win_lo = jnp.full((B,), -1, jnp.int32)
+        win_lo = jnp.full((B * rows,), -1, jnp.int32)
     if Bp > B:       # pad group tail with zero-length sequences (no waves)
         block_tables = jnp.concatenate(
             [block_tables, jnp.zeros((Bp - B, M), block_tables.dtype)])
         seq_lens = jnp.concatenate(
             [seq_lens, jnp.zeros((Bp - B,), seq_lens.dtype)])
         win_lo = jnp.concatenate(
-            [win_lo, jnp.full((Bp - B,), -1, jnp.int32)])
+            [win_lo, jnp.full(((Bp - B) * rows,), -1, jnp.int32)])
     # per-wave coalescibility, derived from the SAME tables the kernel
     # reads (trace-time: stays correct as seq_lens advance inside a
     # K-step scan); zeros = per-block path everywhere
@@ -1147,24 +1190,26 @@ def paged_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
     operands = (qm, k_cache, v_cache)
     in_specs = [
-        pl.BlockSpec((G, Hp, C), lambda b, *_: (b, 0, 0)),
+        pl.BlockSpec((G, Hq, C), lambda b, *_: (b, 0, 0)),
         pl.BlockSpec(memory_space=pltpu.ANY),   # k_cache stays in HBM
         pl.BlockSpec(memory_space=pltpu.ANY),   # v_cache stays in HBM
     ]
     if sink is not None:
-        in_specs.append(pl.BlockSpec((Hp, 1), lambda b, *_: (0, 0)))
-        operands += (jnp.zeros((Hp, 1), jnp.float32).at[:H, 0].set(
-            sink.astype(jnp.float32)),)
+        in_specs.append(pl.BlockSpec((Hq, 1), lambda b, *_: (0, 0)))
+        sink_col = jnp.zeros((Hp, 1), jnp.float32).at[:H, 0].set(
+            sink.astype(jnp.float32))
+        operands += (jnp.tile(sink_col, (rows, 1)) if rows > 1
+                     else sink_col,)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(Bp // G,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((G, Hp, Cv), lambda b, *_: (b, 0, 0)),
+        out_specs=pl.BlockSpec((G, Hq, Cv), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((Hp, 1), jnp.float32),                 # m
-            pltpu.VMEM((Hp, 1), jnp.float32),                 # l
-            pltpu.VMEM((Hp, Cv), jnp.float32),                # acc
+            pltpu.VMEM((Hq, 1), jnp.float32),                 # m
+            pltpu.VMEM((Hq, 1), jnp.float32),                 # l
+            pltpu.VMEM((Hq, Cv), jnp.float32),                # acc
             pltpu.VMEM((2, chunk * block_size, Cx), k_cache.dtype),
             # v buffers shrink to a dummy tile when v aliases k
             # (32 sublanes: the int8 tile, legal for every dtype)
@@ -1189,12 +1234,12 @@ def paged_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             quant_lanes=(C if quantized and quant_sections is None
                          else None),
             v_lanes=v_lanes, quant_sections=quant_sections,
-            coalesce=coalesce, sink_ref=sink_ref)
+            coalesce=coalesce, sink_ref=sink_ref, rows=rows)
 
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Bp, Hp, Cv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((Bp, Hq, Cv), q.dtype),
         interpret=interpret,
         name=name,
     )(block_tables, seq_lens, jnp.asarray(win_lo, jnp.int32), runs,
@@ -1204,9 +1249,9 @@ def paged_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         return out[:B, :H]
     # row h's useful lanes are its kv head's slot; the rest is cross-slot
     # garbage by construction
-    out = out.reshape(Bp, Hp, KVH, Dv)[:B, :H]
+    out = out.reshape(Bp * rows, Hp, KVH, Dv)[:N, :H]
     kh = (jnp.arange(H) // g)[None, :, None, None]
-    return jnp.take_along_axis(out, kh, axis=2)[:, :, 0].reshape(B, H, Dv)
+    return jnp.take_along_axis(out, kh, axis=2)[:, :, 0].reshape(N, H, Dv)
 
 
 def pallas_supported(num_heads: int, num_kv_heads: int, head_dim: int,
@@ -1239,7 +1284,8 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
                     chunk_blocks: int | None = None,
                     v_dim: int | None = None,
                     sink: jax.Array | None = None,
-                    name: str = "paged_attention") -> jax.Array:
+                    name: str = "paged_attention",
+                    rows: int = 1) -> jax.Array:
     """Dispatch: pallas on TPU (block-major streaming kernel, incl. sliding
     windows, soft-capping, and int8 pools w/ in-row per-token scales), XLA
     gather fallback elsewhere and for geometries the kernel can't tile
@@ -1257,12 +1303,16 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
     ``v_dim`` / ``sink`` / ``name``: value heads of another size than the
     keys', a scalar a query head in the softmax's denominator, the Pallas
     call's name (paged_attention_pallas; the XLA form takes the first
-    two)."""
+    two). ``rows`` > 1: several rows a sequence in one pass over its cache,
+    the kernel's alone (paged_attention_pallas): the XLA gather has nothing
+    to share, and its callers hand it a sequence a row."""
     B, H, Dh = q.shape
     # what only a caller with the new geometry passes: every other call
     # reaches the two forms with the arguments it always had
     extra = {k: v for k, v in (("v_dim", v_dim), ("sink", sink))
              if v is not None}
+    if rows > 1:
+        extra["rows"] = rows
     groups = 1
     if k_cache.dtype == jnp.int8:
         if kv_heads is None:
@@ -1282,6 +1332,10 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
                 and pallas_supported(H, KVH, Dh, block_size,
                                      kv_dtype=k_cache.dtype, v_dim=v_dim)
                 else "xla")
+    if rows > 1 and impl not in ("pallas", "pallas_interpret"):
+        raise ValueError(
+            f"rows={rows} is the kernel's form and this call takes the XLA "
+            f"gather: hand it a sequence a row")
     if groups > 1 and impl in ("pallas", "pallas_interpret"):
         raise ValueError(
             f"pallas decode kernel cannot read a tp-grouped int8 pool "

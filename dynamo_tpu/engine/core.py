@@ -976,7 +976,7 @@ class EngineCore:
                     toks, logprobs, draft_logits, kv = \
                         self.model_mod.decode_forward_mtp(
                             unpack_params(params), kv, flat_tokens, flat_pos,
-                            flat_tables, statics, sample)
+                            flat_tables, statics, sample, rows=Tv)
                     toks = toks.reshape(B, Tv)
                     drafts = jnp.argmax(draft_logits, axis=-1).astype(
                         jnp.int32).reshape(B, Tv)
@@ -4221,13 +4221,17 @@ class EngineCore:
         # why none was launched behind this one, where none was) and,
         # beside them, the rows it scored and the drafts it accepted.
         # ctx_tokens / win_tokens count each cached row ONCE a slot,
-        # however many of the slot's rows read it
+        # however many of the slot's rows read it; cache_passes says how
+        # often a read fetched it for them (1 under the kernel, a pass a
+        # row on the XLA gather: the serving module's answer)
         self.flight.record_cycle(
             "decode", K=1, batch_fill=len(applied),
             chained=sum(1 for i, *_ in applied if pending["mask"][i]),
             planned_tokens=len(applied), emitted=emitted, rows=rows,
             accepted=accepted, ctx_tokens=ctx_tokens, sel_tokens=ctx_tokens,
             win_tokens=win_tokens,
+            cache_passes=self.model_mod.decode_cache_passes(
+                self.statics, self.cfg.spec_k + 1),
             win_blocks_live=max(
                 (len(r.win.held) for r in self.slots
                  if r is not None and r.win is not None), default=0),

@@ -1433,3 +1433,11 @@ def engine_cache(cfg: ModelConfig, engine_cfg, dtype, kv_shards: int = 1):
 def prefill_counters(cfg: ModelConfig, bucket: int, rows: int,
                      prompt_len: int) -> dict:
     return {}   # no key of a ``prefill`` flight record is this family's own
+
+
+def decode_cache_passes(statics: ModelStatics, rows: int) -> int:
+    """The passes over a slot's cache a read of a step with ``rows`` rows a
+    slot makes (mimo.decode_cache_passes: the only family with such a
+    step)."""
+    from . import mimo
+    return mimo.decode_cache_passes(statics, rows)

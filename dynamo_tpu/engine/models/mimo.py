@@ -47,7 +47,10 @@ A head padded to 256 lanes would cost 25% more bytes a row for nothing.
 **Reads.** Decode: ``attention.paged_attention`` with ``v_dim`` over the
 whole table (full, Pallas name ``gqa_full_read``) and over a ring view of
 at most ``ring_blocks`` window-pool blocks with ``win_lo`` and the sink
-(window, ``gqa_window_read``). Prefill: a full layer walks its table by key
+(window, ``gqa_window_read``); where several of a step's rows are one slot's
+(a resident drafter's step) the kernel takes them as ONE sequence with
+``rows=`` and fetches the slot's cache once (``_decode_plan``). Prefill: a
+full layer walks its table by key
 blocks of GQA_KEY_BLOCK rows up to the live length and folds each block's
 partial softmax state (``flash_prefill_partial``, ``gqa_full_prefill``); a
 window layer gathers the chunk's own rows and the window - 1 before them
@@ -535,13 +538,17 @@ def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
 
 
 def _decode_plan(kv: KVCache, positions, block_tables,
-                 statics: ModelStatics):
+                 statics: ModelStatics, rows: int = 1):
     """What a decode step's attention blocks share → (slots, slots_s,
     read_full(name), read_window). Both reads are
     ``attention.paged_attention``: the full layers' over the whole table
     (under a Pallas ``name=`` of the caller's), the window layers' over a
     ring view of R window-pool blocks with the window's lower bound and the
-    sink."""
+    sink. ``rows``: how many of the step's rows are one slot's (adjacent
+    positions under one table, slot-major: the shape of the step). Under
+    the kernel they share ONE pass over the slot's cache
+    (``decode_cache_passes``); every row is written into both pools before
+    any read either way."""
     cfg, bsz = statics.cfg, statics.block_size
     cfg_s = cfg.swa_gqa_geometry()
     B = positions.shape[0]
@@ -557,6 +564,18 @@ def _decode_plan(kv: KVCache, positions, block_tables,
     slots_s = view[:, R - 1] * bsz + positions % bsz
     num_blocks = kv["k"].shape[1] // bsz
     win_blocks = kv["win_k"].shape[1] // bsz
+    share = {}
+    if decode_cache_passes(statics, rows) < rows:
+        # the call takes a SLOT's table, length and ring view: its last
+        # row's (that row's ring holds the earlier rows' windows too,
+        # swa_ring_blocks), and every row's own lower bound, moved into that
+        # view's frame: a row's own view starts so many blocks earlier
+        last = slice(rows - 1, None, rows)
+        blk = positions // bsz
+        view_lo = view_lo - (jnp.repeat(blk[last], rows) - blk) * bsz
+        block_tables, seq_lens = block_tables[last], seq_lens[last]
+        view, view_len = view[last], view_len[last]
+        share = {"rows": rows}
 
     def reader(c: ModelConfig, name: str, chunk_blocks: int):
         # under jit so that the kernel is traced and lowered once for all
@@ -565,7 +584,7 @@ def _decode_plan(kv: KVCache, positions, block_tables,
             paged_attention, block_size=bsz, scale=c.head_dim ** -0.5,
             impl=statics.attn_impl, kv_heads=c.num_kv_heads,
             v_dim=c.v_head_dim, coalesce=statics.kv_coalesce,
-            chunk_blocks=chunk_blocks, name=name))
+            chunk_blocks=chunk_blocks, name=name, **share))
 
     def full_reader(name: str):
         full = reader(cfg, name, max(ATTN_CHUNK_BLOCKS, GQA_WAVE_ROWS // bsz))
@@ -592,10 +611,10 @@ def _decode_plan(kv: KVCache, positions, block_tables,
 
 
 def _decode_hidden(params: Params, kv: KVCache, tokens, positions,
-                   block_tables, statics: ModelStatics):
+                   block_tables, statics: ModelStatics, rows: int = 1):
     """→ (the rows' final hidden states [B, D], new kv, the plan)."""
     cfg = statics.cfg
-    plan = _decode_plan(kv, positions, block_tables, statics)
+    plan = _decode_plan(kv, positions, block_tables, statics, rows)
     slots, slots_s, full_reader, read_window = plan
     x = _embed(params, tokens, cfg)
     x, kv_new = walk_layer_kinds(
@@ -694,7 +713,7 @@ def prefill_forward_mtp(params: Params, kv: KVCache, tokens: jax.Array,
 
 def decode_forward_mtp(params: Params, kv: KVCache, tokens: jax.Array,
                        positions: jax.Array, block_tables: jax.Array,
-                       statics: ModelStatics, sample):
+                       statics: ModelStatics, sample, rows: int = 1):
     """``decode_forward`` with the module's tail over the same rows →
     (tokens [N], logprobs [N], draft logits [N, V], new kv). ``sample``:
     logits [N, V] → (tokens, logprobs), the engine's. Row i scores the
@@ -702,10 +721,12 @@ def decode_forward_mtp(params: Params, kv: KVCache, tokens: jax.Array,
     state and the token sampled from it, and its logits are the guess at
     the token AFTER the sampled one. The rows of one slot of a two-row step
     are adjacent positions under the same table: row 1 reads row 0's fresh
-    rows in both pools (written before any read), and the module's too."""
+    rows in both pools (written before any read), and the module's too.
+    ``rows`` says how many a slot has (slot-major; ``_decode_plan``): the
+    three reads then fetch a slot's cache once for all of them."""
     cfg = statics.cfg
     x, kv, plan = _decode_hidden(params, kv, tokens, positions, block_tables,
-                                 statics)
+                                 statics, rows)
     slots, _slots_s, full_reader, _read_window = plan
     toks, logprobs = sample(_logits(params, x, cfg))
     u, kv = _mtp_block(params, kv, x, toks, positions, slots,
@@ -719,3 +740,14 @@ def decode_kernels_tile(cfg: ModelConfig, block_size: int) -> bool:
     return all(pallas_supported(c.num_heads, c.num_kv_heads, c.head_dim,
                                 block_size, v_dim=c.v_head_dim)
                for c in (cfg, cfg.swa_gqa_geometry()))
+
+
+def decode_cache_passes(statics: ModelStatics, rows: int) -> int:
+    """The passes over a slot's cache that a read of a decode step makes
+    when ``rows`` of the step's rows are that slot's: 1 where both reads run
+    as the Pallas kernel (it takes ``rows`` queries a sequence and fetches
+    each wave once for all of them), ``rows`` on the XLA gather, where every
+    row stays a sequence of its own (nothing to share there)."""
+    tiled = (kernel_wanted(statics.attn_impl)
+             and decode_kernels_tile(statics.cfg, statics.block_size))
+    return 1 if tiled or rows < 2 else rows

@@ -291,6 +291,78 @@ def test_two_row_streams_are_the_one_row_streams(served):
     assert out[0][0].kv["k"].shape[0] == 2 and core.kv["k"].shape[0] == 3
 
 
+def test_the_row_path_records_a_pass_a_row(served):
+    """Off the kernel (the CPU's XLA gather, this fixture's 64-lane rows)
+    every row of a slot is a sequence of its own: the ``decode`` record of a
+    two-row step says two passes over a slot's cache."""
+    core = served[1][1][0]
+    rec = [r for r in core.flight.dump() if r["kind"] == "decode"]
+    assert rec and {r["cache_passes"] for r in rec} == {2}
+    assert llama.decode_cache_passes(core.statics, 1) == 1
+    assert not any("cache_passes" in r for r in served[1][0][0].flight.dump())
+
+
+def test_the_kernel_is_handed_a_slots_rows_in_one_call(monkeypatch):
+    """At a width the kernel tiles (128-lane rows) the two-row step hands
+    each of its three reads ONE sequence a slot with ``rows=2``: the last
+    row's table, ring view and length, and a lower bound a row in that
+    view's frame. Held here to the contract of ``rows`` (a call that stands
+    for the rows as sequences of their own under the slot's table, row r
+    seeing R - 1 - r keys fewer: ``tests/test_paged_attention_kernel.py``
+    holds the kernel to the same contract), by which it gives the tokens,
+    the draft logits and the pools of the row path. Three slots: one whose
+    window still starts at the sequence's start (a bound that does not
+    slide with the row), one whose rows lie in one block, one whose second
+    row opens a block, past the window's edge and the ring's wrap."""
+    cfg = ModelConfig.from_hf_config(_hf(head_dim=64, num_hidden_layers=4))
+    bs, M, slots = 16, 4, 3
+    xla = llama.ModelStatics(cfg=cfg, block_size=bs, attn_impl="xla",
+                             table_blocks=M)
+    kernel = dataclasses.replace(xla, attn_impl="pallas_interpret")
+    assert mimo.decode_kernels_tile(cfg, bs)
+    assert (llama.decode_cache_passes(kernel, 2),
+            llama.decode_cache_passes(xla, 2)) == (1, 2)
+    calls, real = [], mimo.paged_attention
+
+    def by_the_contract(q, k, v, tables, lens, *, impl, name, rows=1,
+                        win_lo=None, **kw):
+        calls.append((name, impl, rows, tables.shape[0]))
+        back = jnp.tile(jnp.arange(rows - 1, -1, -1, dtype=jnp.int32),
+                        tables.shape[0])
+        return real(q, k, v, jnp.repeat(tables, rows, axis=0),
+                    jnp.repeat(lens, rows) - back, impl="xla", name=name,
+                    win_lo=win_lo, **kw)
+
+    monkeypatch.setattr(mimo, "paged_attention", by_the_contract)
+    rng = np.random.default_rng(3)
+    draw = lambda shape: jnp.asarray(  # noqa: E731
+        0.05 * rng.standard_normal(shape), jnp.float32)
+    params = {n: draw(shape) for n, shape in llama.param_shapes(cfg).items()}
+    kv = {n: draw(a.shape) for n, a in llama.init_kv_cache(
+        cfg, 16, bs, dtype=jnp.float32).items()}
+    R = mla.swa_ring_blocks(cfg, bs)
+    pos = np.asarray([15, 37, 47], np.int32)       # 15 + 1, 47 + 1: a block
+    tables = 1 + np.stack([rng.permutation(15)[:M + R] for _ in range(slots)])
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, size=2 * slots),
+                         jnp.int32)
+    args = (tokens, jnp.asarray(np.repeat(pos, 2) + [0, 1] * slots),
+            jnp.asarray(np.repeat(tables, 2, axis=0), jnp.int32))
+
+    def sample(logits):
+        return jnp.argmax(logits, -1).astype(jnp.int32), jnp.max(logits, -1)
+
+    got, want = (jax.jit(lambda kv, s=s: mimo.decode_forward_mtp(
+        params, kv, *args, s, sample, rows=2))(kv) for s in (kernel, xla))
+    assert {c for c in calls if c[1] != "xla"} == {
+        (name, "pallas_interpret", 2, slots)
+        for name in ("gqa_full_read", "gqa_window_read", "mtp_full_read")}
+    assert {c[2:] for c in calls if c[1] == "xla"} == {(1, 2 * slots)}
+    assert np.array_equal(got[0], want[0])
+    for g, w in zip(jax.tree.leaves(got[1:]), jax.tree.leaves(want[1:])):
+        assert np.abs(np.asarray(g) - np.asarray(w)).max() < 1e-5 * max(
+            1.0, float(np.abs(np.asarray(w)).max()))
+
+
 # in float32 the engine stands ~1e-5 off the reference, so a breakage that
 # served bf16 logits do not show with room to spare (``FINE``: small by
 # nature under seeded weights) is still told apart here, at a tenth of the
@@ -426,9 +498,11 @@ class Forced:
             return tok, lp, forced(logits[None], tokens[last][None],
                                    (start_pos + last)[None], tok[None])[0], kv
 
-        def decode(params, kv, tokens, positions, tables, statics, sample):
+        def decode(params, kv, tokens, positions, tables, statics, sample,
+                   **rows):
             toks, lps, logits, kv = real["decode_forward_mtp"](
-                params, kv, tokens, positions, tables, statics, sample)
+                params, kv, tokens, positions, tables, statics, sample,
+                **rows)
             return toks, lps, forced(logits, tokens, positions, toks), kv
 
         monkeypatch.setattr(llama, "prefill_forward_mtp", prefill)

@@ -40,9 +40,10 @@ FAMILIES = {
     "tiny-qwen2moe": (llama, {"k", "v"}, set(), set()),
     "tiny-mimo-v2": (llama, {"k", "v", "win_k", "win_v"}, set(), set()),
     # served with its multi-token-prediction module resident (--spec-k 1):
-    # its decode step scores two rows a slot and says so
+    # its decode step scores two rows a slot and says so, and how many
+    # passes over a slot's cache a read made for them
     "tiny-exaone-moe": (llama, {"k", "v", "win_k", "win_v"}, set(),
-                        {"rows", "accepted"}),
+                        {"rows", "accepted", "cache_passes"}),
     "tiny-deepseek-v2": (mla, {"kv"}, set(), set()),
     "tiny-kimi-k2": (mla, {"kv"}, set(), set()),
     "tiny-deepseek-v32": (mla, {"kv", "idx"},

@@ -8,6 +8,8 @@ tables (the reference's lib/llm vendored engines); our block-major layout
 is engine/attention.py's own design.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -131,8 +133,11 @@ def test_v_aliases_k_mode_matches_double_dma():
     lens = rng.integers(0, m * bs + 1, size=(b,))
     lens[0], lens[1] = 0, m * bs
     seq_lens = jnp.asarray(lens, jnp.int32)
+    # 4 sequences a program and waves of 4 blocks (two a full table): a
+    # third of the default tiling's unrolled copies to interpret, and the
+    # aliased read crosses a wave and a padded tail group besides
     kw = dict(block_tables=tables, seq_lens=seq_lens, block_size=bs,
-              scale=0.07, interpret=True)
+              scale=0.07, interpret=True, seqs_per_program=4, chunk_blocks=4)
     a = paged_attention_pallas(q, pool, pool, v_lanes=vl, **kw)
     assert a.shape == (b, h, vl)
     ref = paged_attention_pallas(q, pool, pool, **kw)[..., :vl]
@@ -207,3 +212,92 @@ def test_sectioned_int8_kernel_mode_matches_reference():
     want = np.einsum("bht,btr->bhr", p, k[..., :rank])
     np.testing.assert_allclose(np.asarray(got, np.float32), want,
                                rtol=2e-2, atol=2e-2)  # bf16 q rounding
+
+
+# ---------------------------------------------------------------------------
+# rows > 1: the rows a sequence scores in one step share one pass
+# ---------------------------------------------------------------------------
+
+RBS, RM, RCB = 8, 12, 2            # block 8, <= 96 keys, waves of 16 keys
+# what each sequence of the one call exercises (its length as the LAST row
+# sees it; the window variant reads the last 20 positions of every row, from
+# the sequence's start where it has no more: a bound that does not slide)
+ROW_SEQS = {
+    "boundary_inside_a_block": 13,        # rows at 11, 12 (| 10, 11, 12)
+    "boundary_across_blocks": 17,         # the last row opens a block, a wave
+    "one_wave": 16,
+    "many_waves": 96,                     # six waves, the table's end
+    "shorter_than_rows": 1,               # an idle slot's trash rows
+}                                         # 5 sequences, 2 a program: a
+                                          # padded tail group
+
+
+@functools.cache
+def _rows_case(rows: int, window: bool):
+    """→ (got [B, R, H, Dv], want, live [B, R]): ONE call of the kernel with
+    ``rows`` queries a sequence, and the parent's call with every row a
+    sequence of its own (in XLA, as tier 1 holds the kernel everywhere: the
+    two kernels' outputs were bit-equal when this was written, at four times
+    the seconds). ``window``: a lower bound a row (its last 20 positions)
+    and a sink. Per-block copies: the wave walk is not what ``rows``
+    touches, and interpreting both of its branches doubles the seconds."""
+    rng = np.random.default_rng(100 + rows)
+    nb, b = 40, len(ROW_SEQS)
+    k = jnp.asarray(rng.standard_normal((nb * RBS, C)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((nb * RBS, C)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((b * rows, H, Dh)), jnp.float32)
+    tables = jnp.asarray(rng.integers(1, nb, size=(b, RM)), jnp.int32)
+    lens = jnp.asarray(list(ROW_SEQS.values()), jnp.int32)
+    back = jnp.tile(jnp.arange(rows - 1, -1, -1, dtype=jnp.int32), b)
+    kw = dict(block_size=RBS, scale=Dh ** -0.5)
+    row_lens = jnp.repeat(lens, rows) - back
+    if window:
+        kw["sink"] = jnp.asarray(rng.standard_normal((H,)), jnp.float32)
+        kw["win_lo"] = jnp.maximum(row_lens - 1 - 20, -1)
+    got = paged_attention_pallas(
+        q, k, v, tables, lens, rows=rows, chunk_blocks=RCB,
+        seqs_per_program=2, coalesce=False, interpret=True, **kw)
+    want = paged_attention_xla(q, k, v, jnp.repeat(tables, rows, axis=0),
+                               row_lens, **kw)
+    live = np.asarray(row_lens).reshape(b, rows) > 0
+    shape = (b, rows, H, Dh)
+    return (np.asarray(got).reshape(shape), np.asarray(want).reshape(shape),
+            live)
+
+
+@pytest.mark.parametrize("window", [False, True],
+                         ids=["full", "window_and_sink"])
+@pytest.mark.parametrize("seq", list(ROW_SEQS))
+@pytest.mark.parametrize("rows", [2, 3])
+def test_rows_of_a_sequence_share_one_pass(rows, seq, window):
+    """``rows`` = R queries a sequence (q [B·R, H, Dh], the tables and
+    lengths a SEQUENCE, the last row's; the lower bounds a row) give what
+    the parent's call gives with every row a sequence of its own (tables
+    repeated, each row's own length and lower bound): each wave fetched
+    once, the mask a row's own. A row that sees no key (a length under R: an
+    idle slot) is finite."""
+    got, want, live = _rows_case(rows, window)
+    i = list(ROW_SEQS).index(seq)
+    assert live[i].sum() == min(rows, ROW_SEQS[seq])
+    np.testing.assert_allclose(got[i][live[i]], want[i][live[i]],
+                               rtol=2e-5, atol=2e-5)
+    assert np.isfinite(got[i]).all()
+
+
+def test_rows_are_refused_where_no_read_has_them():
+    """v-aliases-k and int8 pools have no step of several rows a sequence,
+    the XLA gather nothing to share: each says so."""
+    from dynamo_tpu.engine.attention import paged_attention
+    pool = jnp.zeros((64 * 16, 256), jnp.float32)
+    q = jnp.zeros((4, 8, 256), jnp.float32)
+    tables, lens = jnp.zeros((2, 4), jnp.int32), jnp.ones((2,), jnp.int32)
+    kw = dict(block_tables=tables, seq_lens=lens, block_size=16, scale=1.0)
+    with pytest.raises(ValueError, match="rows=2"):
+        paged_attention_pallas(q, pool, pool, v_lanes=128, rows=2,
+                               interpret=True, **kw)
+    with pytest.raises(ValueError, match="whole number"):
+        paged_attention_pallas(q[:3], pool, pool, rows=2, interpret=True,
+                               **kw)
+    with pytest.raises(ValueError, match="a sequence a row"):
+        paged_attention(q, pool, pool, tables, lens, block_size=16,
+                        scale=1.0, impl="xla", rows=2)

@@ -1,10 +1,13 @@
 """K-EXAONE's deviceless builds for a described ``v5e:2x2`` (the helpers and
 fixtures are ``tests/test_tpu_compile.py``'s): the two served programs of a
-resident multi-token-prediction drafter, whole, at the published widths, and
-the three decode reads of its two-row step. A file of its own so that
+resident multi-token-prediction drafter, whole, at the published widths, the
+three decode reads of its two-row step (one pass over a slot's cache for both
+rows), and the paged kernel's traced program at one row a sequence, held to
+what it was before it took ``rows``. A file of its own so that
 ``--dist loadfile`` can give its ~2 minutes to another worker than
 ``test_tpu_compile.py``'s, the longest file of tier 1."""
 
+import hashlib
 import os
 
 import jax
@@ -61,8 +64,10 @@ def test_exaone_served_programs_build_at_the_published_sizes(
     (128 rows through 2 F + 6 S layers, both pools and the module's block),
     int8 weights, 20,480 paged and 2,731 window blocks (a ring of 10: the
     window's nine and the row a chained step may run ahead). Every read is its
-    Pallas kernel under its own name, the module's apart from the model's;
-    weights + pools fill 60% of the chip and the program fits beside them."""
+    Pallas kernel under its own name, the module's apart from the model's,
+    and in the step a call is 64 sequences of two rows each (query tiles of
+    2 x 64 sublanes), not 128 of one; weights + pools fill 60% of the chip
+    and the program fits beside them."""
     cfg, e, core, layout, params, kv = _exaone_shell(monkeypatch)
     place = lambda t: jax.tree.map(lambda x: jax.ShapeDtypeStruct(  # noqa: E731
         x.shape, x.dtype, sharding=one_chip), t)
@@ -90,6 +95,10 @@ def test_exaone_served_programs_build_at_the_published_sizes(
             s((B,), jnp.bool_)).compile()
         names = ("gqa_full_read", "gqa_window_read", "mtp_full_read")
     text = compiled.as_text()
+    if program == "decode_mtp-B64":
+        # the kernels' sparse-slotted queries: [slots, rows x heads, lanes]
+        assert f"bf16[{B},128,1024]" in text
+        assert f"bf16[{2 * B},64,1024]" not in text
     assert all(n in text for n in names), [n for n in names if n not in text]
     m = compiled.memory_analysis()
     held = m.argument_size_in_bytes
@@ -101,13 +110,14 @@ def test_exaone_served_programs_build_at_the_published_sizes(
                                   "gqa_window_read"])
 def test_exaone_two_row_reads_build_at_the_published_sizes(one_chip, read):
     """The three decode reads of the two-row step as the cell serves them:
-    128 rows (two a slot, each a sequence of its own to the kernel), 64
-    query heads over 8 kv heads of 128 lanes, rows of 1,024 | 1,024 lanes.
-    Full and the module's: tables of 449 blocks into the three-layer paged
-    pool of 20,480; window: a ring of 10 blocks of the six-layer pool of
-    2,731 with a lower bound, no sink."""
+    64 sequences of two rows (``rows=2``: a slot's cache fetched once for
+    both, the query tile [G, 2 x 64, 1024]), 64 query heads over 8 kv heads
+    of 128 lanes, rows of 1,024 | 1,024 lanes. Full and the module's: tables
+    of 449 blocks into the three-layer paged pool of 20,480; window: a ring
+    of 10 blocks of the six-layer pool of 2,731 with a lower bound, no
+    sink."""
     from dynamo_tpu.engine.models import mimo
-    rows, bs, H, KVH, d = 128, 16, 64, 8, 128
+    slots, rows, bs, H, KVH, d = 64, 2, 16, 64, 8, 128
     window = read == "gqa_window_read"
     M, layers, blocks, chunk = ((10, 6, 2731, 10) if window else
                                 (449, 3, 20480, mimo.GQA_WAVE_ROWS // bs))
@@ -116,12 +126,55 @@ def test_exaone_two_row_reads_build_at_the_published_sizes(one_chip, read):
         return A.paged_attention(
             q, k, v, tables, lens, block_size=bs, scale=d ** -0.5,
             impl="pallas", kv_heads=KVH, v_dim=d, chunk_blocks=chunk,
-            name=read, **({"win_lo": lo} if window else {}))
+            name=read, rows=rows, **({"win_lo": lo} if window else {}))
 
-    text = _compile(fn, one_chip, ((rows, H, d), jnp.bfloat16),
+    text = _compile(fn, one_chip, ((slots * rows, H, d), jnp.bfloat16),
                     ((layers * blocks * bs, KVH * d), jnp.bfloat16),
                     ((layers * blocks * bs, KVH * d), jnp.bfloat16),
-                    ((rows, M), jnp.int32), ((rows,), jnp.int32),
-                    ((rows,), jnp.int32)).as_text()
+                    ((slots, M), jnp.int32), ((slots,), jnp.int32),
+                    ((slots * rows,), jnp.int32)).as_text()   # a bound a row
     assert read in text
-    assert f"bf16[{rows},{M * bs}," not in text        # no gathered table
+    assert f"bf16[{slots},{rows * H},{KVH * d}]" in text    # the query tile
+    assert f"bf16[{slots * rows},{M * bs}," not in text     # no gathered table
+
+
+# sha256 of the traced program (``jax.make_jaxpr``: the wrapper's ops and the
+# kernel's body, op for op, without source lines) of three ``rows == 1`` calls
+# of the paged kernel, taken on the commit before it learned ``rows``
+ONE_ROW_PROGRAMS = {
+    "llama": (
+        "c37973379029eddb1af83c4cc045623c0b1add545feaf52aaa789f664051c15d",
+        dict(chunk_blocks=4), (32, 128), (1024, 1024)),
+    "mla": (
+        "cbf3c1443d45be3fc1d7d1c238e67d0ffcd794dcbea4bc985d5f1c1b66425023",
+        dict(kv_heads=1, v_lanes=512, chunk_blocks=4), (16, 640), (640, 640)),
+    "mimo": (
+        "364d0d27a4e70544bab64038dc8c9d39b1b337f51e7fdf5731ee6c99f4eed5e9",
+        dict(kv_heads=4, v_dim=128, chunk_blocks=4, name="gqa_window_read"),
+        (16, 192), (768, 512)),
+}
+
+
+@pytest.mark.parametrize("caller", list(ONE_ROW_PROGRAMS))
+def test_one_row_a_sequence_traces_to_the_program_it_was(caller):
+    """``rows`` defaults to 1, and at 1 the call is the program every other
+    caller had: llama's plain read, mla's absorbed decode (v aliases k),
+    mimo's window read (value heads of their own width, a lower bound, a
+    sink, a name). Eight cells' decode steps are this program."""
+    sha, kw, (H, d), (ck, cv) = ONE_ROW_PROGRAMS[caller]
+    B, M, ntok = 4, 8, 64 * 16
+    s = jax.ShapeDtypeStruct
+    args = [s((B, H, d), jnp.bfloat16), s((ntok, ck), jnp.bfloat16),
+            s((ntok, cv), jnp.bfloat16), s((B, M), jnp.int32),
+            s((B,), jnp.int32)]
+    if caller == "mimo":
+        args += [s((B,), jnp.int32), s((H,), jnp.float32)]
+
+    def fn(q, k, v, tables, lens, *lo_sink):
+        extra = dict(zip(("win_lo", "sink"), lo_sink))
+        return A.paged_attention(q, k, v, tables, lens, block_size=16,
+                                 scale=0.1, impl="pallas", **kw, **extra)
+
+    text = str(jax.make_jaxpr(fn)(*args))
+    assert "pallas_call" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == sha
