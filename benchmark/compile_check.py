@@ -13,8 +13,10 @@ file that is not in it yet. The engine is the one the launcher would build
 from the file's ``deployment.flags`` (further launcher flags on this
 command line are appended, so ``--num-kv-blocks 3200`` tries another pool),
 and everything architecture-specific comes from ``EngineCore``'s own
-dispatch: the model module (``kv_lora_rank > 0`` → ``models/mla.py``), its
-parameter tree and pool, and the two step functions that
+dispatch: the model module (``models.module_for``), its parameter tree, the
+arrays of its cache (``module.engine_cache``: the paged pool from the flag,
+a window pool's blocks derived as the engine derives them, per-slot rings
+and state by ``--max-num-seqs``) and the two step functions that
 ``EngineCore._compile_jits`` makes, lowered on shapes only. The attention
 kernels are forced (``attn_impl="pallas"``) and the program's TPU test is
 answered "yes", because code that asks ``jax.devices()`` sees the CPU here.
@@ -36,24 +38,48 @@ sys.path[:0] = [HERE, ROOT]
 
 
 def engine_shell(cfg, engine_cfg):
-    """An ``EngineCore`` with no weights and no pool: the attributes its
-    ``_compile_jits`` reads, set as ``EngineCore.__init__`` sets them on one
-    chip with no mesh, then its own step functions."""
+    """An ``EngineCore`` with no weights and a cache of shapes only: the
+    attributes its ``_compile_jits`` and ``_prefill_table`` read, set as
+    ``EngineCore.__init__`` sets them on one chip with no mesh, then its own
+    step functions. → (core, layout, window-pool blocks); ``core.kv`` is
+    what ``engine_cache`` would build, as shapes.
+
+    A resident drafter stays out of it: under ``--spec-k 1`` a model with a
+    multi-token-prediction module is served by a prefill program with the
+    module's tail and a two-row step (``core.resident_drafter``), and this
+    shell builds the plain one-row programs over the same weights and pools;
+    the served ones' builds are tests/test_tpu_compile_exaone.py -k served."""
     import dataclasses
+    import jax
+    import jax.numpy as jnp
     from dynamo_tpu.engine.core import EngineCore
-    from dynamo_tpu.engine.models import llama, mla
+    from dynamo_tpu.engine.models import llama, module_for
     if engine_cfg.kv_block_size == 0:
         engine_cfg = dataclasses.replace(
             engine_cfg, kv_block_size=engine_cfg.auto_kv_block_size(
                 cfg, engine_cfg.kv_quantization))
     core = object.__new__(EngineCore)
     core.cfg, core.mesh, core.pp = engine_cfg, None, 1
-    core.model_mod = mla if cfg.kv_lora_rank > 0 else llama
+    core.model_mod = module_for(cfg)
+    core.is_hybrid = cfg.is_sambay
+    core.M = engine_cfg.max_blocks_per_seq
     core.statics = llama.ModelStatics(
         cfg=cfg, block_size=engine_cfg.kv_block_size, attn_impl="pallas",
-        kv_coalesce=engine_cfg.kv_contig_alloc)
+        kv_coalesce=engine_cfg.kv_contig_alloc, table_blocks=core.M)
+    beside = {}
+
+    def cache():
+        kv, beside["layout"], beside["win_blocks"] = \
+            core.model_mod.engine_cache(cfg, engine_cfg, jnp.bfloat16, 1)
+        return kv
+    core.kv = jax.eval_shape(cache)
+    layout = beside["layout"]
+    # a window pool's ids ride behind a table's M paged ones: R a decode
+    # table's, M a prefill's
+    core.has_window_pool = layout is not None and layout.window_pool
+    core.R = layout.ring_blocks if core.has_window_pool else 0
     core._compile_jits()
-    return core
+    return core, layout, beside["win_blocks"]
 
 
 def main() -> int:
@@ -99,7 +125,7 @@ def main() -> int:
     if engine_cfg.quantization != "int8":
         raise SystemExit("compile_check builds int8 weights; the "
                          f"deployment asks for {engine_cfg.quantization!r}")
-    core = engine_shell(cfg, engine_cfg)
+    core, layout, win_blocks = engine_shell(cfg, engine_cfg)
     engine_cfg = core.cfg
     blocks, bsz = engine_cfg.num_kv_blocks, engine_cfg.kv_block_size
 
@@ -109,18 +135,22 @@ def main() -> int:
 
     params = jax.eval_shape(lambda: llama.fuse_stacked_matmuls(
         dict(init_params_quantized(cfg, jax.random.PRNGKey(0))), cfg))
-    kv = jax.eval_shape(lambda: core.model_mod.init_kv_cache(
-        cfg, blocks, bsz))
+    kv = core.kv
     size = lambda tree: sum(x.size * x.dtype.itemsize  # noqa: E731
                             for x in jax.tree.leaves(tree))
     report = {"config": opts.config, "layers": cfg.num_layers,
               "model_module": core.model_mod.__name__,
               "weights_bytes": size(params),
               "kv_pool_bytes": size(kv), "num_kv_blocks": blocks,
-              "kv_bytes_per_token": size(kv) // (blocks * bsz),
+              "window_pool_blocks": win_blocks,
+              "cache_bytes": {name: size(x) for name, x in kv.items()},
               "programs": {}}
+    if layout is None:      # one uniform paged pool: a token has one size
+        report["kv_bytes_per_token"] = size(kv) // (blocks * bsz)
     params, kv = placed(params), placed(kv)
-    M = engine_cfg.max_model_len // bsz
+    # the tables as the engine lays them out: _prefill_table's own shape,
+    # and _block_tables' [B, M + R]
+    prefill_table = core._prefill_table([], 0).shape
     B = engine_cfg.max_num_seqs
     i32, f32 = jnp.int32, jnp.float32
 
@@ -130,11 +160,11 @@ def main() -> int:
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     key = s(key.shape, key.dtype)
     jobs = {f"prefill-{b}": (core._prefill_jit, (
-        params, kv, s((b,), i32), s((M,), i32), s((), i32), s((), i32), key,
-        s((), f32), s((), i32), s((), f32)))
+        params, kv, s((b,), i32), s(prefill_table, i32), s((), i32),
+        s((), i32), key, s((), f32), s((), i32), s((), f32)))
         for b in (int(x) for x in opts.buckets.split(",") if x)}
     jobs[f"decode-B{B}"] = (core._decode_jit, (
-        params, kv, s((B,), i32), s((B,), i32), s((B, M), i32),
+        params, kv, s((B,), i32), s((B,), i32), s((B, core.M + core.R), i32),
         s((B,) + key.shape, key.dtype), s((B,), f32), s((B,), i32),
         s((B,), f32)))
     if opts.init:
