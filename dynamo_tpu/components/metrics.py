@@ -194,6 +194,8 @@ _COST_GAUGES = {
     "kv_bytes_per_block": "nv_llm_kv_bytes_per_block",
     "prefill_tok_per_s": "nv_llm_prefill_tok_per_s",
     "kv_block_size": "nv_llm_kv_block_size_tokens",
+    # the per-slot state group's device bytes (0: a cache of rows alone)
+    "kv_state_bytes": "nv_llm_kv_state_bytes",
 }
 
 

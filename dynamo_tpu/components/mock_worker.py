@@ -322,6 +322,8 @@ class MockTokenWorker:
             d["remote_link_rtt_s"] = 1e-3
             d["kv_bytes_per_block"] = 1 << 20
             d["kv_block_size"] = self.block_size
+            # a stateful model's per-slot group: 4 slots of 2 MiB
+            d["kv_state_bytes"] = 4 << 21
             d["prefill_tok_per_s"] = 5e4
             # round 12: a healthy native dataplane (every fetch rides
             # it, zero JSON fallbacks) and a prefill-publish worker

@@ -60,7 +60,7 @@ def _rope_type(raw_rs: Dict[str, Any]) -> str:
 # any other that does, by name)
 MOE_FAMILIES = ("mixtral", "qwen2_moe", "qwen3_moe", "deepseek_v2",
                 "deepseek_v3", "deepseek_v32", "kimi_k2", "dots3_note",
-                "mimo_v2", "exaone_moe")
+                "mimo_v2", "exaone_moe", "kimi_linear")
 
 
 @dataclasses.dataclass
@@ -205,6 +205,18 @@ class ModelConfig:
     norm_on_output: bool = False
     nope_full: bool = False
     mtp_layers: int = 0
+    # kimi_linear (models/kimi_linear.py, docs/hybrid_cache.md part five):
+    # layers whose layer_types entry is "linear_attention" are Kimi Delta
+    # Attention (engine/kda.py): kda_num_heads heads of kda_head_dim key and
+    # value lanes, a gated delta rule over a float32 [heads, dim, dim] state
+    # a slot and layer, q / k / v through depthwise causal convolutions of
+    # kda_conv_kernel taps (kda_num_heads > 0 is what says the model has
+    # them); its "full_attention" layers are mla.py's latent block with a
+    # plain q projection and, with mla_nope, no rotation of the pe lanes
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 0
+    mla_nope: bool = False
     mamba_d_state: int = 0
     mamba_d_conv: int = 0
     mamba_expand: int = 0
@@ -259,6 +271,12 @@ class ModelConfig:
         return self.mamba_d_state > 0
 
     @property
+    def has_kda(self) -> bool:
+        """Kimi-Delta-Attention layers beside latent-attention ones
+        (kimi_linear): models/kimi_linear.py serves it."""
+        return self.kda_num_heads > 0
+
+    @property
     def mamba_d_inner(self) -> int:
         return self.mamba_expand * self.hidden_size
 
@@ -279,6 +297,8 @@ class ModelConfig:
             return cls._from_mimo_v2(cfg)
         if mt == "exaone_moe":
             return cls._from_exaone_moe(cfg)
+        if mt == "kimi_linear":
+            return cls._from_kimi_linear(cfg)
         # a family with no branch here falls through to the llama block:
         # right for its many renamings, wrong for one whose layers keep a
         # recurrent state or come in kinds this parser does not know. It
@@ -296,7 +316,8 @@ class ModelConfig:
                     + ([f"layer_types of kind {', '.join(kinds)}"]
                        if kinds else []))
                 + ", which no model module here reads (state-space layers "
-                "are served for phi4flash only); it is not parsed as llama")
+                "are served for phi4flash, linear-attention layers for "
+                "kimi_linear); it is not parsed as llama")
         if mt.startswith("gemma") and mt not in ("gemma", "gemma2"):
             # gemma3+ has different norms/attention — half-detecting it
             # via the gemma defaults would load garbage silently
@@ -969,6 +990,126 @@ class ModelConfig:
             swa_window=window,
             qk_norm=True, norm_on_output=True, nope_full=True,
             mtp_layers=mtp)
+
+    @classmethod
+    def _from_kimi_linear(cls, cfg: Dict[str, Any]) -> "ModelConfig":
+        """Kimi-Linear's published keys (models/kimi_linear.py): which
+        layers are Kimi Delta Attention and which latent attention by the
+        two 1-based lists of ``linear_attn_config``, the latent block's
+        sizes under DeepSeek's names with ``q_lora_rank`` null and
+        ``mla_use_nope``, the experts under this family's own names
+        (``num_experts``, ``num_experts_per_token``, ``moe_renormalize``,
+        ``moe_router_activation_func``). A cut depth keeps the entries of
+        both lists up to it. What the program does not run is refused by
+        name."""
+        need = ("hidden_size", "num_hidden_layers", "num_attention_heads",
+                "linear_attn_config", "kv_lora_rank", "qk_nope_head_dim",
+                "qk_rope_head_dim", "v_head_dim", "intermediate_size",
+                "moe_intermediate_size", "num_experts",
+                "num_experts_per_token", "first_k_dense_replace",
+                "routed_scaling_factor", "vocab_size")
+        missing = [k for k in need if cfg.get(k) is None]
+        if missing:
+            raise ValueError(f"kimi_linear needs {', '.join(missing)} in its "
+                             f"config (no family's class defaults are this "
+                             f"one's)")
+        n = int(cfg["num_hidden_layers"])
+        lin = dict(cfg["linear_attn_config"])
+        kda = [int(i) for i in lin.get("kda_layers") or () if int(i) <= n]
+        full = [int(i) for i in lin.get("full_attn_layers") or ()
+                if int(i) <= n]
+        problems = []
+        both = sorted(set(kda) & set(full))
+        if both:
+            problems.append(f"linear_attn_config names layer(s) "
+                            f"{', '.join(map(str, both))} in kda_layers AND "
+                            f"in full_attn_layers")
+        absent = sorted(set(range(1, n + 1)) - set(kda) - set(full))
+        if absent or min(kda + full, default=1) < 1:
+            problems.append(f"linear_attn_config's lists do not cover the "
+                            f"layers 1..{n} (missing: "
+                            f"{', '.join(map(str, absent)) or 'none'})")
+        if len(set(kda)) != len(kda) or len(set(full)) != len(full):
+            problems.append("linear_attn_config names a layer twice in one "
+                            "list")
+        if not kda or not full:
+            problems.append("linear_attn_config without a kda layer or "
+                            "without a full-attention layer inside the depth "
+                            "(the plain latent model is deepseek_v3's)")
+        for key in ("num_heads", "head_dim", "short_conv_kernel_size"):
+            if not lin.get(key):
+                problems.append(f"linear_attn_config.{key} is missing")
+        if cfg.get("q_lora_rank"):
+            problems.append("q_lora_rank (the published model has a plain "
+                            "q projection)")
+        if not cfg.get("mla_use_nope", False):
+            problems.append("mla_use_nope false (rotated pe lanes are not "
+                            "this family's)")
+        if cfg.get("rope_scaling"):
+            problems.append("rope_scaling (nothing is rotated)")
+        if str(cfg.get("moe_router_activation_func", "sigmoid")) != "sigmoid":
+            problems.append("a moe_router_activation_func other than sigmoid")
+        if int(cfg.get("num_expert_group") or 1) != 1 or int(
+                cfg.get("topk_group") or 1) != 1:
+            problems.append("num_expert_group / topk_group other than 1")
+        if int(cfg.get("moe_layer_freq") or 1) != 1:
+            problems.append("moe_layer_freq other than 1 (every layer behind "
+                            "the dense prefix holds experts)")
+        if int(cfg.get("num_nextn_predict_layers") or 0):
+            problems.append("num_nextn_predict_layers")
+        if cfg.get("attention_bias"):
+            problems.append("attention_bias")
+        if problems:
+            raise ValueError("kimi_linear is not implemented with: "
+                             + "; ".join(problems))
+        n_experts = int(cfg["num_experts"])
+        n_total = int(cfg.get("num_experts_published") or 0)
+        share = int(cfg.get("expert_share_index") or 0)
+        if n_total and (n_total % n_experts
+                        or not 0 <= share < n_total // n_experts):
+            raise ValueError(
+                f"kimi_linear: num_experts {n_experts} is not a share of "
+                f"num_experts_published {n_total}, or expert_share_index "
+                f"{share} is outside it")
+        kda_set = set(kda)
+        kinds = ["linear_attention" if i in kda_set else "full_attention"
+                 for i in range(1, n + 1)]
+        return cls(
+            model_type="kimi_linear",
+            vocab_size=int(cfg["vocab_size"]),
+            hidden_size=int(cfg["hidden_size"]),
+            intermediate_size=int(cfg["moe_intermediate_size"]),
+            dense_intermediate_size=int(cfg["intermediate_size"]),
+            num_layers=n, num_heads=int(cfg["num_attention_heads"]),
+            num_kv_heads=int(cfg.get("num_key_value_heads")
+                             or cfg["num_attention_heads"]),
+            head_dim=int(cfg["qk_nope_head_dim"])
+            + int(cfg["qk_rope_head_dim"]),
+            max_position_embeddings=int(
+                cfg.get("model_max_length")
+                or cfg.get("max_position_embeddings") or 1048576),
+            rms_norm_eps=float(cfg.get("rms_norm_eps", 1e-5)),
+            rope_theta=float(cfg.get("rope_theta") or 10000.0),
+            tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+            hidden_act=str(cfg.get("hidden_act") or "silu"),
+            q_lora_rank=0, kv_lora_rank=int(cfg["kv_lora_rank"]),
+            qk_nope_head_dim=int(cfg["qk_nope_head_dim"]),
+            qk_rope_head_dim=int(cfg["qk_rope_head_dim"]),
+            v_head_dim=int(cfg["v_head_dim"]), mla_nope=True,
+            num_experts=n_experts,
+            num_experts_total=n_total if n_total != n_experts else 0,
+            expert_share_index=share,
+            num_experts_per_tok=int(cfg["num_experts_per_token"]),
+            moe_norm_topk=bool(cfg.get("moe_renormalize", True)),
+            moe_routing="sigmoid_noaux", n_group=1, topk_group=1,
+            routed_scaling=float(cfg["routed_scaling_factor"]),
+            shared_expert_size=int(cfg.get("num_shared_experts") or 0)
+            * int(cfg["moe_intermediate_size"]),
+            first_k_dense=int(cfg["first_k_dense_replace"]),
+            layer_types=kinds,
+            kda_num_heads=int(lin["num_heads"]),
+            kda_head_dim=int(lin["head_dim"]),
+            kda_conv_kernel=int(lin["short_conv_kernel_size"]))
 
     @classmethod
     def _from_phi4flash(cls, cfg: Dict[str, Any]) -> "ModelConfig":

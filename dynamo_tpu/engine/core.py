@@ -274,9 +274,13 @@ class EngineCore:
                 f"{model_cfg.model_type} is not implemented with: "
                 + "; ".join(refused))
         # the pool row's format (one opaque latent row or heads), and
-        # per-slot state behind the prefill table
+        # per-slot state behind the prefill table: what the cache's layout
+        # says (llm/kv/hybrid.py has_state), whatever the family
         self.is_mla = model_cfg.kv_lora_rank > 0
-        self.is_hybrid = model_cfg.is_sambay
+        layout = self.model_mod.cache_layout(
+            model_cfg, engine_cfg.kv_block_size,
+            jnp.dtype(param_dtype).itemsize)
+        self.is_hybrid = layout is not None and layout.has_state
         if (model_cfg.sliding_window is not None and not self.is_hybrid
                 and engine_cfg.max_model_len <= model_cfg.sliding_window):
             # the window can never bind at this serving length: drop it so
@@ -1504,6 +1508,7 @@ class EngineCore:
             .requests_deadline_exceeded_total,
             kv_bytes_per_block=self.kv_bytes_per_block(),
             kv_block_size=self.cfg.kv_block_size,
+            kv_state_bytes=self.B * self._step_state_bytes // 2,
             prefill_tok_per_s=self.measured_prefill_tok_per_s(),
             trace_dropped_log_lines_total=_tracer.dropped_log_lines,
             loop_lag_ms=self.flight.loop_lag_ms,

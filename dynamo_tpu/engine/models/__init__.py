@@ -5,9 +5,9 @@ architecture.md "What a model family brings"): ``refusals``,
 ``engine_cache`` and ``prefill_counters`` beside its forward passes and
 ``param_shapes`` / ``init_one_param`` / ``init_params``."""
 
-from . import llama, mla, sambay
+from . import kimi_linear, llama, mla, sambay
 
-__all__ = ["llama", "mla", "sambay", "module_for"]
+__all__ = ["kimi_linear", "llama", "mla", "sambay", "module_for"]
 
 
 def module_for(cfg):
@@ -15,6 +15,8 @@ def module_for(cfg):
     ``llama``'s own entry points (ROADMAP D17)."""
     if cfg.is_sambay:
         return sambay
+    if cfg.has_kda:
+        return kimi_linear
     if cfg.kv_lora_rank > 0:
         return mla
     return llama

@@ -652,15 +652,27 @@ OUTPUT_NORM_SEEDED = 0.2
 MTP_HNORM_SEEDED = 0.5
 
 
+# Seeded weights of kimi_linear (ModelConfig.has_kda): SHARE_SEEDED's
+# embedding, and the routed experts' down-projection at a quarter of
+# fan_in^-0.5 (OUTPUT_NORMED_SEEDED's reasoning: only what a flipped choice
+# moves is damped). All 27 layers are held, 26 of them route, and a flip is
+# a held expert's output there or not: at SHARE_SEEDED's half the served
+# program stood 0.15 of the logits' standard deviation off the float32
+# reference on the chip's first probe (PERF.md section 6, PR 54). Attention,
+# the delta-attention block, the dense and the shared MLPs keep fan_in^-0.5.
+KDA_SEEDED = {"embed": 1.0, "moe_down": 0.25}
+
+
 def seeded_std(cfg: ModelConfig, name: str, fan_in: int) -> float:
     """Standard deviation of a --random-weights matrix: fan_in^-0.5, but
-    see SPARSE_SEEDED, MIXED_SEEDED, GQA_MIXED_SEEDED, SHARE_SEEDED and
-    OUTPUT_NORMED_SEEDED."""
+    see SPARSE_SEEDED, MIXED_SEEDED, GQA_MIXED_SEEDED, SHARE_SEEDED,
+    KDA_SEEDED and OUTPUT_NORMED_SEEDED."""
     std = fan_in ** -0.5
     rule = (MIXED_SEEDED if cfg.has_swa_latent
             else OUTPUT_NORMED_SEEDED if cfg.norm_on_output
             else GQA_MIXED_SEEDED if cfg.has_swa_gqa
             else SPARSE_SEEDED if cfg.index_topk > 0
+            else KDA_SEEDED if cfg.has_kda
             else SHARE_SEEDED if cfg.num_experts_total > 0 else {})
     if name == "embed":
         return rule.get("embed", std)

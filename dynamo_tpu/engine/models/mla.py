@@ -291,12 +291,13 @@ def _attn_shapes(cfg: ModelConfig, n: int, prefix: str = "") -> tuple:
 
 
 def layer_kinds(cfg: ModelConfig) -> Tuple[str, ...]:
-    """"F" (attends over the whole context) or "S" (over the window, with
-    the second geometry) for every layer; all "F" without window layers."""
-    if not (cfg.has_swa_latent or cfg.has_swa_gqa):
+    """"F" (attends over the whole context), "S" (over the window, with
+    the second geometry) or "K" (keeps a recurrent state: kimi_linear's
+    delta-attention layers) for every layer; all "F" without either."""
+    if not (cfg.has_swa_latent or cfg.has_swa_gqa or cfg.has_kda):
         return ("F",) * cfg.num_layers
-    return tuple("S" if t == "sliding_attention" else "F"
-                 for t in cfg.layer_types)
+    return tuple({"sliding_attention": "S", "linear_attention": "K"}.get(
+        t, "F") for t in cfg.layer_types)
 
 
 def _n_kind(kinds, kind: str) -> int:
@@ -306,14 +307,26 @@ def _n_kind(kinds, kind: str) -> int:
 
 def layer_plan(cfg: ModelConfig):
     """→ (dense prefix k, the period of the kinds after it, whole periods,
-    the kinds left over): how ``_run_layers_mixed`` walks the layers, e.g.
-    F | F S S S | F S S S -> (1, ("F","S","S","S"), 2, ())."""
+    the kinds left over): how ``walk_layer_kinds`` walks the layers, e.g.
+    F | F S S S | F S S S -> (1, ("F","S","S","S"), 2, ()). ONE rule: the
+    period is the one whose repetition from the first layer after the prefix
+    runs furthest beyond one period of itself (the smallest such); the scan
+    takes its whole periods and what is left is unrolled. A list that repeats
+    to its end, whole or cut (S S F S | S S F), keeps its period; one that
+    ends SHORT of it (kimi_linear's K | K K F K x 6 | K F: the last layer is
+    F where the period says K) keeps it too, with two layers left over, and
+    not the "period" of 23 that alone reaches the end."""
     kinds = layer_kinds(cfg)
     k = cfg.first_k_dense if cfg.num_experts > 0 else 0
     rest = kinds[k:]
-    p = next(p for p in range(1, len(rest) + 1)
-             if all(rest[i] == rest[i % p] for i in range(len(rest))))
-    n = len(rest) // p
+
+    def run(p):         # the length of the longest p-periodic prefix
+        return next((i for i in range(len(rest)) if rest[i] != rest[i % p]),
+                    len(rest))
+
+    p = max(range(1, len(rest) + 1), key=lambda q: (run(q) - q, -q),
+            default=0)
+    n = run(p) // p if p else 0
     return k, rest[:p], n, rest[n * p:]
 
 
@@ -763,8 +776,9 @@ def _latent_rows(lp, hn, positions, cfg: ModelConfig):
     c = rms_norm(c, lp["kv_norm"], cfg.rms_norm_eps)
     if cfg.mla_lora_rescale:
         c = c * (cfg.hidden_size / cfg.kv_lora_rank) ** 0.5
-    inv, att = rope_params(cfg)
-    k_pe = apply_rope_interleaved(k_pe, positions, jnp.asarray(inv), att)
+    if not cfg.mla_nope:     # kimi_linear: the pe lanes are plain key lanes
+        inv, att = rope_params(cfg)
+        k_pe = apply_rope_interleaved(k_pe, positions, jnp.asarray(inv), att)
     return jnp.concatenate([c, k_pe], axis=-1)
 
 
@@ -1109,7 +1123,7 @@ def walk_layer_kinds(params: Params, kv: KVCache, x: jax.Array,
     ``attend``, which is the model's:
 
     attend(kind, hn, pools, ai) -> (what the attention block adds to the
-    stream [N, D], pools): kind "F" or "S", hn the layer's normed input,
+    stream [N, D], pools): kind "F", "S" or "K", hn the layer's normed input,
     pools the cache arrays as the layers before left them, ai the layer's
     index among the layers of its kind (its row of that kind's stacks and
     of that kind's pool)."""
@@ -1157,12 +1171,12 @@ def walk_layer_kinds(params: Params, kv: KVCache, x: jax.Array,
                          kinds[li], lambda hn2, li=li: dense_mlp(hn2, li))
     # a layer's index among its kind: those of its kind before the scan,
     # a period's worth for every period gone by, and its rank in the period
-    before = {kd: _n_kind(kinds[:k], kd) for kd in ("F", "S")}
-    per = {"F": _n_kind(period, "F"), "S": _n_kind(period, "S")}
+    before = {kd: _n_kind(kinds[:k], kd) for kd in ("F", "S", "K")}
+    per = {kd: _n_kind(period, kd) for kd in before}
 
     def run(carry, li0, ai0, some_kinds):
         h, pools = carry
-        seen = {"F": 0, "S": 0}
+        seen = dict.fromkeys(before, 0)
         for j, kind in enumerate(some_kinds):
             h, pools = layer(h, pools, li0 + j, ai0[kind] + seen[kind], kind,
                              lambda hn2, j=j: expert_mlp(hn2, li0 + j - k))
@@ -1443,8 +1457,8 @@ def _dense_chunk(q_nope, q_pe, lp, kv_flat, table_l, start_pos, seq_len,
 
 def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
                     block_table: jax.Array, start_pos: jax.Array,
-                    true_len: jax.Array, statics: ModelStatics
-                    ) -> Tuple[jax.Array, KVCache]:
+                    true_len: jax.Array, statics: ModelStatics,
+                    layers=None) -> Tuple[jax.Array, KVCache]:
     """Same contract as llama.prefill_forward: tokens [T] (padded),
     block_table [M], returns (last-token logits [V], new kv). Supports a
     cached prefix (start_pos > 0 — chunked prefill / prefix reuse): the
@@ -1508,9 +1522,11 @@ def prefill_forward(params: Params, kv: KVCache, tokens: jax.Array,
             params, kv, x, positions, slots, slots_s, cfg, attn, attn_s,
             experts_sharded=statics.sharded, valid_rows=true_len)
     else:
-        x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn,
-                                experts_sharded=statics.sharded,
-                                valid_rows=true_len)
+        # layers: a family that walks kinds of its own (kimi_linear) runs
+        # them with this function's latent read
+        x, kv_new = (layers or _run_layers)(
+            params, kv, x, positions, slots, cfg, attn,
+            experts_sharded=statics.sharded, valid_rows=true_len)
     last = x[jnp.maximum(true_len - 1, 0)]
     return _logits(params, last, cfg), kv_new
 
@@ -1708,7 +1724,8 @@ def latent_wave_blocks(bsz: int) -> int:
 
 def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
                    positions: jax.Array, block_tables: jax.Array,
-                   statics: ModelStatics) -> Tuple[jax.Array, KVCache]:
+                   statics: ModelStatics,
+                   layers=None) -> Tuple[jax.Array, KVCache]:
     """Same contract as llama.decode_forward: tokens [B], positions [B],
     block_tables [B, M] -> (logits [B, V], new kv).
 
@@ -1863,8 +1880,9 @@ def decode_forward(params: Params, kv: KVCache, tokens: jax.Array,
             params, kv, x, positions, slots, slots_s, cfg, attn, attn_s,
             experts_sharded=statics.sharded)
     else:
-        x, kv_new = _run_layers(params, kv, x, positions, slots, cfg, attn,
-                                experts_sharded=statics.sharded)
+        x, kv_new = (layers or _run_layers)(
+            params, kv, x, positions, slots, cfg, attn,
+            experts_sharded=statics.sharded)
     return _logits(params, x, cfg), kv_new
 
 

@@ -257,16 +257,17 @@ def cache_layout(cfg: ModelConfig, block_size: int, dtype_bytes: int = 2):
                      + dtype_bytes * (cfg.mamba_d_conv - 1) * Di))
 
 
-def refusals(cfg: ModelConfig, engine_cfg, mesh) -> list:
-    """What this engine asks for that cannot carry a slot's recurrent state
-    or its window rows yet: the refusal matrix of docs/hybrid_cache.md,
-    read once at engine build. -> the offending options, by name."""
+def state_refusals(engine_cfg, mesh) -> list:
+    """What an engine asks for that cannot carry a slot's recurrent state:
+    the ONE table of the stateful families (this one's Mamba state and
+    window rings; ``models/kimi_linear.py``'s matrix state), the refusal
+    matrix of docs/hybrid_cache.md. -> the offending options, by name."""
     e = engine_cfg
     checks = {
         "--ragged (ragged_forward has no state update)": e.ragged_dispatch,
         "--spec-k (a rejected draft would have advanced the state)":
             e.spec_k > 0,
-        "--kv-quantization (window rows and state have no int8 encoding)":
+        "--kv-quantization (per-slot rows and state have no int8 encoding)":
             e.kv_quantization != "none",
         "--host-kv-blocks / --kv-disk-* / --kv-remote-* (the tiers ship "
         "paged rows only; a block without the state at its boundary "
@@ -274,10 +275,18 @@ def refusals(cfg: ModelConfig, engine_cfg, mesh) -> list:
             e.host_kv_blocks or e.kv_disk_blocks or e.kv_remote_dir),
         "tp/sp/pp/ep/dp meshes (the per-slot arrays have no sharding "
         "rule)": mesh is not None or max(e.tp, e.sp, e.pp, e.ep, e.dp) > 1,
-        "--quantization int4 (the grouped-int4 kernels are unvalidated "
-        "for these projections)": e.quantization.startswith("int4"),
     }
     return [name for name, on in checks.items() if on]
+
+
+def refusals(cfg: ModelConfig, engine_cfg, mesh) -> list:
+    """``state_refusals`` and what is this family's own, read once at
+    engine build. -> the offending options, by name."""
+    bad = state_refusals(engine_cfg, mesh)
+    if engine_cfg.quantization.startswith("int4"):
+        bad.append("--quantization int4 (the grouped-int4 kernels are "
+                   "unvalidated for these projections)")
+    return bad
 
 
 # ---------------------------------------------------------------------------

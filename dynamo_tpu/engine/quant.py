@@ -341,7 +341,14 @@ _LAYER_MATMULS = ("wq", "wk", "wv", "wo", "gate", "up", "down",
                   # norms and biases stay as they are
                   "mlp_gateup", "mlp_down", "ssm_in", "ssm_x", "ssm_dt",
                   "ssm_out", "attn_qkv", "attn_out", "cross_q", "gmu_in",
-                  "gmu_out")
+                  "gmu_out",
+                  # kimi_linear's delta-attention layers (models/
+                  # kimi_linear.py): the fused q|k|v projection and the
+                  # output projection, 37.7 M of a layer's 39.5 M. The
+                  # low-rank pairs (kda_low, kda_fb, kda_gb: the decay and
+                  # the gates stand behind them) stay in the load dtype,
+                  # A_log, dt_bias and the convolution taps float32
+                  "kda_in", "kda_wo")
 # MoE expert tensors [L, E, D, F] → per (L, E, out-channel) scales. For
 # mixtral-class models the experts ARE the weights, so leaving them bf16
 # would forfeit the whole int8 HBM-read win; the router stays full

@@ -219,6 +219,10 @@ def load_params_auto(model_dir: str, cfg: Optional[ModelConfig] = None,
         # phi4flash: its own names and stacks; no mesh serves it yet
         # (sambay.refusals: raised at engine build)
         return load_sambay_params(model_dir, cfg, dtype=dtype)
+    if cfg.has_kda:
+        # kimi_linear: its own names and fused stacks; no mesh serves it
+        # yet (kimi_linear.refusals: raised at engine build)
+        return load_kimi_linear_params(model_dir, cfg, dtype=dtype)
     if mesh is not None:
         return load_params_sharded(model_dir, mesh, cfg, dtype=dtype)
     return load_llama_params(model_dir, cfg, dtype=dtype)
@@ -861,6 +865,186 @@ def save_sambay_hf_style(params: Dict[str, jax.Array], cfg: ModelConfig,
             t = t.T
         elif how == "conv":
             t = t.T[:, None, :]
+        out[tname] = np.ascontiguousarray(t)
+    save_file(out, os.path.join(out_dir, "model.safetensors"))
+
+
+# kimi_linear checkpoint names (the model repository's modelling code, from
+# memory: no network here), per layer under ``model.layers.{i}.``. -> (leaf
+# of engine/models/kimi_linear.py's stacks, how the torch tensor becomes
+# ours, the columns of a fused leaf it fills: a part's index among the
+# parts of ``_KIMI_FUSED``)
+_KIMI_KDA = {
+    "self_attn.q_proj.weight": ("kda_in", _T, 0),
+    "self_attn.k_proj.weight": ("kda_in", _T, 1),
+    "self_attn.v_proj.weight": ("kda_in", _T, 2),
+    "self_attn.q_conv1d.weight": ("kda_conv", "conv", 0),  # [P, 1, K]
+    "self_attn.k_conv1d.weight": ("kda_conv", "conv", 1),
+    "self_attn.v_conv1d.weight": ("kda_conv", "conv", 2),
+    "self_attn.f_a_proj.weight": ("kda_low", _T, 0),
+    "self_attn.g_a_proj.weight": ("kda_low", _T, 1),
+    "self_attn.b_proj.weight": ("kda_low", _T, 2),
+    "self_attn.f_b_proj.weight": ("kda_fb", _T, None),
+    "self_attn.g_b_proj.weight": ("kda_gb", _T, None),
+    "self_attn.g_b_proj.bias": ("kda_gb_bias", None, None),
+    "self_attn.A_log": ("kda_A_log", "flat", None),
+    "self_attn.dt_bias": ("kda_dt_bias", None, None),
+    "self_attn.o_norm.weight": ("kda_onorm", None, None),
+    "self_attn.o_proj.weight": ("kda_wo", _T, None),
+}
+_KIMI_MLA = {
+    "self_attn.q_proj.weight": ("wq", _T, None),
+    "self_attn.kv_a_proj_with_mqa.weight": ("wkv_a", _T, None),
+    "self_attn.kv_a_layernorm.weight": ("kv_norm", None, None),
+    "self_attn.kv_b_proj.weight": ("wkv_b", _T, None),
+    "self_attn.o_proj.weight": ("wo", _T, None),
+}
+_KIMI_NORMS = {"input_layernorm.weight": "ln1",
+               "post_attention_layernorm.weight": "ln2"}
+_KIMI_FLOAT32 = ("kda_A_log", "kda_dt_bias", "kda_conv")
+
+
+def _kimi_fused_parts(cfg: ModelConfig) -> Dict[str, tuple]:
+    """The widths of a fused leaf's parts, in order."""
+    P = cfg.kda_num_heads * cfg.kda_head_dim
+    return {"kda_in": (P, P, P), "kda_conv": (P, P, P),
+            "kda_low": (cfg.kda_head_dim, cfg.kda_head_dim,
+                        cfg.kda_num_heads)}
+
+
+def _kimi_tensor_names(cfg: ModelConfig) -> Dict[str, tuple]:
+    """checkpoint tensor name -> (engine parameter, index in its stack
+    (a tuple: layer, expert), transform, (first column, width) of a fused
+    leaf or None). Experts this chip does not hold and vocabulary rows
+    beyond its slice have no entry: ``load_kimi_linear_params`` passes
+    them over by ``_kimi_elsewhere``."""
+    from .models.mla import layer_kinds
+    names: Dict[str, tuple] = {
+        "model.embed_tokens.weight": ("embed", (), "rows", None),
+        "model.norm.weight": ("final_norm", (), None, None),
+        "lm_head.weight": ("lm_head", (), "head", None)}
+    parts = _kimi_fused_parts(cfg)
+    seen: Dict[str, int] = {}
+    k = cfg.first_k_dense
+    first = cfg.expert_share_index * cfg.num_experts
+    for l, kind in enumerate(layer_kinds(cfg)):
+        i = seen.get(kind, 0)
+        seen[kind] = i + 1
+        pre = f"model.layers.{l}."
+        for sub, leaf in _KIMI_NORMS.items():
+            names[pre + sub] = (f"layers.{leaf}", (l,), None, None)
+        for sub, (leaf, how, part) in (_KIMI_KDA if kind == "K"
+                                       else _KIMI_MLA).items():
+            cols = None
+            if part is not None:
+                widths = parts[leaf]
+                cols = (sum(widths[:part]), widths[part])
+            names[pre + sub] = (f"layers.{leaf}", (i,), how, cols)
+        if l < k:
+            for sub, leaf in (("gate_proj", "dense_gate"),
+                              ("up_proj", "dense_up"),
+                              ("down_proj", "dense_down")):
+                names[f"{pre}mlp.{sub}.weight"] = (
+                    f"layers.{leaf}", (l,), _T, None)
+            continue
+        m = l - k
+        moe = pre + "block_sparse_moe."
+        names[moe + "gate.weight"] = ("layers.router", (m,), _T, None)
+        names[moe + "gate.e_score_correction_bias"] = (
+            "layers.router_bias", (m,), None, None)
+        for e in range(cfg.num_experts):
+            for sub, leaf in (("w1", "moe_gate"), ("w3", "moe_up"),
+                              ("w2", "moe_down")):
+                names[f"{moe}experts.{first + e}.{sub}.weight"] = (
+                    f"layers.{leaf}", (m, e), _T, None)
+        for sub, leaf in (("gate_proj", "sh_gate"), ("up_proj", "sh_up"),
+                          ("down_proj", "sh_down")):
+            names[f"{moe}shared_experts.{sub}.weight"] = (
+                f"layers.{leaf}", (m,), _T, None)
+    return names
+
+
+def _kimi_elsewhere(cfg: ModelConfig, tname: str) -> bool:
+    """A routed expert's tensor that another chip of the share holds."""
+    import re
+    hit = re.search(r"block_sparse_moe\.experts\.(\d+)\.", tname)
+    if not hit or not cfg.num_experts_total:
+        return False
+    e = int(hit.group(1))
+    first = cfg.expert_share_index * cfg.num_experts
+    return (0 <= e < cfg.num_experts_total
+            and not first <= e < first + cfg.num_experts)
+
+
+def load_kimi_linear_params(model_dir: str,
+                            cfg: Optional[ModelConfig] = None,
+                            dtype=jnp.bfloat16) -> Dict[str, jax.Array]:
+    """Load a kimi_linear checkpoint into ``models/kimi_linear.py``'s
+    stacks: the q|k|v projections and convolutions and the low-rank first
+    halves into their fused leaves' columns, this chip's share of the
+    experts (the others' tensors are passed over) and its slice of the
+    vocabulary (the leading rows). A tensor this map does not know, or a
+    parameter the checkpoint lacks, fails loudly: the names are from
+    memory."""
+    from .models.kimi_linear import param_shapes
+    cfg = cfg or ModelConfig.from_model_dir(model_dir)
+    shapes = param_shapes(cfg)
+    names = _kimi_tensor_names(cfg)
+    out = {name: np.zeros(shape, _np_dtype(
+        jnp.float32 if name.rsplit(".", 1)[-1] in _KIMI_FLOAT32
+        else dtype)) for name, shape in shapes.items()}
+    missing = set(names)
+    for tname, tensor in _iter_safetensors(model_dir):
+        if tname not in names:
+            if _kimi_elsewhere(cfg, tname):
+                continue
+            raise ValueError(f"kimi_linear checkpoint tensor {tname!r} has "
+                             f"no place in engine/models/kimi_linear.py's "
+                             f"parameters")
+        name, idx, how, cols = names[tname]
+        t = np.asarray(tensor, np.float32)
+        if how == _T:
+            t = t.T
+        elif how == "conv":
+            t = t[:, 0, :].T
+        elif how == "flat":
+            t = t.reshape(-1)
+        elif how == "rows":
+            t = t[:cfg.vocab_size]
+        elif how == "head":
+            t = t[:cfg.vocab_size].T
+        target = out[name][idx] if idx else out[name]
+        if cols is not None:
+            target = target[..., cols[0]:cols[0] + cols[1]]
+        if target.shape != t.shape:
+            raise ValueError(f"{tname}: shape {t.shape}, the engine holds "
+                             f"{target.shape} for {name}")
+        target[...] = t
+        missing.discard(tname)
+    if missing:
+        raise ValueError(f"kimi_linear checkpoint lacks {len(missing)} "
+                         f"tensor(s), e.g. {sorted(missing)[:3]}")
+    return {name: jnp.asarray(_note_handoff(a)) for name, a in out.items()}
+
+
+def save_kimi_linear_hf_style(params: Dict[str, jax.Array],
+                              cfg: ModelConfig, out_dir: str) -> None:
+    """The inverse of ``load_kimi_linear_params`` (tests): the held share
+    and slice under the checkpoint's names."""
+    from safetensors.numpy import save_file
+    os.makedirs(out_dir, exist_ok=True)
+    out = {}
+    for tname, (name, idx, how, cols) in _kimi_tensor_names(cfg).items():
+        t = np.asarray(params[name], np.float32)
+        t = t[idx] if idx else t
+        if cols is not None:
+            t = t[..., cols[0]:cols[0] + cols[1]]
+        if how in (_T, "head"):
+            t = t.T
+        elif how == "conv":
+            t = t.T[:, None, :]
+        elif how == "flat":
+            t = t.reshape(1, 1, -1, 1)
         out[tname] = np.ascontiguousarray(t)
     save_file(out, os.path.join(out_dir, "model.safetensors"))
 

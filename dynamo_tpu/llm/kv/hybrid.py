@@ -38,6 +38,17 @@ grouped-query rows: K and V arrays of the full layers under the paged ids
 (``kv["k"]``, ``kv["v"]``), K and V arrays of the window layers under the
 window pool's (``kv["win_k"]``, ``kv["win_v"]``), and there the window rows
 are the wider ones (``window_row_bytes``; docs/hybrid_cache.md part three).
+
+``models/kimi_linear.py``'s kimi_linear is the fourth layout: a paged LATENT
+group (the 7 full-attention layers' 640-lane rows under one block id,
+``kv["kv"]``) and a STATE group (20 delta-attention layers, per slot a
+float32 ``[heads, dim, dim]`` matrix and the convolutions' last inputs:
+``kv["kda"]``, ``kv["conv"]``; 2.17 MB a slot and layer at the published
+widths, whatever the context), and no window. The state group is
+``models/sambay.py``'s kind, so ``has_state`` keeps reuse off here too: no
+prefix hit, no block announced to the router (docs/hybrid_cache.md part
+five). ``bytes_by_kind`` sizes it as the first layout: a window group of no
+layers holds no bytes.
 """
 
 from __future__ import annotations
@@ -133,7 +144,8 @@ class HybridCacheLayout:
 
     def bytes_by_kind(self, num_blocks: int, max_num_seqs: int) -> dict:
         """Device bytes of each kind for a pool of ``num_blocks`` and
-        ``max_num_seqs`` slots."""
+        ``max_num_seqs`` slots: sambay's three kinds, or kimi_linear's
+        paged latent rows and matrix state (no window layers: 0 bytes)."""
         block = self.block_size * self.row_bytes
         if self.window_pool:
             raise ValueError("a window pool is sized by window_pool_blocks")

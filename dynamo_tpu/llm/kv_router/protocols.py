@@ -78,6 +78,12 @@ class ForwardPassMetrics:
     # retune there. Zero on old payloads (crossover then unknowable for
     # that worker — it simply drops out of the fleet median).
     kv_block_size: int = 0
+    # device bytes of the per-slot STATE group of a cache with a layout
+    # (llm/kv/hybrid.py: a slot's recurrent state of every stateful layer,
+    # times the slots; phi4flash's Mamba state, kimi_linear's float32
+    # matrices: 2.78 GB at 64 slots): memory that no block count shows and
+    # that neither tier nor fabric ships. 0 where the cache is rows alone
+    kv_state_bytes: int = 0
     # runtime/netstore.py client retry counter (bounded jittered retry;
     # a rising rate means the discovery daemon link is flapping)
     netstore_retries_total: int = 0

@@ -112,7 +112,9 @@ class ModelDeploymentCard:
                 cfg = json.load(f)
         card.model_info = ModelInfo(
             model_type=cfg.get("model_type", "llama"),
-            context_length=int(cfg.get("max_position_embeddings", 4096)),
+            # kimi_linear states its limit as model_max_length alone
+            context_length=int(cfg.get("max_position_embeddings")
+                               or cfg.get("model_max_length") or 4096),
             vocab_size=int(cfg.get("vocab_size", tk.vocab_size)),
             eos_token_ids=specials["eos_token_ids"],
             bos_token_id=specials["bos_token_id"],

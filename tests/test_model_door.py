@@ -25,7 +25,7 @@ import pytest
 
 from dynamo_tpu.engine import models
 from dynamo_tpu.engine.config import ModelConfig
-from dynamo_tpu.engine.models import llama, mla, sambay
+from dynamo_tpu.engine.models import kimi_linear, llama, mla, sambay
 
 FIXTURE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark", "fixtures")
@@ -54,6 +54,8 @@ FAMILIES = {
                         {"key_waves", "key_run_waves"}),
     "tiny-phi4flash": (sambay, {"k", "v", "win_k", "win_v", "ssm", "conv"},
                        set(), set()),
+    "tiny-kimi-linear": (kimi_linear, {"kv", "kda", "conv"}, {"kda_chunks"},
+                         set()),
 }
 
 # the keys every family's records carry, as at PR 48 (the parent of the PR
@@ -76,7 +78,7 @@ SOMETIMES = {"drain"}
 PROMPT_TOKENS, NEW_TOKENS = 24, 4
 
 
-def test_the_fixtures_are_the_eight_the_table_names():
+def test_the_fixtures_are_those_the_table_names():
     found = {os.path.basename(p)[:-5]
              for p in glob.glob(os.path.join(FIXTURE_DIR, "tiny-*.json"))}
     assert found == set(FAMILIES)
@@ -191,9 +193,13 @@ def test_a_served_request_keeps_its_record_keys_and_replays(built):
         assert set(r) - SOMETIMES == DECODE_KEYS | decode_own
     # the families' counters, by the arithmetic PERF.md section 3 states
     n = PROMPT_TOKENS
-    assert prefill[0]["scan_tokens"] == (n if module is sambay else 0)
+    stateful = module in (sambay, kimi_linear)
+    assert prefill[0]["scan_tokens"] == (n if stateful else 0)
     assert prefill[0]["key_tokens"] == (
-        n * (n + 1) // 2 if module is mla and not prefill_own else 0)
-    if prefill_own:
+        n * (n + 1) // 2 if module is kimi_linear
+        or (module is mla and not prefill_own) else 0)
+    if "dsa_blocks" in prefill_own:
         assert 0 < prefill[0]["dsa_blocks_run"] <= prefill[0]["dsa_blocks"]
+    if "kda_chunks" in prefill_own:
+        assert prefill[0]["kda_chunks"] >= 1
     assert differs == []
