@@ -27,16 +27,30 @@ tokens. So every exponent taken here is <= 0.
       S_C = Diag(exp(gamma_C)) S_0 + (K * exp(gamma_C - gamma))^T U
 
   A and B are built in sub-chunks of ``SUB`` tokens: between sub-chunks the
-  decay goes through the sub-chunk's first boundary (both factors <= 1), and
-  inside one it is taken pairwise, one diagonal at a time. ``(I + A)^-1`` is
-  forward substitution: row by row inside the ``SUB`` x ``SUB`` diagonal
-  blocks, block by block across them; never a Neumann series (its terms
-  grow combinatorially when keys are alike). Everything that does not
-  depend on S_0 is computed for all chunks at once in XLA; the walk over
-  the chunks, which carries S in float32 from chunk to chunk in VMEM, is
-  the Pallas kernel named ``kda_chunk``. A row with ``g = 0`` and
-  ``beta = 0`` leaves the state as it is: the caller zeroes both past
-  ``true_len``, so the state that comes out is the one at ``true_len``.
+  decay goes through the later sub-chunk's first boundary (both factors
+  <= 1), and inside one it is taken pairwise, one exponent
+  ``min(gamma_i - gamma_j, 0)`` for A and B. ``(I + A)^-1`` is forward
+  substitution: row by row inside the ``SUB`` x ``SUB`` diagonal blocks,
+  block row by block row across them; never a Neumann series (its terms
+  grow combinatorially when keys are alike). Two Pallas kernels, and no
+  XLA form of any of it:
+
+  - ``kda_prepare`` builds everything that does not depend on S_0, a
+    (chunk, head) tile a program, all programs independent. It holds the
+    tile's q, k, v, g ``[C, d]`` in VMEM (read as column blocks of the
+    rows the caller holds, ``[T, H * d]``: no head-major copy), and in
+    VMEM gamma, A, B, the blocks' inverses and the solve; it writes what
+    the walk takes: ``W = (I + A)^-1 diag(beta) K exp(gamma)``,
+    ``Uv = (I + A)^-1 diag(beta) V``, ``Q exp(gamma)``, B, and
+    ``K^T exp(gamma_C - gamma)`` with the chunk's decay ``exp(gamma_C)``
+    beside it.
+  - ``kda_chunk`` walks a head's chunks in order and holds S ``[dk, dv]``
+    float32 in VMEM from chunk to chunk: ``U = Uv - W S``,
+    ``O = Q~ S + B U``, ``S = exp(gamma_C) S + K^^T U``.
+
+  A row with ``g = 0`` and ``beta = 0`` leaves the state as it is: the
+  caller zeroes both past ``true_len``, so the state that comes out is the
+  one at ``true_len``.
 * ``kda_step`` (decode): one token for each of B slots, every head of a
   slot a program, the state array ``[layers * B, H, dk, dv]`` updated in
   place (aliased) at a layer offset that arrives as a prefetched scalar.
@@ -45,7 +59,7 @@ tokens. So every exponent taken here is <= 0.
   arrive with the channels on sublanes (``[B, dk, H]``), so that scaling
   the rows of S needs no transpose in the kernel.
 
-Off the TPU both kernels run interpreted (``interpret=True``), like
+Off the TPU the kernels run interpreted (``interpret=True``), like
 ``ssm.py``'s: the CPU tests run these bodies. ``kda_recurrence`` is the
 token-by-token form, for tests.
 """
@@ -87,106 +101,119 @@ def kda_recurrence(q, k, v, g, beta, s0):
 # ---------------------------------------------------------------------------
 
 
-def _shift(x, d: int):
-    """x [..., SUB, dk] moved d rows down its sub-chunk (row i holds what
-    row i - d held; the first d rows hold zeros)."""
-    if d == 0:
-        return x
-    pad = [(0, 0)] * x.ndim
-    pad[-2] = (d, 0)
-    return jnp.pad(x, pad)[..., :x.shape[-2], :]
+def _prepare_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref,
+                    w_ref, uv_ref, qd_ref, b_ref, kx_ref):
+    """One chunk of one head, everything of it that does not depend on the
+    carried state (module docstring): q, k, v, g tiles [C, d], the chunk's
+    beta for every head [C, H] -> W, Uv, Q~ [C, d], B [C, C] and, in one
+    [d, 2 C] tile, K^^T beside the chunk's decay exp(gamma_C), a column
+    repeated over C lanes: a [d, C] float32 tile takes 128 lanes in HBM and
+    in VMEM whatever C, so the column rides in lanes that are there."""
+    C, n = CHUNK, CHUNK // SUB
+    f = functools.partial(jax.lax.dot_general, preferred_element_type=_F32,
+                          precision=_HI)
+    mm = lambda a, b: f(a, b, (((1,), (0,)), ((), ())))         # noqa: E731
+    mm_t = lambda a, b: f(a, b, (((1,), (1,)), ((), ())))       # noqa: E731
+    q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
+    d = k.shape[-1]
+    heads = jax.lax.broadcasted_iota(jnp.int32, beta_ref.shape[1:], 1)
+    beta = jnp.sum(jnp.where(heads == pl.program_id(1), beta_ref[0], 0.0),
+                   axis=1, keepdims=True)                           # [C, 1]
+    ri = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    gam = mm((ci <= ri).astype(_F32), g)       # running sum of g, exact
+    last = gam[C - 1:C]                                             # gamma_C
+
+    def row_of(a, j):
+        """Row j of every sub-chunk of a [C, w], over its sub-chunk."""
+        a = a.reshape(n, SUB, a.shape[-1])
+        return jnp.broadcast_to(a[:, j:j + 1], a.shape).reshape(C, -1)
+
+    # inside a sub-chunk, pairwise, a column (the partner j) at a time: one
+    # exponent for A and B; above the diagonal it is clamped and masked
+    local = ci - ri // SUB * SUB          # column inside the row's sub-chunk
+    below = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0) % SUB
+    kb = beta * k
+    b_in = jnp.zeros((C, C), _F32)
+    a_cols = []
+    for j in range(SUB):
+        e = row_of(k, j) * jnp.exp(jnp.minimum(gam - row_of(gam, j), 0.0))
+        a_cols.append(jnp.where(
+            below > j, jnp.sum(kb * e, axis=1, keepdims=True), 0.0))  # [C, 1]
+        b_in = jnp.where(local == j, jnp.sum(q * e, axis=1, keepdims=True),
+                         b_in)
+    # between sub-chunks, through the later one's first boundary: every
+    # earlier row decayed up to it, the later rows from it, both <= 1
+    a_off, b_off = [jnp.zeros((SUB, C), _F32)], [jnp.zeros((SUB, C), _F32)]
+    for s in range(1, n):
+        lo = s * SUB
+        edge = gam[lo - 1:lo]
+        into = jnp.exp(gam[lo:lo + SUB] - edge)
+        rows = jnp.concatenate([k[lo:lo + SUB] * into,
+                                q[lo:lo + SUB] * into], axis=0)
+        upto = k[:lo] * jnp.exp(jnp.minimum(edge - gam[:lo], 0.0))
+        off = mm_t(rows, jnp.concatenate(
+            [upto, jnp.zeros((C - lo, d), _F32)], axis=0))      # [2 SUB, C]
+        a_off.append(off[:SUB])
+        b_off.append(off[SUB:])
+    a_off = beta * jnp.concatenate(a_off, axis=0)
+    b_ref[0, 0] = (jnp.concatenate(b_off, axis=0)
+                   + jnp.where(ci <= ri, b_in, 0.0))
+    # (I + A)^-1 of the diagonal blocks, all n side by side, by forward
+    # substitution: once row j of a block's inverse is final, every later
+    # row i takes its -A[i, j] X[j] (the column sweep of the row recurrence
+    # X[i] = e_i - sum_{j<i} A[i, j] X[j])
+    X = (ci == ri).astype(_F32)
+    for j in range(SUB - 1):
+        X = X - a_cols[j] * row_of(X, j)
+    # and across them a block row at a time: row block s of (I + A)^-1 is
+    # X_s (E_s - A[s, :s] T[:s]), its two products taken apart so that only
+    # the second waits for the rows above
+    M = mm(X, a_off)
+    T = X[:SUB]
+    for lo in range(SUB, C, SUB):
+        above = jnp.concatenate([T, jnp.zeros((C - lo, C), _F32)], axis=0)
+        T = jnp.concatenate(
+            [T, X[lo:lo + SUB] - mm(M[lo:lo + SUB], above)], axis=0)
+    grow = jnp.exp(gam)
+    Y = mm(T, jnp.concatenate([beta * k * grow, beta * v], axis=1))
+    w_ref[0, 0] = Y[:, :d]
+    uv_ref[0, 0] = Y[:, d:]
+    qd_ref[0, 0] = q * grow
+    kx_ref[0, 0] = jnp.concatenate(
+        [k * jnp.exp(last - gam), jnp.broadcast_to(jnp.exp(last), (C, d))],
+        axis=0).T
 
 
-def _inside(x, k, gam):
-    """Pairwise-decayed products inside every sub-chunk: x, k, gam
-    [..., n, SUB, dk] -> [..., n, SUB, SUB] with entry (i, j) =
-    sum_c x_i k_j exp(gam_i - gam_j) for j <= i, 0 above the diagonal. One
-    diagonal (distance d = i - j) at a time: every exponent is <= 0."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, (SUB, SUB), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (SUB, SUB), 1)
-    out = jnp.zeros(x.shape[:-1] + (SUB,), _F32)
-    for d in range(SUB):
-        kd, gd = _shift(k, d), _shift(gam, d)
-        # rows i < d have no partner: their shifted k is zero
-        diag = jnp.sum(x * kd * jnp.exp(jnp.minimum(gam - gd, 0.0)), -1)
-        out = out + jnp.where(rows - cols == d, diag[..., None], 0.0)
-    return out
+def _prepare(q, k, v, g, beta, *, interpret: bool):
+    """q, k, g: [N, C, H * dk]; v: [N, C, H * dv]; beta: [N, C, H], float32,
+    as the caller holds them (a head's tile is a column block) ->
+    chunk-major (W, Uv, Q~, B, [K^^T | dC]): ``_prepare_kernel``."""
+    N, C, H = beta.shape
+    dk, dv = k.shape[-1] // H, v.shape[-1] // H
+    tile = lambda d: pl.BlockSpec((1, C, d), lambda n, h: (n, 0, h))  # noqa: E731
+    out = lambda *shape: pl.BlockSpec(                          # noqa: E731
+        (1, 1) + shape, lambda n, h: (n, h, 0, 0))
+    shapes = [(C, dk), (C, dv), (C, dk), (C, C), (dk, 2 * C)]
+    return pl.pallas_call(
+        _prepare_kernel,
+        grid=(N, H),
+        in_specs=[tile(dk), tile(dk), tile(dv), tile(dk),
+                  pl.BlockSpec((1, C, H), lambda n, h: (n, 0, 0))],
+        out_specs=[out(*s) for s in shapes],
+        out_shape=[jax.ShapeDtypeStruct((N, H) + s, _F32) for s in shapes],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="kda_prepare",
+    )(q, k, v, g, beta)
 
 
-def _decayed_products(q, k, g, beta):
-    """q, k, g: [H, N, C, dk]; beta: [H, N, C] -> (A strictly lower,
-    B lower with its diagonal, both [H, N, C, C]: module docstring; gamma,
-    the running sum of g inside each chunk)."""
-    H, N, C, dk = k.shape
-    n = C // SUB
-    gam = jnp.cumsum(g, axis=2)
-    sub = lambda a: a.reshape(H, N, n, SUB, a.shape[-1])      # noqa: E731
-    gs, ks, qs = sub(gam), sub(k), sub(q)
-    # the boundary a sub-chunk's rows decay from: gamma before its first row
-    edge = jnp.concatenate([jnp.zeros_like(gs[:, :, :1, -1]),
-                            gs[:, :, :-1, -1]], axis=2)        # [H, N, n, dk]
-    into = jnp.exp(gs - edge[..., None, :])                    # <= 1
-    rows = jnp.concatenate([ks * into, qs * into], axis=3)     # [.., 2SUB, dk]
-    # every earlier row decayed up to that boundary; rows at or past it are
-    # masked below, their exponent clamped so that nothing overflows
-    upto = jnp.exp(jnp.minimum(edge[:, :, :, None, :]
-                               - gam[:, :, None, :, :], 0.0))  # [H,N,n,C,dk]
-    cols = k[:, :, None] * upto
-    off = jnp.einsum("hnsid,hnsjd->hnsij", rows, cols, precision=_HI)
-    before = (jnp.arange(C)[None, None, :]
-              < (jnp.arange(n) * SUB)[:, None, None])          # [n, 1, C]
-    off = jnp.where(before, off, 0.0)
-    a_off = off[:, :, :, :SUB].reshape(H, N, C, C)
-    b_off = off[:, :, :, SUB:].reshape(H, N, C, C)
-    # the diagonal blocks, pairwise
-    blk = jnp.eye(n, dtype=_F32)[:, None, :, None]             # [n,1,n,1]
-
-    def spread(d):       # [H, N, n, SUB, SUB] -> block diagonal [H, N, C, C]
-        return (d[:, :, :, :, None, :] * blk).reshape(H, N, C, C)
-
-    a_in = _inside(ks, ks, gs)
-    strict = jnp.tril(jnp.ones((SUB, SUB), _F32), -1)
-    A = (a_off + spread(a_in * strict)) * beta[..., None]
-    B = b_off + spread(_inside(qs, ks, gs))
-    return A, B, gam
-
-
-def _unit_lower_inverse(A):
-    """(I + A)^-1 for strictly lower A [..., C, C] by forward substitution:
-    inside each SUB x SUB diagonal block a row at a time, across the blocks
-    a block row at a time."""
-    C = A.shape[-1]
-    n = C // SUB
-    lead = A.shape[:-2]
-    blocks = A.reshape(lead + (n, SUB, n, SUB))
-    diag = jnp.stack([blocks[..., i, :, i, :] for i in range(n)], -3)
-    eye = jnp.broadcast_to(jnp.eye(SUB, dtype=_F32), diag.shape)
-
-    def row(i, X):
-        # row i of the inverse: e_i - A[i, :i] X[:i]; rows >= i of A[i] are 0
-        a = jax.lax.dynamic_slice_in_dim(diag, i, 1, axis=-2)
-        new = (jax.lax.dynamic_slice_in_dim(eye, i, 1, axis=-2)
-               - jnp.matmul(a, X, precision=_HI))
-        return jax.lax.dynamic_update_slice_in_dim(X, new, i, axis=-2)
-
-    dinv = jax.lax.fori_loop(1, SUB, row, eye)     # [..., n, SUB, SUB]
-    eye_c = jnp.eye(C, dtype=_F32)
-    rows = []
-    for i in range(n):
-        lo = i * SUB
-        rhs = jnp.broadcast_to(eye_c[lo:lo + SUB], lead + (SUB, C))
-        if i:
-            done = jnp.concatenate(rows, axis=-2)              # [.., lo, C]
-            rhs = rhs - jnp.matmul(A[..., lo:lo + SUB, :lo], done,
-                                   precision=_HI)
-        rows.append(jnp.matmul(dinv[..., i, :, :], rhs, precision=_HI))
-    return jnp.concatenate(rows, axis=-2)
-
-
-def _walk_kernel(w_ref, uv_ref, qd_ref, b_ref, kt_ref, dc_ref, s0_ref,
+def _walk_kernel(w_ref, uv_ref, qd_ref, b_ref, kx_ref, s0_ref,
                  o_ref, s_ref, acc_ref):
     """One chunk of one head: U = Uv - W S; O = Qd S + B U;
-    S = dC * S + Kt U, S float32 in VMEM across the chunks of a head."""
+    S = dC * S + Kt U, S float32 in VMEM across the chunks of a head;
+    [Kt | dC] as ``_prepare_kernel`` writes them."""
     @pl.when(pl.program_id(1) == 0)
     def _():
         acc_ref[...] = s0_ref[0]
@@ -196,7 +223,8 @@ def _walk_kernel(w_ref, uv_ref, qd_ref, b_ref, kt_ref, dc_ref, s0_ref,
     S = acc_ref[...]
     U = uv_ref[0, 0] - dot(w_ref[0, 0], S)
     o_ref[0, 0] = dot(qd_ref[0, 0], S) + dot(b_ref[0, 0], U)
-    S = dc_ref[0, 0] * S + dot(kt_ref[0, 0], U)
+    S = (kx_ref[0, 0, :, CHUNK:CHUNK + 1] * S
+         + dot(kx_ref[0, 0, :, :CHUNK], U))
     acc_ref[...] = S
 
     @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
@@ -218,29 +246,20 @@ def kda_chunk(q, k, v, g, beta, s0, *, interpret: bool = False):
                       for a in (q, k, v, g))
         beta = jnp.pad(beta, ((0, pad), (0, 0)))
     N = (T + pad) // C
-    # head-major, chunked: [H, N, C, d]
-    hm = lambda a: jnp.moveaxis(                                # noqa: E731
-        a.astype(_F32).reshape(N, C, H, -1), 2, 0)
-    q, k, v, g = hm(q), hm(k), hm(v), hm(g)
-    beta = jnp.moveaxis(beta.astype(_F32).reshape(N, C, H), 2, 0)
-    A, B, gam = _decayed_products(q, k, g, beta)
-    Tm = _unit_lower_inverse(A)
-    last = gam[:, :, -1:, :]                                    # gamma_C
-    grow = jnp.exp(gam)
-    W = jnp.matmul(Tm, beta[..., None] * k * grow, precision=_HI)
-    Uv = jnp.matmul(Tm, beta[..., None] * v, precision=_HI)
-    Kt = jnp.swapaxes(k * jnp.exp(last - gam), -1, -2)          # [H,N,dk,C]
-    dC = jnp.broadcast_to(jnp.exp(jnp.swapaxes(last, -1, -2)),
-                          (H, N, dk, dv))
+    # chunked as the caller holds the rows: [N, C, H * d], no copy
+    rows = lambda a: a.astype(_F32).reshape(N, C, -1)           # noqa: E731
+    W, Uv, Qd, B, Kx = _prepare(rows(q), rows(k), rows(v), rows(g),
+                                rows(beta), interpret=interpret)
     blk = lambda *shape: pl.BlockSpec(                          # noqa: E731
-        (1, 1) + shape, lambda h, n: (h, n, 0, 0))
+        (1, 1) + shape, lambda h, n: (n, h, 0, 0))
     head = pl.BlockSpec((1, dk, dv), lambda h, n: (h, 0, 0))
     o, S = pl.pallas_call(
         _walk_kernel,
         grid=(H, N),
-        in_specs=[blk(C, dk), blk(C, dv), blk(C, dk), blk(C, C), blk(dk, C),
-                  blk(dk, dv), head],
-        out_specs=[blk(C, dv), head],
+        in_specs=[blk(C, dk), blk(C, dv), blk(C, dk), blk(C, C),
+                  blk(dk, 2 * C), head],
+        out_specs=[pl.BlockSpec((1, 1, C, dv), lambda h, n: (h, n, 0, 0)),
+                   head],
         out_shape=[jax.ShapeDtypeStruct((H, N, C, dv), _F32),
                    jax.ShapeDtypeStruct((H, dk, dv), _F32)],
         scratch_shapes=[pltpu.VMEM((dk, dv), _F32)],
@@ -248,7 +267,7 @@ def kda_chunk(q, k, v, g, beta, s0, *, interpret: bool = False):
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="kda_chunk",
-    )(W, Uv, q * grow, B, Kt, dC, s0.astype(_F32))
+    )(W, Uv, Qd, B, Kx, s0.astype(_F32))
     return jnp.moveaxis(o, 0, 2).reshape(N * C, H, dv)[:T], S
 
 

@@ -3,11 +3,14 @@ Kimi Delta Attention against the token-by-token recurrence, interpreted on
 the CPU (the kernels' own bodies). Builds for the chip:
 ``tests/test_tpu_compile_kimi_linear.py``."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dynamo_tpu.engine import kda
 from dynamo_tpu.engine.kda import (CHUNK, kda_chunk, kda_recurrence,
                                    kda_step)
 
@@ -48,11 +51,24 @@ def test_chunk_form_is_the_recurrence(T):
     _close(S, S2)
 
 
-@pytest.mark.parametrize("g_min", [-20.0, -5.0, -1e-3, 0.0])
+def _by_channel(args):
+    """The decay kept in every other key channel of head 0 and in the
+    first quarter of head 1's, none in the rest."""
+    q, k, v, g, b, s0 = args
+    kept = jnp.stack([jnp.arange(D) % 2 == 0, jnp.arange(D) < D // 4])
+    return q, k, v, jnp.where(kept[None], g, 0.0), b, s0
+
+
+@pytest.mark.parametrize("g_min", [-20.0, -5.0, -1e-3, 0.0, "by channel"])
 def test_chunk_form_holds_under_strong_and_no_decay(g_min):
     """g down to -20 a token: exp(-gamma_j) would overflow float32 after
-    five tokens; every exponent taken is <= 0, so nothing does."""
-    args = _inputs(160, seed=3, g_min=g_min)
+    five tokens; every exponent taken is <= 0, so nothing does. "by
+    channel": -20 a token in some key channels of a head and none in the
+    others, which one decay a head (DeltaNet's) cannot express."""
+    if g_min == "by channel":
+        args = _by_channel(_inputs(2 * CHUNK, seed=3, g_min=-20.0))
+    else:
+        args = _inputs(160, seed=3, g_min=g_min)
     o, S = kda_recurrence(*args)
     o2, S2 = _chunk(*args)
     assert bool(jnp.isfinite(o2).all()) and bool(jnp.isfinite(S2).all())
@@ -98,6 +114,51 @@ def test_rows_past_true_len_leave_the_state_alone(true_len):
                                   g[:true_len], b[:true_len], s0)
     _close(o[:true_len], o_ref)
     _close(S, S_ref)
+
+
+def _matrices_f64(q, k, v, g, beta):
+    """One head's chunk, float64 numpy, as the module docstring writes the
+    matrices: q, k, v, g [C, d]; beta [C]."""
+    q, k, v, g, beta = (np.asarray(a, np.float64) for a in (q, k, v, g, beta))
+    gam = np.cumsum(g, axis=0)
+    decay = np.exp(np.minimum(gam[:, None] - gam[None], 0.0))    # [i, j, c]
+    A = beta[:, None] * np.tril(np.einsum("ic,jc,ijc->ij", k, k, decay), -1)
+    B = np.tril(np.einsum("ic,jc,ijc->ij", q, k, decay))
+    T = np.linalg.inv(np.eye(len(A)) + A)
+    return {"W": T @ (beta[:, None] * k * np.exp(gam)),
+            "Uv": T @ (beta[:, None] * v), "Qd": q * np.exp(gam), "B": B,
+            "Kt": (k * np.exp(gam[-1] - gam)).T, "dC": np.exp(gam[-1])}
+
+
+@functools.lru_cache(maxsize=None)
+def _prepared(decay):
+    """``kda_prepare``'s outputs for two chunks of two heads, once a kind
+    of decay, with the inputs it was given."""
+    args = _inputs(2 * CHUNK, seed=21, g_min=-3.0)
+    if decay == "by channel":
+        args = _by_channel(args)
+    W, Uv, Qd, B, Kx = (np.asarray(a) for a in kda._prepare(
+        *(a.reshape(2, CHUNK, -1) for a in args[:5]), interpret=True))
+    return args, {"W": W, "Uv": Uv, "Qd": Qd, "B": B, "Kt": Kx[..., :CHUNK],
+                  "dC": Kx[..., CHUNK], "dC over its lanes": Kx[..., CHUNK:]}
+
+
+@pytest.mark.parametrize("decay", ["every channel", "by channel"])
+@pytest.mark.parametrize("name", ["W", "Uv", "Qd", "B", "Kt", "dC"])
+def test_prepare_builds_the_chunk_matrices(name, decay):
+    """The six things the walk takes, each tile [chunk, head] against a
+    float64 build of the same matrices; the chunk's decay repeated over
+    the lanes it shares K^^T's tile with."""
+    (q, k, v, g, b, _), got = _prepared(decay)
+    for n in range(2):
+        for h in range(H):
+            rows = slice(n * CHUNK, (n + 1) * CHUNK)
+            want = _matrices_f64(q[rows, h], k[rows, h], v[rows, h],
+                                 g[rows, h], b[rows, h])[name]
+            _close(got[name][n, h], want, 1e-5)
+            if name == "dC":
+                full = got["dC over its lanes"][n, h]
+                assert (full == full[:, :1]).all()
 
 
 @pytest.mark.parametrize("layer", [0, 2])
