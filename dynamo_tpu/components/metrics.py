@@ -127,6 +127,12 @@ _TRACE_GAUGES = {
     "trace_dropped_log_lines_total": "nv_llm_trace_dropped_log_lines_total",
     "loop_lag_ms": "nv_llm_engine_loop_lag_ms",
     "loop_lag_max_ms": "nv_llm_engine_loop_lag_max_ms",
+    # the build log's totals: programs_built_total rising on a worker that
+    # is serving means a step recompiled, and the stall is the rise of the
+    # second
+    "programs_built_total": "nv_llm_engine_programs_built_total",
+    "program_build_seconds_total":
+        "nv_llm_engine_program_build_seconds_total",
 }
 
 # remote (G4) fleet KV fabric (llm/kv/remotestore.py + fabric.py):

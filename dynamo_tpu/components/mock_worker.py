@@ -388,6 +388,9 @@ class MockTokenWorker:
             d["trace_dropped_log_lines_total"] = served // 3
             d["loop_lag_ms"] = 0.4
             d["loop_lag_max_ms"] = 2.5
+            # a warm start: 140 programs read from the cache in 48 s
+            d["programs_built_total"] = 140
+            d["program_build_seconds_total"] = 48.0
             d["netstore_retries_total"] = 0
         if eng is not None and not d.get("disagg_stream_layers_total"):
             # round 15: synthetic streaming-handoff gauges (docs/

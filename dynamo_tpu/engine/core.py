@@ -1511,7 +1511,7 @@ class EngineCore:
             kv_state_bytes=self.B * self._step_state_bytes // 2,
             prefill_tok_per_s=self.measured_prefill_tok_per_s(),
             trace_dropped_log_lines_total=_tracer.dropped_log_lines,
-            loop_lag_ms=self.flight.loop_lag_ms,
+            loop_lag_ms=self.flight.loop_lag_ms, **self.flight.metrics_kw(),
             loop_lag_max_ms=self.flight.loop_lag_max_ms,
             **tier_kw,
             request_active_slots=active,

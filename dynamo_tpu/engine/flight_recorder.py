@@ -28,6 +28,14 @@ Pieces:
   ``nv_llm_engine_loop_lag_ms`` gauge.
 - A process-global registry (weak, keyed by name) so the HTTP
   ``/debug`` endpoint can enumerate recorders without plumbing.
+- :class:`BuildLog` (``BUILD_LOG``, process-global like JAX's own
+  listeners) — every XLA program the process builds, from JAX's
+  monitoring events: one entry a program with its Python trace, its
+  lowering and its compile-or-cache-load times, whether the persistent
+  cache had it, and the loop's phase. Each entry is also a ``build``
+  record in every live recorder's ring, and every cycle record carries
+  the log's running totals, so a step that recompiles while serving is
+  seen from inside (docs/observability.md "The build log").
 - The ``trace/`` KV-store key layout + worker-side watch loop behind
   ``llmctl trace dump``: the CLI writes the control key, every watching
   worker publishes its ring under its lease, the CLI collects.
@@ -39,6 +47,7 @@ import asyncio
 import contextlib
 import itertools
 import logging
+import threading
 import time
 import weakref
 from collections import deque
@@ -46,8 +55,8 @@ from typing import Dict, List, Optional
 
 logger = logging.getLogger("dynamo_tpu.engine.flight")
 
-__all__ = ["FlightRecorder", "PhaseClock", "PHASES",
-           "register_recorder", "all_recorders",
+__all__ = ["FlightRecorder", "PhaseClock", "PHASES", "BuildLog", "BUILD_LOG",
+           "register_recorder", "all_recorders", "logged_build",
            "trace_control_key", "trace_dump_key", "watch_trace_dump_loop",
            "TRACE_PREFIX"]
 
@@ -138,12 +147,133 @@ class PhaseClock:
         return out
 
 
+class BuildLog:
+    """Every XLA program this process builds, as JAX reports it.
+
+    JAX 0.9 fires three ``jax.monitoring`` duration events a program, each
+    with its name: ``jaxpr_trace_duration`` (``fun_name='decode_k'``), then
+    ``jaxpr_to_mlir_module_duration`` and ``backend_compile_duration``
+    (``fun_name='jit(decode_k)'``), and inside the last the plain events
+    ``cache_hits`` / ``cache_misses`` of the persistent cache. A program
+    that is built fires none of them again, so the log costs nothing in
+    steady state. An entry closes when its ``backend_compile_duration``
+    arrives (a compilation, or the cache's retrieval).
+
+    A jit traced inside a jit reports its own trace first and inside the
+    outer's duration, and lowering rules trace helpers of their own before
+    the lowering's event: an entry's ``trace_ms`` is the LAST trace event
+    under the name of the program that lowers, never a sum; a lowering
+    with no such trace (JAX kept the jaxpr) reads 0. Builds are matched up
+    per thread; the totals only grow."""
+
+    TRACE = "/jax/core/compile/jaxpr_trace_duration"
+    LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    COMPILE = "/jax/core/compile/backend_compile_duration"
+    CACHE = {"/jax/compilation_cache/cache_hits": "hit",
+             "/jax/compilation_cache/cache_misses": "miss"}
+
+    def __init__(self, capacity: int = 2048):
+        self.entries: deque = deque(maxlen=capacity)
+        self.built = 0               # programs built
+        self.built_trace_ms = 0.0    # of built_ms: Python trace + lowering
+        self.built_ms = 0.0          # all three stages
+        self.cache_misses = 0
+        self._installed = False
+        self._lock = threading.Lock()
+        self._open = threading.local()   # the build this thread has open
+        self._recorders: "weakref.WeakValueDictionary[int, FlightRecorder]" \
+            = weakref.WeakValueDictionary()
+
+    def install(self) -> None:
+        """Start listening to JAX's build events. Idempotent; called where
+        an engine is about to be built, never at import."""
+        with self._lock:
+            if self._installed:
+                return
+            self._installed = True
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(self.on_duration)
+        monitoring.register_event_listener(self.on_event)
+
+    def attach(self, recorder: "FlightRecorder") -> None:
+        """``recorder`` gets a ``build`` record for every entry closed
+        while it lives."""
+        self._recorders[id(recorder)] = recorder
+
+    def _mine(self) -> dict:
+        """The build the calling thread has open."""
+        try:
+            return self._open.build
+        except AttributeError:
+            self._open.build = {"traces": {}, "lowered": ("", 0.0),
+                                "cache": "none"}
+            return self._open.build
+
+    def on_event(self, event: str, **_kw) -> None:
+        cache = self.CACHE.get(event)
+        if cache is not None:
+            self._mine()["cache"] = cache
+
+    def on_duration(self, event: str, secs: float, fun_name: str = "",
+                    **_kw) -> None:
+        if event == self.TRACE:
+            self._mine()["traces"][fun_name] = secs
+        elif event == self.LOWER:
+            self._mine()["lowered"] = (fun_name, secs)
+        elif event == self.COMPILE:
+            mine = self._mine()
+            del self._open.build
+            program = fun_name[4:-1] if fun_name.startswith("jit(") \
+                else fun_name
+            lowered, lower_s = mine["lowered"]
+            self._close(program, mine["traces"].get(program, 0.0),
+                        lower_s if lowered == fun_name else 0.0, secs,
+                        mine["cache"])
+
+    def _close(self, program: str, trace_s: float, lower_s: float,
+               compile_s: float, cache: str) -> None:
+        recorders = list(self._recorders.values())
+        entry = {"program": program,
+                 "trace_ms": round(1e3 * trace_s, 3),
+                 "lower_ms": round(1e3 * lower_s, 3),
+                 "compile_ms": round(1e3 * compile_s, 3),
+                 "host_ms": round(1e3 * (trace_s + lower_s + compile_s), 3),
+                 "cache": cache}
+        with self._lock:
+            self.built += 1
+            self.built_trace_ms += 1e3 * (trace_s + lower_s)
+            self.built_ms += 1e3 * (trace_s + lower_s + compile_s)
+            if cache == "miss":
+                self.cache_misses += 1
+            # the newest engine's phase; "init" while none lives
+            self.entries.append(dict(
+                entry, t=time.time(), phase=recorders[-1].clock.running
+                if recorders else "init"))
+        for recorder in recorders:
+            recorder.record("build", phase=recorder.clock.running, **entry)
+
+    def totals(self) -> dict:
+        return {"built": self.built,
+                "built_ms": round(self.built_ms, 3),
+                "built_trace_ms": round(self.built_trace_ms, 3),
+                "cache_misses": self.cache_misses}
+
+    def costliest(self, n: int = 5) -> List[dict]:
+        """The ``n`` entries the log still holds that cost the host most."""
+        return sorted(self.entries, key=lambda e: -e["host_ms"])[:n]
+
+
+BUILD_LOG = BuildLog()
+
+
 class FlightRecorder:
     """Bounded ring of per-dispatch records + loop-lag probe."""
 
     def __init__(self, capacity: int = 512,
                  lag_probe_interval: float = 0.5):
         self.clock = PhaseClock()
+        BUILD_LOG.install()      # an engine built directly is seen from here
+        BUILD_LOG.attach(self)
         self._ring: deque = deque(maxlen=capacity)
         self.capacity = capacity
         self.records_total = 0
@@ -163,13 +293,17 @@ class FlightRecorder:
         """One ``decode`` / ``ragged`` / ``verify`` record, closing the
         clock's cycle: the phase split, ``admits`` / ``admit_tokens`` /
         ``yield_iters``, ``device_ms`` (the cycle's ``wait``: what the loop
-        blocked on the device) and ``host_gap_ms`` (the rest of the
-        harvest-to-harvest cycle)."""
+        blocked on the device), ``host_gap_ms`` (the rest of the
+        harvest-to-harvest cycle) and the build log's running totals
+        ``built`` / ``built_ms`` / ``built_trace_ms``, read as a counter is:
+        two records' difference is what was built between them."""
         split = self.clock.close_cycle()
         cycle_ms = split.pop("cycle_ms")
         self.record(kind, **fields, device_ms=split["wait_ms"],
                     host_gap_ms=round(cycle_ms - split["wait_ms"], 3),
-                    **split)
+                    **split, built=BUILD_LOG.built,
+                    built_ms=round(BUILD_LOG.built_ms, 3),
+                    built_trace_ms=round(BUILD_LOG.built_trace_ms, 3))
 
     def dump(self, last: Optional[int] = None) -> List[dict]:
         out = list(self._ring)
@@ -183,7 +317,16 @@ class FlightRecorder:
                 "ring": len(self._ring), "capacity": self.capacity,
                 "kinds": kinds,
                 "loop_lag_ms": round(self.loop_lag_ms, 3),
-                "loop_lag_max_ms": round(self.loop_lag_max_ms, 3)}
+                "loop_lag_max_ms": round(self.loop_lag_max_ms, 3),
+                **BUILD_LOG.totals(),
+                "costliest_builds": BUILD_LOG.costliest()}
+
+    def metrics_kw(self) -> dict:
+        """The build log's totals as ``ForwardPassMetrics`` fields
+        (``nv_llm_engine_programs_built_total`` and
+        ``nv_llm_engine_program_build_seconds_total``)."""
+        return {"programs_built_total": BUILD_LOG.built,
+                "program_build_seconds_total": BUILD_LOG.built_ms / 1e3}
 
     # ------------------------------------------------------------- lag probe
     def start_lag_probe(self) -> None:
@@ -224,6 +367,26 @@ def register_recorder(recorder: FlightRecorder,
 
 def all_recorders() -> Dict[str, FlightRecorder]:
     return dict(_REGISTRY)
+
+
+def logged_build(construct, *args, **kwargs):
+    """``construct(*args, **kwargs)`` — an ``EngineCore`` — with the build
+    log listening from before the constructor runs, so that the random
+    weights' init and quantise programs are in it; then one ``engine_build``
+    record in the new engine's ring: the constructor's wall time
+    (``host_ms``) beside what the log counted over it, i.e. its programs and
+    the rest (pool allocation, host work). ``launch/run.py``
+    ``build_jax_core`` builds through here. It lives in this file because
+    a line added to ``launch/run.py`` above ``run_http``'s ``thread_main``
+    moves the compile-cache key of every prefill program (that frame is in
+    their kernels' source locations: PERF.md section 6, PR 56)."""
+    BUILD_LOG.install()
+    before, t0 = BUILD_LOG.totals(), time.monotonic()
+    core = construct(*args, **kwargs)
+    core.flight.record(
+        "engine_build", host_ms=round(1e3 * (time.monotonic() - t0), 3),
+        **{k: round(v - before[k], 3) for k, v in BUILD_LOG.totals().items()})
+    return core
 
 
 # ---------------------------------------------------------------------------

@@ -620,6 +620,9 @@ async def _trace_cmd(runtime, args) -> int:
               f"/{fl.get('records_total', 0)}  "
               f"loop_lag={fl.get('loop_lag_ms', 0):.1f}ms "
               f"(max {fl.get('loop_lag_max_ms', 0):.1f}ms)  "
+              f"built={fl.get('built', 0)} "
+              f"({fl.get('built_ms', 0) / 1e3:.1f}s, "
+              f"{fl.get('cache_misses', 0)} compiled)  "
               f"traces={tr.get('completed', 0)} "
               f"log_dropped={tr.get('dropped_log_lines', 0)}")
         for r in d.get("records", []):

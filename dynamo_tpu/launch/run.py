@@ -345,11 +345,11 @@ async def build_engine(args, out: str, runtime):
 
 
 def build_jax_core(args):
-    """Construct the (possibly sharded) EngineCore from CLI flags. Every
-    rank of a multi-host engine calls this with identical flags, which is
-    what makes the leader's and followers' device state bit-identical."""
+    """The (possibly sharded) EngineCore from CLI flags. Every rank of a multi-
+    host engine calls this with the same flags: bit-identical device state."""
     from ..engine.config import ModelConfig
     from ..engine.core import EngineCore
+    from ..engine.flight_recorder import logged_build
     if not args.model_path:
         raise SystemExit("out=jax needs --model-path")
     try:
@@ -375,7 +375,7 @@ def build_jax_core(args):
     if not args.random_weights:
         from ..engine.weights import load_params_auto
         params = load_params_auto(args.model_path, model_cfg, mesh=mesh)
-    return EngineCore(model_cfg, ecfg, params=params, mesh=mesh)
+    return logged_build(EngineCore, model_cfg, ecfg, params=params, mesh=mesh)
 
 
 async def run_follower_rank(args, out: str) -> None:

@@ -182,6 +182,16 @@ class ForwardPassMetrics:
     disagg_stream_fallbacks_total: int = 0
     disagg_stream_overlap_ratio: float = 0.0
     disagg_stream_layers: int = 0
+    # the build log (engine/flight_recorder.py BuildLog; appended): XLA
+    # programs this worker's process has built, from the compiler or the
+    # persistent cache, and the host seconds their trace, lowering and
+    # compile-or-load took. Both only grow, and all of it should happen
+    # before the worker serves: a rise of the first on a serving worker is
+    # the alert "a step recompiled" (a prefill bucket, a defrag copy's
+    # block count or a seed met for the first time stalls every stream
+    # for as long as the second rose). Zeros on old payloads.
+    programs_built_total: int = 0
+    program_build_seconds_total: float = 0.0
 
     def to_dict(self) -> dict:
         # every field is a scalar; dataclasses.asdict would deep-copy

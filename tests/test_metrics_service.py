@@ -131,6 +131,9 @@ async def test_http_exposition(daemon):
         # (per-worker gauges), plus the collector's latency histograms
         assert "nv_llm_trace_dropped_log_lines_total" in body
         assert "nv_llm_engine_loop_lag_ms" in body
+        # ... and the build log's totals (a rise while serving: a recompile)
+        assert "nv_llm_engine_programs_built_total" in body
+        assert "nv_llm_engine_program_build_seconds_total" in body
         assert "nv_llm_trace_ttft_seconds" in body
     finally:
         if runner is not None:

@@ -70,7 +70,9 @@ DECODE_KEYS = {
     "ctx_tokens", "sel_tokens", "win_tokens", "win_blocks_live",
     "state_bytes", "device_ms", "host_gap_ms", "sweep_ms", "complete_ms",
     "admit_ms", "build_ms", "dispatch_ms", "wait_ms", "post_ms", "yield_ms",
-    "admits", "admit_tokens", "yield_iters"}
+    "admits", "admit_tokens", "yield_iters",
+    # the build log's running totals, on every cycle record since PR 56
+    "built", "built_ms", "built_trace_ms"}
 # a decode record says why no successor was launched behind it, where none
 # was: present on some cycles only
 SOMETIMES = {"drain"}

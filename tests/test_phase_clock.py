@@ -94,8 +94,14 @@ def test_record_cycle_carries_the_split_and_counts_admits():
     assert rec["kind"] == "decode" and rec["batch_fill"] == 3
     assert rec["admits"] == 2 and rec["device_ms"] == rec["wait_ms"] >= 2.0
     assert "cycle_ms" not in rec
+    # the split, the counts, the two sums and the build log's three totals
+    assert set(rec) == {"kind", "t", "K", "batch_fill", "device_ms",
+                        "host_gap_ms", "admits", "admit_tokens",
+                        "yield_iters", "built", "built_ms",
+                        "built_trace_ms"} | {f"{p}_ms" for p in PHASES}
     fr.record_cycle("decode", K=1, batch_fill=3)
     assert fr.dump()[-1]["admits"] == 0
+    assert fr.dump()[-1]["built"] >= rec["built"]
 
 
 def test_close_cycle_returns_and_resets_the_admitted_tokens():

@@ -124,6 +124,17 @@ async def test_engine_loop_and_handlers_share_the_serving_thread(
         out = await asyncio.to_thread(_complete, args.http_port, 4)
         assert out["usage"]["completion_tokens"] == 4
         assert seen and set(seen) == {"http-serve"}
+        # the build log: the launcher's record of the constructor, and the
+        # programs the first request built, by the loop's phase
+        records = core.flight.dump()
+        (engine_build,) = [r for r in records if r["kind"] == "engine_build"]
+        assert engine_build["host_ms"] >= engine_build["built_ms"] >= 0
+        built = {r["program"]: r["phase"] for r in records
+                 if r["kind"] == "build"}
+        assert built["prefill"] == "admit" and built["decode_k"] == "dispatch"
+        decode = [r for r in records if r["kind"] == "decode"]
+        assert decode[0]["built"] >= engine_build["built"] + 2
+        assert core.metrics().programs_built_total == decode[-1]["built"]
     finally:
         task.cancel()
         await asyncio.gather(task, return_exceptions=True)

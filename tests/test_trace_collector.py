@@ -323,7 +323,7 @@ async def test_llmctl_trace_dump_collects_flight_recorder(daemon, capsys):
         assert rc == 0
         out = capsys.readouterr().out
         assert "decode" in out and "prefill" in out
-        assert "loop_lag" in out
+        assert "loop_lag" in out and "built=" in out
         # a namespace nobody serves times out politely
         rc = await llmctl.amain(["--runtime-server", daemon.address,
                                  "trace", "dump", "nobody",
