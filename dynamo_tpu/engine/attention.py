@@ -710,26 +710,32 @@ def _make_wave_dma(block_tables_ref, runs_ref, k_hbm, v_hbm,
     double-buffer slot; ``nb`` the sequence's valid block count (tail
     clamp for the per-block path)."""
 
-    def block_copies(sq, ci, slot, nb):
-        """Per-block copies of sequence `sq`'s chunk `ci` into buffer
-        `slot` — 2*chunk (k and v), or chunk in v-aliases-k mode
-        (reconstructed identically at wait time; all on one
-        semaphore)."""
-        copies = []
-        for j in range(chunk):                 # static unroll
+    def block_copies(op, sq, ci, slot, nb):
+        """Start or wait the per-block copies of sequence `sq`'s chunk
+        `ci` into buffer `slot` — 2*chunk (k and v), or chunk in
+        v-aliases-k mode; the wait rebuilds the descriptor the start
+        issued, all on one semaphore. One traced body, not a Python
+        unroll (every wave_dma site is traced at every start of a served
+        process, and a wave is 16-64 blocks); the lowering unrolls it,
+        because the scalar core issues descriptors from a rolled loop
+        7-50% slower a fragmented wave (2x in index_scores; PERF.md §6,
+        PR 57)."""
+        def one_block(j, carry):
             bi = ci * chunk + j
             bi = jax.lax.select(bi < nb, bi, 0)  # clamp tail
-            blk = block_tables_ref[sq, bi]
-            copies.append(pltpu.make_async_copy(
-                k_hbm.at[pl.ds(blk * block_size, block_size), :],
-                k_bufs.at[slot, pl.ds(j * block_size, block_size), :],
-                sems.at[slot]))
+            src = pl.ds(block_tables_ref[sq, bi] * block_size, block_size)
+            dst = pl.ds(pl.multiple_of(j * block_size, block_size),
+                        block_size)
+            getattr(pltpu.make_async_copy(
+                k_hbm.at[src, :], k_bufs.at[slot, dst, :],
+                sems.at[slot]), op)()
             if v_lanes is None:                # v aliases k otherwise
-                copies.append(pltpu.make_async_copy(
-                    v_hbm.at[pl.ds(blk * block_size, block_size), :],
-                    v_bufs.at[slot, pl.ds(j * block_size, block_size), :],
-                    sems.at[slot]))
-        return copies
+                getattr(pltpu.make_async_copy(
+                    v_hbm.at[src, :], v_bufs.at[slot, dst, :],
+                    sems.at[slot]), op)()
+            return carry
+
+        jax.lax.fori_loop(0, chunk, one_block, 0, unroll=True)
 
     def run_copies(sq, ci, slot):
         """The coalesced form of one wave: the chunk blocks are
@@ -753,8 +759,7 @@ def _make_wave_dma(block_tables_ref, runs_ref, k_hbm, v_hbm,
         either way the semaphore balances: one coalesced copy carries
         the same byte count as the chunk per-block copies)."""
         if not coalesce:
-            for c in block_copies(sq, ci, slot, nb):
-                getattr(c, op)()
+            block_copies(op, sq, ci, slot, nb)
             return
         contig = runs_ref[sq, ci] > 0
 
@@ -765,8 +770,7 @@ def _make_wave_dma(block_tables_ref, runs_ref, k_hbm, v_hbm,
 
         @pl.when(~contig)
         def _():
-            for c in block_copies(sq, ci, slot, nb):
-                getattr(c, op)()
+            block_copies(op, sq, ci, slot, nb)
 
     return wave_dma
 
@@ -872,10 +876,10 @@ def _paged_attn_kernel(block_tables_ref, seq_lens_ref, win_lo_ref,
     against the zero-padded query is identical to the full-precision
     layout.
 
-    Each grid program handles G = seqs_per_program sequences (static
-    unroll): per-program fixed costs (q/o block pipelining, grid step
-    dispatch) measured ~150 us per kernel call at B=128 on v5e — ~2.4
-    ms/step over 16 layers — and amortize G-fold.
+    Each grid program handles G = seqs_per_program sequences (a loop on
+    the device over one traced body): per-program fixed costs (q/o block
+    pipelining, grid step dispatch) measured ~150 us per kernel call at
+    B=128 on v5e — ~2.4 ms/step over 16 layers — and amortize G-fold.
 
     The DMA pipeline crosses sequence AND program boundaries: scratch
     persists over the grid, so each sequence's LAST wave prefetches the
@@ -924,8 +928,11 @@ def _paged_attn_kernel(block_tables_ref, seq_lens_ref, win_lo_ref,
     def _():
         wave_ref[0] = 0
 
-    for s in range(G):                         # static unroll over the
-        sq = pb * G + s                        # program's sequence group
+    def sequence(s, carry):
+        """One sequence of the program's group: a loop on the device, so
+        the body (its one-wave path, its wave loop and seven wave_dma
+        sites) is traced once a program and not once a sequence."""
+        sq = pb * G + s
         num_blocks, num_chunks, start_ci = seq_shape(sq)
         seq_len = seq_lens_ref[sq]
         if rows == 1:
@@ -959,15 +966,13 @@ def _paged_attn_kernel(block_tables_ref, seq_lens_ref, win_lo_ref,
             pred_started = jnp.bool_(False)
 
         @pl.when((start_ci < num_chunks) & ~pred_started)
-        def _(start_ci=start_ci, p0=p0, sq=sq, num_blocks=num_blocks):
+        def _():
             # empty range: an unwaited start would leak semaphore signal
             # into the next sequence's waves
             wave_dma("start", sq, start_ci, jax.lax.rem(p0, 2),
                      num_blocks)
 
-        def wave_scores(ci, slot, *, sq=sq, num_chunks=num_chunks,
-                        num_blocks=num_blocks, seq_len=seq_len,
-                        win_lo=win_lo, qm=qm):
+        def wave_scores(ci, slot):
             """DMA bookkeeping + masked scores for wave `ci`: start the
             next wave (or the successor sequence's first), wait this
             one, return (p-ready scores, v)."""
@@ -1001,9 +1006,9 @@ def _paged_attn_kernel(block_tables_ref, seq_lens_ref, win_lo_ref,
                            sm, NEG_INF)
             return sm, v
 
-        def body(ci, _, *, p0=p0, start_ci=start_ci, ws=wave_scores):
+        def body(ci, _):
             slot = jax.lax.rem(p0 + (ci - start_ci), 2)
-            sm, v = ws(ci, slot)
+            sm, v = wave_scores(ci, slot)
             m_prev = m_ref[:]                       # [Hp, 1]
             m_new = jnp.maximum(m_prev, jnp.max(sm, axis=1, keepdims=True))
             p = jnp.exp(sm - m_new)
@@ -1015,12 +1020,12 @@ def _paged_attn_kernel(block_tables_ref, seq_lens_ref, win_lo_ref,
             return 0
 
         @pl.when(one_wave)
-        def _(s=s, start_ci=start_ci, p0=p0, ws=wave_scores):
+        def _():
             # fast path for sequences whose live KV fits one wave (every
             # sequence at seq <= chunk*block_size, the common serving
             # case): plain softmax straight to the output block — no
             # scratch init, no carry reads, no epilogue divide pass
-            sm, v = ws(start_ci, jax.lax.rem(p0, 2))
+            sm, v = wave_scores(start_ci, jax.lax.rem(p0, 2))
             m = jnp.max(sm, axis=1, keepdims=True)
             if sink_ref is not None:
                 m = jnp.maximum(m, sink_ref[:])
@@ -1033,7 +1038,7 @@ def _paged_attn_kernel(block_tables_ref, seq_lens_ref, win_lo_ref,
                 / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
 
         @pl.when(~one_wave)
-        def _(s=s, start_ci=start_ci, num_chunks=num_chunks, body=body):
+        def _():
             if sink_ref is None:
                 m_ref[:] = jnp.full_like(m_ref, NEG_INF)  # online-softmax
                 l_ref[:] = jnp.zeros_like(l_ref)          # carry state
@@ -1049,6 +1054,9 @@ def _paged_attn_kernel(block_tables_ref, seq_lens_ref, win_lo_ref,
         # placed it at 1 - rem(p0 + num_waves - 1, 2) == rem(p0+waves, 2)
         wave_ref[0] = jax.lax.rem(
             p0 + jnp.maximum(num_chunks - start_ci, 0), 2)
+        return carry
+
+    jax.lax.fori_loop(0, G, sequence, 0)
 
 
 def paged_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,

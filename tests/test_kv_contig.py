@@ -280,7 +280,7 @@ def _tables(kind: str, rng, nb=NB, m=M, b=B):
 
 
 @pytest.mark.parametrize("kind", ["contig", "fragmented", "mixed"])
-@pytest.mark.parametrize("cb", [2])
+@pytest.mark.parametrize("cb", [2, 4, 8])
 def test_coalesced_bit_identical_f32(kind, cb):
     rng = np.random.default_rng(11)
     k = jnp.asarray(rng.standard_normal((NB * BS, C)), jnp.float32)

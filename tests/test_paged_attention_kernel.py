@@ -168,12 +168,33 @@ def test_v_aliases_k_rejects_bad_geometry():
                                block_size=16, scale=1.0, interpret=True)
 
 
+def _sectioned_reference(q, pool, tables, seq_lens, bs, scale, sections):
+    """Gather + host-side sectioned dequant + masked softmax, in numpy:
+    what the kernel's quant_sections form must give."""
+    from dynamo_tpu.engine.attention import dequant_kv_rows_sections
+    rank, dr = sections
+    b, m = tables.shape
+    Wq = q.shape[-1]
+    deq = np.asarray(dequant_kv_rows_sections(
+        pool[:, :rank + dr + 128], sections, jnp.float32))
+    qf = np.asarray(q, np.float32)
+    idx = np.asarray(tables)[:, :, None] * bs + np.arange(bs)[None, None]
+    idx = idx.reshape(b, -1)
+    k = deq[idx]                                       # [b, T, rank + dr]
+    kq = np.pad(k, ((0, 0), (0, 0), (0, Wq - rank - dr)))
+    scores = np.einsum("bhw,btw->bht", qf, kq) * scale
+    mask = np.arange(m * bs)[None, :] < np.asarray(seq_lens)[:, None]
+    scores = np.where(mask[:, None, :], scores, -1e30)
+    p = np.exp(scores - scores.max(-1, keepdims=True))
+    p = p / p.sum(-1, keepdims=True)
+    return np.einsum("bht,btr->bhr", p, k[..., :rank])
+
+
 def test_sectioned_int8_kernel_mode_matches_reference():
     """quant_sections (int8 MLA pools): in-kernel per-section dequant +
     v-aliases-k must equal the host-side sectioned dequant reference —
     the path models/mla.py decode takes on TPU for int8 latent pools."""
-    from dynamo_tpu.engine.attention import (dequant_kv_rows_sections,
-                                             quantize_kv_rows_sections)
+    from dynamo_tpu.engine.attention import quantize_kv_rows_sections
     rng = np.random.default_rng(88)
     rank, dr = 128, 64                  # sum 192 -> q width 256, row 384
     Wq, bs, m, b, h = 256, 32, 4, 6, 8
@@ -196,20 +217,8 @@ def test_sectioned_int8_kernel_mode_matches_reference():
         v_lanes=rank, quant_sections=(rank, dr), interpret=True)
     assert got.shape == (b, h, rank)
 
-    # reference: gather + host-side sectioned dequant + masked softmax
-    deq = np.asarray(dequant_kv_rows_sections(
-        pool[:, :rank + dr + 128], (rank, dr), jnp.float32))
-    qf = np.asarray(q, np.float32)
-    idx = np.asarray(tables)[:, :, None] * bs + np.arange(bs)[None, None]
-    idx = idx.reshape(b, -1)
-    k = deq[idx]                                       # [b, T, 192]
-    kq = np.pad(k, ((0, 0), (0, 0), (0, Wq - rank - dr)))
-    scores = np.einsum("bhw,btw->bht", qf, kq) * 0.05
-    mask = np.arange(m * bs)[None, :] < np.asarray(seq_lens)[:, None]
-    scores = np.where(mask[:, None, :], scores, -1e30)
-    p = np.exp(scores - scores.max(-1, keepdims=True))
-    p = p / p.sum(-1, keepdims=True)
-    want = np.einsum("bht,btr->bhr", p, k[..., :rank])
+    want = _sectioned_reference(q, pool, tables, seq_lens, bs, 0.05,
+                                (rank, dr))
     np.testing.assert_allclose(np.asarray(got, np.float32), want,
                                rtol=2e-2, atol=2e-2)  # bf16 q rounding
 
@@ -301,3 +310,159 @@ def test_rows_are_refused_where_no_read_has_them():
     with pytest.raises(ValueError, match="a sequence a row"):
         paged_attention(q, pool, pool, tables, lens, block_size=16,
                         scale=1.0, impl="xla", rows=2)
+
+
+# ---------------------------------------------------------------------------
+# The looped body: one traced sequence and one traced block copy serve every
+# form the cells read through
+# ---------------------------------------------------------------------------
+
+FB, FM, FCB = 11, 8, 2      # 11 sequences at the default 8 a program: the
+                            # last group pads with 5 of no length; waves of
+                            # 2 blocks, up to 4 a sequence
+FORMS = ["bf16", "int8", "v_lanes", "quant_sections", "v_dim", "sink",
+         "win_lo", "rows2"]
+
+
+def _form_tables(kind: str, rng, nb: int, b: int = FB):
+    if kind == "contiguous":
+        return np.stack([1 + (i * FM) % (nb - FM - 1) + np.arange(FM)
+                         for i in range(b)]).astype(np.int32)
+    return rng.integers(1, nb, size=(b, FM)).astype(np.int32)
+
+
+@functools.cache
+def _form_case(form: str, tables_kind: str):
+    """→ (call(coalesce) → got, want, live, tolerance): the kernel's
+    arguments in one of the forms a cell serves, and the gather's answer to
+    the same call (once for both settings of ``coalesce``)."""
+    from dynamo_tpu.engine.attention import (quantize_kv_rows,
+                                             quantize_kv_rows_sections)
+    rng = np.random.default_rng(570 + FORMS.index(form))
+    bs = 32 if form in ("int8", "quant_sections") else BS
+    nb = 48
+    tables = jnp.asarray(_form_tables(tables_kind, rng, nb))
+    lens = rng.integers(1, FM * bs + 1, size=(FB,))
+    lens[0], lens[1], lens[2], lens[5] = 0, 1, FM * bs, 0
+    seq_lens = jnp.asarray(lens, jnp.int32)
+    live = lens > 0
+    kw = dict(block_size=bs, scale=Dh ** -0.5)
+    pkw, tol = {}, 2e-5
+    q = jnp.asarray(rng.standard_normal((FB, H, Dh)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((nb * bs, C)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((nb * bs, C)), jnp.float32)
+    ref = None
+    if form == "bf16":      # the served dtypes; the gather on their values
+        q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+        ref = paged_attention_xla(*(x.astype(jnp.float32) for x in (q, k, v)),
+                                  tables, seq_lens, **kw)
+        tol = 1e-2          # the kernel's bfloat16 output
+    elif form == "int8":
+        k = v = quantize_kv_rows(k * 3.0)
+        ref = paged_attention_xla(q, k, v, tables, seq_lens, kv_heads=KVH,
+                                  **kw)
+    elif form == "v_lanes":             # a 256-lane one-head row, v its
+        k = v = jnp.asarray(            # first 128 lanes
+            rng.standard_normal((nb * bs, 256)), jnp.float32)
+        q = jnp.asarray(rng.standard_normal((FB, H, 256)), jnp.float32)
+        kw["scale"], pkw["v_lanes"], tol = 0.07, 128, 2e-4
+        ref = paged_attention_xla(q, k, v, tables, seq_lens, **kw)[..., :128]
+    elif form == "quant_sections":      # rank 128 | rope 64, int8 rows
+        sections = (128, 64)
+        vals = np.concatenate(
+            [rng.standard_normal((nb * bs, 128)).astype(np.float32),
+             rng.standard_normal((nb * bs, 64)).astype(np.float32) * 15.0],
+            axis=1)
+        enc = np.asarray(quantize_kv_rows_sections(jnp.asarray(vals),
+                                                   sections))
+        k = v = jnp.asarray(np.pad(enc, ((0, 0), (0, 384 - enc.shape[1]))))
+        q = jnp.asarray(rng.standard_normal((FB, H, 256)).astype(np.float32)
+                        * 0.3, jnp.bfloat16)
+        kw["scale"], tol = 0.05, 2e-2   # bf16 q rounding
+        pkw.update(v_lanes=128, quant_sections=sections)
+        ref = _sectioned_reference(q, k, tables, seq_lens, bs, 0.05, sections)
+    elif form == "v_dim":               # value heads twice the keys' size
+        v = jnp.asarray(rng.standard_normal((nb * bs, KVH * 128)),
+                        jnp.float32)
+        kw["v_dim"] = 128
+    elif form == "sink":
+        kw["sink"] = jnp.asarray(rng.standard_normal((H,)), jnp.float32)
+    elif form == "win_lo":
+        # lower bounds up to 100: past wave 0 (32 keys) and wave 2 for some
+        win_lo = rng.integers(-1, 100, size=(FB,))
+        win_lo[2], win_lo[3] = 70, -1   # the full table read from wave 2 on
+        kw["win_lo"] = jnp.asarray(win_lo, jnp.int32)
+        live = lens > np.maximum(win_lo + 1, 0)
+        assert (win_lo[live] >= FCB * bs).any()
+    elif form == "rows2":               # two rows a sequence, one pass
+        q = jnp.asarray(rng.standard_normal((FB * 2, H, Dh)), jnp.float32)
+        row_lens = jnp.repeat(seq_lens, 2) - jnp.tile(
+            jnp.asarray([1, 0], jnp.int32), FB)
+        ref = paged_attention_xla(q, k, v, jnp.repeat(tables, 2, axis=0),
+                                  row_lens, **kw)
+        pkw["rows"] = 2
+        live = np.asarray(row_lens) > 0
+    if ref is None:
+        ref = paged_attention_xla(q, k, v, tables, seq_lens, **kw)
+
+    def call(coalesce: bool):
+        return paged_attention_pallas(
+            q, k, v, tables, seq_lens, chunk_blocks=FCB, coalesce=coalesce,
+            interpret=True, **kw, **pkw)
+    return call, np.asarray(ref, np.float32), live, tol
+
+
+@pytest.mark.parametrize("coalesce", [True, False],
+                         ids=["coalesced", "per_block"])
+@pytest.mark.parametrize("tables_kind", ["contiguous", "fragmented"])
+@pytest.mark.parametrize("form", FORMS)
+def test_looped_body_equals_the_gather(form, tables_kind, coalesce):
+    """The kernel's body is traced once a program and its block copies
+    once a wave (two loops on the device): every form a cell reads
+    through — pool dtype, aliased or narrower or wider values, a sink, a
+    window that starts past wave 0, two rows a sequence — over tables that
+    take the one-copy and the per-block path, in a batch whose last group
+    pads with sequences of no length, equals the XLA gather."""
+    call, want, live, tol = _form_case(form, tables_kind)
+    got = np.asarray(call(coalesce), np.float32)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
+
+
+def _equations(jaxpr) -> int:
+    """Equations of a jaxpr and of every jaxpr its equations hold."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _equations(sub)
+    return n
+
+
+@pytest.mark.parametrize("shape", ["chat-open", "latent-chunk-64"])
+def test_the_body_is_traced_once_whatever_the_group_and_the_wave(shape):
+    """What a served process traces at every start: the kernel at 8
+    sequences a program and 16 (64) blocks a wave holds no more than 1.5
+    times the equations it holds at one sequence and one block (7.2 times
+    while the group and a wave's copies unrolled in Python)."""
+    if shape == "chat-open":            # mistral-7b: B 64, H 32, KVH 8
+        b, h, dh, lanes, m, deep, kw = 64, 32, 128, 1024, 256, 16, {}
+    else:                               # kimi-k2's latent row, v its 512
+        b, h, dh, lanes, m, deep, kw = 16, 64, 640, 640, 1600, 64, {
+            "v_lanes": 512}
+
+    def equations(group: int, chunk: int) -> int:
+        s = jax.ShapeDtypeStruct
+        pool = s((2048 * 16, lanes), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(functools.partial(
+            paged_attention_pallas, block_size=16, scale=0.1,
+            seqs_per_program=group, chunk_blocks=chunk, **kw))(
+                s((b, h, dh), jnp.bfloat16), pool, pool,
+                s((b, m), jnp.int32), s((b,), jnp.int32))
+        return _equations(jaxpr.jaxpr)
+
+    assert equations(8, deep) <= 1.5 * equations(1, 1)

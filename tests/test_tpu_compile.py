@@ -92,9 +92,34 @@ def _decode_case(one_chip, cfg, block_size, kv_int8, B=8, max_len=4096,
 
 @pytest.mark.parametrize("cfg,block_size,kv_int8", [
     (CFG_1B, 16, False), (CFG_1B, 32, True), (CFG_8B, 16, False),
-], ids=["1b-bf16", "1b-int8kv", "8b-bf16"])
+    (CFG_8B, 32, True),
+], ids=["1b-bf16", "1b-int8kv", "8b-bf16", "8b-int8kv"])
 def test_paged_decode_kernel_compiles(one_chip, cfg, block_size, kv_int8):
     _decode_case(one_chip, cfg, block_size, kv_int8)
+
+
+@pytest.mark.parametrize("form", ["latent-chunk64", "per-block"])
+def test_paged_decode_kernel_loops_compile(one_chip, form):
+    """The kernel's sequence loop and its block copies (one traced body,
+    unrolled by the lowering) build for the chip at the deepest wave a
+    cell serves (kimi-k2's latent row, 64 blocks, v aliased) and where
+    every wave takes the per-block path. Two rows a sequence:
+    test_tpu_compile_exaone.py."""
+    bs = 16
+    if form == "latent-chunk64":
+        B, H, d, lanes, M = 16, 64, 640, 640, 1600
+        kw = dict(v_lanes=512, chunk_blocks=64)
+    else:
+        B, H, d, lanes, M = 64, 32, 128, 1024, 256
+        kw = dict(coalesce=False)
+
+    def fn(q, k, v, bt, sl):
+        return A.paged_attention_pallas(q, k, v, bt, sl, block_size=bs,
+                                        scale=0.1, **kw)
+
+    pool = ((4096 * bs, lanes), jnp.bfloat16)
+    _compile(fn, one_chip, ((B, H, d), jnp.bfloat16), pool, pool,
+             ((B, M), jnp.int32), ((B,), jnp.int32))
 
 
 @pytest.mark.parametrize("cfg", [CFG_1B, CFG_8B],

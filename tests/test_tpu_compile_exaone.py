@@ -140,16 +140,19 @@ def test_exaone_two_row_reads_build_at_the_published_sizes(one_chip, read):
 
 # sha256 of the traced program (``jax.make_jaxpr``: the wrapper's ops and the
 # kernel's body, op for op, without source lines) of three ``rows == 1`` calls
-# of the paged kernel, taken on the commit before it learned ``rows``
+# of the paged kernel: taken on the commit before it learned ``rows`` and
+# again, with ``rows`` at 1 throughout, when the kernel's sequence group and a
+# wave's block copies became loops on the device (PR 57: a body of 1,087-1,221
+# lines where the unrolled one had 3,544-4,068; the outputs bit-equal)
 ONE_ROW_PROGRAMS = {
     "llama": (
-        "c37973379029eddb1af83c4cc045623c0b1add545feaf52aaa789f664051c15d",
+        "791343932fbb8328c98f1b1cd2b9c1467d020f0877f7c50a363dd0ec0a9ea659",
         dict(chunk_blocks=4), (32, 128), (1024, 1024)),
     "mla": (
-        "cbf3c1443d45be3fc1d7d1c238e67d0ffcd794dcbea4bc985d5f1c1b66425023",
+        "6a77e88d0e5f56b1d81a91c1d4b6cc21c804952d55f4f20c3055fe6ab445e3df",
         dict(kv_heads=1, v_lanes=512, chunk_blocks=4), (16, 640), (640, 640)),
     "mimo": (
-        "364d0d27a4e70544bab64038dc8c9d39b1b337f51e7fdf5731ee6c99f4eed5e9",
+        "f15e100ba12b17e220db605a2222a6e420fdefef1b1f5ed63cbaad8a36c2856e",
         dict(kv_heads=4, v_dim=128, chunk_blocks=4, name="gqa_window_read"),
         (16, 192), (768, 512)),
 }
