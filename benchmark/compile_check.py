@@ -61,7 +61,6 @@ def engine_shell(cfg, engine_cfg):
     core = object.__new__(EngineCore)
     core.cfg, core.mesh, core.pp = engine_cfg, None, 1
     core.model_mod = module_for(cfg)
-    core.is_hybrid = cfg.is_sambay
     core.M = engine_cfg.max_blocks_per_seq
     core.statics = llama.ModelStatics(
         cfg=cfg, block_size=engine_cfg.kv_block_size, attn_impl="pallas",
@@ -74,6 +73,9 @@ def engine_shell(cfg, engine_cfg):
         return kv
     core.kv = jax.eval_shape(cache)
     layout = beside["layout"]
+    # per-slot state behind the prefill table: what the cache's layout says,
+    # whatever the family, as EngineCore.__init__ takes it
+    core.is_hybrid = layout is not None and layout.has_state
     # a window pool's ids ride behind a table's M paged ones: R a decode
     # table's, M a prefill's
     core.has_window_pool = layout is not None and layout.window_pool
@@ -99,13 +101,12 @@ def main() -> int:
     import run as bench_run
     from dynamo_tpu.engine import attention
     from dynamo_tpu.engine.config import ModelConfig
-    from dynamo_tpu.engine.models import llama
+    from dynamo_tpu.engine.models import llama, module_for
     from dynamo_tpu.engine.quant import (_quantize_named,
                                          init_params_quantized)
     from dynamo_tpu.launch import run as launcher
 
     jax.config.update("jax_enable_compilation_cache", False)
-    attention._on_tpu = llama._on_tpu = lambda: True
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
@@ -119,6 +120,11 @@ def main() -> int:
     if opts.layers:
         hf["num_hidden_layers"] = opts.layers
     cfg = ModelConfig.from_hf_config(hf)
+    # "is this a TPU?" is answered yes wherever the served family asks: a
+    # model module binds attention's test under its own name at import
+    for mod in (attention, llama, module_for(cfg)):
+        if hasattr(mod, "_on_tpu"):
+            mod._on_tpu = lambda: True
     flags = list(config.get("deployment", {}).get("flags", ()))
     engine_cfg = launcher.engine_config(launcher.build_parser().parse_args(
         ["in=http", "out=jax", *flags, *launcher_flags]))

@@ -1,13 +1,22 @@
-"""Kernels: share of its roofline that the sliding-attention layers' window read
-reaches in a decode step, in %: min(context, 128) rows of 5,120 B a sequence
-and layer (the rows the model needs, not the 9 blocks the ring holds) over the
-HBM peak (or its operations over the MXU's, if more) against
-kernel.gqa_window_ms (mimo-v2.5; ``references/mimo_v2_costs.py``). A program
-without the kernel or its counters: nothing to read."""
-
-# benchmark/ is on sys.path wherever a reader is loaded (run.py, selftest.py)
-from references import mimo_v2_costs as costs
+"""Kernels: share of its roofline that the sliding-attention layers' window
+read (``gqa_window``: the paged-attention kernel under the name
+``gqa_window_read``, over a sequence's ring of window-pool blocks) reaches
+in a decode step, in %: the least time the chip could take for the
+operations and bytes the mathematics needs in a median decode step of the
+window (from the ``decode`` flight records' ``win_tokens`` and the
+configuration's shapes, against ``peaks.py``: the larger of bytes over the
+HBM peak and operations over the MXU's) over the stage's measured device
+time per step (``kernel.gqa_window_ms``). What is counted, at which shapes,
+is said by the configuration's costs module (``ctx["costs"]``, found by
+``run.costs_module``), in its ``stage_roofline_pct``:
+``references/mimo_v2_costs.py`` and ``references/exaone_moe_costs.py`` price
+it today. Only what the algorithm must touch is counted, so the share cannot
+pass 100. A cell whose family prices no ``gqa_window`` stage, or a run
+without its ops or counters: nothing to read."""
 
 
 def read(ctx):
+    costs = ctx.get("costs")
+    if "gqa_window" not in getattr(costs, "KERNELS", ()):
+        return None         # this cell's family prices no such stage
     return costs.stage_roofline_pct(ctx, "gqa_window")

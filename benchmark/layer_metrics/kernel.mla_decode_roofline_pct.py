@@ -1,15 +1,22 @@
-"""Kernels: share of its roofline that the paged-attention kernel reaches in
-its one-head latent form (64 query heads × 640 lanes), in %: the least time
-the chip could take to read each live latent row once a layer and use it for
-every head (``references/kimi_k2_costs.decode_read``, from the ``decode``
-flight records' ``ctx_tokens``, against ``peaks.py``: the larger of bytes
-over the HBM peak and operations over the MXU's) over the kernel's measured
-device time per step (``kernel.paged_attention_ms``). A trace without the
-kernel or records without the counter: nothing to read."""
-
-# benchmark/ is on sys.path wherever a reader is loaded (run.py, selftest.py)
-from references import kimi_k2_costs as costs
+"""Kernels: share of its roofline that the paged-attention kernel in its
+one-head latent form (``mla_decode``: the absorbed read of every
+latent-attention layer, each live latent row once a layer and used by every
+head) reaches in a decode step, in %: the least time the chip could take for the
+operations and bytes the mathematics needs in a median decode step of the
+window (from the ``decode`` flight records' ``ctx_tokens`` and the
+configuration's shapes, against ``peaks.py``: the larger of bytes over the
+HBM peak and operations over the MXU's) over the stage's measured device
+time per step (``kernel.paged_attention_ms``). What is counted, at which
+shapes, is said by the configuration's costs module (``ctx["costs"]``, found
+by ``run.costs_module``), in its ``stage_roofline_pct``:
+``references/kimi_k2_costs.py`` and ``references/kimi_linear_costs.py``
+price it today. Only what the algorithm must touch is counted, so the share
+cannot pass 100. A cell whose family prices no ``mla_decode`` stage, or a
+run without its ops or counters: nothing to read."""
 
 
 def read(ctx):
-    return costs.decode_roofline_pct(ctx)
+    costs = ctx.get("costs")
+    if "mla_decode" not in getattr(costs, "KERNELS", ()):
+        return None         # this cell's family prices no such stage
+    return costs.stage_roofline_pct(ctx, "mla_decode")

@@ -1,15 +1,18 @@
-"""Kernels: device time of DeepSeek Sparse Attention's ``sparse_attention`` stage per
-decode step, all layers of the step together, in ms: the seconds of the
-stage's ops (named by their result shapes in
-``references/deepseek_v32_costs.stage_patterns``, where the reasons are)
-over the dispatches of the served decode program ``jit_decode_k`` in the
-profiler's window. A program without the stage (no indexer; the parent of
-PR 31): nothing to read."""
-
-# benchmark/ is on sys.path wherever a reader is loaded (run.py, selftest.py)
-from references import deepseek_v32_costs as costs
+"""Kernels: device time of the attention over the selected rows
+(``sparse_attention``: every layer that selects) per decode step, all such
+layers together, in ms: the seconds of the stage's ops over the dispatches
+of the served decode program in the profiler's window. Which ops and which
+program are the stage's at a configuration's shapes is said by that
+configuration's costs module (``ctx["costs"]``, found by
+``run.costs_module``), in its ``KERNELS`` and ``stage_seconds_per_step``:
+``references/deepseek_v32_costs.py`` and ``references/dots3_note_costs.py``
+price it today. A cell whose family prices no ``sparse_attention`` stage, or
+a trace without its ops: nothing to read."""
 
 
 def read(ctx):
+    costs = ctx.get("costs")
+    if "sparse_attention" not in getattr(costs, "KERNELS", ()):
+        return None         # this cell's family prices no such stage
     seconds = costs.stage_seconds_per_step(ctx, "sparse_attention")
     return None if seconds is None else seconds * 1e3

@@ -1,15 +1,21 @@
-"""Kernels: share of its roofline that DeepSeek Sparse Attention's
-``sparse_attention`` stage reaches in a decode step, in %: the least time the chip
-could take for the stage's operations and bytes
-(``references/deepseek_v32_costs.py``, from the step's ``ctx_tokens`` /
-``sel_tokens`` and the configuration's shapes, against ``peaks.py``) over
-the stage's measured device time per step (``kernel.sparse_attention_ms``). Only
-what the algorithm must read is counted, so the share cannot pass 100. A
-program without the stage or without the counters: nothing to read."""
-
-# benchmark/ is on sys.path wherever a reader is loaded (run.py, selftest.py)
-from references import deepseek_v32_costs as costs
+"""Kernels: share of its roofline that the attention over the selected rows
+(``sparse_attention``: every layer that selects) reaches in a decode step,
+in %: the least time the chip could take for the operations and bytes the
+mathematics needs in a median decode step of the window (from the ``decode``
+flight records' ``sel_tokens`` and the configuration's shapes, against
+``peaks.py``: the larger of bytes over the HBM peak and operations over the
+MXU's) over the stage's measured device time per step
+(``kernel.sparse_attention_ms``). What is counted, at which shapes, is said
+by the configuration's costs module (``ctx["costs"]``, found by
+``run.costs_module``), in its ``stage_roofline_pct``:
+``references/deepseek_v32_costs.py`` and ``references/dots3_note_costs.py``
+price it today. Only what the algorithm must touch is counted, so the share
+cannot pass 100. A cell whose family prices no ``sparse_attention`` stage,
+or a run without its ops or counters: nothing to read."""
 
 
 def read(ctx):
+    costs = ctx.get("costs")
+    if "sparse_attention" not in getattr(costs, "KERNELS", ()):
+        return None         # this cell's family prices no such stage
     return costs.stage_roofline_pct(ctx, "sparse_attention")

@@ -13,16 +13,20 @@ A step scores ``rows`` adjacent rows a slot (2: the last token and the
 draft). **A slot's cached rows are read ONCE a layer and step however many
 rows of that slot are scored**: the rows of one slot read the same context
 but for the newest row or two, so the mathematics needs each cached row once.
-A program that reads them once a scored row (the two-row step in verify's
-shape does: each row is a sequence of its own to the paged kernel) therefore
-shows as a share UNDER its roofline, not over it.
+A program that reads them once a scored row (the two-row step did until
+PR 52: in verify's shape each row was a sequence of its own to the paged
+kernel) therefore shows as a share UNDER its roofline, not over it; since
+PR 52 the served step reads a slot's rows once under the kernel for both
+scored rows, which is the work counted here.
 
 * **the full read** (``decode`` flight records carry ``ctx_tokens`` = the sum
   over the step's slots of the context up to the slot's last row): every live
   row once a full layer of the main model;
 * **the module's read**: the same rows of the module's own layer, once;
 * **the window read** (``win_tokens`` = the sum of min(context, window +
-  rows - 1): the union of the rows' windows): once a sliding layer.
+  rows - 1): the union of the rows' windows, 129 rows at the published
+  window of 128 and two scored rows; not the 9 or 10 blocks a slot's ring
+  holds, not once a row): once a sliding layer.
 
 Pairs: each of a slot's rows attends the context (or the window), so the
 operations are the bytes' rows times the rows scored a slot. Queries and

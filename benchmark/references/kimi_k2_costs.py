@@ -173,6 +173,17 @@ def decode_roofline_pct(ctx: dict):
     return _share(decode_read(found[0], statistics.median(counts)), measured)
 
 
+# The spelling a kernel's reader calls whatever configuration's shapes the
+# kernel runs at (``layer_metrics/kernel.mla_decode_roofline_pct.py`` finds
+# this module in ``ctx["costs"]``): the stages priced here by the calls
+# that are theirs, and the share of a stage by its name.
+KERNELS = {"mla_decode": DECODE_KERNEL}
+
+
+def stage_roofline_pct(ctx: dict, stage: str):
+    return {"mla_decode": decode_roofline_pct}[stage](ctx)
+
+
 def _share(cost: dict, measured: float):
     import jax
     import peaks

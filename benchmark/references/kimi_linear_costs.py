@@ -18,13 +18,18 @@ work whatever implements it.
   in and out once a head and dispatch, not once a chunk: it stays on the
   chip between chunks. The chunks are those that hold a prompt row
   (``scan_tokens`` of the ``prefill`` records), not the padded bucket's.
-  The matrices ``A``, ``B`` and ``(I + A)^-1`` are built before the call by
-  XLA ops that a trace does not tell from others of their shapes: their
-  time and their work are both left out.
+  The matrices ``A``, ``B`` and ``(I + A)^-1`` are built before the call,
+  since PR 55 by a Pallas kernel of their own (``%kda_prepare*``, read by
+  ``layer_metrics/kernel.kda_prepare_ms.py``, which gives it no share: no
+  peak of ``peaks.py`` prices its exponentials and small HIGHEST matmuls);
+  their time and their work are both left out of this stage.
 * ``latent read`` (a decode step's absorbed attention, the 7 F layers): each
   live latent row (576 values, bf16) once a layer, used by all 32 heads:
   per row and head 2 x 576 operations for the score and 2 x 512 for
-  ``probs . c``.
+  ``probs . c``. It is the stage every family with latent attention prices
+  as ``mla_decode`` (the paged-attention kernel in its one-head latent form,
+  here 32 query heads x 640 lanes): ``kernel.mla_decode_roofline_pct`` asks
+  it by that name.
 
 The counters: ``decode`` flight records carry ``ctx_tokens`` and
 ``batch_fill`` (live slots), ``prefill`` records ``scan_tokens``. Only what
@@ -100,6 +105,12 @@ KERNELS = {"kda_step": ("%kda_step", DECODE),
            "latent_read": ("%paged_attention", DECODE)}
 
 
+# the latent read under the name every family's costs module prices it by
+# (``layer_metrics/kernel.mla_decode_roofline_pct.py`` finds this module in
+# ``ctx["costs"]`` and asks ``stage_roofline_pct(ctx, "mla_decode")``)
+KERNELS["mla_decode"] = KERNELS["latent_read"]
+
+
 def _seconds(ctx: dict, kernel: str) -> float:
     return sum(sec for name, sec, _ in (ctx.get("trace") or {}).get(
         "ops", ()) if name.startswith(kernel))
@@ -140,6 +151,10 @@ def cost_of(ctx: dict, which: str):
         return None if live is None else kda_step_cost(hf, live)
     ctx_tokens = _median(decode, "ctx_tokens")
     return None if ctx_tokens is None else latent_read_cost(hf, ctx_tokens)
+
+
+def stage_roofline_pct(ctx: dict, stage: str):
+    return roofline_pct(ctx, {"mla_decode": "latent_read"}.get(stage, stage))
 
 
 def roofline_pct(ctx: dict, which: str):

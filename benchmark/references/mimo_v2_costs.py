@@ -5,10 +5,12 @@ the traced Pallas calls the two decode reads are held against.
 
 A layer of a kind has H query heads over KVH key/value heads, keys of dk and
 values of dv lanes; a cached row of one layer is KVH·(dk + dv) values of 2
-bytes, the heads side by side and unpadded (``models/mimo.py``: 2,560 B in a
-full layer, 5,120 B in a window layer at the published sizes; no lane of the
-row is padding, so none is counted). Every (query, key) pair costs each query
-head 2·dk operations for the score and 2·dv for probs·v.
+bytes, the heads side by side and unpadded (``models/mimo.py``: at the
+published sizes 64 query heads over 4 key/value heads in a full layer, key
+rows of 768 and value rows of 512 lanes, 2,560 B; 64 over 8 with a sink in the
+softmax's denominator in a window layer, 1,536 and 1,024 lanes, 5,120 B; no
+lane of the row is padding, so none is counted). Every (query, key) pair
+costs each query head 2·dk operations for the score and 2·dv for probs·v.
 
 * **the full read** of a decode step (``decode`` flight records carry
   ``ctx_tokens`` = the sum of the live context over the step's sequences):

@@ -17,7 +17,10 @@ by name in ``BENCHMARK.json``, ``benchmark/configs/<config>.json`` and
 ``benchmark/layer_metrics/<metric>.py``; the plain reference a
 configuration is held to is the module its file names
 (``"reference": "<name>"`` → ``benchmark/references/<name>.py``; none named:
-``benchmark/reference.py``). Adding any of them edits no file that is there.
+``benchmark/reference.py``), and the functions that price a kernel at its
+shapes are that family's costs module (``references/<name>_costs.py``; none
+named: ``benchmark/costs.py``), which a kernel's reader finds in its ``ctx``.
+Adding any of them edits no file that is there.
 
 The last line of standard output is the result object; earlier lines are
 ``# key: value`` notes. A builder-only mode, never the driver's:
@@ -110,6 +113,21 @@ def reference_module(config: dict):
         raise SystemExit(f"the configuration names the reference {name!r}; "
                          f"there is no {os.path.relpath(path, ROOT)}")
     return load_by_path("reference_" + name.replace("-", "_"), path)
+
+
+def costs_module(config: dict):
+    """The operations and bytes of this configuration's family, and the
+    traced ops that are each kernel's: what a kernel's reader prices with
+    (``ctx["costs"]``), so that one reader serves the kernel at every
+    configuration's shapes. Found by the name the file gives its reference
+    (``"reference": "deepseek_v32"`` → ``references/deepseek_v32_costs.py``);
+    a file that names none is of the llama family and gets ``costs.py``.
+    None where the family brought no costs module."""
+    name = config.get("reference")
+    module = "costs" if name is None else f"references.{name}_costs"
+    if importlib.util.find_spec(module) is None:
+        return None
+    return importlib.import_module(module)
 
 
 def metric_reader(group: str, name: str):
@@ -485,7 +503,8 @@ async def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
                         "idle_gaps": reduction["idle_gaps"]}
     ctx = {"spans": seen["quiet_spans"], "flight": seen["quiet_flight"],
            "window_s": seen["quiet_s"], "trace": reduction,
-           "memory": stats_mem, "engine": engine, "load": load}
+           "memory": stats_mem, "engine": engine, "load": load,
+           "costs": costs_module(load_config(bench, cell["config"]))}
     out["metrics"] = read_metrics(bench, cell["name"], "per_layer", ctx)
     return out
 

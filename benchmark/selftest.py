@@ -235,15 +235,109 @@ def reader_check() -> None:
              "programs": [["jit_decode_k", 3.329, 85],
                           ["jit_prefill", 1.105, 13]]}
     engine = {"max_num_seqs": 64}
-    for name in ("kernel.dsa_select_ms", "kernel.dsa_select_ms.dots"):
-        read = bench_run.metric_reader("per_layer", name)
-        got = read({"trace": trace, "engine": engine})
+    read = bench_run.metric_reader("per_layer", "kernel.dsa_select_ms")
+    for costs in (costs_of("deepseek_v32"), costs_of("dots3_note")):
+        got = read({"trace": trace, "engine": engine, "costs": costs})
         check(abs(got - 5.41) < 1e-9
               and abs(read({"trace": dict(trace, ops=trace["ops"][:2]),
-                            "engine": engine}) - 4.27) < 1e-9,
-              f"{name}: %index_scores* and %dsa_select_compact* with the "
-              f"decode batch first, over the dispatches of jit_decode_k, "
-              f"in ms ({got:.2f})")
+                            "engine": engine, "costs": costs}) - 4.27) < 1e-9,
+              f"kernel.dsa_select_ms priced by {costs.__name__}: "
+              f"%index_scores* and %dsa_select_compact* with the decode "
+              f"batch first, over the dispatches of jit_decode_k, in ms "
+              f"({got:.2f})")
+
+
+def costs_of(reference: str):
+    """The costs module of the family whose reference is ``reference``, as
+    ``run_cell`` puts it into the readers' ``ctx``."""
+    return bench_run.costs_module({"reference": reference})
+
+
+# One reader a kernel, whatever configuration's shapes it runs at (PR 58): a
+# reader of these takes the cell's costs module from ``ctx["costs"]``. Per
+# reader: the stage it asks by, the reader's unit over the module's (ms over
+# seconds a step; a share is a share) and, per family that prices the stage
+# today, the module's own call that the reader has to return.
+def _stage_call(call: str, stage: str):
+    return lambda costs, ctx: getattr(costs, call)(ctx, stage)
+
+
+MERGED_READERS = {
+    f"kernel.{stage}_{what}": (stage, scale, {
+        family: _stage_call(call, stage) for family in families})
+    for stage, families in (("dsa_select", ("deepseek_v32", "dots3_note")),
+                            ("sparse_attention", ("deepseek_v32",
+                                                  "dots3_note")),
+                            ("gqa_full", ("mimo_v2", "exaone_moe")),
+                            ("gqa_window", ("mimo_v2", "exaone_moe")))
+    for what, call, scale in (("ms", "stage_seconds_per_step", 1e3),
+                              ("roofline_pct", "stage_roofline_pct", 1))}
+MERGED_READERS["kernel.mla_decode_roofline_pct"] = ("mla_decode", 1, {
+    "kimi_k2": lambda costs, ctx: costs.decode_roofline_pct(ctx),
+    "kimi_linear": lambda costs, ctx: costs.roofline_pct(ctx, "latent_read")})
+# Kernel rows made up beside the recorded trace's ops (it is a dense model's:
+# it holds none of these), the decode batch first, and the served decode
+# programs' dispatches; the counters a step's cost is worked out from.
+KERNEL_ROWS = [
+    [f"%{kernel}.7 bf16[64,{8 + i},128] custom-call tpu_custom_call",
+     0.04 * (i + 1), 40 * (i + 1)] for i, kernel in enumerate((
+         "index_scores", "dsa_select_compact", "sparse_latent_attention",
+         "gqa_full_read", "gqa_window_read", "paged_attention", "kda_step"))]
+DECODE_PROGRAMS = [["jit_decode_k", 0.9, 40], ["jit_decode_mtp", 0.8, 32]]
+DECODE_RECORD = {"kind": "decode", "K": 1, "batch_fill": 64, "rows": 128,
+                 "ctx_tokens": 262144, "sel_tokens": 98304,
+                 "win_tokens": 8192}
+
+
+def family_engine(reference: str) -> dict:
+    """What ``run_cell`` reports of the engine of the configuration of
+    BENCHMARK.json that names ``reference``, from its deployment's flags (a
+    costs module finds its configuration by them)."""
+    bench = bench_run.load_benchmark()
+    config = next(c for c in (bench_run.load_config(bench, e["name"])
+                              for e in bench["configs"])
+                  if c.get("reference") == reference)
+    flags = config["deployment"]["flags"]
+    return {key: int(flags[flags.index(f"--{key.replace('_', '-')}") + 1])
+            for key in ("max_num_seqs", "num_kv_blocks", "kv_block_size")}
+
+
+def merged_reader(name: str) -> None:
+    """The reader ``name`` with ``ctx["costs"]`` = a family's costs module
+    returns what that module's own function returns, for every family that
+    prices its stage, and nothing under any other costs module. The share
+    of a roofline is priced at the v5e's peaks here (the CPU is in no table
+    of peaks): the arithmetic is what is held, the number is no chip's."""
+    import peaks
+    stage, scale, families = MERGED_READERS[name]
+    read = bench_run.metric_reader("per_layer", name)
+    check(reader_file(name) == f"{name}.py", f"{name}: a file of its own")
+    trace = trace_reduce.reduce(fixture_events())
+    trace = dict(trace, ops=trace["ops"] + KERNEL_ROWS,
+                 programs=DECODE_PROGRAMS)
+    ctx = {"trace": trace, "flight": [DECODE_RECORD] * 3}
+    real = peaks.roofline_s
+    peaks.roofline_s = lambda flops, moved, kind, **kw: real(
+        flops, moved, "TPU v5 lite", **kw)
+    try:
+        for family, own in families.items():
+            costs = costs_of(family)
+            cell = dict(ctx, engine=family_engine(family), costs=costs)
+            got, want = read(cell), own(costs, cell)
+            check(want is not None and want > 0 and got == want * scale,
+                  f"{name} with ctx['costs'] = {costs.__name__}: what the "
+                  f"module's own function returns ({got:.4f})")
+        others = [None, costs_of(None)] + [
+            costs_of(f) for f in ("deepseek_v32", "dots3_note", "mimo_v2",
+                                  "exaone_moe", "kimi_k2", "kimi_linear",
+                                  "phi4flash", "deepseek_v2")
+            if f not in families]
+        check(all(read(dict(ctx, engine={}, costs=c)) is None
+                  for c in others) and read(dict(ctx, engine={})) is None,
+              f"{name}: nothing to read where the cell's family prices no "
+              f"{stage} stage, or brought no costs module")
+    finally:
+        peaks.roofline_s = real
 
 
 PER_LAYER_LIMIT = 128       # the contract's
@@ -508,6 +602,8 @@ def main() -> int:
     window_arithmetic()
     trace_reduction()
     reader_check()
+    for name in MERGED_READERS:
+        merged_reader(name)
     layout()
     reference_lookup()
     names = fixtures()

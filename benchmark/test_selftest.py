@@ -2,13 +2,15 @@
 
     JAX_PLATFORMS=cpu python3 -m pytest benchmark/test_selftest.py -q -p no:cacheprovider
 
-One case per check and, for the reference, one per fixture, so that each
-counts. The served rehearsal (``selftest.end_to_end``, minutes) stays in
-``selftest.py``. Each case has a limit of its own (no pytest-timeout here:
-an alarm). Tier 1 runs ``tests/`` only: until a PR that may touch ``tests/``
-imports these cases there, they run by the command above (PERF.md §7).
+One case per check, one per reader that takes its costs module from its
+``ctx`` and, for the reference, one per fixture, so that each counts. The
+served rehearsal (``selftest.end_to_end``, minutes) stays in ``selftest.py``.
+Each case has a limit of its own (no pytest-timeout here: an alarm). Tier 1
+runs ``tests/`` only: ``tests/test_benchmark_selftest.py`` imports both test
+functions, so a case added to either is tier 1's too.
 """
 
+import functools
 import os
 import signal
 import sys
@@ -22,6 +24,10 @@ LIMIT_S = 60
 CHECKS = (selftest.arithmetic, selftest.generator, selftest.window_arithmetic,
           selftest.trace_reduction, selftest.reader_check, selftest.layout,
           selftest.reference_lookup)
+CASES = [pytest.param(check, id=check.__name__) for check in CHECKS] + [
+    pytest.param(functools.partial(selftest.merged_reader, name),
+                 id=f"merged_reader-{name}")
+    for name in selftest.MERGED_READERS]
 
 
 @pytest.fixture(autouse=True)
@@ -37,7 +43,7 @@ def limit():
         signal.signal(signal.SIGALRM, before)
 
 
-@pytest.mark.parametrize("check", CHECKS, ids=lambda f: f.__name__)
+@pytest.mark.parametrize("check", CASES)
 def test_yardstick(check):
     check()
 
