@@ -60,7 +60,7 @@ def _rope_type(raw_rs: Dict[str, Any]) -> str:
 # any other that does, by name)
 MOE_FAMILIES = ("mixtral", "qwen2_moe", "qwen3_moe", "deepseek_v2",
                 "deepseek_v3", "deepseek_v32", "kimi_k2", "dots3_note",
-                "mimo_v2", "exaone_moe", "kimi_linear")
+                "mimo_v2", "exaone_moe", "kimi_linear", "granitemoehybrid")
 
 
 @dataclasses.dataclass
@@ -217,6 +217,26 @@ class ModelConfig:
     kda_head_dim: int = 0
     kda_conv_kernel: int = 0
     mla_nope: bool = False
+    # granitemoehybrid (models/granite_hybrid.py, docs/hybrid_cache.md part
+    # six): layers whose layer_types entry is "mamba" are Mamba-2
+    # (engine/ssd.py): ssd_num_heads heads of ssd_head_dim lanes, one decay
+    # a head and token over a float32 [heads, lanes, ssd_d_state] state a
+    # slot and layer, x | B | C (one B / C group) through ONE depthwise
+    # causal convolution of ssd_conv_kernel taps with a bias
+    # (ssd_num_heads > 0 is what says the model has them); its "attention"
+    # layers are grouped-query rows in llama.py's paged pool with no
+    # rotation (nope_full) at the score scale attention_multiplier, held as
+    # query_pre_attn_scalar = attention_multiplier^-2. Granite's three
+    # multipliers: embedding_multiplier on the embedding rows (0: none;
+    # embed_scale is gemma's sqrt(hidden)), residual_multiplier on every
+    # branch before it joins the stream, logits / logits_scaling
+    ssd_num_heads: int = 0
+    ssd_head_dim: int = 0
+    ssd_d_state: int = 0
+    ssd_conv_kernel: int = 0
+    embedding_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     mamba_d_state: int = 0
     mamba_d_conv: int = 0
     mamba_expand: int = 0
@@ -277,6 +297,12 @@ class ModelConfig:
         return self.kda_num_heads > 0
 
     @property
+    def has_ssd(self) -> bool:
+        """Mamba-2 layers beside grouped-query attention layers
+        (granitemoehybrid): models/granite_hybrid.py serves it."""
+        return self.ssd_num_heads > 0
+
+    @property
     def mamba_d_inner(self) -> int:
         return self.mamba_expand * self.hidden_size
 
@@ -299,6 +325,8 @@ class ModelConfig:
             return cls._from_exaone_moe(cfg)
         if mt == "kimi_linear":
             return cls._from_kimi_linear(cfg)
+        if mt == "granitemoehybrid":
+            return cls._from_granitemoehybrid(cfg)
         # a family with no branch here falls through to the llama block:
         # right for its many renamings, wrong for one whose layers keep a
         # recurrent state or come in kinds this parser does not know. It
@@ -316,8 +344,9 @@ class ModelConfig:
                     + ([f"layer_types of kind {', '.join(kinds)}"]
                        if kinds else []))
                 + ", which no model module here reads (state-space layers "
-                "are served for phi4flash, linear-attention layers for "
-                "kimi_linear); it is not parsed as llama")
+                "are served for phi4flash (Mamba-1) and granitemoehybrid "
+                "(Mamba-2), linear-attention layers for kimi_linear); it "
+                "is not parsed as llama")
         if mt.startswith("gemma") and mt not in ("gemma", "gemma2"):
             # gemma3+ has different norms/attention — half-detecting it
             # via the gemma defaults would load garbage silently
@@ -1110,6 +1139,98 @@ class ModelConfig:
             kda_num_heads=int(lin["num_heads"]),
             kda_head_dim=int(lin["head_dim"]),
             kda_conv_kernel=int(lin["short_conv_kernel_size"]))
+
+    @classmethod
+    def _from_granitemoehybrid(cls, cfg: Dict[str, Any]) -> "ModelConfig":
+        """Granite 4.0-H's published keys (models/granite_hybrid.py): which
+        layers are Mamba-2 and which attend by ``layer_types`` ("mamba" /
+        "attention"; a cut depth keeps its first ``num_hidden_layers``
+        entries), the state-space sizes under ``mamba_*``, the experts as
+        ``num_local_experts`` of width ``intermediate_size`` beside one
+        shared expert of ``shared_intermediate_size``, and the family's
+        four multipliers. What the program does not run is refused by
+        name."""
+        need = ("hidden_size", "num_hidden_layers", "layer_types",
+                "num_attention_heads", "num_key_value_heads",
+                "mamba_n_heads", "mamba_d_head", "mamba_d_state",
+                "mamba_d_conv", "num_local_experts", "num_experts_per_tok",
+                "intermediate_size", "shared_intermediate_size",
+                "vocab_size", "attention_multiplier",
+                "embedding_multiplier", "residual_multiplier",
+                "logits_scaling")
+        missing = [k for k in need if cfg.get(k) is None]
+        if missing:
+            raise ValueError(f"granitemoehybrid needs {', '.join(missing)} "
+                             f"in its config (no family's class defaults "
+                             f"are this one's)")
+        n = int(cfg["num_hidden_layers"])
+        kinds = list(cfg["layer_types"])[:n]
+        hidden = int(cfg["hidden_size"])
+        heads, lanes = int(cfg["mamba_n_heads"]), int(cfg["mamba_d_head"])
+        problems = []
+        if len(kinds) < n:
+            problems.append(f"layer_types names {len(kinds)} layers of "
+                            f"num_hidden_layers = {n}")
+        other = sorted(set(kinds) - {"mamba", "attention"})
+        if other:
+            problems.append(f"layer_types of kind {', '.join(other)}")
+        if "mamba" not in kinds or "attention" not in kinds:
+            problems.append("layer_types without a mamba layer or without "
+                            "an attention layer inside the depth (the cache "
+                            "is a state group beside paged rows)")
+        if str(cfg.get("position_embedding_type", "nope")) != "nope":
+            problems.append("a position_embedding_type other than nope")
+        if cfg.get("rope_scaling"):
+            problems.append("rope_scaling (nothing is rotated)")
+        if int(cfg.get("mamba_n_groups") or 1) != 1:
+            problems.append("mamba_n_groups other than 1 (one B / C group "
+                            "is what engine/ssd.py shares between heads)")
+        if int(cfg.get("mamba_expand") or 2) * hidden != heads * lanes:
+            problems.append("mamba_expand * hidden_size is not "
+                            "mamba_n_heads * mamba_d_head")
+        if cfg.get("mamba_proj_bias"):
+            problems.append("mamba_proj_bias")
+        if not cfg.get("mamba_conv_bias", True):
+            problems.append("mamba_conv_bias false (the convolution is "
+                            "served with its bias)")
+        if cfg.get("attention_bias"):
+            problems.append("attention_bias")
+        if str(cfg.get("normalization_function", "rmsnorm")) != "rmsnorm":
+            problems.append("a normalization_function other than rmsnorm")
+        if str(cfg.get("hidden_act") or "silu") != "silu":
+            problems.append("a hidden_act other than silu")
+        if hidden % int(cfg["num_attention_heads"]):
+            problems.append("hidden_size is not a multiple of "
+                            "num_attention_heads (the head size is their "
+                            "quotient)")
+        if problems:
+            raise ValueError("granitemoehybrid is not implemented with: "
+                             + "; ".join(problems))
+        return cls(
+            model_type="granitemoehybrid",
+            vocab_size=int(cfg["vocab_size"]), hidden_size=hidden,
+            intermediate_size=int(cfg["intermediate_size"]),
+            num_layers=n, num_heads=int(cfg["num_attention_heads"]),
+            num_kv_heads=int(cfg["num_key_value_heads"]),
+            head_dim=hidden // int(cfg["num_attention_heads"]),
+            max_position_embeddings=int(
+                cfg.get("max_position_embeddings") or 131072),
+            rms_norm_eps=float(cfg.get("rms_norm_eps", 1e-5)),
+            rope_theta=float(cfg.get("rope_theta") or 10000.0),
+            tie_word_embeddings=bool(cfg.get("tie_word_embeddings", True)),
+            num_experts=int(cfg["num_local_experts"]),
+            num_experts_per_tok=int(cfg["num_experts_per_tok"]),
+            moe_norm_topk=True,
+            shared_expert_size=int(cfg["shared_intermediate_size"]),
+            nope_full=True,
+            query_pre_attn_scalar=float(cfg["attention_multiplier"]) ** -2,
+            layer_types=kinds,
+            ssd_num_heads=heads, ssd_head_dim=lanes,
+            ssd_d_state=int(cfg["mamba_d_state"]),
+            ssd_conv_kernel=int(cfg["mamba_d_conv"]),
+            embedding_multiplier=float(cfg["embedding_multiplier"]),
+            residual_multiplier=float(cfg["residual_multiplier"]),
+            logits_scaling=float(cfg["logits_scaling"]))
 
     @classmethod
     def _from_phi4flash(cls, cfg: Dict[str, Any]) -> "ModelConfig":

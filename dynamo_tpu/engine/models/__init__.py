@@ -5,9 +5,10 @@ architecture.md "What a model family brings"): ``refusals``,
 ``engine_cache`` and ``prefill_counters`` beside its forward passes and
 ``param_shapes`` / ``init_one_param`` / ``init_params``."""
 
-from . import kimi_linear, llama, mla, sambay
+from . import granite_hybrid, kimi_linear, llama, mla, sambay
 
-__all__ = ["kimi_linear", "llama", "mla", "sambay", "module_for"]
+__all__ = ["granite_hybrid", "kimi_linear", "llama", "mla", "sambay",
+           "module_for"]
 
 
 def module_for(cfg):
@@ -17,6 +18,8 @@ def module_for(cfg):
         return sambay
     if cfg.has_kda:
         return kimi_linear
+    if cfg.has_ssd:
+        return granite_hybrid
     if cfg.kv_lora_rank > 0:
         return mla
     return llama

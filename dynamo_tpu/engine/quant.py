@@ -348,7 +348,13 @@ _LAYER_MATMULS = ("wq", "wk", "wv", "wo", "gate", "up", "down",
                   # low-rank pairs (kda_low, kda_fb, kda_gb: the decay and
                   # the gates stand behind them) stay in the load dtype,
                   # A_log, dt_bias and the convolution taps float32
-                  "kda_in", "kda_wo")
+                  "kda_in", "kda_wo",
+                  # granitemoehybrid's Mamba-2 layers (models/
+                  # granite_hybrid.py): the z | xBC | dt projection and the
+                  # output projection, 102.2 M of a layer's 102.3 M. A_log,
+                  # dt_bias, D and the convolution's taps stay float32, its
+                  # bias and the gated norm in the load dtype
+                  "ssd_in", "ssd_out")
 # MoE expert tensors [L, E, D, F] → per (L, E, out-channel) scales. For
 # mixtral-class models the experts ARE the weights, so leaving them bf16
 # would forfeit the whole int8 HBM-read win; the router stays full

@@ -223,6 +223,10 @@ def load_params_auto(model_dir: str, cfg: Optional[ModelConfig] = None,
         # kimi_linear: its own names and fused stacks; no mesh serves it
         # yet (kimi_linear.refusals: raised at engine build)
         return load_kimi_linear_params(model_dir, cfg, dtype=dtype)
+    if cfg.has_ssd:
+        # granitemoehybrid: its own names, the experts' fused gate|up split;
+        # no mesh serves it yet (granite_hybrid.refusals)
+        return load_granite_hybrid_params(model_dir, cfg, dtype=dtype)
     if mesh is not None:
         return load_params_sharded(model_dir, mesh, cfg, dtype=dtype)
     return load_llama_params(model_dir, cfg, dtype=dtype)
@@ -1045,6 +1049,148 @@ def save_kimi_linear_hf_style(params: Dict[str, jax.Array],
             t = t.T[:, None, :]
         elif how == "flat":
             t = t.reshape(1, 1, -1, 1)
+        out[tname] = np.ascontiguousarray(t)
+    save_file(out, os.path.join(out_dir, "model.safetensors"))
+
+
+# granitemoehybrid checkpoint names (the published ``transformers`` modelling
+# code, GraniteMoeHybrid*, from memory: no network here), per layer under
+# ``model.layers.{i}.``. UNVERIFIED: no published checkpoint has been read
+# through this map (the repository holds none; the round trip through
+# ``save_granite_hybrid_hf_style`` proves the transforms, not the names). -> (leaf of engine/models/granite_hybrid.py's stacks,
+# how the torch tensor becomes ours)
+_GRANITE_MAMBA = {
+    "mamba.in_proj.weight": ("ssd_in", _T),            # z | xBC | dt rows
+    "mamba.conv1d.weight": ("ssd_conv", "conv"),       # [lanes, 1, taps]
+    "mamba.conv1d.bias": ("ssd_conv_b", None),
+    "mamba.dt_bias": ("ssd_dt_bias", None),
+    "mamba.A_log": ("ssd_A_log", None),
+    "mamba.D": ("ssd_D", None),
+    "mamba.norm.weight": ("ssd_norm", None),
+    "mamba.out_proj.weight": ("ssd_out", _T),
+}
+_GRANITE_ATTN = {
+    "self_attn.q_proj.weight": ("wq", _T),
+    "self_attn.k_proj.weight": ("wk", _T),
+    "self_attn.v_proj.weight": ("wv", _T),
+    "self_attn.o_proj.weight": ("wo", _T),
+}
+# every layer: the norms, the router, the experts' fused input ([E, 2F, D]:
+# gate rows, then up rows) and output ([E, D, F]) and the shared expert's
+# ([2Fs, D], [D, Fs])
+_GRANITE_EVERY = {
+    "input_layernorm.weight": ("ln1", None),
+    "post_attention_layernorm.weight": ("ln2", None),
+    "block_sparse_moe.router.layer.weight": ("router", _T),
+    "block_sparse_moe.input_linear.weight": (("moe_gate", "moe_up"),
+                                             "halves"),
+    "block_sparse_moe.output_linear.weight": ("moe_down", "experts"),
+    "shared_mlp.input_linear.weight": (("sh_gate", "sh_up"), "halves"),
+    "shared_mlp.output_linear.weight": ("sh_down", _T),
+}
+_GRANITE_FLOAT32 = ("ssd_A_log", "ssd_dt_bias", "ssd_D", "ssd_conv")
+
+
+def _granite_tensor_names(cfg: ModelConfig) -> Dict[str, tuple]:
+    """checkpoint tensor name -> (engine leaf or the two a fused tensor
+    fills, the layer's index in that stack, transform)."""
+    from .models.granite_hybrid import layer_kinds
+    names: Dict[str, tuple] = {
+        "model.embed_tokens.weight": ("embed", None, None),
+        "model.norm.weight": ("final_norm", None, None)}
+    if not cfg.tie_word_embeddings:
+        names["lm_head.weight"] = ("lm_head", None, _T)
+    seen: Dict[str, int] = {}
+    for l, kind in enumerate(layer_kinds(cfg)):
+        i = seen.get(kind, 0)
+        seen[kind] = i + 1
+        pre = f"model.layers.{l}."
+        for sub, (leaf, how) in _GRANITE_EVERY.items():
+            names[pre + sub] = (leaf, l, how)
+        for sub, (leaf, how) in (_GRANITE_MAMBA if kind == "M"
+                                 else _GRANITE_ATTN).items():
+            names[pre + sub] = (leaf, i, how)
+    return names
+
+
+def _granite_parts(t: np.ndarray, how) -> list:
+    """A checkpoint tensor as the engine's leaves hold it: one array, or
+    two for a fused gate|up."""
+    if how == _T:
+        return [t.T]
+    if how == "conv":
+        return [t[:, 0, :].T]
+    if how == "experts":                     # [E, D, F] -> [E, F, D]
+        return [np.swapaxes(t, -1, -2)]
+    if how == "halves":                      # [.., 2F, D] -> 2 x [.., D, F]
+        half = t.shape[-2] // 2
+        return [np.swapaxes(t[..., :half, :], -1, -2),
+                np.swapaxes(t[..., half:, :], -1, -2)]
+    return [t]
+
+
+def load_granite_hybrid_params(model_dir: str,
+                               cfg: Optional[ModelConfig] = None,
+                               dtype=jnp.bfloat16) -> Dict[str, jax.Array]:
+    """Load a granitemoehybrid checkpoint into ``models/granite_hybrid.py``'s
+    stacks: the Mamba-2 and attention leaves at the layer's index among its
+    kind, the experts' and the shared expert's fused input split into gate
+    and up. A tensor this map does not know, or a parameter the checkpoint
+    lacks, fails loudly: the names are from memory. A depth cut below the
+    checkpoint's passes the deeper layers' tensors over."""
+    import re
+    from .models.granite_hybrid import param_shapes
+    cfg = cfg or ModelConfig.from_model_dir(model_dir)
+    names = _granite_tensor_names(cfg)
+    out = {name: np.zeros(shape, _np_dtype(
+        jnp.float32 if name.rsplit(".", 1)[-1] in _GRANITE_FLOAT32
+        else dtype)) for name, shape in param_shapes(cfg).items()}
+    missing = set(names)
+    for tname, tensor in _iter_safetensors(model_dir):
+        if tname not in names:
+            deeper = re.match(r"model\.layers\.(\d+)\.", tname)
+            if deeper and int(deeper.group(1)) >= cfg.num_layers:
+                continue
+            raise ValueError(f"granitemoehybrid checkpoint tensor {tname!r} "
+                             f"has no place in engine/models/"
+                             f"granite_hybrid.py's parameters")
+        leaf, idx, how = names[tname]
+        leaves = leaf if isinstance(leaf, tuple) else (leaf,)
+        for one, t in zip(leaves, _granite_parts(
+                np.asarray(tensor, np.float32), how)):
+            name = one if idx is None else f"layers.{one}"
+            target = out[name] if idx is None else out[name][idx]
+            if target.shape != t.shape:
+                raise ValueError(f"{tname}: shape {t.shape}, the engine "
+                                 f"holds {target.shape} for {name}")
+            target[...] = t
+        missing.discard(tname)
+    if missing:
+        raise ValueError(f"granitemoehybrid checkpoint lacks {len(missing)} "
+                         f"tensor(s), e.g. {sorted(missing)[:3]}")
+    return {name: jnp.asarray(_note_handoff(a)) for name, a in out.items()}
+
+
+def save_granite_hybrid_hf_style(params: Dict[str, jax.Array],
+                                 cfg: ModelConfig, out_dir: str) -> None:
+    """The inverse of ``load_granite_hybrid_params`` (tests)."""
+    from safetensors.numpy import save_file
+    os.makedirs(out_dir, exist_ok=True)
+    out = {}
+    for tname, (leaf, idx, how) in _granite_tensor_names(cfg).items():
+        def held(one):
+            t = np.asarray(params[one if idx is None else f"layers.{one}"],
+                           np.float32)
+            return t if idx is None else t[idx]
+        if how == "halves":
+            t = np.concatenate([np.swapaxes(held(one), -1, -2)
+                                for one in leaf], axis=-2)
+        elif how in (_T, "experts"):
+            t = np.swapaxes(held(leaf), -1, -2)
+        elif how == "conv":
+            t = held(leaf).T[:, None, :]
+        else:
+            t = held(leaf)
         out[tname] = np.ascontiguousarray(t)
     save_file(out, os.path.join(out_dir, "model.safetensors"))
 

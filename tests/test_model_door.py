@@ -25,7 +25,8 @@ import pytest
 
 from dynamo_tpu.engine import models
 from dynamo_tpu.engine.config import ModelConfig
-from dynamo_tpu.engine.models import kimi_linear, llama, mla, sambay
+from dynamo_tpu.engine.models import (granite_hybrid, kimi_linear, llama, mla,
+                                      sambay)
 
 FIXTURE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark", "fixtures")
@@ -56,6 +57,8 @@ FAMILIES = {
                        set(), set()),
     "tiny-kimi-linear": (kimi_linear, {"kv", "kda", "conv"}, {"kda_chunks"},
                          set()),
+    "tiny-granite-moe-hybrid": (granite_hybrid, {"k", "v", "ssd", "conv"},
+                                {"ssd_chunks"}, set()),
 }
 
 # the keys every family's records carry, as at PR 48 (the parent of the PR
@@ -195,13 +198,15 @@ def test_a_served_request_keeps_its_record_keys_and_replays(built):
         assert set(r) - SOMETIMES == DECODE_KEYS | decode_own
     # the families' counters, by the arithmetic PERF.md section 3 states
     n = PROMPT_TOKENS
-    stateful = module in (sambay, kimi_linear)
+    stateful = module in (sambay, kimi_linear, granite_hybrid)
     assert prefill[0]["scan_tokens"] == (n if stateful else 0)
     assert prefill[0]["key_tokens"] == (
-        n * (n + 1) // 2 if module is kimi_linear
+        n * (n + 1) // 2 if module in (kimi_linear, granite_hybrid)
         or (module is mla and not prefill_own) else 0)
     if "dsa_blocks" in prefill_own:
         assert 0 < prefill[0]["dsa_blocks_run"] <= prefill[0]["dsa_blocks"]
     if "kda_chunks" in prefill_own:
         assert prefill[0]["kda_chunks"] >= 1
+    if "ssd_chunks" in prefill_own:
+        assert prefill[0]["ssd_chunks"] == 1
     assert differs == []
