@@ -7,30 +7,72 @@ an XLA expansion of the block's keys and values ``[KB, H, 192]``, the shared
 flash kernel over them (values padded to the keys' width, because it has one
 width for both), an XLA merge of the partial result — a 1,024-token chunk
 at 16,384 live rows took 13.2 ms a layer on a v5e against 4.8 ms of
-operations at the MXU's peak; this kernel takes 8.4. A head's queries stay
-in VMEM while the block's rows stream past them once: each tile of rows is
-expanded through the head's slices of ``wkv_b`` on the spot (the expanded
-keys and values never exist in HBM), scored against ``q_nope`` and ``q_pe``
-separately (128 and 64 lanes: no padding of the values, no concatenation
-of the keys), and folded into the running max, sum and accumulator, which
-come in from the previous key block and go out to the next.
+operations at the MXU's peak; this kernel took 8.4 (PR 37) and takes 6.7.
+A head's queries stay in VMEM while the block's rows stream past them
+once: each tile of rows is expanded through the head's slices of ``wkv_b``
+on the spot (the expanded keys and values never exist in HBM), scored
+against ``q_nope`` and ``q_pe`` separately (128 and 64 lanes: no padding of
+the values, no concatenation of the keys), and folded into the running max,
+sum and accumulator, which come in from the previous key block and go out
+to the next.
 
 Per grid step (head h, query tile i, row tile j):
 
     c, k_pe = rows[j][:, :rank], rows[j][:, rank:rank+dr]
     k_nope  = c · W_k[h]            # [tk, dn], float32 accumulation
     v       = c · W_v[h]            # [tk, dv]
-    s       = (q_nope[h, i] · k_nopeᵀ + q_pe[h, i] · k_peᵀ) · scale
-    s       = where(key <= query and key < live, s, -inf)
-    m, l, acc ← online softmax
+    for each sub-range a of the tile's queries (Q_SPLIT = 4 of 256 rows):
+        s = (q_nope[h, i, a] · k_nopeᵀ + q_pe[h, i, a] · k_peᵀ) · scale
+        s = where(key <= query and key < live, s, -inf)   # masked tiles only
+        m, l, acc[a] ← online softmax
 
 The grid's last axis runs over the row tiles ("arbitrary": the state is a
-scratch carried across it); a tile wholly above the causal diagonal or past
-the live length computes nothing. A query tile is as long as the chunk up to
-``Q_TILE`` rows, so a chunk of 1,024 expands every row once a head. Row
-tiles of 1,024 measured 8.4 ms where 512 took 11.3 and 256 took 18.9 (the
-same chunk; fewer grid steps and state updates a row); 2,048 and 4,096 read
-the same as 1,024.
+scratch carried across it). **A tile has one of three classes, read from
+the scalars the kernel holds** (``q_lo``, ``live``, the grid indices, the
+tile sizes; no option chooses):
+
+- *interior*: every row live and at or below the tile's FIRST query
+  (``(j+1)·tk <= live`` and ``(j+1)·tk - 1 <= q_first``), so every query
+  reads every row: scores → max → exp → sum → cast, no mask built or
+  applied. Eight of a 1,024-token chunk's nine live tiles at the coding
+  cell's mean length (8,636 keys a query), fifteen of sixteen at 16,384.
+- *masked*: some row that some query may read, and not interior — the
+  tile on the causal diagonal, the tile that holds the live length's edge,
+  a tile whose queries sit at negative positions in this key block's frame.
+  The same arithmetic with the mask as ONE compare a score (column against
+  ``min(row + q_first - k_first, live - k_first - 1)``, a per-row bound)
+  and one ``where``; a row that reads nothing yet keeps its state.
+- *skipped*: wholly above the diagonal or past the live length: nothing.
+
+Either body is one straight line over the query tile's sub-ranges with
+sub-range a+1's score matmuls issued BEFORE sub-range a's softmax: the
+bundle scheduler then lays the softmax's vector work under the MXU's (a
+sub-range's rows depend on no other's, so the state is updated exactly as
+often as in one piece, which is what a split over the KEYS cannot offer).
+The results are bit-equal to the one-piece masked body's.
+
+A query tile is as long as the chunk up to ``Q_TILE`` rows, so a chunk of
+1,024 expands every row once a head. Row tiles of 1,024 measured 8.4 ms
+where 512 took 11.3 and 256 took 18.9 (PR 37, the same chunk; fewer grid
+steps and state updates a row); 2,048 and 4,096 read the same as 1,024.
+
+Measured alone on a v5e (PR 60; published widths, bf16, ms a layer for a
+1,024-token chunk at 16,384 live rows | the first chunk of a prompt | a
+4,096-token bucket from position 0; in brackets the body's bundles from
+the compiler's dump, interior / masked; the MXU is busy 7,050-7,280):
+PR 37's body 8.32 | 0.88 | 7.97 (9,862); the tile classes 7.34 | 0.88 |
+7.57 (8,268 / 9,860); the mask as one compare too 7.28 | 0.82 | 7.32;
+**the sub-ranges, this file, 6.69 | 0.78 | 6.97 (7,770 / 7,864)**.
+Sub-ranges without the early issue 7.58 (8,502), two / eight of them
+issued early 6.78 / 6.72.
+Tried and left out: the scale multiplied into the keys (7.22 in one piece,
+nothing under the sub-ranges, 7,720 bundles, and the results are no longer
+the parent's bit for bit); the diagonal tile by sub-tiles of 512 or 256
+(7.28 / 7.56: a skipped sub-tile saves less than the second state update
+costs); key sub-tiles inside an interior grid step (7.99 at 512, 14.77 at
+256); the running sum kept by lane (7.26); one matmul over
+``[k_nope | k_pe]`` (6.54 with the sub-ranges: 2% for a concatenation a
+sub-range); with no softmax at all the step reads 6.46.
 """
 
 from __future__ import annotations
@@ -47,6 +89,7 @@ __all__ = ["mla_prefill_block", "mla_prefill_supported", "Q_TILE", "K_TILE"]
 NEG_INF = -1e30
 Q_TILE = 1024          # query rows a grid step holds (the whole chunk up to it)
 K_TILE = 1024          # latent rows a grid step expands and scores
+Q_SPLIT = 4            # query sub-ranges a grid step's body is laid out in
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 
@@ -67,7 +110,8 @@ def _kernel(meta_ref, qn_ref, qp_ref, rows_ref, wk_ref, wv_ref, acc_in,
     ml_in/out [1, tq, 2] f32 (max, sum)."""
     i, j = pl.program_id(1), pl.program_id(2)
     q_lo, live = meta_ref[0], meta_ref[1]
-    q_first = q_lo + i * tq
+    q_first, k_first = q_lo + i * tq, j * tk
+    f32 = jnp.float32
 
     @pl.when(j == 0)
     def _():
@@ -75,34 +119,69 @@ def _kernel(meta_ref, qn_ref, qp_ref, rows_ref, wk_ref, wv_ref, acc_in,
         m_ref[:] = ml_in[0, :, 0:1]
         l_ref[:] = ml_in[0, :, 1:2]
 
-    # a tile with a key some query of the tile may read
-    @pl.when((j * tk < live) & (j * tk <= q_first + tq - 1))
-    def _():
+    # the query tile in sub-ranges, where they fall on whole sublane groups
+    # of the queries' packed rows
+    n = Q_SPLIT if tq % (16 * Q_SPLIT) == 0 else 1
+    qr = tq // n
+    sub_ranges = [slice(a * qr, (a + 1) * qr) for a in range(n)]
+
+    def fold(masked: bool):
         rows = rows_ref[:]
         c, k_pe = rows[:, :rank], rows[:, rank:rank + dr]
-        f32 = jnp.float32
         k_nope = jnp.dot(c, wk_ref[0],
                          preferred_element_type=f32).astype(rows.dtype)
         v = jnp.dot(c, wv_ref[0],
                     preferred_element_type=f32).astype(rows.dtype)
         contract_last = (((1,), (1,)), ((), ()))
-        s = (jax.lax.dot_general(qn_ref[0], k_nope, contract_last,
-                                 preferred_element_type=f32)
-             + jax.lax.dot_general(qp_ref[0], k_pe, contract_last,
-                                   preferred_element_type=f32)) * scale
-        kpos = j * tk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        qpos = q_first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        mask = (kpos <= qpos) & (kpos < live)
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # a row with nothing to read yet: exp(NEG_INF - NEG_INF) is not 0
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=f32)
-        m_ref[:] = m_new
+
+        def scores(rq: slice):
+            return (jax.lax.dot_general(qn_ref[0, rq, :], k_nope,
+                                        contract_last,
+                                        preferred_element_type=f32)
+                    + jax.lax.dot_general(qp_ref[0, rq, :], k_pe,
+                                          contract_last,
+                                          preferred_element_type=f32)
+                    ) * scale
+
+        if masked:
+            # key <= query and key < live, as ONE compare: row r of a
+            # sub-range reads the tile's columns up to min(r + its first
+            # query - k_first, live - k_first - 1)
+            col = jax.lax.broadcasted_iota(jnp.int32, (qr, tk), 1)
+            row = jax.lax.broadcasted_iota(jnp.int32, (qr, 1), 0)
+        # one straight line: sub-range a+1's scores are issued before
+        # sub-range a's softmax, which then runs under the MXU's work
+        s_next = scores(sub_ranges[0])
+        for a, rq in enumerate(sub_ranges):
+            s = s_next
+            if a + 1 < n:
+                s_next = scores(sub_ranges[a + 1])
+            m_prev = m_ref[rq, :]
+            if masked:
+                last = jnp.minimum(row + (q_first + a * qr - k_first),
+                                   live - k_first - 1)
+                s = jnp.where(col <= last, s, NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            if masked:
+                # a row with nothing to read yet keeps max NEG_INF, and
+                # exp(NEG_INF - NEG_INF) is not 0: subtract 0 there
+                p = jnp.exp(s - jnp.where(m_new == NEG_INF, 0.0, m_new))
+            else:
+                p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[rq, :] = l_ref[rq, :] * alpha + jnp.sum(
+                p, axis=1, keepdims=True)
+            acc_ref[rq, :] = acc_ref[rq, :] * alpha + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=f32)
+            m_ref[rq, :] = m_new
+
+    # a tile's class, from scalars: wholly live and at or below every query
+    # of the tile (no mask) / some key that some query may read (the mask) /
+    # neither (nothing)
+    interior = (k_first + tk <= live) & (k_first + tk - 1 <= q_first)
+    reads = (k_first < live) & (k_first <= q_first + tq - 1)
+    pl.when(interior)(functools.partial(fold, False))
+    pl.when(reads & jnp.logical_not(interior))(functools.partial(fold, True))
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():

@@ -1382,32 +1382,57 @@ _CHUNK_CASES = {
     "fragmented_table": (16, 24, 13, [9, 3, 14, 1, 7, 12, 2, 5]),
     "table_not_a_multiple_of_the_key_block": (16, 24, 16,
                                               [4, 5, 6, 7, 8, 9, 10]),
+    # the kernel's tile classes (engine/mla_prefill.py; row tiles of 8, key
+    # blocks of 16): three key blocks wholly under the chunk and live
+    "interior_key_blocks_only": (16, 48, 16, [1, 2, 3, 4, 5, 6, 7, 8]),
+    # the chunk starts inside a row tile; in the second key block's frame
+    # the first queries sit at negative positions and read nothing there
+    "start_inside_a_tile": (32, 4, 28, [1, 2, 3, 4, 5, 6, 7, 8]),
+    # live length 19: the edge lies in row tile 16..23, which the query
+    # tiles from 24 on (padding rows, two_query_tiles) see wholly below
+    # their diagonal
+    "edge_below_the_diagonal": (32, 16, 3, [1, 2, 3, 4, 5, 6, 7, 8]),
+    # live length 24: the last row tile is full, its key block half empty
+    "length_on_a_tile_edge": (16, 8, 16, [1, 2, 3, 4, 5, 6, 7, 8]),
+    # a query tile of 64 rows: the kernel lays its body out in four
+    # sub-ranges of 16 (mla_prefill.Q_SPLIT), each with its own mask offset
+    "query_sub_ranges": (64, 24, 61, [1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12]),
 }
+
+
+def _chunk_args(case, dtype, seed):
+    """→ (the arguments of ``_dense_chunk`` / ``_plain_dense_chunk`` up to
+    the implementation for one of ``_CHUNK_CASES``, its true length)."""
+    T, start, true_len, blocks = _CHUNK_CASES[case]
+    cfg = _cfg(q_lora=12)
+    H, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    kv_flat = jax.random.normal(
+        keys[0], (NUM_BLOCKS * BS, mla.latent_row_lanes(cfg))).astype(dtype)
+    lp = {"wkv_b": (jax.random.normal(
+        keys[1], (cfg.kv_lora_rank, H * (dn + cfg.v_head_dim)))
+        * cfg.kv_lora_rank ** -0.5).astype(dtype)}
+    q_nope = jax.random.normal(keys[2], (T, H, dn)).astype(dtype)
+    q_pe = jax.random.normal(keys[3], (T, H, dr)).astype(dtype)
+    return (q_nope, q_pe, lp, kv_flat, jnp.asarray(blocks, jnp.int32),
+            jnp.asarray(start), jnp.asarray(start + true_len), cfg, BS,
+            mla.softmax_scale(cfg)), true_len
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("case", sorted(_CHUNK_CASES))
+@pytest.mark.parametrize("case", [
+    "cached_prefix", "fragmented_table", "length_on_a_block_edge",
+    "length_on_a_key_block_edge", "table_not_a_multiple_of_the_key_block",
+    "true_len_under_bucket", "whole_prompt"])
 def test_blocked_dense_chunk_equals_the_plain_dense_form(case, dtype,
                                                          monkeypatch):
     """The key-block walk (two blocks of the pool a key block here, so
     every case crosses several) against the whole-table expression, on the
     valid query rows: float32 to rounding, bf16 to bf16's."""
     monkeypatch.setattr(mla, "MLA_KEY_BLOCK", 2 * BS)
-    T, start, true_len, blocks = _CHUNK_CASES[case]
-    cfg = _cfg(q_lora=12)
-    H, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    keys = jax.random.split(jax.random.PRNGKey(3), 4)
-    W = mla.latent_row_lanes(cfg)
-    kv_flat = jax.random.normal(keys[0], (NUM_BLOCKS * BS, W)).astype(dtype)
-    lp = {"wkv_b": (jax.random.normal(
-        keys[1], (cfg.kv_lora_rank, H * (dn + cfg.v_head_dim)))
-        * cfg.kv_lora_rank ** -0.5).astype(dtype)}
-    q_nope = jax.random.normal(keys[2], (T, H, dn)).astype(dtype)
-    q_pe = jax.random.normal(keys[3], (T, H, dr)).astype(dtype)
-    table = jnp.asarray(blocks, jnp.int32)
-    args = (q_nope, q_pe, lp, kv_flat, table, jnp.asarray(start),
-            jnp.asarray(start + true_len), cfg, BS, mla.softmax_scale(cfg))
+    args, true_len = _chunk_args(case, dtype, seed=3)
+    cfg = args[7]
     got = np.asarray(jax.jit(
         lambda *a: mla._dense_chunk(*a, cfg, BS, mla.softmax_scale(cfg),
                                     "xla"))(*args[:7]), np.float32)
@@ -1417,37 +1442,39 @@ def test_blocked_dense_chunk_equals_the_plain_dense_form(case, dtype,
                                atol=tol)
 
 
+# the cases put under the kernel: float32 to rounding, two of them in bf16
+_KERNEL_CASES = [(case, jnp.float32) for case in (
+    "cached_prefix", "fragmented_table", "true_len_under_bucket",
+    "interior_key_blocks_only", "whole_prompt", "start_inside_a_tile",
+    "edge_below_the_diagonal", "length_on_a_tile_edge", "query_sub_ranges")
+] + [("start_inside_a_tile", jnp.bfloat16),
+     ("query_sub_ranges", jnp.bfloat16)]
+
+
 @pytest.mark.parametrize("q_tile", [1024, 8], ids=["one_query_tile",
                                                     "two_query_tiles"])
-@pytest.mark.parametrize("case", ["cached_prefix", "fragmented_table",
-                                  "true_len_under_bucket"])
-def test_blocked_dense_chunk_through_the_kernel(case, q_tile, monkeypatch):
+@pytest.mark.parametrize(
+    "case, dtype", _KERNEL_CASES,
+    ids=[case if dtype == jnp.float32 else f"{case}_bfloat16"
+         for case, dtype in _KERNEL_CASES])
+def test_blocked_dense_chunk_through_the_kernel(case, dtype, q_tile,
+                                                monkeypatch):
     """The same walk with each key block expanded, attended and merged in
     the Pallas kernel ``mla_prefill`` (interpreted here; two row tiles a
     key block, the state carried from one call to the next): the form the
-    chip runs."""
+    chip runs. The cases put every class of tile under it — interior
+    (no mask), diagonal, live edge, skipped — and every row of the bucket is
+    held to the plain form, the padding rows past ``true_len`` too (they
+    are queries like any other to both)."""
     monkeypatch.setattr(mla, "MLA_KEY_BLOCK", 2 * BS)
     for module in (mla, mla_prefill):
         monkeypatch.setattr(module, "K_TILE", 8)
         monkeypatch.setattr(module, "Q_TILE", q_tile)
-    T, start, true_len, blocks = _CHUNK_CASES[case]
-    cfg = _cfg(q_lora=12)
-    H, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    keys = jax.random.split(jax.random.PRNGKey(4), 4)
-    kv_flat = jax.random.normal(keys[0], (NUM_BLOCKS * BS,
-                                          mla.latent_row_lanes(cfg)))
-    lp = {"wkv_b": jax.random.normal(
-        keys[1], (cfg.kv_lora_rank, H * (dn + cfg.v_head_dim)))
-        * cfg.kv_lora_rank ** -0.5}
-    q_nope = jax.random.normal(keys[2], (T, H, dn))
-    q_pe = jax.random.normal(keys[3], (T, H, dr))
-    args = (q_nope, q_pe, lp, kv_flat, jnp.asarray(blocks, jnp.int32),
-            jnp.asarray(start), jnp.asarray(start + true_len), cfg, BS,
-            mla.softmax_scale(cfg))
-    got = np.asarray(mla._dense_chunk(*args, "pallas_interpret"))
-    want = np.asarray(_plain_dense_chunk(*args))
-    np.testing.assert_allclose(got[:true_len], want[:true_len], rtol=2e-5,
-                               atol=2e-5)
+    args, _ = _chunk_args(case, dtype, seed=4)
+    got = np.asarray(mla._dense_chunk(*args, "pallas_interpret"), np.float32)
+    want = np.asarray(_plain_dense_chunk(*args), np.float32)
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
 
 
 def test_blocked_prefill_work_follows_the_live_length(monkeypatch):
